@@ -17,8 +17,6 @@ import numpy as np
 
 
 def main():
-    from hetu_tpu.utils.device import force_cpu_if_requested
-    force_cpu_if_requested()
     import jax
     import jax.numpy as jnp
 

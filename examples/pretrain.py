@@ -97,4 +97,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from hetu_tpu.utils.device import enable_compile_cache
+    enable_compile_cache()
     main()

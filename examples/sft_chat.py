@@ -17,8 +17,6 @@ import numpy as np
 
 
 def main():
-    from hetu_tpu.utils.device import force_cpu_if_requested
-    force_cpu_if_requested()
     import jax
 
     from hetu_tpu.data import ChatFormat, InputOutputTemplate, build_sft_example

@@ -23,11 +23,6 @@ Top-level namespaces mirror the reference's Python framework
 
 __version__ = "0.1.0"
 
-# version-portability shims FIRST: later imports (and user code) may use
-# jax.shard_map / lax.axis_size / lax.pvary on releases that predate them
-from hetu_tpu.core import jax_compat as _jax_compat
-_jax_compat.install()
-
 from hetu_tpu.core.mesh import (
     MeshConfig,
     create_mesh,

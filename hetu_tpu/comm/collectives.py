@@ -65,9 +65,7 @@ def eligible(x, mode: str, block_size: int = DEFAULT_BLOCK) -> bool:
 # ---------------------------------------------------------------------------
 
 def axis_size(axis_name: str) -> int:
-    """Static size of a bound mesh axis.  `lax.axis_size` is guaranteed
-    to exist here: hetu_tpu/__init__ installs the version-portability
-    shim (core/jax_compat.py) before any submodule loads."""
+    """Static size of a bound mesh axis."""
     return int(lax.axis_size(axis_name))
 
 

@@ -67,7 +67,7 @@ def quantize_blockwise(x, block_size: int = DEFAULT_BLOCK, *,
         # pages) inherits it transparently
         from hetu_tpu.ops.pallas import resolve_route
         from hetu_tpu.ops.pallas import quant as _pq
-        if resolve_route("quant", _pq.compatible(n, block_size, bits)):
+        if resolve_route("quant", _pq.check_shapes, n, block_size, bits):
             with jax.named_scope("pallas_quantize"):
                 return _pq.quantize_blockwise_pallas(flat, block_size,
                                                      bits=bits)
@@ -92,8 +92,8 @@ def dequantize_blockwise(q, scale) -> jnp.ndarray:
     """(q int8 [nb, bs], scales f32 [nb]) -> flat f32 [nb*bs]."""
     from hetu_tpu.ops.pallas import resolve_route
     from hetu_tpu.ops.pallas import quant as _pq
-    if resolve_route("quant",
-                     _pq.compatible(q.shape[0] * q.shape[1], q.shape[1])):
+    if resolve_route("quant", _pq.check_shapes, q.shape[0] * q.shape[1],
+                     q.shape[1]):
         with jax.named_scope("pallas_dequantize"):
             return _pq.dequantize_blockwise_pallas(q, scale)
     return (q.astype(jnp.float32) * scale[:, None]).reshape(-1)

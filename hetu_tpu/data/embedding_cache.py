@@ -29,7 +29,7 @@ def _lib(policy: str = "lru"):
     if policy in _LIBS:
         return _LIBS[policy]
     name = f"lib{policy}_cache.so"
-    lib = load_native_lib(name, name)
+    lib = load_native_lib(name)
     for fn, res, args in (
             (f"{policy}_create", ctypes.c_void_p, [ctypes.c_int64]),
             (f"{policy}_destroy", None, [ctypes.c_void_p]),

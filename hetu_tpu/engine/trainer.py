@@ -73,6 +73,7 @@ class Trainer:
         self.params = None
         self.opt_state = None
         self._step_fn = None
+        self.kernel_routes: dict = {}   # of the last plan traced
         self._ckpt = (CheckpointManager(config.ckpt_dir, config.ckpt_keep)
                       if config.ckpt_dir else None)
         self.global_step = 0
@@ -272,6 +273,42 @@ class Trainer:
                       "m": pshard, "v": pshard}
         return pshard, sshard
 
+    def lower_abstract(self):
+        """The train step lowered for ABSTRACT arguments laid out as
+        `build` lays out the real ones (`.compile()` gives the program
+        `train_step` runs for the configured batch).  Nothing is
+        materialised, so it lowers over a mesh of described devices too:
+        tests/test_chip_compile.py compiles it for a TPU that is not
+        attached.  The default step only — no compressed sync, no loss
+        scaler (their state is built by `build`)."""
+        if (self._grad_compress != "none" or self._zero_compress != "none"
+                or self._scaler is not None):
+            raise NotImplementedError(
+                "lower_abstract covers the default train step only")
+        c, mesh = self.config, self.mesh
+
+        def placed(tree, shardings):
+            return jax.tree.map(
+                lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                  sharding=s),
+                tree, shardings)
+        with use_mesh(mesh), self._declared():
+            self._pshard, self._sshard = self._make_shardings()
+            abstract = self.model.abstract_params()
+            params = placed(abstract, self._pshard)
+            opt_state = placed(jax.eval_shape(self.optimizer.init, abstract),
+                               self._sshard)
+            n_micro = c.num_micro_batches(max(self.strategy.dp, 1))
+            tokens = jax.ShapeDtypeStruct(
+                (n_micro, c.global_batch_size // n_micro, c.seq_len),
+                jnp.int32, sharding=self._batch_sharding(3))
+            key = jax.eval_shape(lambda: jax.random.key(0))
+            key = jax.ShapeDtypeStruct(key.shape, key.dtype,
+                                       sharding=NamedSharding(mesh, P()))
+            return self._make_step_pool(self._pshard, self._sshard).lower(
+                params, opt_state, {"input_ids": tokens, "labels": tokens},
+                key, None)
+
     def build(self, rng: Optional[jax.Array] = None):
         """Materialize sharded params/opt state and compile the step."""
         c, mesh = self.config, self.mesh
@@ -414,6 +451,7 @@ class Trainer:
             self.run_log.log(
                 "compile", name=pool_name, plan=str(key)[:500],
                 compile_s=compile_s, flops=est.get("flops_per_step"),
+                kernel_routes=self.kernel_routes,
                 estimated_mfu=est.get("estimated_mfu"),
                 estimated_step_s=est.get("estimated_step_s"),
                 comm_bytes=comm.get("total_wire_bytes"),
@@ -601,6 +639,17 @@ class Trainer:
         are never donated; host-fetched only on record boundaries).
         Flag unset: the wrapper never runs, the trace is byte-identical
         (registered identity contract, swept by tools_lint --flags)."""
+        from hetu_tpu.ops.pallas import record_routes
+        with record_routes() as routes:
+            out = self._train_step_traced(params, opt_state, batches, rng,
+                                          scaler_state)
+        # which kernels this plan runs, and why (trace-time fact; the
+        # compile run-event carries it)
+        self.kernel_routes = routes
+        return out
+
+    def _train_step_traced(self, params, opt_state, batches, rng,
+                           scaler_state):
         if not self._numerics:
             return self._train_step_impl(params, opt_state, batches, rng,
                                          scaler_state)
@@ -765,7 +814,16 @@ class Trainer:
         # lands here too — it consumes the updated shards)
         with jax.named_scope("optimizer"):
             if self._zero_compress == "none":
-                return self.optimizer.update(grads, opt_state, params)
+                # the fused optimizer kernel runs once per shard of the
+                # STATE layout (under ZeRO: the dp shard the update is
+                # owed on), so the optimizer is told where the state lives
+                from hetu_tpu.dstates import DistributedStates
+                layouts = jax.tree.map(
+                    lambda ns, p: DistributedStates.from_pspec(ns.spec,
+                                                               p.ndim),
+                    self._sshard["m"], params)
+                return self.optimizer.update(grads, opt_state, params,
+                                             layouts=layouts)
             from hetu_tpu.optim.zero_refresh import quantized_zero_update
             return quantized_zero_update(
                 self.optimizer, grads, opt_state, params, mesh=self.mesh,
@@ -821,7 +879,7 @@ class Trainer:
         replica's axis index (grad_sync.per_replica_keys) so each replica
         draws independent masks — matching the per-row independence of
         the GSPMD lowering."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from hetu_tpu.comm.grad_sync import (ef_specs, per_replica_keys,
                                              quantized_grad_sync)
         from hetu_tpu.obs import numerics as _numerics
@@ -865,7 +923,7 @@ class Trainer:
             out_specs=(P(), P(), P(), especs, P()),
             # the gathered grads ARE replicated over dp but the checker
             # cannot infer that through all-to-all
-            check_rep=False)
+            check_vma=False)
         from hetu_tpu.dstates import suppress_constraints
         with suppress_constraints():
             # the model's activation constraints (strategy.constrain) are

@@ -134,7 +134,8 @@ class GPTAttention(Module):
         else:
             attn = ops.flash_attention(
                 q, k, v, causal=True, segment_ids=segment_ids,
-                use_pallas=None if c.use_flash_attention else False)
+                use_pallas=None if c.use_flash_attention else False,
+                layout=st.act_attn())
         attn = st.constrain(attn, st.act_attn())
         # named so the "dots_attn" remat policy can save the kernel output
         # (mirrors models/llama/model.py)

@@ -76,7 +76,8 @@ class LlamaAttention(Module):
 
         # one fused Pallas pass over q AND k when routed
         # (HETU_TPU_PALLAS; fallback = the seed's two apply_rotary calls)
-        q, k = ops.apply_rotary_qk(q, k, cos, sin, position_ids)
+        q, k = ops.apply_rotary_qk(q, k, cos, sin, position_ids,
+                                   layout=st.act_attn())
 
         use_attn_dropout = (c.attention_dropout > 0.0 and not deterministic
                             and rng is not None)
@@ -103,7 +104,8 @@ class LlamaAttention(Module):
             # use_pallas=None -> auto (Pallas kernel when built & on TPU)
             attn = ops.flash_attention(
                 q, k, v, causal=True, segment_ids=segment_ids,
-                use_pallas=None if c.use_flash_attention else False)
+                use_pallas=None if c.use_flash_attention else False,
+                layout=st.act_attn())
         attn = st.constrain(attn, st.act_attn())
         # named so the "dots_attn" remat policy can SAVE the kernel output:
         # recomputing flash attention in the bwd is the single most
@@ -135,7 +137,8 @@ class LlamaMLP(Module):
         st = self.strategy
         gu = jnp.einsum("bsh,hci->bsci", x, params["w_gate_up"].astype(x.dtype))
         gu = st.constrain(gu, st.act_gate_up())
-        hidden = ops.swiglu(gu[:, :, 0, :], gu[:, :, 1, :])
+        hidden = ops.swiglu(gu[:, :, 0, :], gu[:, :, 1, :],
+                            layout=st.act_inner())
         return self.down_proj(params["down_proj"], hidden)
 
 

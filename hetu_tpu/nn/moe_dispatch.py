@@ -226,7 +226,7 @@ def explicit_forward(layer, params, xg, ig, capacity: int,
             out_full, plan)
         return yg, aux, rst
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     rst_spec = ({"load": P(gs, None), "load_max": P(gs),
                  "entropy": P(gs), "dropped": P(gs), "drop_frac": P(gs)}
                 if active else {})
@@ -237,7 +237,7 @@ def explicit_forward(layer, params, xg, ig, capacity: int,
         out_specs=(P(gs, None, None), P(gs), rst_spec),
         # routing (hence yg/aux) is replicated over ep by construction,
         # but the checker cannot see that through the a2a
-        check_rep=False)
+        check_vma=False)
     yg, aux, rst = fn(xg, ig, params["router"],
                       params["w_gate_up"], params["w_down"])
     if rst:

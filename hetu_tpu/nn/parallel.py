@@ -136,7 +136,9 @@ class ParallelRMSNorm(Module):
         returns (norm(x + h), x + h).  Routes to the Pallas fused_norm
         kernel under HETU_TPU_PALLAS; the fallback is exactly the seed
         composition `s = x + h; forward(s)`, same constrain."""
-        y, s = ops.residual_rms_norm(x, h, params["weight"], self.eps)
+        y, s = ops.residual_rms_norm(
+            x, h, params["weight"], self.eps,
+            layout=self.strategy.act_hidden() if x.ndim == 3 else None)
         if x.ndim == 3:
             y = self.strategy.constrain(y, self.strategy.act_hidden())
         return y, s
@@ -165,7 +167,8 @@ class ParallelLayerNorm(Module):
         ParallelRMSNorm.residual."""
         y, s = ops.residual_layer_norm(
             x, h, params["weight"],
-            params["bias"] if self.use_bias else None, self.eps)
+            params["bias"] if self.use_bias else None, self.eps,
+            layout=self.strategy.act_hidden() if x.ndim == 3 else None)
         if x.ndim == 3:
             y = self.strategy.constrain(y, self.strategy.act_hidden())
         return y, s

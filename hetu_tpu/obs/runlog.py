@@ -13,7 +13,10 @@ Record shape (all kinds):
 Kind fields:
     step          step, step_time_s, loss, tokens_per_s, device_mem_bytes,
                   plan (fingerprint of the dispatched plan)
-    compile       name, plan, compile_s, flops, estimated_mfu
+    compile       name, plan, compile_s, flops, estimated_mfu,
+                  kernel_routes (ops/pallas.record_routes: per kernel, how
+                  many dispatches took Pallas / XLA while the plan was
+                  traced, and why)
     switch        from_id, to_id, wall_s, moved_bytes, total_bytes
     elastic_epoch epoch, alive, strategy
     fault         fault (ckpt_corrupt | step_exception |
@@ -56,7 +59,8 @@ Kind fields:
                   evict-and-requeue;
                   reshard: tier, strategy, pause_s (+ kv_repage=true
                   when HETU_TPU_SERVE_KV_REPAGE migrated the pool);
-                  report: requests, tokens, elapsed_s, tokens_per_s;
+                  report: requests, tokens, elapsed_s, tokens_per_s,
+                  kernel_routes (as on a compile record);
                   failover: requeued, exhausted, queue_depth — one per
                   engine fail_over (chaos engine_kill);
                   retry: req, slot, attempt, tokens_discarded — a

@@ -28,21 +28,23 @@ def sigmoid(x):
     return jax.nn.sigmoid(x)
 
 
-def swiglu(gate, up, use_pallas=None):
+def swiglu(gate, up, use_pallas=None, layout=None):
     """SwiGLU combine (reference: ops/SwiGLU.cc): silu(gate) * up.
 
     Routes to the fused Pallas kernel (ops/pallas/swiglu — one pass,
     custom-vjp backward) under HETU_TPU_PALLAS; the jnp composition is
-    the exact fallback."""
+    the exact fallback.  `layout` (a DistributedStates) declares how
+    gate/up/result lie over the mesh: under a multi-device mesh the
+    kernel runs once per shard of it (ops/pallas.per_shard)."""
+    from hetu_tpu.ops import pallas as _pl
+    from hetu_tpu.ops.pallas import swiglu as _sw
+    layouts = None if layout is None else (layout, layout)
     if use_pallas is None:
-        from hetu_tpu.ops.pallas import resolve_route
-        from hetu_tpu.ops.pallas import swiglu as _sw
-        use_pallas = resolve_route(
-            "swiglu", _sw.compatible(gate.shape, up.shape))
+        use_pallas = _pl.resolve_route("swiglu", _sw.check_shapes,
+                                       gate.shape, up.shape, layouts=layouts)
     if use_pallas:
-        from hetu_tpu.ops.pallas.swiglu import fused_swiglu
         with jax.named_scope("pallas_swiglu"):
-            return fused_swiglu(gate, up)
+            return _pl.per_shard(_sw.fused_swiglu, layouts, layout)(gate, up)
     return silu(gate) * up
 
 

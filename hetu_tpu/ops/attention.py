@@ -15,6 +15,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from hetu_tpu.dstates import DistributedStates
+
 
 def attention(q, k, v, *, causal: bool = True, bias: Optional[jnp.ndarray] = None,
               segment_ids: Optional[jnp.ndarray] = None, softmax_scale: Optional[float] = None,
@@ -50,47 +52,50 @@ def attention(q, k, v, *, causal: bool = True, bias: Optional[jnp.ndarray] = Non
     return out.astype(orig_dtype)
 
 
-try:
-    from hetu_tpu.ops.pallas.flash_attention import flash_attention as _pallas_fa
-except ImportError:  # pallas kernel not built yet / not importable on CPU
-    _pallas_fa = None
-
-
-def _pallas_compatible(q, k) -> bool:
-    """The auto path's shape gate.  Delegates to the kernel module's own
-    `compatible` — which is implemented AS the entry validation
-    (flash_attention.check_default_shapes), so the gate's verdict and
-    what the kernel actually accepts can never silently diverge (the
-    drift test in tests/test_pallas_kernels.py pins the contract)."""
-    from hetu_tpu.ops.pallas.flash_attention import compatible
-    return compatible(q.shape, k.shape)
-
-
 def flash_attention(q, k, v, *, causal: bool = True,
                     segment_ids: Optional[jnp.ndarray] = None,
                     softmax_scale: Optional[float] = None,
-                    use_pallas: Optional[bool] = None):
+                    use_pallas: Optional[bool] = None, layout=None):
     """Fused attention entry point. Routes to the Pallas TPU kernel when
-    running on TPU with compatible shapes; XLA composition otherwise."""
+    running on TPU with compatible shapes; XLA composition otherwise.
+
+    `layout` (a DistributedStates) declares how q/k/v [b, s, heads, hd]
+    and the result lie over the mesh: under a multi-device mesh the
+    kernel runs once per shard of it — per batch row and per (kv) head.
+    A layout that shards the SEQUENCE is ring attention's business
+    (parallel/ring_attention) and is refused here."""
+    from hetu_tpu.ops import pallas as _pl
+    from hetu_tpu.ops.pallas import flash_attention as _fa
+    layouts = None
+    if layout is not None:
+        tokens = DistributedStates(layout.spec[:2])
+        layouts = ((layout,) * 3
+                   + (() if segment_ids is None else (tokens,)))
     if use_pallas is None:
         # HETU_TPU_PALLAS=1/0 force-routes; "auto" keeps the shape gate
         # (reference: the HETU_PARALLEL_ATTN env family, GetExecEnvs);
-        # HETU_TPU_PALLAS_KERNELS can exclude just this kernel
-        from hetu_tpu.ops.pallas import kernel_enabled
-        forced = kernel_enabled("flash")
-        if forced is not None:
-            use_pallas = forced
-        else:
-            use_pallas = (jax.default_backend() == "tpu"
-                          and _pallas_fa is not None
-                          and _pallas_compatible(q, k))
+        # HETU_TPU_PALLAS_KERNELS can exclude just this kernel.  The gate
+        # is the kernel module's own entry validation, so the two can
+        # never silently diverge (the drift test in
+        # tests/test_pallas_kernels.py pins the contract)
+        use_pallas = _pl.resolve_route(
+            "flash", _fa.check_shapes, q.shape, k.shape,
+            layouts=None if layouts is None else layouts[:2])
     if use_pallas:
-        if _pallas_fa is None:
-            raise RuntimeError("use_pallas=True but the Pallas kernel is unavailable")
+        if layout is not None and layout.spec[1]:
+            raise ValueError(
+                f"flash_attention: layout {layout} shards the sequence dim "
+                f"over {layout.spec[1]}; attention is not per-shard there "
+                f"(use parallel.ring_attention)")
+
+        def kernel(q, k, v, *seg):
+            return _fa.flash_attention(
+                q, k, v, causal=causal, segment_ids=seg[0] if seg else None,
+                softmax_scale=softmax_scale)
         # named so obs.hlo_profile attributes the custom-call to its
         # kernel group (layer_table `.../pallas_flash_attention` rows)
         with jax.named_scope("pallas_flash_attention"):
-            return _pallas_fa(q, k, v, causal=causal, segment_ids=segment_ids,
-                              softmax_scale=softmax_scale)
+            return _pl.per_shard(kernel, layouts, layout)(
+                q, k, v, *(() if segment_ids is None else (segment_ids,)))
     return attention(q, k, v, causal=causal, segment_ids=segment_ids,
                      softmax_scale=softmax_scale)

@@ -35,36 +35,47 @@ def layer_norm(x, weight, bias, eps: float = 1e-5):
 
 
 def residual_rms_norm(x, h, weight, eps: float = 1e-5,
-                      use_pallas: Optional[bool] = None):
+                      use_pallas: Optional[bool] = None, layout=None):
     """Fused residual-add + RMSNorm: returns (rms_norm(x + h) * weight,
     x + h) — the pre-norm block's pair, one Pallas pass when routed
     (HETU_TPU_PALLAS auto/1/0 + the `norm` kernel gate), the exact seed
-    composition otherwise."""
+    composition otherwise.  `layout` (a DistributedStates) declares how
+    x/h and both results lie over the mesh (the gain is replicated):
+    under a multi-device mesh the kernel runs once per shard of it."""
+    from hetu_tpu.ops import pallas as _pl
+    from hetu_tpu.ops.pallas import fused_norm as _fn
+    layouts = None if layout is None else (layout, layout, None)
     if use_pallas is None:
-        from hetu_tpu.ops.pallas import resolve_route
-        from hetu_tpu.ops.pallas import fused_norm as _fn
-        use_pallas = resolve_route(
-            "norm", _fn.compatible(x.shape, h.shape, weight.shape))
+        use_pallas = _pl.resolve_route(
+            "norm", _fn.check_shapes, x.shape, h.shape, weight.shape,
+            layouts=layouts)
     if use_pallas:
-        from hetu_tpu.ops.pallas.fused_norm import fused_residual_rmsnorm
         with jax.named_scope("pallas_residual_rmsnorm"):
-            return fused_residual_rmsnorm(x, h, weight, eps)
+            return _pl.per_shard(
+                lambda x, h, w: _fn.fused_residual_rmsnorm(x, h, w, eps),
+                layouts, (layout, layout))(x, h, weight)
     s = x + h
     return rms_norm(s, weight, eps), s
 
 
 def residual_layer_norm(x, h, weight, bias, eps: float = 1e-5,
-                        use_pallas: Optional[bool] = None):
+                        use_pallas: Optional[bool] = None, layout=None):
     """Fused residual-add + LayerNorm: returns (layer_norm(x + h), x + h).
     Same routing contract as `residual_rms_norm`."""
+    from hetu_tpu.ops import pallas as _pl
+    from hetu_tpu.ops.pallas import fused_norm as _fn
+    operands = (x, h, weight) + (() if bias is None else (bias,))
+    layouts = (None if layout is None
+               else (layout, layout) + (None,) * (len(operands) - 2))
     if use_pallas is None:
-        from hetu_tpu.ops.pallas import resolve_route
-        from hetu_tpu.ops.pallas import fused_norm as _fn
-        use_pallas = resolve_route(
-            "norm", _fn.compatible(x.shape, h.shape, weight.shape))
+        use_pallas = _pl.resolve_route(
+            "norm", _fn.check_shapes, x.shape, h.shape, weight.shape,
+            layouts=None if layout is None else layouts[:3])
     if use_pallas:
-        from hetu_tpu.ops.pallas.fused_norm import fused_residual_layernorm
         with jax.named_scope("pallas_residual_layernorm"):
-            return fused_residual_layernorm(x, h, weight, bias, eps)
+            return _pl.per_shard(
+                lambda x, h, w, *b: _fn.fused_residual_layernorm(
+                    x, h, w, b[0] if b else None, eps),
+                layouts, (layout, layout))(*operands)
     s = x + h
     return layer_norm(s, weight, bias, eps), s

@@ -33,11 +33,17 @@ fused bytes too.
 Routing: `HETU_TPU_PALLAS` (auto/1/0) gates the WHOLE layer the way it
 always gated flash attention; `HETU_TPU_PALLAS_KERNELS` restricts which
 kernels participate (comma list / all / none) so one kernel can be
-bisected out without losing the rest.
+bisected out without losing the rest.  `resolve_route` is the one rule
+every dispatcher asks; under a multi-device mesh the kernel runs once per
+shard of the layouts the caller declares (`per_shard`), and every
+decision taken while a program is traced is recorded with its reason
+(`record_routes` -> `Trainer.kernel_routes`, `ServingEngine.kernel_routes`).
 """
 from __future__ import annotations
 
-from typing import FrozenSet, Optional
+import contextlib
+import threading
+from typing import FrozenSet, Optional, Tuple
 
 #: every routable kernel name (the HETU_TPU_PALLAS_KERNELS vocabulary)
 KERNEL_NAMES = ("flash", "norm", "swiglu", "rotary", "quant", "paged_attn",
@@ -49,6 +55,20 @@ def _interpret() -> bool:
     definition shared by every kernel module."""
     import jax
     return jax.default_backend() == "cpu"
+
+
+def fit_sublane_block(n: int, cap: int) -> int:
+    """The largest block of an `n`-long second-minor axis that divides
+    `n`, stays within `cap` and that the TPU lowering accepts: a multiple
+    of 8 (the sublane tile), or `n` itself.  0 when there is none — the
+    shape gates turn that into a ValueError, so the XLA path takes the
+    shape instead of a kernel the compiler refuses."""
+    if n <= cap:
+        return n
+    r = cap - cap % 8
+    while r and n % r:
+        r -= 8
+    return r
 
 
 def _selected_kernels() -> FrozenSet[str]:
@@ -86,12 +106,146 @@ def kernel_enabled(name: str) -> Optional[bool]:
     return None
 
 
-def resolve_route(name: str, compatible: bool) -> bool:
-    """The one auto-routing rule (mirrors ops.attention.flash_attention):
-    forced flags win; auto takes the kernel only on a TPU backend with a
-    passing shape gate."""
+# -- where a Mosaic call can lower ---------------------------------------
+# The TPU lowering refuses a Mosaic call inside a program GSPMD partitions
+# ("Mosaic kernels cannot be automatically partitioned"): it needs a
+# one-device program or a shard_map region with EVERY mesh axis manual.
+# Under a multi-device mesh the dispatchers therefore run the kernel once
+# per shard (`per_shard`), on the layouts their caller declares.
+
+def _open_axes():
+    """(mesh, its axes no shard_map has made manual yet) where we are
+    tracing — (None, ()) in a one-device program and inside an all-manual
+    region, where a Mosaic call lowers as it stands."""
+    import jax
+    from hetu_tpu.core.mesh import current_mesh
+    am = jax.sharding.get_abstract_mesh()
+    if am.manual_axes:          # inside a shard_map: nest over the rest
+        rest = tuple(a for a in am.axis_names if a not in am.manual_axes)
+        return (am, rest) if rest else (None, ())
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return None, ()
+    return mesh, tuple(mesh.axis_names)
+
+
+def _shard_shape(shape, layout, mesh, axes) -> Tuple[int, ...]:
+    """One shard's shape of a `shape` array laid out as `layout` (a
+    DistributedStates; None = replicated) over the open `axes`."""
+    if layout is None:
+        return shape
+    out = []
+    for n, dim_axes in zip(shape, layout.spec):
+        ways = 1
+        for a in dim_axes:
+            if a in axes:
+                ways *= int(mesh.shape[a])
+        if n % ways:
+            raise ValueError(f"a dim of {n} does not split {ways} ways "
+                             f"over mesh axes {dim_axes}")
+        out.append(n // ways)
+    return tuple(out)
+
+
+def per_shard(fn, in_layouts, out_layouts):
+    """`fn` — a function of arrays that launches a Mosaic kernel — run
+    once per shard under a multi-device mesh: a shard_map over every open
+    mesh axis, with the declared layouts (a DistributedStates per operand
+    and per result, None = replicated) as its specs.  The kernels are
+    per-token, per-head or elementwise over the sharded dims, so the
+    shards need no exchange; the cotangent of a replicated operand (a
+    norm gain) is summed over the mesh by shard_map's transpose.
+
+    `fn` itself in a one-device program and in an all-manual region —
+    and where the caller declared no layouts (`in_layouts=None`): only a
+    forced flag routes a kernel there, and the TPU lowering then refuses
+    it in its own words."""
+    mesh, axes = _open_axes()
+    if not axes or in_layouts is None:
+        return fn
+    import jax
+    from jax.sharding import PartitionSpec as P
+    from hetu_tpu.dstates import suppress_constraints
+
+    def spec(layout):
+        if isinstance(layout, tuple):
+            return tuple(spec(l) for l in layout)
+        return P() if layout is None else layout.partition_spec()
+
+    def local(*args):
+        with suppress_constraints():     # vacuous and illegal when manual
+            return fn(*args)
+    return jax.shard_map(local, mesh=mesh, in_specs=spec(tuple(in_layouts)),
+                         out_specs=spec(out_layouts),
+                         axis_names=frozenset(axes), check_vma=False)
+
+
+# -- the routes a traced program took --------------------------------------
+
+_routes = threading.local()
+
+
+@contextlib.contextmanager
+def record_routes(into: Optional[dict] = None):
+    """Collect every `resolve_route` decision this thread takes inside the
+    block — i.e. while a program is traced — as
+    {kernel: {"pallas": n, "xla": n, "why": {reason: n}}}.  The Trainer
+    and the ServingEngine keep this as `kernel_routes` and put it on their
+    compile events: which kernels a program really runs is a fact of the
+    run, not something to re-derive."""
+    log = {} if into is None else into
+    prev = getattr(_routes, "log", None)
+    _routes.log = log
+    try:
+        yield log
+    finally:
+        _routes.log = prev
+
+
+def _note_route(name: str, routed: bool, why: str):
+    log = getattr(_routes, "log", None)
+    if log is None:
+        return
+    rec = log.setdefault(name, {"pallas": 0, "xla": 0, "why": {}})
+    rec["pallas" if routed else "xla"] += 1
+    rec["why"][why] = rec["why"].get(why, 0) + 1
+
+
+def resolve_route(name: str, check, *shapes, layouts=None, **kw) -> bool:
+    """The one routing rule.  `check(*shapes, **kw)` is the kernel
+    module's own entry validation (it raises ValueError with the reason);
+    `shapes` are the operands' GLOBAL shapes and `layouts` how the caller
+    lays them out over the mesh (one DistributedStates or None =
+    replicated per shape; `layouts=None` = not declared) — the gate is
+    asked about ONE SHARD's shapes, which is what the kernel will see.
+
+    Forced flags win.  Auto takes the kernel on a TPU backend when the
+    gate passes and the call can lower: in a one-device program, an
+    all-manual region, or — through `per_shard` — under a multi-device
+    mesh whose caller declared the layouts.  A kernel that then fails to
+    compile is an error, never a route."""
     en = kernel_enabled(name)
     if en is not None:
-        return en
-    import jax
-    return jax.default_backend() == "tpu" and compatible
+        routed = en
+        why = ("forced on by HETU_TPU_PALLAS=1" if en else
+               "switched off by HETU_TPU_PALLAS / HETU_TPU_PALLAS_KERNELS")
+    else:
+        import jax
+        mesh, axes = _open_axes()
+        routed = False
+        if jax.default_backend() != "tpu":
+            why = "not a TPU backend"
+        elif axes and layouts is None:
+            why = ("multi-device mesh and the caller declares no layout: "
+                   "a Mosaic call has to run per shard")
+        else:
+            try:
+                if axes:
+                    shapes = tuple(_shard_shape(s, l, mesh, axes)
+                                   for s, l in zip(shapes, layouts))
+                check(*shapes, **kw)
+                routed, why = True, "shape gate passes"
+            except ValueError as e:
+                why = f"shape gate: {e}"
+    _note_route(name, routed, why)
+    return routed

@@ -17,7 +17,8 @@ wd*p).  b1/b2/eps/wd are static (they pick the compiled kernel, like
 every other hyperparameter-shaped knob).
 
 Shape contract (drift-tested against `compatible`): the four leaf
-buffers share one shape whose element count is lane-aligned (% 128);
+buffers share one shape whose element count is lane-aligned (% 128) and
+whose lane rows block legally (<= 256 rows, or a multiple-of-8 divisor);
 ragged leaves (biases, norm gains) keep the XLA path."""
 from __future__ import annotations
 
@@ -29,13 +30,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from hetu_tpu.ops.pallas import _interpret
+from hetu_tpu.ops.pallas import _interpret, fit_sublane_block
 
 #: leaf rows (of 128 lanes) handled per grid step
 _ROWS = 256
 
 
-def _check_shapes(p_shape, g_shape, m_shape, v_shape) -> int:
+def check_shapes(p_shape, g_shape, m_shape, v_shape) -> int:
     shapes = (tuple(p_shape), tuple(g_shape), tuple(m_shape),
               tuple(v_shape))
     if len(set(shapes)) != 1:
@@ -46,6 +47,10 @@ def _check_shapes(p_shape, g_shape, m_shape, v_shape) -> int:
     if n == 0 or n % 128:
         raise ValueError(f"leaf of {n} elements is not lane-aligned "
                          f"(% 128); the XLA update handles it")
+    if not fit_sublane_block(n // 128, _ROWS):
+        raise ValueError(f"leaf of {n // 128} lane rows has no "
+                         f"sublane-aligned (% 8) row block; the XLA update "
+                         f"handles it")
     return n
 
 
@@ -54,17 +59,10 @@ def compatible(p_shape, g_shape=None, m_shape=None, v_shape=None) -> bool:
     m_shape = p_shape if m_shape is None else m_shape
     v_shape = p_shape if v_shape is None else v_shape
     try:
-        _check_shapes(p_shape, g_shape, m_shape, v_shape)
+        check_shapes(p_shape, g_shape, m_shape, v_shape)
         return True
     except ValueError:
         return False
-
-
-def _fit_rows(nb: int) -> int:
-    r = min(nb, _ROWS)
-    while nb % r:
-        r -= 1
-    return r
 
 
 def _adam_kernel(p_ref, g_ref, m_ref, v_ref, sc_ref,
@@ -90,9 +88,9 @@ def adam_update(p, g, m, v, lr, c1, c2, *, b1: float, b2: float,
     """One leaf's fused AdamW step -> (new_p, new_m, new_v).  lr/c1/c2
     are traced f32 scalars (step-dependent); b1/b2/eps/weight_decay are
     static.  Raises ValueError on shapes outside `compatible`."""
-    n = _check_shapes(p.shape, g.shape, m.shape, v.shape)
+    n = check_shapes(p.shape, g.shape, m.shape, v.shape)
     nb = n // 128
-    rows = _fit_rows(nb)
+    rows = fit_sublane_block(nb, _ROWS)
     sc = jnp.stack([jnp.asarray(lr, jnp.float32),
                     jnp.asarray(c1, jnp.float32),
                     jnp.asarray(c2, jnp.float32)]).reshape(1, 3)

@@ -40,12 +40,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from hetu_tpu.ops.pallas import _interpret
+
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    # CPU (the virtual test mesh) runs kernels in interpret mode
-    return jax.default_backend() == "cpu"
 
 
 # swept on v5e at b8/s2048/h12/d128 (tools_bench_attn.py, 2026-07): f+b
@@ -164,13 +161,19 @@ def check_default_shapes(sq: int, sk: int, d: int,
     return bq, bk
 
 
+def check_shapes(q_shape, k_shape):
+    """`check_default_shapes` of [b, s, h, d] operand shapes — the gate
+    `ops.attention.flash_attention` hands to `resolve_route`."""
+    return check_default_shapes(q_shape[1], k_shape[1], q_shape[-1])
+
+
 def compatible(q_shape, k_shape) -> bool:
     """Will the public entry accept these [b, s, h, d] shapes under the
     DEFAULT block geometry?  Implemented AS the entry validation so the
-    auto-route gate (`ops.attention._pallas_compatible`) can never drift
+    auto-route gate (`ops.attention.flash_attention`) can never drift
     from what the kernel accepts."""
     try:
-        check_default_shapes(q_shape[1], k_shape[1], q_shape[-1])
+        check_shapes(q_shape, k_shape)
         return True
     except ValueError:
         return False

@@ -18,7 +18,7 @@ The backward is a custom_vjp running a second fused kernel: it receives
 cotangents for y AND s (the residual stream is consumed downstream too),
 recomputes the row statistics from the saved s (cheaper than saving
 inv/mean: one fused read instead of extra HBM residents), and emits
-dx (= dh) plus per-block partial dw/db rows that are summed outside.
+dx (= dh) plus dw/db folded onto 8 sublane rows that are summed outside.
 
 Shape contract (`compatible` mirrors the entry validation EXACTLY — the
 drift test pins them): hidden (the normed axis) must be lane-aligned
@@ -35,13 +35,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from hetu_tpu.ops.pallas import _interpret
+from hetu_tpu.ops.pallas import _interpret, fit_sublane_block
 
 #: per-buffer VMEM budget (bytes, f32) used to pick the row-block size
 _VMEM_ROW_BUDGET = 512 * 1024
 
 
-def _check_shapes(x_shape, h_shape, w_shape) -> Tuple[int, int]:
+def check_shapes(x_shape, h_shape, w_shape) -> Tuple[int, int]:
     """Entry validation — raises ValueError exactly when `compatible`
     says False (the drift-test contract).  Returns (tokens, hidden)."""
     if tuple(x_shape) != tuple(h_shape):
@@ -70,7 +70,7 @@ def compatible(x_shape, h_shape=None, w_shape=None) -> bool:
     h_shape = x_shape if h_shape is None else h_shape
     w_shape = (x_shape[-1],) if w_shape is None else w_shape
     try:
-        _check_shapes(x_shape, h_shape, w_shape)
+        check_shapes(x_shape, h_shape, w_shape)
         return True
     except ValueError:
         return False
@@ -78,12 +78,10 @@ def compatible(x_shape, h_shape=None, w_shape=None) -> bool:
 
 def _fit_rows(tokens: int, hidden: int) -> int:
     """Largest divisor of `tokens` that is a multiple of 8 and keeps one
-    f32 [rows, hidden] buffer near the VMEM budget."""
-    cap = max(8, _VMEM_ROW_BUDGET // max(hidden * 4, 1))
-    r = min(tokens, cap - cap % 8 or 8)
-    while tokens % r or r % 8:
-        r -= 1
-    return max(r, 8)
+    f32 [rows, hidden] buffer near the VMEM budget (never 0: the gate
+    admits only token counts that divide by 8)."""
+    return fit_sublane_block(
+        tokens, max(8, _VMEM_ROW_BUDGET // max(hidden * 4, 1)))
 
 
 # ---------------------------------------------------------------------------
@@ -129,10 +127,21 @@ def _bwd_kernel(s_ref, w_ref, dy_ref, dr_ref, dx_ref, dw_ref, db_ref, *,
                     - xhat * jnp.mean(g * xhat, axis=-1, keepdims=True))
     ds = ds + dr_ref[...].astype(jnp.float32)
     dx_ref[...] = ds.astype(dx_ref.dtype)
-    dw_ref[...] = jnp.sum(dy * xhat, axis=0, keepdims=True)
-    # written even for the bias-free RMS variant (discarded outside):
+
+    # dw/db accumulate across the (sequential) grid in ONE resident
+    # (8, hidden) block: the TPU lowering refuses a (1, hidden) block on
+    # an (n, hidden) array, and folding rows onto the 8 sublanes is pure
+    # VPU adds — the 8 -> 1 reduce happens once, outside the kernel.
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+        db_ref[...] = jnp.zeros_like(db_ref)
+
+    rows, hidden = dy.shape
+    dw_ref[...] += (dy * xhat).reshape(rows // 8, 8, hidden).sum(axis=0)
+    # accumulated even for the bias-free RMS variant (discarded outside):
     # an output block a kernel MIGHT not write is undefined on TPU
-    db_ref[...] = jnp.sum(dy, axis=0, keepdims=True)
+    db_ref[...] += dy.reshape(rows // 8, 8, hidden).sum(axis=0)
 
 
 def _call_fwd(x2, h2, w2, b2, *, eps, kind, has_bias, rows, hidden):
@@ -159,15 +168,15 @@ def _call_bwd(s2, w2, dy2, dr2, *, eps, kind, rows, hidden):
     kern = functools.partial(_bwd_kernel, eps=eps, kind=kind)
     row_spec = pl.BlockSpec((rows, hidden), lambda i: (i, 0))
     w_spec = pl.BlockSpec((1, hidden), lambda i: (0, 0))
-    part_spec = pl.BlockSpec((1, hidden), lambda i: (i, 0))
+    part_spec = pl.BlockSpec((8, hidden), lambda i: (0, 0))
     dx, dw_parts, db_parts = pl.pallas_call(
         kern,
         grid=(n,),
         in_specs=[row_spec, w_spec, row_spec, row_spec],
         out_specs=[row_spec, part_spec, part_spec],
         out_shape=[jax.ShapeDtypeStruct(s2.shape, s2.dtype),
-                   jax.ShapeDtypeStruct((n, hidden), jnp.float32),
-                   jax.ShapeDtypeStruct((n, hidden), jnp.float32)],
+                   jax.ShapeDtypeStruct((8, hidden), jnp.float32),
+                   jax.ShapeDtypeStruct((8, hidden), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
@@ -183,7 +192,7 @@ def _fused(x, h, weight, bias, *, eps, kind):
     shape = x.shape
     hidden = shape[-1]
     has_bias = bias is not None
-    tokens, hidden = _check_shapes(shape, h.shape, weight.shape)
+    tokens, hidden = check_shapes(shape, h.shape, weight.shape)
     rows = _fit_rows(tokens, hidden)
     x2 = x.reshape(tokens, hidden)
     h2 = h.reshape(tokens, hidden)

@@ -87,7 +87,7 @@ def _check_pool(q_heads_hd, pool_shape, table_shape, pos_shape, S, *,
     return P, ps, n_kv
 
 
-def _check_shapes(q_shape, pool_shape, table_shape, pos_shape, *,
+def check_shapes(q_shape, pool_shape, table_shape, pos_shape, *,
                   quant: str = "none"
                   ) -> Tuple[int, int, int, int, int, int]:
     if len(q_shape) != 3 or len(pool_shape) != 4:
@@ -99,7 +99,7 @@ def _check_shapes(q_shape, pool_shape, table_shape, pos_shape, *,
     return S, nq, hd, P, ps, n_kv
 
 
-def _check_shapes_verify(q_shape, pool_shape, table_shape, pos_shape, *,
+def check_shapes_verify(q_shape, pool_shape, table_shape, pos_shape, *,
                          quant: str = "none"
                          ) -> Tuple[int, int, int, int, int, int, int]:
     if len(q_shape) != 4 or len(pool_shape) != 4:
@@ -117,7 +117,7 @@ def _check_shapes_verify(q_shape, pool_shape, table_shape, pos_shape, *,
 def compatible(q_shape, pool_shape, table_shape, pos_shape, *,
                quant: str = "none") -> bool:
     try:
-        _check_shapes(q_shape, pool_shape, table_shape, pos_shape,
+        check_shapes(q_shape, pool_shape, table_shape, pos_shape,
                       quant=quant)
         return True
     except ValueError:
@@ -127,7 +127,7 @@ def compatible(q_shape, pool_shape, table_shape, pos_shape, *,
 def verify_compatible(q_shape, pool_shape, table_shape, pos_shape, *,
                       quant: str = "none") -> bool:
     try:
-        _check_shapes_verify(q_shape, pool_shape, table_shape, pos_shape,
+        check_shapes_verify(q_shape, pool_shape, table_shape, pos_shape,
                              quant=quant)
         return True
     except ValueError:
@@ -296,7 +296,7 @@ def paged_attention(q, k_pool, v_pool, table, positions, *,
     `compatible` (the dense-gather fallback in models/generation
     handles those)."""
     quant = _resolve_quant(quant, k_scale, v_scale)
-    S, nq, hd, P, ps, n_kv = _check_shapes(
+    S, nq, hd, P, ps, n_kv = check_shapes(
         q.shape, k_pool.shape, table.shape, positions.shape, quant=quant)
     if quant != "none" and tuple(k_scale.shape) != (P, ps, n_kv):
         raise ValueError(f"scales {k_scale.shape} must be "
@@ -353,7 +353,7 @@ def paged_verify(q, k_pool, v_pool, table, positions, *,
     shapes outside `verify_compatible` (the gather verify program in
     models/generation handles those)."""
     quant = _resolve_quant(quant, k_scale, v_scale)
-    S, C, nq, hd, P, ps, n_kv = _check_shapes_verify(
+    S, C, nq, hd, P, ps, n_kv = check_shapes_verify(
         q.shape, k_pool.shape, table.shape, positions.shape, quant=quant)
     if quant != "none" and tuple(k_scale.shape) != (P, ps, n_kv):
         raise ValueError(f"scales {k_scale.shape} must be "

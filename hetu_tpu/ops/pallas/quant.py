@@ -30,13 +30,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from hetu_tpu.ops.pallas import _interpret
+from hetu_tpu.ops.pallas import _interpret, fit_sublane_block
 
 #: quantize blocks (rows) handled per grid step
 _ROWS = 256
 
 
-def _check_shapes(n: int, block_size: int, bits: int = 8) -> int:
+def check_shapes(n: int, block_size: int, bits: int = 8) -> int:
     if bits not in (8, 4):
         raise ValueError(f"bits must be 8 or 4, got {bits}")
     if block_size % 128:
@@ -45,22 +45,19 @@ def _check_shapes(n: int, block_size: int, bits: int = 8) -> int:
     if n % block_size:
         raise ValueError(f"buffer of {n} elements is not a multiple of "
                          f"block_size={block_size}; pad first")
+    if not fit_sublane_block(n // block_size, _ROWS):
+        raise ValueError(f"{n // block_size} blocks have no "
+                         f"sublane-aligned (% 8) row block; the XLA "
+                         f"fallback handles it")
     return n // block_size
 
 
 def compatible(n: int, block_size: int, bits: int = 8) -> bool:
     try:
-        _check_shapes(n, block_size, bits)
+        check_shapes(n, block_size, bits)
         return True
     except ValueError:
         return False
-
-
-def _fit_rows(nb: int) -> int:
-    r = min(nb, _ROWS)
-    while nb % r:
-        r -= 1
-    return r
 
 
 def _quant_kernel(x_ref, q_ref, s_ref, *, qmax):
@@ -81,9 +78,9 @@ def quantize_blockwise_pallas(x, block_size: int, *, bits: int = 8
     fused pass (deterministic rounding only).  Raises ValueError on
     shapes outside `compatible`."""
     flat = x.reshape(-1).astype(jnp.float32)
-    nb = _check_shapes(flat.shape[0], block_size, bits)
+    nb = check_shapes(flat.shape[0], block_size, bits)
     qmax = 127.0 if bits == 8 else 7.0
-    rows = _fit_rows(nb)
+    rows = fit_sublane_block(nb, _ROWS)
     blk = pl.BlockSpec((rows, block_size), lambda i: (i, 0))
     s_blk = pl.BlockSpec((rows, 1), lambda i: (i, 0))
     q, s = pl.pallas_call(
@@ -104,8 +101,8 @@ def dequantize_blockwise_pallas(q, scale) -> jnp.ndarray:
     """(q int8 [nb, bs], scales f32 [nb]) -> flat f32 [nb*bs] in one
     fused pass.  Raises ValueError on shapes outside `compatible`."""
     nb, bs = q.shape
-    _check_shapes(nb * bs, bs)
-    rows = _fit_rows(nb)
+    check_shapes(nb * bs, bs)
+    rows = fit_sublane_block(nb, _ROWS)
     blk = pl.BlockSpec((rows, bs), lambda i: (i, 0))
     s_blk = pl.BlockSpec((rows, 1), lambda i: (i, 0))
     y = pl.pallas_call(

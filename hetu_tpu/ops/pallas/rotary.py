@@ -13,8 +13,11 @@ per (batch, position) as [b, s, hd//2] (the dispatcher in `ops.rotary`
 does the position_ids lookup — one tiny gather feeding one fused pass).
 
 Shape contract (drift-tested against `compatible`): hd must be even and
-lane-aligned (% 128); b/s/heads are free (s is row-blocked to a VMEM
-budget)."""
+lane-aligned (% 128); b/s/heads are free.  s is row-blocked to a VMEM
+budget in multiples of 8 (the sublane tile the TPU lowering wants of a
+second-minor block dim); where no such block divides s the last one
+overhangs, which is safe here because every row is rotated on its own:
+the overhang's reads are never used and its writes are dropped."""
 from __future__ import annotations
 
 import functools
@@ -25,12 +28,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from hetu_tpu.ops.pallas import _interpret
+from hetu_tpu.ops.pallas import _interpret, fit_sublane_block
 
 _VMEM_SEQ_BUDGET = 512 * 1024
 
 
-def _check_shapes(q_shape, k_shape) -> Tuple[int, int, int, int, int]:
+def check_shapes(q_shape, k_shape) -> Tuple[int, int, int, int, int]:
     if len(q_shape) != 4 or len(k_shape) != 4:
         raise ValueError(f"expected [b, s, heads, hd], got {q_shape} / "
                          f"{k_shape}")
@@ -49,19 +52,19 @@ def _check_shapes(q_shape, k_shape) -> Tuple[int, int, int, int, int]:
 
 def compatible(q_shape, k_shape) -> bool:
     try:
-        _check_shapes(q_shape, k_shape)
+        check_shapes(q_shape, k_shape)
         return True
     except ValueError:
         return False
 
 
 def _fit_seq(s: int, width: int) -> int:
-    """Largest divisor of s keeping one f32 [S, width] buffer in budget."""
-    cap = max(1, _VMEM_SEQ_BUDGET // max(width * 4, 1))
-    r = min(s, cap)
-    while s % r:
-        r -= 1
-    return r
+    """Row block of s keeping one f32 [S, width] buffer in budget.  The
+    [b, s, hd/2] tables block on s as their second-minor axis, so S is s
+    itself or a multiple of 8: one that divides s where there is one,
+    else the largest in budget (the grid then overhangs s)."""
+    cap = max(8, _VMEM_SEQ_BUDGET // max(width * 4, 1))
+    return fit_sublane_block(s, cap) or cap - cap % 8
 
 
 def _kernel(cos_ref, sin_ref, q_ref, k_ref, qo_ref, ko_ref, *, d2):
@@ -80,7 +83,7 @@ def _kernel(cos_ref, sin_ref, q_ref, k_ref, qo_ref, ko_ref, *, d2):
 
 
 def _apply(q, k, cos_t, sin_t):
-    b, s, nq, nk, hd = _check_shapes(q.shape, k.shape)
+    b, s, nq, nk, hd = check_shapes(q.shape, k.shape)
     d2 = hd // 2
     S = _fit_seq(s, max(nq, nk) * hd)
     kern = functools.partial(_kernel, d2=d2)
@@ -89,7 +92,7 @@ def _apply(q, k, cos_t, sin_t):
     k_spec = pl.BlockSpec((1, S, nk, hd), lambda bi, si: (bi, si, 0, 0))
     return pl.pallas_call(
         kern,
-        grid=(b, s // S),
+        grid=(b, pl.cdiv(s, S)),
         in_specs=[cs_spec, cs_spec, q_spec, k_spec],
         out_specs=[q_spec, k_spec],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),

@@ -78,7 +78,7 @@ def gumbel(w0, w1, idx, lane: int = 0):
     return -jnp.log(-jnp.log(hash_uniform(w0, w1, idx, lane)))
 
 
-def _check_shapes(hidden_shape, w_shape) -> Tuple[int, int, int]:
+def check_shapes(hidden_shape, w_shape) -> Tuple[int, int, int]:
     if len(hidden_shape) != 2 or len(w_shape) != 2:
         raise ValueError(f"expected hidden [R, H] and head [H, V], got "
                          f"{hidden_shape} / {w_shape}")
@@ -98,7 +98,7 @@ def _check_shapes(hidden_shape, w_shape) -> Tuple[int, int, int]:
 
 def compatible(hidden_shape, w_shape) -> bool:
     try:
-        _check_shapes(hidden_shape, w_shape)
+        check_shapes(hidden_shape, w_shape)
         return True
     except ValueError:
         return False
@@ -191,7 +191,7 @@ def fused_sample(hidden, w, key_words, temps, top_ks, top_ps):
     raw data of each row's fold_in(key(seed), position) key; temps /
     top_ks / top_ps: [R] per-row sampling params (temp 0 = greedy row).
     Raises ValueError on shapes outside `compatible`."""
-    R, H, V = _check_shapes(hidden.shape, w.shape)
+    R, H, V = check_shapes(hidden.shape, w.shape)
     if tuple(key_words.shape) != (R, 2):
         raise ValueError(f"key_words {key_words.shape} must be [R={R}, 2]")
     for name, arr in (("temps", temps), ("top_ks", top_ks),

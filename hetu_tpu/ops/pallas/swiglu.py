@@ -30,7 +30,7 @@ from hetu_tpu.ops.pallas import _interpret
 from hetu_tpu.ops.pallas.fused_norm import _fit_rows
 
 
-def _check_shapes(g_shape, u_shape) -> Tuple[int, int]:
+def check_shapes(g_shape, u_shape) -> Tuple[int, int]:
     if tuple(g_shape) != tuple(u_shape):
         raise ValueError(f"gate/up shapes differ: {g_shape} vs {u_shape}")
     if len(g_shape) < 2:
@@ -49,7 +49,7 @@ def _check_shapes(g_shape, u_shape) -> Tuple[int, int]:
 
 def compatible(g_shape, u_shape=None) -> bool:
     try:
-        _check_shapes(g_shape, g_shape if u_shape is None else u_shape)
+        check_shapes(g_shape, g_shape if u_shape is None else u_shape)
         return True
     except ValueError:
         return False
@@ -87,7 +87,7 @@ def _run(kern, inputs, out_shapes, rows, inner, n):
 
 @jax.custom_vjp
 def _swiglu(gate, up):
-    tokens, inner = _check_shapes(gate.shape, up.shape)
+    tokens, inner = check_shapes(gate.shape, up.shape)
     rows = _fit_rows(tokens, inner)
     y = _run(_fwd_kernel,
              (gate.reshape(tokens, inner), up.reshape(tokens, inner)),

@@ -11,6 +11,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from hetu_tpu.dstates import DistributedStates
+
 
 def build_rope_cache(max_len: int, head_dim: int, base: float = 10000.0,
                      dtype=jnp.float32):
@@ -42,21 +44,27 @@ def apply_rotary(x, cos, sin, position_ids: Optional[jnp.ndarray] = None):
 
 
 def apply_rotary_qk(q, k, cos, sin, position_ids: Optional[jnp.ndarray] = None,
-                    use_pallas: Optional[bool] = None):
+                    use_pallas: Optional[bool] = None, layout=None):
     """Apply RoPE to q [b, s, nq, hd] AND k [b, s, nk, hd] in one fused
     Pallas pass (ops/pallas/rotary — the tables are gathered once and
     both tensors rotate in VMEM; the rotation's vjp is the same kernel
     with -sin).  Falls back to two `apply_rotary` calls — the exact seed
     composition — when the kernel is gated off or the shape gate
-    rejects.  Returns (q_rotated, k_rotated)."""
+    rejects.  `layout` (a DistributedStates) declares how q/k and both
+    results lie over the mesh: under a multi-device mesh the kernel runs
+    once per shard of it, the gathered tables sharded like q's batch and
+    seq dims.  Returns (q_rotated, k_rotated)."""
+    from hetu_tpu.ops import pallas as _pl
+    from hetu_tpu.ops.pallas import rotary as _pr
+    layouts = None
+    if layout is not None:
+        tables = DistributedStates(layout.spec[:2] + ((),))
+        layouts = (layout, layout, tables, tables)
     if use_pallas is None:
-        from hetu_tpu.ops.pallas import resolve_route
-        from hetu_tpu.ops.pallas import rotary as _pr
-        use_pallas = resolve_route(
-            "rotary", q.ndim == 4 and k.ndim == 4
-            and _pr.compatible(q.shape, k.shape))
+        use_pallas = _pl.resolve_route(
+            "rotary", _pr.check_shapes, q.shape, k.shape,
+            layouts=None if layouts is None else layouts[:2])
     if use_pallas:
-        from hetu_tpu.ops.pallas.rotary import fused_rotary_qk
         b, s = q.shape[0], q.shape[1]
         d2 = cos.shape[-1]
         if position_ids is None:
@@ -66,7 +74,8 @@ def apply_rotary_qk(q, k, cos, sin, position_ids: Optional[jnp.ndarray] = None,
             cos_t = jnp.broadcast_to(cos[position_ids], (b, s, d2))
             sin_t = jnp.broadcast_to(sin[position_ids], (b, s, d2))
         with jax.named_scope("pallas_rotary"):
-            return fused_rotary_qk(q, k, cos_t.astype(jnp.float32),
-                                   sin_t.astype(jnp.float32))
+            return _pl.per_shard(_pr.fused_rotary_qk, layouts,
+                                 (layout, layout))(
+                q, k, cos_t.astype(jnp.float32), sin_t.astype(jnp.float32))
     return (apply_rotary(q, cos, sin, position_ids),
             apply_rotary(k, cos, sin, position_ids))

@@ -71,7 +71,7 @@ def quantized_zero_update(optimizer, grads, opt_state, params, *, mesh,
     still dp-sharded).  `dims`/`specs` from `refresh_dims`/`refresh_specs`
     of the m-tree shardings; `grads_sharded=True` when the caller already
     constrained grads to the opt-state sharding (ZeRO-2)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     if not {"step", "m", "v"} <= set(opt_state):
         # the body threads the AdamW slot layout explicitly; a different
@@ -131,7 +131,7 @@ def quantized_zero_update(optimizer, grads, opt_state, params, *, mesh,
         out_specs=(P(), specs, specs, P(), P()),
         # the gathered params ARE replicated over dp but the checker
         # cannot infer that through the quantized gather
-        check_rep=False)
+        check_vma=False)
     new_params, new_m, new_v, new_step, nstats = fn(
         params, grads, opt_state["m"], opt_state["v"], opt_state["step"])
     # stats folded across dp inside the body are step-level values here:

@@ -1,10 +1,12 @@
-"""ctypes binding for the C++ search core (csrc/dp_core.cpp), with a pure-
-python fallback (reference: tools/Galvatron/csrc/dp_core.cpp bound via
-pybind11; ctypes here — no pybind11 in the TPU image)."""
+"""ctypes binding for the C++ search core (csrc/dp_core.cpp; reference:
+tools/Galvatron/csrc/dp_core.cpp bound via pybind11; ctypes here — no
+pybind11 in the TPU image).  The core is built from source on first use
+(utils/native.py); `_dp_python` is the plain reference the tests hold it
+to, not a fallback."""
 from __future__ import annotations
 
 import ctypes
-from typing import List, Optional, Sequence, Tuple
+from typing import List, Sequence, Tuple
 
 import numpy as np
 
@@ -13,18 +15,14 @@ from hetu_tpu.utils.native import load_native_lib
 _LIB = None
 
 
-def _lib() -> Optional[ctypes.CDLL]:
+def _lib() -> ctypes.CDLL:
     global _LIB
-    if _LIB is not None:
-        return _LIB or None
-    lib = load_native_lib("libdp_core.so", "libdp_core.so", required=False)
-    if lib is None:
-        _LIB = False
-        return None
-    lib.dynamic_programming_core.restype = ctypes.c_int
-    lib.balance_stages.restype = ctypes.c_int
-    _LIB = lib
-    return lib
+    if _LIB is None:
+        lib = load_native_lib("libdp_core.so")
+        lib.dynamic_programming_core.restype = ctypes.c_int
+        lib.balance_stages.restype = ctypes.c_int
+        _LIB = lib
+    return _LIB
 
 
 def dynamic_programming_core(time: Sequence[float], mem: Sequence[int],
@@ -37,22 +35,19 @@ def dynamic_programming_core(time: Sequence[float], mem: Sequence[int],
     time_a = np.ascontiguousarray(time, np.float64)
     mem_a = np.ascontiguousarray(mem, np.int32)
     trans_a = np.ascontiguousarray(trans, np.float64).reshape(S * S)
-    lib = _lib()
-    if lib is not None:
-        out = np.zeros(num_layers, np.int32)
-        out_t = ctypes.c_double()
-        rc = lib.dynamic_programming_core(
-            ctypes.c_int32(num_layers), ctypes.c_int32(S),
-            time_a.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
-            mem_a.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-            trans_a.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
-            ctypes.c_int32(budget),
-            out.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-            ctypes.byref(out_t))
-        if rc != 0:
-            raise ValueError("no feasible strategy assignment under budget")
-        return out.tolist(), out_t.value
-    return _dp_python(time_a, mem_a, trans_a.reshape(S, S), num_layers, budget)
+    out = np.zeros(num_layers, np.int32)
+    out_t = ctypes.c_double()
+    rc = _lib().dynamic_programming_core(
+        ctypes.c_int32(num_layers), ctypes.c_int32(S),
+        time_a.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
+        mem_a.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+        trans_a.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
+        ctypes.c_int32(budget),
+        out.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+        ctypes.byref(out_t))
+    if rc != 0:
+        raise ValueError("no feasible strategy assignment under budget")
+    return out.tolist(), out_t.value
 
 
 def _dp_python(time, mem, trans, L, budget):
@@ -100,28 +95,11 @@ def balance_stages(num_layers: int, speeds: Sequence[float]) -> List[int]:
     hetero pipeline balancing; reference: engine/strategy.py StrategyModel)."""
     P = len(speeds)
     sp = np.ascontiguousarray(speeds, np.float64)
-    lib = _lib()
-    if lib is not None:
-        out = np.zeros(P, np.int32)
-        rc = lib.balance_stages(
-            ctypes.c_int32(num_layers), ctypes.c_int32(P),
-            sp.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
-            out.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
-        if rc != 0:
-            raise ValueError("cannot balance stages")
-        return out.tolist()
-    # python fallback
-    total = float(sp.sum())
-    raw = [max(1, round(num_layers * s / total)) for s in sp]
-    while sum(raw) != num_layers:
-        if sum(raw) < num_layers:
-            raw[int(np.argmax(sp))] += 1
-        else:
-            idx = sorted(range(P), key=lambda p: sp[p])
-            for p in idx:
-                if raw[p] > 1:
-                    raw[p] -= 1
-                    break
-            else:
-                raise ValueError("cannot balance stages")
-    return raw
+    out = np.zeros(P, np.int32)
+    rc = _lib().balance_stages(
+        ctypes.c_int32(num_layers), ctypes.c_int32(P),
+        sp.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
+        out.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
+    if rc != 0:
+        raise ValueError("cannot balance stages")
+    return out.tolist()

@@ -51,6 +51,7 @@ See docs/serving.md for the architecture and known limits.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import time
 from typing import List, Optional, Sequence
@@ -64,6 +65,7 @@ from hetu_tpu.models.generation import (_check_context_length,
 from hetu_tpu.obs.health import maybe_serving_health_monitor
 from hetu_tpu.obs.metrics import MetricsRegistry, get_registry
 from hetu_tpu.obs.runlog import RunLog, default_runlog_path
+from hetu_tpu.ops.pallas import record_routes
 from hetu_tpu.serving.kv_pool import PagePool, PoolArrays
 from hetu_tpu.serving.request import (Request, RequestResult,
                                       RequestStats, rid_sampled)
@@ -420,9 +422,23 @@ class ServingEngine:
                  c.head_dim)
         self._scratch = (jnp.zeros(shape, c.compute_dtype),
                          jnp.zeros(shape, c.compute_dtype))
-        self._build_programs()
+        # every kernel routing decision of this engine — the static ones
+        # _build_programs takes, then each program's as it is traced —
+        # with its reason (ops/pallas.record_routes)
+        self.kernel_routes: dict = {}
+        with record_routes(self.kernel_routes):
+            self._build_programs()
 
     # ------------------------------------------------------------ build
+    def _recording(self, fn):
+        """`fn`, noting the kernel routes its trace takes in
+        `self.kernel_routes`."""
+        @functools.wraps(fn)
+        def traced(*args):
+            with record_routes(self.kernel_routes):
+                return fn(*args)
+        return traced
+
     def _use_paged_kernel(self) -> bool:
         """Route the decode program through the gather-free Pallas
         paged-attention kernel (ops/pallas/paged_attention) when the
@@ -446,13 +462,13 @@ class ServingEngine:
         if self.spec:
             q_shape = (S, self.config.spec_k + 1,
                        c.num_attention_heads, c.head_dim)
-            ok = _pa.verify_compatible(q_shape, pool_shape, table_shape,
-                                       (S,), quant=self.pool.quant)
-            return resolve_route("paged_verify", ok)
+            return resolve_route(
+                "paged_verify", _pa.check_shapes_verify, q_shape, pool_shape,
+                table_shape, (S,), quant=self.pool.quant)
         q_shape = (S, c.num_attention_heads, c.head_dim)
-        ok = _pa.compatible(q_shape, pool_shape, table_shape, (S,),
-                            quant=self.pool.quant)
-        return resolve_route("paged_attn", ok)
+        return resolve_route("paged_attn", _pa.check_shapes, q_shape,
+                             pool_shape, table_shape, (S,),
+                             quant=self.pool.quant)
 
     def _build_programs(self):
         model, pool = self.model, self.pool
@@ -530,9 +546,9 @@ class ServingEngine:
             from hetu_tpu.ops.pallas import sample as _psample
             mc = model.config
             self.verify_fused_sample = resolve_route(
-                "sample", _psample.compatible(
-                    (self.config.num_slots * K1, mc.hidden_size),
-                    (mc.hidden_size, mc.vocab_size)))
+                "sample", _psample.check_shapes,
+                (self.config.num_slots * K1, mc.hidden_size),
+                (mc.hidden_size, mc.vocab_size))
         fused_sample = self.verify_fused_sample
 
         def verify_forward(params, pool_tree, table, tokens, positions,
@@ -685,15 +701,16 @@ class ServingEngine:
         # returned tree, so the donated input is never reused).  With
         # speculative decoding on, the verify program IS the decode-step
         # program (there is no single-token decode to build).
+        rec = self._recording
         if self.spec:
             self._decode_jit = None
-            self._verify_jit = jax.jit(verify_fn, donate_argnums=(1,))
+            self._verify_jit = jax.jit(rec(verify_fn), donate_argnums=(1,))
         else:
-            self._decode_jit = jax.jit(decode_fn, donate_argnums=(1,))
+            self._decode_jit = jax.jit(rec(decode_fn), donate_argnums=(1,))
             self._verify_jit = None
-        self._chunk_jit = jax.jit(chunk_fn)
-        self._write_jit = jax.jit(write_fn, donate_argnums=(0,))
-        self._prime_jit = (jax.jit(prime_fn)
+        self._chunk_jit = jax.jit(rec(chunk_fn))
+        self._write_jit = jax.jit(rec(write_fn), donate_argnums=(0,))
+        self._prime_jit = (jax.jit(rec(prime_fn))
                            if self.prefix_cache is not None else None)
 
     # ---------------------------------------------------- numerics taps
@@ -748,6 +765,55 @@ class ServingEngine:
         if self._num_health is not None:
             self._num_health.observe(self.steps_done, host)
 
+    def _dummy_args(self, program: str):
+        """Arguments of the engine's own shapes for one of its programs
+        ("decode" | "verify", "prefill_chunk", "write_pages"), all aimed
+        at the null page (zero table/row): what `warmup` runs and
+        `lower_programs` abstracts."""
+        S, C = self.config.num_slots, self.config.prefill_chunk
+        max_pages = self.scheduler.max_pages
+        if program == "prefill_chunk":
+            return (self.params, jnp.zeros((1, C), jnp.int32),
+                    self._scratch, jnp.int32(0))
+        if program == "write_pages":
+            return (self.pool.arrays.tree(), jnp.zeros(max_pages, jnp.int32),
+                    self._scratch[0][:, 0], self._scratch[1][:, 0])
+        table = jnp.zeros((S, max_pages), jnp.int32)
+        pos = jnp.zeros(S, jnp.int32)
+        sample_args = self._sample_args([]) if self.config.sampling else ()
+        if program == "decode":
+            return (self.params, self.pool.arrays.tree(), table,
+                    jnp.zeros(S, jnp.int32), pos, *sample_args)
+        if program != "verify":
+            raise ValueError(f"unknown program {program!r}")
+        K1 = self.config.spec_k + 1
+        extra = ()
+        if self.spec_stochastic:
+            vocab = self.model.config.vocab_size
+            extra = (jnp.full((S, K1 - 1, vocab), 1.0 / vocab, jnp.float32),)
+        return (self.params, self.pool.arrays.tree(), table,
+                jnp.zeros((S, K1), jnp.int32), pos, *extra, *sample_args)
+
+    def lower_programs(self, sharding=None) -> dict:
+        """{program: jax.stages.Lowered} for the decode step ("verify"
+        with speculative decoding on), the prefill chunk and the page
+        write, lowered for ABSTRACT arguments of the engine's own shapes —
+        `.compile().as_text()` is the program the engine runs.  `sharding`
+        places the arguments (default: where the arrays are); a described
+        device's compiles the programs for a chip that is not attached
+        (tests/test_chip_compile.py)."""
+        def abstract(tree):
+            return jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype,
+                    sharding=sharding or getattr(a, "sharding", None)), tree)
+        step = "verify" if self.spec else "decode"
+        jits = {step: self._verify_jit if self.spec else self._decode_jit,
+                "prefill_chunk": self._chunk_jit,
+                "write_pages": self._write_jit}
+        return {name: fn.lower(*abstract(self._dummy_args(name)))
+                for name, fn in jits.items()}
+
     def warmup(self):
         """Compile all three programs so the first request's TTFT is not
         a compile.  The dummy decode/write still target the null page
@@ -755,38 +821,19 @@ class ServingEngine:
         trees are donated through the calls, so the returned trees must
         be committed back (discarding them would leave self.pool.arrays
         pointing at deleted buffers on donating backends)."""
-        S, C = self.config.num_slots, self.config.prefill_chunk
-        table = jnp.zeros((S, self.scheduler.max_pages), jnp.int32)
-        toks = jnp.zeros(S, jnp.int32)
-        pos = jnp.zeros(S, jnp.int32)
-        sample_args = self._sample_args([]) if self.config.sampling else ()
         if self.spec:
-            toks2 = jnp.zeros((S, self.config.spec_k + 1), jnp.int32)
-            extra = ()
-            if self.spec_stochastic:
-                extra = (jnp.full(
-                    (S, self.config.spec_k,
-                     self.model.config.vocab_size),
-                    1.0 / self.model.config.vocab_size, jnp.float32),)
-            nxt, _, tree = self._run_verify(
-                self.params, self.pool.arrays.tree(), table, toks2, pos,
-                *extra, *sample_args)
+            nxt, _, tree = self._run_verify(*self._dummy_args("verify"))
         else:
-            nxt, tree = self._run_decode(
-                self.params, self.pool.arrays.tree(), table, toks, pos,
-                *sample_args)
+            nxt, tree = self._run_decode(*self._dummy_args("decode"))
         self.pool.arrays = PoolArrays.from_tree(tree)
-        lg, cache = self._chunk_jit(self.params,
-                                    jnp.zeros((1, C), jnp.int32),
-                                    self._scratch, jnp.int32(0))
-        row = jnp.zeros(self.scheduler.max_pages, jnp.int32)
-        tree = self._run_write(self.pool.arrays.tree(), row,
-                               cache[0][:, 0], cache[1][:, 0])
+        lg, cache = self._chunk_jit(*self._dummy_args("prefill_chunk"))
+        tree = self._run_write(*self._dummy_args("write_pages"))
         self.pool.arrays = PoolArrays.from_tree(tree)
         if self._prime_jit is not None:
-            jax.block_until_ready(
-                self._prime_jit(self.pool.arrays.tree(), row))
-        jax.block_until_ready(nxt)
+            jax.block_until_ready(self._prime_jit(
+                self.pool.arrays.tree(),
+                jnp.zeros(self.scheduler.max_pages, jnp.int32)))
+        jax.block_until_ready((nxt, lg, cache))
         return self
 
     # ----------------------------------------------------------- intake
@@ -1627,7 +1674,8 @@ class ServingEngine:
             self._log_serve(event="report",
                             requests=len(results), tokens=n_tokens,
                             elapsed_s=elapsed, now=now,
-                            tokens_per_s=n_tokens / elapsed)
+                            tokens_per_s=n_tokens / elapsed,
+                            kernel_routes=self.kernel_routes)
         return sorted(results, key=lambda r: r.rid)
 
     def close(self):

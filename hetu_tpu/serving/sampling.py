@@ -143,7 +143,7 @@ def sample_hidden(hidden, w, seeds, positions, temps, top_ks, top_ps):
     bytes, never the distribution."""
     from hetu_tpu.ops import pallas as _pl
     from hetu_tpu.ops.pallas import sample as _ps
-    if _pl.resolve_route("sample", _ps.compatible(hidden.shape, w.shape)):
+    if _pl.resolve_route("sample", _ps.check_shapes, hidden.shape, w.shape):
         words = key_words(seeds, positions)
         with jax.named_scope("pallas_fused_sample"):
             return _ps.fused_sample(hidden, w, words,

@@ -95,8 +95,8 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
          "per-step work", identity="1"),
     Flag("HETU_TPU_HW_PROFILE", "str", "",
          "hardware profile JSON for the MFU/roofline reporter (obs.mfu); "
-         "default: repo-root hardware_profile_v5e.json, else built-in v5e "
-         "constants"),
+         "default: repo-root hardware_profile_v5e.json; the chosen file "
+         "must exist"),
     Flag("HETU_TPU_PROFILE", "bool", False,
          "per-compile analytic step profile (obs.hlo_profile): per-layer "
          "HLO attribution (FLOPs/HBM bytes/wire bytes per named "

@@ -1,0 +1,281 @@
+"""Compile the main path for a DESCRIBED TPU v5e — no chip attached.
+
+The TPU compiler is installed in the test sandbox and compiles for a chip
+that is described, not attached (`get_topology_desc`).  That shows what
+interpret mode cannot: a block shape the Mosaic lowering refuses, more
+VMEM than a kernel may use, a kernel the partitioner cannot split.  Each
+of these compiled under interpret mode for twenty PRs and was refused
+the first time the whole program met the chip's compiler (PR 21: the
+fused-norm backward's (1, hidden) partial block, the rotary kernel's
+row block at seq 40/300/1100, every kernel under a multi-device mesh —
+repaired by a padded partial block, an overhanging row block and a
+per-shard shard_map respectively).
+
+One case per main-path kernel at Llama-2-7B widths, then the three whole
+programs `chip_smoke.py` runs on the chip.  Nothing executes: these
+tests say a program COMPILES for v5e, never how it runs.
+
+The code under test asks `jax.default_backend()` (interpret mode,
+kernel routing) and would take its CPU branch here, so the module
+fixture steers that one call — in the test, not through an option of
+the program.
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+# libtpu lets one process at a time load it (/tmp/libtpu_lockfile).  Nothing
+# here touches a chip, and under pytest-xdist every worker imports this
+# module: without this, all but one worker would skip it and the workers
+# would disagree on what was collected.
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+
+try:
+    TOPO = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:  # no TPU compiler in this installation
+    pytest.skip(f"cannot describe a TPU v5e here: {e!r}",
+                allow_module_level=True)
+
+ONE_CHIP = SingleDeviceSharding(TOPO.devices[0])
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+# Llama-2-7B widths (models/llama/config.py llama2_7b)
+HIDDEN, INTER, HEADS, HEAD_DIM, VOCAB, SEQ = 4096, 11008, 32, 128, 32000, 4096
+
+
+@pytest.fixture(scope="module", autouse=True)
+def described_chip():
+    """Route and lower as on a TPU backend, with the persistent compile
+    cache off: an entry written for a described chip cannot be read back
+    without one, and the next compile would warn about it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    patch = pytest.MonkeyPatch()
+    patch.setattr(jax, "default_backend", lambda: "tpu")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    cc.reset_cache()
+    patch.undo()
+
+
+def spec(shape, dtype, sharding=ONE_CHIP):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def compile_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def scalar_loss(fn):
+    """fwd+bwd of `fn`: grads of the f32 sum of squares of its outputs
+    w.r.t. every argument (squares, so that the backward of a LINEAR
+    kernel still depends on the arguments — jit drops unused arguments,
+    and with them the described device the program is compiled for)."""
+    def loss(*args):
+        return sum(jnp.square(o.astype(F32)).sum()
+                   for o in jax.tree.leaves(fn(*args)))
+    return lambda *args: jax.grad(loss, argnums=tuple(range(len(args))))(*args)
+
+
+# ---------------------------------------------------------------------------
+# kernels, one case each
+# ---------------------------------------------------------------------------
+
+def _flash(n_kv):
+    from hetu_tpu.ops.pallas.flash_attention import flash_attention
+    q = spec((1, SEQ, HEADS, HEAD_DIM), BF16)
+    kv = spec((1, SEQ, n_kv, HEAD_DIM), BF16)
+    return scalar_loss(lambda q, k, v: flash_attention(q, k, v)), (q, kv, kv)
+
+
+def _norm(bwd):
+    from hetu_tpu.ops.pallas.fused_norm import fused_residual_rmsnorm
+    x = spec((2, SEQ, HIDDEN), BF16)
+    fn = fused_residual_rmsnorm
+    return (scalar_loss(fn) if bwd else fn), (x, x, spec((HIDDEN,), BF16))
+
+
+def _swiglu():
+    from hetu_tpu.ops.pallas.swiglu import fused_swiglu
+    g = spec((2, SEQ, INTER), BF16)
+    return scalar_loss(fused_swiglu), (g, g)
+
+
+def _rotary(batch, seq, bwd=True):
+    """seq 4096 is training; 40, 300 and 1100 are prompts whose row block
+    has to be a multiple of 8 (the PR 21 refusal) — 300 and 1100 have no
+    such divisor in budget, so their last block overhangs; 1 is the decode
+    step."""
+    from hetu_tpu.ops.pallas.rotary import fused_rotary_qk
+    q = spec((batch, seq, HEADS, HEAD_DIM), BF16)
+    t = spec((batch, seq, HEAD_DIM // 2), F32)
+
+    def fn(q, k):   # tables are inputs, not differentiated
+        return fused_rotary_qk(q, k, jnp.ones(t.shape, F32),
+                               jnp.zeros(t.shape, F32))
+    return (scalar_loss(fn) if bwd else fn), (q, q)
+
+
+def _adam(shape):
+    from hetu_tpu.ops.pallas.adam import adam_update
+
+    def fn(p, g, m, v):
+        return adam_update(p, g, m, v, 1e-4, 0.1, 0.05, b1=0.9, b2=0.95,
+                           eps=1e-8, weight_decay=0.1)
+    return fn, (spec(shape, BF16), spec(shape, F32), spec(shape, F32),
+                spec(shape, F32))
+
+
+def _paged(quant, n_kv, verify_c=0):
+    """8 slots x 2048 positions of 16-token pages, as chip_smoke serves."""
+    from hetu_tpu.ops.pallas.paged_attention import (paged_attention,
+                                                     paged_verify)
+    slots, page, pages, max_pages = 8, 16, 8 * 128 + 1, 128
+    q_shape = ((slots, verify_c, HEADS, HEAD_DIM) if verify_c
+               else (slots, HEADS, HEAD_DIM))
+    pool = spec((pages, page, n_kv, HEAD_DIM), jnp.int8 if quant else BF16)
+    scales = ((spec((pages, page, n_kv), F32),) * 2 if quant else ())
+    kernel = paged_verify if verify_c else paged_attention
+
+    def fn(q, k, v, table, pos, *sc):
+        kw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
+        return kernel(q, k, v, table, pos, **kw)
+    return fn, (spec(q_shape, BF16), pool, pool,
+                spec((slots, max_pages), I32), spec((slots,), I32), *scales)
+
+
+def _quant(bits):
+    from hetu_tpu.ops.pallas.quant import quantize_blockwise_pallas
+    return (lambda x: quantize_blockwise_pallas(x, 128, bits=bits),
+            (spec((HIDDEN * INTER,), F32),))
+
+
+KERNEL_CASES = {
+    "flash_fwd_bwd_mha": lambda: _flash(HEADS),
+    "flash_fwd_bwd_gqa": lambda: _flash(8),
+    "norm_fwd": lambda: _norm(bwd=False),
+    "norm_fwd_bwd": lambda: _norm(bwd=True),
+    "swiglu_fwd_bwd": _swiglu,
+    "rotary_fwd_bwd_train": lambda: _rotary(2, SEQ),
+    "rotary_prompt_40": lambda: _rotary(2, 40, bwd=False),
+    "rotary_prompt_300": lambda: _rotary(2, 300, bwd=False),
+    "rotary_prompt_1100": lambda: _rotary(1, 1100, bwd=False),
+    "rotary_decode_step": lambda: _rotary(8, 1, bwd=False),
+    "adam_matrix": lambda: _adam((HIDDEN, 2, INTER)),
+    "adam_embedding": lambda: _adam((VOCAB, HIDDEN)),
+    "adam_vector": lambda: _adam((HIDDEN,)),
+    "paged_attention_fp": lambda: _paged(False, HEADS),
+    "paged_attention_fp_gqa": lambda: _paged(False, 8),
+    "paged_attention_int8": lambda: _paged(True, HEADS),
+    "paged_verify_c5": lambda: _paged(False, HEADS, verify_c=5),
+    "quant_int8": lambda: _quant(8),
+    "quant_int4": lambda: _quant(4),
+}
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_kernel_compiles_for_v5e(case):
+    fn, args = KERNEL_CASES[case]()
+    assert "tpu_custom_call" in compile_text(fn, *args)
+
+
+# ---------------------------------------------------------------------------
+# whole programs, as chip_smoke.py runs them
+# ---------------------------------------------------------------------------
+
+def _train_step(strategy, batch=2):
+    """(the Trainer, its step compiled for described devices): the 2-layer
+    Llama-2-7B-width model at batch 2 x seq 4096 of `chip_smoke.py`,
+    lowered by `Trainer.lower_abstract` — no parameter is materialised."""
+    from hetu_tpu.core.mesh import create_mesh
+    from hetu_tpu.engine.trainer import Trainer
+    from hetu_tpu.engine.trainer_config import TrainingConfig
+    from hetu_tpu.models.llama import LlamaConfig, LlamaLMHeadModel
+
+    cfg = LlamaConfig.llama2_7b(num_hidden_layers=2, param_dtype=BF16,
+                                remat_policy="dots_attn")
+    tc = TrainingConfig(global_batch_size=batch,
+                        micro_batch_size=batch // max(strategy.dp, 1),
+                        seq_len=SEQ)
+    trainer = Trainer(LlamaLMHeadModel(cfg, strategy), tc, strategy,
+                      mesh=create_mesh(strategy.mesh, devices=TOPO.devices))
+    return trainer, trainer.lower_abstract().compile()
+
+
+def _assert_every_train_kernel(trainer, compiled):
+    """flash, norm, swiglu, rotary and adam: each routed to Pallas by the
+    shape gate (nothing forced), each a tpu_custom_call in the program."""
+    from chip_smoke import TRAIN_KERNELS, kernels_in
+    routes = trainer.kernel_routes
+    assert sorted(routes) == sorted(TRAIN_KERNELS), routes
+    for name, rec in routes.items():
+        assert rec["pallas"] and not rec["xla"], (name, rec)
+        assert list(rec["why"]) == ["shape gate passes"], (name, rec)
+    found = kernels_in(compiled.as_text())
+    assert all(found[k] for k in TRAIN_KERNELS), found
+
+
+def test_train_step_compiles_for_one_v5e_with_every_kernel():
+    """The donated AdamW step `chip_smoke.py`'s train phase runs, with
+    flash, norm, swiglu, rotary and adam all routed to Pallas, inside one
+    chip's 16 GB."""
+    from hetu_tpu.parallel import ParallelStrategy
+    trainer, compiled = _train_step(ParallelStrategy())
+    _assert_every_train_kernel(trainer, compiled)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_sharded_train_step_compiles_for_four_v5e_with_every_kernel():
+    """dp2 x tp2 + sequence parallel + ZeRO over four described chips —
+    `chip_smoke.py --chips 4`.  GSPMD cannot partition a Mosaic call (the
+    lowering raises), so each kernel runs once per shard inside a
+    shard_map over the layouts the model declares (ops/pallas.per_shard):
+    the same five kernels as on one chip, and the collectives of the
+    partitioned program around them."""
+    from hetu_tpu.core.mesh import MeshConfig
+    from hetu_tpu.parallel import ParallelStrategy
+    strategy = ParallelStrategy(mesh=MeshConfig(dp=2, tp=2),
+                                sequence_parallel=True, zero=True)
+    trainer, compiled = _train_step(strategy)
+    _assert_every_train_kernel(trainer, compiled)
+    text = compiled.as_text()
+    assert "all-gather" in text and "all-reduce" in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_serving_programs_compile_for_one_v5e():
+    """The engine's decode step (with the paged-attention kernel walking
+    the page tables), prefill chunk and page write, for 8 slots x 2048
+    positions at Llama-2-7B widths."""
+    from chip_smoke import kernels_in
+    from hetu_tpu.models.llama import LlamaConfig, LlamaLMHeadModel
+    from hetu_tpu.serving.engine import ServeConfig, ServingEngine
+
+    layers = 2
+    cfg = LlamaConfig.llama2_7b(num_hidden_layers=layers, param_dtype=BF16)
+    model = LlamaLMHeadModel(cfg)
+    sc = ServeConfig(num_slots=8, page_size=16, max_len=2048,
+                     prefill_chunk=128)
+    params = jax.tree.map(lambda a: spec(a.shape, a.dtype),
+                          model.abstract_params())
+    engine = ServingEngine(model, params, sc)   # programs are built lazily
+    assert engine.decode_paged
+
+    programs = engine.lower_programs(sharding=ONE_CHIP)
+    assert sorted(programs) == ["decode", "prefill_chunk", "write_pages"]
+    compiled = {name: low.compile() for name, low in programs.items()}
+    found = kernels_in(compiled["decode"].as_text())
+    assert found["paged_attn"] and found["rotary"] and found["swiglu"], found
+    routes = engine.kernel_routes
+    assert all(routes[k]["pallas"] and not routes[k]["xla"]
+               for k in ("paged_attn", "rotary", "swiglu")), routes

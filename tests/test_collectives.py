@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from hetu_tpu.comm import collectives as qc
@@ -24,7 +24,7 @@ def _mesh(dp=4):
 def _run(mesh, body, *xs, in_specs=None, out_specs=P("dp")):
     in_specs = in_specs or tuple(P("dp") for _ in xs)
     return jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False))(*xs)
+                             out_specs=out_specs, check_vma=False))(*xs)
 
 
 def _rand(shape, seed=0):
@@ -179,7 +179,7 @@ def test_two_level_sync_matches_psum():
         return out["w"][None]
 
     out = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("dp"),),
-                            out_specs=P("dp"), check_rep=False))(gw)
+                            out_specs=P("dp"), check_vma=False))(gw)
     ref = np.asarray(gw).sum(0)
     # three quantize hops (intra-RS, inter-AR, intra-AG)
     np.testing.assert_allclose(np.asarray(out[0]), ref,
@@ -228,7 +228,7 @@ def test_two_level_inter_slice_bytes_shrink():
                                          "int8", {}, topology=topology)
             return out["w"][None]
         fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("dp"),),
-                               out_specs=P("dp"), check_rep=False))
+                               out_specs=P("dp"), check_vma=False))
         return collective_table(
             fn.lower(jnp.zeros((dp, 128, 128), jnp.float32)).compile())
 
@@ -268,7 +268,7 @@ def _sp_program(mesh):
 
     return jax.jit(shard_map(run, mesh=mesh,
                              in_specs=(P(None, "tp"), P()),
-                             out_specs=P(None, "tp"), check_rep=False))
+                             out_specs=P(None, "tp"), check_vma=False))
 
 
 def test_convert_sp_compress_cuts_bytes_3x(monkeypatch):
@@ -351,7 +351,7 @@ def test_sp_compress_loss_parity(monkeypatch):
             in_specs=(P(None, "tp"), P("tp", None),
                       P(None, "tp"), P(None, "tp")),
             out_specs=(P(), (P(None, "tp"), P("tp", None))),
-            check_rep=False))
+            check_vma=False))
         w1, w2 = w1_0, w2_0
         losses = []
         for _ in range(steps):

@@ -138,7 +138,7 @@ def test_bucket_plan_fuses_small_leaves():
 # ---------------------------------------------------------------------------
 
 def test_quantized_grad_sync_matches_psum():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
     from hetu_tpu.comm.grad_sync import (ef_init, ef_specs,
                                          quantized_grad_sync)
@@ -168,7 +168,7 @@ def test_quantized_grad_sync_matches_psum():
             body, mesh=mesh,
             in_specs=(P("dp"), P("dp"), especs),
             out_specs=({"w": P(), "b": P()}, especs),
-            check_rep=False))(gw, gb, ef0)
+            check_vma=False))(gw, gb, ef0)
     ref_w, ref_b = np.asarray(gw).sum(0), np.asarray(gb).sum(0)
     # two int8 stages: relative error ~1/127 per stage of the block absmax
     np.testing.assert_allclose(np.asarray(out["w"]), ref_w,
@@ -610,7 +610,7 @@ def test_trainer_two_level_flag_flat_is_hlo_identical(tmp_path, monkeypatch):
 # ---------------------------------------------------------------------------
 
 def test_per_replica_keys_differ_across_replicas():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from hetu_tpu.comm.grad_sync import per_replica_keys
     from hetu_tpu.core.mesh import create_mesh
@@ -625,7 +625,7 @@ def test_per_replica_keys_differ_across_replicas():
 
     out = np.asarray(jax.jit(shard_map(
         body, mesh=mesh, in_specs=(P(),), out_specs=P("dp"),
-        check_rep=False))(keys))          # [dp, n_micro, 4]
+        check_vma=False))(keys))          # [dp, n_micro, 4]
     flat = out.reshape(4, -1)
     for i in range(4):
         for j in range(i + 1, 4):
@@ -656,7 +656,7 @@ def test_wire_formulas_match_analyzer_on_lowered_programs():
     """Every ring formula in comm/wire.py must agree with what the
     analyzer reports for a real lowered program emitting that collective
     — catches drift as new variants land."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from hetu_tpu.comm.wire import ring_wire_bytes
     from hetu_tpu.core.mesh import create_mesh
@@ -687,7 +687,7 @@ def test_wire_formulas_match_analyzer_on_lowered_programs():
     for op, fn in cases.items():
         lowered = jax.jit(shard_map(
             fn, mesh=mesh, in_specs=(P(),), out_specs=P(),
-            check_rep=False)).lower(jnp.zeros((N,), jnp.float32)).compile()
+            check_vma=False)).lower(jnp.zeros((N,), jnp.float32)).compile()
         rows = [r for r in collective_table(lowered) if r["op"] == op]
         assert rows, f"no {op} in lowered HLO"
         measured = sum(r["wire_bytes"] for r in rows)
@@ -742,7 +742,7 @@ def test_analyzer_trip_count_nonzero_start_fori_loop():
     rebases the induction to 0 and folds the start into the compare
     bound before the post-optimization text the analyzer parses — this
     pins that assumption."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from hetu_tpu.core.mesh import create_mesh
     from hetu_tpu.obs.comm import collective_report
@@ -756,7 +756,7 @@ def test_analyzer_trip_count_nonzero_start_fori_loop():
 
     compiled = jax.jit(shard_map(
         step, mesh=mesh, in_specs=(P("dp"),), out_specs=P("dp"),
-        check_rep=False)).lower(jnp.ones((4, 256))).compile()
+        check_vma=False)).lower(jnp.ones((4, 256))).compile()
     rep = collective_report(compiled, hw={
         "chip": "t", "ici_allreduce_gbps": 45, "ici_p2p_gbps": 90})
     assert rep["collectives"]["all-reduce"]["count"] == 8
@@ -766,7 +766,7 @@ def test_analyzer_trip_count_nonzero_start_fori_loop():
 def test_analyzer_counts_real_scanned_collectives():
     """A real lax.scan with a psum inside lowers to a while whose trip
     count the analyzer must recover (the documented PR 2 undercount)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from hetu_tpu.core.mesh import create_mesh
     from hetu_tpu.obs.comm import collective_report
@@ -781,7 +781,7 @@ def test_analyzer_counts_real_scanned_collectives():
 
     compiled = jax.jit(shard_map(
         step, mesh=mesh, in_specs=(P("dp"),), out_specs=P("dp"),
-        check_rep=False)).lower(jnp.ones((4, 512))).compile()
+        check_vma=False)).lower(jnp.ones((4, 512))).compile()
     rep = collective_report(compiled, hw={
         "chip": "t", "ici_allreduce_gbps": 45, "ici_p2p_gbps": 90})
     assert rep["collectives"]["all-reduce"]["count"] == 5

@@ -190,7 +190,7 @@ def test_vjp_signature_lint(tmp_path):
 def test_shardmap_constraints_lint(tmp_path):
     bad = _lint_src(tmp_path, """\
         from jax import lax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def run(mesh, spec, x):
             def region(v):
@@ -202,7 +202,7 @@ def test_shardmap_constraints_lint(tmp_path):
     # constraint OUTSIDE the region composes via GSPMD — legal
     good = _lint_src(tmp_path, """\
         from jax import lax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def run(mesh, spec, x):
             x = lax.with_sharding_constraint(x, spec)
@@ -215,7 +215,7 @@ def test_shardmap_constraints_lint(tmp_path):
     # a module that references suppress_constraints knows the hatch
     hatched = _lint_src(tmp_path, """\
         from jax import lax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from hetu_tpu.dstates import suppress_constraints
 
         def run(mesh, spec, x):

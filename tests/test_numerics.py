@@ -162,7 +162,7 @@ def test_sp_collective_probe(monkeypatch):
     """The dstates.convert SNR probe measures the exact int8 roundtrip
     of an SP payload when a frame is open in the same trace."""
     monkeypatch.setenv("HETU_TPU_SP_COMPRESS", "int8")
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from hetu_tpu import dstates as ds
     from hetu_tpu.core.mesh import MeshConfig, create_mesh
@@ -181,7 +181,7 @@ def test_sp_collective_probe(monkeypatch):
 
             full, stats = shard_map(
                 body, mesh=mesh, in_specs=(P("dp"),),
-                out_specs=(P(), P()), check_rep=False)(x)
+                out_specs=(P(), P()), check_vma=False)(x)
             numerics.merge(stats)
             out = col.finalize()
         return full, out

@@ -28,7 +28,8 @@ from hetu_tpu import ops  # noqa: E402
 from hetu_tpu.ops import norms  # noqa: E402
 from hetu_tpu.ops.pallas import (KERNEL_NAMES, fused_norm,  # noqa: E402
                                  kernel_enabled, paged_attention, quant,
-                                 resolve_route, rotary, swiglu)
+                                 record_routes, resolve_route, rotary,
+                                 swiglu)
 
 FWD_TOL = 1e-5
 GRAD_TOL = 1e-4
@@ -115,6 +116,29 @@ def test_fused_rotary_parity():
         argnums=(0, 1))(q, k)
     for a, r in zip(ga, gb):
         np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                   atol=GRAD_TOL)
+
+
+@pytest.mark.parametrize("seq", [300, 1100, 40])
+def test_fused_rotary_overhanging_row_block(seq):
+    """At 32 heads x 128 the row budget is 32 rows: seq 300 and 1100 have
+    no multiple-of-8 divisor under it, so the last block overhangs the
+    sequence (40 blocks evenly by 8).  Rows rotate on their own, so the
+    overhang must change nothing — values and gradients as XLA's."""
+    assert (rotary._fit_seq(seq, 32 * 128) % 8 == 0
+            and rotary.compatible((1, seq, 32, 128), (1, seq, 8, 128)))
+    q, k = _rand((1, seq, 32, 128), 0), _rand((1, seq, 8, 128), 1)
+    cos, sin = ops.build_rope_cache(seq, 128)
+
+    def loss(use_pallas):
+        def f(q, k):
+            qr, kr = ops.apply_rotary_qk(q, k, cos, sin,
+                                         use_pallas=use_pallas)
+            return (qr ** 2).sum() + (kr * k).sum(), (qr, kr)
+        return jax.value_and_grad(f, argnums=(0, 1), has_aux=True)(q, k)
+    ((_, fused), gf), ((_, ref), gr) = loss(True), loss(False)
+    for a, b in zip(fused + gf, ref + gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=GRAD_TOL)
 
 
@@ -477,8 +501,8 @@ def test_gate_drift_adam(shape):
     (256, 256, 128), (256, 256, 64), (100, 256, 128), (8, 8, 128),
 ])
 def test_gate_drift_flash(sq, sk, d):
-    """ops.attention._pallas_compatible delegates to the kernel module's
-    own `compatible` — pin that the verdict matches the public entry's
+    """ops.attention.flash_attention routes on the kernel module's own
+    `compatible` — pin that the verdict matches the public entry's
     acceptance under the default block geometry."""
     from hetu_tpu.ops.pallas import flash_attention as fa
     q = jnp.zeros((1, sq, 2, d), jnp.float32)
@@ -486,8 +510,6 @@ def test_gate_drift_flash(sq, sk, d):
     gate = fa.compatible(q.shape, k.shape)
     assert gate == _accepts(
         lambda q, k: fa.flash_attention(q, k, k, causal=False), q, k)
-    from hetu_tpu.ops.attention import _pallas_compatible
-    assert _pallas_compatible(q, k) == gate
 
 
 # ---------------------------------------------------------------------------
@@ -499,8 +521,8 @@ def test_kernel_routing_flags(monkeypatch):
     monkeypatch.delenv("HETU_TPU_PALLAS_KERNELS", raising=False)
     for name in KERNEL_NAMES:
         assert kernel_enabled(name) is None          # auto
-        # auto on CPU resolves to the fallback
-        assert resolve_route(name, True) is False
+        # auto on CPU resolves to the fallback, whatever the gate says
+        assert resolve_route(name, lambda: None) is False
     monkeypatch.setenv("HETU_TPU_PALLAS", "0")
     assert all(kernel_enabled(n) is False for n in KERNEL_NAMES)
     monkeypatch.setenv("HETU_TPU_PALLAS", "1")
@@ -566,6 +588,160 @@ def test_model_forced_pallas_parity():
     assert abs(float(l0) - float(l1)) < 1e-4
     for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-3)
+
+
+# ---------------------------------------------------------------------------
+# under a multi-device mesh: once per shard, on the declared layouts
+# ---------------------------------------------------------------------------
+# The TPU lowering refuses a Mosaic call in a program GSPMD partitions, so
+# under a mesh the dispatchers wrap the kernel in a shard_map over the
+# layouts their caller declares (ops/pallas.per_shard).  Interpret mode
+# would let GSPMD partition the kernel body instead, so these force the
+# kernels on and check both that the per-shard region is there and that it
+# computes what the XLA composition computes on one device — gradients
+# included (the gain's cotangent is summed over the mesh by the
+# shard_map's transpose).
+
+def _dp2_tp2(devices):
+    from hetu_tpu.core.mesh import MeshConfig
+    from hetu_tpu.parallel import ParallelStrategy
+    st = ParallelStrategy(mesh=MeshConfig(dp=2, tp=2),
+                          sequence_parallel=True, zero=True)
+    return st, st.build_mesh(devices[:4])
+
+
+def _per_shard_case(name, st):
+    """-> (fn(use_pallas, layouts_on) -> loss over *args, args)"""
+    b, s, hd = 2, 128, 128
+    if name == "swiglu":
+        args = (_rand((b, s, 512), 0), _rand((b, s, 512), 1))
+
+        def fn(on, lay):
+            return lambda g, u: ops.swiglu(
+                g, u, use_pallas=on, layout=st.act_inner() if lay else None)
+    elif name == "norm":
+        args = (_rand((b, s, 256), 0), _rand((b, s, 256), 1),
+                _rand((256,), 2))
+
+        def fn(on, lay):
+            return lambda x, h, w: ops.residual_rms_norm(
+                x, h, w, use_pallas=on,
+                layout=st.act_hidden() if lay else None)
+    elif name == "rotary":
+        args = (_rand((b, s, 4, hd), 0), _rand((b, s, 2, hd), 1))
+        cos, sin = ops.build_rope_cache(s, hd)
+
+        def fn(on, lay):
+            return lambda q, k: ops.apply_rotary_qk(
+                q, k, cos, sin, use_pallas=on,
+                layout=st.act_attn() if lay else None)
+    else:
+        assert name == "flash", name
+        args = (_rand((b, s, 4, hd), 0), _rand((b, s, 2, hd), 1),
+                _rand((b, s, 2, hd), 2))
+        seg = jnp.asarray(np.repeat([[0] * 64 + [1] * 64], b, 0), jnp.int32)
+
+        def fn(on, lay):
+            return lambda q, k, v: ops.flash_attention(
+                q, k, v, segment_ids=seg, use_pallas=on,
+                layout=st.act_attn() if lay else None)
+
+    def loss(on, lay):
+        def f(*a):
+            outs = jax.tree.leaves(fn(on, lay)(*a))
+            return sum((o * jnp.cos(o)).sum() for o in outs)
+        return jax.value_and_grad(f, argnums=tuple(range(len(args))))
+    return loss, args
+
+
+@pytest.mark.parametrize("name", ["swiglu", "norm", "rotary", "flash"])
+def test_kernel_runs_per_shard_under_a_mesh(name, devices):
+    from hetu_tpu.core.mesh import use_mesh
+    st, mesh = _dp2_tp2(devices)
+    loss, args = _per_shard_case(name, st)
+    want, want_g = loss(False, False)(*args)          # XLA, one device
+    with use_mesh(mesh):
+        sharded = jax.jit(loss(True, True))
+        assert "manual_computation" in sharded.lower(*args).as_text()
+        got, got_g = sharded(*args)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+    for a, b in zip(got_g, want_g):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=GRAD_TOL)
+
+
+def test_flash_refuses_a_layout_that_shards_the_sequence(devices):
+    from hetu_tpu.core.mesh import MeshConfig, use_mesh
+    from hetu_tpu.parallel import ParallelStrategy
+    st = ParallelStrategy(mesh=MeshConfig(cp=2))
+    q = _rand((2, 128, 2, 128))
+    with use_mesh(st.build_mesh(devices[:2])):
+        with pytest.raises(ValueError, match="shards the sequence"):
+            ops.flash_attention(q, q, q, use_pallas=True,
+                                layout=st.act_attn())
+
+
+def test_trainer_runs_every_kernel_per_shard(devices, monkeypatch):
+    """The whole step under dp2 x tp2 + SP + ZeRO with every kernel forced
+    on — flash, norm, swiglu, rotary per shard of the activations, adam
+    per shard of the optimizer state — tracks the one-device step, and
+    the Trainer says which kernels the plan runs."""
+    from hetu_tpu.engine.trainer import Trainer
+    from hetu_tpu.engine.trainer_config import TrainingConfig
+    from hetu_tpu.models.llama import LlamaConfig, LlamaLMHeadModel
+    from hetu_tpu.parallel import ParallelStrategy
+    monkeypatch.setenv("HETU_TPU_PALLAS", "1")
+    cfg = LlamaConfig.tiny(hidden_size=256, num_attention_heads=2,
+                           num_key_value_heads=2, intermediate_size=256,
+                           compute_dtype=jnp.float32)
+    ids = np.random.default_rng(0).integers(0, 256, (2, 128), dtype=np.int32)
+    batch = {"input_ids": ids, "labels": ids}
+    losses = {}
+    for name, st in (("single", ParallelStrategy()),
+                     ("sharded", _dp2_tp2(devices)[0])):
+        tc = TrainingConfig(global_batch_size=2,
+                            micro_batch_size=2 // max(st.dp, 1), seq_len=128,
+                            lr=1e-2, warmup_steps=1, total_steps=4,
+                            log_every=10 ** 9)
+        trainer = Trainer(LlamaLMHeadModel(cfg, st), tc, st).build()
+        losses[name] = [float(trainer.train_step(batch)["loss"])
+                        for _ in range(3)]
+        routes = trainer.kernel_routes
+        assert sorted(routes) == ["adam", "flash", "norm", "rotary", "swiglu"]
+        assert all(r["pallas"] and not r["xla"] for r in routes.values())
+        assert ("manual_computation" in trainer.lowered_step(batch)) == (
+            name == "sharded")
+        trainer.close()
+    np.testing.assert_allclose(losses["sharded"], losses["single"],
+                               rtol=1e-4)
+
+
+def test_routes_are_recorded_with_their_reasons(monkeypatch, devices):
+    from hetu_tpu.core.mesh import use_mesh
+    monkeypatch.delenv("HETU_TPU_PALLAS", raising=False)
+    st, mesh = _dp2_tp2(devices)
+    g = jnp.zeros((2, 128, 512), jnp.float32)
+
+    def trace(*a, **kw):      # routing happens while tracing; run nothing
+        jax.eval_shape(lambda: ops.swiglu(*a, **kw))
+    with record_routes() as log:
+        trace(g, g)                                       # CPU: XLA
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        trace(g, g)                                       # gate passes
+        trace(g[..., :100], g[..., :100])                 # gate refuses
+        with use_mesh(mesh):
+            trace(g, g)                                   # no layout
+            # dp=2 cannot split a batch of 1
+            trace(g[:1], g[:1], layout=st.act_inner())
+    rec = log["swiglu"]
+    assert (rec["pallas"], rec["xla"]) == (1, 4)
+    for reason in ("multi-device mesh and the caller declares no layout",
+                   "not a TPU backend",
+                   "shape gate: a dim of 1 does not split 2 ways",
+                   "shape gate: inner dim 100", "shape gate passes"):
+        assert sum(n for w, n in rec["why"].items()
+                   if w.startswith(reason)) == 1, (reason, rec["why"])
+    assert sum(rec["why"].values()) == 5
 
 
 def test_fused_sample_token_identity(monkeypatch):

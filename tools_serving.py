@@ -267,4 +267,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from hetu_tpu.utils.device import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -25,8 +25,6 @@ def main():
     ap.add_argument("--size", type=int, default=4096)
     args = ap.parse_args()
 
-    from hetu_tpu.utils.device import force_cpu_if_requested
-    force_cpu_if_requested()   # honor JAX_PLATFORMS=cpu despite the plugin
     import jax
     import jax.numpy as jnp
 

@@ -37,6 +37,7 @@ logger = get_logger("trainer")
 
 
 from hetu_tpu.utils.profiling import device_mem_bytes as _device_mem_bytes
+from hetu_tpu.utils.profiling import phase_span
 
 
 class Trainer:
@@ -495,26 +496,15 @@ class Trainer:
         if not _flags.bool_flag("HETU_TPU_PROFILE"):
             return None
         try:
-            from hetu_tpu.obs.hlo_profile import (flame_trace,
-                                                  layer_profile,
+            from hetu_tpu.obs.hlo_profile import (layer_profile,
                                                   profile_record)
-            # ONE as_text (shared with the hook's comm analysis) + ONE
-            # attribution walk, shared by the record and the flame graph
+            # ONE as_text (shared with the hook's comm analysis) and ONE
+            # attribution walk
             txt = hlo_text_fn() if hlo_text_fn is not None \
                 else plan.as_text()
-            full = layer_profile(txt)
             prof = profile_record(
                 plan, top_k=_flags.int_flag("HETU_TPU_PROFILE_TOPK"),
-                profile=full, text=txt)
-            trace_path = _flags.str_flag("HETU_TPU_PROFILE_TRACE")
-            if trace_path:
-                try:
-                    flame_trace(full).save(trace_path)
-                    logger.info(
-                        f"analytic flame graph written to {trace_path}")
-                except OSError as e:
-                    logger.warning(f"flame graph to {trace_path} "
-                                   f"failed: {e!r}")
+                profile=layer_profile(txt), text=txt)
             return prof
         except Exception as e:
             logger.warning(f"per-compile profile failed: {e!r}")
@@ -1129,14 +1119,26 @@ class Trainer:
         return self._memo_by_shape("_profile_reports", host_batch, compute)
 
     def train_step(self, host_batch: Dict[str, np.ndarray]) -> Dict[str, float]:
-        batches = self.prepare_batch(host_batch)
-        rng = jax.random.fold_in(jax.random.key(self.config.seed + 1),
-                                 self.global_step)
-        with use_mesh(self.mesh), self._declared():
-            self.params, self.opt_state, metrics, self.scaler_state = \
-                self._step_fn(self.params, self.opt_state, batches, rng,
-                              self.scaler_state,
-                              strategy_id=self._plan_dispatch_key())
+        """One step, dispatched and not waited for.  Its two host phases
+        are spans on the profiler's clock (`trainer.prepare_batch`,
+        `trainer.dispatch`, inside a `trainer.step` step annotation) and
+        durations in `trainer.step_phase_s{phase}` (docs/observability.md)."""
+        phases: Dict[str, float] = {}
+        with jax.profiler.StepTraceAnnotation("trainer.step",
+                                              step_num=self.global_step):
+            with phase_span("trainer.prepare_batch", phases):
+                batches = self.prepare_batch(host_batch)
+            with phase_span("trainer.dispatch", phases):
+                rng = jax.random.fold_in(
+                    jax.random.key(self.config.seed + 1), self.global_step)
+                with use_mesh(self.mesh), self._declared():
+                    self.params, self.opt_state, metrics, \
+                        self.scaler_state = self._step_fn(
+                            self.params, self.opt_state, batches, rng,
+                            self.scaler_state,
+                            strategy_id=self._plan_dispatch_key())
+        for name, dt in phases.items():
+            self._registry.observe("trainer.step_phase_s", dt, phase=name)
         self.global_step += 1
         return metrics
 
@@ -1154,6 +1156,9 @@ class Trainer:
                 break
             with self.profiler.step(self.global_step):
                 metrics = self.train_step(host_batch)
+                self.profiler.in_flight(metrics["loss"])
+            # the interval between step completions, read one step late
+            # (StepProfiler): never the enqueue of the step just sent
             step_s = self.profiler.last_step_s
             batch_tokens = int(np.prod(host_batch["input_ids"].shape))
             tokens += batch_tokens

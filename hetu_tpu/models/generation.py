@@ -406,14 +406,15 @@ def _decode_step_paged_gpt(model, params, tokens, k_pool, v_pool, table,
                          lp["attn"]["wqkv"].astype(h.dtype)) \
             + lp["attn"]["bqkv"].astype(h.dtype)
         q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
-        if quant:
-            kp, ksc = _paged_write_q(kp, ksc, table, positions, k[:, 0],
-                                     bits=bits)
-            vp, vsc = _paged_write_q(vp, vsc, table, positions, v[:, 0],
-                                     bits=bits)
-        else:
-            kp = _paged_write(kp, table, positions, k[:, 0])
-            vp = _paged_write(vp, table, positions, v[:, 0])
+        with jax.named_scope("kv_write"):
+            if quant:
+                kp, ksc = _paged_write_q(kp, ksc, table, positions,
+                                         k[:, 0], bits=bits)
+                vp, vsc = _paged_write_q(vp, vsc, table, positions,
+                                         v[:, 0], bits=bits)
+            else:
+                kp = _paged_write(kp, table, positions, k[:, 0])
+                vp = _paged_write(vp, table, positions, v[:, 0])
         with jax.named_scope("pallas_paged_attention"):
             attn = paged_attention(q[:, 0], kp, vp, table, positions,
                                    softmax_scale=scale,
@@ -474,8 +475,11 @@ def decode_step_paged(model, params, tokens, k_pool, v_pool, table,
     from hetu_tpu.ops.pallas.paged_attention import paged_attention
     mp_ = params["model"]
     b = tokens.shape[0]
-    x = model.model.embed(mp_["embed"], tokens[:, None]).astype(
-        c.compute_dtype)
+    # the scopes the training programs carry, so that a device trace of
+    # the serving programs is summed under the same names (obs.scope_map)
+    with jax.named_scope("embed"):
+        x = model.model.embed(mp_["embed"], tokens[:, None]).astype(
+            c.compute_dtype)
     cos, sin = ops.build_rope_cache(c.max_position_embeddings, c.head_dim,
                                     c.rope_theta)
     block = model.model.layers.block
@@ -488,38 +492,44 @@ def decode_step_paged(model, params, tokens, k_pool, v_pool, table,
         else:
             layer_params, kp, vp = xs
             ksc = vsc = None
-        hn = block.input_norm(layer_params["input_norm"], h)
-        qkv = jnp.einsum("bsh,hkgd->bskgd", hn,
-                         layer_params["attn"]["wqkv"].astype(h.dtype))
-        q = qkv[..., : att.group, :].reshape(b, 1, att.n_q, c.head_dim)
-        k = qkv[..., att.group, :]
-        v = qkv[..., att.group + 1, :]
-        q, k = ops.apply_rotary_qk(q, k, cos, sin, positions[:, None])
-        if quant:
-            kp, ksc = _paged_write_q(kp, ksc, table, positions, k[:, 0],
-                                     bits=bits)
-            vp, vsc = _paged_write_q(vp, vsc, table, positions, v[:, 0],
-                                     bits=bits)
-        else:
-            kp = _paged_write(kp, table, positions, k[:, 0])
-            vp = _paged_write(vp, table, positions, v[:, 0])
-        with jax.named_scope("pallas_paged_attention"):
-            attn = paged_attention(q[:, 0], kp, vp, table, positions,
-                                   softmax_scale=scale,
-                                   k_scale=ksc, v_scale=vsc,
-                                   quant=kv_quant)
-        h = h + att.o_proj(layer_params["attn"]["o_proj"],
-                           attn.reshape(b, 1, att.n_q * c.head_dim))
-        mlp_out = block.mlp(layer_params["mlp"],
-                            block.post_norm(layer_params["post_norm"], h))
-        if isinstance(mlp_out, tuple):  # MoE
-            mlp_out = mlp_out[0]
-        h = h + mlp_out
+        with jax.named_scope("attn"):
+            hn = block.input_norm(layer_params["input_norm"], h)
+            qkv = jnp.einsum("bsh,hkgd->bskgd", hn,
+                             layer_params["attn"]["wqkv"].astype(h.dtype))
+            q = qkv[..., : att.group, :].reshape(b, 1, att.n_q,
+                                                 c.head_dim)
+            k = qkv[..., att.group, :]
+            v = qkv[..., att.group + 1, :]
+            q, k = ops.apply_rotary_qk(q, k, cos, sin, positions[:, None])
+            with jax.named_scope("kv_write"):
+                if quant:
+                    kp, ksc = _paged_write_q(kp, ksc, table, positions,
+                                             k[:, 0], bits=bits)
+                    vp, vsc = _paged_write_q(vp, vsc, table, positions,
+                                             v[:, 0], bits=bits)
+                else:
+                    kp = _paged_write(kp, table, positions, k[:, 0])
+                    vp = _paged_write(vp, table, positions, v[:, 0])
+            with jax.named_scope("pallas_paged_attention"):
+                attn = paged_attention(q[:, 0], kp, vp, table, positions,
+                                       softmax_scale=scale,
+                                       k_scale=ksc, v_scale=vsc,
+                                       quant=kv_quant)
+            h = h + att.o_proj(layer_params["attn"]["o_proj"],
+                               attn.reshape(b, 1, att.n_q * c.head_dim))
+        with jax.named_scope("mlp"):
+            mlp_out = block.mlp(
+                layer_params["mlp"],
+                block.post_norm(layer_params["post_norm"], h))
+            if isinstance(mlp_out, tuple):  # MoE
+                mlp_out = mlp_out[0]
+            h = h + mlp_out
         return h, ((kp, vp, ksc, vsc) if quant else (kp, vp))
 
     xs = ((mp_["layers"]["layers"], k_pool, v_pool, k_scale, v_scale)
           if quant else (mp_["layers"]["layers"], k_pool, v_pool))
-    x, pools = lax.scan(body, x, xs)
+    with jax.named_scope("layer"):
+        x, pools = lax.scan(body, x, xs)
     hidden = model.model.final_norm(mp_["final_norm"], x)
     logits = model.logits(params, hidden)[:, 0, :]
     return (logits,) + tuple(pools)
@@ -552,14 +562,15 @@ def _verify_step_paged_gpt(model, params, tokens, k_pool, v_pool, table,
                          lp["attn"]["wqkv"].astype(h.dtype)) \
             + lp["attn"]["bqkv"].astype(h.dtype)
         q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
-        if quant:
-            kp, ksc = _paged_write_tokens_q(kp, ksc, table, positions, k,
-                                            bits=bits)
-            vp, vsc = _paged_write_tokens_q(vp, vsc, table, positions, v,
-                                            bits=bits)
-        else:
-            kp = _paged_write_tokens(kp, table, positions, k)
-            vp = _paged_write_tokens(vp, table, positions, v)
+        with jax.named_scope("kv_write"):
+            if quant:
+                kp, ksc = _paged_write_tokens_q(kp, ksc, table, positions,
+                                                k, bits=bits)
+                vp, vsc = _paged_write_tokens_q(vp, vsc, table, positions,
+                                                v, bits=bits)
+            else:
+                kp = _paged_write_tokens(kp, table, positions, k)
+                vp = _paged_write_tokens(vp, table, positions, v)
         with jax.named_scope("pallas_paged_verify"):
             attn = paged_verify(
                 q.reshape(S, C, nh, hd), kp, vp, table, positions,
@@ -639,14 +650,15 @@ def verify_step_paged(model, params, tokens, k_pool, v_pool, table,
         k = qkv[..., att.group, :]
         v = qkv[..., att.group + 1, :]
         q, k = ops.apply_rotary_qk(q, k, cos, sin, qpos)
-        if quant:
-            kp, ksc = _paged_write_tokens_q(kp, ksc, table, positions, k,
-                                            bits=bits)
-            vp, vsc = _paged_write_tokens_q(vp, vsc, table, positions, v,
-                                            bits=bits)
-        else:
-            kp = _paged_write_tokens(kp, table, positions, k)
-            vp = _paged_write_tokens(vp, table, positions, v)
+        with jax.named_scope("kv_write"):
+            if quant:
+                kp, ksc = _paged_write_tokens_q(kp, ksc, table, positions,
+                                                k, bits=bits)
+                vp, vsc = _paged_write_tokens_q(vp, vsc, table, positions,
+                                                v, bits=bits)
+            else:
+                kp = _paged_write_tokens(kp, table, positions, k)
+                vp = _paged_write_tokens(vp, table, positions, v)
         with jax.named_scope("pallas_paged_verify"):
             attn = paged_verify(q, kp, vp, table, positions,
                                 softmax_scale=scale, k_scale=ksc,
@@ -736,7 +748,8 @@ def extend_cache(model, params, tokens, cache, start, *,
     rows = jnp.arange(b)
     start = jnp.broadcast_to(jnp.asarray(start, jnp.int32), (b,))
     qpos = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]  # [b, C]
-    x = model.model.embed(mp["embed"], tokens).astype(c.compute_dtype)
+    with jax.named_scope("embed"):
+        x = model.model.embed(mp["embed"], tokens).astype(c.compute_dtype)
     cos, sin = ops.build_rope_cache(c.max_position_embeddings, c.head_dim,
                                     c.rope_theta)
     block = model.model.layers.block
@@ -748,27 +761,33 @@ def extend_cache(model, params, tokens, cache, start, *,
     def body(carry, xs):
         h = carry
         layer_params, ck, cv = xs
-        hn = block.input_norm(layer_params["input_norm"], h)
-        qkv = jnp.einsum("bsh,hkgd->bskgd", hn,
-                         layer_params["attn"]["wqkv"].astype(h.dtype))
-        q = qkv[..., : att.group, :].reshape(b, C, att.n_q, c.head_dim)
-        k = qkv[..., att.group, :]
-        v = qkv[..., att.group + 1, :]
-        q, k = ops.apply_rotary_qk(q, k, cos, sin, qpos)
-        ck = ck.at[rows[:, None], qpos].set(k.astype(ck.dtype))
-        cv = cv.at[rows[:, None], qpos].set(v.astype(cv.dtype))
-        attn = _attend_cached_chunk(q, ck, cv, start, scale)
-        h = h + att.o_proj(layer_params["attn"]["o_proj"],
-                           attn.reshape(b, C, att.n_q * c.head_dim))
-        mlp_out = block.mlp(layer_params["mlp"],
-                            block.post_norm(layer_params["post_norm"], h))
-        if isinstance(mlp_out, tuple):  # MoE
-            mlp_out = mlp_out[0]
-        h = h + mlp_out
+        with jax.named_scope("attn"):
+            hn = block.input_norm(layer_params["input_norm"], h)
+            qkv = jnp.einsum("bsh,hkgd->bskgd", hn,
+                             layer_params["attn"]["wqkv"].astype(h.dtype))
+            q = qkv[..., : att.group, :].reshape(b, C, att.n_q,
+                                                 c.head_dim)
+            k = qkv[..., att.group, :]
+            v = qkv[..., att.group + 1, :]
+            q, k = ops.apply_rotary_qk(q, k, cos, sin, qpos)
+            with jax.named_scope("kv_write"):
+                ck = ck.at[rows[:, None], qpos].set(k.astype(ck.dtype))
+                cv = cv.at[rows[:, None], qpos].set(v.astype(cv.dtype))
+            attn = _attend_cached_chunk(q, ck, cv, start, scale)
+            h = h + att.o_proj(layer_params["attn"]["o_proj"],
+                               attn.reshape(b, C, att.n_q * c.head_dim))
+        with jax.named_scope("mlp"):
+            mlp_out = block.mlp(
+                layer_params["mlp"],
+                block.post_norm(layer_params["post_norm"], h))
+            if isinstance(mlp_out, tuple):  # MoE
+                mlp_out = mlp_out[0]
+            h = h + mlp_out
         return h, ((ck, cv, k, v) if collect_token_kv else (ck, cv))
 
-    x, ys = lax.scan(
-        body, x, (mp["layers"]["layers"], cache_k, cache_v))
+    with jax.named_scope("layer"):
+        x, ys = lax.scan(
+            body, x, (mp["layers"]["layers"], cache_k, cache_v))
     hidden = model.model.final_norm(mp["final_norm"], x)
     logits = model.logits(params, hidden)
     if collect_token_kv:

@@ -465,16 +465,18 @@ class LlamaLMHeadModel(Module):
         if loss_reduction == "sum":
             # (sum, token_count) — lets grad accumulation / DP weight micro
             # batches by their true token counts instead of mean-of-means
-            loss = ops.softmax_cross_entropy_sparse(
-                lg, tgt, ignore_index=-100, reduction="sum")
-            count = jnp.sum((tgt != -100).astype(jnp.float32))
+            with jax.named_scope("loss"):
+                loss = ops.softmax_cross_entropy_sparse(
+                    lg, tgt, ignore_index=-100, reduction="sum")
+                count = jnp.sum((tgt != -100).astype(jnp.float32))
             # aux (MoE router losses) scales with the token count so that
             # sum/count recovers mean-loss + aux
             if include_aux_loss:
                 loss = loss + aux * count
             return loss, count
-        loss = ops.softmax_cross_entropy_sparse(
-            lg, tgt, ignore_index=-100)
+        with jax.named_scope("loss"):
+            loss = ops.softmax_cross_entropy_sparse(
+                lg, tgt, ignore_index=-100)
         return loss + aux if include_aux_loss else loss
 
     # ------------------------------------------------------------------

@@ -34,9 +34,9 @@ from hetu_tpu.obs.budget import (BudgetError, PerfBudget,  # noqa: F401
 from hetu_tpu.obs.comm import (collective_report,  # noqa: F401
                                collective_table)
 from hetu_tpu.obs.hlo_profile import (PROFILE_SCHEMA,  # noqa: F401
-                                      analytic_peak_hbm, flame_trace,
-                                      layer_profile, layer_table,
-                                      peak_hbm_estimate, profile_record)
+                                      analytic_peak_hbm, layer_profile,
+                                      layer_table, peak_hbm_estimate,
+                                      profile_record, scope_map)
 from hetu_tpu.obs.health import (HealthMonitor,  # noqa: F401
                                  NumericsHealthMonitor,
                                  ServingHealthMonitor,
@@ -70,7 +70,7 @@ __all__ = [
     "analytic_transformer_estimate", "load_hardware_profile",
     "collective_report", "collective_table",
     "layer_table", "layer_profile", "peak_hbm_estimate",
-    "analytic_peak_hbm", "profile_record", "flame_trace",
+    "analytic_peak_hbm", "profile_record", "scope_map",
     "PROFILE_SCHEMA",
     "PerfBudget", "BudgetError", "check_absolute", "diff_metrics",
     "extract_metrics",

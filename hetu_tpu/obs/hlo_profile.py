@@ -20,9 +20,14 @@ text walk:
 
 * **roofline per group** (`layer_profile`) — each group bounded by
   max(flops/compute_rate, out_bytes/hbm_rate) + wire_bytes/ici_rate
-  over the hardware profile, rendered as an **analytic flame graph**
-  (`flame_trace` — a Chrome-trace lane of predicted per-group times
-  next to the schedule traces obs.trace already draws).
+  over the hardware profile: PREDICTED times.
+
+* **the join key for MEASURED times** (`scope_map`) — {instruction
+  name: (group, pass)} from the same `op_name` metadata.  A profiler
+  trace names each device event by its HLO instruction and carries no
+  `op_name`; `benchmarks/scopes.py` looks the instruction up here and
+  sums measured device time per scope, forward, backward and
+  recomputed forward apart.
 
 ALL HLO-text parsing primitives (line anatomy, shapes, collectives,
 while-trip/call-graph multipliers, dot FLOPs, donation contracts) live
@@ -128,8 +133,11 @@ def group_of(op_name: str, phases: Tuple[str, ...] = PHASES) -> str:
                   if _LAYER_SEG_PAT.match(s)), None)
     known = (*phases, *EXTRA_GROUPS)
     phase = next((s for s in reversed(segs) if s in known), None)
+    # not the primitive's own name: on TPU the kernel is ONE custom call
+    # whose path ends `.../pallas_flash_attention/pallas_call`
     kernel = next((s for s in reversed(segs)
-                   if s.startswith(KERNEL_SCOPE_PREFIX)), None)
+                   if s.startswith(KERNEL_SCOPE_PREFIX)
+                   and s != "pallas_call"), None)
     if layer and phase:
         base = f"{layer}/{phase}"
     elif layer:
@@ -141,6 +149,52 @@ def group_of(op_name: str, phases: Tuple[str, ...] = PHASES) -> str:
     else:
         return "other"
     return f"{base}/{kernel}" if kernel else base
+
+
+#: scopes that name a group in `scope_map` beside the model phases and
+#: EXTRA_GROUPS: the paged pool's token and page writes
+#: (models/generation.py, serving/engine.py) and the cross-entropy after
+#: the head (models/llama `forward`)
+SCOPE_MAP_GROUPS = ("kv_write", "loss")
+UNSCOPED = "unscoped"
+_INSTR_PAT = re.compile(r'^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=')
+
+
+def pass_of(op_name: str) -> str:
+    """`recompute` for a forward that `jax.checkpoint` runs again inside
+    the backward pass (`.../checkpoint/rematted_computation/...`), `bwd`
+    for the transposed program (`transpose(jvp(...))` somewhere in the
+    path), else `fwd`.  Read from compiled v5e and CPU HLO alike: both
+    carry the two markers (tests/test_step_spans.py)."""
+    if "rematted_computation" in op_name:
+        return "recompute"
+    return "bwd" if "transpose(" in op_name else "fwd"
+
+
+def scope_map(compiled_or_text) -> Dict[str, Tuple[str, str]]:
+    """{instruction name: (group, pass)} over EVERY instruction of the
+    post-optimization HLO module, fused ones included (a fusion carries
+    the `op_name` of its root).  `group` is `group_of`'s key with the
+    groups of SCOPE_MAP_GROUPS known too -- `layer/attn`,
+    `layer/attn/pallas_flash_attention`, `layer/kv_write`, `lm_head`,
+    `optimizer/pallas_adam`, ... -- and `unscoped` where the instruction
+    has no `op_name` or its path names no scope (the compiler's own
+    copies, a scan's slicing and stacking of its operands, the loss
+    scaling around the micro-batch loop).  `pass` is `pass_of`.
+    Instruction names are unique within a module, not across modules:
+    keep one map per program."""
+    phases = (*PHASES, *SCOPE_MAP_GROUPS)
+    out: Dict[str, Tuple[str, str]] = {}
+    for line in as_hlo_text(compiled_or_text).splitlines():
+        m = _INSTR_PAT.match(line)
+        if m is None:
+            continue
+        om = OP_NAME_PAT.search(line)
+        op_name = om.group(1) if om is not None else ""
+        group = group_of(op_name, phases)
+        out[m.group(1)] = (UNSCOPED if group == "other" else group,
+                           pass_of(op_name))
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -559,7 +613,7 @@ def analytic_peak_hbm(num_params: float, *, batch: int, seq: int,
 
 
 # ---------------------------------------------------------------------------
-# the schema-versioned profile record + the flame graph
+# the schema-versioned profile record
 # ---------------------------------------------------------------------------
 
 def profile_record(compiled_or_text, *, hw: Optional[Dict] = None,
@@ -574,7 +628,7 @@ def profile_record(compiled_or_text, *, hw: Optional[Dict] = None,
 
     The HLO text is materialized ONCE and shared by the attribution and
     peak walks; callers that already hold a `layer_profile` report
-    and/or the text (the trainer's flame-graph path) pass them in to
+    and/or the text (the trainer's compile hook) pass them in to
     skip the re-walk."""
     txt = text if text is not None else (
         compiled_or_text if isinstance(compiled_or_text, str)
@@ -603,29 +657,3 @@ def profile_record(compiled_or_text, *, hw: Optional[Dict] = None,
     if "headroom_frac" in peak:
         rec["hbm_headroom_frac"] = peak["headroom_frac"]
     return rec
-
-
-def flame_trace(profile: Dict[str, Any]) -> "ChromeTrace":
-    """Render a `layer_profile` report as an analytic flame graph: one
-    Chrome-trace lane of per-group predicted roofline times in model
-    order (compute/memory/wire bound in the args), openable next to the
-    schedule traces at https://ui.perfetto.dev."""
-    from hetu_tpu.obs.trace import ChromeTrace
-    tr = ChromeTrace()
-    pid = "analytic step"
-    tr.name_process(pid, "analytic step profile "
-                         f"({profile.get('chip', 'unknown')})")
-    tr.name_thread(pid, "roofline", "predicted per-group time")
-    t = 0.0
-    for g, rec in profile["groups"].items():
-        dur = float(rec.get("time_s", 0.0)) * 1e6
-        if dur <= 0:
-            continue
-        tr.add_complete(g, t, dur, pid=pid, tid="roofline",
-                        cat=rec.get("bound", ""),
-                        args={"flops": rec.get("flops"),
-                              "out_bytes": rec.get("out_bytes"),
-                              "wire_bytes": rec.get("wire_bytes"),
-                              "bound": rec.get("bound")})
-        t += dur
-    return tr

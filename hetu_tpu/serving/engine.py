@@ -72,8 +72,24 @@ from hetu_tpu.serving.request import (Request, RequestResult,
 from hetu_tpu.serving.scheduler import Scheduler
 from hetu_tpu.serving.tracing import maybe_tracer
 from hetu_tpu.utils.logging import get_logger
+from hetu_tpu.utils.profiling import phase_span
 
 logger = get_logger("serving.engine")
+
+#: the host phase spans of one `ServingEngine.step`, in the order a step
+#: enters them, all nested in `serve.step`.  The two marked SYNC wait
+#: for the device; the others only dispatch to it or work on the host.
+STEP_PHASES = (
+    "serve.admit",            # fault results, deadlines, admission, stalls
+    "serve.prefill_chunk",    # per prefilling slot: ids + chunk dispatch
+    "serve.first_token",      # SYNC: the last chunk's first token
+    "serve.page_write",       # scratch -> pages dispatch, prefix insert
+    "serve.decode_build",     # positions, tokens, page table, sampling
+    "serve.decode_dispatch",  # the decode (or verify) program
+    "serve.token_fetch",      # SYNC: the step's tokens to the host
+    "serve.emit",             # per-token bookkeeping, finishes
+    "serve.housekeeping",     # numerics, gauges, health, brownout, reshard
+)
 
 
 def first_token_from_logits(req, logits_row, position: int, *,
@@ -343,6 +359,9 @@ class ServingEngine:
         #: driver-clock time at the end of the last step — the default
         #: timestamp for between-step fault events (fail_over)
         self._last_clock = 0.0
+        #: the slowest `step()` so far: {"step", "now", "step_s",
+        #: "phases": seconds per host phase} (None before the first)
+        self.slowest_step: Optional[dict] = None
         self.reshard = reshard
         self._registry = registry if registry is not None else get_registry()
         if run_log is None:
@@ -515,8 +534,9 @@ class ServingEngine:
                 ck, cv = pool.gather(pool_tree, table)
                 logits, _, (kt, vt) = decode_step_slots(
                     model, params, tokens, (ck, cv), positions)
-                new_tree = pool.write_token(pool_tree, table, positions,
-                                            kt, vt)
+                with jax.named_scope("kv_write"):
+                    new_tree = pool.write_token(pool_tree, table,
+                                                positions, kt, vt)
                 nxt = pick_token(logits, positions, sample_args)
                 return nxt, new_tree
 
@@ -524,7 +544,8 @@ class ServingEngine:
             return extend_cache(model, params, chunk, cache, start)
 
         def write_fn(pool_tree, pages_row, ks, vs):
-            return pool.write_pages(pool_tree, pages_row, ks, vs)
+            with jax.named_scope("kv_write"):
+                return pool.write_pages(pool_tree, pages_row, ks, vs)
 
         # speculative-decoding verify (serving/spec_decode.py): score
         # the last token + k drafts in one multi-query forward —
@@ -911,6 +932,7 @@ class ServingEngine:
         st.pos = req.prompt_len
         st.generated.append(int(t1))
         st.stats.first_token_t = now
+        st.stats.token_ts.append(now)
         ttft = st.stats.ttft_s
         self._registry.observe("serve.ttft_s", ttft)
         self._registry.observe("serve.ttft_s_class", ttft,
@@ -978,171 +1000,227 @@ class ServingEngine:
         the slots whose prefill is complete.  One-chunk-per-step is the
         disaggregation contract: a long prompt adds engine steps for its
         own slot, never a multi-chunk stall to the decode batch's
-        inter-token gap.  Returns requests that finished this step."""
+        inter-token gap.  Returns requests that finished this step.
+
+        Every dispatch, sync and bookkeeping block runs inside one of the
+        host phase spans of `STEP_PHASES` (utils/profiling.phase_span): a
+        TraceAnnotation on the profiler's clock, nested in `serve.step`,
+        and the phase's seconds in this step's record, which ends in
+        `serve.step_phase_s{phase}`, `serve.step_s` and, for the slowest
+        step so far, `self.slowest_step` (docs/serving.md).  The loop
+        over the slots between the blocks and `_note_step_phases` itself
+        are in no phase, so the phases sum to a little under the step."""
         t0 = time.perf_counter()
 
         def clock() -> float:
             return now + (time.perf_counter() - t0)
 
-        finished: List[RequestResult] = []
-        if self._fault_results:
-            finished.extend(self._fault_results)
-            self._fault_results.clear()
-        if self.config.deadline:
-            # before admissions: an expired queued request must not
-            # grab a slot on the step it dies
-            self._expire_deadlines(clock(), finished)
-        while True:
-            t_adm = clock()
-            adm = self.scheduler.admit_next(t_adm)
-            if adm is None:
-                # SLO-class preemption (HETU_TPU_SERVE_PREEMPT): a
-                # stalled strictly-higher-priority head may evict the
-                # lowest-priority live slot and retry the admission
-                if (self.config.preempt and self.scheduler.queue
-                        and self._try_preempt(clock())):
-                    continue
-                break
-            slot_idx, st = adm
-            st.prefilling = True
-            if self.ledger is not None:
-                self.ledger.on_admit(st.request.rid, len(st.pages), t_adm)
-            self._start_prefill(slot_idx, st, t_adm)
-            if self.tracer is not None:
-                self.tracer.on_admit(st.request, slot_idx, t_adm,
-                                     shared_tokens=st.shared_tokens)
-        if self.scheduler.queue:
-            # admission declined with work queued: count the stall and
-            # stamp the scheduler's reserve-on-admit attribution on
-            # every waiting request (the counter must not depend on the
-            # tracing flag — it is the registry's stall signal)
-            reason = self.scheduler.last_stall or "none"
-            self._registry.inc("serve.admission_stalls", reason=reason)
-            if self.tracer is not None:
-                self.tracer.on_stall(
-                    [r.rid for r in self.scheduler.queue], reason)
-
-        for i in self.scheduler.active_slots():
-            st = self.scheduler.slots[i]
-            if st is not None and st.prefilling:
-                self._advance_prefill(i, st, clock, finished)
-
-        active = [i for i in self.scheduler.active_slots()
-                  if not self.scheduler.slots[i].prefilling]
-        if active:
-            td = time.perf_counter()
-            # the decode batch's inputs are DERIVED from scheduler state
-            # every step (single source of truth): last emitted token +
-            # next write position per decoding slot; empty/prefilling
-            # rows ride along at (0, 0) writing into their masked region
-            S = self.config.num_slots
-            positions = np.zeros(S, np.int32)
-            for i in active:
-                positions[i] = self.scheduler.slots[i].pos
-            sample_args = (self._sample_args(active)
-                           if self.config.sampling else ())
-            if self.spec:
-                emitted = self._spec_decode_step(active, positions,
-                                                 sample_args)
-            else:
-                tokens = np.zeros(S, np.int32)
-                for i in active:
-                    tokens[i] = self.scheduler.slots[i].generated[-1]
-                nxt, pool_tree = self._run_decode(
-                    self.params, self.pool.arrays.tree(),
-                    self._decode_table(active),
-                    jnp.asarray(tokens), jnp.asarray(positions),
-                    *sample_args)
-                nxt = np.asarray(nxt)
-                self.pool.arrays = PoolArrays.from_tree(pool_tree)
-                emitted = {i: [int(nxt[i])] for i in active}
-            decode_wall = time.perf_counter() - td
-            self._registry.inc("serve.decode_steps")
-            # token_latency_s is the USER-visible inter-token gap: every
-            # active slot advances >= one token per decode step, so the
-            # gap IS the step wall.  The amortized per-token engine cost
-            # (wall / tokens emitted — the throughput number) is its own
-            # series; conflating them would understate latency by up to
-            # num_slots x.
-            n_emitted = sum(len(v) for v in emitted.values())
-            self._registry.observe("serve.token_latency_s", decode_wall)
-            self._registry.observe("serve.token_cost_s",
-                                   decode_wall / max(n_emitted, 1))
-            tnow = clock()
-            n_done0 = len(finished)
-            for i in active:
-                st = self.scheduler.slots[i]
-                for tok in emitted[i]:
-                    st.generated.append(tok)
-                    st.pos += 1
-                    self._registry.inc("serve.tokens_out")
+        phases: dict = {}
+        with jax.profiler.TraceAnnotation("serve.step"):
+            with phase_span("serve.admit", phases):
+                finished: List[RequestResult] = []
+                if self._fault_results:
+                    finished.extend(self._fault_results)
+                    self._fault_results.clear()
+                if self.config.deadline:
+                    # before admissions: an expired queued request must
+                    # not grab a slot on the step it dies
+                    self._expire_deadlines(clock(), finished)
+                while True:
+                    t_adm = clock()
+                    adm = self.scheduler.admit_next(t_adm)
+                    if adm is None:
+                        # SLO-class preemption (HETU_TPU_SERVE_PREEMPT):
+                        # a stalled strictly-higher-priority head may
+                        # evict the lowest-priority live slot and retry
+                        # the admission
+                        if (self.config.preempt and self.scheduler.queue
+                                and self._try_preempt(clock())):
+                            continue
+                        break
+                    slot_idx, st = adm
+                    st.prefilling = True
+                    if self.ledger is not None:
+                        self.ledger.on_admit(st.request.rid, len(st.pages),
+                                             t_adm)
+                    self._start_prefill(slot_idx, st, t_adm)
                     if self.tracer is not None:
-                        self.tracer.on_token(st.request, tnow)
-                    self._maybe_finish(i, st, tok, tnow, finished)
-                    if self.scheduler.slots[i] is None:
-                        break            # finished: drop surplus drafts
-            if self.tracer is not None and len(finished) > n_done0:
-                # an eviction changed the batch composition: split the
-                # survivors' decode segments so the boundary is visible
-                survivors = [self.scheduler.slots[i].request.rid
-                             for i in self.scheduler.active_slots()
-                             if not self.scheduler.slots[i].prefilling]
-                if survivors:
-                    self.tracer.on_split(survivors, tnow, "evict")
+                        self.tracer.on_admit(st.request, slot_idx, t_adm,
+                                             shared_tokens=st.shared_tokens)
+                if self.scheduler.queue:
+                    # admission declined with work queued: count the
+                    # stall and stamp the scheduler's reserve-on-admit
+                    # attribution on every waiting request (the counter
+                    # must not depend on the tracing flag — it is the
+                    # registry's stall signal)
+                    reason = self.scheduler.last_stall or "none"
+                    self._registry.inc("serve.admission_stalls",
+                                       reason=reason)
+                    if self.tracer is not None:
+                        self.tracer.on_stall(
+                            [r.rid for r in self.scheduler.queue], reason)
 
-        self.steps_done += 1
-        self._maybe_record_numerics()
-        self._registry.set_gauge("serve.queue_depth",
-                                 self.scheduler.queue_depth)
-        self._registry.set_gauge("serve.slot_occupancy",
-                                 self.scheduler.occupancy)
-        self._registry.set_gauge("serve.page_util", self.pool.utilization)
-        for t in self.config.quotas:
-            # quota gauges: each quota'd tenant's live usage, so a
-            # registry snapshot shows who is pinned at their cap
-            self._registry.set_gauge("serve.tenant_slots",
-                                     self.scheduler.tenant_slots.get(t, 0),
-                                     tenant=t)
-            self._registry.set_gauge("serve.tenant_pages",
-                                     self.scheduler.tenant_pages.get(t, 0),
-                                     tenant=t)
-        if self.health is not None:
-            self.health.observe_step(
-                self.steps_done, queue_depth=self.scheduler.queue_depth,
-                page_util=self.pool.utilization, t=clock())
-        if self.config.brownout:
-            self._maybe_brownout(clock(), finished)
+            for i in self.scheduler.active_slots():
+                st = self.scheduler.slots[i]
+                if st is not None and st.prefilling:
+                    self._advance_prefill(i, st, clock, finished, phases)
 
-        if self.reshard is not None:
-            tier = self.reshard.observe(self.scheduler.queue_depth)
-            if tier is not None:
-                t_pause0 = clock()
-                with self._registry.timer("serve.reshard_s"):
-                    self.params = self.reshard.reshard(self.params, tier)
-                    if self.config.kv_repage:
-                        # the KV pool rides the same hot switch
-                        # (HETU_TPU_SERVE_KV_REPAGE): in-flight requests
-                        # keep their cache across the tier change
-                        self.pool.arrays = self.reshard.reshard_pool(
-                            self.pool.arrays, tier)
-                        self._registry.inc("serve.kv_repages")
-                t_pause1 = clock()
-                self._registry.inc("serve.reshards")
-                if self.tracer is not None:
-                    paused = [self.scheduler.slots[i].request.rid
-                              for i in self.scheduler.active_slots()
-                              if not self.scheduler.slots[i].prefilling]
-                    self.tracer.on_pause(paused, t_pause0, t_pause1,
-                                         tier=tier)
-                self._log_serve(event="reshard", tier=tier,
-                                strategy=self.reshard.describe(tier),
-                                now=t_pause1,
-                                pause_s=t_pause1 - t_pause0,
-                                queue_depth=self.scheduler.queue_depth,
-                                **({"kv_repage": True}
-                                   if self.config.kv_repage else {}))
-        self._last_clock = clock()
+            active = [i for i in self.scheduler.active_slots()
+                      if not self.scheduler.slots[i].prefilling]
+            if active:
+                td = time.perf_counter()
+                with phase_span("serve.decode_build", phases):
+                    # the decode batch's inputs are DERIVED from scheduler
+                    # state every step (single source of truth): last
+                    # emitted token + next write position per decoding
+                    # slot; empty/prefilling rows ride along at (0, 0)
+                    # writing into their masked region
+                    S = self.config.num_slots
+                    positions = np.zeros(S, np.int32)
+                    for i in active:
+                        positions[i] = self.scheduler.slots[i].pos
+                    # what this decode step's attention reads: every
+                    # slot's cached tokens, the one it writes included
+                    self._registry.inc("serve.decode_slot_steps",
+                                       len(active))
+                    self._registry.inc("serve.decode_context_tokens",
+                                       int(positions.sum()) + len(active))
+                    sample_args = (self._sample_args(active)
+                                   if self.config.sampling else ())
+                if self.spec:
+                    emitted = self._spec_decode_step(active, positions,
+                                                     sample_args, phases)
+                else:
+                    with phase_span("serve.decode_build", phases):
+                        tokens = np.zeros(S, np.int32)
+                        for i in active:
+                            tokens[i] = \
+                                self.scheduler.slots[i].generated[-1]
+                        decode_args = (
+                            self.params, self.pool.arrays.tree(),
+                            self._decode_table(active),
+                            jnp.asarray(tokens), jnp.asarray(positions),
+                            *sample_args)
+                    with phase_span("serve.decode_dispatch", phases):
+                        nxt, pool_tree = self._run_decode(*decode_args)
+                    with phase_span("serve.token_fetch", phases):
+                        # the step's one wait for the device
+                        nxt = np.asarray(nxt)
+                    with phase_span("serve.emit", phases):
+                        self.pool.arrays = PoolArrays.from_tree(pool_tree)
+                        emitted = {i: [int(nxt[i])] for i in active}
+                with phase_span("serve.emit", phases):
+                    decode_wall = time.perf_counter() - td
+                    self._registry.inc("serve.decode_steps")
+                    # token_latency_s is the USER-visible inter-token
+                    # gap: every active slot advances >= one token per
+                    # decode step, so the gap IS the step wall.  The
+                    # amortized per-token engine cost (wall / tokens
+                    # emitted — the throughput number) is its own
+                    # series; conflating them would understate latency
+                    # by up to num_slots x.
+                    n_emitted = sum(len(v) for v in emitted.values())
+                    self._registry.observe("serve.token_latency_s",
+                                           decode_wall)
+                    self._registry.observe("serve.token_cost_s",
+                                           decode_wall / max(n_emitted, 1))
+                    tnow = clock()
+                    n_done0 = len(finished)
+                    for i in active:
+                        st = self.scheduler.slots[i]
+                        for tok in emitted[i]:
+                            st.generated.append(tok)
+                            st.pos += 1
+                            st.stats.token_ts.append(tnow)
+                            self._registry.inc("serve.tokens_out")
+                            if self.tracer is not None:
+                                self.tracer.on_token(st.request, tnow)
+                            self._maybe_finish(i, st, tok, tnow, finished)
+                            if self.scheduler.slots[i] is None:
+                                break    # finished: drop surplus drafts
+                    if self.tracer is not None and len(finished) > n_done0:
+                        # an eviction changed the batch composition:
+                        # split the survivors' decode segments so the
+                        # boundary is visible
+                        survivors = [
+                            self.scheduler.slots[i].request.rid
+                            for i in self.scheduler.active_slots()
+                            if not self.scheduler.slots[i].prefilling]
+                        if survivors:
+                            self.tracer.on_split(survivors, tnow, "evict")
+
+            with phase_span("serve.housekeeping", phases):
+                self.steps_done += 1
+                self._maybe_record_numerics()
+                self._registry.set_gauge("serve.queue_depth",
+                                         self.scheduler.queue_depth)
+                self._registry.set_gauge("serve.slot_occupancy",
+                                         self.scheduler.occupancy)
+                self._registry.set_gauge("serve.page_util",
+                                         self.pool.utilization)
+                for t in self.config.quotas:
+                    # quota gauges: each quota'd tenant's live usage, so
+                    # a registry snapshot shows who is pinned at their cap
+                    self._registry.set_gauge(
+                        "serve.tenant_slots",
+                        self.scheduler.tenant_slots.get(t, 0), tenant=t)
+                    self._registry.set_gauge(
+                        "serve.tenant_pages",
+                        self.scheduler.tenant_pages.get(t, 0), tenant=t)
+                if self.health is not None:
+                    self.health.observe_step(
+                        self.steps_done,
+                        queue_depth=self.scheduler.queue_depth,
+                        page_util=self.pool.utilization, t=clock())
+                if self.config.brownout:
+                    self._maybe_brownout(clock(), finished)
+
+                if self.reshard is not None:
+                    tier = self.reshard.observe(self.scheduler.queue_depth)
+                    if tier is not None:
+                        t_pause0 = clock()
+                        with self._registry.timer("serve.reshard_s"):
+                            self.params = self.reshard.reshard(
+                                self.params, tier)
+                            if self.config.kv_repage:
+                                # the KV pool rides the same hot switch
+                                # (HETU_TPU_SERVE_KV_REPAGE): in-flight
+                                # requests keep their cache across the
+                                # tier change
+                                self.pool.arrays = \
+                                    self.reshard.reshard_pool(
+                                        self.pool.arrays, tier)
+                                self._registry.inc("serve.kv_repages")
+                        t_pause1 = clock()
+                        self._registry.inc("serve.reshards")
+                        if self.tracer is not None:
+                            paused = [
+                                self.scheduler.slots[i].request.rid
+                                for i in self.scheduler.active_slots()
+                                if not self.scheduler.slots[i].prefilling]
+                            self.tracer.on_pause(paused, t_pause0,
+                                                 t_pause1, tier=tier)
+                        self._log_serve(
+                            event="reshard", tier=tier,
+                            strategy=self.reshard.describe(tier),
+                            now=t_pause1, pause_s=t_pause1 - t_pause0,
+                            queue_depth=self.scheduler.queue_depth,
+                            **({"kv_repage": True}
+                               if self.config.kv_repage else {}))
+                self._last_clock = clock()
+        self._note_step_phases(now, time.perf_counter() - t0, phases)
         return finished
+
+    def _note_step_phases(self, now: float, step_s: float, phases: dict):
+        """The finished step's phase record into the registry, and into
+        `slowest_step` if no step of this engine took longer: so the
+        slowest step of any run, traced or not, names its phase."""
+        for name, dt in phases.items():
+            self._registry.observe("serve.step_phase_s", dt, phase=name)
+        self._registry.observe("serve.step_s", step_s)
+        if self.slowest_step is None or step_s > self.slowest_step["step_s"]:
+            self.slowest_step = {"step": self.steps_done, "now": now,
+                                 "step_s": step_s, "phases": phases}
 
     # ----------------------------------------------------------- faults
     def _finish_faulted(self, req, now: float, finished, *, reason: str,
@@ -1359,58 +1437,66 @@ class ServingEngine:
         return jnp.asarray(table)
 
     # ------------------------------------------------------ spec decode
-    def _spec_decode_step(self, active, positions, sample_args):
+    def _spec_decode_step(self, active, positions, sample_args, phases):
         """One speculative decode step over the active slots: draft k
         tokens per slot on the host, verify all k+1 in ONE batched
         forward, accept by sample-then-match — or by the full
         stochastic p/q rejection rule when the drafter reports its
         proposal distribution (serving/spec_decode.py).  Returns
-        {slot: emitted tokens} (>= 1 per active slot)."""
-        S, k = self.config.num_slots, self.config.spec_k
-        w = getattr(self.drafter, "window", None)
-        tokens = np.zeros((S, k + 1), np.int32)
-        q_probs = (np.zeros((S, k, self.model.config.vocab_size),
-                            np.float32)
-                   if self.spec_stochastic else None)
-        for i in active:
-            st = self.scheduler.slots[i]
-            # hand the drafter only the trailing window it reads —
-            # O(window) per step, not O(prompt + generated)
-            if w:
-                from_prompt = max(0, w - len(st.generated))
-                ctx = (st.request.prompt[st.request.prompt_len
-                                         - from_prompt:].tolist()
-                       + st.generated[-w:])
-            else:
-                ctx = st.request.prompt.tolist() + st.generated
-            tokens[i, 0] = st.generated[-1]
-            if q_probs is not None:
-                sp = st.request.sampling
-                tokens[i, 1:], q_probs[i] = \
-                    self.drafter.propose_with_probs(
-                        ctx, k, seed=sp.seed & 0xFFFFFFFF,
-                        start_pos=int(positions[i]) + 1)
-            else:
-                tokens[i, 1:] = self.drafter.propose(ctx, k)
-        extra = ((jnp.asarray(q_probs),) if q_probs is not None else ())
-        targets, n_emit, pool_tree = self._run_verify(
-            self.params, self.pool.arrays.tree(),
-            self._decode_table(active),
-            jnp.asarray(tokens), jnp.asarray(positions), *extra,
-            *sample_args)
-        targets = np.asarray(targets)
-        n_emit = np.asarray(n_emit)
-        self.pool.arrays = PoolArrays.from_tree(pool_tree)
-        emitted = {}
-        for i in active:
-            n = int(n_emit[i])
-            emitted[i] = [int(t) for t in targets[i, :n]]
-            st = self.scheduler.slots[i]
-            st.stats.spec_proposed += k
-            st.stats.spec_accepted += n - 1
-            self._registry.inc("serve.spec_proposed", value=k)
-            self._registry.inc("serve.spec_accepted", value=n - 1)
-            self._registry.observe("serve.spec_emitted", float(n))
+        {slot: emitted tokens} (>= 1 per active slot).  Drafting is this
+        step's `serve.decode_build` phase, acceptance part of its
+        `serve.emit`."""
+        with phase_span("serve.decode_build", phases):
+            S, k = self.config.num_slots, self.config.spec_k
+            w = getattr(self.drafter, "window", None)
+            tokens = np.zeros((S, k + 1), np.int32)
+            q_probs = (np.zeros((S, k, self.model.config.vocab_size),
+                                np.float32)
+                       if self.spec_stochastic else None)
+            for i in active:
+                st = self.scheduler.slots[i]
+                # hand the drafter only the trailing window it reads —
+                # O(window) per step, not O(prompt + generated)
+                if w:
+                    from_prompt = max(0, w - len(st.generated))
+                    ctx = (st.request.prompt[st.request.prompt_len
+                                             - from_prompt:].tolist()
+                           + st.generated[-w:])
+                else:
+                    ctx = st.request.prompt.tolist() + st.generated
+                tokens[i, 0] = st.generated[-1]
+                if q_probs is not None:
+                    sp = st.request.sampling
+                    tokens[i, 1:], q_probs[i] = \
+                        self.drafter.propose_with_probs(
+                            ctx, k, seed=sp.seed & 0xFFFFFFFF,
+                            start_pos=int(positions[i]) + 1)
+                else:
+                    tokens[i, 1:] = self.drafter.propose(ctx, k)
+            extra = ((jnp.asarray(q_probs),) if q_probs is not None
+                     else ())
+            verify_args = (
+                self.params, self.pool.arrays.tree(),
+                self._decode_table(active),
+                jnp.asarray(tokens), jnp.asarray(positions), *extra,
+                *sample_args)
+        with phase_span("serve.decode_dispatch", phases):
+            targets, n_emit, pool_tree = self._run_verify(*verify_args)
+        with phase_span("serve.token_fetch", phases):
+            targets = np.asarray(targets)
+            n_emit = np.asarray(n_emit)
+        with phase_span("serve.emit", phases):
+            self.pool.arrays = PoolArrays.from_tree(pool_tree)
+            emitted = {}
+            for i in active:
+                n = int(n_emit[i])
+                emitted[i] = [int(t) for t in targets[i, :n]]
+                st = self.scheduler.slots[i]
+                st.stats.spec_proposed += k
+                st.stats.spec_accepted += n - 1
+                self._registry.inc("serve.spec_proposed", value=k)
+                self._registry.inc("serve.spec_accepted", value=n - 1)
+                self._registry.observe("serve.spec_emitted", float(n))
         return emitted
 
     # ------------------------------------------------------- preemption
@@ -1487,87 +1573,98 @@ class ServingEngine:
             self._registry.set_gauge("serve.prefix_cache_pages",
                                      self.prefix_cache.num_pages)
 
-    def _advance_prefill(self, slot_idx: int, st, clock, finished):
+    def _advance_prefill(self, slot_idx: int, st, clock, finished, phases):
         """Run ONE prefill chunk for a prefilling slot; on the last
         chunk, scatter the scratch K/V into the slot's pages, emit the
         first token, and join the decode batch.  A radix-cache hit
         starts chunking at the shared boundary (`st.shared_tokens` —
         the primed prefix is already in the scratch) and never
         re-writes the shared pages."""
-        req = st.request
-        plen = req.prompt_len
-        C = self.config.prefill_chunk
-        base = st.shared_tokens
-        padded = base + math.ceil((plen - base) / C) * C
-        s = base + st.chunks_done * C
-        ids = np.zeros(C, np.int32)
-        seg = req.prompt[s: min(s + C, plen)]
-        ids[: len(seg)] = seg
-        logits, st.prefill_cache = self._chunk_jit(
-            self.params, jnp.asarray(ids[None]), st.prefill_cache,
-            jnp.int32(s))
-        st.chunks_done += 1
-        st.stats.prefill_chunks += 1
-        self._registry.inc("serve.prefill_chunks")
-        if s + C < padded:
+        with phase_span("serve.prefill_chunk", phases):
+            req = st.request
+            plen = req.prompt_len
+            C = self.config.prefill_chunk
+            base = st.shared_tokens
+            padded = base + math.ceil((plen - base) / C) * C
+            s = base + st.chunks_done * C
+            ids = np.zeros(C, np.int32)
+            seg = req.prompt[s: min(s + C, plen)]
+            ids[: len(seg)] = seg
+            logits, st.prefill_cache = self._chunk_jit(
+                self.params, jnp.asarray(ids[None]), st.prefill_cache,
+                jnp.int32(s))
+            st.chunks_done += 1
+            st.stats.prefill_chunks += 1
+            self._registry.inc("serve.prefill_chunks")
+            self._registry.inc("serve.prefill_tokens", len(seg))
+            if s + C < padded:
+                if self.tracer is not None:
+                    self.tracer.on_chunk(req, clock(), st.chunks_done)
+                return                    # more chunks: next engine step
+        with phase_span("serve.first_token", phases):
+            # first generated token: at the last VALID prompt position of
+            # the final chunk (padding tail positions carry garbage) —
+            # argmax, or the seeded sampler for sampling requests (same
+            # key derivation as the decode program: position plen).  The
+            # host waits here for the chunk it has just dispatched.
+            t1 = self._first_token(req, logits[0, plen - 1 - s], plen)
+
+        with phase_span("serve.page_write", phases):
+            # scatter only the FRESHLY prefilled pages; shared-prefix
+            # pages already hold these tokens' K/V (they are what the
+            # scratch was primed from) and are read-only to this slot
+            # (COW) — their row entries point at the null page so the
+            # write lands harmlessly
+            pages_row = np.full(self.scheduler.max_pages,
+                                PagePool.NULL_PAGE, np.int32)
+            pages_row[: len(st.pages)] = st.pages
+            pages_row[: base // self.pool.page_size] = PagePool.NULL_PAGE
+            tree = self._run_write(self.pool.arrays.tree(),
+                                   jnp.asarray(pages_row),
+                                   st.prefill_cache[0][:, 0],
+                                   st.prefill_cache[1][:, 0])
+            self.pool.arrays = PoolArrays.from_tree(tree)
+            if self.prefix_cache is not None:
+                # index the finished prompt: full page-blocks not yet
+                # cached adopt this request's pages (incref — the slot
+                # keeps its own reference and releases it on finish)
+                self.prefix_cache.insert(req.prompt, st.pages, clock())
+
+        with phase_span("serve.emit", phases):
+            st.prefilling = False
+            st.prefill_cache = None
+            st.pos = plen
+            st.generated.append(t1)
+            tnow = clock()
+            st.stats.first_token_t = tnow
+            st.stats.token_ts.append(tnow)
+            ttft = st.stats.ttft_s
+            self._registry.observe("serve.ttft_s", ttft)
+            self._registry.observe("serve.ttft_s_class", ttft,
+                                   slo_class=req.slo.name)
+            if st.stats.queue_wait_s is not None:
+                self._registry.observe("serve.queue_wait_s",
+                                       st.stats.queue_wait_s)
+            self._registry.inc("serve.tokens_out")
             if self.tracer is not None:
-                self.tracer.on_chunk(req, clock(), st.chunks_done)
-            return                        # more chunks: next engine step
-        # first generated token: at the last VALID prompt position of
-        # the final chunk (padding tail positions carry garbage) —
-        # argmax, or the seeded sampler for sampling requests (same
-        # key derivation as the decode program: position plen)
-        t1 = self._first_token(req, logits[0, plen - 1 - s], plen)
-
-        # scatter only the FRESHLY prefilled pages; shared-prefix pages
-        # already hold these tokens' K/V (they are what the scratch was
-        # primed from) and are read-only to this slot (COW) — their row
-        # entries point at the null page so the write lands harmlessly
-        pages_row = np.full(self.scheduler.max_pages, PagePool.NULL_PAGE,
-                            np.int32)
-        pages_row[: len(st.pages)] = st.pages
-        pages_row[: base // self.pool.page_size] = PagePool.NULL_PAGE
-        tree = self._run_write(self.pool.arrays.tree(),
-                               jnp.asarray(pages_row),
-                               st.prefill_cache[0][:, 0],
-                               st.prefill_cache[1][:, 0])
-        self.pool.arrays = PoolArrays.from_tree(tree)
-        if self.prefix_cache is not None:
-            # index the finished prompt: full page-blocks not yet
-            # cached adopt this request's pages (incref — the slot
-            # keeps its own reference and releases it on finish)
-            self.prefix_cache.insert(req.prompt, st.pages, clock())
-
-        st.prefilling = False
-        st.prefill_cache = None
-        st.pos = plen
-        st.generated.append(t1)
-        tnow = clock()
-        st.stats.first_token_t = tnow
-        ttft = st.stats.ttft_s
-        self._registry.observe("serve.ttft_s", ttft)
-        self._registry.observe("serve.ttft_s_class", ttft,
-                               slo_class=req.slo.name)
-        if st.stats.queue_wait_s is not None:
-            self._registry.observe("serve.queue_wait_s",
-                                   st.stats.queue_wait_s)
-        self._registry.inc("serve.tokens_out")
-        if self.tracer is not None:
-            self.tracer.on_first_token(req, slot_idx, tnow,
-                                       chunk=st.chunks_done)
-        if self.health is not None:
-            self.health.observe_ttft(ttft, step=self.steps_done, t=tnow)
-        if self._sampled(req.rid):
-            self._log_serve(event="admit", req=req.rid,
-                            slot=slot_idx, prompt_len=plen,
-                            chunks=st.stats.prefill_chunks, ttft_s=ttft,
-                            queue_wait_s=st.stats.queue_wait_s, now=tnow,
-                            slo_class=req.slo.name, tenant=req.tenant,
-                            shared_tokens=st.shared_tokens,
-                            queue_depth=self.scheduler.queue_depth,
-                            page_util=self.pool.utilization,
-                            **self._weight_fields())
-        self._maybe_finish(slot_idx, st, t1, tnow, finished)
+                self.tracer.on_first_token(req, slot_idx, tnow,
+                                           chunk=st.chunks_done)
+            if self.health is not None:
+                self.health.observe_ttft(ttft, step=self.steps_done,
+                                         t=tnow)
+            if self._sampled(req.rid):
+                self._log_serve(event="admit", req=req.rid,
+                                slot=slot_idx, prompt_len=plen,
+                                chunks=st.stats.prefill_chunks,
+                                ttft_s=ttft,
+                                queue_wait_s=st.stats.queue_wait_s,
+                                now=tnow,
+                                slo_class=req.slo.name, tenant=req.tenant,
+                                shared_tokens=st.shared_tokens,
+                                queue_depth=self.scheduler.queue_depth,
+                                page_util=self.pool.utilization,
+                                **self._weight_fields())
+            self._maybe_finish(slot_idx, st, t1, tnow, finished)
 
     # ----------------------------------------------------------- finish
     def _maybe_finish(self, slot_idx: int, st, tok: int, tnow: float,

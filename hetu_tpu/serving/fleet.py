@@ -515,6 +515,7 @@ class FleetSimulator:
         st.generated.append(0)     # modeled token (no logits exist)
         self.tokens_out += 1
         st.stats.first_token_t = now
+        st.stats.token_ts.append(now)
         rid = req.rid
         if self._sampled(rid):
             self.tracer.on_first_token(req, slot_idx, now,
@@ -918,6 +919,7 @@ class FleetSimulator:
         self.tokens_out += 1
         self.adoptions += 1
         st.stats.first_token_t = now
+        st.stats.token_ts.append(now)
         if self._sampled(rid):
             if reason != "none":
                 self.tracer.on_stall([rid], reason)
@@ -1104,6 +1106,7 @@ class FleetSimulator:
                 st = sched.slots[i]
                 st.generated.append(0)
                 st.pos += 1
+                st.stats.token_ts.append(now)
                 self.tokens_out += 1
                 if self._sampled(st.request.rid):
                     self.tracer.on_token(st.request, now)

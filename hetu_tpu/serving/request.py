@@ -273,6 +273,11 @@ class RequestStats:
     #: times this request re-entered the queue after its serving
     #: replica died (chaos ``engine_kill``; budget HETU_TPU_SERVE_RETRY)
     retries: int = 0
+    #: driver-clock time of every generated token, in order: the first
+    #: is ``first_token_t``, the last of a finished request ``done_t``;
+    #: tokens of one speculative step share a time.  The gaps between
+    #: them are what a stalled engine step shows in.
+    token_ts: List[float] = dataclasses.field(default_factory=list)
 
     @property
     def queue_wait_s(self) -> Optional[float]:

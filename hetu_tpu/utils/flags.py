@@ -107,10 +107,6 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
     Flag("HETU_TPU_PROFILE_TOPK", "int", 8,
          "how many top layers/op-groups (by predicted roofline time) the "
          "'profile' RunLog record and BENCH detail.profile carry"),
-    Flag("HETU_TPU_PROFILE_TRACE", "str", "",
-         "write the analytic flame graph (obs.hlo_profile.flame_trace — "
-         "a Chrome-trace lane of predicted per-layer roofline times) to "
-         "this path on each fresh compile; open in Perfetto"),
     Flag("HETU_TPU_BUDGETS", "str", "",
          "declared perf-budget JSON (obs/budget.py PerfBudget: absolute "
          "ceilings for step time / comm bytes / peak HBM / MFU plus "

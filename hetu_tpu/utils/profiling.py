@@ -9,7 +9,8 @@ TPU mapping: XLA owns op scheduling, so per-op timing comes from
 jax.profiler traces; this module keeps the reference's ENV-FLAG CONTRACT and
 provides step-level timing + trace capture:
 
-    HETU_TPU_EVENT_TIMING=1        step timing logged per step
+    HETU_TPU_EVENT_TIMING=1        step timing logged per step (the interval
+                                   between step completions, `StepProfiler`)
     HETU_TPU_TRACE_DIR=/tmp/trace  capture a jax.profiler trace (step window)
     HETU_TPU_MEMORY_PROFILE=1      per-step device memory stats (if exposed)
 """
@@ -45,8 +46,48 @@ def device_mem_bytes() -> Optional[int]:
         return None
 
 
+class phase_span:
+    """One step-scoped span with two sinks: a `jax.profiler.TraceAnnotation`
+    (on the device trace's clock by construction; read when a profiler
+    session runs, a flag check when none does) and the span's
+    `perf_counter` duration added to the caller's per-step phase record
+    under the name's last dotted part (`serve.emit` -> `emit`; a phase
+    entered twice in one step accumulates).  Used inside
+    `ServingEngine.step` and `Trainer.train_step`; "tracing off" is "no
+    profiler session" -- there is no flag."""
+    __slots__ = ("_ann", "_record", "_key", "_t0")
+
+    def __init__(self, name: str, record: Dict[str, float]):
+        self._ann = jax.profiler.TraceAnnotation(name)
+        self._record = record
+        self._key = name.rsplit(".", 1)[-1]
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        rec = self._record
+        rec[self._key] = (rec.get(self._key, 0.0)
+                          + time.perf_counter() - self._t0)
+        return False
+
+
 class StepProfiler:
-    """Step-level timing/trace hooks for the trainer loop."""
+    """Step-level timing/trace hooks for the trainer loop.
+
+    A step's time is the interval between consecutive step COMPLETIONS,
+    observed one step late (the `Trainer._note_scaler` discipline): the
+    loop hands the step it just dispatched to `in_flight`, and on
+    leaving `step()` the profiler waits for the step dispatched BEFORE
+    it, which has had a whole dispatch to finish, and stamps the clock.
+    So the loop never waits on the step it just dispatched, the
+    intervals add up to the loop's wall time, and in steady state each
+    is one step's device time -- not the enqueue, which returns while
+    the device still works.  The first interval holds the first step's
+    trace, compile and dispatch."""
 
     def __init__(self):
         from hetu_tpu.utils import flags
@@ -57,6 +98,8 @@ class StepProfiler:
         self._trace_done = False
         self._first_step: Optional[int] = None
         self._times = []
+        self._t_mark: Optional[float] = None
+        self._dispatched = self._waiting_on = None
         #: most recent HETU_TPU_MEMORY_PROFILE probe (bytes_in_use), so
         #: the RunLog step record and merged cluster traces see memory
         #: too, not just the log line (None: profiling off / backend
@@ -72,6 +115,11 @@ class StepProfiler:
                 self._trace_active = False
                 self._trace_done = True
 
+    def in_flight(self, value):
+        """`value`: a device array of the step being dispatched (its
+        loss); its readiness is that step's completion."""
+        self._dispatched = value
+
     @contextlib.contextmanager
     def step(self, step_idx: int, trace_steps=(2, 4)):
         """trace_steps are RELATIVE to the first profiled step, so traces
@@ -83,11 +131,17 @@ class StepProfiler:
                 and rel >= trace_steps[0]):
             jax.profiler.start_trace(self.trace_dir)
             self._trace_active = True
-        t0 = time.perf_counter()
+        if self._t_mark is None:
+            self._t_mark = time.perf_counter()
         try:
             yield
         finally:
-            dt = time.perf_counter() - t0
+            prev, self._waiting_on = self._waiting_on, self._dispatched
+            self._dispatched = None
+            if prev is not None:
+                jax.block_until_ready(prev)
+            now = time.perf_counter()
+            dt, self._t_mark = now - self._t_mark, now
             self._times.append(dt)
             if self.event_timing:
                 logger.info(f"step {step_idx}: {dt * 1000:.1f} ms")
@@ -102,13 +156,16 @@ class StepProfiler:
 
     @property
     def last_step_s(self) -> float:
-        """Wall seconds of the most recent profiled step (0.0 before the
-        first) — the trainer's RunLog step records read it."""
+        """The most recent completion interval (0.0 before the first) --
+        the trainer's RunLog step records, `trainer.step_time_s` and the
+        health monitor read it."""
         return self._times[-1] if self._times else 0.0
 
     def close(self):
         """Flush an in-flight trace (called by the trainer when the loop
-        ends before the trace window closes)."""
+        ends before the trace window closes) and let go of the last
+        step's array."""
+        self._waiting_on = self._dispatched = None
         self._stop_trace()
 
     def summary(self) -> Dict[str, float]:

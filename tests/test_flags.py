@@ -76,7 +76,7 @@ def test_every_env_read_is_registered():
     # the analytic step profiler + perf-budget surface
     # (obs.hlo_profile / obs.budget, docs/observability.md)
     for name in ("HETU_TPU_PROFILE", "HETU_TPU_PROFILE_TOPK",
-                 "HETU_TPU_PROFILE_TRACE", "HETU_TPU_BUDGETS"):
+                 "HETU_TPU_BUDGETS"):
         assert name in flags.REGISTRY
     # the fused-kernel layer's routing knobs (ops/pallas,
     # docs/kernels.md): the whole-layer switch + the per-kernel bisect
@@ -228,12 +228,11 @@ def test_doc_flag_drift():
 
 
 def test_profile_flag_defaults_are_off_path():
-    """Profiler defaults: off, top-8, no trace path, no budget file —
+    """Profiler defaults: off, top-8, no budget file —
     and all of them are post-compile analysis only (the HLO
     byte-identity half lives in tests/test_hlo_profile.py)."""
     assert flags.bool_flag("HETU_TPU_PROFILE") is False
     assert flags.int_flag("HETU_TPU_PROFILE_TOPK") == 8
-    assert flags.str_flag("HETU_TPU_PROFILE_TRACE") == ""
     assert flags.str_flag("HETU_TPU_BUDGETS") == ""
 
 

@@ -240,7 +240,7 @@ def test_analytic_peak_hbm_model():
 
 
 # ---------------------------------------------------------------------------
-# profile record + flame graph
+# profile record
 # ---------------------------------------------------------------------------
 
 def test_profile_record_schema_and_topk():
@@ -251,19 +251,6 @@ def test_profile_record_schema_and_topk():
     assert rec["peak_hbm_bytes"] > 0
     assert 0 < rec["hbm_headroom_frac"] < 1
     assert json.loads(json.dumps(rec))  # JSONL-safe
-
-
-def test_flame_trace_renders_groups():
-    prof = hp.layer_profile(_compiled(L=2, scan=False))
-    tr = hp.flame_trace(prof)
-    spans = [e for e in tr.events if e.get("ph") == "X"]
-    names = {e["name"] for e in spans}
-    assert "layer_0/attn" in names and "lm_head" in names
-    assert all(e["dur"] > 0 for e in spans)
-    # lanes are sequential: spans must not overlap
-    spans.sort(key=lambda e: e["ts"])
-    for a, b in zip(spans, spans[1:]):
-        assert b["ts"] >= a["ts"] + a["dur"] - 1e-9
 
 
 def test_layer_profile_totals_and_order():

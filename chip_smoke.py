@@ -248,10 +248,11 @@ def train_phase(cfg, *, batch: int, seq: int, steps: int, seed: int,
 def serve_layers(cfg, serve_cfg, n_requests: int, bytes_limit: int) -> dict:
     """As many layers as fit beside the KV pool, from the sizes the
     compiled programs show (AOT compile for the described chip, PR 21):
-    the decode program holds the pool twice (it is scanned xs -> ys, so
-    the donated pool is copied), every prefilling request holds one dense
-    [max_len] scratch cache next to the template, and ~1 GiB goes to the
-    programs' other temporaries.  15% of the device is left free."""
+    the decode program updates the donated pool in place (PR 25: it held
+    it twice while the pool was an xs -> ys of the layer scan), every
+    prefilling request holds one dense [max_len] scratch cache next to
+    the template, and ~1 GiB goes to the programs' other temporaries.
+    15% of the device is left free."""
     c, s = cfg, serve_cfg
     item = np.dtype(c.compute_dtype).itemsize
     kv = c.num_key_value_heads * c.head_dim
@@ -261,7 +262,7 @@ def serve_layers(cfg, serve_cfg, n_requests: int, bytes_limit: int) -> dict:
                         + 2 * c.hidden_size) * np.dtype(c.param_dtype).itemsize
     pool = 2 * (s.num_pages + 1) * s.page_size * kv * item
     scratch = 2 * s.max_len * kv * item
-    per_layer = per_layer_params + 2 * pool + (1 + n_requests) * scratch
+    per_layer = per_layer_params + pool + (1 + n_requests) * scratch
     fixed = (2 * c.vocab_size * c.hidden_size
              * np.dtype(c.param_dtype).itemsize) + (1 << 30)
     layers = int((0.85 * bytes_limit - fixed) // per_layer)

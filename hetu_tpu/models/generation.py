@@ -304,17 +304,6 @@ def decode_step(model, params, token, cache, pos):
     return logits, new_cache
 
 
-def _paged_write(pool, table, positions, t):
-    """Scatter one token's K (or V) [S, n_kv, hd] into ONE layer's page
-    array [P, ps, n_kv, hd] at each slot's (table[pos // ps], pos % ps).
-    Inactive slots' tables point at the null page (id 0) — their write
-    lands there harmlessly (serving/kv_pool.py)."""
-    ps = pool.shape[1]
-    S = positions.shape[0]
-    page = table[jnp.arange(S), positions // ps]
-    return pool.at[page, positions % ps].set(t.astype(pool.dtype))
-
-
 def _quantize_head_vectors(t, bits: int):
     """Quantize [..., hd] head-vectors for a paged pool: int8 through
     the SAME blockwise primitives the gather path uses (comm/compress ->
@@ -335,17 +324,30 @@ def _quantize_head_vectors(t, bits: int):
     return q, s.reshape(t.shape[:-1])
 
 
-def _paged_write_q(pool, scale, table, positions, t, *, bits: int = 8):
-    """The quantized-page form of `_paged_write` (int8, or int4 nibble
-    payloads with ``bits=4``): write payload + per-head-vector f32
-    scale."""
-    ps = pool.shape[1]
-    S = positions.shape[0]
+def _paged_put(pool, scale, page, off, t, layer, base, bits):
+    """Scatter head-vectors `t` at (page, off) of ONE layer into the
+    carried pool (`_scan_layers_paged`): the payload into the flat page
+    array [L * P, ps, n_kv, hd] at page `base + page` (base = l * P,
+    added after any null-page redirect), and for quantized pages
+    (`scale` not None: int8, or int4 nibble payloads with ``bits=4``)
+    the per-head-vector f32 scale into the layer's plane of
+    [L, P, ps, n_kv].  Returns (pool, scale)."""
+    if scale is None:
+        return pool.at[base + page, off].set(t.astype(pool.dtype)), None
     q, s = _quantize_head_vectors(t, bits)
-    page = table[jnp.arange(S), positions // ps]
-    off = positions % ps
-    return pool.at[page, off].set(q.astype(pool.dtype)), \
-        scale.at[page, off].set(s)
+    return pool.at[base + page, off].set(q.astype(pool.dtype)), \
+        scale.at[layer, page, off].set(s)
+
+
+def _paged_write(pool, scale, table, positions, t, layer, base, bits):
+    """Write one token's K (or V) [S, n_kv, hd] at each slot's
+    (table[pos // ps], pos % ps).  Inactive slots' tables point at the
+    null page (the layer's id 0) — their write lands there harmlessly
+    (serving/kv_pool.py)."""
+    ps = pool.shape[1]
+    page = table[jnp.arange(positions.shape[0]), positions // ps]
+    return _paged_put(pool, scale, page, positions % ps, t, layer, base,
+                      bits)
 
 
 def _token_block_pages(table, positions, C, ps):
@@ -363,22 +365,54 @@ def _token_block_pages(table, positions, C, ps):
     return page, pos % ps
 
 
-def _paged_write_tokens(pool, table, positions, t):
-    """Scatter a C-token block's K (or V) [S, C, n_kv, hd] into ONE
-    layer's page array — the verify-step sibling of `_paged_write`."""
-    ps = pool.shape[1]
-    page, off = _token_block_pages(table, positions, t.shape[1], ps)
-    return pool.at[page, off].set(t.astype(pool.dtype))
+def _paged_write_tokens(pool, scale, table, positions, t, layer, base,
+                        bits):
+    """Write a C-token block's K (or V) [S, C, n_kv, hd] — the
+    verify-step sibling of `_paged_write`."""
+    page, off = _token_block_pages(table, positions, t.shape[1],
+                                   pool.shape[1])
+    return _paged_put(pool, scale, page, off, t, layer, base, bits)
 
 
-def _paged_write_tokens_q(pool, scale, table, positions, t, *,
-                          bits: int = 8):
-    """Quantized-page form of `_paged_write_tokens`."""
-    ps = pool.shape[1]
-    q, s = _quantize_head_vectors(t, bits)
-    page, off = _token_block_pages(table, positions, t.shape[1], ps)
-    return pool.at[page, off].set(q.astype(pool.dtype)), \
-        scale.at[page, off].set(s)
+def _scan_layers_paged(layer, x, layer_params, pools):
+    """Scan `layer` over the stacked layers with the KV pool as a loop
+    CARRY that is updated in place — never an xs or a ys of the scan,
+    which would slice every layer's slab out and stack it back into a
+    fresh buffer (three full-pool copies a step: PERF.md, PR 25).
+
+    pools: (k_pool, v_pool, k_scale, v_scale), each [L, P, ...], the
+    scales None for exact pages.  The scan carries the page arrays as
+    their flat views [L * P, ps, n_kv, hd] (a bitcast): layer l's page
+    p is page l * P + p, so with `table + l * P` as its page table the
+    same scatter and the same kernel walk the same bytes, and l * P is
+    the layer's null page.  The scale planes stay [L, P, ps, n_kv]: the
+    kernel reads a page's scales as a block of a page-major array whose
+    rows are padded to 128 lanes, which is not how the planes are
+    stored, so it is handed ONE layer's plane, `scale[l]`, to read by
+    the engine's own page ids (`scale_table`), and what is converted
+    for it is P pages a layer, never L * P.
+
+    layer(h, layer_params, pools, l, base) -> (h, pools), base = l * P.
+    Returns (x, pools): the arrays that came in (no None), in their
+    [L, P, ...] shapes and, when the caller donated them, their
+    buffers."""
+    k_pool, v_pool, k_scale, v_scale = pools
+    L, P = k_pool.shape[:2]
+
+    def body(carry, xs):
+        h, pools = carry
+        lp, l = xs
+        h, pools = layer(h, lp, pools, l, l * P)
+        return (h, tuple(pools)), None
+
+    flat = (L * P,) + k_pool.shape[2:]
+    (x, (k_flat, v_flat, k_scale, v_scale)), _ = lax.scan(
+        body,
+        (x, (k_pool.reshape(flat), v_pool.reshape(flat), k_scale, v_scale)),
+        (layer_params, jnp.arange(L, dtype=jnp.int32)))
+    pools = (k_flat.reshape(k_pool.shape), v_flat.reshape(v_pool.shape),
+             k_scale, v_scale)
+    return x, tuple(p for p in pools if p is not None)
 
 
 def _decode_step_paged_gpt(model, params, tokens, k_pool, v_pool, table,
@@ -395,42 +429,34 @@ def _decode_step_paged_gpt(model, params, tokens, k_pool, v_pool, table,
     nh, hd = c.num_attention_heads, c.head_dim
     scale = hd ** -0.5
 
-    def body(h, xs):
-        if quant:
-            lp, kp, vp, ksc, vsc = xs
-        else:
-            lp, kp, vp = xs
-            ksc = vsc = None
+    def layer(h, lp, pools, l, base):
+        kp, vp, ksc, vsc = pools
         hn = block.ln1(lp["ln1"], h)
         qkv = jnp.einsum("bsh,hngd->bsngd", hn,
                          lp["attn"]["wqkv"].astype(h.dtype)) \
             + lp["attn"]["bqkv"].astype(h.dtype)
         q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
         with jax.named_scope("kv_write"):
-            if quant:
-                kp, ksc = _paged_write_q(kp, ksc, table, positions,
-                                         k[:, 0], bits=bits)
-                vp, vsc = _paged_write_q(vp, vsc, table, positions,
-                                         v[:, 0], bits=bits)
-            else:
-                kp = _paged_write(kp, table, positions, k[:, 0])
-                vp = _paged_write(vp, table, positions, v[:, 0])
+            kp, ksc = _paged_write(kp, ksc, table, positions, k[:, 0],
+                                   l, base, bits)
+            vp, vsc = _paged_write(vp, vsc, table, positions, v[:, 0],
+                                   l, base, bits)
         with jax.named_scope("pallas_paged_attention"):
-            attn = paged_attention(q[:, 0], kp, vp, table, positions,
+            ksl, vsl = (ksc[l], vsc[l]) if quant else (None, None)
+            attn = paged_attention(q[:, 0], kp, vp, table + base, positions,
                                    softmax_scale=scale,
-                                   k_scale=ksc, v_scale=vsc,
-                                   quant=kv_quant)
+                                   k_scale=ksl, v_scale=vsl,
+                                   quant=kv_quant, scale_table=table)
         h = h + att.o_proj(lp["attn"]["o_proj"],
                            attn.reshape(b, 1, nh * hd))
         h = h + block.mlp(lp["mlp"], block.ln2(lp["ln2"], h))
-        return h, ((kp, vp, ksc, vsc) if quant else (kp, vp))
+        return h, (kp, vp, ksc, vsc)
 
-    xs = ((mp_["blocks"], k_pool, v_pool, k_scale, v_scale) if quant
-          else (mp_["blocks"], k_pool, v_pool))
-    x, pools = lax.scan(body, x, xs)
+    x, pools = _scan_layers_paged(
+        layer, x, mp_["blocks"], (k_pool, v_pool, k_scale, v_scale))
     hidden = model.model.final_ln(mp_["final_ln"], x)
     logits = model.logits(params, hidden)[:, 0, :]
-    return (logits,) + tuple(pools)
+    return (logits,) + pools
 
 
 def decode_step_paged(model, params, tokens, k_pool, v_pool, table,
@@ -446,7 +472,10 @@ def decode_step_paged(model, params, tokens, k_pool, v_pool, table,
     it.  This step's K/V are scattered into each slot's page BEFORE the
     kernel runs (so the token sees itself, exactly like the dense path's
     write-then-attend), and the updated pools are returned:
-    (logits [S, vocab], new_k_pool, new_v_pool).
+    (logits [S, vocab], new_k_pool, new_v_pool).  The pools are a carry
+    of the layer loop, written IN PLACE (`_scan_layers_paged`): a caller
+    that donates them (the engine does) gets its own buffers back, with
+    no second pool among the program's temporaries.
 
     int8 pools (``HETU_TPU_KV_QUANT=int8``) pass their per-head-vector
     f32 scales [L, P, page_size, n_kv] as k_scale/v_scale: the token
@@ -486,12 +515,8 @@ def decode_step_paged(model, params, tokens, k_pool, v_pool, table,
     att = block.attn
     scale = c.head_dim ** -0.5
 
-    def body(h, xs):
-        if quant:
-            layer_params, kp, vp, ksc, vsc = xs
-        else:
-            layer_params, kp, vp = xs
-            ksc = vsc = None
+    def layer(h, layer_params, pools, l, base):
+        kp, vp, ksc, vsc = pools
         with jax.named_scope("attn"):
             hn = block.input_norm(layer_params["input_norm"], h)
             qkv = jnp.einsum("bsh,hkgd->bskgd", hn,
@@ -502,19 +527,16 @@ def decode_step_paged(model, params, tokens, k_pool, v_pool, table,
             v = qkv[..., att.group + 1, :]
             q, k = ops.apply_rotary_qk(q, k, cos, sin, positions[:, None])
             with jax.named_scope("kv_write"):
-                if quant:
-                    kp, ksc = _paged_write_q(kp, ksc, table, positions,
-                                             k[:, 0], bits=bits)
-                    vp, vsc = _paged_write_q(vp, vsc, table, positions,
-                                             v[:, 0], bits=bits)
-                else:
-                    kp = _paged_write(kp, table, positions, k[:, 0])
-                    vp = _paged_write(vp, table, positions, v[:, 0])
+                kp, ksc = _paged_write(kp, ksc, table, positions, k[:, 0],
+                                       l, base, bits)
+                vp, vsc = _paged_write(vp, vsc, table, positions, v[:, 0],
+                                       l, base, bits)
             with jax.named_scope("pallas_paged_attention"):
-                attn = paged_attention(q[:, 0], kp, vp, table, positions,
-                                       softmax_scale=scale,
-                                       k_scale=ksc, v_scale=vsc,
-                                       quant=kv_quant)
+                ksl, vsl = (ksc[l], vsc[l]) if quant else (None, None)
+                attn = paged_attention(
+                    q[:, 0], kp, vp, table + base, positions,
+                    softmax_scale=scale, k_scale=ksl, v_scale=vsl,
+                    quant=kv_quant, scale_table=table)
             h = h + att.o_proj(layer_params["attn"]["o_proj"],
                                attn.reshape(b, 1, att.n_q * c.head_dim))
         with jax.named_scope("mlp"):
@@ -524,15 +546,15 @@ def decode_step_paged(model, params, tokens, k_pool, v_pool, table,
             if isinstance(mlp_out, tuple):  # MoE
                 mlp_out = mlp_out[0]
             h = h + mlp_out
-        return h, ((kp, vp, ksc, vsc) if quant else (kp, vp))
+        return h, (kp, vp, ksc, vsc)
 
-    xs = ((mp_["layers"]["layers"], k_pool, v_pool, k_scale, v_scale)
-          if quant else (mp_["layers"]["layers"], k_pool, v_pool))
     with jax.named_scope("layer"):
-        x, pools = lax.scan(body, x, xs)
+        x, pools = _scan_layers_paged(
+            layer, x, mp_["layers"]["layers"],
+            (k_pool, v_pool, k_scale, v_scale))
     hidden = model.model.final_norm(mp_["final_norm"], x)
     logits = model.logits(params, hidden)[:, 0, :]
-    return (logits,) + tuple(pools)
+    return (logits,) + pools
 
 
 def _verify_step_paged_gpt(model, params, tokens, k_pool, v_pool, table,
@@ -551,43 +573,35 @@ def _verify_step_paged_gpt(model, params, tokens, k_pool, v_pool, table,
     nh, hd = c.num_attention_heads, c.head_dim
     scale = hd ** -0.5
 
-    def body(h, xs):
-        if quant:
-            lp, kp, vp, ksc, vsc = xs
-        else:
-            lp, kp, vp = xs
-            ksc = vsc = None
+    def layer(h, lp, pools, l, base):
+        kp, vp, ksc, vsc = pools
         hn = block.ln1(lp["ln1"], h)
         qkv = jnp.einsum("bsh,hngd->bsngd", hn,
                          lp["attn"]["wqkv"].astype(h.dtype)) \
             + lp["attn"]["bqkv"].astype(h.dtype)
         q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
         with jax.named_scope("kv_write"):
-            if quant:
-                kp, ksc = _paged_write_tokens_q(kp, ksc, table, positions,
-                                                k, bits=bits)
-                vp, vsc = _paged_write_tokens_q(vp, vsc, table, positions,
-                                                v, bits=bits)
-            else:
-                kp = _paged_write_tokens(kp, table, positions, k)
-                vp = _paged_write_tokens(vp, table, positions, v)
+            kp, ksc = _paged_write_tokens(kp, ksc, table, positions, k,
+                                          l, base, bits)
+            vp, vsc = _paged_write_tokens(vp, vsc, table, positions, v,
+                                          l, base, bits)
         with jax.named_scope("pallas_paged_verify"):
+            ksl, vsl = (ksc[l], vsc[l]) if quant else (None, None)
             attn = paged_verify(
-                q.reshape(S, C, nh, hd), kp, vp, table, positions,
-                softmax_scale=scale, k_scale=ksc, v_scale=vsc,
-                quant=kv_quant)
+                q.reshape(S, C, nh, hd), kp, vp, table + base, positions,
+                softmax_scale=scale, k_scale=ksl, v_scale=vsl,
+                quant=kv_quant, scale_table=table)
         h = h + att.o_proj(lp["attn"]["o_proj"],
                            attn.reshape(S, C, nh * hd))
         h = h + block.mlp(lp["mlp"], block.ln2(lp["ln2"], h))
-        return h, ((kp, vp, ksc, vsc) if quant else (kp, vp))
+        return h, (kp, vp, ksc, vsc)
 
-    xs = ((mp_["blocks"], k_pool, v_pool, k_scale, v_scale) if quant
-          else (mp_["blocks"], k_pool, v_pool))
-    x, pools = lax.scan(body, x, xs)
+    x, pools = _scan_layers_paged(
+        layer, x, mp_["blocks"], (k_pool, v_pool, k_scale, v_scale))
     hidden = model.model.final_ln(mp_["final_ln"], x)
     if return_hidden:
-        return (hidden,) + tuple(pools)
-    return (model.logits(params, hidden),) + tuple(pools)
+        return (hidden,) + pools
+    return (model.logits(params, hidden),) + pools
 
 
 def verify_step_paged(model, params, tokens, k_pool, v_pool, table,
@@ -602,7 +616,8 @@ def verify_step_paged(model, params, tokens, k_pool, v_pool, table,
     positions: [S] int32 — token i of the block sits at positions[s]+i.
     The block's K/V are scattered into each slot's pages BEFORE the
     kernel runs (write-then-attend, exactly like the dense path), and
-    the updated pools return: (logits [S, C, vocab], *new_pools).
+    the updated pools return: (logits [S, C, vocab], *new_pools) — in
+    place, as in `decode_step_paged` (`_scan_layers_paged`).
     Quantized pools pass scales (+ ``kv_quant="int4"`` for nibble
     pages) exactly as `decode_step_paged`.
 
@@ -637,12 +652,8 @@ def verify_step_paged(model, params, tokens, k_pool, v_pool, table,
     att = block.attn
     scale = c.head_dim ** -0.5
 
-    def body(h, xs):
-        if quant:
-            layer_params, kp, vp, ksc, vsc = xs
-        else:
-            layer_params, kp, vp = xs
-            ksc = vsc = None
+    def layer(h, layer_params, pools, l, base):
+        kp, vp, ksc, vsc = pools
         hn = block.input_norm(layer_params["input_norm"], h)
         qkv = jnp.einsum("bsh,hkgd->bskgd", hn,
                          layer_params["attn"]["wqkv"].astype(h.dtype))
@@ -651,18 +662,16 @@ def verify_step_paged(model, params, tokens, k_pool, v_pool, table,
         v = qkv[..., att.group + 1, :]
         q, k = ops.apply_rotary_qk(q, k, cos, sin, qpos)
         with jax.named_scope("kv_write"):
-            if quant:
-                kp, ksc = _paged_write_tokens_q(kp, ksc, table, positions,
-                                                k, bits=bits)
-                vp, vsc = _paged_write_tokens_q(vp, vsc, table, positions,
-                                                v, bits=bits)
-            else:
-                kp = _paged_write_tokens(kp, table, positions, k)
-                vp = _paged_write_tokens(vp, table, positions, v)
+            kp, ksc = _paged_write_tokens(kp, ksc, table, positions, k,
+                                          l, base, bits)
+            vp, vsc = _paged_write_tokens(vp, vsc, table, positions, v,
+                                          l, base, bits)
         with jax.named_scope("pallas_paged_verify"):
-            attn = paged_verify(q, kp, vp, table, positions,
-                                softmax_scale=scale, k_scale=ksc,
-                                v_scale=vsc, quant=kv_quant)
+            ksl, vsl = (ksc[l], vsc[l]) if quant else (None, None)
+            attn = paged_verify(q, kp, vp, table + base, positions,
+                                softmax_scale=scale, k_scale=ksl,
+                                v_scale=vsl, quant=kv_quant,
+                                scale_table=table)
         h = h + att.o_proj(layer_params["attn"]["o_proj"],
                            attn.reshape(S, C, att.n_q * c.head_dim))
         mlp_out = block.mlp(layer_params["mlp"],
@@ -670,15 +679,15 @@ def verify_step_paged(model, params, tokens, k_pool, v_pool, table,
         if isinstance(mlp_out, tuple):  # MoE
             mlp_out = mlp_out[0]
         h = h + mlp_out
-        return h, ((kp, vp, ksc, vsc) if quant else (kp, vp))
+        return h, (kp, vp, ksc, vsc)
 
-    xs = ((mp_["layers"]["layers"], k_pool, v_pool, k_scale, v_scale)
-          if quant else (mp_["layers"]["layers"], k_pool, v_pool))
-    x, pools = lax.scan(body, x, xs)
+    x, pools = _scan_layers_paged(
+        layer, x, mp_["layers"]["layers"],
+        (k_pool, v_pool, k_scale, v_scale))
     hidden = model.model.final_norm(mp_["final_norm"], x)
     if return_hidden:
-        return (hidden,) + tuple(pools)
-    return (model.logits(params, hidden),) + tuple(pools)
+        return (hidden,) + pools
+    return (model.logits(params, hidden),) + pools
 
 
 def _extend_cache_gpt(model, params, tokens, cache, start,

@@ -153,8 +153,8 @@ def _load_page(page_ref, scale_ref, *, quant, ps, n_kv, hd):
 
 def _kernel(*refs, scale, ps, n_kv, group, mp, quant):
     if quant != "none":
-        (table_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-         o_ref, m_scr, l_scr, acc_scr) = refs
+        (table_ref, pos_ref, stable_ref, q_ref, k_ref, v_ref, ks_ref,
+         vs_ref, o_ref, m_scr, l_scr, acc_scr) = refs
     else:
         (table_ref, pos_ref, q_ref, k_ref, v_ref,
          o_ref, m_scr, l_scr, acc_scr) = refs
@@ -210,8 +210,8 @@ def _verify_kernel(*refs, scale, C, ps, n_kv, group, mp, quant):
     accumulator rows are laid out (n_kv, C, group) so the grouped-GQA
     contraction stays a single batched dot per page."""
     if quant != "none":
-        (table_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-         o_ref, m_scr, l_scr, acc_scr) = refs
+        (table_ref, pos_ref, stable_ref, q_ref, k_ref, v_ref, ks_ref,
+         vs_ref, o_ref, m_scr, l_scr, acc_scr) = refs
     else:
         (table_ref, pos_ref, q_ref, k_ref, v_ref,
          o_ref, m_scr, l_scr, acc_scr) = refs
@@ -282,9 +282,32 @@ def _resolve_quant(quant, k_scale, v_scale):
     return quant
 
 
+def _scalar_prefetch(table, positions, scale_table, k_scale, P, ps, n_kv,
+                     quant):
+    """The kernels' scalar-prefetch operands: (table, positions) and,
+    for quantized pages, the page ids the SCALE planes are read by —
+    `scale_table` into planes of their own page count, or `table`
+    itself into planes of the payload's P pages."""
+    scalars = [table.astype(jnp.int32), positions.astype(jnp.int32)]
+    if quant == "none":
+        return scalars
+    if scale_table is None:
+        scale_table = table
+    else:
+        P = k_scale.shape[0]
+    if tuple(k_scale.shape) != (P, ps, n_kv):
+        raise ValueError(f"scales {k_scale.shape} must be "
+                         f"[P={P}, ps={ps}, n_kv={n_kv}]")
+    if scale_table.shape != table.shape:
+        raise ValueError(f"scale_table {scale_table.shape} must match "
+                         f"table {table.shape}")
+    return scalars + [scale_table.astype(jnp.int32)]
+
+
 def paged_attention(q, k_pool, v_pool, table, positions, *,
                     softmax_scale: Optional[float] = None,
-                    k_scale=None, v_scale=None, quant=None):
+                    k_scale=None, v_scale=None, quant=None,
+                    scale_table=None):
     """Decode attention over paged KV.  q: [S, nq, hd] (one token per
     slot); k_pool/v_pool: [P, page_size, n_kv, hd] (page 0 = the null
     page); table: [S, max_pages] int32 page ids; positions: [S] int32 —
@@ -292,39 +315,42 @@ def paged_attention(q, k_pool, v_pool, table, positions, *,
     pass their per-head-vector f32 scales [P, page_size, n_kv] as
     k_scale/v_scale and dequantize in-kernel; int4 pools additionally
     pass ``quant="int4"`` (uint8 nibble payloads, pool head dim hd//2).
+    ``scale_table`` [S, max_pages] gives the scales page ids of their
+    own, for a caller whose scale planes are not laid out like the
+    payload (models/generation hands the kernel every layer's payload
+    pages in one array and one layer's scales; ignored for exact pages).
     Returns [S, nq, hd].  Raises ValueError on shapes outside
     `compatible` (the dense-gather fallback in models/generation
     handles those)."""
     quant = _resolve_quant(quant, k_scale, v_scale)
     S, nq, hd, P, ps, n_kv = check_shapes(
         q.shape, k_pool.shape, table.shape, positions.shape, quant=quant)
-    if quant != "none" and tuple(k_scale.shape) != (P, ps, n_kv):
-        raise ValueError(f"scales {k_scale.shape} must be "
-                         f"[P={P}, ps={ps}, n_kv={n_kv}]")
+    scalars = _scalar_prefetch(table, positions, scale_table, k_scale,
+                               P, ps, n_kv, quant)
     mp = table.shape[1]
     group = nq // n_kv
     scale = softmax_scale if softmax_scale is not None else hd ** -0.5
     hd_p = k_pool.shape[-1]
 
     page_spec = pl.BlockSpec((1, ps, n_kv, hd_p),
-                             lambda s, p, tab, pos: (tab[s, p], 0, 0, 0))
+                             lambda s, p, tab, *_: (tab[s, p], 0, 0, 0))
     in_specs = [
-        pl.BlockSpec((1, nq, hd), lambda s, p, tab, pos: (s, 0, 0)),
+        pl.BlockSpec((1, nq, hd), lambda s, p, *_: (s, 0, 0)),
         page_spec, page_spec,
     ]
     operands = [q, k_pool, v_pool]
     if quant != "none":
         scale_spec = pl.BlockSpec(
-            (1, ps, n_kv), lambda s, p, tab, pos: (tab[s, p], 0, 0))
+            (1, ps, n_kv), lambda s, p, tab, pos, stab: (stab[s, p], 0, 0))
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(S, mp),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, nq, hd),
-                               lambda s, p, tab, pos: (s, 0, 0)),
+                               lambda s, p, *_: (s, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((nq, 1), jnp.float32),
             pltpu.VMEM((nq, 1), jnp.float32),
@@ -339,25 +365,25 @@ def paged_attention(q, k_pool, v_pool, table, positions, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
-    )(table.astype(jnp.int32), positions.astype(jnp.int32), *operands)
+    )(*scalars, *operands)
 
 
 def paged_verify(q, k_pool, v_pool, table, positions, *,
                  softmax_scale: Optional[float] = None,
-                 k_scale=None, v_scale=None, quant=None):
+                 k_scale=None, v_scale=None, quant=None,
+                 scale_table=None):
     """Multi-query verify attention over paged KV (spec decoding).
     q: [S, C, nq, hd] — slot s's C = k+1 query positions sit at global
     positions positions[s]..positions[s]+C-1, each attending causally
-    over the slot's pages; pools/table/scales exactly as
+    over the slot's pages; pools/table/scales/scale_table exactly as
     `paged_attention`.  Returns [S, C, nq, hd].  Raises ValueError on
     shapes outside `verify_compatible` (the gather verify program in
     models/generation handles those)."""
     quant = _resolve_quant(quant, k_scale, v_scale)
     S, C, nq, hd, P, ps, n_kv = check_shapes_verify(
         q.shape, k_pool.shape, table.shape, positions.shape, quant=quant)
-    if quant != "none" and tuple(k_scale.shape) != (P, ps, n_kv):
-        raise ValueError(f"scales {k_scale.shape} must be "
-                         f"[P={P}, ps={ps}, n_kv={n_kv}]")
+    scalars = _scalar_prefetch(table, positions, scale_table, k_scale,
+                               P, ps, n_kv, quant)
     mp = table.shape[1]
     group = nq // n_kv
     scale = softmax_scale if softmax_scale is not None else hd ** -0.5
@@ -365,24 +391,24 @@ def paged_verify(q, k_pool, v_pool, table, positions, *,
     rows = n_kv * C * group
 
     page_spec = pl.BlockSpec((1, ps, n_kv, hd_p),
-                             lambda s, p, tab, pos: (tab[s, p], 0, 0, 0))
+                             lambda s, p, tab, *_: (tab[s, p], 0, 0, 0))
     in_specs = [
-        pl.BlockSpec((1, C, nq, hd), lambda s, p, tab, pos: (s, 0, 0, 0)),
+        pl.BlockSpec((1, C, nq, hd), lambda s, p, *_: (s, 0, 0, 0)),
         page_spec, page_spec,
     ]
     operands = [q, k_pool, v_pool]
     if quant != "none":
         scale_spec = pl.BlockSpec(
-            (1, ps, n_kv), lambda s, p, tab, pos: (tab[s, p], 0, 0))
+            (1, ps, n_kv), lambda s, p, tab, pos, stab: (stab[s, p], 0, 0))
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(S, mp),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, C, nq, hd),
-                               lambda s, p, tab, pos: (s, 0, 0, 0)),
+                               lambda s, p, *_: (s, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((rows, 1), jnp.float32),
             pltpu.VMEM((rows, 1), jnp.float32),
@@ -397,4 +423,4 @@ def paged_verify(q, k_pool, v_pool, table, positions, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
-    )(table.astype(jnp.int32), positions.astype(jnp.int32), *operands)
+    )(*scalars, *operands)

@@ -719,9 +719,13 @@ class ServingEngine:
         # allocation and it flows through every step — without donation
         # XLA would copy the whole pool to update one token per slot
         # (the engine always reassigns self.pool.arrays from the
-        # returned tree, so the donated input is never reused).  With
-        # speculative decoding on, the verify program IS the decode-step
-        # program (there is no single-token decode to build).
+        # returned tree, so the donated input is never reused).  The
+        # paged programs keep that promise inside too: the pool is a
+        # carry of their layer loop, written in place, one buffer from
+        # argument to result (models/generation._scan_layers_paged;
+        # tests/test_chip_compile.py holds the compiled program to it).
+        # With speculative decoding on, the verify program IS the
+        # decode-step program (there is no single-token decode to build).
         rec = self._recording
         if self.spec:
             self._decode_jit = None

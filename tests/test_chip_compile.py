@@ -253,24 +253,33 @@ def test_sharded_train_step_compiles_for_four_v5e_with_every_kernel():
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
+def _serving_engine(cfg=None, num_slots=8, **serve):
+    """The engine `chip_smoke.py` serves with — 8 slots x 2048 positions
+    of 16-token pages, 2 layers at Llama-2-7B widths — over abstract
+    parameters (programs are built lazily, nothing is materialised but
+    the zeroed pool)."""
+    from hetu_tpu.models.llama import LlamaConfig, LlamaLMHeadModel
+    from hetu_tpu.serving.engine import ServeConfig, ServingEngine
+
+    cfg = cfg or LlamaConfig.llama2_7b(num_hidden_layers=2,
+                                       param_dtype=BF16)
+    model = LlamaLMHeadModel(cfg)
+    sc = ServeConfig(num_slots=num_slots, page_size=16, max_len=2048,
+                     prefill_chunk=128, **serve)
+    params = jax.tree.map(lambda a: spec(a.shape, a.dtype),
+                          model.abstract_params())
+    engine = ServingEngine(model, params, sc)
+    assert engine.decode_paged
+    return engine
+
+
 def test_serving_programs_compile_for_one_v5e():
     """The engine's decode step (with the paged-attention kernel walking
     the page tables), prefill chunk and page write, for 8 slots x 2048
     positions at Llama-2-7B widths."""
     from chip_smoke import kernels_in
-    from hetu_tpu.models.llama import LlamaConfig, LlamaLMHeadModel
-    from hetu_tpu.serving.engine import ServeConfig, ServingEngine
 
-    layers = 2
-    cfg = LlamaConfig.llama2_7b(num_hidden_layers=layers, param_dtype=BF16)
-    model = LlamaLMHeadModel(cfg)
-    sc = ServeConfig(num_slots=8, page_size=16, max_len=2048,
-                     prefill_chunk=128)
-    params = jax.tree.map(lambda a: spec(a.shape, a.dtype),
-                          model.abstract_params())
-    engine = ServingEngine(model, params, sc)   # programs are built lazily
-    assert engine.decode_paged
-
+    engine = _serving_engine()
     programs = engine.lower_programs(sharding=ONE_CHIP)
     assert sorted(programs) == ["decode", "prefill_chunk", "write_pages"]
     compiled = {name: low.compile() for name, low in programs.items()}
@@ -279,3 +288,64 @@ def test_serving_programs_compile_for_one_v5e():
     routes = engine.kernel_routes
     assert all(routes[k]["pallas"] and not routes[k]["xla"]
                for k in ("paged_attn", "rotary", "swiglu")), routes
+
+
+@pytest.mark.parametrize("kv_quant", ["none", "int8"])
+def test_decode_program_updates_the_pool_in_place(kv_quant):
+    """The donated KV pool is ONE buffer from the decode program's
+    argument to its result (PR 25).  As an xs -> ys of the layer scan it
+    was sliced, stacked and copied whole three times a step (24 ms of a
+    56 ms decode step, and a second pool in memory: PERF.md s6); this is
+    the guard that keeps those copies from coming back:
+
+      * the program's temporaries are under a quarter of the pool — for
+        the model and the pool of the benchmark's serving cells
+        (InternLM2-1.8B, 32 slots, 2048 pages: 3.2 GB of bf16 pages).
+        At Llama-2-7B widths a layer's weights sliced out of the stack
+        (ROADMAP queue 1, 2b) are 450 MB of temporaries by themselves,
+        and which layout the compiler keeps the scale planes in changes
+        with the shape, so the guard is on the shape that is served;
+      * no copy, dynamic-slice or dynamic-update-slice — by opcode or by
+        the name the compiler gives the fusion around one — yields a
+        whole pool array (as stored, or as the flat view the scan
+        carries) or one layer's slab of page payload.  One layer's SCALE
+        plane (int8 pages) is still sliced and converted for the kernel,
+        which reads scales in a lane-padded layout: 1 MB a layer, and
+        what `_scan_layers_paged` says it does."""
+    import re
+    from hetu_tpu.models.llama import LlamaConfig
+    cfg = LlamaConfig(vocab_size=92544, hidden_size=2048,
+                      intermediate_size=8192, num_hidden_layers=24,
+                      num_attention_heads=16, num_key_value_heads=8,
+                      max_position_embeddings=32768, rope_theta=1e6,
+                      param_dtype=BF16)
+    engine = _serving_engine(cfg, num_slots=32, num_pages=2048,
+                             kv_quant=kv_quant)
+    pool = list(engine.pool.arrays.tree())
+    compiled = engine.lower_programs(sharding=ONE_CHIP)["decode"].compile()
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in pool)
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 4
+
+    def dims(shape):
+        return ",".join(map(str, shape))
+    banned = set()
+    for a in pool:
+        L, P = a.shape[:2]
+        banned |= {dims(a.shape), dims((L * P,) + a.shape[2:])}
+        if a.ndim == 5:     # page payload: a layer's slab too
+            banned |= {dims(a.shape[1:]), dims((1,) + a.shape[1:])}
+    moves = re.compile(r"copy|dynamic[-_]slice|dynamic[-_]update[-_]slice")
+    inst = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = \(?\w+\[([\d,]*)\]"
+                      r"[^ ]* ([\w\-]+)\(")
+    found, scatters = [], 0
+    for line in compiled.as_text().splitlines():
+        m = inst.match(line)
+        if not m:
+            continue
+        name, shape, opcode = m.groups()
+        if shape in banned and moves.search(name + " " + opcode):
+            found.append(f"{name} [{shape}] {opcode}")
+        scatters += opcode == "scatter" and shape in banned
+    assert not found, found
+    # ... and the token IS written there: one in-place scatter per array
+    assert scatters == len(pool), scatters

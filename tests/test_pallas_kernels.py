@@ -899,6 +899,128 @@ def test_serving_paged_decode_token_identical(monkeypatch):
     assert [r.tokens for r in r2] == [r.tokens for r in r3]
 
 
+def _paged_step_case(family, quant):
+    """A 3-layer model with 128-wide heads, a pool of 6 pages of 8 tokens
+    (+ the null page) filled with random content, and four slots: one
+    inactive (zeroed table row), one whose verify block crosses a page."""
+    from hetu_tpu.serving.kv_pool import PagePool
+    if family == "llama":
+        model, params = _tiny_llama(hd128=True, num_hidden_layers=3)
+        n_kv = model.config.num_key_value_heads
+    else:
+        from hetu_tpu.models.gpt.model import GPTConfig, GPTLMHeadModel
+        model = GPTLMHeadModel(GPTConfig.tiny(
+            hidden_size=256, num_attention_heads=2, num_hidden_layers=3,
+            compute_dtype=jnp.float32, param_dtype=jnp.float32,
+            remat=False, use_flash_attention=False))
+        params = model.init(jax.random.key(0))
+        n_kv = model.config.num_attention_heads
+    pool = PagePool(num_layers=3, num_pages=6, page_size=8,
+                    num_kv_heads=n_kv, head_dim=model.config.head_dim,
+                    dtype=jnp.float32, quant=quant)
+    rng = np.random.default_rng(5)
+    a = pool.arrays
+    if quant == "none":
+        tree = tuple(_rand(a.k.shape, seed) for seed in (1, 2))
+    else:
+        lo, hi, dt = ((-127, 128, np.int8) if quant == "int8"
+                      else (0, 256, np.uint8))
+        tree = tuple(jnp.asarray(rng.integers(lo, hi, a.k.shape).astype(dt))
+                     for _ in range(2)) + tuple(
+            jnp.asarray(rng.uniform(0.005, 0.02, a.k_scale.shape)
+                        .astype(np.float32)) for _ in range(2))
+    table = jnp.asarray([[3, 5, 0, 0], [1, 0, 0, 0], [0, 0, 0, 0],
+                         [2, 4, 6, 0]], jnp.int32)
+    return model, params, pool, tree, table
+
+
+@pytest.mark.parametrize("step", ["decode", "verify"])
+@pytest.mark.parametrize("quant", ["none", "int8", "int4"])
+@pytest.mark.parametrize("family", ["llama", "gpt"])
+def test_paged_step_writes_the_pool_in_place(family, quant, step):
+    """One `decode_step_paged` / `verify_step_paged` (the pool a loop
+    carry, layer l's pages at flat ids l * P + p) against the gather
+    path (`decode_step_slots` / `verify_step_slots` over `pool.gather`,
+    then `pool.write_token` / `write_tokens`):
+
+      * every pool entry but the written (layer, page, offset) ones is
+        untouched, BIT FOR BIT, payload and scale planes alike — a wrong
+        l * P lands a layer's token in another layer's page;
+      * layer 0's written payload is bit-identical to the gather path's
+        (nothing upstream of it but the embedding); deeper layers sit
+        downstream of an attention the two paths compute differently
+        (the kernel's online softmax; a quantized token that attends to
+        its own dequantized K/V, where the gather path sees it exact),
+        so they agree to rounding — 1e-5 exact, two quantization steps
+        int8 / int4;
+      * the inactive slot's writes land in EVERY layer's own null page
+        (for the verify block also through the out-of-reach redirect);
+      * logits of the active slots match."""
+    from hetu_tpu.models import generation as G
+    from hetu_tpu.serving.kv_pool import dequantize_heads
+    model, params, pool, tree, table = _paged_step_case(family, quant)
+    L, ps, mp = 3, 8, table.shape[1]
+    kw = ({} if quant == "none" else
+          dict(k_scale=tree[2], v_scale=tree[3], kv_quant=quant))
+    ck, cv = pool.gather(tree, table)
+    if step == "decode":
+        positions = jnp.asarray([11, 7, 0, 16], jnp.int32)
+        tokens = jnp.asarray([5, 9, 0, 77], jnp.int32)
+        pos_grid = np.asarray(positions)[:, None]
+        ref_logits, _, (kt, vt) = G.decode_step_slots(
+            model, params, tokens, (ck, cv), positions)
+        ref = pool.write_token(tree, table, positions, kt, vt)
+        logits, *new = G.decode_step_paged(
+            model, params, tokens, tree[0], tree[1], table, positions, **kw)
+    else:
+        # slot 0's block crosses into its second page; the inactive
+        # slot's runs past the table's reach (positions 30, 31 | 32)
+        positions = jnp.asarray([6, 2, 30, 18], jnp.int32)
+        tokens = jnp.asarray(
+            np.random.default_rng(9).integers(1, 250, (4, 3)), jnp.int32)
+        pos_grid = np.asarray(positions)[:, None] + np.arange(3)[None, :]
+        ref_logits, _, (kt, vt) = G.verify_step_slots(
+            model, params, tokens, (ck, cv), positions)
+        ref = pool.write_tokens(tree, table, jnp.asarray(pos_grid), kt, vt)
+        logits, *new = G.verify_step_paged(
+            model, params, tokens, tree[0], tree[1], table, positions, **kw)
+    assert len(new) == len(tree)
+    new, ref, old = ([np.asarray(x) for x in t] for t in (new, ref, tree))
+
+    # where a step writes: [P + 1, ps], the same in every layer
+    pidx = pos_grid // ps
+    page = np.where(pidx < mp,
+                    np.asarray(table)[np.arange(4)[:, None],
+                                      np.clip(pidx, 0, mp - 1)], 0)
+    written = np.zeros(old[0].shape[1:3], bool)
+    written[page, pos_grid % ps] = True
+    live, null = written.copy(), written.copy()
+    live[0], null[1:] = False, False
+    assert null.any() and live.sum() == 3 * pos_grid.shape[1]
+
+    for n, o in zip(new, old):
+        assert n.shape == o.shape and n.dtype == o.dtype
+        np.testing.assert_array_equal(n[:, ~written], o[:, ~written])
+        for l in range(L):    # each layer's OWN null page took the write
+            assert (n[l][null] != o[l][null]).any(), l
+    for a in (0, 1):
+        np.testing.assert_array_equal(new[a][0][live], ref[a][0][live])
+        if quant == "none":
+            got, want, tol = new[a], ref[a], 1e-5
+        else:
+            bits = 4 if quant == "int4" else 8
+            got, want = (np.asarray(dequantize_heads(
+                jnp.asarray(t[a]), jnp.asarray(t[a + 2]), bits))
+                for t in (new, ref))
+            tol = 2.0 * ref[a + 2][:, live].max()
+        np.testing.assert_allclose(got[:, live], want[:, live],
+                                   rtol=0, atol=tol)
+    active = np.asarray([0, 1, 3])
+    np.testing.assert_allclose(
+        np.asarray(logits)[active], np.asarray(ref_logits)[active], rtol=0,
+        atol={"none": 1e-5, "int8": 2e-2, "int4": 0.5}[quant])
+
+
 # ---------------------------------------------------------------------------
 # shared int4 nibble packer (satellite 1)
 # ---------------------------------------------------------------------------

@@ -1,14 +1,12 @@
-"""The yardstick's constants and counts: device peaks, and the operations
-and bytes that the benchmark's utilization and roofline shares divide by.
+"""The yardstick's constants: device peaks, and how a family's counts
+(`benchmarks/families/<family>.py`: its weights, widths and kernel cost
+functions) become the operations a utilization or a roofline share
+divides by.
 
-Nothing here is measured.  A later PR may not edit this file, so the
-numerators of `model_flops_util`, `flash_attn_roofline` and
-`chat.paged_attn_roofline` cannot move with the code they judge.
-
-The byte counts follow `hetu_tpu/ops/pallas/traffic.py`'s *fused* path (one
-read of each input, one write of each output); its `paged_attn_traffic`
-prices the whole page table, this file prices the tokens a step's slots
-really hold, which is what the kernel has to read.
+Nothing here is measured.  A later PR may not edit this file or a family
+module that is there, so the numerators of `model_flops_util`,
+`flash_attn_roofline` and `chat.paged_attn_roofline` cannot move with the
+code they judge.
 """
 from __future__ import annotations
 
@@ -33,75 +31,16 @@ def peaks_for(device_kind: str) -> dict:
     return PEAKS[device_kind]
 
 
-def matmul_params(cfg: dict) -> int:
-    """Weights that take part in a matrix multiplication per token: the
-    attention projections, the SwiGLU MLP and the untied head.  The
-    embedding is a lookup and the norm gains are elementwise."""
-    h, i = cfg["hidden_size"], cfg["intermediate_size"]
-    hd = cfg.get("head_dim") or h // cfg["num_attention_heads"]
-    q = cfg["num_attention_heads"] * hd
-    kv = cfg["num_key_value_heads"] * hd
-    per_layer = h * (q + 2 * kv) + q * h + 3 * h * i
-    return cfg["num_hidden_layers"] * per_layer + h * cfg["vocab_size"]
-
-
-def total_params(cfg: dict) -> int:
-    h = cfg["hidden_size"]
-    return (matmul_params(cfg) + cfg["vocab_size"] * h
-            + (2 * cfg["num_hidden_layers"] + 1) * h)
-
-
-def train_flops_per_token(cfg: dict, seq: int) -> float:
+def train_flops_per_token(counts: dict, seq: int) -> float:
     """Operations the forward and backward passes REQUIRE per trained
-    token: 6 per matmul weight, plus causal attention (QK^T and PV see on
-    average seq/2 keys: 2 matmuls x 2 x seq/2 x q-width forward, twice that
-    backward).  Recomputation under remat and the flash kernel's own
-    recomputed scores are not counted."""
-    hd = cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]
-    q = cfg["num_attention_heads"] * hd
-    attn = 6.0 * cfg["num_hidden_layers"] * seq * q
-    return 6.0 * matmul_params(cfg) + attn
-
-
-def flash_attn_cost(cfg: dict, *, batch: int, seq: int, shards: int = 1,
-                    elem_bytes: float = 2.0) -> dict:
-    """Required operations and bytes of causal flash attention, forward
-    and backward, for ONE train step on ONE chip (`shards` chips share the
-    batch x heads evenly).  Forward: 2 matmuls over the causal half.
-    Backward: 4 (dV, dP, dQ, dK); the kernel's recomputed QK^T is not
-    required work.  Bytes: forward reads q,k,v and writes o and the f32
-    row statistics; backward reads q,k,v,o,do and the statistics and
-    writes dq,dk,dv."""
-    hd = cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]
-    nq, nkv, L = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                  cfg["num_hidden_layers"])
-    matmul = 2.0 * batch * nq * seq * seq * hd / 2.0      # one, causal
-    ops = L * 6.0 * matmul
-    q_io = elem_bytes * batch * seq * nq * hd
-    kv_io = elem_bytes * batch * seq * nkv * hd
-    lse = 4.0 * batch * nq * seq
-    fwd = q_io + 2 * kv_io + q_io + lse
-    bwd = (3 * q_io + 2 * kv_io + lse) + (q_io + 2 * kv_io)
-    return {"ops": ops / shards, "bytes": L * (fwd + bwd) / shards}
-
-
-def paged_attn_cost(cfg: dict, *, context_tokens: int, queries: int,
-                    elem_bytes: float = 2.0) -> dict:
-    """Required operations and bytes of paged decode attention over all
-    layers, for `queries` single-token queries whose contexts hold
-    `context_tokens` cached positions in total: every cached K and V
-    vector is read once, q is read and o written."""
-    hd = cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]
-    nq, nkv, L = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                  cfg["num_hidden_layers"])
-    ops = L * 2.0 * 2.0 * context_tokens * nq * hd
-    bytes_ = L * elem_bytes * (2.0 * context_tokens * nkv * hd
-                               + 2.0 * queries * nq * hd)
-    return {"ops": ops, "bytes": bytes_}
-
-
-COST_FUNCTIONS = {"flash_attn_cost": flash_attn_cost,
-                  "paged_attn_cost": paged_attn_cost}
+    token, from a family's `counts(cfg)`: 6 per weight that takes part in
+    a matmul for that token (`matmul_params`: for an expert model the
+    active ones), plus causal attention (QK^T and PV see on average seq/2
+    keys: 2 matmuls x 2 x seq/2 x q-width forward, twice that backward;
+    `attn_width` is the q-width summed over the layers).  Recomputation
+    under remat and the flash kernel's own recomputed scores are not
+    counted."""
+    return 6.0 * counts["matmul_params"] + 6.0 * seq * counts["attn_width"]
 
 
 def roofline_seconds(cost: dict, peaks: dict) -> dict:

@@ -1,20 +1,11 @@
-"""The plain reference both configurations are held to, and the comparisons
-that decide `correct`.
+"""The comparisons that decide `correct`, and their tolerances.
 
-One decoder block family (what `models/llama` implements and both
-configuration files describe): pre-norm RMSNorm, grouped-query attention
-with rotary embeddings (half-split rotation, as Hugging Face's Llama,
-Mistral and InternLM2 code), SwiGLU MLP, final RMSNorm, untied head.
-Written in straightforward float32 `jax.numpy` under
-`default_matmul_precision("highest")`: no kernel, no cache, no batching
-tricks.  (Under `jax.grad` a layer is recomputed in the backward pass, so
-that one layer's intermediates are held and not all of them.)  It reads the program's parameter tree (the weights are
-the system's own, made from the seed) and nothing else of the program.
-
-InternLM2 publishes one fused `wqkv`; the program keeps a fused
-`[hidden, kv_heads, group + 2, head_dim]` weight as well; both are the
-same equations as separate q, k and v projections, which is how they are
-applied here.
+They are written against a family's plain float32 forward of one sequence
+(`benchmarks/families/<family>.py`'s `logits_at(params, ids, rows, cfg)`,
+passed in as `forward`): the comparisons and their limits do not depend
+on the block, the forward they call does.  A forward reads the program's
+parameter tree (the weights are the system's own, made from the seed) and
+nothing else of the program.
 """
 from __future__ import annotations
 
@@ -25,70 +16,6 @@ import jax.numpy as jnp
 import numpy as np
 
 F32 = jnp.float32
-
-
-def _rms_norm(x, gain, eps):
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * gain.astype(F32)
-
-
-def _rope(x, theta):
-    """x [s, heads, hd]; positions 0..s-1; half-split rotation."""
-    s, _, hd = x.shape
-    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=F32) / hd))
-    ang = jnp.outer(jnp.arange(s, dtype=F32), inv)        # [s, hd/2]
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., : hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _block(x, lp, cfg):
-    """One decoder layer on one sequence x [s, hidden] (float32)."""
-    eps, theta = cfg["rms_norm_eps"], cfg["rope_theta"]
-    nq, nkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    group = nq // nkv
-    s = x.shape[0]
-    wqkv = lp["attn"]["wqkv"].astype(F32)       # [h, nkv, group + 2, hd]
-    hd = wqkv.shape[-1]
-    h = _rms_norm(x, lp["input_norm"]["weight"], eps)
-    qkv = jnp.einsum("sh,hkgd->skgd", h, wqkv)
-    q = qkv[:, :, :group, :].reshape(s, nq, hd)   # q head = kv * group + g
-    k, v = qkv[:, :, group, :], qkv[:, :, group + 1, :]
-    q, k = _rope(q, theta), _rope(k, theta)
-    k = jnp.repeat(k, group, axis=1)              # each q head's kv head
-    v = jnp.repeat(v, group, axis=1)
-    scores = jnp.einsum("qnd,knd->nqk", q, k) / math.sqrt(hd)
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    scores = jnp.where(causal[None], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    attn = jnp.einsum("nqk,knd->qnd", probs, v).reshape(s, nq * hd)
-    x = x + attn @ lp["attn"]["o_proj"]["weight"].astype(F32)
-    h = _rms_norm(x, lp["post_norm"]["weight"], eps)
-    gu = jnp.einsum("sh,hci->sci", h, lp["mlp"]["w_gate_up"].astype(F32))
-    act = jax.nn.silu(gu[:, 0, :]) * gu[:, 1, :]
-    return x + act @ lp["mlp"]["down_proj"]["weight"].astype(F32)
-
-
-def hidden_states(params, ids, cfg):
-    """Final-norm hidden states [s, hidden] of one sequence `ids` [s].
-    Layers are walked with `lax.scan` over the stacked weights so that
-    only one layer is ever held in float32."""
-    with jax.default_matmul_precision("highest"):
-        m = params["model"]
-        x = m["embed"]["weight"][ids].astype(F32)
-
-        @jax.checkpoint
-        def body(x, lp):
-            return _block(x, lp, cfg), None
-        x, _ = jax.lax.scan(body, x, m["layers"]["layers"])
-        return _rms_norm(x, m["final_norm"]["weight"], cfg["rms_norm_eps"])
-
-
-def logits_at(params, ids, rows, cfg):
-    """Reference logits [len(rows), vocab] at the positions `rows`."""
-    with jax.default_matmul_precision("highest"):
-        hid = hidden_states(params, ids, cfg)[rows]
-        return hid @ params["lm_head"].astype(F32)
 
 
 # ---------------------------------------------------------------------------
@@ -157,8 +84,8 @@ def logit_gap_tolerance(max_logit: float) -> float:
     return 16.0 * 2.0 ** (math.floor(math.log2(max(abs(max_logit), 1e-6))) - 7)
 
 
-def check_stream(params, cfg, prompt, tokens, pad_to: int) -> dict:
-    """Hold one served greedy stream to the reference: forward over
+def check_stream(forward, params, cfg, prompt, tokens, pad_to: int) -> dict:
+    """Hold one served greedy stream to the reference `forward`: it runs over
     prompt + tokens[:-1] (right-padded to `pad_to`; causal, so the pad is
     inert) and compare at each generated position."""
     plen, n = len(prompt), len(tokens)
@@ -166,8 +93,8 @@ def check_stream(params, cfg, prompt, tokens, pad_to: int) -> dict:
     stream[:plen] = prompt
     stream[plen: plen + n - 1] = tokens[:-1]
     rows = np.arange(plen - 1, plen - 1 + n)
-    lg = np.asarray(_logits_jit(cfg)(params, jnp.asarray(stream),
-                                     jnp.asarray(_pad_rows(rows))))
+    lg = np.asarray(_logits_jit(forward, cfg)(
+        params, jnp.asarray(stream), jnp.asarray(_pad_rows(rows))))
     lg = lg[:n]
     top = lg.max(axis=-1)
     gaps = top - lg[np.arange(n), np.asarray(tokens)]
@@ -198,21 +125,21 @@ ROW_PAD = 384
 _JITS = {}
 
 
-def _logits_jit(cfg):
-    key = tuple(sorted((k, v) for k, v in cfg.items()
-                       if isinstance(v, (int, float))))
+def _logits_jit(forward, cfg):
+    key = (forward,) + tuple(sorted((k, v) for k, v in cfg.items()
+                                    if isinstance(v, (int, float))))
     if key not in _JITS:
-        _JITS[key] = jax.jit(lambda p, ids, rows: logits_at(p, ids, rows, cfg))
+        _JITS[key] = jax.jit(lambda p, ids, rows: forward(p, ids, rows, cfg))
     return _JITS[key]
 
 
-def check_training(params, cfg, ids, system_logits) -> dict:
+def check_training(forward, params, cfg, ids, system_logits) -> dict:
     """Hold the system's forward on one sequence `ids` [s] to the
-    reference: its logits [s, vocab] (any float dtype) and the loss they
-    give."""
+    reference `forward`: its logits [s, vocab] (any float dtype) and the
+    loss they give."""
     ids = jnp.asarray(ids)
     n = ids.shape[0]
-    ref = jax.jit(lambda p, i: logits_at(p, i, jnp.arange(n), cfg))(params, ids)
+    ref = jax.jit(lambda p, i: forward(p, i, jnp.arange(n), cfg))(params, ids)
 
     def loss_of(lg):
         lg = lg[:-1].astype(F32)
@@ -230,11 +157,11 @@ def check_training(params, cfg, ids, system_logits) -> dict:
             "loss_rtol": LOSS_RTOL, "logit_rms_rtol": LOGIT_RMS_RTOL}
 
 
-def _loss_and_grad_norm(params, ids, cfg):
+def _loss_and_grad_norm(forward, params, ids, cfg):
     rows = jnp.arange(ids.shape[1] - 1)
 
     def seq_loss(p, seq):
-        lg = logits_at(p, seq, rows, cfg)
+        lg = forward(p, seq, rows, cfg)
         lse = jax.nn.logsumexp(lg, axis=-1)
         return jnp.mean(lse - jnp.take_along_axis(lg, seq[1:, None], -1)[:, 0])
 
@@ -246,11 +173,12 @@ def _loss_and_grad_norm(params, ids, cfg):
     return loss, jnp.sqrt(jnp.sum(jnp.stack(sq)))
 
 
-def loss_and_grad_norm(params, cfg, ids) -> dict:
+def loss_and_grad_norm(forward, params, cfg, ids) -> dict:
     """Mean next-token loss over the sequences `ids` [b, s] (every sequence
     the same weight) and the global norm of its gradient with respect to
-    every parameter, by `jax.grad` of the reference."""
-    loss, gnorm = jax.jit(lambda p, i: _loss_and_grad_norm(p, i, cfg))(
+    every parameter, by `jax.grad` of the reference `forward`."""
+    loss, gnorm = jax.jit(
+        lambda p, i: _loss_and_grad_norm(forward, p, i, cfg))(
         params, jnp.asarray(ids))
     return {"loss": float(loss), "grad_norm": float(gnorm)}
 

@@ -12,7 +12,16 @@ standard output.  Earlier lines (also JSON) carry the set-up phases, the
 kernel routes, `check_s` and the path of the file the run's readings went
 to.  With `--trace 0` the metrics are the cell's end-to-end metrics, with
 `--trace 1` its per-layer metrics, read from a profiler trace of the last
-seconds of the window and from the loop's own counters.
+seconds of the window, from the loop's own counts and from the
+differences of the program's `MetricsRegistry` over the window; the
+`summary` line (and the readings file) carry what the program says of
+itself beside them: each step phase's largest duration, the slowest
+engine step's record and, in a traced run, where in the sync spans the
+device's idle lies and which phase launched the one-operation programs.
+
+Everything the runner knows about a model architecture comes from the
+module `benchmarks/families/<family>.py` that the configuration file
+names: the program's model, the plain reference's forward, the counts.
 
 There is no CPU fallback: without a TPU, with a device kind that
 `peaks.py` does not list, or with fewer chips than the cell asks for, the
@@ -30,6 +39,7 @@ T_PROCESS_START = time.perf_counter()
 
 import argparse        # noqa: E402
 import gc              # noqa: E402
+import importlib       # noqa: E402
 import json            # noqa: E402
 import math            # noqa: E402
 import os              # noqa: E402
@@ -73,9 +83,12 @@ def load_cell(benchmark_file: str, workload: str) -> dict:
     with open(os.path.join(ROOT, configs[cell["config"]]["file"])) as f:
         config = json.load(f)
 
+    family = importlib.import_module(
+        "benchmarks.families." + config["family"])
+
     def reported_here(metric):
         return workload in metric.get("workloads", [workload])
-    return {"cell": cell, "config": config,
+    return {"cell": cell, "config": config, "family": family,
             "traffic": traffic_mod.load_traffic(cell["traffic"]),
             "end_to_end": [m for m in bench["end_to_end"] if reported_here(m)],
             "per_layer": [m for m in bench["per_layer"] if reported_here(m)]}
@@ -83,25 +96,6 @@ def load_cell(benchmark_file: str, workload: str) -> dict:
 
 def metric_spec(name: str) -> dict:
     return traffic_mod.load_json("metrics", name)
-
-
-def llama_config(config: dict, traffic: dict):
-    """The program's `LlamaConfig` from the configuration file's published
-    keys; dtype and remat policy are the traffic's (how the job is run,
-    not what the model is)."""
-    import jax.numpy as jnp
-    from hetu_tpu.models.llama import LlamaConfig
-    keys = ("vocab_size", "hidden_size", "intermediate_size",
-            "num_hidden_layers", "num_attention_heads",
-            "num_key_value_heads", "max_position_embeddings",
-            "rms_norm_eps", "rope_theta", "tie_word_embeddings")
-    kw = {k: config[k] for k in keys}
-    hd = config.get("head_dim")
-    if hd and hd * config["num_attention_heads"] != config["hidden_size"]:
-        raise Refused("models/llama derives head_dim from hidden / heads")
-    return LlamaConfig(
-        param_dtype=jnp.dtype(traffic.get("param_dtype", "bfloat16")),
-        remat_policy=traffic.get("remat_policy", "nothing"), **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -209,6 +203,35 @@ def span(name):
     return jax.profiler.TraceAnnotation(name)
 
 
+def counters_now(registry) -> dict:
+    """Every counter of the program's registry, flat, as of now.  Taken
+    where the window and its traced part begin and end (a few
+    milliseconds each: the snapshot summarises the histograms too; the
+    loops take it just outside the traced part)."""
+    return trace_mod.counter_values(registry.snapshot())
+
+
+def program_readings(registry, phase_family, tracer, tr, tr_window) -> dict:
+    """What the program says of itself that is no metric: the largest
+    duration any step spent in each phase (the whole run's, warm-up
+    included) and, from a traced run, where in the two sync spans the
+    device's idle lies and which phase launched which one-operation
+    program."""
+    out = {"phase_max_ms": {
+        h["labels"]["phase"]: 1e3 * h["max"]
+        for h in registry.snapshot()["histograms"]
+        if h["name"] == phase_family and h.get("max") is not None}}
+    if tr is not None and tr_window:
+        out["sync_idle_position_ms"] = {
+            name: trace_mod.idle_position_ms(tr, tr_window, name)
+            for name in trace_mod.SYNC_SPANS}
+        path = trace_mod.find_xplane(tracer.dir)
+        if path:
+            out["eager_dispatches"] = trace_mod.eager_dispatches(
+                trace_mod.read_host_events(path), tr_window)
+    return out
+
+
 # ---------------------------------------------------------------------------
 # training
 # ---------------------------------------------------------------------------
@@ -218,11 +241,11 @@ def run_train(cell, args, dev, tracer, compiles, phases):
     from hetu_tpu.core.mesh import MeshConfig
     from hetu_tpu.engine.trainer import Trainer
     from hetu_tpu.engine.trainer_config import TrainingConfig
-    from hetu_tpu.models.llama import LlamaLMHeadModel
+    from hetu_tpu.obs.metrics import get_registry
     from hetu_tpu.parallel import ParallelStrategy
 
     phases["program_import_s"] = time.perf_counter() - phases.pop("_t_loop")
-    config, tf = cell["config"], cell["traffic"]
+    config, tf, family = cell["config"], cell["traffic"], cell["family"]
     chips = cell["cell"]["chips"]
     mesh = tf.get("mesh", {})
     dp, tp = int(mesh.get("dp", 1)), int(mesh.get("tp", 1))
@@ -232,8 +255,7 @@ def run_train(cell, args, dev, tracer, compiles, phases):
         mesh=MeshConfig(dp=dp, tp=tp),
         sequence_parallel=bool(tf.get("sequence_parallel", False)),
         zero=bool(tf.get("zero", False))) if chips > 1 else ParallelStrategy())
-    lcfg = llama_config(config, tf)
-    model = LlamaLMHeadModel(lcfg, strategy)
+    model = family.build_model(config, tf, strategy)
     B, S = int(tf["global_batch"]), int(tf["seq_len"])
     tc = TrainingConfig(
         global_batch_size=B, micro_batch_size=int(tf["micro_batch"]),
@@ -242,12 +264,13 @@ def run_train(cell, args, dev, tracer, compiles, phases):
         grad_clip=float(tf["grad_clip"]),
         seed=traffic_mod.jax_seed(args.seed), log_every=10 ** 9)
     trainer = Trainer(model, tc, strategy)
+    registry = get_registry()       # the process's, which the Trainer uses
     t0 = time.perf_counter()
     trainer.build()
     jax.block_until_ready((trainer.params, trainer.opt_state))
     phases["build_s"] = time.perf_counter() - t0
 
-    batches = traffic_mod.train_batches(tf, args.seed, lcfg.vocab_size)
+    batches = traffic_mod.train_batches(tf, args.seed, config["vocab_size"])
 
     def dispatch():
         with span("feed_batch"):
@@ -282,6 +305,7 @@ def run_train(cell, args, dev, tracer, compiles, phases):
     nxt = dispatch()
     completions.append(wait(in_flight))       # the window starts here
     compiles.armed = True
+    marks = {"window_start": counters_now(registry)}
     losses.append(in_flight)
     in_flight = nxt
     t_begin = completions[0]
@@ -293,7 +317,9 @@ def run_train(cell, args, dev, tracer, compiles, phases):
                            float(tf.get("trace_s", 4.0)))
         if tracer.started and started_at is None:
             started_at = len(completions)
-        if started_at is not None and len(completions) >= started_at + 2:
+        if started_at is not None and len(completions) >= started_at + 2 \
+                and "trace_start" not in marks:
+            marks["trace_start"] = counters_now(registry)
             tracer.mark_start()      # two steps on: the pipeline is full
         nxt = dispatch()
         completions.append(wait(in_flight))
@@ -301,6 +327,7 @@ def run_train(cell, args, dev, tracer, compiles, phases):
         in_flight = nxt
     tracer.mark_end()
     compiles.armed = False
+    marks["window_end"] = counters_now(registry)
     wait(in_flight)                          # the step beyond the window
     tr, tr_window = tracer.stop()
 
@@ -317,7 +344,11 @@ def run_train(cell, args, dev, tracer, compiles, phases):
     rep = trainer.memory_report(hb)      # an AOT compile: a cache hit
     mem = peak_memory(list(trainer.mesh.devices.flat),
                       rep["argument_size"] + rep["temp_size"])
-    check = check_training(trainer, lcfg, config, tf, args.seed)
+    # the text of the step program `memory_report` compiled, for the
+    # scope join; taken after the window and on traced runs only
+    hlo_texts = ([trainer.lowered_step(hb, optimized=True)]
+                 if args.trace else [])
+    check = check_training(trainer, family, config, tf, args.seed)
     check_s = time.perf_counter() - t0
     failed = sum(not math.isfinite(x) for x in loss_values)
     correct = bool(check["ok"] and failed == 0)
@@ -333,30 +364,40 @@ def run_train(cell, args, dev, tracer, compiles, phases):
     }
     if dev["platform"] == "tpu":
         counters["model_flops_util"] = (
-            100.0 * peaks.train_flops_per_token(config, S)
+            100.0 * peaks.train_flops_per_token(family.counts(config), S)
             * tokens_per_s_chip
             / peaks.peaks_for(dev["kind"])["flops_per_s"])
     # main() cuts the traced window to whole executions of the step
-    # program and multiplies the per-step cost by their number
+    # program; their number is the `steps` of the window's counts
     ctx = {"snap_to_module": "train_step", "counters": counters,
-           "config": config, "cost_args": {"flash_attn_cost": {
-               "batch": B, "seq": S, "shards": chips}}}
+           "registry": trace_mod.counter_diff(marks["window_start"],
+                                              marks["window_end"]),
+           "hlo_texts": hlo_texts,
+           "window_counts": {
+               "batch": B, "seq": S, "shards": chips,
+               "counters": trace_mod.counter_diff(
+                   marks.get("trace_start", marks["window_end"]),
+                   marks["window_end"])}}
     trainer.close()
+    inside = dict(program_readings(registry, "trainer.step_phase_s", tracer,
+                                   tr, tr_window),
+                  end_to_end={"train_tokens_per_s_chip": tokens_per_s_chip})
+    readings["inside"] = inside
     return {
         "setup_s": setup_s, "attempted": steps,
         "failed": failed, "correct": correct, "check": check,
         "check_s": check_s, "memory": mem, "readings": readings,
         "end_to_end": {"train_tokens_per_s_chip": tokens_per_s_chip},
         "ctx": ctx, "trace": tr, "trace_window": tr_window,
-        "summary": {"steps": steps, "window_steps_s": stats["span_s"],
-                    "median_step_s": stats["median_s"],
-                    "stall_pct": stats["stall_pct"],
-                    "first_loss": loss_values[0],
-                    "last_loss": loss_values[-1]},
+        "summary": dict({"steps": steps, "window_steps_s": stats["span_s"],
+                         "median_step_s": stats["median_s"],
+                         "stall_pct": stats["stall_pct"],
+                         "first_loss": loss_values[0],
+                         "last_loss": loss_values[-1]}, **inside),
     }
 
 
-def check_training(trainer, lcfg, config, tf, seed):
+def check_training(trainer, family, config, tf, seed):
     """Two comparisons with the float32 reference, which runs on device 0
     with the trainer's own weights gathered there.  (1) At the weights the
     window left: the forward of the model the trainer built (its sharded
@@ -387,7 +428,8 @@ def check_training(trainer, lcfg, config, tf, seed):
     n = int(tf.get("check_seq", 1024))
     dp = max(trainer.strategy.dp, 1)
     rng = traffic_mod.rng_for(seed, "check")
-    ids = rng.integers(0, lcfg.vocab_size, size=(dp, n), dtype=np.int32)
+    vocab, forward = config["vocab_size"], family.logits_at
+    ids = rng.integers(0, vocab, size=(dp, n), dtype=np.int32)
     mesh = trainer.mesh
     spec = P("dp", None) if dp > 1 else P()
     first = SingleDeviceSharding(list(mesh.devices.flat)[0])
@@ -398,7 +440,8 @@ def check_training(trainer, lcfg, config, tf, seed):
         sys_logits = jax.device_put(logits[0], first)
     del logits
     params0 = jax.device_put(trainer.params, first)
-    out = reference.check_training(params0, config, ids[0], sys_logits)
+    out = reference.check_training(forward, params0, config, ids[0],
+                                   sys_logits)
     del sys_logits, params0
     out["sequence_tokens"] = n
 
@@ -406,11 +449,12 @@ def check_training(trainer, lcfg, config, tf, seed):
     trainer.build()
     params0 = jax.device_put(trainer.params, first)
     B, S = int(tf["global_batch"]), int(tf["seq_len"])
-    batch = rng.integers(0, lcfg.vocab_size, size=(B, S), dtype=np.int32)
+    batch = rng.integers(0, vocab, size=(B, S), dtype=np.int32)
     n = int(tf.get("check_step_seq", n))
     labels = batch.copy()
     labels[:, n:] = -100
-    ref = reference.loss_and_grad_norm(params0, config, batch[:, :n])
+    ref = reference.loss_and_grad_norm(forward, params0, config,
+                                       batch[:, :n])
     del params0
 
     def v_sum():
@@ -440,26 +484,22 @@ def check_training(trainer, lcfg, config, tf, seed):
 
 def run_serve(cell, args, dev, tracer, compiles, phases):
     import jax
-    from hetu_tpu.models.llama import LlamaLMHeadModel
     from hetu_tpu.obs.metrics import MetricsRegistry
-    from hetu_tpu.serving.engine import ServeConfig, ServingEngine
+    from hetu_tpu.serving.engine import ServingEngine
     from hetu_tpu.serving.request import Request
 
     phases["program_import_s"] = time.perf_counter() - phases.pop("_t_loop")
-    config, tf = cell["config"], cell["traffic"]
-    sv = config["serving"]
-    lcfg = llama_config(config, {"param_dtype": sv["param_dtype"]})
-    model = LlamaLMHeadModel(lcfg)
+    config, tf, family = cell["config"], cell["traffic"], cell["family"]
+    sv, vocab = config["serving"], config["vocab_size"]
+    model = family.build_model(config, sv)
     t0 = time.perf_counter()
     params = jax.jit(model.init)(
         jax.random.key(traffic_mod.jax_seed(args.seed)))
     jax.block_until_ready(params)
     phases["build_s"] = time.perf_counter() - t0
-    sc = ServeConfig(num_slots=sv["num_slots"], page_size=sv["page_size"],
-                     max_len=sv["max_len"], prefill_chunk=sv["prefill_chunk"],
-                     num_pages=sv["num_pages"], kv_quant=sv["kv_quant"])
     registry = MetricsRegistry()
-    engine = ServingEngine(model, params, sc, registry=registry)
+    engine = ServingEngine(model, params, family.serve_config(config),
+                           registry=registry)
     t0 = time.perf_counter()
     engine.warmup()
     phases["warmup_s"] = time.perf_counter() - t0
@@ -469,15 +509,14 @@ def run_serve(cell, args, dev, tracer, compiles, phases):
     ramp_s = float(tf.get("ramp_s", 0.0))
     drain_limit = float(tf.get("drain_limit_s", 30.0))
     seconds = float(args.seconds)
-    C = sc.prefill_chunk
     if open_loop:
         plan = traffic_mod.plan_requests(
-            tf, args.seed, lcfg.vocab_size, ramp_s=ramp_s,
+            tf, args.seed, vocab, ramp_s=ramp_s,
             until_s=seconds + drain_limit)
     else:
         # more requests than the loop can complete in any window
         plan = traffic_mod.plan_requests(
-            tf, args.seed, lcfg.vocab_size, count=int(tf["plan_requests"]))
+            tf, args.seed, vocab, count=int(tf["plan_requests"]))
     outstanding_target = int(tf.get("outstanding", 0))
     phases["plan_s"] = time.perf_counter() - t0 - phases["warmup_s"]
     phases["ramp_s"] = ramp_s
@@ -492,15 +531,18 @@ def run_serve(cell, args, dev, tracer, compiles, phases):
     submitted = {}          # rid -> (PlannedRequest, submit_t, due)
     results = {}            # rid -> RequestResult
     first_seen = {}         # rid -> (admit_t, first_token_t)
-    steps = []              # (t_end, prompt_tokens, generated_tokens,
-    #                          decode_batch, context_tokens)
-    seen_chunks = {}        # rid -> prefill chunks already counted
-    tokens_out = 0
+    steps = []              # (t_end, prompt tokens prefilled, tokens
+    #                          generated): the program's own counters
+    counted = {"serve.prefill_tokens": 0.0, "serve.tokens_out": 0.0}
+    marks = {}              # moment -> (engine steps so far, counters)
     in_engine = 0
     window_open = gc_frozen = False
     setup_s = None
-    trace_from = None
+    slowest_step = None
     t_window_end = None
+
+    def mark(moment):
+        marks[moment] = (len(steps), counters_now(registry))
 
     def submit_due(now):
         nonlocal nxt, in_engine
@@ -523,38 +565,25 @@ def run_serve(cell, args, dev, tracer, compiles, phases):
             nxt += 1
 
     def account(finished, t_end):
-        """What this engine step did, read from the scheduler's slots: a
-        slot that is past its prefill took part in the step's decode
-        batch (one that finished its prefill in this step too), and so
-        did every request the step finished."""
-        nonlocal tokens_out, in_engine
-        prompt_toks = ctx_toks = decoding = 0
+        """What this engine step did: the tokens it prefilled and
+        generated, by the program's counters; and what no counter gives,
+        the admit and first-token times of requests still in a slot (a
+        slot past its prefill has its first token)."""
+        nonlocal in_engine
         for st in engine.scheduler.slots:
-            if st is None:
-                continue
-            rid, plen = st.request.rid, st.request.prompt_len
-            c, prev = st.stats.prefill_chunks, seen_chunks.get(rid, 0)
-            if c != prev:
-                prompt_toks += min(c * C, plen) - min(prev * C, plen)
-                seen_chunks[rid] = c
-            if not st.prefilling:
-                decoding += 1
-                ctx_toks += st.pos
-                if rid not in first_seen:
-                    first_seen[rid] = (st.stats.admit_t,
-                                       st.stats.first_token_t)
+            if st is not None and not st.prefilling \
+                    and st.request.rid not in first_seen:
+                first_seen[st.request.rid] = (st.stats.admit_t,
+                                              st.stats.first_token_t)
         for r in finished:
             results[r.rid] = r
             in_engine -= 1
-            decoding += 1
-            ctx_toks += len(submitted[r.rid][0].prompt) + len(r.tokens) - 1
             first_seen.setdefault(r.rid, (r.stats.admit_t,
                                           r.stats.first_token_t))
-            seen_chunks.pop(r.rid, None)
-        out_now = int(registry.counter_value("serve.tokens_out"))
-        steps.append((t_end, prompt_toks, out_now - tokens_out, decoding,
-                      ctx_toks))
-        tokens_out = out_now
+        read = {name: registry.counter_value(name) for name in counted}
+        steps.append((t_end,) + tuple(int(read[name] - counted[name])
+                                      for name in counted))
+        counted.update(read)
 
     def first_tokens_pending():
         """Requests due inside the window that have no first token yet."""
@@ -572,17 +601,21 @@ def run_serve(cell, args, dev, tracer, compiles, phases):
             window_open = True
             setup_s = time.perf_counter() - T_PROCESS_START
             compiles.armed = True
+            engine.slowest_step = None          # of the window, not the ramp
+            mark("window_start")
         if window_open and t_window_end is None and now >= seconds:
             t_window_end = now
             compiles.armed = False
             tracer.mark_end()
+            slowest_step = engine.slowest_step
+            mark("window_end")
         if t_window_end is not None and (
                 not first_tokens_pending() or now >= seconds + drain_limit):
             break
         if window_open and t_window_end is None:
-            if tracer.started and trace_from is None:
+            if tracer.started and "trace_start" not in marks:
+                mark("trace_start")
                 tracer.mark_start()  # one engine step after the start
-                trace_from = len(steps)
             tracer.maybe_start(now, seconds, float(tf.get("trace_s", 5.0)))
         submit_due(now)
         if not (engine.scheduler.active_slots() or engine.scheduler.queue):
@@ -651,11 +684,8 @@ def run_serve(cell, args, dev, tracer, compiles, phases):
     sl = traffic_mod.slice_rates(
         [s[0] for s in in_window], [s[1] + s[2] for s in in_window],
         before[-1] if before else 0.0, int(tf["slice_steps"]))
-    decode_steps = [s for s in in_window if s[3] > 0]
-    prompt_total = sum(s[1] for s in in_window)
-    token_total = sum(s[1] + s[2] for s in in_window)
-    traced = [s for s in (steps[trace_from:] if trace_from is not None
-                          else []) if s[0] < seconds]
+    token_gaps = [1e3 * (b - a) for r in done_in for a, b in
+                  zip(r.stats.token_ts, r.stats.token_ts[1:])]
 
     end_to_end = {
         "norm_latency_mean_ms": float(np.mean(norm)),
@@ -665,9 +695,12 @@ def run_serve(cell, args, dev, tracer, compiles, phases):
     # ---- outside the window: sizes, then correctness (the engine's pool
     # is freed first so that the reference has room)
     t0 = time.perf_counter()
-    mem = peak_memory([jax.devices()[0]], max(
-        program_bytes_of(low.compile())
-        for low in engine.lower_programs().values()))
+    compiled = [low.compile() for low in engine.lower_programs().values()]
+    mem = peak_memory([jax.devices()[0]],
+                      max(program_bytes_of(c) for c in compiled))
+    # the programs' texts, for the scope join: on traced runs only
+    hlo_texts = [c.as_text() for c in compiled] if args.trace else []
+    del compiled
     engine.close()
     del engine
     gc.collect()
@@ -683,8 +716,8 @@ def run_serve(cell, args, dev, tracer, compiles, phases):
         pr = submitted[rid][0]
         streams.append(dict(
             rid=rid, prompt=len(pr.prompt), **reference.check_stream(
-                params, config, pr.prompt, results[rid].tokens,
-                sv["max_len"])))
+                family.logits_at, params, config, pr.prompt,
+                results[rid].tokens, sv["max_len"])))
     check = {"ok": bool(streams) and all(s["ok"] for s in streams),
              "streams": streams,
              "rule": "each served token's reference logit within 16 bf16 "
@@ -693,12 +726,16 @@ def run_serve(cell, args, dev, tracer, compiles, phases):
                      "reference's argmax"}
     check_s = time.perf_counter() - t0
 
+    # the loop's own counts: what no counter of the program gives.  The
+    # program's counters are read as their difference between the marks,
+    # which stand at the top of the loop: over the engine steps that
+    # BEGAN in the window (`engine_steps` of them)
+    (n0, at_start), (n1, at_end) = marks["window_start"], marks["window_end"]
+    n_traced, at_trace = marks.get("trace_start", (n1, at_end))
     counters = {
+        "engine_steps": n1 - n0,
         "loadgen_late_p99_ms": traffic_mod.percentile(late, 99),
         "queue_wait_p90_ms": traffic_mod.percentile(waits, 90),
-        "decode_batch_mean": (sum(s[3] for s in decode_steps)
-                              / max(len(decode_steps), 1)),
-        "prefill_token_share": 100.0 * prompt_total / max(token_total, 1),
         "ttft_mean_ms": float(np.mean(ttft)),
         "ttft_p90_ms": traffic_mod.percentile(ttft, 90),
         "tpot_mean_ms": float(np.mean(tpot)),
@@ -707,12 +744,22 @@ def run_serve(cell, args, dev, tracer, compiles, phases):
         "compiles_in_window": compiles.count,
         "peak_hbm_gb": mem["peak_bytes"] / 1e9,
     }
-    ctx = {"steps": len(traced), "counters": counters, "config": config,
-           "cost_args": {"paged_attn_cost": {
-               "context_tokens": sum(s[4] for s in traced),
-               "queries": sum(s[3] for s in traced)}}}
+    if token_gaps:
+        counters["token_gap_p99_ms"] = traffic_mod.percentile(token_gaps, 99)
+    ctx = {"steps": n1 - n_traced, "counters": counters,
+           "registry": trace_mod.counter_diff(at_start, at_end),
+           "hlo_texts": hlo_texts,
+           "window_counts": {
+               "counters": trace_mod.counter_diff(at_trace, at_end)}}
+    # a traced run's line holds no end-to-end metric: kept here so that
+    # what the profiler costs can be read (same seed, --trace 0)
+    inside = dict(program_readings(registry, "serve.step_phase_s", tracer,
+                                   tr, tr_window),
+                  end_to_end=end_to_end, token_gaps=len(token_gaps),
+                  slowest_step=slowest_step)
     readings = {"kind": tf["kind"], "requests": per_request,
                 "steps": [list(s) for s in in_window],
+                "registry": ctx["registry"], "inside": inside,
                 "slice_rates": sl["slice_rates"], "slice_s": sl["slice_s"]}
     return {
         "setup_s": setup_s, "attempted": len(due_in),
@@ -720,17 +767,17 @@ def run_serve(cell, args, dev, tracer, compiles, phases):
         "check": check, "check_s": check_s, "memory": mem,
         "readings": readings, "end_to_end": end_to_end, "ctx": ctx,
         "trace": tr, "trace_window": tr_window,
-        "summary": {"engine_steps": len(in_window),
-                    "requests_due_in_window": len(ttft) + failed
-                    if open_loop else None,
-                    "requests_done_in_window": len(done_in),
-                    "requests_submitted": len(submitted),
-                    "ttft_p50_ms": traffic_mod.percentile(ttft, 50),
-                    "tpot_p50_ms": traffic_mod.percentile(tpot, 50),
-                    "median_slice_tokens_per_s": float(np.median(
-                        sl["slice_rates"])),
-                    "slices": len(sl["slice_rates"]),
-                    "loop_end_s": t_end_loop},
+        "summary": dict({"engine_steps": len(in_window),
+                         "requests_due_in_window": len(ttft) + failed
+                         if open_loop else None,
+                         "requests_done_in_window": len(done_in),
+                         "requests_submitted": len(submitted),
+                         "ttft_p50_ms": traffic_mod.percentile(ttft, 50),
+                         "tpot_p50_ms": traffic_mod.percentile(tpot, 50),
+                         "median_slice_tokens_per_s": float(np.median(
+                             sl["slice_rates"])),
+                         "slices": len(sl["slice_rates"]),
+                         "loop_end_s": t_end_loop}, **inside),
     }
 
 
@@ -761,7 +808,7 @@ def main(argv=None) -> int:
         from hetu_tpu.utils.device import enable_compile_cache
         dev = device_info(cell["cell"]["chips"], args.rehearse)
         cache_dir = enable_compile_cache()
-    except (Refused, KeyError) as e:
+    except (Refused, KeyError, ImportError) as e:
         print(f"benchmarks/run.py: {e}", file=sys.stderr)
         return 2
     phases = {"import_s": time.perf_counter() - T_PROCESS_START}
@@ -802,15 +849,15 @@ def main(argv=None) -> int:
                                            "unit": m["unit"]}
     else:
         tr, window = res["trace"], res["trace_window"]
-        ctx = res["ctx"]
+        ctx = dict(res["ctx"], config=cell["config"], family=cell["family"],
+                   emit=emit)
         if on_chip:
             ctx["peaks"] = peaks.peaks_for(dev["kind"])
         if tr is not None and window is not None and \
                 ctx.get("snap_to_module"):
             window, ctx["steps"] = trace_mod.snap_to_modules(
                 tr, window, ctx["snap_to_module"])
-            for cost_args in ctx["cost_args"].values():
-                cost_args["batch"] *= ctx["steps"]
+        ctx["window_counts"]["steps"] = ctx.get("steps")
         busy = (trace_mod.busy_seconds(tr, window)
                 if tr is not None and window is not None else None)
         if on_chip and not busy:

@@ -1,6 +1,7 @@
 """The runner end to end at a tiny size on the CPU (`--rehearse`), the
-refusals, and that cells, configurations, traffic mixes and per-layer
-metrics are data: added by new files and one new entry each."""
+refusals, and that cells, configurations, traffic mixes, per-layer
+metrics and whole architectures are data: added by new files and one new
+entry each."""
 import json
 import os
 import subprocess
@@ -117,8 +118,11 @@ def test_unknown_device_kind_is_an_error():
     ("tiny-train-4dev", 4, 1, {"train_stall_pct", "train_step_median_ms",
                                "train.compiles_in_window"}),
     ("tiny-chat", 1, 0, {"norm_latency_mean_ms", "setup_s"}),
-    ("tiny-batch", 1, 1, {"batch.prefill_token_share", "batch.stall_pct",
-                          "batch.compiles_in_window"}),
+    ("tiny-batch", 1, 1, {"batch.prefill_token_share_inside",
+                          "batch.stall_pct", "batch.compiles_in_window"}),
+    ("tiny-gpt2-chat", 1, 0, {"norm_latency_mean_ms", "setup_s"}),
+    ("tiny-gpt2-train", 1, 1, {"train_stall_pct", "train_step_median_ms",
+                               "train.compiles_in_window"}),
 ])
 def test_rehearsal_prints_the_contracts_line(cell, devices, trace_on, expect):
     p = run("--rehearse", "--benchmark-file", REHEARSAL, "--workload", cell,
@@ -211,3 +215,130 @@ def test_a_cell_config_traffic_and_metric_are_added_by_files_alone(dummies):
     per_layer = last_line(run(*common, "--trace", "1"))
     assert set(per_layer["metrics"]) == {"cpu_rehearsal.zz.dummy_steps"}
     assert per_layer["correct"] and e2e["correct"]
+
+
+# ------------------------------------- an architecture, by files alone
+
+ZZ_FAMILY = '''"""A family added by a test: models/gpt with an UNTIED head, and a cost
+function of its own."""
+from benchmarks.families.gpt2 import (  # noqa: F401
+    build_model, counts, logits_at, serve_config)
+
+
+def zz_decode_cost(cfg, window):
+    """One operation and two bytes per decoded token and layer."""
+    queries = window["counters"].get("serve.decode_slot_steps")
+    if not queries:
+        return None
+    return {"ops": 1.0 * cfg["n_layer"] * queries,
+            "bytes": 2.0 * cfg["n_layer"] * queries}
+'''
+
+ZZ_METRICS = {
+    "zz.decode_mlp_dev_ms": {"rule": "scope_ms", "program": "decode_fn",
+                             "phase": ["mlp"]},
+    "zz.tokens_per_decode_step": {"rule": "counter",
+                                  "counter": "serve.tokens_out",
+                                  "over": "serve.decode_steps"},
+    "zz.decode_roofline": {"rule": "roofline_pct", "match": "^zz_kernel",
+                           "cost": "zz_decode_cost"},
+}
+
+
+@pytest.fixture
+def architecture(tmp_path):
+    """A family module, a configuration that names it, a cell and three
+    per-layer metrics (a scope, a counter of the program's registry, a
+    roofline share with the family's own cost function): six new files
+    and the entries in a copy of the rehearsal registry.  Nothing that
+    exists is edited."""
+    made = []
+
+    def write(rel, text):
+        path = os.path.join(BENCH, rel)
+        assert not os.path.exists(path)
+        with open(path, "w") as f:
+            f.write(text)
+        made.append(path)
+    try:
+        write("families/zz_arch.py", ZZ_FAMILY)
+        cfg = dict(traffic.load_json("configs", "tiny-gpt2"),
+                   family="zz_arch", n_layer=1, tie_word_embeddings=False)
+        write("configs/zz-arch.json", json.dumps(cfg))
+        for name, reduce in ZZ_METRICS.items():
+            write(f"metrics/{name}.json", json.dumps({
+                "name": name, "what": "added by a test", "device": False,
+                "reduce": reduce}))
+        with open(REHEARSAL) as f:
+            reg = json.load(f)
+        reg["configs"].append({"name": "zz-arch", "source": "none",
+                               "file": "benchmarks/configs/zz-arch.json",
+                               "reduced": [], "why": "dummy"})
+        reg["workloads"].append({"name": "zz-arch-cell", "config": "zz-arch",
+                                 "traffic": "tiny-open", "chips": 1,
+                                 "why": "dummy"})
+        for m in reg["end_to_end"]:
+            if m["name"] == "norm_latency_mean_ms":
+                m["workloads"].append("zz-arch-cell")
+        reg["per_layer"] += [
+            {"name": name, "unit": "x", "better": "lower",
+             "source": "device_trace", "layer": "Model programs",
+             "moves": "norm_latency_mean_ms", "workloads": ["zz-arch-cell"]}
+            for name in ZZ_METRICS]
+        reg_path = tmp_path / "registry.json"
+        reg_path.write_text(json.dumps(reg))
+        yield str(reg_path)
+    finally:
+        for path in made:
+            os.remove(path)
+
+
+def test_an_architecture_is_added_by_files_alone(architecture):
+    common = ["--rehearse", "--benchmark-file", architecture, "--workload",
+              "zz-arch-cell", "--seed", "7", "--seconds", "2"]
+    e2e = last_line(run(*common, "--trace", "0"))
+    assert set(e2e["metrics"]) == {"cpu_rehearsal.norm_latency_mean_ms",
+                                   "cpu_rehearsal.setup_s"}
+    # `correct` is decided against the new family's forward (untied head)
+    assert e2e["correct"] and not e2e["failed"]
+    per_layer = last_line(run(*common, "--trace", "1"))
+    # a CPU run's trace has no device plane: the scope and the roofline
+    # metric find nothing to read there and are left out, not refused
+    assert set(per_layer["metrics"]) == {
+        "cpu_rehearsal.zz.tokens_per_decode_step"}
+    assert per_layer["metrics"]["cpu_rehearsal.zz.tokens_per_decode_step"][
+        "value"] >= 1.0
+    assert per_layer["correct"]
+
+    # the same three files reduced as `main()` reduces them, from a
+    # hand-made device trace of the new family's decode program
+    from benchmarks import run as runner
+    cell = runner.load_cell(architecture, "zz-arch-cell")
+    assert cell["family"].__name__ == "benchmarks.families.zz_arch"
+    assert [m["name"] for m in cell["per_layer"]] == list(ZZ_METRICS)
+    hlo = ("HloModule jit_decode_fn, is_scheduled=true\n\nENTRY %main {\n"
+           '  %fusion.4 = f32[4,256]{0} fusion(%p), metadata={op_name="jit('
+           'decode_fn)/layer/while/body/closed_call/mlp/dot_general"}\n'
+           '  ROOT %zz_kernel.5 = f32[4,64]{0} fusion(%p), metadata={op_name'
+           '="jit(decode_fn)/layer/attn/dot_general"}\n}\n')
+    dev = "/device:TPU:0"
+    ops = [trace.Event("fusion.4_f32_4_256_", 0.0, 0.002),
+           trace.Event("zz_kernel.5_f32_4_64_", 0.002, 0.004),
+           trace.Event("fusion.4_f32_4_256_", 0.01, 0.002),
+           trace.Event("zz_kernel.5_f32_4_64_", 0.012, 0.004)]
+    mods = [trace.Event("jit_decode_fn(1)", 0.0, 0.006),
+            trace.Event("jit_decode_fn(1)", 0.01, 0.006)]
+    tr = trace.Trace({dev: ops}, {dev: mods}, [])
+    ctx = {"config": cell["config"], "family": cell["family"],
+           "hlo_texts": [hlo], "counters": {},
+           "peaks": {"flops_per_s": 1e3, "hbm_bytes_per_s": 1e3},
+           "registry": {"serve.tokens_out": 6.0, "serve.decode_steps": 2.0},
+           "window_counts": {"steps": 2, "counters": {
+               "serve.decode_slot_steps": 4.0}}}
+    got = {name: trace.reduce_metric(runner.metric_spec(name), tr,
+                                     (0.0, 0.02), ctx)
+           for name in ZZ_METRICS}
+    # 2 bytes x 1 layer x 4 queries at 1e3 B/s over 8 ms of the kernel
+    assert got == pytest.approx({"zz.decode_mlp_dev_ms": 2.0,
+                                 "zz.tokens_per_decode_step": 3.0,
+                                 "zz.decode_roofline": 100.0})

@@ -1,7 +1,8 @@
-"""The readings taken from inside the program (`benchmarks/scopes.py`):
-each rule on hand-made events with exact answers, the scope join on a
-hand-made trace of two programs that share an instruction name, the
-entries of `inside_metrics.json`, and `run_inside.py` end to end at the
+"""The readings taken from inside the program (`benchmarks/trace.py`'s span,
+scope and counter rules): each rule on hand-made events with exact
+answers, the scope join on a hand-made trace of two programs that share
+an instruction name, the counter rule on hand-made registry snapshots
+through the metric files that use it, and `run.py` end to end at the
 rehearsal size."""
 import json
 import os
@@ -15,23 +16,16 @@ BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, ROOT)
 
-from benchmarks import scopes, trace  # noqa: E402
+from benchmarks import trace, traffic  # noqa: E402
 from benchmarks.trace import Event, Trace  # noqa: E402
 
 DEV = "/device:TPU:0"
 RECORDED = os.path.join(HERE, "tiny-chat.xplane.pb")
-SYNC = list(scopes.SYNC_SPANS)
+SYNC = list(trace.SYNC_SPANS)
 
 
 def ev(name, start, dur):
     return Event(name, float(start), float(dur))
-
-
-@pytest.fixture
-def program_spans(monkeypatch):
-    """What `run_inside.main` does to `trace.HOST_SPANS`."""
-    monkeypatch.setattr(trace, "HOST_SPANS",
-                        scopes.PROGRAM_SPANS + trace.HOST_SPANS)
 
 
 @pytest.fixture
@@ -61,17 +55,17 @@ def test_span_self_is_the_step_minus_its_sync_phases(stepped):
     w = trace.window_of(stepped)
     p = {"span": "serve.step", "minus": SYNC}
     # step one 10 - 6, step two 10 - (2 + 5)
-    assert scopes.rule_span_self_ms(p, stepped, w, {}) == \
+    assert trace.rule_span_self_ms(p, stepped, w, {}) == \
         pytest.approx(1e3 * (4.0 + 3.0) / 2)
-    assert scopes.rule_span_self_ms({"span": "serve.step"}, stepped, w,
+    assert trace.rule_span_self_ms({"span": "serve.step"}, stepped, w,
                                     {}) == pytest.approx(1e4)
 
 
 def test_starved_and_sync_idle_split_the_idle_in_the_step(stepped):
     w = trace.window_of(stepped)
-    starved = scopes.rule_span_idle_ms(
+    starved = trace.rule_span_idle_ms(
         {"span": "serve.step", "exclude": SYNC}, stepped, w, {})
-    sync = scopes.rule_span_idle_ms(
+    sync = trace.rule_span_idle_ms(
         {"span": "serve.step", "only": SYNC}, stepped, w, {})
     # starved: [0,3] + [9,10] in step one; [10,10.5] + [13,14] + [19,20]
     assert starved == pytest.approx(1e3 * (4.0 + 2.5) / 2)
@@ -86,18 +80,18 @@ def test_starved_and_sync_idle_split_the_idle_in_the_step(stepped):
 def test_idle_position_in_a_sync_span(stepped):
     w = trace.window_of(stepped)
     # token_fetch [3, 9] busy [3, 8]; [14, 19] busy [14, 18]
-    assert scopes.idle_position_ms(stepped, w, "serve.token_fetch") == \
+    assert trace.idle_position_ms(stepped, w, "serve.token_fetch") == \
         pytest.approx({"head": 0.0, "middle": 0.0, "tail": 1000.0})
     # first_token [11, 13] busy until 12
-    assert scopes.idle_position_ms(stepped, w, "serve.first_token") == \
+    assert trace.idle_position_ms(stepped, w, "serve.first_token") == \
         pytest.approx({"head": 0.0, "middle": 0.0, "tail": 1000.0})
     # decode_build [0.5, 2.5] and [13, 14]: idle throughout
-    assert scopes.idle_position_ms(stepped, w, "serve.decode_build")[
+    assert trace.idle_position_ms(stepped, w, "serve.decode_build")[
         "head"] == pytest.approx(1500.0)
-    assert scopes.idle_position_ms(stepped, w, "serve.nothing") is None
+    assert trace.idle_position_ms(stepped, w, "serve.nothing") is None
 
 
-def test_idle_gaps_name_the_phase(stepped, program_spans):
+def test_idle_gaps_name_the_phase(stepped):
     w = trace.window_of(stepped)
     gaps = dict(trace.attribute_gaps(stepped, w))
     assert gaps == pytest.approx({
@@ -117,8 +111,8 @@ def test_rules_find_nothing_where_the_program_has_no_spans():
             ("span_idle_ms", {"span": "serve.step", "only": SYNC}),
             ("scope_ms", {"program": "decode_fn", "phase": ["attn"]}),
             ("scope_pct", {"program": "decode_fn", "group": "unscoped"})]:
-        assert scopes.RULES[rule](p, tr, w, {"hlo_texts": []}) is None
-    assert scopes.rule_span_self_ms({"span": "serve.step"}, None, None,
+        assert trace.RULES[rule](p, tr, w, {"hlo_texts": []}) is None
+    assert trace.rule_span_self_ms({"span": "serve.step"}, None, None,
                                     {}) is None
 
 
@@ -187,7 +181,7 @@ def two_programs():
 
 
 def test_scope_index_keys_are_the_trace_names():
-    name, index = scopes.scope_index(DECODE)
+    name, index = trace.scope_index(DECODE)
     assert name == "jit_decode_fn"
     assert index["fusion.1_bf16_8_128_"] == ("layer/attn", "fwd")
     assert index["fusion.2_bf16_8_128_"] == ("layer/kv_write", "fwd")
@@ -200,7 +194,7 @@ def test_join_gives_an_operation_to_the_program_that_ran_it(two_programs):
     emitted = []
     ctx = {"hlo_texts": [DECODE, CHUNK],
            "emit": lambda **rec: emitted.append(rec)}
-    table = scopes.scope_table(two_programs, (0.0, 30.0), ctx)
+    table = trace.scope_table(two_programs, (0.0, 30.0), ctx)
     dec, chunk = table["jit_decode_fn"], table["jit_chunk_fn"]
     assert dec["executions"] == 2               # the third is cut
     assert dec["device_s"] == pytest.approx(12.0)
@@ -218,7 +212,7 @@ def test_join_gives_an_operation_to_the_program_that_ran_it(two_programs):
         pytest.approx({("unscoped", "fwd", "compute"): 0.25})
     assert list(dec["ops"]["unscoped"]) == ["copy.5_bf16_24_2049_16_8_128_"]
     # built once, printed once
-    assert scopes.scope_table(two_programs, (0.0, 30.0), ctx) is table
+    assert trace.scope_table(two_programs, (0.0, 30.0), ctx) is table
     assert [r["phase"] for r in emitted] == ["scopes"]
     printed = emitted[0]["programs"]["jit_decode_fn"]
     assert printed["top_ops"] == {
@@ -245,7 +239,7 @@ def test_join_gives_an_operation_to_the_program_that_ran_it(two_programs):
     ({"program": "train_step", "phase": ["attn"]}, None),
 ])
 def test_scope_ms_per_execution(two_programs, p, expect):
-    got = scopes.rule_scope_ms(p, two_programs, (0.0, 30.0),
+    got = trace.rule_scope_ms(p, two_programs, (0.0, 30.0),
                                {"hlo_texts": [DECODE, CHUNK]})
     assert got == (None if expect is None else pytest.approx(expect))
 
@@ -253,10 +247,10 @@ def test_scope_ms_per_execution(two_programs, p, expect):
 def test_scope_pct_and_the_groups_sum_to_the_program(two_programs):
     ctx = {"hlo_texts": [DECODE, CHUNK]}
     w = (0.0, 30.0)
-    assert scopes.rule_scope_pct(
+    assert trace.rule_scope_pct(
         {"program": "decode_fn", "group": "unscoped"}, two_programs, w,
         ctx) == pytest.approx(100.0 / 6)
-    parts = [scopes.rule_scope_ms({"program": "decode_fn", **sel},
+    parts = [trace.rule_scope_ms({"program": "decode_fn", **sel},
                                   two_programs, w, ctx)
              for sel in ({"phase": ["attn"]}, {"phase": ["kv_write"]},
                          {"phase": ["mlp"]}, {"group": "unscoped"})]
@@ -271,12 +265,12 @@ def test_scope_pct_and_the_groups_sum_to_the_program(two_programs):
     ("pallas_quantize", "pallas_quantize", "pallas_quantize"),
     ("unscoped", "unscoped", None)])
 def test_phase_and_kernel_of_a_group(group, phase, kernel):
-    assert scopes.phase_of(group) == phase
-    assert scopes.kernel_of(group) == kernel
+    assert trace.phase_of(group) == phase
+    assert trace.kernel_of(group) == kernel
 
 
 def test_by_opcode_sums_instances_of_one_kind():
-    assert scopes.by_opcode({
+    assert trace.by_opcode({
         "all-gather.172_bf16_16_2048_2_4096_": 2.0,
         "all-gather.169_bf16_46272_2048_": 1.0,
         "copy_bitcast_fusion.13_bf16_2_4096_2_4096_": 2.5,
@@ -295,7 +289,7 @@ def test_eager_dispatches_are_counted_once_under_their_phase():
             ("PjitFunction(dynamic_slice)", 2.1, 2.2),
             ("PjitFunction(squeeze)", 2.3, 2.4),
             ("PjitFunction(dynamic_slice)", 11.0, 11.1)]          # outside
-    assert scopes.eager_dispatches(host, (0.0, 10.0)) == {
+    assert trace.eager_dispatches(host, (0.0, 10.0)) == {
         "serve.prefill_chunk": {"PjitFunction(convert_element_type)": 1,
                                 "PjitFunction(chunk_fn)": 1},
         "serve.first_token": {"PjitFunction(dynamic_slice)": 1,
@@ -303,78 +297,128 @@ def test_eager_dispatches_are_counted_once_under_their_phase():
 
 
 # ---------------------------------------------------------------------------
-# the entries and the entry point
+# the program's counters, the entries and the entry point
 # ---------------------------------------------------------------------------
 
-def test_inside_metrics_are_entries_a_benchmark_pr_can_move():
-    """Each entry names cells of `BENCHMARK.json` and then of
-    `tests/rehearsal.json` (no third list), and in each file every named
-    cell reports the end-to-end metric the entry moves."""
-    with open(os.path.join(BENCH, "inside_metrics.json")) as f:
-        added = json.load(f)["per_layer"]
+def snapshot(**counters):
+    """A `MetricsRegistry.snapshot()` with these counters; a dict value is
+    one series per label value of `reason`."""
+    out = []
+    for name, v in counters.items():
+        name = name.replace("__", ".")
+        series = v if isinstance(v, dict) else {None: v}
+        out += [{"name": name, "labels": {"reason": r} if r else {},
+                 "value": float(x)} for r, x in series.items()]
+    return {"counters": out, "gauges": [], "histograms": []}
+
+
+START = snapshot(serve__decode_steps=10, serve__decode_slot_steps=30,
+                 serve__decode_context_tokens=3000, serve__prefill_tokens=500,
+                 serve__tokens_out=30,
+                 serve__admission_stalls={"no_pages": 1})
+END = snapshot(serve__decode_steps=12, serve__decode_slot_steps=40,
+               serve__decode_context_tokens=6000, serve__prefill_tokens=628,
+               serve__tokens_out=40,
+               serve__admission_stalls={"no_pages": 1, "no_slot": 1})
+
+
+def window_ctx():
+    return {"counters": {"engine_steps": 2},
+            "registry": trace.counter_diff(trace.counter_values(START),
+                                           trace.counter_values(END))}
+
+
+def test_counter_values_are_flat_by_name_and_by_series():
+    flat = trace.counter_values(END)
+    assert flat["serve.admission_stalls"] == 2.0          # every reason
+    assert flat["serve.admission_stalls{reason=no_slot}"] == 1.0
+    assert flat["serve.tokens_out"] == 40.0
+    diff = window_ctx()["registry"]
+    # a series that did not exist at the start counted from 0
+    assert diff["serve.admission_stalls{reason=no_slot}"] == 1.0
+    assert diff["serve.admission_stalls{reason=no_pages}"] == 0.0
+    assert trace.counter_key("a.b", {"z": 1, "k": "v"}) == "a.b{k=v,z=1}"
+
+
+@pytest.mark.parametrize("metric,expect", [
+    # the four readings `run_inside.window_counters` made by hand (PR 24),
+    # now one rule and a data file each
+    ("chat.admit_stall_pct", 50.0),
+    ("chat.decode_ctx_ktokens_step", 1.5),
+    ("chat.decode_batch_inside", 5.0),
+    ("batch.prefill_token_share_inside", 100.0 * 128 / (128 + 10)),
+])
+def test_counter_rule_files_read_the_registrys_difference(metric, expect):
+    spec = traffic.load_json("metrics", metric)
+    assert spec["reduce"]["rule"] == "counter"
+    assert trace.reduce_metric(spec, None, None, window_ctx()) == \
+        pytest.approx(expect)
+    # a program that has no such counter: nothing to read, no error
+    assert trace.reduce_metric(spec, None, None,
+                               {"counters": {}, "registry": {}}) is None
+
+
+def test_counter_rule_by_labels_and_with_an_untouched_numerator():
+    ctx = window_ctx()
+    by_label = {"rule": "counter", "counter": "serve.admission_stalls",
+                "labels": {"reason": "no_slot"}}
+    assert trace.rule_counter(by_label, None, None, ctx) == 1.0
+    # a counter the program never touched counts 0 over steps that ran,
+    # and alone it is nothing to read
+    never = {"rule": "counter", "counter": "serve.preemptions"}
+    assert trace.rule_counter(never, None, None, ctx) is None
+    assert trace.rule_counter(dict(never, over="engine_steps", scale=100.0),
+                              None, None, ctx) == 0.0
+    # the loop's own values are found by the same rule
+    assert trace.rule_counter({"counter": "engine_steps"}, None, None,
+                              ctx) == 2
+
+
+def test_every_metric_of_the_benchmark_is_rehearsed():
+    """`rehearsal.json` lists exactly the per-layer metrics of
+    `BENCHMARK.json` (the 26 folded in by PR 26 among them), each on cells
+    that report the end-to-end metric it moves, and each has its file."""
     files = []
     for path in (os.path.join(ROOT, "BENCHMARK.json"),
                  os.path.join(HERE, "rehearsal.json")):
         with open(path) as f:
             files.append(json.load(f))
-    bench = files[0]
-    layers = {m["layer"] for m in bench["per_layer"]}
-    have = {m["name"] for m in bench["per_layer"]}
-    names = [m["name"] for m in added]
-    assert len(names) == len(set(names)) and not set(names) & have
-    rules = dict(trace.RULES, **scopes.RULES)
-    for m in added:
-        assert m["layer"] in layers, m["name"]
-        assert m["reduce"]["rule"] in rules, m["name"]
-        assert m["better"] in ("lower", "higher")
-        assert m["source"] in ("device_trace", "program_span",
-                               "program_counter", "host_clock")
-        left = set(m["workloads"])
-        for f in files:
-            cells = {w["name"] for w in f["workloads"]} & left
-            assert cells, m["name"]
+    bench, rehearsal = files
+    assert [m["name"] for m in rehearsal["per_layer"]] == \
+        [m["name"] for m in bench["per_layer"]]
+    assert len(bench["per_layer"]) == 55
+    for f in files:
+        cells = {w["name"] for w in f["workloads"]}
+        for m in f["per_layer"]:
+            assert traffic.load_json("metrics", m["name"])["reduce"][
+                "rule"] in trace.RULES, m["name"]
             moved = next(e for e in f["end_to_end"]
                          if e["name"] == m["moves"])
-            assert cells <= set(moved["workloads"]), m["name"]
-            left -= cells
-        assert not left, (m["name"], left)
+            assert m["workloads"] and set(m["workloads"]) <= cells \
+                & set(moved["workloads"]), m["name"]
+    same = ("unit", "better", "source", "layer", "moves")
+    for a, b in zip(bench["per_layer"], rehearsal["per_layer"]):
+        assert [a[k] for k in same] == [b[k] for k in same], a["name"]
 
 
-def test_window_counters_take_the_steps_that_end_in_the_window():
-    """`account()`'s window: the engine steps whose END lies in
-    [0, seconds); the counters' differences are taken from the last step
-    that ended before it."""
-    from benchmarks import run_inside
-
-    def counts(decode_steps, slot_steps, ctx, prefill, out, stalls):
-        return dict(decode_steps=decode_steps, slot_steps=slot_steps,
-                    ctx=ctx, prefill=prefill, out=out, stalls=stalls)
-    seen = {"steps": [(-0.5, counts(10, 30, 3000, 500, 30, 1)),
-                      (0.5, counts(11, 34, 4000, 628, 34, 1)),
-                      (1.5, counts(12, 40, 6000, 628, 40, 2)),
-                      (2.5, counts(13, 50, 9000, 756, 50, 3))]}
-    got = run_inside.window_counters(seen, 2.0)
-    assert got == pytest.approx({
-        "admit_stall_pct": 50.0, "decode_ctx_ktokens_step": 1.5,
-        "decode_batch_inside": 5.0,
-        "prefill_token_share_inside": 100.0 * 128 / (128 + 10)})
-    assert run_inside.window_counters({}, 2.0) == {}
-
-
-@pytest.mark.parametrize("cell,present,twins", [
+@pytest.mark.parametrize("cell,present,ratio", [
     ("tiny-chat", ("chat.host_work_ms_step", "chat.token_gap_p99_ms",
                    "chat.admit_stall_pct", "chat.decode_ctx_ktokens_step"),
-     ("chat.decode_batch_inside", "decode_batch_mean")),
+     ("chat.decode_batch_inside", "serve.decode_slot_steps",
+      ("serve.decode_steps",), 1.0)),
     ("tiny-batch", ("batch.host_work_ms_step",),
-     ("batch.prefill_token_share_inside", "batch.prefill_token_share")),
+     ("batch.prefill_token_share_inside", "serve.prefill_tokens",
+      ("serve.prefill_tokens", "serve.tokens_out"), 100.0)),
+    ("tiny-gpt2-chat", ("chat.host_work_ms_step", "chat.admit_stall_pct"),
+     ("chat.decode_batch_inside", "serve.decode_slot_steps",
+      ("serve.decode_steps",), 1.0)),
 ])
-def test_run_inside_rehearsal_reports_the_program_readings(cell, present,
-                                                           twins):
+def test_rehearsal_reports_the_program_readings(cell, present, ratio):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=1")
     env.pop("BENCH_RUN", None)
     proc = subprocess.run(
-        [sys.executable, os.path.join(BENCH, "run_inside.py"), "--rehearse",
+        [sys.executable, os.path.join(BENCH, "run.py"), "--rehearse",
          "--benchmark-file", os.path.join(HERE, "rehearsal.json"),
          "--workload", cell, "--seed", "2147483659", "--seconds",
          "2", "--trace", "1"],
@@ -388,9 +432,20 @@ def test_run_inside_rehearsal_reports_the_program_readings(cell, present,
     metrics = lines[-1]["metrics"]
     for name in present:
         assert "cpu_rehearsal." + name in metrics
-    # what the program counts where the work happens against what
-    # `account()` reconstructs from the scheduler's slots: the same
-    inside, outside = (metrics["cpu_rehearsal." + n]["value"]
-                       for n in twins)
-    assert inside == pytest.approx(outside, rel=1e-9)
-    assert lines[-1]["correct"] and not lines[-1]["failed"]
+    # a counter metric is the difference of the program's registry over
+    # the window, which the readings file keeps, and nothing else
+    path = next(ln for ln in lines if ln.get("phase") == "readings")["path"]
+    with open(os.path.join(ROOT, path)) as f:
+        readings = json.load(f)
+    name, counter, over, scale = ratio
+    diff = readings["registry"]
+    assert metrics["cpu_rehearsal." + name]["value"] == pytest.approx(
+        scale * diff[counter] / sum(diff[o] for o in over), rel=1e-12)
+    # the judged rate's per-step token counts are the program's counters:
+    # whole steps of the window sum to within one step of the difference
+    steps = readings["steps"]
+    assert all(len(s) == 3 for s in steps)
+    assert sum(s[2] for s in steps) == pytest.approx(
+        diff["serve.tokens_out"], abs=2 * max(s[2] for s in steps) + 1)
+    assert lines[-1]["correct"] and not lines[-1]["failed"], next(
+        ln for ln in lines if ln.get("phase") == "check")

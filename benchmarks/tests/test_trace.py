@@ -113,19 +113,39 @@ def test_host_self_time(toy):
         {"span": "engine.step"}, toy, (0.0, 10.0), {}) == pytest.approx(1000.0)
 
 
-def test_roofline_share_uses_the_cost_function(toy):
+def test_roofline_share_uses_the_familys_cost_function(toy):
+    from benchmarks.families import llama
     cfg = {"hidden_size": 2048, "num_attention_heads": 16,
            "num_key_value_heads": 8, "num_hidden_layers": 24}
-    ctx = {"config": cfg,
+    ctx = {"config": cfg, "family": llama,
            "peaks": {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
-           "cost_args": {"paged_attn_cost": {"context_tokens": 8_000_000,
-                                             "queries": 36}}}
-    pct = trace.rule_roofline_pct(
-        {"match": "pallas_paged_attention", "cost": "paged_attn_cost"},
-        toy, (0.0, 10.0), ctx)
+           "window_counts": {"steps": 2, "counters": {
+               "serve.decode_context_tokens": 8_000_000,
+               "serve.decode_slot_steps": 36}}}
+    p = {"match": "pallas_paged_attention", "cost": "paged_attn_cost"}
+    pct = trace.rule_roofline_pct(p, toy, (0.0, 10.0), ctx)
     kv_bytes = 24 * 2 * (2 * 8_000_000 * 8 * 128 + 2 * 36 * 16 * 128)
     assert pct == pytest.approx(100 * (kv_bytes / 819e9) / 2.0)
     assert ctx["notes"]["paged_attn_cost"]["bound"] == "memory"
+    # a window in which the program counted no decode step: nothing to read
+    assert trace.rule_roofline_pct(
+        p, toy, (0.0, 10.0), dict(ctx, window_counts={"counters": {}})) is None
+    with pytest.raises(KeyError):
+        trace.rule_roofline_pct(dict(p, cost="no_such_cost"), toy,
+                                (0.0, 10.0), ctx)
+
+
+def test_flash_cost_scales_with_the_windows_steps_and_shards():
+    from benchmarks.families import llama
+    cfg = {"hidden_size": 4096, "num_attention_heads": 32,
+           "num_key_value_heads": 8, "num_hidden_layers": 2}
+    one = llama.flash_attn_cost(cfg, {"steps": 1, "batch": 2, "seq": 4096,
+                                      "shards": 1})
+    assert one["ops"] == 2 * 6.0 * (2.0 * 2 * 32 * 4096 * 4096 * 128 / 2.0)
+    many = llama.flash_attn_cost(cfg, {"steps": 12, "batch": 2, "seq": 4096,
+                                       "shards": 4})
+    assert many["ops"] == pytest.approx(3 * one["ops"])
+    assert many["bytes"] == pytest.approx(3 * one["bytes"])
 
 
 def test_collective_time_and_its_exposed_part():

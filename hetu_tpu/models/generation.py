@@ -557,6 +557,126 @@ def decode_step_paged(model, params, tokens, k_pool, v_pool, table,
     return (logits,) + pools
 
 
+# ---------------------------------------------------------------------------
+# The serving programs written against the model's attention-cache contract
+# ---------------------------------------------------------------------------
+# A model that says what a token stores (`cache_contract()`:
+# models/cache_contract.py) and how a query attends it brings no copy of
+# the paged decode, chunk or page-write programs: the three below take
+# from the model
+#
+#   embed_tokens(params, ids)            -> x [b, s, hidden]
+#   rope_tables(max_len)                 -> whatever its `project` takes
+#   serving_layers(params)               -> [(block, layer params)]
+#       in the pool's layer order, run one after the other
+#   block.input_norm / post_norm / mlp_stats(params, x) -> (y, stats)
+#   block.attn.project(p, hn, rope, pos_ids) -> (q, entries)
+#       HOW A TOKEN'S CACHE ENTRY IS MADE: one array per pool array,
+#       [b, s, *stored shape]
+#   block.attn.attend_paged(p, q, pools, table, positions)
+#   block.attn.attend_dense(p, q, caches, start)
+#       HOW A QUERY ATTENDS IT, over pages and over a dense per-slot cache
+#   block.attn.output(p, attn), final_hidden(params, x), logits(params, h)
+#
+#   STATS, zero_stats(), add_stats(a, b)
+#       `stats` is a small int32 vector a layer counts of itself (an
+#       expert layer's assignments: models/kimi_k2.MOE_STATS); `STATS`
+#       names each entry's counter and says whether executions add up or
+#       take the maximum (empty for a model that counts nothing); the
+#       programs take the running vector in and hand it on, so the
+#       engine reads it with the tokens and nowhere else.
+# models/llama and models/gpt keep the bodies above: their compiled
+# programs are held byte-for-byte (PR 27), and moving them onto the
+# contract is ROADMAP's.
+
+def _contract_layers(model, params, x, state, stats, layer):
+    """Walk `serving_layers` in the pool's layer order.
+    layer(block, h, lp, state, l) -> (h, state, stats of the layer);
+    `state` is the cache (pages or dense), handed from layer to layer
+    and updated in place (never an xs -> ys of a scan: PR 25)."""
+    with jax.named_scope("layer"):
+        for l, (block, lp) in enumerate(model.serving_layers(params)):
+            x, state, st = layer(block, x, lp, state, jnp.int32(l))
+            stats = model.add_stats(stats, st)
+    return x, state, stats
+
+
+def decode_step_paged_contract(model, params, tokens, pools, table,
+                               positions, stats):
+    """`decode_step_paged` for a model with a cache contract.  pools: a
+    tuple of page arrays [L, P, page_size, *stored shape], one per array
+    of the contract; the rest as `decode_step_paged`.  This step's
+    entries are scattered into each slot's page BEFORE the query attends
+    (write-then-attend), the pools are carried as their flat views
+    [L * P, ...] and written in place (`_scan_layers_paged` says why).
+    Returns (logits [S, vocab], pools, stats)."""
+    positions = positions.astype(jnp.int32)
+    table = table.astype(jnp.int32)
+    L, P, ps = pools[0].shape[:3]
+    rope = model.rope_tables(table.shape[1] * ps)
+    with jax.named_scope("embed"):
+        x = model.embed_tokens(params, tokens[:, None])
+
+    def layer(block, h, lp, flat, l):
+        base = l * P
+        with jax.named_scope("attn"):
+            hn = block.input_norm(lp["input_norm"], h)
+            q, entries = block.attn.project(lp["attn"], hn, rope,
+                                            positions[:, None])
+            with jax.named_scope("kv_write"):
+                flat = tuple(
+                    _paged_write(pool, None, table, positions, e[:, 0], l,
+                                 base, 8)[0]
+                    for pool, e in zip(flat, entries))
+            attn = block.attn.attend_paged(lp["attn"], q, flat,
+                                           table + base, positions)
+            h = h + block.attn.output(lp["attn"], attn)
+        with jax.named_scope("mlp"):
+            y, st = block.mlp_stats(
+                lp["mlp"], block.post_norm(lp["post_norm"], h))
+        return h + y, flat, st
+
+    flat = tuple(p.reshape((L * P,) + p.shape[2:]) for p in pools)
+    x, flat, stats = _contract_layers(model, params, x, flat, stats, layer)
+    logits = model.logits(params, model.final_hidden(params, x))[:, 0, :]
+    return (logits, tuple(f.reshape(p.shape) for f, p in zip(flat, pools)),
+            stats)
+
+
+def extend_cache_contract(model, params, tokens, cache, start, stats):
+    """`extend_cache` for a model with a cache contract.  cache: a tuple
+    of dense per-slot caches [L, b, M, *stored shape]; tokens [b, C] at
+    positions start..start+C-1.  Returns (logits [b, C, vocab], cache,
+    stats)."""
+    b, C = tokens.shape
+    rows = jnp.arange(b)
+    start = jnp.broadcast_to(jnp.asarray(start, jnp.int32), (b,))
+    qpos = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
+    rope = model.rope_tables(cache[0].shape[2])
+    with jax.named_scope("embed"):
+        x = model.embed_tokens(params, tokens)
+
+    def layer(block, h, lp, cache, l):
+        with jax.named_scope("attn"):
+            hn = block.input_norm(lp["input_norm"], h)
+            q, entries = block.attn.project(lp["attn"], hn, rope, qpos)
+            with jax.named_scope("kv_write"):
+                cache = tuple(
+                    c.at[l, rows[:, None], qpos].set(e.astype(c.dtype))
+                    for c, e in zip(cache, entries))
+            attn = block.attn.attend_dense(
+                lp["attn"], q, tuple(c[l] for c in cache), start)
+            h = h + block.attn.output(lp["attn"], attn)
+        with jax.named_scope("mlp"):
+            y, st = block.mlp_stats(
+                lp["mlp"], block.post_norm(lp["post_norm"], h))
+        return h + y, cache, st
+
+    x, cache, stats = _contract_layers(model, params, x, tuple(cache),
+                                       stats, layer)
+    return model.logits(params, model.final_hidden(params, x)), cache, stats
+
+
 def _verify_step_paged_gpt(model, params, tokens, k_pool, v_pool, table,
                            positions, k_scale, v_scale, kv_quant,
                            return_hidden):

@@ -472,3 +472,172 @@ class MoELayer(Module):
             out = DS.make(3, {0: "ep"}).constrain(out)
         y = jnp.einsum("ech,tec->th", out, combine.astype(x.dtype))
         return y.reshape(b, s, h), aux
+
+
+# ---------------------------------------------------------------------------
+# dropless routing over the experts ONE chip holds (DeepSeek-V3 / Kimi-K2)
+# ---------------------------------------------------------------------------
+
+def noaux_tc_gate(x, w_gate, bias, *, top_k: int, norm_topk_prob: bool,
+                  routed_scaling_factor: float):
+    """The published `noaux_tc` gate with `n_group` = `topk_group` = 1,
+    in float32 as the published code computes it.  x [T, h];
+    w_gate [h, E]; bias [E] (`e_score_correction_bias`, a buffer).
+    The `top_k` experts of a token are the largest of sigmoid(x W_g) + b;
+    their weights are the sigmoid scores WITHOUT b at those experts,
+    divided by their sum (`norm_topk_prob`), times the scaling factor.
+    Returns (expert ids [T, k] int32, weights [T, k] float32)."""
+    scores = jax.nn.sigmoid(jnp.einsum(
+        "th,he->te", x.astype(jnp.float32), w_gate.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if norm_topk_prob:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * routed_scaling_factor
+
+
+def dropless_local_experts(x, idx, weights, w_gate_up, w_down, *,
+                           first_expert: int, share: float = 1.0):
+    """sum_i w_i E_i(x) over the experts HELD here: experts
+    first_expert .. first_expert + held - 1 of the router's range, each a
+    SwiGLU (w_gate_up [held, h, 2 I]: gate columns then up columns;
+    w_down [held, I, h]).  No capacity: the (token, expert) pairs are
+    sorted by expert, pairs whose expert is elsewhere go last and belong
+    to no group, and one grouped matrix product (`jax.lax.ragged_dot`)
+    walks the groups, so every pair on a held expert is computed however
+    uneven the load.  A token none of whose experts is here gets 0.
+
+    `share` is the part of all pairs expected here (held / router
+    width).  The grouped product works in row tiles of min(rows, 512)
+    and pays a whole tile for each expert it visits (compiler, PR 27),
+    so with 1/32 of the pairs here a product over all rows would
+    multiply mostly padding.  The sorted pairs on held experts are
+    walked in BLOCKS of one and a half times their expected number of
+    rows (rounded up to 64), as many blocks as they fill (a loop whose
+    trip count the device computes: none where no pair is here, one as
+    a rule): the cost follows the pairs and no pair is dropped.  Pairs
+    are not independent (a chunk's padding rows are one token and route
+    alike): 0.6-8% of executions hold more than one block's (my chip
+    run, PR 27), so nothing here assumes that they fit.
+
+    Returns (y [T, h] in x's dtype, counts [held] int32: pairs per held
+    expert, extra int32: blocks walked beyond the first)."""
+    T, h = x.shape
+    k = idx.shape[1]
+    held, inter = w_gate_up.shape[0], w_gate_up.shape[-1] // 2
+    local = idx - first_expert                              # [T, k]
+    here = (local >= 0) & (local < held)
+    key = jnp.where(here, local, held).reshape(T * k)       # elsewhere last
+    order = jnp.argsort(key, stable=True)
+    counts = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    ends = jnp.cumsum(counts)
+    n_here = ends[-1]
+    rows = min(T * k, -(-int(1.5 * share * T * k) // 64) * 64)
+    # whole blocks, so that no slice below is shifted back at the end
+    pad = -(T * k) % rows
+    token = jnp.pad((order // k).astype(jnp.int32), (0, pad))
+    w_sorted = jnp.pad(jnp.where(here, weights, 0.0).reshape(T * k)[order],
+                       (0, pad))
+
+    def block(b, y):
+        lo = b * rows
+        # each expert's pairs that fall in rows lo .. lo + rows - 1
+        sizes = (jnp.clip(ends - lo, 0, rows)
+                 - jnp.clip(ends - counts - lo, 0, rows))
+        tok = jax.lax.dynamic_slice_in_dim(token, lo, rows)
+        gu = jax.lax.ragged_dot(x[tok], w_gate_up.astype(x.dtype), sizes)
+        act = jax.nn.silu(gu[:, :inter]) * gu[:, inter:]
+        out = jax.lax.ragged_dot(act, w_down.astype(x.dtype), sizes)
+        # rows past the last group are not computed: take them as 0
+        out = jnp.where((lo + jnp.arange(rows) < n_here)[:, None],
+                        out.astype(jnp.float32), 0.0) \
+            * jax.lax.dynamic_slice_in_dim(w_sorted, lo, rows)[:, None]
+        return y.at[tok].add(out)
+
+    blocks = (n_here + rows - 1) // rows
+    y = jax.lax.fori_loop(0, blocks, block, jnp.zeros((T, h), jnp.float32))
+    return y.astype(x.dtype), counts, jnp.maximum(blocks - 1, 0)
+
+
+class SharedRoutedExperts(Module):
+    """The expert layer of DeepSeek-V3 / Kimi-K2 on ONE chip of an
+    expert-parallel deployment: it is TOLD which experts it holds
+    (`first_expert`, `experts_held`), routes every token over all
+    `n_routed_experts` (the router keeps its published width), computes
+    its own experts' part of sum_i w_i E_i(x) without dropping a token,
+    and adds the shared expert, which every chip computes alike.  With
+    all experts held it is the whole layer; across chips the parts would
+    be summed by the exchange this layer does not do (no code stands in
+    for absent chips).
+
+    forward(params, x [b, s, h]) -> (y [b, s, h], stats int32 [5] in the
+    order of `STATS`: (token, expert) pairs chosen, pairs on held
+    experts, held experts with at least one token, row blocks the
+    grouped product walked beyond the first (`dropless_local_experts`),
+    the largest load of a held expert)."""
+
+    STATS = ("assignments", "local_assignments", "expert_hits",
+             "extra_row_blocks", "max_expert_load")
+
+    def __init__(self, hidden: int, inter: int, *, n_routed_experts: int,
+                 experts_held: int, first_expert: int, top_k: int,
+                 n_shared_experts: int, norm_topk_prob: bool,
+                 routed_scaling_factor: float, param_dtype=jnp.float32,
+                 initializer_range: float = 0.02,
+                 bias_range: float = 0.02):
+        super().__init__()
+        if not 0 <= first_expert <= n_routed_experts - experts_held:
+            raise ValueError(
+                f"experts {first_expert}..{first_expert + experts_held - 1}"
+                f" are not among the router's {n_routed_experts}")
+        self.first_expert, self.held = first_expert, experts_held
+        self.share = experts_held / n_routed_experts
+        self.top_k, self.norm = top_k, norm_topk_prob
+        self.scaling = routed_scaling_factor
+        w = init.normal(initializer_range)
+        # the router's weights are float32 whatever the model's dtype:
+        # the published gate is computed in float32
+        self.param("w_gate", (hidden, n_routed_experts), w,
+                   dtype=jnp.float32)
+        # a buffer of the published model (its update rule is not part of
+        # `config`); random, small and non-zero here so that choosing by
+        # s + b and weighting by s differ
+        self.param("e_score_correction_bias", (n_routed_experts,),
+                   init.normal(bias_range), dtype=jnp.float32)
+        # gate|up fused as [.., hidden, 2 I] (gate columns, then up): a
+        # [.., hidden, 2, I] weight is tiled (2, 128) on the chip and
+        # copied whole into matmul layout at every step (compiler, PR 27)
+        self.param("w_gate_up", (experts_held, hidden, 2 * inter), w,
+                   dtype=param_dtype)
+        self.param("w_down", (experts_held, inter, hidden), w,
+                   dtype=param_dtype)
+        si = inter * n_shared_experts
+        self.param("shared_gate_up", (hidden, 2 * si), w, dtype=param_dtype)
+        self.param("shared_down", (si, hidden), w, dtype=param_dtype)
+
+    def route(self, params, xt):
+        return noaux_tc_gate(
+            xt, params["w_gate"], params["e_score_correction_bias"],
+            top_k=self.top_k, norm_topk_prob=self.norm,
+            routed_scaling_factor=self.scaling)
+
+    def forward(self, params, x):
+        b, s, h = x.shape
+        xt = x.reshape(b * s, h)
+        with jax.named_scope("router"):
+            idx, weights = self.route(params, xt)
+        with jax.named_scope("experts"):
+            y, counts, extra = dropless_local_experts(
+                xt, idx, weights, params["w_gate_up"], params["w_down"],
+                first_expert=self.first_expert, share=self.share)
+        with jax.named_scope("shared_expert"):
+            gu = xt @ params["shared_gate_up"].astype(x.dtype)
+            si = gu.shape[-1] // 2
+            y = y + (jax.nn.silu(gu[:, :si]) * gu[:, si:]) \
+                @ params["shared_down"].astype(x.dtype)
+        stats = jnp.stack([
+            jnp.int32(idx.size), jnp.sum(counts),
+            jnp.sum((counts > 0).astype(jnp.int32)), extra,
+            jnp.max(counts)])
+        return y.reshape(b, s, h), stats

@@ -154,10 +154,19 @@ def group_of(op_name: str, phases: Tuple[str, ...] = PHASES) -> str:
 #: scopes that name a group in `scope_map` beside the model phases and
 #: EXTRA_GROUPS: the paged pool's token and page writes
 #: (models/generation.py, serving/engine.py) and the cross-entropy after
-#: the head (models/llama `forward`)
-SCOPE_MAP_GROUPS = ("kv_write", "loss")
+#: the head (models/llama `forward`); the parts of latent attention
+#: (`attn` > `mla_q`, `mla_kv`, `mla_out`) and of a routed expert layer
+#: (`mlp` > `router`, `experts`, `shared_expert`) of models/kimi_k2 and
+#: nn/moe.SharedRoutedExperts.  The innermost known name is the group,
+#: so these split their parent's time and leave in `layer/attn` and
+#: `layer/mlp` what is outside them; no program without these scopes
+#: changes its groups.
+SCOPE_MAP_GROUPS = ("kv_write", "loss", "mla_q", "mla_kv", "mla_out",
+                    "router", "experts", "shared_expert")
 UNSCOPED = "unscoped"
 _INSTR_PAT = re.compile(r'^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=')
+_OPERAND_PAT = re.compile(r'%([\w.\-]+)')
+_RAGGED_DOT = "ragged-dot"
 
 
 def pass_of(op_name: str) -> str:
@@ -181,6 +190,11 @@ def scope_map(compiled_or_text) -> Dict[str, Tuple[str, str]]:
     has no `op_name` or its path names no scope (the compiler's own
     copies, a scan's slicing and stacking of its operands, the loss
     scaling around the micro-batch loop).  `pass` is `pass_of`.
+    The TPU compiler lowers `jax.lax.ragged_dot` to custom calls whose
+    `op_name` is "ragged-dot-none", the jit path dropped: such a call
+    takes the group and pass of its first operand that has a scope (the
+    rows it multiplies), so a grouped product stays in the scope that
+    made its input.
     Instruction names are unique within a module, not across modules:
     keep one map per program."""
     phases = (*PHASES, *SCOPE_MAP_GROUPS)
@@ -194,6 +208,11 @@ def scope_map(compiled_or_text) -> Dict[str, Tuple[str, str]]:
         group = group_of(op_name, phases)
         out[m.group(1)] = (UNSCOPED if group == "other" else group,
                            pass_of(op_name))
+        if op_name.startswith(_RAGGED_DOT):
+            out[m.group(1)] = next(
+                (out[o] for o in _OPERAND_PAT.findall(line[m.end():])
+                 if out.get(o, (UNSCOPED,))[0] != UNSCOPED),
+                out[m.group(1)])
     return out
 
 

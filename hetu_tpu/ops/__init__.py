@@ -9,7 +9,9 @@ functional forms plus the hand-written Pallas kernels for the hot ops
 """
 from hetu_tpu.ops.activations import gelu, silu, swiglu, relu, leaky_relu, mish, softplus, hardswish, sigmoid, dropout
 from hetu_tpu.ops.norms import rms_norm, layer_norm, residual_rms_norm, residual_layer_norm
-from hetu_tpu.ops.rotary import build_rope_cache, apply_rotary, apply_rotary_qk
+from hetu_tpu.ops.rotary import (build_rope_cache, apply_rotary, apply_rotary_qk,
+                                 build_yarn_rope_cache, yarn_inv_freq,
+                                 yarn_mscale)
 from hetu_tpu.ops.losses import (
     softmax_cross_entropy,
     softmax_cross_entropy_sparse,

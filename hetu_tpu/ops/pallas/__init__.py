@@ -16,6 +16,9 @@ The fused-kernel layer (docs/kernels.md):
   * paged_verify     — the multi-query sibling: k+1 speculative query
                        positions per slot attend the same pages in one
                        launch (spec-decode verification)
+  * paged_latent_attention — the paged decode kernel of latent (MLA)
+                       attention: one pool of [c_kv | k_rope] vectors,
+                       each page read once and used as key and value
   * sample           — fused last-layer epilogue: lm_head matmul +
                        temperature/top-k/top-p filter + Gumbel draw per
                        row without materializing [rows, vocab] logits
@@ -47,7 +50,7 @@ from typing import FrozenSet, Optional, Tuple
 
 #: every routable kernel name (the HETU_TPU_PALLAS_KERNELS vocabulary)
 KERNEL_NAMES = ("flash", "norm", "swiglu", "rotary", "quant", "paged_attn",
-                "paged_verify", "sample", "adam")
+                "paged_verify", "sample", "adam", "paged_latent")
 
 
 def _interpret() -> bool:

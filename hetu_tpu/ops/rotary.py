@@ -79,3 +79,57 @@ def apply_rotary_qk(q, k, cos, sin, position_ids: Optional[jnp.ndarray] = None,
                 q, k, cos_t.astype(jnp.float32), sin_t.astype(jnp.float32))
     return (apply_rotary(q, cos, sin, position_ids),
             apply_rotary(k, cos, sin, position_ids))
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's magnitude correction m(mscale) = 0.1 mscale ln(factor) + 1
+    (1 for factor <= 1): attention scores are multiplied by its square
+    where the model's `mscale_all_dim` is set (DeepSeek-V3, Kimi-K2)."""
+    import math
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(head_dim: int, base: float, *, factor: float,
+                  original_max_position_embeddings: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0):
+    """YaRN's per-pair inverse frequencies [head_dim // 2] (Peng et al.
+    2023, as DeepSeek-V3's and Kimi-K2's published rotary code computes
+    them): pair i keeps base^(-2i/d) where it makes more than
+    `beta_fast` rotations over the original context, takes that over
+    `factor` where it makes fewer than `beta_slow`, and a linear blend
+    over the pair index between the two correction dimensions."""
+    import math
+    d, L = head_dim, original_max_position_embeddings
+
+    def correction_dim(rotations):
+        return d * math.log(L / (rotations * 2 * math.pi)) / (
+            2 * math.log(base))
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), d - 1)
+    extra = base ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    keep = 1.0 - ramp                  # 1: extrapolate (unscaled pair)
+    return extra / factor * (1.0 - keep) + extra * keep
+
+
+def build_yarn_rope_cache(max_len: int, head_dim: int, base: float, *,
+                          factor: float,
+                          original_max_position_embeddings: int,
+                          beta_fast: float = 32.0, beta_slow: float = 1.0,
+                          mscale: float = 1.0, mscale_all_dim: float = 0.0,
+                          dtype=jnp.float32):
+    """cos/sin tables [max_len, head_dim // 2] under YaRN scaling, built
+    to `max_len` positions (the caller's, not the model's 262,144).  The
+    tables carry the factor m(mscale) / m(mscale_all_dim), which is 1
+    where the two are equal."""
+    inv_freq = yarn_inv_freq(
+        head_dim, base, factor=factor,
+        original_max_position_embeddings=original_max_position_embeddings,
+        beta_fast=beta_fast, beta_slow=beta_slow)
+    freqs = jnp.outer(jnp.arange(max_len, dtype=jnp.float32), inv_freq)
+    m = yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)
+    return ((jnp.cos(freqs) * m).astype(dtype),
+            (jnp.sin(freqs) * m).astype(dtype))

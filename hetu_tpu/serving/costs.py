@@ -75,6 +75,26 @@ class CostModel:
             page_size=page_size,
             wire_bytes_per_token=wire_bytes_per_token)
 
+    @staticmethod
+    def from_model(model, *, num_params: float, page_size: int,
+                   kv_mode: str = "fp32",
+                   wire_bytes_per_token: float = 4.0) -> "CostModel":
+        """Price from the model itself: the cache bytes a token occupies
+        come from its cache contract (models/cache_contract.py), the one
+        place `PagePool` and the engine size the cache from too: a latent
+        of 576 values is 1,152 B a layer in bf16, K and V of 8 x 128 are
+        4,096 B."""
+        from hetu_tpu.models.cache_contract import cache_contract
+        from hetu_tpu.serving.kv_pool import contract_bytes_per_token
+        c = model.config
+        return CostModel(
+            flops_per_token=2.0 * float(num_params),
+            attn_flops_per_ctx=4.0 * c.num_hidden_layers * c.hidden_size,
+            kv_bytes_per_token=contract_bytes_per_token(
+                cache_contract(model), kv_mode),
+            page_size=page_size,
+            wire_bytes_per_token=wire_bytes_per_token)
+
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
 

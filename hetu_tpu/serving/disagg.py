@@ -108,6 +108,12 @@ class PrefillWorker:
         self.sampling = sampling
         self._registry = registry
         c = model.config
+        from hetu_tpu.models.cache_contract import has_cache_contract
+        if has_cache_contract(model):
+            raise NotImplementedError(
+                f"{type(model).__name__} brings its own cache contract; the "
+                "disaggregated prefill tier (serving/disagg.py) ships K/V "
+                "scratch only and is not built for it")
         n_kv = getattr(c, "num_key_value_heads", c.num_attention_heads)
         shape = (c.num_hidden_layers, 1, max_len, n_kv, c.head_dim)
         self._scratch = (jnp.zeros(shape, c.compute_dtype),

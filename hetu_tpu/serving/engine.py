@@ -60,6 +60,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from hetu_tpu.models.cache_contract import (cache_contract,
+                                            has_cache_contract)
 from hetu_tpu.models.generation import (_check_context_length,
                                         decode_step_slots, extend_cache)
 from hetu_tpu.obs.health import maybe_serving_health_monitor
@@ -289,13 +291,18 @@ class ServingEngine:
         self.config = config or ServeConfig.from_flags()
         c = model.config
         _check_context_length(c, self.config.max_len)
-        n_kv = getattr(c, "num_key_value_heads", c.num_attention_heads)
-        self.pool = PagePool(
-            num_layers=c.num_hidden_layers,
-            num_pages=self.config.num_pages,
-            page_size=self.config.page_size,
-            num_kv_heads=n_kv, head_dim=c.head_dim,
-            dtype=c.compute_dtype, quant=self.config.kv_quant)
+        # what a token stores a layer is the MODEL's to say
+        # (models/cache_contract.py): the pool, the prefill scratch and
+        # the cache-byte gauges are all sized from this one contract
+        self.cache = cache_contract(model)
+        #: the model brings its own cache contract: the serving programs
+        #: are the contract's (models/generation.*_contract)
+        self._contract_programs = has_cache_contract(model)
+        if self._contract_programs:
+            self._refuse_unbuilt(reshard, draft_model, drafter)
+        self.pool = PagePool.for_contract(
+            self.cache, num_pages=self.config.num_pages,
+            page_size=self.config.page_size, quant=self.config.kv_quant)
         # radix prefix cache (serving/prefix_cache.py): shared prompt
         # prefixes admit with their pages already resident
         self.prefix_cache = None
@@ -435,18 +442,53 @@ class ServingEngine:
                                      eb["fp_bytes"])
 
         # per-request prefill scratch: a dense [L, 1, max_len] cache the
-        # chunk program advances; template zeros reused (functionally)
-        # for every admission
-        shape = (c.num_hidden_layers, 1, self.config.max_len, n_kv,
-                 c.head_dim)
-        self._scratch = (jnp.zeros(shape, c.compute_dtype),
-                         jnp.zeros(shape, c.compute_dtype))
+        # chunk program advances, one array per array of the contract;
+        # template zeros reused (functionally) for every admission
+        self._scratch = tuple(
+            jnp.zeros((self.cache.num_layers, 1, self.config.max_len)
+                      + tuple(shape), c.compute_dtype)
+            for shape in self.cache.stored_shapes)
+        from hetu_tpu.serving.kv_pool import contract_bytes_per_token
+        mode = (self.config.kv_quant if self.config.kv_quant != "none" else
+                {2: "bf16", 4: "fp32"}[jnp.dtype(c.compute_dtype).itemsize])
+        self._registry.set_gauge(
+            "serve.kv_bytes_per_token",
+            contract_bytes_per_token(self.cache, mode))
+        #: the running stats vector of the contract programs (what
+        #: `model.STATS` names: an expert model's assignment counts), on
+        #: the device between fetches; None for the K/V families, whose
+        #: programs carry none
+        self._stats_zero = (model.zero_stats() if self._contract_programs
+                            else None)
+        self._stats_acc = self._stats_zero
         # every kernel routing decision of this engine — the static ones
         # _build_programs takes, then each program's as it is traced —
         # with its reason (ops/pallas.record_routes)
         self.kernel_routes: dict = {}
         with record_routes(self.kernel_routes):
             self._build_programs()
+
+    def _refuse_unbuilt(self, reshard, draft_model, drafter):
+        """A model that brings its own cache contract (latent attention)
+        runs the normal path; what this engine has only for K/V pools is
+        refused here by name, never run as something else."""
+        cfg, name = self.config, type(self.model).__name__
+        unbuilt = {
+            "speculative decoding (spec_decode / verify_step_*)":
+                cfg.spec_decode != "none" or drafter is not None
+                or draft_model is not None,
+            "the radix prefix cache (prefix_cache)": cfg.prefix_cache,
+            "int8 / int4 pages (kv_quant)": cfg.kv_quant != "none",
+            "resident quantized experts (moe_dispatch int8 / int4, "
+            "serving/experts.py)": cfg.moe_dispatch in ("int8", "int4"),
+            "the reshard hook (serving/reshard.py)": reshard is not None,
+        }
+        asked = [what for what, on in unbuilt.items() if on]
+        if asked:
+            raise NotImplementedError(
+                f"{name} stores {self.cache.token_shapes} a token a layer "
+                f"(its own cache contract); not built for it: "
+                + "; ".join(asked))
 
     # ------------------------------------------------------------ build
     def _recording(self, fn):
@@ -469,6 +511,10 @@ class ServingEngine:
         causally-masked query positions per slot per launch.  Evaluated
         once at build: the decision is static, like every other program
         shape."""
+        if self._contract_programs:
+            # one decode program, gather-free over the page table; which
+            # attention it calls there is the model's route
+            return True
         from hetu_tpu.ops.pallas import paged_attention as _pa
         from hetu_tpu.ops.pallas import resolve_route
         c = self.model.config
@@ -546,6 +592,27 @@ class ServingEngine:
         def write_fn(pool_tree, pages_row, ks, vs):
             with jax.named_scope("kv_write"):
                 return pool.write_pages(pool_tree, pages_row, ks, vs)
+
+        if self._contract_programs:
+            from hetu_tpu.models.generation import (
+                decode_step_paged_contract, extend_cache_contract)
+
+            def decode_fn(params, pool_tree, table, tokens, positions,
+                          stats, *sample_args):
+                logits, pools, stats = decode_step_paged_contract(
+                    model, params, tokens, pool_tree, table, positions,
+                    stats)
+                nxt = pick_token(logits, positions, sample_args)
+                # the stats ride out behind the tokens: one fetch
+                return jnp.concatenate([nxt, stats]), pools
+
+            def chunk_fn(params, chunk, cache, start, stats):
+                return extend_cache_contract(model, params, chunk, cache,
+                                             start, stats)
+
+            def write_fn(pool_tree, pages_row, *caches):
+                with jax.named_scope("kv_write"):
+                    return pool.write_pages(pool_tree, pages_row, *caches)
 
         # speculative-decoding verify (serving/spec_decode.py): score
         # the last token + k drafts in one multi-query forward —
@@ -797,18 +864,19 @@ class ServingEngine:
         `lower_programs` abstracts."""
         S, C = self.config.num_slots, self.config.prefill_chunk
         max_pages = self.scheduler.max_pages
+        stats = self._stats_args()
         if program == "prefill_chunk":
             return (self.params, jnp.zeros((1, C), jnp.int32),
-                    self._scratch, jnp.int32(0))
+                    self._scratch, jnp.int32(0), *stats)
         if program == "write_pages":
             return (self.pool.arrays.tree(), jnp.zeros(max_pages, jnp.int32),
-                    self._scratch[0][:, 0], self._scratch[1][:, 0])
+                    *(a[:, 0] for a in self._scratch))
         table = jnp.zeros((S, max_pages), jnp.int32)
         pos = jnp.zeros(S, jnp.int32)
         sample_args = self._sample_args([]) if self.config.sampling else ()
         if program == "decode":
             return (self.params, self.pool.arrays.tree(), table,
-                    jnp.zeros(S, jnp.int32), pos, *sample_args)
+                    jnp.zeros(S, jnp.int32), pos, *stats, *sample_args)
         if program != "verify":
             raise ValueError(f"unknown program {program!r}")
         K1 = self.config.spec_k + 1
@@ -851,7 +919,8 @@ class ServingEngine:
         else:
             nxt, tree = self._run_decode(*self._dummy_args("decode"))
         self.pool.arrays = PoolArrays.from_tree(tree)
-        lg, cache = self._chunk_jit(*self._dummy_args("prefill_chunk"))
+        lg, cache = self._chunk_jit(
+            *self._dummy_args("prefill_chunk"))[:2]
         tree = self._run_write(*self._dummy_args("write_pages"))
         self.pool.arrays = PoolArrays.from_tree(tree)
         if self._prime_jit is not None:
@@ -1104,13 +1173,15 @@ class ServingEngine:
                             self.params, self.pool.arrays.tree(),
                             self._decode_table(active),
                             jnp.asarray(tokens), jnp.asarray(positions),
-                            *sample_args)
+                            *self._stats_args(), *sample_args)
                     with phase_span("serve.decode_dispatch", phases):
                         nxt, pool_tree = self._run_decode(*decode_args)
                     with phase_span("serve.token_fetch", phases):
                         # the step's one wait for the device
                         nxt = np.asarray(nxt)
                     with phase_span("serve.emit", phases):
+                        if self._contract_programs:
+                            self._note_program_stats(nxt[S:])
                         self.pool.arrays = PoolArrays.from_tree(pool_tree)
                         emitted = {i: [int(nxt[i])] for i in active}
                 with phase_span("serve.emit", phases):
@@ -1214,6 +1285,25 @@ class ServingEngine:
                 self._last_clock = clock()
         self._note_step_phases(now, time.perf_counter() - t0, phases)
         return finished
+
+    def _stats_args(self) -> tuple:
+        """The extra argument of the contract programs: the running
+        stats vector (none for the K/V families' programs)."""
+        return () if self._stats_acc is None else (self._stats_acc,)
+
+    def _note_program_stats(self, values):
+        """The contract programs' running stats vector, fetched behind
+        the step's tokens, into the counters the MODEL names
+        (`model.STATS`: (counter, "sum" | "max") per entry): a sum as an
+        increment, a maximum as the running maximum; the device-side
+        vector starts again from zero."""
+        for (name, how), v in zip(self.model.STATS, values):
+            more = int(v)
+            if how == "max":
+                more -= self._registry.counter_value(name)
+            if more > 0:
+                self._registry.inc(name, more)
+        self._stats_acc = self._stats_zero
 
     def _note_step_phases(self, now: float, step_s: float, phases: dict):
         """The finished step's phase record into the registry, and into
@@ -1594,9 +1684,12 @@ class ServingEngine:
             ids = np.zeros(C, np.int32)
             seg = req.prompt[s: min(s + C, plen)]
             ids[: len(seg)] = seg
-            logits, st.prefill_cache = self._chunk_jit(
+            out = self._chunk_jit(
                 self.params, jnp.asarray(ids[None]), st.prefill_cache,
-                jnp.int32(s))
+                jnp.int32(s), *self._stats_args())
+            logits, st.prefill_cache = out[:2]
+            if self._contract_programs:
+                self._stats_acc = out[2]
             st.chunks_done += 1
             st.stats.prefill_chunks += 1
             self._registry.inc("serve.prefill_chunks")
@@ -1625,8 +1718,7 @@ class ServingEngine:
             pages_row[: base // self.pool.page_size] = PagePool.NULL_PAGE
             tree = self._run_write(self.pool.arrays.tree(),
                                    jnp.asarray(pages_row),
-                                   st.prefill_cache[0][:, 0],
-                                   st.prefill_cache[1][:, 0])
+                                   *(a[:, 0] for a in st.prefill_cache))
             self.pool.arrays = PoolArrays.from_tree(tree)
             if self.prefix_cache is not None:
                 # index the finished prompt: full page-blocks not yet

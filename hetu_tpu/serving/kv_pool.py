@@ -30,6 +30,11 @@ both paged Pallas kernels unpacking the nibbles in-VMEM.  The exact fp
 path is the default and stores pages in the model's compute dtype —
 byte-identical semantics to `init_cache`.
 
+What a token stores comes from the model's cache contract
+(models/cache_contract.py, `PagePool.for_contract`): K and V arrays as
+above, or ONE array of the model's own token shape (a latent-attention
+model: `[L, num_pages, page_size, 640]`, exact pages only).
+
 Host side (allocator, free list) is plain Python; device side
 (gather/scatter) is pure-functional jax, jitted by the engine.
 """
@@ -48,6 +53,22 @@ from hetu_tpu.comm.compress import dequantize_blockwise, quantize_blockwise
 #: analytic bytes per element for each page mode (int8 carries one f32
 #: scale per head-vector block of `head_dim` elements)
 _ELEM_BYTES = {"fp32": 4.0, "bf16": 2.0, "fp16": 2.0}
+
+
+def contract_bytes_per_token(contract, mode: str = "fp32") -> float:
+    """Cache bytes one token position occupies over all layers, from the
+    model's cache contract (models/cache_contract.py): the values a
+    token STORES (1,152 B a layer for a latent of 576 in bf16), not the
+    lanes the device pads them to.  K/V contracts go through
+    `kv_bytes_per_token`, which knows the quantized page modes."""
+    if contract.kind == "kv":
+        n_kv, hd = contract.token_shapes[0]
+        return kv_bytes_per_token(contract.num_layers, n_kv, hd, mode)
+    if mode not in _ELEM_BYTES:
+        raise ValueError(f"a {len(contract.token_shapes)}-array cache "
+                         f"contract has exact pages only, not {mode!r}")
+    return (contract.num_layers * contract.values_per_token_layer
+            * _ELEM_BYTES[mode])
 
 
 def kv_bytes_per_token(num_layers: int, num_kv_heads: int, head_dim: int,
@@ -110,18 +131,20 @@ class PoolArrays:
     """The device-side pool state threaded through the engine's jitted
     step (a pytree: quant scales are None in the exact mode)."""
     k: jnp.ndarray
-    v: jnp.ndarray
+    v: Optional[jnp.ndarray] = None     # None: a one-array (latent) pool
     k_scale: Optional[jnp.ndarray] = None
     v_scale: Optional[jnp.ndarray] = None
 
     def tree(self):
+        if self.v is None:
+            return (self.k,)
         if self.k_scale is None:
             return (self.k, self.v)
         return (self.k, self.v, self.k_scale, self.v_scale)
 
     @staticmethod
     def from_tree(t) -> "PoolArrays":
-        return PoolArrays(*t) if len(t) == 4 else PoolArrays(t[0], t[1])
+        return PoolArrays(*t)
 
 
 def repage_arrays(arrays: PoolArrays, mesh) -> PoolArrays:
@@ -152,13 +175,42 @@ class PagePool:
 
     NULL_PAGE = 0
 
+    @classmethod
+    def for_contract(cls, contract, *, num_pages: int, page_size: int,
+                     quant: str = "none", **kw) -> "PagePool":
+        """The pool of a model's cache contract
+        (models/cache_contract.py): what a token stores comes from the
+        model, not from `num_key_value_heads` x `head_dim`."""
+        if contract.kind == "kv":
+            n_kv, hd = contract.token_shapes[0]
+            what = dict(num_kv_heads=n_kv, head_dim=hd)
+        else:
+            (stored,) = contract.stored_shapes
+            what = dict(token_shape=tuple(stored))
+        return cls(num_layers=contract.num_layers, num_pages=num_pages,
+                   page_size=page_size, dtype=contract.dtype, quant=quant,
+                   **what, **kw)
+
     def __init__(self, *, num_layers: int, num_pages: int, page_size: int,
-                 num_kv_heads: int, head_dim: int,
+                 num_kv_heads: Optional[int] = None,
+                 head_dim: Optional[int] = None,
                  dtype=jnp.float32, quant: str = "none",
-                 device_arrays: bool = True):
+                 device_arrays: bool = True,
+                 token_shape: Optional[Tuple[int, ...]] = None):
         if quant not in ("none", "int8", "int4"):
             raise ValueError(f"kv quant mode {quant!r} invalid; "
                              "choices: ('none', 'int8', 'int4')")
+        if (token_shape is None) == (num_kv_heads is None
+                                     or head_dim is None):
+            raise ValueError("a pool holds K and V arrays of num_kv_heads "
+                             "x head_dim, or ONE array of token_shape")
+        #: a ONE-array pool's stored shape per token ((640,) for a latent);
+        #: None = the K and V arrays of `num_kv_heads` x `head_dim`
+        self.token_shape = token_shape
+        if token_shape is not None and quant != "none":
+            raise ValueError(
+                f"kv_quant={quant!r}: int8/int4 pages are built for K/V "
+                f"pools only, not for one array of {token_shape} a token")
         if quant == "int4" and head_dim % 2:
             raise ValueError(f"int4 pages need an even head_dim, "
                              f"got {head_dim}")
@@ -173,14 +225,16 @@ class PagePool:
         self.quant = quant
         #: payload bit width of the stored pages (8 also covers fp modes)
         self.quant_bits = 4 if quant == "int4" else 8
-        shape = (num_layers, num_pages + 1, page_size, num_kv_heads,
-                 head_dim)
+        shape = (num_layers, num_pages + 1, page_size) + (
+            token_shape or (num_kv_heads, head_dim))
         if not device_arrays:
             # host-only pool (serving/fleet.py's discrete-event sim): the
             # allocator / refcount / page-table machinery is the real
             # thing, but no device memory is ever touched — a 10^6-page
             # pool costs one numpy array, not gigabytes of jnp.zeros
             self.arrays = None
+        elif token_shape is not None:
+            self.arrays = PoolArrays(k=jnp.zeros(shape, dtype))
         elif quant == "int4":
             pshape = shape[:-1] + (head_dim // 2,)
             self.arrays = PoolArrays(
@@ -272,7 +326,6 @@ class PagePool:
         L = self.num_layers
         S, mp = table.shape
         M = mp * self.page_size
-
         def dense(pool, scale):
             g = pool[:, table]       # [L, S, mp, ps, n_kv, hd(/2)]
             if self.quant == "int4":
@@ -340,14 +393,18 @@ class PagePool:
         nv, nvs = put(a.v, a.v_scale, v_toks)
         return PoolArrays(nk, nv, nks, nvs).tree()
 
-    def write_pages(self, arrays_tree, pages_row, ks, vs):
+    def write_pages(self, arrays_tree, pages_row, ks, vs=None):
         """Bulk-write a prefilled sequence's K/V into its pages.
         pages_row: [mp] int32 page ids (pad unused tail entries with the
         null page — their garbage lands in page 0); ks/vs:
-        [L, mp*page_size, n_kv, hd]."""
+        [L, mp*page_size, n_kv, hd]; a one-array pool takes its one
+        dense cache [L, mp*page_size, *token_shape] as `ks`."""
         a = PoolArrays.from_tree(arrays_tree)
         L = self.num_layers
         mp = pages_row.shape[0]
+        if self.token_shape is not None:
+            x = ks.reshape((L, mp, self.page_size) + self.token_shape)
+            return (a.k.at[:, pages_row].set(x.astype(a.k.dtype)),)
         paged_shape = (L, mp, self.page_size, self.num_kv_heads,
                        self.head_dim)
 

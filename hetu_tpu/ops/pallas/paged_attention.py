@@ -5,50 +5,84 @@ The serving engine's decode step (PR 7 follow-up, closed here) used to
 GATHER every slot's pages into a dense [S, max_len, n_kv, hd] view per
 layer before attending — three passes over the cache bytes (gather read,
 dense write, attention read), most of them over DEAD tail positions.
-This kernel walks each slot's page list via scalar-prefetched block
-index maps (the splash-attention technique the flash kernel already
-uses for its live-pair tables): grid (slot, page_slot), with the K/V
-BlockSpec index maps reading `table[s, p]` so each grid step DMAs ONE
-page straight from the pool.  Pages past the slot's live length are
-scheduled but compute-skipped (`pl.when`); the null page (id 0) that
-inactive slots point at is masked the same way the dense path masks it
-(position mask over the global key index).
+This kernel reads each slot's live pages straight from the pool, once.
 
-Online-softmax accumulation across a slot's pages mirrors the flash
-forward; GQA folds grouped q heads against the pool's kv heads via an
-in-VMEM reshape (no materialized repeat).  Decode is forward-only — no
-vjp (the training path keeps flash attention).
+**The walk** (`_walk_live_blocks`, the one page walk of this module).
+The grid is the slots: one grid step a slot, whatever the table's
+width.  The pools stay whole in HBM (`memory_space=pl.ANY`); the page
+table and the positions are scalar-prefetched.  Inside a grid step a
+loop runs over BLOCKS of `pages_per_block` pages, and its trip count is
+the slot's own: ``ceil(live_pages / pages_per_block)`` with
+``live_pages = (positions[s] + C - 1) // page_size + 1``.  A page slot
+past a slot's length costs nothing — no grid step, no copy, no
+arithmetic — and an idle or prefilling slot (the engine sends it at
+position 0 with a null table row) costs one grid step and one page.
+A block's pages are not contiguous in the pool, so each is fetched by a
+copy of the kernel's own (`pltpu.make_async_copy`, one per page and
+pool array) into a VMEM buffer of ``[pages_per_block * page_size, n_kv,
+hd]``; the buffers are double: while block b is computed, block b + 1
+— or, in a slot's last block, the NEXT slot's first block — is in
+flight, so no slot waits for its first page but slot 0.  That makes the
+slot axis sequential (`"arbitrary"`): a copy started in one grid step
+is waited for in the next.  In a slot's last block only the live pages
+are fetched.
 
-int8 pages (``HETU_TPU_KV_QUANT=int8``, the PR 9 "exact-fp pages only"
-gap closed): the kernel takes the pool's per-head-vector f32 absmax
-scales as two extra page-indexed operands and dequantizes each page
-IN-VMEM (``k * scale``) right after the DMA — the HBM read is the int8
-payload (+ the small scale plane), ~3.9x fewer cache bytes per decode
-step than fp32 pages (ops/pallas/traffic.paged_attn_traffic prices it;
-`detail.kernels` records the row).  The token K/V scattered pre-kernel
-quantize through the SAME blockwise primitives the gather path uses
-(comm/compress -> ops/pallas/quant when routed), so pool contents are
-bit-identical across the two decode programs.
+**A block's arithmetic** is one online-softmax update (the float32
+running max, sum and accumulator of the flash forward), not one per
+page.  A block ``[T, n_kv, hd]`` is, by a free reshape, ``T * n_kv``
+keys of ``hd``: ALL query heads are scored against all of them in one
+MXU product ``[rows, hd] x [hd, T * n_kv]``, the entries whose KV head
+is not the query head's (``qh // group != kvh``) are masked together
+with the positions past the slot's, and ``p . v`` runs over the same
+flat axis.  It spends ``n_kv`` times the arithmetic, which is nothing
+beside the bytes, and needs no relayout of a page: no per-head batched
+product, no transpose (on a v5e the per-head form is 3-5 times slower:
+PERF.md s6, PR 28).  The two products take the pool's dtype with
+float32 accumulation (bfloat16 pools: ``q . k`` exact product for
+product; float32 pools keep float32 products).  The value rows past the
+slot's last position are zeroed in its last block, so that what a
+buffer holds there (an earlier block, or nothing yet) cannot reach the
+result through ``0 * x``; nothing past a slot's length is read from the
+pool at all.  GQA, the null page (id 0) of inactive slots, the position
+mask over the global key index and the softmax scale are what they were.
+Decode is forward-only — no vjp (the training path keeps flash
+attention).
 
-int4 pages (``HETU_TPU_KV_QUANT=int4``) push the same trick to nibble
-storage: the pool holds uint8 payloads of HALF the head dim packed via
-`ops/quantization.pack_nibbles` (even index = LOW nibble, values offset
-by +8) plus the same per-head-vector f32 scale plane; the kernel unpacks
-and dequantizes in-VMEM (``(nibble - 8) * scale``), ~7.5x fewer cache
-bytes than fp32 pages at hd=128.
+`pages_per_block` is derived here from what the wrapper sees (page
+size, heads, head dim, element size, table width, query rows): as many
+pages as hold `_BLOCK_TOKENS` tokens within `_VMEM_BUDGET` of buffers
+and temporaries, 1 where a page is already that large.  No caller
+chooses it.
+
+int8 pages (``HETU_TPU_KV_QUANT=int8``): the pool's per-head-vector f32
+absmax scales ride in as two more HBM arrays, fetched by the same
+per-page copies (through `scale_table` where their page ids differ), and
+a block is dequantized IN-VMEM (``k * scale``) after its copies land —
+the HBM read is the int8 payload (+ the small scale plane), ~3.9x fewer
+cache bytes per decode step than fp32 pages
+(ops/pallas/traffic.paged_attn_traffic prices it).  The token K/V
+scattered pre-kernel quantize through the SAME blockwise primitives the
+gather path uses (comm/compress -> ops/pallas/quant when routed), so
+pool contents are bit-identical across the two decode programs.  int4
+pages (``HETU_TPU_KV_QUANT=int4``): uint8 payloads of HALF the head dim
+packed via `ops/quantization.pack_nibbles` (even index = LOW nibble,
+values offset by +8) plus the same scale plane, unpacked and
+dequantized in-VMEM (``(nibble - 8) * scale``).  Quantized blocks are
+multiplied in float32, as they were.
 
 `paged_verify` is the multi-query sibling (spec-decode verification):
 q carries C = k+1 query positions per slot, all attending the slot's
 pages in ONE launch with per-position causal masks (query i sees keys
-at global positions <= positions[s] + i).  Same page walk, same online
-softmax with C*nq accumulator rows, same none/int8/int4 page modes —
-it replaces the gather program `verify_step_slots` used to dispatch
-(three passes over the cache bytes) with one pass over the quantized
-pool.
+at global positions <= positions[s] + i).  It is the same kernel with
+C * nq query rows (decode is C = 1): same walk, same block update, same
+none/int8/int4 page modes.
 
 Shape contract (drift-tested against `compatible`/`verify_compatible`):
 hd % 128, q heads divide by kv heads, table/positions/q agree on the
-slot count, scales present iff quant, pool head dim halved for int4."""
+slot count, scales present iff quant, pool head dim halved for int4,
+one page's buffers and temporaries within `_VMEM_LIMIT`, and a page's
+row of KV heads at least one 32-bit word wide (bfloat16 pages of ONE
+KV head are refused: Mosaic cannot slice such a page out of the pool)."""
 from __future__ import annotations
 
 import functools
@@ -63,9 +97,42 @@ from hetu_tpu.ops.pallas import _interpret
 
 NEG_INF = -1e30
 
+# A block's size (PERF.md s6, PR 28: the sweep on a v5e at the serving
+# cells' shape).  The tokens a block holds when the budget allows, the
+# VMEM its two buffers per pool array and the block's temporaries may
+# take of the 16 MiB a kernel gets by default, and what ONE page may need
+# before the shape gate sends the shape to the gather fallback.
+_BLOCK_TOKENS = 256
+_VMEM_BUDGET = 6 << 20
+_VMEM_LIMIT = 12 << 20
 
-def _check_pool(q_heads_hd, pool_shape, table_shape, pos_shape, S, *,
-                quant: str) -> Tuple[int, int, int]:
+
+def _token_vmem_bytes(rows: int, n_kv: int, hd: int, itemsize: int,
+                      quant: str) -> int:
+    """VMEM bytes one cached token of a block takes: K and V in their two
+    buffers each, for quantized pages their scale rows (a page's row
+    padded to 8 sublanes) and the payload as float32, and the float32
+    scores and probabilities of `rows` query rows against its `n_kv`
+    keys."""
+    hd_p = hd // 2 if quant == "int4" else hd
+    nbytes = 2 * 2 * n_kv * hd_p * itemsize + 3 * rows * n_kv * 4
+    if quant != "none":
+        nbytes += 2 * 2 * 8 * n_kv * 4 + 2 * n_kv * hd * 4
+    return nbytes
+
+
+def pages_per_block(rows: int, ps: int, n_kv: int, hd: int, itemsize: int,
+                    max_pages: int, quant: str = "none") -> int:
+    """Pages the walk fetches and attends at once, from the shapes alone:
+    `_BLOCK_TOKENS` tokens where `_VMEM_BUDGET` holds them, fewer where
+    it does not, never more than the table is wide, at least one."""
+    tokens = min(_BLOCK_TOKENS, _VMEM_BUDGET // _token_vmem_bytes(
+        rows, n_kv, hd, itemsize, quant))
+    return max(1, min(tokens // ps, max_pages))
+
+
+def _check_pool(rows, q_heads_hd, pool_shape, table_shape, pos_shape, S, *,
+                quant: str, pool_dtype) -> Tuple[int, int, int]:
     nq, hd = q_heads_hd
     if quant not in ("none", "int8", "int4"):
         raise ValueError(f"paged-attention page mode {quant!r} "
@@ -84,23 +151,37 @@ def _check_pool(q_heads_hd, pool_shape, table_shape, pos_shape, S, *,
     if hd % 128:
         raise ValueError(f"head dim {hd} is not lane-aligned (% 128); "
                          f"the gather fallback handles it")
+    # element size: the pool's where the caller knows it, else the widest
+    # a page mode stores (float32 pages, one-byte quantized payloads)
+    itemsize = (jnp.dtype(pool_dtype).itemsize if pool_dtype is not None
+                else 4 if quant == "none" else 1)
+    if n_kv * itemsize < 4 and quant == "none":
+        raise ValueError(f"a page's row of {n_kv} KV head(s) of "
+                         f"{itemsize}-byte elements is under one 32-bit "
+                         f"word; the gather fallback handles it")
+    page_bytes = ps * _token_vmem_bytes(rows, n_kv, hd, itemsize, quant)
+    if page_bytes > _VMEM_LIMIT:
+        raise ValueError(f"one page of {ps} tokens needs {page_bytes} "
+                         f"bytes of VMEM (limit {_VMEM_LIMIT}); the gather "
+                         f"fallback handles it")
     return P, ps, n_kv
 
 
 def check_shapes(q_shape, pool_shape, table_shape, pos_shape, *,
-                  quant: str = "none"
+                  quant: str = "none", pool_dtype=None
                   ) -> Tuple[int, int, int, int, int, int]:
     if len(q_shape) != 3 or len(pool_shape) != 4:
         raise ValueError(f"expected q [S, nq, hd] and pool [P, ps, n_kv, "
                          f"hd], got {q_shape} / {pool_shape}")
     S, nq, hd = q_shape
-    P, ps, n_kv = _check_pool((nq, hd), pool_shape, table_shape,
-                              pos_shape, S, quant=quant)
+    P, ps, n_kv = _check_pool(nq, (nq, hd), pool_shape, table_shape,
+                              pos_shape, S, quant=quant,
+                              pool_dtype=pool_dtype)
     return S, nq, hd, P, ps, n_kv
 
 
 def check_shapes_verify(q_shape, pool_shape, table_shape, pos_shape, *,
-                         quant: str = "none"
+                         quant: str = "none", pool_dtype=None
                          ) -> Tuple[int, int, int, int, int, int, int]:
     if len(q_shape) != 4 or len(pool_shape) != 4:
         raise ValueError(f"expected q [S, C, nq, hd] and pool [P, ps, "
@@ -109,166 +190,188 @@ def check_shapes_verify(q_shape, pool_shape, table_shape, pos_shape, *,
     if C < 1:
         raise ValueError(f"verify needs at least one query position, "
                          f"got C={C}")
-    P, ps, n_kv = _check_pool((nq, hd), pool_shape, table_shape,
-                              pos_shape, S, quant=quant)
+    P, ps, n_kv = _check_pool(C * nq, (nq, hd), pool_shape, table_shape,
+                              pos_shape, S, quant=quant,
+                              pool_dtype=pool_dtype)
     return S, C, nq, hd, P, ps, n_kv
 
 
 def compatible(q_shape, pool_shape, table_shape, pos_shape, *,
-               quant: str = "none") -> bool:
+               quant: str = "none", pool_dtype=None) -> bool:
     try:
         check_shapes(q_shape, pool_shape, table_shape, pos_shape,
-                      quant=quant)
+                      quant=quant, pool_dtype=pool_dtype)
         return True
     except ValueError:
         return False
 
 
 def verify_compatible(q_shape, pool_shape, table_shape, pos_shape, *,
-                      quant: str = "none") -> bool:
+                      quant: str = "none", pool_dtype=None) -> bool:
     try:
         check_shapes_verify(q_shape, pool_shape, table_shape, pos_shape,
-                             quant=quant)
+                             quant=quant, pool_dtype=pool_dtype)
         return True
     except ValueError:
         return False
 
 
-def _load_page(page_ref, scale_ref, *, quant, ps, n_kv, hd):
-    """DMA'd page block -> dequantized f32 [ps, n_kv, hd] in VMEM."""
-    x = page_ref[0]
-    if quant == "none":
-        return x.astype(jnp.float32)
+def _walk_live_blocks(pages_of, streams, sem, parity, carry, block, *,
+                      ppb):
+    """The page walk: `block(b, buffer, carry, last)` over the live blocks
+    of this grid step's slot, each block's pages fetched by per-page
+    copies into the double buffers while the block before is computed.
+
+    `pages_of(s)` is slot s's live page count (>= 1); `streams` is
+    ``(table_ref, hbm_ref, vmem_ref)`` per pool array, `hbm_ref`
+    ``[P, ...]`` read at ``table_ref[s, page_slot]`` into `vmem_ref`
+    ``[2, ppb, ...]``; `sem` is DMA semaphores ``[2, len(streams)]``
+    (a buffer's copies of one array share one, each waited for by its
+    own size); `parity` is an SMEM word that carries, from one grid step
+    to the next, which buffer the slot's first block was fetched into —
+    by the LAST block of the slot before it, so the slot axis must run
+    in order.  A block's copies are issued only for its live pages.
+    Returns the carry after the slot's last block, which alone is called
+    with ``last=True``."""
+    s_idx, n_slots = pl.program_id(0), pl.num_programs(0)
+    n_pages = pages_of(s_idx)
+    nb = (n_pages + ppb - 1) // ppb
+
+    def each_live_page(s, live, b, buffer, do):
+        # a loop of the block's live pages, not `ppb` unrolled branches:
+        # a dead page slot costs nothing, on the scalar core either, and
+        # the kernel's code does not grow with the block
+        def page(j, carry):
+            for i, (table_ref, hbm_ref, vmem_ref) in enumerate(streams):
+                do(pltpu.make_async_copy(
+                    hbm_ref.at[table_ref[s, b * ppb + j]],
+                    vmem_ref.at[buffer, j], sem.at[buffer, i]))
+            return carry
+        jax.lax.fori_loop(0, jnp.clip(live - b * ppb, 0, ppb), page, 0)
+
+    @pl.when(s_idx == 0)
+    def _first_slot():
+        parity[0] = 0
+        each_live_page(s_idx, n_pages, 0, 0, lambda c: c.start())
+    first = parity[0]
+
+    def fetch_and(b, carry, last):
+        buffer = jax.lax.rem(first + b, 2)
+        # what follows this block: the slot's next one, or the next
+        # slot's first (none after the last slot's last)
+        s_next = s_idx if not last else jnp.minimum(s_idx + 1, n_slots - 1)
+        live_next = (n_pages if not last else
+                     jnp.where(s_idx + 1 < n_slots, pages_of(s_next), 0))
+        each_live_page(s_next, live_next, 0 if last else b + 1, 1 - buffer,
+                       lambda c: c.start())
+        each_live_page(s_idx, n_pages, b, buffer, lambda c: c.wait())
+        return block(b, buffer, carry, last)
+
+    carry = jax.lax.fori_loop(
+        0, nb - 1, lambda b, c: fetch_and(b, c, False), carry)
+    carry = fetch_and(nb - 1, carry, True)
+    parity[0] = jax.lax.rem(first + nb, 2)
+    return carry
+
+
+def _load_block(page_ref, buffer, *, quant, hd):
+    """A fetched block ``[ppb, ps, n_kv, hd_p]`` -> ``[T * n_kv, hd]`` keys
+    (or values) in the dtype they are multiplied in: the pool's own for
+    exact pages, the integer payload as float32 for int8/int4 (its scales
+    go on the scores and the probabilities: `_scale_row`)."""
+    x = page_ref[buffer]
     if quant == "int4":
-        # unpack the nibble payload [ps, n_kv, hd//2] (even index = LOW
-        # nibble, ops/quantization.pack_nibbles layout, +8 offset)
+        # unpack the nibble payload [.., hd//2] (even index = LOW nibble,
+        # ops/quantization.pack_nibbles layout, +8 offset)
         p8 = x.astype(jnp.uint8)
         lo = (p8 & 0xF).astype(jnp.int32) - 8
         hi = (p8 >> 4).astype(jnp.int32) - 8
-        x = jnp.stack((lo, hi), axis=-1).reshape(ps, n_kv, hd)
-    x = x.astype(jnp.float32)
-    # one f32 absmax scale per head-vector (the kv_pool blockwise layout)
-    return x * scale_ref[0].astype(jnp.float32)[..., None]
-
-
-def _kernel(*refs, scale, ps, n_kv, group, mp, quant):
+        x = jnp.stack((lo, hi), axis=-1).reshape(x.shape[:-1] + (hd,))
     if quant != "none":
-        (table_ref, pos_ref, stable_ref, q_ref, k_ref, v_ref, ks_ref,
-         vs_ref, o_ref, m_scr, l_scr, acc_scr) = refs
-    else:
-        (table_ref, pos_ref, q_ref, k_ref, v_ref,
-         o_ref, m_scr, l_scr, acc_scr) = refs
-        ks_ref = vs_ref = None
-    s_idx = pl.program_id(0)
-    p = pl.program_id(1)
-
-    @pl.when(p == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    pos = pos_ref[s_idx]
-
-    # page p holds global positions [p*ps, (p+1)*ps); skip the compute
-    # body for wholly-future pages (they are scheduled — the grid is
-    # static — but move no math; their DMA reads the null page)
-    @pl.when(p * ps <= pos)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)               # [nq, hd]
-        nq, hd = q.shape
-        k = _load_page(k_ref, ks_ref, quant=quant, ps=ps, n_kv=n_kv, hd=hd)
-        v = _load_page(v_ref, vs_ref, quant=quant, ps=ps, n_kv=n_kv, hd=hd)
-        qg = q.reshape(n_kv, group, hd)
-        s = jax.lax.dot_general(
-            qg, k, (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * scale  # [n_kv, g, ps]
-        kpos = p * ps + jax.lax.broadcasted_iota(jnp.int32, (1, 1, ps), 2)
-        s = jnp.where(kpos <= pos, s, NEG_INF)
-        sf = s.reshape(nq, ps)
-
-        m_prev = m_scr[:]                               # [nq, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(sf, axis=1, keepdims=True))
-        p_ = jnp.exp(sf - m_new)                        # [nq, ps]
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[:] = l_scr[:] * corr + jnp.sum(p_, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p_.reshape(n_kv, group, ps), v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)          # [n_kv, g, hd]
-        acc_scr[:] = acc_scr[:] * corr + pv.reshape(nq, hd)
-        m_scr[:] = m_new
-
-    @pl.when(p == mp - 1)
-    def _fin():
-        l = l_scr[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+        x = x.astype(jnp.float32)
+    return x.reshape(-1, hd)
 
 
-def _verify_kernel(*refs, scale, C, ps, n_kv, group, mp, quant):
-    """Multi-query form: the slot's q block carries C = k+1 positions;
-    accumulator rows are laid out (n_kv, C, group) so the grouped-GQA
-    contraction stays a single batched dot per page."""
+def _scale_row(scale_ref, buffer):
+    """A fetched block's scales ``[ppb, 1, ps * n_kv]`` -> the row
+    ``[1, T * n_kv]`` that lies along a block's keys: one f32 absmax
+    scale per head-vector (the kv_pool blockwise layout), so a key's
+    scale is a factor of its score and a value's of its probability."""
+    rows = scale_ref[buffer]
+    return jnp.concatenate([rows[j] for j in range(rows.shape[0])], axis=1)
+
+
+def _kernel(*refs, scale, C, ps, ppb, n_kv, group, mp, quant):
+    """One grid step = one slot: its ``C * nq`` query rows (row r is query
+    position r // nq, head r % nq) against its live blocks."""
     if quant != "none":
-        (table_ref, pos_ref, stable_ref, q_ref, k_ref, v_ref, ks_ref,
-         vs_ref, o_ref, m_scr, l_scr, acc_scr) = refs
+        (table_ref, pos_ref, stable_ref, q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm,
+         o_ref, k_buf, v_buf, ks_buf, vs_buf, sem, parity) = refs
+        streams = ((table_ref, k_hbm, k_buf), (table_ref, v_hbm, v_buf),
+                   (stable_ref, ks_hbm, ks_buf), (stable_ref, vs_hbm, vs_buf))
     else:
-        (table_ref, pos_ref, q_ref, k_ref, v_ref,
-         o_ref, m_scr, l_scr, acc_scr) = refs
-        ks_ref = vs_ref = None
-    s_idx = pl.program_id(0)
-    p = pl.program_id(1)
+        (table_ref, pos_ref, q_ref, k_hbm, v_hbm,
+         o_ref, k_buf, v_buf, sem, parity) = refs
+        ks_buf = vs_buf = None
+        streams = ((table_ref, k_hbm, k_buf), (table_ref, v_hbm, v_buf))
+    T = ppb * ps
+    rows, hd = q_ref.shape[1:]
+    nq = rows // C
+    pos = pos_ref[pl.program_id(0)]
 
-    @pl.when(p == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def pages_of(s):
+        # the LAST query position (pos + C - 1) decides which pages hold
+        # any visible key
+        return jnp.minimum((pos_ref[s] + C - 1) // ps + 1, mp)
 
-    pos = pos_ref[s_idx]
+    # the products take the pool's dtype (quantized payloads: float32)
+    q = q_ref[0].astype(jnp.float32 if quant != "none" else k_buf.dtype)
+    # column j of a block is token j // n_kv, KV head j % n_kv
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, T * n_kv), 1)
+    q_head = jax.lax.rem(row, nq) if C > 1 else row
+    q_pos = pos + (jax.lax.div(row, nq) if C > 1 else 0)      # [rows, 1]
+    own_head = jax.lax.rem(col, n_kv) == jax.lax.div(q_head, group)
 
-    # the LAST query position (pos + C - 1) decides which pages hold any
-    # visible keys; wholly-future pages move no math
-    @pl.when(p * ps <= pos + (C - 1))
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)               # [C, nq, hd]
-        nq, hd = q.shape[1], q.shape[2]
-        k = _load_page(k_ref, ks_ref, quant=quant, ps=ps, n_kv=n_kv, hd=hd)
-        v = _load_page(v_ref, vs_ref, quant=quant, ps=ps, n_kv=n_kv, hd=hd)
-        rows = n_kv * C * group
-        qg = q.reshape(C, n_kv, group, hd).transpose(1, 0, 2, 3) \
-              .reshape(n_kv, C * group, hd)
+    def block(b, buffer, carry, last):
+        m_prev, l_prev, acc = carry
+        k = _load_block(k_buf, buffer, quant=quant, hd=hd)
+        v = _load_block(v_buf, buffer, quant=quant, hd=hd)
         s = jax.lax.dot_general(
-            qg, k, (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * scale  # [n_kv, C*g, ps]
-        # per-position causal mask: query i sees keys at global
-        # positions <= pos + i
-        ci = jax.lax.broadcasted_iota(jnp.int32, (1, C, 1, ps), 1)
-        kp = p * ps + jax.lax.broadcasted_iota(jnp.int32, (1, C, 1, ps), 3)
-        s = jnp.where(kp <= pos + ci, s.reshape(n_kv, C, group, ps),
-                      NEG_INF)
-        sf = s.reshape(rows, ps)
-
-        m_prev = m_scr[:]                               # [rows, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(sf, axis=1, keepdims=True))
-        p_ = jnp.exp(sf - m_new)                        # [rows, ps]
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [rows, T * n_kv]
+        if quant != "none":
+            s = s * _scale_row(ks_buf, buffer)
+        if last or C > 1:
+            # query row r sees the keys at global positions <= q_pos[r]
+            seen = own_head & (col < (q_pos - b * T + 1) * n_kv)
+        else:
+            seen = own_head             # a block before the last is all live
+        if last:
+            # rows of the buffer past the slot's last token hold whatever
+            # they held: they must not reach the result through 0 * x
+            v_row = jax.lax.broadcasted_iota(jnp.int32, (T * n_kv, 1), 0)
+            v = jnp.where(v_row < (pos + C - b * T) * n_kv, v,
+                          jnp.zeros_like(v))
+        s = jnp.where(seen, s, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p_ = jnp.exp(s - m_new)        # an unseen key's is exp(-1e30) = 0
         corr = jnp.exp(m_prev - m_new)
-        l_scr[:] = l_scr[:] * corr + jnp.sum(p_, axis=1, keepdims=True)
+        l_new = l_prev * corr + jnp.sum(p_, axis=1, keepdims=True)
+        if quant != "none":
+            # (a page not fetched has no scales: 0 * x again)
+            p_ = jnp.where(seen, p_ * _scale_row(vs_buf, buffer), 0.0)
         pv = jax.lax.dot_general(
-            p_.reshape(n_kv, C * group, ps), v,
-            (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)          # [n_kv, C*g, hd]
-        acc_scr[:] = acc_scr[:] * corr + pv.reshape(rows, hd)
-        m_scr[:] = m_new
+            p_.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # [rows, hd]
+        return m_new, l_new, acc * corr + pv
 
-    @pl.when(p == mp - 1)
-    def _fin():
-        l = l_scr[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        hd = o_ref.shape[3]
-        o = (acc_scr[:] / l_safe).reshape(n_kv, C, group, hd) \
-            .transpose(1, 0, 2, 3).reshape(C, n_kv * group, hd)
-        o_ref[0] = o.astype(o_ref.dtype)
+    carry = (jnp.full((rows, 1), NEG_INF, jnp.float32),
+             jnp.zeros((rows, 1), jnp.float32),
+             jnp.zeros((rows, hd), jnp.float32))
+    _, l, acc = _walk_live_blocks(pages_of, streams, sem, parity, carry,
+                                  block, ppb=ppb)
+    o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
 def _resolve_quant(quant, k_scale, v_scale):
@@ -284,7 +387,7 @@ def _resolve_quant(quant, k_scale, v_scale):
 
 def _scalar_prefetch(table, positions, scale_table, k_scale, P, ps, n_kv,
                      quant):
-    """The kernels' scalar-prefetch operands: (table, positions) and,
+    """The kernel's scalar-prefetch operands: (table, positions) and,
     for quantized pages, the page ids the SCALE planes are read by —
     `scale_table` into planes of their own page count, or `table`
     itself into planes of the payload's P pages."""
@@ -304,6 +407,49 @@ def _scalar_prefetch(table, positions, scale_table, k_scale, P, ps, n_kv,
     return scalars + [scale_table.astype(jnp.int32)]
 
 
+def _attend(q, k_pool, v_pool, scalars, k_scale, v_scale, *, C, scale,
+            quant):
+    """q ``[S, C * nq, hd]`` over the pools: the one `pallas_call` of
+    this module (decode is C = 1)."""
+    S, rows, hd = q.shape
+    _, ps, n_kv, hd_p = k_pool.shape
+    mp = scalars[0].shape[1]
+    ppb = pages_per_block(rows, ps, n_kv, hd, k_pool.dtype.itemsize, mp,
+                          quant)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    operands = [q, k_pool, v_pool]
+    scratch = [pltpu.VMEM((2, ppb, ps, n_kv, hd_p), k_pool.dtype),
+               pltpu.VMEM((2, ppb, ps, n_kv, hd_p), v_pool.dtype)]
+    if quant != "none":
+        # a page's scales as ONE lane-dense row: Mosaic slices no page
+        # out of a plane whose minor dim is the few KV heads
+        operands += [x.reshape(x.shape[0], 1, ps * n_kv)
+                     for x in (k_scale, v_scale)]
+        scratch += [pltpu.VMEM((2, ppb, 1, ps * n_kv), x.dtype)
+                    for x in (k_scale, v_scale)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars),
+        grid=(S,),
+        in_specs=[pl.BlockSpec((1, rows, hd), lambda s, *_: (s, 0, 0))]
+        + [hbm] * (len(operands) - 1),
+        out_specs=pl.BlockSpec((1, rows, hd), lambda s, *_: (s, 0, 0)),
+        scratch_shapes=scratch + [
+            pltpu.SemaphoreType.DMA((2, len(operands) - 1)),
+            pltpu.SMEM((1,), jnp.int32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel, scale=scale, C=C, ps=ps, ppb=ppb,
+                          n_kv=n_kv, group=rows // C // n_kv, mp=mp,
+                          quant=quant),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, rows, hd), q.dtype),
+        # in order: a slot's last block fetches the next slot's first
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(),
+    )(*scalars, *operands)
+
+
 def paged_attention(q, k_pool, v_pool, table, positions, *,
                     softmax_scale: Optional[float] = None,
                     k_scale=None, v_scale=None, quant=None,
@@ -311,7 +457,8 @@ def paged_attention(q, k_pool, v_pool, table, positions, *,
     """Decode attention over paged KV.  q: [S, nq, hd] (one token per
     slot); k_pool/v_pool: [P, page_size, n_kv, hd] (page 0 = the null
     page); table: [S, max_pages] int32 page ids; positions: [S] int32 —
-    slot s attends over global positions <= positions[s].  int8 pools
+    slot s attends over global positions <= positions[s], and only the
+    table entries of the pages that hold them are read.  int8 pools
     pass their per-head-vector f32 scales [P, page_size, n_kv] as
     k_scale/v_scale and dequantize in-kernel; int4 pools additionally
     pass ``quant="int4"`` (uint8 nibble payloads, pool head dim hd//2).
@@ -324,48 +471,13 @@ def paged_attention(q, k_pool, v_pool, table, positions, *,
     handles those)."""
     quant = _resolve_quant(quant, k_scale, v_scale)
     S, nq, hd, P, ps, n_kv = check_shapes(
-        q.shape, k_pool.shape, table.shape, positions.shape, quant=quant)
+        q.shape, k_pool.shape, table.shape, positions.shape, quant=quant,
+        pool_dtype=k_pool.dtype)
     scalars = _scalar_prefetch(table, positions, scale_table, k_scale,
                                P, ps, n_kv, quant)
-    mp = table.shape[1]
-    group = nq // n_kv
     scale = softmax_scale if softmax_scale is not None else hd ** -0.5
-    hd_p = k_pool.shape[-1]
-
-    page_spec = pl.BlockSpec((1, ps, n_kv, hd_p),
-                             lambda s, p, tab, *_: (tab[s, p], 0, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, nq, hd), lambda s, p, *_: (s, 0, 0)),
-        page_spec, page_spec,
-    ]
-    operands = [q, k_pool, v_pool]
-    if quant != "none":
-        scale_spec = pl.BlockSpec(
-            (1, ps, n_kv), lambda s, p, tab, pos, stab: (stab[s, p], 0, 0))
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scale, v_scale]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(scalars),
-        grid=(S, mp),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, nq, hd),
-                               lambda s, p, *_: (s, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((nq, 1), jnp.float32),
-            pltpu.VMEM((nq, 1), jnp.float32),
-            pltpu.VMEM((nq, hd), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel, scale=scale, ps=ps, n_kv=n_kv,
-                          group=group, mp=mp, quant=quant),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, nq, hd), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=_interpret(),
-    )(*scalars, *operands)
+    return _attend(q, k_pool, v_pool, scalars, k_scale, v_scale, C=1,
+                   scale=scale, quant=quant)
 
 
 def paged_verify(q, k_pool, v_pool, table, positions, *,
@@ -381,46 +493,11 @@ def paged_verify(q, k_pool, v_pool, table, positions, *,
     models/generation handles those)."""
     quant = _resolve_quant(quant, k_scale, v_scale)
     S, C, nq, hd, P, ps, n_kv = check_shapes_verify(
-        q.shape, k_pool.shape, table.shape, positions.shape, quant=quant)
+        q.shape, k_pool.shape, table.shape, positions.shape, quant=quant,
+        pool_dtype=k_pool.dtype)
     scalars = _scalar_prefetch(table, positions, scale_table, k_scale,
                                P, ps, n_kv, quant)
-    mp = table.shape[1]
-    group = nq // n_kv
     scale = softmax_scale if softmax_scale is not None else hd ** -0.5
-    hd_p = k_pool.shape[-1]
-    rows = n_kv * C * group
-
-    page_spec = pl.BlockSpec((1, ps, n_kv, hd_p),
-                             lambda s, p, tab, *_: (tab[s, p], 0, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, C, nq, hd), lambda s, p, *_: (s, 0, 0, 0)),
-        page_spec, page_spec,
-    ]
-    operands = [q, k_pool, v_pool]
-    if quant != "none":
-        scale_spec = pl.BlockSpec(
-            (1, ps, n_kv), lambda s, p, tab, pos, stab: (stab[s, p], 0, 0))
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scale, v_scale]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(scalars),
-        grid=(S, mp),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, C, nq, hd),
-                               lambda s, p, *_: (s, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, hd), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_verify_kernel, scale=scale, C=C, ps=ps,
-                          n_kv=n_kv, group=group, mp=mp, quant=quant),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, C, nq, hd), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=_interpret(),
-    )(*scalars, *operands)
+    out = _attend(q.reshape(S, C * nq, hd), k_pool, v_pool, scalars,
+                  k_scale, v_scale, C=C, scale=scale, quant=quant)
+    return out.reshape(S, C, nq, hd)

@@ -172,7 +172,12 @@ def paged_attn_traffic(slots: int, max_pages: int, page_size: int,
     """Paged decode vs the gather path: the fallback gathers every
     slot's pages into a dense [S, max_len] view (read pool, write
     dense) and the attention reads the dense view back — three passes
-    over the cache bytes.  The kernel DMAs each scheduled page once.
+    over the cache bytes.  The kernel copies each LIVE page once and no
+    page slot past a slot's length (ops/pallas/paged_attention: the
+    walk's trip count is the slot's own), so its read follows the
+    tokens the slots hold; this function prices the WHOLE table on both
+    sides, `max_pages` a slot — the kernel's bound with every slot at
+    `max_len`, which is where the ratio of the two is taken.
 
     ``quant="int8"`` prices the int8-page mode (serving/kv_pool.py:
     1 byte/elem + one f32 scale per head-vector): the kernel's read is

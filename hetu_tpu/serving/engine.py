@@ -529,11 +529,13 @@ class ServingEngine:
                        c.num_attention_heads, c.head_dim)
             return resolve_route(
                 "paged_verify", _pa.check_shapes_verify, q_shape, pool_shape,
-                table_shape, (S,), quant=self.pool.quant)
+                table_shape, (S,), quant=self.pool.quant,
+                pool_dtype=self.pool.arrays.k.dtype)
         q_shape = (S, c.num_attention_heads, c.head_dim)
         return resolve_route("paged_attn", _pa.check_shapes, q_shape,
                              pool_shape, table_shape, (S,),
-                             quant=self.pool.quant)
+                             quant=self.pool.quant,
+                             pool_dtype=self.pool.arrays.k.dtype)
 
     def _build_programs(self):
         model, pool = self.model, self.pool

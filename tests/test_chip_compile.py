@@ -152,6 +152,22 @@ def _paged(quant, n_kv, verify_c=0):
                 spec((slots, max_pages), I32), spec((slots,), I32), *scales)
 
 
+def _paged_serving_cell():
+    """The paged kernel as the benchmark's InternLM2 serving cells call
+    it inside the layer scan: 32 slots, 16 query / 8 KV heads of 128,
+    128 table entries of 16-token pages, bfloat16, and as the pool every
+    layer's 2048 pages in ONE flat array, the layer's pages reached
+    through the table (`models/generation._scan_layers_paged`)."""
+    from hetu_tpu.ops.pallas.paged_attention import paged_attention
+    slots, layers, pages = 32, 24, 2048
+    pool = spec((layers * pages, 16, 8, HEAD_DIM), BF16)
+
+    def fn(q, k, v, table, pos):
+        return paged_attention(q, k, v, table + 5 * pages, pos)
+    return fn, (spec((slots, 16, HEAD_DIM), BF16), pool, pool,
+                spec((slots, 128), I32), spec((slots,), I32))
+
+
 def _quant(bits):
     from hetu_tpu.ops.pallas.quant import quantize_blockwise_pallas
     return (lambda x: quantize_blockwise_pallas(x, 128, bits=bits),
@@ -176,6 +192,7 @@ KERNEL_CASES = {
     "paged_attention_fp_gqa": lambda: _paged(False, 8),
     "paged_attention_int8": lambda: _paged(True, HEADS),
     "paged_verify_c5": lambda: _paged(False, HEADS, verify_c=5),
+    "paged_attention_serving_cell": _paged_serving_cell,
     "quant_int8": lambda: _quant(8),
     "quant_int4": lambda: _quant(4),
 }
@@ -185,6 +202,24 @@ KERNEL_CASES = {
 def test_kernel_compiles_for_v5e(case):
     fn, args = KERNEL_CASES[case]()
     assert "tpu_custom_call" in compile_text(fn, *args)
+
+
+def test_paged_kernel_takes_the_serving_cells_pool_as_it_lies():
+    """At the serving cells' shape the kernel reads the flat pool of all
+    layers where it lies: the pools are whole-array HBM operands
+    (`pl.ANY`), so the program around the call holds no temporary, let
+    alone a copy of a 1.6 GB pool in another layout (PR 27 found a 2.0 GB
+    layout copy of the latent pool this way), and the kernel's double
+    buffers fit the default VMEM of a call with room to spare."""
+    from hetu_tpu.ops.pallas import paged_attention as pa
+    fn, args = _paged_serving_cell()
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    ppb = pa.pages_per_block(16, 16, 8, HEAD_DIM, 2, 128)
+    assert ppb * 16 == pa._BLOCK_TOKENS
+    assert ppb * 16 * pa._token_vmem_bytes(16, 8, HEAD_DIM, 2, "none") \
+        <= pa._VMEM_BUDGET
 
 
 # ---------------------------------------------------------------------------

@@ -368,6 +368,125 @@ def test_paged_verify_parity(quant):
                                atol=FWD_TOL)
 
 
+# the walk (PR 28): a slot's live pages in blocks, fetched by the kernel's
+# own copies.  Each case is (q heads, kv heads, page size, table width,
+# tokens a block holds (None: the module's own), tokens each slot holds
+# (1 = an idle slot: position 0 over a null table row), pool dtype,
+# page ids permuted, tolerance).
+_WALK_CASES = {
+    # 21 = two pages and five tokens of a third: the context ends mid-page
+    "ends_mid_page": (4, 2, 8, 8, 24, [21, 5, 38], "float32", False),
+    # a block is 3 pages x 8 tokens: one, two and three whole blocks
+    "ends_on_a_block_boundary": (4, 2, 8, 9, 24, [24, 48, 72], "float32",
+                                 False),
+    "one_token_over_a_null_row": (4, 2, 8, 8, 24, [1, 30, 1], "float32",
+                                  False),
+    "slot_at_max_pages_full": (4, 2, 8, 6, 24, [48, 48, 17], "float32",
+                               False),
+    # 7 page slots in blocks of 3: the last block is one page wide
+    "table_not_a_multiple_of_the_block": (4, 2, 8, 7, 24, [56, 50, 9],
+                                          "float32", False),
+    "permuted_page_ids": (4, 2, 8, 8, 24, [21, 64, 38], "float32", True),
+    "group_2_16q_8kv": (16, 8, 8, 8, 24, [21, 1, 60], "float32", True),
+    "group_4_32q_8kv": (32, 8, 8, 8, 24, [21, 1, 60], "float32", True),
+    "one_page_a_block": (4, 2, 8, 8, 8, [21, 1, 64], "float32", True),
+    # the module's own block (256 tokens = 16 pages of 16): one block,
+    # two, and two with a single live page in the second
+    "the_modules_own_block": (4, 2, 16, 40, None, [300, 1, 257, 512],
+                              "float32", True),
+    # bfloat16 pages against the float32 reference: the products round
+    # q, k, p and v to bfloat16 (8 bits of mantissa: 2**-8 relative)
+    "bfloat16_pools": (16, 8, 16, 8, 48, [21, 100, 1], "bfloat16", True),
+}
+
+
+def _walk_inputs(case, seed=11):
+    nq, n_kv, ps, mp, block_tokens, lengths, dtype, permute = \
+        _WALK_CASES[case]
+    rng = np.random.default_rng(seed)
+    S, hd = len(lengths), 128
+    P = S * mp + 1
+    ids = np.arange(1, P)
+    if permute:
+        ids = rng.permutation(ids)
+    table = ids.reshape(S, mp).astype(np.int32)
+    for s, n in enumerate(lengths):
+        if n == 1:
+            table[s] = 0        # an idle slot: position 0, the null row
+    dt = jnp.dtype(dtype)
+    kp = jnp.asarray(rng.standard_normal((P, ps, n_kv, hd),
+                                         dtype=np.float32)).astype(dt)
+    vp = jnp.asarray(rng.standard_normal((P, ps, n_kv, hd),
+                                         dtype=np.float32)).astype(dt)
+    q = jnp.asarray(rng.standard_normal((S, nq, hd),
+                                        dtype=np.float32)).astype(dt)
+    positions = jnp.asarray(np.asarray(lengths) - 1, jnp.int32)
+    return q, kp, vp, jnp.asarray(table), positions, block_tokens
+
+
+@pytest.mark.parametrize("case", _WALK_CASES)
+def test_paged_attention_walk_parity(case, monkeypatch):
+    """The block walk against the dense gather+mask reference, a case
+    per edge of the walk."""
+    q, kp, vp, table, positions, block_tokens = _walk_inputs(case)
+    if block_tokens is not None:
+        monkeypatch.setattr(paged_attention, "_BLOCK_TOKENS", block_tokens)
+    ps, mp = kp.shape[1], table.shape[1]
+    ppb = paged_attention.pages_per_block(
+        q.shape[1], ps, kp.shape[2], q.shape[2], kp.dtype.itemsize, mp)
+    assert ppb == min((block_tokens or 256) // ps, mp)
+    out = paged_attention.paged_attention(q, kp, vp, table, positions)
+    f32 = jnp.float32
+    ref = _dense_paged_reference(q.astype(f32), kp.astype(f32),
+                                 vp.astype(f32), table, positions)
+    assert out.dtype == q.dtype
+    # float32 pools: float32 products.  bfloat16 pools: the output is
+    # bfloat16 (2**-8 relative at |out| <= ~1.5) over bfloat16 products
+    tol = FWD_TOL if kp.dtype == f32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out.astype(f32)),
+                               np.asarray(ref), atol=tol)
+
+
+@pytest.mark.parametrize("kernel", ["decode", "verify_c3"])
+def test_paged_walk_reads_nothing_past_a_slots_length(kernel, monkeypatch):
+    """Every table entry names a page of its own; every page past a
+    slot's length, and the tail of its last live page, is NaN (the null
+    page kept): the result is what it was, and finite."""
+    monkeypatch.setattr(paged_attention, "_BLOCK_TOKENS", 24)
+    rng = np.random.default_rng(13)
+    C = 3 if kernel == "verify_c3" else 1
+    S, ps, mp, n_kv, nq, hd = 4, 8, 8, 2, 4, 128
+    P = S * mp + 1
+    table = rng.permutation(np.arange(1, P)).reshape(S, mp).astype(np.int32)
+    lengths = np.asarray([21, C, 48, 58])       # up to the LAST query position
+    kp = rng.standard_normal((P, ps, n_kv, hd), dtype=np.float32)
+    vp = rng.standard_normal((P, ps, n_kv, hd), dtype=np.float32)
+    kn, vn = kp.copy(), vp.copy()
+    for s, n in enumerate(lengths):
+        for slot in range(mp):
+            live = min(max(n - slot * ps, 0), ps)
+            kn[table[s, slot], live:] = np.nan
+            vn[table[s, slot], live:] = np.nan
+    assert np.isfinite(kn[0]).all() and np.isnan(kn).sum() > kn.size // 3
+    positions = jnp.asarray(lengths - C, jnp.int32)
+    table = jnp.asarray(table)
+    if C == 1:
+        q = jnp.asarray(rng.standard_normal((S, nq, hd), dtype=np.float32))
+        fn, ref = paged_attention.paged_attention, _dense_paged_reference
+    else:
+        q = jnp.asarray(rng.standard_normal((S, C, nq, hd),
+                                            dtype=np.float32))
+        fn, ref = paged_attention.paged_verify, _dense_verify_reference
+    clean = fn(q, jnp.asarray(kp), jnp.asarray(vp), table, positions)
+    poisoned = fn(q, jnp.asarray(kn), jnp.asarray(vn), table, positions)
+    assert np.isfinite(np.asarray(poisoned)).all()
+    np.testing.assert_array_equal(np.asarray(poisoned), np.asarray(clean))
+    np.testing.assert_allclose(
+        np.asarray(clean),
+        np.asarray(ref(q, jnp.asarray(kp), jnp.asarray(vp), table,
+                       positions)), atol=FWD_TOL)
+
+
 # ---------------------------------------------------------------------------
 # gate/kernel drift: the gate's verdict must MATCH what the kernel
 # actually accepts (satellite 2 — extended to every kernel's gate)
@@ -446,6 +565,31 @@ def test_gate_drift_paged(shapes):
     pos = jnp.zeros(pos_s, jnp.int32)
     assert paged_attention.compatible(qs, pool_s, ts, pos_s) == _accepts(
         paged_attention.paged_attention, q, kp, kp, table, pos)
+
+
+@pytest.mark.parametrize("shapes,dtype,takes", [
+    (((3, 4, 128), (9, 8, 2, 128), (3, 4), (3,)), "bfloat16", True),
+    # one bfloat16 KV head: a page's row is under a 32-bit word
+    (((3, 4, 128), (9, 8, 1, 128), (3, 4), (3,)), "bfloat16", False),
+    (((3, 4, 128), (9, 8, 1, 128), (3, 4), (3,)), "float32", True),
+    # one page alone over the kernel's VMEM limit (33 MB of buffers)
+    (((3, 32, 128), (9, 512, 32, 128), (3, 4), (3,)), "float32", False),
+    (((3, 32, 128), (9, 128, 32, 128), (3, 4), (3,)), "bfloat16", True),
+])
+def test_gate_drift_paged_pool_dtype(shapes, dtype, takes):
+    """The gate told the pool's dtype (as the engine tells it) agrees
+    with the kernel on the limits that depend on the element size."""
+    qs, pool_s, ts, pos_s = shapes
+    q = jnp.zeros(qs, dtype)
+    kp = jnp.zeros(pool_s, dtype)
+    table = jnp.zeros(ts, jnp.int32)
+    pos = jnp.zeros(pos_s, jnp.int32)
+    for gate, fn, q_ in (
+            (paged_attention.compatible, paged_attention.paged_attention, q),
+            (paged_attention.verify_compatible,
+             paged_attention.paged_verify, q[:, None])):
+        assert gate(q_.shape, pool_s, ts, pos_s, pool_dtype=dtype) == takes
+        assert _accepts(fn, q_, kp, kp, table, pos) == takes
 
 
 @pytest.mark.parametrize("shapes", [

@@ -1,4 +1,4 @@
-"""Autoregressive generation with a KV cache.
+"""Autoregressive generation with a KV cache, and the serving programs.
 
 Rebuild of the reference's model-generation surface (reference:
 python/hetu/models/utils/model_utils.py PreTrainedModel generate path; the
@@ -6,29 +6,61 @@ reference is training-first and so are we — this is the functional decode
 loop for eval/demo, TPU-shaped: static max length, lax.scan decode, cache as
 a pytree carried through the scan).
 
-Works with both model families' stacked-scan parameter layouts: the
-per-layer KV caches are stacked [L, b, max_len, n_kv, hd] and the decode
-step scans layers with the cache rows as per-layer xs/ys.  prefill and
-decode_step dispatch on the family (LLaMA: RMSNorm/rotary/fused-GQA QKV;
-GPT: LayerNorm/wpe/biased fused QKV).
+ONE set of programs, written against hooks the model brings: no program
+here knows a family.  What a token stores is the model's cache contract
+(models/cache_contract.py); how its entry is made and how a query attends
+it are the model's hooks:
 
-Serving-facing surface (hetu_tpu/serving, docs/serving.md): the decode
-step also comes in a slot-masked form — `decode_step_slots` takes a
-PER-SLOT position vector (each batch row is an independent sequence at
-its own depth) and returns this step's per-layer K/V so a paged cache
-can scatter them into its pool — and `extend_cache` is the multi-token
-(chunked-prefill) sibling that advances a cache by a whole token block.
+  embed_tokens(params, ids, pos_ids)   -> x [b, s, hidden]
+  rope_tables(max_len)                 -> whatever its `project` takes
+  serving_layers(params)               -> runs (block, parameters, count)
+      in the cache's layer order.  count = n: the parameters are n
+      layers' STACKED [n, ...], and the run is scanned; count None: one
+      layer's own arrays, and the layer is called.  `_walk_layers`, the
+      one walk over the layers, chooses by that and by nothing else.
+  block.input_norm / post_norm / mlp_stats(params, x) -> (y, stats)
+  block.attn.project(p, hn, rope, pos_ids) -> (q, entries)
+      HOW A TOKEN'S CACHE ENTRY IS MADE: one array per array of the
+      contract, [b, s, *stored shape]
+  block.attn.attend_paged(p, q, pools, table, positions, base)
+  block.attn.attend_dense(p, q, caches, start)
+  block.attn.attend_prompt(p, q, entries)
+      HOW A QUERY ATTENDS IT: over pages, over a dense per-slot cache,
+      and a whole prompt over its own entries (for the K/V kind all
+      three are `cache_contract.KVAttention`)
+  block.attn.output(p, attn), final_hidden(params, x), logits(params, h),
+  lm_head_weight(params)
+
+  STATS, zero_stats(), add_stats(a, b)
+      `stats` is a small int32 vector a layer counts of itself (an
+      expert layer's assignments: models/kimi_k2.MOE_STATS); `STATS`
+      names each entry's counter and says whether executions add up or
+      take the maximum, and is EMPTY for a model that counts nothing
+      (llama, gpt: `mlp_stats` returns None for it, and the other two
+      are not asked for).  A program takes the running vector in and
+      hands it on only when it is given one, so the engine reads it with
+      the tokens and nowhere else, and the programs of a model that
+      counts nothing have no such argument.
+
+The programs: `prefill` (a whole prompt into a fresh dense cache),
+`decode_step_slots` (one token a row over a dense cache; `decode_step`
+at one position for all rows), `extend_cache` (a chunk of tokens a row
+over a dense cache: chunked prefill, and the gather route's verify step
+`verify_step_slots`), and `decode_step_paged` / `verify_step_paged` (one
+token, or a block, a slot, attending a paged pool where it lies).  Every
+one runs `_layer`, the one decoder layer, and differs in the two lines it
+hands it: where the entry is written and how the query attends
+(docs/serving.md).
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from hetu_tpu import ops
+from hetu_tpu.models.cache_contract import cache_contract
 
 
 def _attend_cached(q, ck, cv, pos, scale):
@@ -75,19 +107,13 @@ def _attend_cached_chunk(q, ck, cv, start, scale):
     return out.reshape(b, C, nq, hd).astype(q.dtype)
 
 
-def _is_gpt(model) -> bool:
-    return hasattr(model.model, "wte")
-
 
 def lm_head_weight(model, params):
     """The lm_head slice as a [hidden, vocab] matrix — the weight
     operand of the fused sampling epilogue (serving/sampling.
     sample_hidden).  Matches `model.logits`: tied embeddings transpose
     the token-embedding table, untied models carry an explicit head."""
-    if model.config.tie_word_embeddings:
-        key = "wte" if _is_gpt(model) else "embed"
-        return params["model"][key]["weight"].T
-    return params["lm_head"]
+    return model.lm_head_weight(params)
 
 
 def _check_context_length(config, max_len: int):
@@ -100,198 +126,204 @@ def _check_context_length(config, max_len: int):
             f"{config.max_position_embeddings}")
 
 
+
 def init_cache(model, batch: int, max_len: int):
-    """Empty KV cache [L, b, max_len, n_kv, hd] (n_kv = heads for GPT)."""
+    """Empty dense cache: one array [L, b, max_len, *stored shape] per
+    array of the model's cache contract ((k, v) of [.., n_kv, hd] for the
+    K/V kind)."""
     c = model.config
     _check_context_length(c, max_len)
-    n_kv = getattr(c, "num_key_value_heads", c.num_attention_heads)
-    shape = (c.num_hidden_layers, batch, max_len, n_kv, c.head_dim)
-    return (jnp.zeros(shape, c.compute_dtype), jnp.zeros(shape, c.compute_dtype))
+    contract = cache_contract(model)
+    return tuple(
+        jnp.zeros((contract.num_layers, batch, max_len) + tuple(shape),
+                  c.compute_dtype) for shape in contract.stored_shapes)
 
 
-def _gpt_embed(model, mp, ids, pos_ids):
-    x = model.model.wte(mp["wte"], ids) \
-        + jnp.take(mp["wpe"], pos_ids, axis=0)
-    return x.astype(model.config.compute_dtype)
+# ---------------------------------------------------------------------------
+# One decoder layer, one walk over the layers
+# ---------------------------------------------------------------------------
+
+def _layer(block, lp, h, rope, pos_ids, cache_step):
+    """THE decoder layer of every program below: norm, projection,
+    attention over the cache, output and residual, then the MLP and its
+    residual, under the scopes a device trace is summed by
+    (obs.scope_map: `attn`, `attn/kv_write`, `mlp`).
+
+    cache_step(attn module, its params, q, entries) -> (attention
+    output [b, s, n_q * hd], *rest) is what a program differs by: where
+    the token's entries are written and how the query attends them.
+    Returns (h, the layer's stats, *rest)."""
+    with jax.named_scope("attn"):
+        hn = block.input_norm(lp["input_norm"], h)
+        q, entries = block.attn.project(lp["attn"], hn, rope, pos_ids)
+        attn, *rest = cache_step(block.attn, lp["attn"], q, entries)
+        h = h + block.attn.output(lp["attn"], attn)
+    with jax.named_scope("mlp"):
+        y, st = block.mlp_stats(lp["mlp"],
+                                block.post_norm(lp["post_norm"], h))
+        h = h + y
+    return (h, st, *rest)
 
 
-def _prefill_gpt(model, params, input_ids, max_len: int):
-    mp = params["model"]
-    pos = jnp.arange(input_ids.shape[1], dtype=jnp.int32)
-    x = _gpt_embed(model, mp, input_ids, pos)
-    block = model.model.block
+def _walk_layers(model, params, x, state, stats, layer, *, sliced=False):
+    """THE walk over `model.serving_layers(params)`, in the cache's layer
+    order.  A run of stacked parameters [n, ...] is scanned, a layer with
+    arrays of its own is called: a scan over stacked weights slices each
+    layer's out of the stack at every execution (26% of the chat decode
+    program: PERF.md s5), which a family avoids by giving every layer
+    its own arrays (models/kimi_k2: 1.35 GB a layer a step), at the price
+    of a program that grows with the depth.  The model's parameters say
+    which; nothing else does.
 
-    def body(h, lp):
-        out = block(lp, h)
-        hn = block.ln1(lp["ln1"], h)
-        # contract only the K/V planes for the cache (the block forward
-        # above already computed full QKV for its own attention)
-        kv = jnp.einsum("bsh,hngd->bsngd", hn,
-                        lp["attn"]["wqkv"][:, :, 1:3, :].astype(h.dtype)) \
-            + lp["attn"]["bqkv"][:, 1:3, :].astype(h.dtype)
-        return out, (kv[..., 0, :], kv[..., 1, :])
+    layer(block, lp, h, state, at) -> (h, stats of the layer, state,
+    out).  `state` is the cache, arrays whose leading dim is the layer:
 
-    x, (ks, vs) = lax.scan(body, x, mp["blocks"])
-    hidden = model.model.final_ln(mp["final_ln"], x)
-    logits = model.logits(params, hidden)[:, -1, :]
-    pad = max_len - input_ids.shape[1]
-    cache_k = jnp.pad(ks, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-    cache_v = jnp.pad(vs, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-    return logits, (cache_k, cache_v)
+    * as it comes (sliced=False: a paged pool): every layer is handed
+      ALL of it with its index, `at` = (l,), and hands it on, updated
+      in place.
+      In a scan it is a loop CARRY — never an xs or a ys, which would
+      slice every layer's slab out and stack it back into a fresh buffer
+      (three full-pool copies a step: PERF.md s6, PR 25);
+    * sliced=True (a dense cache [L, b, M, ...]): a scanned run's layer
+      is handed its OWN slice and hands back the new one, xs -> ys,
+      `at` = (); a called layer is handed all of it and `at` = (l,),
+      the index of its slice.  `c[at]` reads and `c.at[at + (...)]`
+      writes the layer's part either way (`_at`).
+
+    `out` is whatever a layer hands out besides (a token's entries for a
+    paged pool to scatter; None): stacked over the layers.
+    Returns (x, stats, state, out).  The caller opens the `layer` scope
+    (a trace's name for the stack: obs.scope_map) around the walk and
+    what it does to the state before and after."""
+    num_layers = cache_contract(model).num_layers
+    outs, l0 = [], 0
+
+    def add(stats, st):
+        return stats if stats is None else model.add_stats(stats, st)
+
+    for block, lp, count in model.serving_layers(params):
+        if count is None:
+            x, st, state, out = layer(block, lp, x, state,
+                                      (jnp.int32(l0),))
+            stats = add(stats, st)
+            out = jax.tree.map(lambda a: a[None], out)
+        elif not sliced:
+            def body(carry, xs, block=block):
+                h, state, stats = carry
+                lp, l = xs
+                h, st, state, out = layer(block, lp, h, state, (l,))
+                return (h, state, add(stats, st)), out
+
+            (x, state, stats), out = lax.scan(
+                body, (x, state, stats),
+                (lp, jnp.arange(l0, l0 + count, dtype=jnp.int32)))
+        else:
+            def body(carry, xs, block=block):
+                h, stats = carry
+                lp, own = xs
+                h, st, own, out = layer(block, lp, h, own, ())
+                return (h, add(stats, st)), (own, out)
+
+            whole = count == num_layers
+            own = state if whole else jax.tree.map(
+                lambda c: c[l0:l0 + count], state)
+            (x, stats), (own, out) = lax.scan(body, (x, stats),
+                                              (lp, own))
+            state = own if whole else jax.tree.map(
+                lambda c, n: lax.dynamic_update_slice_in_dim(
+                    c, n, l0, 0), state, own)
+        outs.append(out)
+        l0 += count or 1
+    out = outs[0] if len(outs) == 1 else jax.tree.map(
+        lambda *a: jnp.concatenate(a), *outs)
+    return x, stats, state, out
 
 
-def _cache_write_token(ck, k, positions, uniform: bool):
-    """Write one token's K (or V) [b, 1, n_kv, hd] into a cache row
-    [b, M, n_kv, hd] at `positions`.  Uniform (scalar) positions keep
-    the old contiguous dynamic_update_slice lowering — the generate()
-    hot loop must not pay batched-scatter cost for a broadcast index —
-    per-slot vectors scatter per row (the serving form)."""
-    if uniform:
-        return lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                        (0, positions, 0, 0))
-    b = ck.shape[0]
-    return ck.at[jnp.arange(b), positions].set(k[:, 0].astype(ck.dtype))
+def _at(c, at):
+    """A layer's part of a cache array: `c` itself where the walk handed
+    the layer its own slice (at = ()), c[l] where it handed it all."""
+    return c[at] if at else c
 
 
-def _decode_step_slots_gpt(model, params, tokens, cache, positions):
-    c = model.config
-    mp = params["model"]
-    b = tokens.shape[0]
-    uniform = jnp.ndim(positions) == 0
-    pos_ids = (jnp.broadcast_to(positions, (1,)) if uniform
-               else positions[:, None])
-    x = _gpt_embed(model, mp, tokens[:, None], pos_ids)
-    block = model.model.block
-    att = block.attn
-    nh, hd = c.num_attention_heads, c.head_dim
-    scale = hd ** -0.5
-    cache_k, cache_v = cache
-
-    def body(h, xs):
-        lp, ck, cv = xs
-        hn = block.ln1(lp["ln1"], h)
-        qkv = jnp.einsum("bsh,hngd->bsngd", hn,
-                         lp["attn"]["wqkv"].astype(h.dtype)) \
-            + lp["attn"]["bqkv"].astype(h.dtype)
-        q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
-        kt, vt = k[:, 0], v[:, 0]                       # [b, n_kv, hd]
-        ck = _cache_write_token(ck, k, positions, uniform)
-        cv = _cache_write_token(cv, v, positions, uniform)
-        attn = _attend_cached(q, ck, cv, positions, scale)
-        h = h + att.o_proj(lp["attn"]["o_proj"],
-                           attn.reshape(b, 1, nh * hd))
-        h = h + block.mlp(lp["mlp"], block.ln2(lp["ln2"], h))
-        return h, (ck, cv, kt, vt)
-
-    x, (new_k, new_v, k_toks, v_toks) = lax.scan(
-        body, x, (mp["blocks"], cache_k, cache_v))
-    hidden = model.model.final_ln(mp["final_ln"], x)
-    logits = model.logits(params, hidden)[:, 0, :]
-    return logits, (new_k, new_v), (k_toks, v_toks)
-
+# ---------------------------------------------------------------------------
+# Dense caches: prefill, the single-token step, the chunk
+# ---------------------------------------------------------------------------
 
 def prefill(model, params, input_ids, max_len: int):
-    """Run the full forward over the prompt, returning (last_logits, cache).
-    Uses the model's training forward (flash path) plus a kv-extraction pass.
-    """
-    c = model.config
-    if not c.use_scan:
-        raise ValueError("generation requires use_scan=True (stacked layer "
-                         "params); rebuild the model with use_scan=True")
-    _check_context_length(c, max_len)
-    if _is_gpt(model):
-        return _prefill_gpt(model, params, input_ids, max_len)
+    """Run whole prompts [b, plen] through the layers, each attending its
+    own entries (`attend_prompt`: the flash path for the K/V kind), and
+    return (last_logits [b, vocab], cache): the entries of every layer,
+    padded to `max_len` positions."""
+    _check_context_length(model.config, max_len)
     b, plen = input_ids.shape
-    # extract per-layer k/v by re-running the projections layer by layer —
-    # one pass via the scan collecting (k, v) as ys
-    mp = params["model"]
-    x = model.model.embed(mp["embed"], input_ids).astype(c.compute_dtype)
-    cos, sin = ops.build_rope_cache(c.max_position_embeddings, c.head_dim,
-                                    c.rope_theta)
-    block = model.model.layers.block
+    pos_ids = jnp.broadcast_to(jnp.arange(plen, dtype=jnp.int32), (b, plen))
+    rope = model.rope_tables(max_len)
+    x = model.embed_tokens(params, input_ids, pos_ids)
 
-    att = block.attn
+    def layer(block, lp, h, state, at):
+        h, st, entries = _layer(
+            block, lp, h, rope, pos_ids,
+            lambda attn, p, q, entries: (attn.attend_prompt(p, q, entries),
+                                         entries))
+        return h, st, state, entries
 
-    def body(carry, layer_params):
-        h = carry
-        out, _aux = block(layer_params, h, cos=cos, sin=sin)
-        # recompute only the K/V planes of the fused projection for the cache
-        # (the q-head planes are sliced out of the weight before the einsum)
-        w_kv = layer_params["attn"]["wqkv"][:, :, att.group: att.group + 2, :]
-        kv = jnp.einsum("bsh,hkgd->bskgd",
-                        block.input_norm(layer_params["input_norm"], h),
-                        w_kv.astype(h.dtype))
-        k = ops.apply_rotary(kv[..., 0, :], cos, sin, None)
-        v = kv[..., 1, :]
-        return out, (k, v)
+    with jax.named_scope("layer"):
+        x, _, _, entries = _walk_layers(model, params, x, None, None, layer)
+    logits = model.logits(params, model.final_hidden(params, x))[:, -1, :]
+    pad = ((0, 0), (0, 0), (0, max_len - plen))
+    return logits, tuple(jnp.pad(e, pad + ((0, 0),) * (e.ndim - 3))
+                         for e in entries)
 
-    x, (ks, vs) = lax.scan(body, x, mp["layers"]["layers"])
-    hidden = model.model.final_norm(mp["final_norm"], x)
-    logits = model.logits(params, hidden)[:, -1, :]
-    pad = max_len - plen
-    cache_k = jnp.pad(ks, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-    cache_v = jnp.pad(vs, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-    return logits, (cache_k, cache_v)
+
+def _cache_write_token(c, e, positions, uniform: bool, at=()):
+    """Write one token's entry e [b, 1, ...] into the layer's part of a
+    cache array ([b, M, ...] at `at`) at `positions`.  Uniform (scalar)
+    positions keep the contiguous dynamic_update_slice lowering — the
+    generate() hot loop must not pay batched-scatter cost for a
+    broadcast index — per-slot vectors scatter per row (the serving
+    form)."""
+    if uniform:
+        return lax.dynamic_update_slice(
+            c, e.astype(c.dtype).reshape((1,) * len(at) + e.shape),
+            at + (0, positions) + (0,) * (e.ndim - 2))
+    return c.at[at + (jnp.arange(e.shape[0]), positions)].set(
+        e[:, 0].astype(c.dtype))
 
 
 def decode_step_slots(model, params, tokens, cache, positions):
-    """One token step with PER-SLOT positions (the serving engine's form:
-    each batch row is an independent sequence at its own depth).
+    """One token step with PER-SLOT positions (the serving engine's
+    gather route: each batch row is an independent sequence at its own
+    depth) over a dense cache (`init_cache`'s arrays).
 
     tokens: [b] int32; positions: [b] int32 (this token's absolute
-    position per slot) — or a scalar, which keeps the old contiguous
+    position per slot) — or a scalar, which keeps the contiguous
     dynamic_update_slice cache lowering for the uniform-position
-    generate() hot loop.  Returns (logits [b, vocab], new_cache,
-    (k_toks, v_toks)) where k_toks/v_toks are THIS step's per-layer K/V
-    [L, b, n_kv, hd] — a paged cache scatters them into its pool instead
-    of carrying the dense cache."""
-    c = model.config
-    if not c.use_scan:
-        raise ValueError("generation requires use_scan=True (stacked layer "
-                         "params)")
-    if _is_gpt(model):
-        return _decode_step_slots_gpt(model, params, tokens, cache, positions)
-    mp = params["model"]
+    generate() hot loop.  Returns (logits [b, vocab], new_cache, token
+    entries): THIS step's entries per layer, one array [L, b, *stored
+    shape] per array of the cache ((k_toks, v_toks) for the K/V kind) —
+    a paged cache scatters them into its pool instead of carrying the
+    dense cache."""
     b = tokens.shape[0]
     uniform = jnp.ndim(positions) == 0
-    x = model.model.embed(mp["embed"], tokens[:, None]).astype(c.compute_dtype)
-    cos, sin = ops.build_rope_cache(c.max_position_embeddings, c.head_dim,
-                                    c.rope_theta)
-    block = model.model.layers.block
-    att = block.attn
-    scale = c.head_dim ** -0.5
     pos_ids = (jnp.broadcast_to(positions, (b, 1)) if uniform
                else positions[:, None])
-    cache_k, cache_v = cache
+    rope = model.rope_tables(cache[0].shape[2])
+    x = model.embed_tokens(params, tokens[:, None], pos_ids)
 
-    def body(carry, xs):
-        h = carry
-        layer_params, ck, cv = xs
-        hn = block.input_norm(layer_params["input_norm"], h)
-        qkv = jnp.einsum("bsh,hkgd->bskgd", hn,
-                         layer_params["attn"]["wqkv"].astype(h.dtype))
-        q = qkv[..., : att.group, :].reshape(b, 1, att.n_q, c.head_dim)
-        k = qkv[..., att.group, :]
-        v = qkv[..., att.group + 1, :]
-        q, k = ops.apply_rotary_qk(q, k, cos, sin, pos_ids)
-        kt, vt = k[:, 0], v[:, 0]                       # [b, n_kv, hd]
-        ck = _cache_write_token(ck, k, positions, uniform)
-        cv = _cache_write_token(cv, v, positions, uniform)
-        attn = _attend_cached(q, ck, cv, positions, scale)
-        h = h + att.o_proj(layer_params["attn"]["o_proj"],
-                           attn.reshape(b, 1, att.n_q * c.head_dim))
-        mlp_out = block.mlp(layer_params["mlp"],
-                            block.post_norm(layer_params["post_norm"], h))
-        if isinstance(mlp_out, tuple):  # MoE
-            mlp_out = mlp_out[0]
-        h = h + mlp_out
-        return h, (ck, cv, kt, vt)
+    def layer(block, lp, h, cache, at):
+        def step(attn, p, q, entries):
+            new = tuple(_cache_write_token(c, e, positions, uniform, at)
+                        for c, e in zip(cache, entries))
+            return (attn.attend_dense(p, q, tuple(_at(c, at) for c in new),
+                                      positions),
+                    new, tuple(e[:, 0] for e in entries))
+        return _layer(block, lp, h, rope, pos_ids, step)
 
-    x, (new_k, new_v, k_toks, v_toks) = lax.scan(
-        body, x, (mp["layers"]["layers"], cache_k, cache_v))
-    hidden = model.model.final_norm(mp["final_norm"], x)
-    logits = model.logits(params, hidden)[:, 0, :]
-    return logits, (new_k, new_v), (k_toks, v_toks)
+    with jax.named_scope("layer"):
+        x, _, cache, toks = _walk_layers(model, params, x, tuple(cache),
+                                         None, layer, sliced=True)
+    logits = model.logits(params, model.final_hidden(params, x))[:, 0, :]
+    return logits, cache, toks
 
 
 def decode_step(model, params, token, cache, pos):
@@ -304,625 +336,50 @@ def decode_step(model, params, token, cache, pos):
     return logits, new_cache
 
 
-def _quantize_head_vectors(t, bits: int):
-    """Quantize [..., hd] head-vectors for a paged pool: int8 through
-    the SAME blockwise primitives the gather path uses (comm/compress ->
-    the fused Pallas quant kernel when routed), int4 through the shared
-    `ops/quantization` nibble packer — so pool contents are
-    bit-identical across the decode programs.  Returns (payload
-    [..., hd or hd//2], scales [...])."""
-    hd = t.shape[-1]
-    x32 = t.astype(jnp.float32)
-    if bits == 4:
-        from hetu_tpu.ops.quantization import quantize_int4
-        q, s = quantize_int4(x32, block_size=hd)
-        q = q.reshape(t.shape[:-1] + (hd // 2,))
-    else:
-        from hetu_tpu.comm.compress import quantize_blockwise
-        q, s = quantize_blockwise(x32, block_size=hd)
-        q = q.reshape(t.shape)
-    return q, s.reshape(t.shape[:-1])
-
-
-def _paged_put(pool, scale, page, off, t, layer, base, bits):
-    """Scatter head-vectors `t` at (page, off) of ONE layer into the
-    carried pool (`_scan_layers_paged`): the payload into the flat page
-    array [L * P, ps, n_kv, hd] at page `base + page` (base = l * P,
-    added after any null-page redirect), and for quantized pages
-    (`scale` not None: int8, or int4 nibble payloads with ``bits=4``)
-    the per-head-vector f32 scale into the layer's plane of
-    [L, P, ps, n_kv].  Returns (pool, scale)."""
-    if scale is None:
-        return pool.at[base + page, off].set(t.astype(pool.dtype)), None
-    q, s = _quantize_head_vectors(t, bits)
-    return pool.at[base + page, off].set(q.astype(pool.dtype)), \
-        scale.at[layer, page, off].set(s)
-
-
-def _paged_write(pool, scale, table, positions, t, layer, base, bits):
-    """Write one token's K (or V) [S, n_kv, hd] at each slot's
-    (table[pos // ps], pos % ps).  Inactive slots' tables point at the
-    null page (the layer's id 0) — their write lands there harmlessly
-    (serving/kv_pool.py)."""
-    ps = pool.shape[1]
-    page = table[jnp.arange(positions.shape[0]), positions // ps]
-    return _paged_put(pool, scale, page, positions % ps, t, layer, base,
-                      bits)
-
-
-def _token_block_pages(table, positions, C, ps):
-    """Page ids + offsets for a C-token block at positions[s] + i.
-    Block positions past the table's reach land in the null page (id 0)
-    — the same redirect `serving/kv_pool.write_tokens` applies — and
-    inactive slots' zeroed table rows point there already."""
-    S = positions.shape[0]
-    mp = table.shape[1]
-    pos = positions[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
-    pidx = pos // ps
-    safe = pidx < mp
-    page = jnp.where(
-        safe, table[jnp.arange(S)[:, None], jnp.clip(pidx, 0, mp - 1)], 0)
-    return page, pos % ps
-
-
-def _paged_write_tokens(pool, scale, table, positions, t, layer, base,
-                        bits):
-    """Write a C-token block's K (or V) [S, C, n_kv, hd] — the
-    verify-step sibling of `_paged_write`."""
-    page, off = _token_block_pages(table, positions, t.shape[1],
-                                   pool.shape[1])
-    return _paged_put(pool, scale, page, off, t, layer, base, bits)
-
-
-def _scan_layers_paged(layer, x, layer_params, pools):
-    """Scan `layer` over the stacked layers with the KV pool as a loop
-    CARRY that is updated in place — never an xs or a ys of the scan,
-    which would slice every layer's slab out and stack it back into a
-    fresh buffer (three full-pool copies a step: PERF.md, PR 25).
-
-    pools: (k_pool, v_pool, k_scale, v_scale), each [L, P, ...], the
-    scales None for exact pages.  The scan carries the page arrays as
-    their flat views [L * P, ps, n_kv, hd] (a bitcast): layer l's page
-    p is page l * P + p, so with `table + l * P` as its page table the
-    same scatter and the same kernel walk the same bytes, and l * P is
-    the layer's null page.  The scale planes stay [L, P, ps, n_kv]: the
-    kernel reads a page's scales as a block of a page-major array whose
-    rows are padded to 128 lanes, which is not how the planes are
-    stored, so it is handed ONE layer's plane, `scale[l]`, to read by
-    the engine's own page ids (`scale_table`), and what is converted
-    for it is P pages a layer, never L * P.
-
-    layer(h, layer_params, pools, l, base) -> (h, pools), base = l * P.
-    Returns (x, pools): the arrays that came in (no None), in their
-    [L, P, ...] shapes and, when the caller donated them, their
-    buffers."""
-    k_pool, v_pool, k_scale, v_scale = pools
-    L, P = k_pool.shape[:2]
-
-    def body(carry, xs):
-        h, pools = carry
-        lp, l = xs
-        h, pools = layer(h, lp, pools, l, l * P)
-        return (h, tuple(pools)), None
-
-    flat = (L * P,) + k_pool.shape[2:]
-    (x, (k_flat, v_flat, k_scale, v_scale)), _ = lax.scan(
-        body,
-        (x, (k_pool.reshape(flat), v_pool.reshape(flat), k_scale, v_scale)),
-        (layer_params, jnp.arange(L, dtype=jnp.int32)))
-    pools = (k_flat.reshape(k_pool.shape), v_flat.reshape(v_pool.shape),
-             k_scale, v_scale)
-    return x, tuple(p for p in pools if p is not None)
-
-
-def _decode_step_paged_gpt(model, params, tokens, k_pool, v_pool, table,
-                           positions, k_scale, v_scale, kv_quant):
-    from hetu_tpu.ops.pallas.paged_attention import paged_attention
-    c = model.config
-    mp_ = params["model"]
-    b = tokens.shape[0]
-    quant = k_scale is not None
-    bits = 4 if kv_quant == "int4" else 8
-    x = _gpt_embed(model, mp_, tokens[:, None], positions[:, None])
-    block = model.model.block
-    att = block.attn
-    nh, hd = c.num_attention_heads, c.head_dim
-    scale = hd ** -0.5
-
-    def layer(h, lp, pools, l, base):
-        kp, vp, ksc, vsc = pools
-        hn = block.ln1(lp["ln1"], h)
-        qkv = jnp.einsum("bsh,hngd->bsngd", hn,
-                         lp["attn"]["wqkv"].astype(h.dtype)) \
-            + lp["attn"]["bqkv"].astype(h.dtype)
-        q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
-        with jax.named_scope("kv_write"):
-            kp, ksc = _paged_write(kp, ksc, table, positions, k[:, 0],
-                                   l, base, bits)
-            vp, vsc = _paged_write(vp, vsc, table, positions, v[:, 0],
-                                   l, base, bits)
-        with jax.named_scope("pallas_paged_attention"):
-            ksl, vsl = (ksc[l], vsc[l]) if quant else (None, None)
-            attn = paged_attention(q[:, 0], kp, vp, table + base, positions,
-                                   softmax_scale=scale,
-                                   k_scale=ksl, v_scale=vsl,
-                                   quant=kv_quant, scale_table=table)
-        h = h + att.o_proj(lp["attn"]["o_proj"],
-                           attn.reshape(b, 1, nh * hd))
-        h = h + block.mlp(lp["mlp"], block.ln2(lp["ln2"], h))
-        return h, (kp, vp, ksc, vsc)
-
-    x, pools = _scan_layers_paged(
-        layer, x, mp_["blocks"], (k_pool, v_pool, k_scale, v_scale))
-    hidden = model.model.final_ln(mp_["final_ln"], x)
-    logits = model.logits(params, hidden)[:, 0, :]
-    return (logits,) + pools
-
-
-def decode_step_paged(model, params, tokens, k_pool, v_pool, table,
-                      positions, *, k_scale=None, v_scale=None,
-                      kv_quant=None):
-    """One decode step attending DIRECTLY over a paged KV pool — the
-    gather-free form of `decode_step_slots` (ops/pallas/paged_attention;
-    serving engine's HETU_TPU_PALLAS decode program).
-
-    k_pool/v_pool: [L, P, page_size, n_kv, hd] (page 0 = the null page);
-    table: [S, max_pages] int32; positions: [S] int32 — slot s's current
-    token sits at positions[s] and attends over everything at or before
-    it.  This step's K/V are scattered into each slot's page BEFORE the
-    kernel runs (so the token sees itself, exactly like the dense path's
-    write-then-attend), and the updated pools are returned:
-    (logits [S, vocab], new_k_pool, new_v_pool).  The pools are a carry
-    of the layer loop, written IN PLACE (`_scan_layers_paged`): a caller
-    that donates them (the engine does) gets its own buffers back, with
-    no second pool among the program's temporaries.
-
-    int8 pools (``HETU_TPU_KV_QUANT=int8``) pass their per-head-vector
-    f32 scales [L, P, page_size, n_kv] as k_scale/v_scale: the token
-    write quantizes through the shared blockwise primitives and the
-    kernel dequantizes pages in-VMEM; the return gains
-    (..., new_k_scale, new_v_scale).  int4 pools
-    (``HETU_TPU_KV_QUANT=int4``) additionally pass ``kv_quant="int4"``
-    — uint8 nibble payloads of head dim hd//2, the
-    `ops/quantization.pack_nibbles` storage layout."""
-    c = model.config
-    if not c.use_scan:
-        raise ValueError("generation requires use_scan=True (stacked layer "
-                         "params)")
-    if (k_scale is None) != (v_scale is None):
-        raise ValueError("pass both k_scale and v_scale, or neither")
-    quant = k_scale is not None
-    if kv_quant is None:
-        kv_quant = "int8" if quant else None
-    bits = 4 if kv_quant == "int4" else 8
-    positions = positions.astype(jnp.int32)
-    table = table.astype(jnp.int32)
-    if _is_gpt(model):
-        return _decode_step_paged_gpt(model, params, tokens, k_pool,
-                                      v_pool, table, positions,
-                                      k_scale, v_scale, kv_quant)
-    from hetu_tpu.ops.pallas.paged_attention import paged_attention
-    mp_ = params["model"]
-    b = tokens.shape[0]
-    # the scopes the training programs carry, so that a device trace of
-    # the serving programs is summed under the same names (obs.scope_map)
-    with jax.named_scope("embed"):
-        x = model.model.embed(mp_["embed"], tokens[:, None]).astype(
-            c.compute_dtype)
-    cos, sin = ops.build_rope_cache(c.max_position_embeddings, c.head_dim,
-                                    c.rope_theta)
-    block = model.model.layers.block
-    att = block.attn
-    scale = c.head_dim ** -0.5
-
-    def layer(h, layer_params, pools, l, base):
-        kp, vp, ksc, vsc = pools
-        with jax.named_scope("attn"):
-            hn = block.input_norm(layer_params["input_norm"], h)
-            qkv = jnp.einsum("bsh,hkgd->bskgd", hn,
-                             layer_params["attn"]["wqkv"].astype(h.dtype))
-            q = qkv[..., : att.group, :].reshape(b, 1, att.n_q,
-                                                 c.head_dim)
-            k = qkv[..., att.group, :]
-            v = qkv[..., att.group + 1, :]
-            q, k = ops.apply_rotary_qk(q, k, cos, sin, positions[:, None])
-            with jax.named_scope("kv_write"):
-                kp, ksc = _paged_write(kp, ksc, table, positions, k[:, 0],
-                                       l, base, bits)
-                vp, vsc = _paged_write(vp, vsc, table, positions, v[:, 0],
-                                       l, base, bits)
-            with jax.named_scope("pallas_paged_attention"):
-                ksl, vsl = (ksc[l], vsc[l]) if quant else (None, None)
-                attn = paged_attention(
-                    q[:, 0], kp, vp, table + base, positions,
-                    softmax_scale=scale, k_scale=ksl, v_scale=vsl,
-                    quant=kv_quant, scale_table=table)
-            h = h + att.o_proj(layer_params["attn"]["o_proj"],
-                               attn.reshape(b, 1, att.n_q * c.head_dim))
-        with jax.named_scope("mlp"):
-            mlp_out = block.mlp(
-                layer_params["mlp"],
-                block.post_norm(layer_params["post_norm"], h))
-            if isinstance(mlp_out, tuple):  # MoE
-                mlp_out = mlp_out[0]
-            h = h + mlp_out
-        return h, (kp, vp, ksc, vsc)
-
-    with jax.named_scope("layer"):
-        x, pools = _scan_layers_paged(
-            layer, x, mp_["layers"]["layers"],
-            (k_pool, v_pool, k_scale, v_scale))
-    hidden = model.model.final_norm(mp_["final_norm"], x)
-    logits = model.logits(params, hidden)[:, 0, :]
-    return (logits,) + pools
-
-
-# ---------------------------------------------------------------------------
-# The serving programs written against the model's attention-cache contract
-# ---------------------------------------------------------------------------
-# A model that says what a token stores (`cache_contract()`:
-# models/cache_contract.py) and how a query attends it brings no copy of
-# the paged decode, chunk or page-write programs: the three below take
-# from the model
-#
-#   embed_tokens(params, ids)            -> x [b, s, hidden]
-#   rope_tables(max_len)                 -> whatever its `project` takes
-#   serving_layers(params)               -> [(block, layer params)]
-#       in the pool's layer order, run one after the other
-#   block.input_norm / post_norm / mlp_stats(params, x) -> (y, stats)
-#   block.attn.project(p, hn, rope, pos_ids) -> (q, entries)
-#       HOW A TOKEN'S CACHE ENTRY IS MADE: one array per pool array,
-#       [b, s, *stored shape]
-#   block.attn.attend_paged(p, q, pools, table, positions)
-#   block.attn.attend_dense(p, q, caches, start)
-#       HOW A QUERY ATTENDS IT, over pages and over a dense per-slot cache
-#   block.attn.output(p, attn), final_hidden(params, x), logits(params, h)
-#
-#   STATS, zero_stats(), add_stats(a, b)
-#       `stats` is a small int32 vector a layer counts of itself (an
-#       expert layer's assignments: models/kimi_k2.MOE_STATS); `STATS`
-#       names each entry's counter and says whether executions add up or
-#       take the maximum (empty for a model that counts nothing); the
-#       programs take the running vector in and hand it on, so the
-#       engine reads it with the tokens and nowhere else.
-# models/llama and models/gpt keep the bodies above: their compiled
-# programs are held byte-for-byte (PR 27), and moving them onto the
-# contract is ROADMAP's.
-
-def _contract_layers(model, params, x, state, stats, layer):
-    """Walk `serving_layers` in the pool's layer order.
-    layer(block, h, lp, state, l) -> (h, state, stats of the layer);
-    `state` is the cache (pages or dense), handed from layer to layer
-    and updated in place (never an xs -> ys of a scan: PR 25)."""
-    with jax.named_scope("layer"):
-        for l, (block, lp) in enumerate(model.serving_layers(params)):
-            x, state, st = layer(block, x, lp, state, jnp.int32(l))
-            stats = model.add_stats(stats, st)
-    return x, state, stats
-
-
-def decode_step_paged_contract(model, params, tokens, pools, table,
-                               positions, stats):
-    """`decode_step_paged` for a model with a cache contract.  pools: a
-    tuple of page arrays [L, P, page_size, *stored shape], one per array
-    of the contract; the rest as `decode_step_paged`.  This step's
-    entries are scattered into each slot's page BEFORE the query attends
-    (write-then-attend), the pools are carried as their flat views
-    [L * P, ...] and written in place (`_scan_layers_paged` says why).
-    Returns (logits [S, vocab], pools, stats)."""
-    positions = positions.astype(jnp.int32)
-    table = table.astype(jnp.int32)
-    L, P, ps = pools[0].shape[:3]
-    rope = model.rope_tables(table.shape[1] * ps)
-    with jax.named_scope("embed"):
-        x = model.embed_tokens(params, tokens[:, None])
-
-    def layer(block, h, lp, flat, l):
-        base = l * P
-        with jax.named_scope("attn"):
-            hn = block.input_norm(lp["input_norm"], h)
-            q, entries = block.attn.project(lp["attn"], hn, rope,
-                                            positions[:, None])
-            with jax.named_scope("kv_write"):
-                flat = tuple(
-                    _paged_write(pool, None, table, positions, e[:, 0], l,
-                                 base, 8)[0]
-                    for pool, e in zip(flat, entries))
-            attn = block.attn.attend_paged(lp["attn"], q, flat,
-                                           table + base, positions)
-            h = h + block.attn.output(lp["attn"], attn)
-        with jax.named_scope("mlp"):
-            y, st = block.mlp_stats(
-                lp["mlp"], block.post_norm(lp["post_norm"], h))
-        return h + y, flat, st
-
-    flat = tuple(p.reshape((L * P,) + p.shape[2:]) for p in pools)
-    x, flat, stats = _contract_layers(model, params, x, flat, stats, layer)
-    logits = model.logits(params, model.final_hidden(params, x))[:, 0, :]
-    return (logits, tuple(f.reshape(p.shape) for f, p in zip(flat, pools)),
-            stats)
-
-
-def extend_cache_contract(model, params, tokens, cache, start, stats):
-    """`extend_cache` for a model with a cache contract.  cache: a tuple
-    of dense per-slot caches [L, b, M, *stored shape]; tokens [b, C] at
-    positions start..start+C-1.  Returns (logits [b, C, vocab], cache,
-    stats)."""
-    b, C = tokens.shape
-    rows = jnp.arange(b)
-    start = jnp.broadcast_to(jnp.asarray(start, jnp.int32), (b,))
-    qpos = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
-    rope = model.rope_tables(cache[0].shape[2])
-    with jax.named_scope("embed"):
-        x = model.embed_tokens(params, tokens)
-
-    def layer(block, h, lp, cache, l):
-        with jax.named_scope("attn"):
-            hn = block.input_norm(lp["input_norm"], h)
-            q, entries = block.attn.project(lp["attn"], hn, rope, qpos)
-            with jax.named_scope("kv_write"):
-                cache = tuple(
-                    c.at[l, rows[:, None], qpos].set(e.astype(c.dtype))
-                    for c, e in zip(cache, entries))
-            attn = block.attn.attend_dense(
-                lp["attn"], q, tuple(c[l] for c in cache), start)
-            h = h + block.attn.output(lp["attn"], attn)
-        with jax.named_scope("mlp"):
-            y, st = block.mlp_stats(
-                lp["mlp"], block.post_norm(lp["post_norm"], h))
-        return h + y, cache, st
-
-    x, cache, stats = _contract_layers(model, params, x, tuple(cache),
-                                       stats, layer)
-    return model.logits(params, model.final_hidden(params, x)), cache, stats
-
-
-def _verify_step_paged_gpt(model, params, tokens, k_pool, v_pool, table,
-                           positions, k_scale, v_scale, kv_quant,
-                           return_hidden):
-    from hetu_tpu.ops.pallas.paged_attention import paged_verify
-    c = model.config
-    mp_ = params["model"]
-    S, C = tokens.shape
-    quant = k_scale is not None
-    bits = 4 if kv_quant == "int4" else 8
-    qpos = positions[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
-    x = _gpt_embed(model, mp_, tokens, qpos)
-    block = model.model.block
-    att = block.attn
-    nh, hd = c.num_attention_heads, c.head_dim
-    scale = hd ** -0.5
-
-    def layer(h, lp, pools, l, base):
-        kp, vp, ksc, vsc = pools
-        hn = block.ln1(lp["ln1"], h)
-        qkv = jnp.einsum("bsh,hngd->bsngd", hn,
-                         lp["attn"]["wqkv"].astype(h.dtype)) \
-            + lp["attn"]["bqkv"].astype(h.dtype)
-        q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
-        with jax.named_scope("kv_write"):
-            kp, ksc = _paged_write_tokens(kp, ksc, table, positions, k,
-                                          l, base, bits)
-            vp, vsc = _paged_write_tokens(vp, vsc, table, positions, v,
-                                          l, base, bits)
-        with jax.named_scope("pallas_paged_verify"):
-            ksl, vsl = (ksc[l], vsc[l]) if quant else (None, None)
-            attn = paged_verify(
-                q.reshape(S, C, nh, hd), kp, vp, table + base, positions,
-                softmax_scale=scale, k_scale=ksl, v_scale=vsl,
-                quant=kv_quant, scale_table=table)
-        h = h + att.o_proj(lp["attn"]["o_proj"],
-                           attn.reshape(S, C, nh * hd))
-        h = h + block.mlp(lp["mlp"], block.ln2(lp["ln2"], h))
-        return h, (kp, vp, ksc, vsc)
-
-    x, pools = _scan_layers_paged(
-        layer, x, mp_["blocks"], (k_pool, v_pool, k_scale, v_scale))
-    hidden = model.model.final_ln(mp_["final_ln"], x)
-    if return_hidden:
-        return (hidden,) + pools
-    return (model.logits(params, hidden),) + pools
-
-
-def verify_step_paged(model, params, tokens, k_pool, v_pool, table,
-                      positions, *, k_scale=None, v_scale=None,
-                      kv_quant=None, return_hidden: bool = False):
-    """The speculative VERIFY step attending DIRECTLY over a paged KV
-    pool — `verify_step_slots` without the gather (ops/pallas/
-    paged_attention.paged_verify: all k+1 query positions walk the
-    slot's pages in one launch with per-position causal masks).
-
-    tokens: [S, C] int32 (last emitted token + k drafts per slot);
-    positions: [S] int32 — token i of the block sits at positions[s]+i.
-    The block's K/V are scattered into each slot's pages BEFORE the
-    kernel runs (write-then-attend, exactly like the dense path), and
-    the updated pools return: (logits [S, C, vocab], *new_pools) — in
-    place, as in `decode_step_paged` (`_scan_layers_paged`).
-    Quantized pools pass scales (+ ``kv_quant="int4"`` for nibble
-    pages) exactly as `decode_step_paged`.
-
-    ``return_hidden=True`` returns the final-norm HIDDEN states
-    [S, C, hidden] instead of logits — the fused sampling epilogue
-    (serving/sampling.sample_hidden_grid) consumes them directly so the
-    [S, C, vocab] logits plane never materializes in HBM."""
-    c = model.config
-    if not c.use_scan:
-        raise ValueError("generation requires use_scan=True (stacked layer "
-                         "params)")
-    if (k_scale is None) != (v_scale is None):
-        raise ValueError("pass both k_scale and v_scale, or neither")
-    quant = k_scale is not None
-    if kv_quant is None:
-        kv_quant = "int8" if quant else None
-    bits = 4 if kv_quant == "int4" else 8
-    positions = positions.astype(jnp.int32)
-    table = table.astype(jnp.int32)
-    if _is_gpt(model):
-        return _verify_step_paged_gpt(model, params, tokens, k_pool,
-                                      v_pool, table, positions, k_scale,
-                                      v_scale, kv_quant, return_hidden)
-    from hetu_tpu.ops.pallas.paged_attention import paged_verify
-    mp_ = params["model"]
-    S, C = tokens.shape
-    qpos = positions[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
-    x = model.model.embed(mp_["embed"], tokens).astype(c.compute_dtype)
-    cos, sin = ops.build_rope_cache(c.max_position_embeddings, c.head_dim,
-                                    c.rope_theta)
-    block = model.model.layers.block
-    att = block.attn
-    scale = c.head_dim ** -0.5
-
-    def layer(h, layer_params, pools, l, base):
-        kp, vp, ksc, vsc = pools
-        hn = block.input_norm(layer_params["input_norm"], h)
-        qkv = jnp.einsum("bsh,hkgd->bskgd", hn,
-                         layer_params["attn"]["wqkv"].astype(h.dtype))
-        q = qkv[..., : att.group, :].reshape(S, C, att.n_q, c.head_dim)
-        k = qkv[..., att.group, :]
-        v = qkv[..., att.group + 1, :]
-        q, k = ops.apply_rotary_qk(q, k, cos, sin, qpos)
-        with jax.named_scope("kv_write"):
-            kp, ksc = _paged_write_tokens(kp, ksc, table, positions, k,
-                                          l, base, bits)
-            vp, vsc = _paged_write_tokens(vp, vsc, table, positions, v,
-                                          l, base, bits)
-        with jax.named_scope("pallas_paged_verify"):
-            ksl, vsl = (ksc[l], vsc[l]) if quant else (None, None)
-            attn = paged_verify(q, kp, vp, table + base, positions,
-                                softmax_scale=scale, k_scale=ksl,
-                                v_scale=vsl, quant=kv_quant,
-                                scale_table=table)
-        h = h + att.o_proj(layer_params["attn"]["o_proj"],
-                           attn.reshape(S, C, att.n_q * c.head_dim))
-        mlp_out = block.mlp(layer_params["mlp"],
-                            block.post_norm(layer_params["post_norm"], h))
-        if isinstance(mlp_out, tuple):  # MoE
-            mlp_out = mlp_out[0]
-        h = h + mlp_out
-        return h, (kp, vp, ksc, vsc)
-
-    x, pools = _scan_layers_paged(
-        layer, x, mp_["layers"]["layers"],
-        (k_pool, v_pool, k_scale, v_scale))
-    hidden = model.model.final_norm(mp_["final_norm"], x)
-    if return_hidden:
-        return (hidden,) + pools
-    return (model.logits(params, hidden),) + pools
-
-
-def _extend_cache_gpt(model, params, tokens, cache, start,
-                      collect: bool = False):
-    c = model.config
-    mp = params["model"]
-    b, C = tokens.shape
-    rows = jnp.arange(b)
-    start = jnp.broadcast_to(jnp.asarray(start, jnp.int32), (b,))
-    qpos = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]  # [b, C]
-    x = _gpt_embed(model, mp, tokens, qpos)
-    block = model.model.block
-    att = block.attn
-    nh, hd = c.num_attention_heads, c.head_dim
-    scale = hd ** -0.5
-    cache_k, cache_v = cache
-
-    def body(h, xs):
-        lp, ck, cv = xs
-        hn = block.ln1(lp["ln1"], h)
-        qkv = jnp.einsum("bsh,hngd->bsngd", hn,
-                         lp["attn"]["wqkv"].astype(h.dtype)) \
-            + lp["attn"]["bqkv"].astype(h.dtype)
-        q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
-        ck = ck.at[rows[:, None], qpos].set(k.astype(ck.dtype))
-        cv = cv.at[rows[:, None], qpos].set(v.astype(cv.dtype))
-        attn = _attend_cached_chunk(q, ck, cv, start, scale)
-        h = h + att.o_proj(lp["attn"]["o_proj"],
-                           attn.reshape(b, C, nh * hd))
-        h = h + block.mlp(lp["mlp"], block.ln2(lp["ln2"], h))
-        return h, ((ck, cv, k, v) if collect else (ck, cv))
-
-    x, ys = lax.scan(body, x, (mp["blocks"], cache_k, cache_v))
-    hidden = model.model.final_ln(mp["final_ln"], x)
-    logits = model.logits(params, hidden)
-    if collect:
-        new_k, new_v, k_chunk, v_chunk = ys
-        return logits, (new_k, new_v), (k_chunk, v_chunk)
-    return logits, ys
-
-
-def extend_cache(model, params, tokens, cache, start, *,
+def extend_cache(model, params, tokens, cache, start, stats=None, *,
                  collect_token_kv: bool = False):
-    """Advance a KV cache by a whole token block (chunked prefill).
+    """Advance a dense cache by a whole token block (chunked prefill).
 
     tokens: [b, C] int32 at absolute positions start..start+C-1 (start
-    scalar or [b]); the chunk's K/V are written into the cache and each
-    query attends causally over cache[:start+i+1].  Returns
+    scalar or [b]); the chunk's entries are written into the cache and
+    each query attends causally over cache[:start+i+1].  Returns
     (logits [b, C, vocab], new_cache).  Running consecutive chunks
     through this is numerically the incremental form of `prefill` — the
     serving engine uses it so one long prompt never stalls the decode
     batch (docs/serving.md).
 
     ``collect_token_kv=True`` (the `verify_step_slots` path) also
-    returns the chunk's per-layer K/V [L, b, C, n_kv, hd] so a paged
-    cache can scatter them into its pool; the default False traces
-    exactly the pre-speculative chunk program."""
-    c = model.config
-    if not c.use_scan:
-        raise ValueError("generation requires use_scan=True (stacked layer "
-                         "params)")
-    if _is_gpt(model):
-        return _extend_cache_gpt(model, params, tokens, cache, start,
-                                 collect=collect_token_kv)
-    mp = params["model"]
+    returns the chunk's entries per layer ((k, v) [L, b, C, n_kv, hd])
+    so a paged cache can scatter them into its pool; given the running
+    `stats` vector of a model that counts (`model.STATS`), the advanced
+    one is returned last."""
     b, C = tokens.shape
     rows = jnp.arange(b)
     start = jnp.broadcast_to(jnp.asarray(start, jnp.int32), (b,))
     qpos = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]  # [b, C]
+    rope = model.rope_tables(cache[0].shape[2])
+    # the scopes the training programs carry, so that a device trace of
+    # the serving programs is summed under the same names (obs.scope_map)
     with jax.named_scope("embed"):
-        x = model.model.embed(mp["embed"], tokens).astype(c.compute_dtype)
-    cos, sin = ops.build_rope_cache(c.max_position_embeddings, c.head_dim,
-                                    c.rope_theta)
-    block = model.model.layers.block
-    att = block.attn
-    scale = c.head_dim ** -0.5
+        x = model.embed_tokens(params, tokens, qpos)
 
-    cache_k, cache_v = cache
-
-    def body(carry, xs):
-        h = carry
-        layer_params, ck, cv = xs
-        with jax.named_scope("attn"):
-            hn = block.input_norm(layer_params["input_norm"], h)
-            qkv = jnp.einsum("bsh,hkgd->bskgd", hn,
-                             layer_params["attn"]["wqkv"].astype(h.dtype))
-            q = qkv[..., : att.group, :].reshape(b, C, att.n_q,
-                                                 c.head_dim)
-            k = qkv[..., att.group, :]
-            v = qkv[..., att.group + 1, :]
-            q, k = ops.apply_rotary_qk(q, k, cos, sin, qpos)
+    def layer(block, lp, h, cache, at):
+        def step(attn, p, q, entries):
             with jax.named_scope("kv_write"):
-                ck = ck.at[rows[:, None], qpos].set(k.astype(ck.dtype))
-                cv = cv.at[rows[:, None], qpos].set(v.astype(cv.dtype))
-            attn = _attend_cached_chunk(q, ck, cv, start, scale)
-            h = h + att.o_proj(layer_params["attn"]["o_proj"],
-                               attn.reshape(b, C, att.n_q * c.head_dim))
-        with jax.named_scope("mlp"):
-            mlp_out = block.mlp(
-                layer_params["mlp"],
-                block.post_norm(layer_params["post_norm"], h))
-            if isinstance(mlp_out, tuple):  # MoE
-                mlp_out = mlp_out[0]
-            h = h + mlp_out
-        return h, ((ck, cv, k, v) if collect_token_kv else (ck, cv))
+                new = tuple(
+                    c.at[at + (rows[:, None], qpos)].set(e.astype(c.dtype))
+                    for c, e in zip(cache, entries))
+            return (attn.attend_dense(p, q, tuple(_at(c, at) for c in new),
+                                      start),
+                    new, entries if collect_token_kv else None)
+        return _layer(block, lp, h, rope, qpos, step)
 
     with jax.named_scope("layer"):
-        x, ys = lax.scan(
-            body, x, (mp["layers"]["layers"], cache_k, cache_v))
-    hidden = model.model.final_norm(mp["final_norm"], x)
-    logits = model.logits(params, hidden)
-    if collect_token_kv:
-        new_k, new_v, k_chunk, v_chunk = ys
-        return logits, (new_k, new_v), (k_chunk, v_chunk)
-    return logits, ys
+        x, stats, cache, chunk = _walk_layers(
+            model, params, x, tuple(cache), stats, layer, sliced=True)
+    logits = model.logits(params, model.final_hidden(params, x))
+    return ((logits, cache) + ((chunk,) if collect_token_kv else ())
+            + (() if stats is None else (stats,)))
 
 
 def verify_step_slots(model, params, tokens, cache, positions):
@@ -949,6 +406,158 @@ def verify_step_slots(model, params, tokens, cache, positions):
     return extend_cache(model, params, tokens, cache,
                         positions.astype(jnp.int32),
                         collect_token_kv=True)
+
+
+# ---------------------------------------------------------------------------
+# Paged pools: the decode step and the verify step
+# ---------------------------------------------------------------------------
+
+def _paged_write(pool, scale, table, positions, t, layer, base, bits):
+    """Scatter a block's entries t [S, C, *stored shape], token i of slot
+    s at (table[s, (positions[s] + i) // ps], (positions[s] + i) % ps),
+    into ONE layer's pages of the carried pool: the payload into the
+    flat page array [L * P, ps, ...] at page `base + page` (base =
+    l * P, added after any null-page redirect), and for quantized pages
+    (`scale` not None: int8, or int4 nibble payloads with ``bits=4``)
+    the per-head-vector f32 scale into the layer's plane of
+    [L, P, ps, n_kv].  Inactive slots' tables point at the null page
+    (the layer's id 0) — their write lands there harmlessly
+    (serving/kv_pool.py) — and so do a block's positions past the
+    table's reach (the redirect `serving/kv_pool.write_tokens` applies;
+    a single token's position never is).  Returns (pool, scale)."""
+    S, C = t.shape[:2]
+    ps, mp = pool.shape[1], table.shape[1]
+    if C == 1:
+        t = t[:, 0]
+        page, off = table[jnp.arange(S), positions // ps], positions % ps
+    else:
+        pos = positions[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
+        pidx = pos // ps
+        page = jnp.where(
+            pidx < mp,
+            table[jnp.arange(S)[:, None], jnp.clip(pidx, 0, mp - 1)], 0)
+        off = pos % ps
+    if scale is None:
+        return pool.at[base + page, off].set(t.astype(pool.dtype)), None
+    # the SAME primitives as the gather route's writes (kv_pool.write_*):
+    # pool contents are bit-identical across the decode programs
+    from hetu_tpu.serving.kv_pool import quantize_heads
+    q, s = quantize_heads(t.astype(jnp.float32), bits)
+    return pool.at[base + page, off].set(q.astype(pool.dtype)), \
+        scale.at[layer, page, off].set(s)
+
+
+def _paged_forward(model, params, tokens, pool_tree, table, positions,
+                   stats):
+    """A block of C tokens a slot (tokens [S, C], token i at
+    positions[s] + i; [S] and C = 1 for the decode step) through the
+    layers, attending a paged pool where it lies.
+
+    pool_tree: the pool's arrays as it lays them out
+    (`serving/kv_pool.PoolArrays.tree()`): one page array
+    [L, P, page_size, *stored shape] per array of the model's cache
+    contract, then, for quantized pages (int8, or uint8 nibble pairs of
+    int4: the K/V kind only), a plane of per-head-vector f32 scales
+    [L, P, page_size, n_kv] for each.  The block's entries are scattered
+    into each slot's pages BEFORE the query attends (write-then-attend:
+    the token sees itself, exactly like the dense path).
+
+    The pool is carried whole through the layers and written in place
+    (`_walk_layers`): a caller that donates it (the engine does) gets
+    its own buffers back, with no second pool among the program's
+    temporaries.  The page arrays are carried as their flat views
+    [L * P, ps, ...] (a bitcast): layer l's page p is page l * P + p,
+    so with `table + l * P` as its page table the same scatter and the
+    same kernel walk the same bytes, and l * P is the layer's null
+    page.  The scale planes stay [L, P, ps, n_kv]: the kernel reads a
+    page's scales as a block of a page-major array whose rows are padded
+    to 128 lanes, which is not how the planes are stored, so it is
+    handed ONE layer's plane, `scale[l]`, to read by the engine's own
+    page ids (`scale_table`), and what is converted for it is P pages a
+    layer, never L * P.
+
+    Returns (final-norm hidden [S, C, hidden], pool tree, stats)."""
+    positions = positions.astype(jnp.int32)
+    table = table.astype(jnp.int32)
+    C = 1 if tokens.ndim == 1 else tokens.shape[1]
+    n = len(cache_contract(model).token_shapes)
+    pools, scales = tuple(pool_tree[:n]), tuple(pool_tree[n:])
+    L, P, ps = pools[0].shape[:3]
+    quant = (None if not scales
+             else "int4" if pools[0].dtype == jnp.uint8 else "int8")
+    bits = 4 if quant == "int4" else 8
+    pos_ids = positions[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
+    with jax.named_scope("embed"):
+        x = model.embed_tokens(
+            params, tokens[:, None] if tokens.ndim == 1 else tokens, pos_ids)
+    rope = model.rope_tables(table.shape[1] * ps)
+
+    def layer(block, lp, h, state, at):
+        flat, scales = state
+        (l,) = at
+        base = l * P
+
+        def step(attn, p, q, entries):
+            with jax.named_scope("kv_write"):
+                new = [_paged_write(pool, sc, table, positions, e, l, base,
+                                    bits)
+                       for pool, sc, e in zip(flat, scales or (None,) * n,
+                                              entries)]
+            flat_, scales_ = (tuple(w[0] for w in new),
+                              tuple(w[1] for w in new) if scales else ())
+            quantized = (dict(scales=scales_, layer=l, quant=quant)
+                         if scales else {})
+            return (attn.attend_paged(p, q, flat_, table, positions, base,
+                                      **quantized), (flat_, scales_))
+        h, st, state = _layer(block, lp, h, rope, pos_ids, step)
+        return h, st, state, None
+
+    with jax.named_scope("layer"):
+        flat = tuple(p.reshape((L * P,) + p.shape[2:]) for p in pools)
+        x, stats, (flat, scales), _ = _walk_layers(
+            model, params, x, (flat, scales), stats, layer)
+        pools = tuple(f.reshape(p.shape) for f, p in zip(flat, pools))
+    return model.final_hidden(params, x), pools + scales, stats
+
+
+def decode_step_paged(model, params, tokens, pool_tree, table, positions,
+                      stats=None):
+    """One decode step attending DIRECTLY over a paged pool — the
+    gather-free form of `decode_step_slots` (ops/pallas/paged_attention;
+    the serving engine's paged decode program).
+
+    tokens: [S] int32; pool_tree: the pool's arrays (`_paged_forward`
+    says which), page 0 of a layer the null page; table: [S, max_pages]
+    int32; positions: [S] int32 — slot s's current token sits at
+    positions[s] and attends over everything at or before it.  Returns
+    (logits [S, vocab], the updated pool tree) and, given the running
+    `stats` vector of a model that counts, the advanced one last."""
+    hidden, pool_tree, stats = _paged_forward(
+        model, params, tokens, pool_tree, table, positions, stats)
+    logits = model.logits(params, hidden)[:, 0, :]
+    return (logits, pool_tree) + (() if stats is None else (stats,))
+
+
+def verify_step_paged(model, params, tokens, pool_tree, table, positions,
+                      *, return_hidden: bool = False):
+    """The speculative VERIFY step attending DIRECTLY over a paged KV
+    pool — `verify_step_slots` without the gather (ops/pallas/
+    paged_attention.paged_verify: all k+1 query positions walk the
+    slot's pages in one launch with per-position causal masks).
+
+    tokens: [S, C] int32 (last emitted token + k drafts per slot);
+    positions: [S] int32 — token i of the block sits at positions[s]+i;
+    the pool tree as `decode_step_paged`.  Returns
+    (logits [S, C, vocab], the updated pool tree).
+
+    ``return_hidden=True`` returns the final-norm HIDDEN states
+    [S, C, hidden] instead of logits — the fused sampling epilogue
+    (serving/sampling.sample_hidden_grid) consumes them directly so the
+    [S, C, vocab] logits plane never materializes in HBM."""
+    hidden, pool_tree, _ = _paged_forward(model, params, tokens, pool_tree,
+                                          table, positions, None)
+    return (hidden if return_hidden else model.logits(params, hidden),
+            pool_tree)
 
 
 def generate(model, params, input_ids, *, max_new_tokens: int,

@@ -17,6 +17,7 @@ from jax import lax
 
 from hetu_tpu import ops
 from hetu_tpu.dstates import DistributedStates as DS
+from hetu_tpu.models.cache_contract import KVAttention
 from hetu_tpu.nn import initializers as init
 from hetu_tpu.nn.module import Module, stack_param_specs
 from hetu_tpu.nn.parallel import (ParallelLayerNorm, RowParallelLinear,
@@ -87,7 +88,7 @@ class GPTConfig:
         return 6.0 * n + 12 * self.num_hidden_layers * self.hidden_size * seq_len
 
 
-class GPTAttention(Module):
+class GPTAttention(Module, KVAttention):
     """MHA with biases (reference: gpt_model.py GPTAttention)."""
 
     def __init__(self, config: GPTConfig, strategy: ParallelStrategy):
@@ -143,6 +144,20 @@ class GPTAttention(Module):
         attn = checkpoint_name(attn, "attn_out")
         return self.o_proj(params["o_proj"], attn.reshape(b, s, h))
 
+    # -- the serving programs' hooks (models/generation.py); how a query
+    # attends the cached K/V is `KVAttention`'s ----------------------------
+    def project(self, params, hn, rope, pos_ids):
+        """hn [b, s, h] (normed) -> (q, entries (k, v)), each
+        [b, s, heads, hd]: the biased fused projection of `forward`
+        (positions are in the embedding: `rope` is None)."""
+        qkv = jnp.einsum("bsh,hngd->bsngd", hn,
+                         params["wqkv"].astype(hn.dtype)) \
+            + params["bqkv"].astype(hn.dtype)
+        return qkv[..., 0, :], (qkv[..., 1, :], qkv[..., 2, :])
+
+    def output(self, params, attn):
+        return self.o_proj(params["o_proj"], attn)
+
 
 class GPTMLP(Module):
     def __init__(self, config: GPTConfig, strategy: ParallelStrategy):
@@ -179,6 +194,14 @@ class GPTBlock(Module):
                                      eps=c.layer_norm_eps,
                                      param_dtype=c.param_dtype)
         self.mlp = GPTMLP(c, strategy)
+
+    # the serving programs' names for the two norms and the MLP
+    # (`serving_layers` hands the parameters out under the same names)
+    input_norm = property(lambda self: self.ln1)
+    post_norm = property(lambda self: self.ln2)
+
+    def mlp_stats(self, params, x):
+        return self.mlp(params, x), None
 
     def forward(self, params, x, *, position_ids=None, segment_ids=None,
                 rng=None, deterministic=True):
@@ -358,6 +381,43 @@ class GPTLMHeadModel(Module):
             self.param("lm_head", (config.hidden_size, config.vocab_size),
                        init.normal(config.initializer_range),
                        dtype=config.param_dtype, ds=lm_ds)
+
+    # -- what the serving programs of models/generation.py take -----------
+    #: the programs carry no stats vector for this family
+    STATS = ()
+
+    def embed_tokens(self, params, ids, pos_ids):
+        mp = params["model"]
+        x = self.model.wte(mp["wte"], ids) \
+            + jnp.take(mp["wpe"], pos_ids, axis=0)
+        return x.astype(self.config.compute_dtype)
+
+    def rope_tables(self, max_len: int):
+        return None     # learned positions, added in `embed_tokens`
+
+    def serving_layers(self, params):
+        """Runs (block, parameters, count) in the cache's layer order
+        (as `LlamaLMHeadModel.serving_layers`), a layer's parameters
+        under the names the programs read: ln1 / ln2 as input_norm /
+        post_norm."""
+        mp, n = params["model"], self.config.num_hidden_layers
+
+        def named(lp):
+            return {"input_norm": lp["ln1"], "attn": lp["attn"],
+                    "post_norm": lp["ln2"], "mlp": lp["mlp"]}
+        if self.config.use_scan:
+            return [(self.model.block, named(mp["blocks"]), n)]
+        return [(self.model.block, named(mp[f"block_{i}"]), None)
+                for i in range(n)]
+
+    def final_hidden(self, params, x):
+        return self.model.final_ln(params["model"]["final_ln"], x)
+
+    def lm_head_weight(self, params):
+        """The head as a [hidden, vocab] matrix, tied or not."""
+        if self.config.tie_word_embeddings:
+            return params["model"]["wte"]["weight"].T
+        return params["lm_head"]
 
     def logits(self, params, hidden):
         """hidden -> logits via the tied/untied head (one implementation

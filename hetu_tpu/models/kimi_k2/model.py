@@ -1,7 +1,7 @@
 """Kimi-K2 (the DeepSeek-V3 block): multi-head latent attention, a
 leading dense layer, then layers of sigmoid-routed experts with a shared
 expert, YaRN rotary scaling.  Serving only: `ServingEngine` takes the
-model through the cache-contract programs of `models/generation.py`;
+model through the programs of `models/generation.py`, by the hooks below;
 `Trainer` does not know it (ROADMAP).
 
 One layer, pre-norm, x one token's hidden state:
@@ -172,14 +172,16 @@ class MLAttention(Module):
             parts.append(jnp.zeros(q_lat.shape[:-1] + (pad,), q_lat.dtype))
         return jnp.concatenate(parts, axis=-1)
 
-    def attend_paged(self, params, q, pools, table, positions):
-        """ABSORBED form over the paged pool.  pools = (latent pages
-        [P, ps, stored],); table [S, max_pages] of page ids into it;
+    def attend_paged(self, params, q, pools, table, positions, base):
+        """ABSORBED form over the paged pool.  pools = (latent pages of
+        all layers [L * P, ps, stored],), this layer's P pages from
+        `base` on; table [S, max_pages] of page ids within a layer;
         slot s attends positions <= positions[s].  -> [S, 1, nh * dv]."""
         from hetu_tpu.ops.pallas import paged_latent_attention as _pla
         from hetu_tpu.ops.pallas import resolve_route
         c = self.config
         (pool,) = pools
+        table = table + base
         qa = self.absorb_query(params, q)                # [S, nh, stored]
         kw = dict(value_dim=c.kv_lora_rank, softmax_scale=c.softmax_scale)
         if resolve_route("paged_latent", _pla.check_shapes, qa.shape,
@@ -200,12 +202,17 @@ class MLAttention(Module):
         with jax.named_scope("mla_out"):
             return attn @ params["wo"].astype(attn.dtype)
 
+    def attend_prompt(self, params, q, entries):
+        """Whole prompts attending their own entries, causally."""
+        b, s = entries[0].shape[:2]
+        return self.attend_dense(params, q, entries,
+                                 jnp.zeros((b,), jnp.int32),
+                                 block=math.gcd(s, 512))
+
     def forward(self, params, hn, rope, pos_ids):
         """Whole sequences hn [b, s, h] at positions 0..s-1."""
         q, entries = self.project(params, hn, rope, pos_ids)
-        return self.output(params, self.attend_dense(
-            params, q, entries, jnp.zeros((hn.shape[0],), jnp.int32),
-            block=math.gcd(hn.shape[1], 512)))
+        return self.output(params, self.attend_prompt(params, q, entries))
 
 
 class DenseMLP(Module):
@@ -287,8 +294,11 @@ class _Layers(Module):
         specs = self.block.param_specs()
         return {f"layer_{i}": copy.deepcopy(specs) for i in range(self.num)}
 
-    def pairs(self, params):
-        return [(self.block, params[f"layer_{i}"]) for i in range(self.num)]
+    def runs(self, params):
+        """(block, a layer's own parameters, None) per layer: runs of one,
+        which the walk of models/generation.py calls and never scans."""
+        return [(self.block, params[f"layer_{i}"], None)
+                for i in range(self.num)]
 
 
 class KimiK2Model(Module):
@@ -347,19 +357,22 @@ class KimiK2LMHeadModel(Module):
     add_stats = staticmethod(add_moe_stats)
     STATS = MOE_STATS
 
-    def embed_tokens(self, params, ids):
+    def embed_tokens(self, params, ids, pos_ids):
         return self.model.embed(params["model"]["embed"], ids).astype(
             self.config.compute_dtype)
 
     def serving_layers(self, params):
-        """[(block, its parameters)] in the pool's layer order: the
-        leading dense layers, then the expert layers."""
+        """Runs (block, parameters, count) in the pool's layer order:
+        the leading dense layers, then the expert layers."""
         m, mp = self.model, params["model"]
-        return (m.dense_layers.pairs(mp["dense_layers"])
-                + m.moe_layers.pairs(mp["moe_layers"]))
+        return (m.dense_layers.runs(mp["dense_layers"])
+                + m.moe_layers.runs(mp["moe_layers"]))
 
     def final_hidden(self, params, x):
         return self.model.final_norm(params["model"]["final_norm"], x)
+
+    def lm_head_weight(self, params):
+        return params["lm_head"]
 
     def logits(self, params, hidden):
         with jax.named_scope("lm_head"):
@@ -371,8 +384,8 @@ class KimiK2LMHeadModel(Module):
         rope = self.rope_tables(s)
         pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
         with jax.named_scope("embed"):
-            x = self.embed_tokens(params, input_ids)
+            x = self.embed_tokens(params, input_ids, pos)
         with jax.named_scope("layer"):
-            for block, lp in self.serving_layers(params):
+            for block, lp, _ in self.serving_layers(params):
                 x = block(lp, x, rope, pos)
         return self.logits(params, self.final_hidden(params, x))

@@ -32,11 +32,12 @@ from hetu_tpu.nn.parallel import (
     VocabParallelEmbedding,
 )
 from hetu_tpu.parallel.strategy import ParallelStrategy
+from hetu_tpu.models.cache_contract import KVAttention
 from hetu_tpu.models.llama.config import LlamaConfig
 from hetu_tpu.dstates import DistributedStates as DS
 
 
-class LlamaAttention(Module):
+class LlamaAttention(Module, KVAttention):
     """GQA attention with RoPE (reference: llama_model.py:88)."""
 
     def __init__(self, config: LlamaConfig, strategy: ParallelStrategy):
@@ -115,6 +116,25 @@ class LlamaAttention(Module):
         out = self.o_proj(params["o_proj"], attn.reshape(b, s, self.n_q * hd))
         return out
 
+    # -- the serving programs' hooks (models/generation.py); how a query
+    # attends the cached K/V is `KVAttention`'s ----------------------------
+    def project(self, params, hn, rope, pos_ids):
+        """hn [b, s, h] (normed) at positions pos_ids [b, s] ->
+        (q [b, s, n_q, hd], entries (k, v) [b, s, n_kv, hd]), q and k
+        rotated: the fused projection of `forward`."""
+        b, s, _ = hn.shape
+        qkv = jnp.einsum("bsh,hkgd->bskgd", hn,
+                         params["wqkv"].astype(hn.dtype))
+        q = qkv[..., : self.group, :].reshape(b, s, self.n_q,
+                                              self.config.head_dim)
+        k = qkv[..., self.group, :]
+        v = qkv[..., self.group + 1, :]
+        q, k = ops.apply_rotary_qk(q, k, *rope, pos_ids)
+        return q, (k, v)
+
+    def output(self, params, attn):
+        return self.o_proj(params["o_proj"], attn)
+
 
 class LlamaMLP(Module):
     """SwiGLU MLP with fused gate+up (reference: llama_model.py:292)."""
@@ -168,6 +188,12 @@ class LlamaBlock(Module):
                 initializer_range=c.initializer_range)
         else:
             self.mlp = LlamaMLP(c, strategy)
+
+    def mlp_stats(self, params, x):
+        """(mlp(x), what the layer counts of itself: nothing) — the
+        serving programs' form of the MLP, dense or routed."""
+        y = self.mlp(params, x)
+        return (y[0] if self.config.num_experts > 0 else y), None
 
     def forward(self, params, x, *, cos, sin, position_ids=None,
                 segment_ids=None, rng=None, deterministic=True,
@@ -418,6 +444,39 @@ class LlamaLMHeadModel(Module):
             self.param("lm_head", (c.hidden_size, c.vocab_size),
                        init.normal(c.initializer_range), dtype=c.param_dtype,
                        ds=lm_ds)
+
+    # -- what the serving programs of models/generation.py take -----------
+    #: the programs carry no stats vector for this family
+    STATS = ()
+
+    def embed_tokens(self, params, ids, pos_ids):
+        return self.model.embed(params["model"]["embed"], ids).astype(
+            self.config.compute_dtype)
+
+    def rope_tables(self, max_len: int):
+        c = self.config
+        return ops.build_rope_cache(c.max_position_embeddings, c.head_dim,
+                                    c.rope_theta)
+
+    def serving_layers(self, params):
+        """Runs (block, parameters, count) in the cache's layer order:
+        the whole stack as one run of stacked parameters (scanned), or,
+        built with use_scan=False, a run per layer (count None: the
+        layer's own arrays, called)."""
+        stack, lp = self.model.layers, params["model"]["layers"]
+        if self.config.use_scan:
+            return [(stack.block, lp["layers"], stack.num_layers)]
+        return [(stack.block, lp[f"layer_{i}"], None)
+                for i in range(stack.num_layers)]
+
+    def final_hidden(self, params, x):
+        return self.model.final_norm(params["model"]["final_norm"], x)
+
+    def lm_head_weight(self, params):
+        """The head as a [hidden, vocab] matrix, tied or not."""
+        if self.config.tie_word_embeddings:
+            return params["model"]["embed"]["weight"].T
+        return params["lm_head"]
 
     def logits(self, params, hidden):
         c = self.config

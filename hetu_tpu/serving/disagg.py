@@ -59,7 +59,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from hetu_tpu.models.generation import extend_cache
+from hetu_tpu.models.cache_contract import cache_contract
+from hetu_tpu.models.generation import extend_cache, init_cache
 from hetu_tpu.serving.engine import first_token_from_logits
 from hetu_tpu.serving.kv_pool import dequantize_heads, quantize_heads
 from hetu_tpu.serving.request import Request, RequestResult
@@ -107,17 +108,13 @@ class PrefillWorker:
         self.num_slots = num_slots
         self.sampling = sampling
         self._registry = registry
-        c = model.config
-        from hetu_tpu.models.cache_contract import has_cache_contract
-        if has_cache_contract(model):
+        kind = cache_contract(model).kind
+        if kind != "kv":
             raise NotImplementedError(
-                f"{type(model).__name__} brings its own cache contract; the "
+                f"{type(model).__name__} keeps a {kind!r} cache; the "
                 "disaggregated prefill tier (serving/disagg.py) ships K/V "
                 "scratch only and is not built for it")
-        n_kv = getattr(c, "num_key_value_heads", c.num_attention_heads)
-        shape = (c.num_hidden_layers, 1, max_len, n_kv, c.head_dim)
-        self._scratch = (jnp.zeros(shape, c.compute_dtype),
-                         jnp.zeros(shape, c.compute_dtype))
+        self._scratch = init_cache(model, 1, max_len)
 
         def chunk_fn(params, chunk, cache, start):
             return extend_cache(model, params, chunk, cache, start)

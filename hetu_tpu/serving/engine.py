@@ -39,8 +39,9 @@ prefix cache (HETU_TPU_SERVE_PREFIX_CACHE, serving/prefix_cache.py —
 shared prompts admit with their KV pages resident), speculative
 decoding (HETU_TPU_SPEC_DECODE, serving/spec_decode.py — the decode
 program becomes a batched k+1-token verify), and SLO-class preemptive
-admission (HETU_TPU_SERVE_PREEMPT).  Model families: llama + gpt, via
-the family dispatch in `models/generation`.
+admission (HETU_TPU_SERVE_PREEMPT).  The programs know no model family:
+`models/generation` writes them against hooks the model brings (llama,
+gpt, kimi_k2; a family of one's own needs no edit here).
 
 The optional `reshard` hook (`serving/reshard.LoadAdaptiveMesh`) is the
 Hetis move: queue-depth tier changes re-shard the serving params through
@@ -60,10 +61,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from hetu_tpu.models.cache_contract import (cache_contract,
-                                            has_cache_contract)
+from hetu_tpu.models.cache_contract import cache_contract
 from hetu_tpu.models.generation import (_check_context_length,
-                                        decode_step_slots, extend_cache)
+                                        decode_step_paged, decode_step_slots,
+                                        extend_cache, init_cache,
+                                        lm_head_weight, verify_step_paged,
+                                        verify_step_slots)
 from hetu_tpu.obs.health import maybe_serving_health_monitor
 from hetu_tpu.obs.metrics import MetricsRegistry, get_registry
 from hetu_tpu.obs.runlog import RunLog, default_runlog_path
@@ -295,10 +298,7 @@ class ServingEngine:
         # (models/cache_contract.py): the pool, the prefill scratch and
         # the cache-byte gauges are all sized from this one contract
         self.cache = cache_contract(model)
-        #: the model brings its own cache contract: the serving programs
-        #: are the contract's (models/generation.*_contract)
-        self._contract_programs = has_cache_contract(model)
-        if self._contract_programs:
+        if self.cache.kind != "kv":
             self._refuse_unbuilt(reshard, draft_model, drafter)
         self.pool = PagePool.for_contract(
             self.cache, num_pages=self.config.num_pages,
@@ -444,22 +444,18 @@ class ServingEngine:
         # per-request prefill scratch: a dense [L, 1, max_len] cache the
         # chunk program advances, one array per array of the contract;
         # template zeros reused (functionally) for every admission
-        self._scratch = tuple(
-            jnp.zeros((self.cache.num_layers, 1, self.config.max_len)
-                      + tuple(shape), c.compute_dtype)
-            for shape in self.cache.stored_shapes)
+        self._scratch = init_cache(model, 1, self.config.max_len)
         from hetu_tpu.serving.kv_pool import contract_bytes_per_token
         mode = (self.config.kv_quant if self.config.kv_quant != "none" else
                 {2: "bf16", 4: "fp32"}[jnp.dtype(c.compute_dtype).itemsize])
         self._registry.set_gauge(
             "serve.kv_bytes_per_token",
             contract_bytes_per_token(self.cache, mode))
-        #: the running stats vector of the contract programs (what
-        #: `model.STATS` names: an expert model's assignment counts), on
-        #: the device between fetches; None for the K/V families, whose
-        #: programs carry none
-        self._stats_zero = (model.zero_stats() if self._contract_programs
-                            else None)
+        #: the running stats vector of the programs of a model that
+        #: counts (what `model.STATS` names: an expert model's assignment
+        #: counts), on the device between fetches; None for a model whose
+        #: STATS is empty: its programs carry none
+        self._stats_zero = model.zero_stats() if model.STATS else None
         self._stats_acc = self._stats_zero
         # every kernel routing decision of this engine — the static ones
         # _build_programs takes, then each program's as it is traced —
@@ -469,7 +465,7 @@ class ServingEngine:
             self._build_programs()
 
     def _refuse_unbuilt(self, reshard, draft_model, drafter):
-        """A model that brings its own cache contract (latent attention)
+        """A model whose cache is not of the K/V kind (latent attention)
         runs the normal path; what this engine has only for K/V pools is
         refused here by name, never run as something else."""
         cfg, name = self.config, type(self.model).__name__
@@ -487,7 +483,7 @@ class ServingEngine:
         if asked:
             raise NotImplementedError(
                 f"{name} stores {self.cache.token_shapes} a token a layer "
-                f"(its own cache contract); not built for it: "
+                f"(a {self.cache.kind!r} cache, not K/V); not built for it: "
                 + "; ".join(asked))
 
     # ------------------------------------------------------------ build
@@ -511,9 +507,10 @@ class ServingEngine:
         causally-masked query positions per slot per launch.  Evaluated
         once at build: the decision is static, like every other program
         shape."""
-        if self._contract_programs:
-            # one decode program, gather-free over the page table; which
-            # attention it calls there is the model's route
+        if self.cache.kind != "kv":
+            # no gather route for such a pool: one decode program over
+            # the page table, and which attention it calls there is the
+            # model's route (`attend_paged`)
             return True
         from hetu_tpu.ops.pallas import paged_attention as _pa
         from hetu_tpu.ops.pallas import resolve_route
@@ -557,64 +554,42 @@ class ServingEngine:
             return sample_tokens(logits, seeds, positions + 1,
                                  temps, top_ks, top_ps)
 
-        if self.decode_paged:
-            from hetu_tpu.models.generation import decode_step_paged
+        #: 1 where the programs carry the model's running stats vector
+        #: (`model.STATS`), as one more argument and result; else 0
+        counts = int(bool(model.STATS))
 
-            def decode_fn(params, pool_tree, table, tokens, positions,
-                          *sample_args):
-                # gather-free: the kernel walks the page table directly;
-                # this token's K/V are scattered inside the step (the
-                # write_token scatter is folded into the program).
+        paged = self.decode_paged
+
+        def decode_fn(params, pool_tree, table, tokens, positions, *rest):
+            stats, sample_args = rest[:counts], rest[counts:]
+            if paged:
+                # gather-free: the model's `attend_paged` walks the page
+                # table directly; this token's entries are scattered
+                # inside the step (the write_token scatter is folded
+                # into the program).  The tree is the pool's own:
                 # int8/int4 pools carry (k, v, k_scale, v_scale) — the
                 # kernel dequantizes pages in-VMEM
-                quant = len(pool_tree) == 4
-                ks = pool_tree[2] if quant else None
-                vs = pool_tree[3] if quant else None
-                logits, *new_pools = decode_step_paged(
-                    model, params, tokens, pool_tree[0], pool_tree[1],
-                    table, positions, k_scale=ks, v_scale=vs,
-                    kv_quant=pool.quant if quant else None)
-                nxt = pick_token(logits, positions, sample_args)
-                return nxt, tuple(new_pools)
-        else:
-            def decode_fn(params, pool_tree, table, tokens, positions,
-                          *sample_args):
+                logits, pool_tree, *stats = decode_step_paged(
+                    model, params, tokens, pool_tree, table, positions,
+                    *stats)
+            else:
                 ck, cv = pool.gather(pool_tree, table)
                 logits, _, (kt, vt) = decode_step_slots(
                     model, params, tokens, (ck, cv), positions)
                 with jax.named_scope("kv_write"):
-                    new_tree = pool.write_token(pool_tree, table,
-                                                positions, kt, vt)
-                nxt = pick_token(logits, positions, sample_args)
-                return nxt, new_tree
+                    pool_tree = pool.write_token(pool_tree, table,
+                                                 positions, kt, vt)
+            nxt = pick_token(logits, positions, sample_args)
+            # the stats ride out behind the tokens: one fetch
+            return (jnp.concatenate([nxt, *stats]) if stats else nxt,
+                    pool_tree)
 
-        def chunk_fn(params, chunk, cache, start):
-            return extend_cache(model, params, chunk, cache, start)
+        def chunk_fn(params, chunk, cache, start, *stats):
+            return extend_cache(model, params, chunk, cache, start, *stats)
 
-        def write_fn(pool_tree, pages_row, ks, vs):
+        def write_fn(pool_tree, pages_row, *caches):
             with jax.named_scope("kv_write"):
-                return pool.write_pages(pool_tree, pages_row, ks, vs)
-
-        if self._contract_programs:
-            from hetu_tpu.models.generation import (
-                decode_step_paged_contract, extend_cache_contract)
-
-            def decode_fn(params, pool_tree, table, tokens, positions,
-                          stats, *sample_args):
-                logits, pools, stats = decode_step_paged_contract(
-                    model, params, tokens, pool_tree, table, positions,
-                    stats)
-                nxt = pick_token(logits, positions, sample_args)
-                # the stats ride out behind the tokens: one fetch
-                return jnp.concatenate([nxt, stats]), pools
-
-            def chunk_fn(params, chunk, cache, start, stats):
-                return extend_cache_contract(model, params, chunk, cache,
-                                             start, stats)
-
-            def write_fn(pool_tree, pages_row, *caches):
-                with jax.named_scope("kv_write"):
-                    return pool.write_pages(pool_tree, pages_row, *caches)
+                return pool.write_pages(pool_tree, pages_row, *caches)
 
         # speculative-decoding verify (serving/spec_decode.py): score
         # the last token + k drafts in one multi-query forward —
@@ -644,18 +619,10 @@ class ServingEngine:
         def verify_forward(params, pool_tree, table, tokens, positions,
                            pos_grid, want_hidden):
             """-> (logits_or_hidden [S, K1, ...], new pool tree)."""
-            quant = len(pool_tree) == 4
             if verify_paged:
-                from hetu_tpu.models.generation import verify_step_paged
-                ks = pool_tree[2] if quant else None
-                vs = pool_tree[3] if quant else None
-                out, *new_pools = verify_step_paged(
-                    model, params, tokens, pool_tree[0], pool_tree[1],
-                    table, positions, k_scale=ks, v_scale=vs,
-                    kv_quant=pool.quant if quant else None,
+                return verify_step_paged(
+                    model, params, tokens, pool_tree, table, positions,
                     return_hidden=want_hidden)
-                return out, tuple(new_pools)
-            from hetu_tpu.models.generation import verify_step_slots
             ck, cv = pool.gather(pool_tree, table)
             logits, _, (kc, vc) = verify_step_slots(
                 model, params, tokens, (ck, cv), positions)
@@ -700,7 +667,6 @@ class ServingEngine:
                     params, pool_tree, table, tokens, positions,
                     pos_grid, fused_sample)
                 if fused_sample:
-                    from hetu_tpu.models.generation import lm_head_weight
                     from hetu_tpu.serving.sampling import \
                         sample_hidden_grid
                     seeds, temps, top_ks, top_ps = full_sample_args(
@@ -791,7 +757,7 @@ class ServingEngine:
         # returned tree, so the donated input is never reused).  The
         # paged programs keep that promise inside too: the pool is a
         # carry of their layer loop, written in place, one buffer from
-        # argument to result (models/generation._scan_layers_paged;
+        # argument to result (models/generation._walk_layers;
         # tests/test_chip_compile.py holds the compiled program to it).
         # With speculative decoding on, the verify program IS the
         # decode-step program (there is no single-token decode to build).
@@ -1182,8 +1148,7 @@ class ServingEngine:
                         # the step's one wait for the device
                         nxt = np.asarray(nxt)
                     with phase_span("serve.emit", phases):
-                        if self._contract_programs:
-                            self._note_program_stats(nxt[S:])
+                        self._note_program_stats(nxt[S:])
                         self.pool.arrays = PoolArrays.from_tree(pool_tree)
                         emitted = {i: [int(nxt[i])] for i in active}
                 with phase_span("serve.emit", phases):
@@ -1289,13 +1254,14 @@ class ServingEngine:
         return finished
 
     def _stats_args(self) -> tuple:
-        """The extra argument of the contract programs: the running
-        stats vector (none for the K/V families' programs)."""
+        """The extra argument of the programs of a model that counts
+        (`model.STATS`): the running stats vector; none otherwise."""
         return () if self._stats_acc is None else (self._stats_acc,)
 
     def _note_program_stats(self, values):
-        """The contract programs' running stats vector, fetched behind
-        the step's tokens, into the counters the MODEL names
+        """The programs' running stats vector, fetched behind the
+        step's tokens (empty for a model that counts nothing), into the
+        counters the MODEL names
         (`model.STATS`: (counter, "sum" | "max") per entry): a sum as an
         increment, a maximum as the running maximum; the device-side
         vector starts again from zero."""
@@ -1689,9 +1655,9 @@ class ServingEngine:
             out = self._chunk_jit(
                 self.params, jnp.asarray(ids[None]), st.prefill_cache,
                 jnp.int32(s), *self._stats_args())
-            logits, st.prefill_cache = out[:2]
-            if self._contract_programs:
-                self._stats_acc = out[2]
+            logits, st.prefill_cache, *stats = out
+            if stats:
+                (self._stats_acc,) = stats
             st.chunks_done += 1
             st.stats.prefill_chunks += 1
             self._registry.inc("serve.prefill_chunks")

@@ -157,7 +157,7 @@ def _paged_serving_cell():
     it inside the layer scan: 32 slots, 16 query / 8 KV heads of 128,
     128 table entries of 16-token pages, bfloat16, and as the pool every
     layer's 2048 pages in ONE flat array, the layer's pages reached
-    through the table (`models/generation._scan_layers_paged`)."""
+    through the table (`models/generation._paged_forward`)."""
     from hetu_tpu.ops.pallas.paged_attention import paged_attention
     slots, layers, pages = 32, 24, 2048
     pool = spec((layers * pages, 16, 8, HEAD_DIM), BF16)
@@ -288,19 +288,19 @@ def test_sharded_train_step_compiles_for_four_v5e_with_every_kernel():
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
-def _serving_engine(cfg=None, num_slots=8, **serve):
+def _serving_engine(cfg=None, num_slots=8, model=None, **serve):
     """The engine `chip_smoke.py` serves with — 8 slots x 2048 positions
-    of 16-token pages, 2 layers at Llama-2-7B widths — over abstract
-    parameters (programs are built lazily, nothing is materialised but
-    the zeroed pool)."""
+    of 16-token pages, 2 layers at Llama-2-7B widths, or `model` — over
+    abstract parameters (programs are built lazily, nothing is
+    materialised but the zeroed pool)."""
     from hetu_tpu.models.llama import LlamaConfig, LlamaLMHeadModel
     from hetu_tpu.serving.engine import ServeConfig, ServingEngine
 
-    cfg = cfg or LlamaConfig.llama2_7b(num_hidden_layers=2,
-                                       param_dtype=BF16)
-    model = LlamaLMHeadModel(cfg)
-    sc = ServeConfig(num_slots=num_slots, page_size=16, max_len=2048,
-                     prefill_chunk=128, **serve)
+    if model is None:
+        model = LlamaLMHeadModel(cfg or LlamaConfig.llama2_7b(
+            num_hidden_layers=2, param_dtype=BF16))
+    sc = ServeConfig(**{**dict(num_slots=num_slots, page_size=16,
+                               max_len=2048, prefill_chunk=128), **serve})
     params = jax.tree.map(lambda a: spec(a.shape, a.dtype),
                           model.abstract_params())
     engine = ServingEngine(model, params, sc)
@@ -308,21 +308,62 @@ def _serving_engine(cfg=None, num_slots=8, **serve):
     return engine
 
 
-def test_serving_programs_compile_for_one_v5e():
-    """The engine's decode step (with the paged-attention kernel walking
-    the page tables), prefill chunk and page write, for 8 slots x 2048
-    positions at Llama-2-7B widths."""
-    from chip_smoke import kernels_in
+def _gpt_block():
+    """Two GPT blocks (LayerNorm, learned positions, biased fused QKV,
+    GELU) at 16 heads of 128: the paged kernel's lane gate refuses
+    GPT-2's own head_dim of 64, which the gather route serves."""
+    from hetu_tpu.models.gpt import GPTConfig, GPTLMHeadModel
+    return GPTLMHeadModel(GPTConfig(
+        vocab_size=50304, hidden_size=2048, num_hidden_layers=2,
+        num_attention_heads=16, max_position_embeddings=2048,
+        param_dtype=BF16)), {}
 
-    engine = _serving_engine()
+
+def _kimi_block():
+    """Kimi-K2's dense layer and ONE expert layer at published widths,
+    12 of 384 experts held, at the serving cell's page and chunk."""
+    from hetu_tpu.models.kimi_k2 import KimiK2Config, KimiK2LMHeadModel
+    return KimiK2LMHeadModel(KimiK2Config(
+        vocab_size=20480, num_hidden_layers=2, experts_held=12,
+        rope_scaling={"type": "yarn", "factor": 64, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                      "original_max_position_embeddings": 4096},
+        param_dtype=BF16)), dict(page_size=256, max_len=4096,
+                                 prefill_chunk=512)
+
+
+#: family -> ((model, ServeConfig overrides) or None for the Llama-2-7B
+#: block, the kernels its decode program must hold as tpu_custom_calls)
+SERVING_FAMILIES = {
+    "llama": (None, ("paged_attn", "rotary", "swiglu")),
+    "gpt": (_gpt_block, ("paged_attn",)),
+    "kimi": (_kimi_block, ("paged_latent",)),
+}
+
+
+@pytest.mark.parametrize("family", SERVING_FAMILIES)
+def test_serving_programs_compile_for_one_v5e(family):
+    """The engine's decode step (with the family's paged-attention kernel
+    walking the page tables), prefill chunk and page write, for 8 slots x
+    2048 positions (Kimi: 4096, in its cell's 256-token pages).  One set
+    of programs (models/generation.py) for every family: the GPT block
+    had never met the chip's compiler before it shared every line with
+    the ones that run in three cells."""
+    from chip_smoke import KERNEL_SCOPES
+
+    make, kernels = SERVING_FAMILIES[family]
+    model, serve = make() if make else (None, {})
+    engine = _serving_engine(model=model, **serve)
     programs = engine.lower_programs(sharding=ONE_CHIP)
     assert sorted(programs) == ["decode", "prefill_chunk", "write_pages"]
     compiled = {name: low.compile() for name, low in programs.items()}
-    found = kernels_in(compiled["decode"].as_text())
-    assert found["paged_attn"] and found["rotary"] and found["swiglu"], found
+    calls = [ln for ln in compiled["decode"].as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
     routes = engine.kernel_routes
-    assert all(routes[k]["pallas"] and not routes[k]["xla"]
-               for k in ("paged_attn", "rotary", "swiglu")), routes
+    for k in kernels:
+        scope = KERNEL_SCOPES.get(k, f"pallas_{k}_attention")
+        assert any(scope in ln for ln in calls), (k, len(calls))
+        assert routes[k]["pallas"] and not routes[k]["xla"], routes
 
 
 @pytest.mark.parametrize("kv_quant", ["none", "int8"])
@@ -346,7 +387,7 @@ def test_decode_program_updates_the_pool_in_place(kv_quant):
         carries) or one layer's slab of page payload.  One layer's SCALE
         plane (int8 pages) is still sliced and converted for the kernel,
         which reads scales in a lane-padded layout: 1 MB a layer, and
-        what `_scan_layers_paged` says it does."""
+        what `_paged_forward` says it does."""
     import re
     from hetu_tpu.models.llama import LlamaConfig
     cfg = LlamaConfig(vocab_size=92544, hidden_size=2048,

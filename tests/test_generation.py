@@ -116,15 +116,31 @@ def _attend_cached_repeat(q, ck, cv, pos, scale):
     return out.astype(q.dtype)
 
 
+def _assert_within_ulps(new, old, ulps, scale):
+    """|new - old| <= `ulps` float32 ulps AT `scale`, the magnitude of
+    the largest term of the sums compared: that sets the rounding of a
+    float32 sum, so an element that cancels to near zero is held to it,
+    not to its own magnitude."""
+    np.testing.assert_allclose(
+        np.asarray(new), np.asarray(old), rtol=0,
+        atol=ulps * np.spacing(np.float32(scale)))
+
+
 def test_gqa_attend_bit_exact_vs_repeat_path():
-    """Regression vs the old repeat-then-attend GQA path.  The grouped
-    q·k score contraction is BIT-identical (same per-head dot, same
-    mapping q head j -> kv head j // group; asserted exactly).  The p·v
-    output contraction reassociates the softmax-weighted sum over the
-    cache axis when the operand is not materialized group-repeated —
-    bounded here at float32-ulp scale — and end-to-end greedy decode
-    stays token-identical (the goldens elsewhere in this file pin that
-    against full recompute and HF)."""
+    """Regression vs the old repeat-then-attend GQA path: the same
+    per-head dot, the same mapping q head j -> kv head j // group, so
+    both contractions equal the repeat path's up to float32 summation
+    order.  CHANGES.md PR 7 recorded "q·k scores bit-identical, p·v
+    within f32 ulp" and this test asserted bit equality of the scores;
+    on the XLA:CPU of this installation the two score einsums reduce
+    over head_dim in different orders (398 of 576 scores differ, by one
+    ulp of the largest score; each as near a float64 reference as the
+    other: 1.3e-6 and 1.0e-6), so the scores are held to what the
+    outputs are: 2 float32 ulps at the scale of the sums' largest term —
+    the largest score for q·k, the largest cached value for p·v, whose
+    weights are <= 1 (measured: 1 ulp each).  End-to-end greedy decode
+    stays token-identical (below, and the goldens elsewhere in this file
+    pin that against full recompute and HF)."""
     from hetu_tpu.models.generation import _attend_cached
     rng = np.random.default_rng(0)
     b, M, n_kv, group, hd = 3, 24, 2, 4, 16
@@ -132,24 +148,24 @@ def test_gqa_attend_bit_exact_vs_repeat_path():
     q = jnp.asarray(rng.normal(size=(b, 1, nq, hd)), jnp.float32)
     ck = jnp.asarray(rng.normal(size=(b, M, n_kv, hd)), jnp.float32)
     cv = jnp.asarray(rng.normal(size=(b, M, n_kv, hd)), jnp.float32)
-    # scores: grouped einsum == repeated einsum, bit for bit
+    # scores: grouped einsum == repeated einsum
     ckr = jnp.repeat(ck, group, axis=2)
     s_old = jnp.einsum("bqhd,bkhd->bhqk", q, ckr)
     s_new = jnp.einsum("bqhgd,bkhd->bhgqk",
                        q.reshape(b, 1, n_kv, group, hd), ck)
-    np.testing.assert_array_equal(
-        np.asarray(s_old), np.asarray(s_new).reshape(b, nq, 1, M))
-    # full attend: ulp-scale tolerance from the reassociated p·v sum
+    _assert_within_ulps(np.asarray(s_new).reshape(b, nq, 1, M), s_old, 2,
+                        scale=np.abs(s_old).max())
+    # full attend: the reassociated p·v sum on top
+    v_max = np.abs(cv).max()
     for pos in (0, 5, M - 1):
-        old = np.asarray(_attend_cached_repeat(q, ck, cv, pos, hd ** -0.5))
-        new = np.asarray(_attend_cached(q, ck, cv, pos, hd ** -0.5))
-        np.testing.assert_allclose(new, old, atol=5e-6, rtol=1e-5)
+        _assert_within_ulps(
+            _attend_cached(q, ck, cv, pos, hd ** -0.5),
+            _attend_cached_repeat(q, ck, cv, pos, hd ** -0.5), 2, v_max)
     # MHA (group == 1): same code path shape, same tolerance contract
     q1 = jnp.asarray(rng.normal(size=(b, 1, n_kv, hd)), jnp.float32)
-    np.testing.assert_allclose(
-        np.asarray(_attend_cached(q1, ck, cv, 7, hd ** -0.5)),
-        np.asarray(_attend_cached_repeat(q1, ck, cv, 7, hd ** -0.5)),
-        atol=5e-6, rtol=1e-5)
+    _assert_within_ulps(
+        _attend_cached(q1, ck, cv, 7, hd ** -0.5),
+        _attend_cached_repeat(q1, ck, cv, 7, hd ** -0.5), 2, v_max)
     # and the decode-level contract: token-identical greedy continuations
     # through the real model (GQA config) vs full recompute
     cfg = LlamaConfig.tiny(remat=False, compute_dtype=jnp.float32,

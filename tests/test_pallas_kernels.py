@@ -1104,8 +1104,6 @@ def test_paged_step_writes_the_pool_in_place(family, quant, step):
     from hetu_tpu.serving.kv_pool import dequantize_heads
     model, params, pool, tree, table = _paged_step_case(family, quant)
     L, ps, mp = 3, 8, table.shape[1]
-    kw = ({} if quant == "none" else
-          dict(k_scale=tree[2], v_scale=tree[3], kv_quant=quant))
     ck, cv = pool.gather(tree, table)
     if step == "decode":
         positions = jnp.asarray([11, 7, 0, 16], jnp.int32)
@@ -1114,8 +1112,8 @@ def test_paged_step_writes_the_pool_in_place(family, quant, step):
         ref_logits, _, (kt, vt) = G.decode_step_slots(
             model, params, tokens, (ck, cv), positions)
         ref = pool.write_token(tree, table, positions, kt, vt)
-        logits, *new = G.decode_step_paged(
-            model, params, tokens, tree[0], tree[1], table, positions, **kw)
+        logits, new = G.decode_step_paged(
+            model, params, tokens, tree, table, positions)
     else:
         # slot 0's block crosses into its second page; the inactive
         # slot's runs past the table's reach (positions 30, 31 | 32)
@@ -1126,8 +1124,8 @@ def test_paged_step_writes_the_pool_in_place(family, quant, step):
         ref_logits, _, (kt, vt) = G.verify_step_slots(
             model, params, tokens, (ck, cv), positions)
         ref = pool.write_tokens(tree, table, jnp.asarray(pos_grid), kt, vt)
-        logits, *new = G.verify_step_paged(
-            model, params, tokens, tree[0], tree[1], table, positions, **kw)
+        logits, new = G.verify_step_paged(
+            model, params, tokens, tree, table, positions)
     assert len(new) == len(tree)
     new, ref, old = ([np.asarray(x) for x in t] for t in (new, ref, tree))
 

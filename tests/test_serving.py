@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from hetu_tpu import serving
+from hetu_tpu.models.cache_contract import KVAttention
 from hetu_tpu.models.generation import generate, prefill, decode_step
 from hetu_tpu.models.llama import LlamaConfig, LlamaLMHeadModel
 from hetu_tpu.obs.metrics import MetricsRegistry
@@ -342,30 +343,224 @@ def test_page_reservation_gates_admission():
     sched.check_invariants()
 
 
+class _HooksOnlyFamily:
+    """A K/V family that lives OUTSIDE the package, defined by the hooks
+    `models/generation.py` lists and nothing else (no Module, no edit
+    under hetu_tpu/): pre-norm blocks of rotary multi-query-group
+    attention and a GELU MLP, the first layer with arrays of its own and
+    the other two STACKED, so `ServingEngine` both calls and scans it.
+    How a query attends the cached K/V is the package's `KVAttention`,
+    as for llama and gpt; its own dense `forward` uses none of it."""
+
+    STATS = ()
+
+    class Attn(KVAttention):
+        def __init__(self, config):
+            self.config = config
+
+        def project(self, p, hn, rope, pos_ids):
+            from hetu_tpu import ops
+            c, (b, s, _) = self.config, hn.shape
+            q = (hn @ p["wq"]).reshape(b, s, c.num_attention_heads,
+                                       c.head_dim)
+            k = (hn @ p["wk"]).reshape(b, s, c.num_key_value_heads,
+                                       c.head_dim)
+            v = (hn @ p["wv"]).reshape(k.shape)
+            return (ops.apply_rotary(q, *rope, pos_ids),
+                    (ops.apply_rotary(k, *rope, pos_ids), v))
+
+        def output(self, p, attn):
+            return attn @ p["wo"]
+
+    class Block:
+        def __init__(self, config):
+            self.attn = _HooksOnlyFamily.Attn(config)
+
+        @staticmethod
+        def input_norm(p, x):
+            return x * jax.lax.rsqrt(
+                jnp.mean(x * x, -1, keepdims=True) + 1e-6) * p
+        post_norm = input_norm
+
+        def mlp_stats(self, p, x):
+            return jax.nn.gelu(x @ p["up"]) @ p["down"], None
+
+    def __init__(self, head_dim=16):
+        import types
+        self.config = c = types.SimpleNamespace(
+            vocab_size=256, hidden_size=2 * head_dim * 2,
+            num_hidden_layers=3, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=head_dim,
+            max_position_embeddings=256, compute_dtype=jnp.float32,
+            use_flash_attention=False)
+        self.block = self.Block(c)
+
+    def init(self, key):
+        c = self.config
+        h, kv = c.hidden_size, c.num_key_value_heads * c.head_dim
+        shapes = {"input_norm": (h,), "post_norm": (h,),
+                  "attn": {"wq": (h, h), "wk": (h, kv), "wv": (h, kv),
+                           "wo": (h, h)},
+                  "mlp": {"up": (h, 2 * h), "down": (2 * h, h)}}
+        keys = iter(jax.random.split(key, 64))
+
+        def make(shape, lead=()):
+            if len(shape) == 1:
+                return jnp.ones(lead + shape, jnp.float32)
+            return 0.05 * jax.random.normal(next(keys), lead + shape)
+
+        def layer(lead=()):
+            return jax.tree.map(lambda sh: make(sh, lead), shapes,
+                                is_leaf=lambda x: isinstance(x, tuple))
+        return {"embed": make((c.vocab_size, h)), "first": layer(),
+                "stack": layer((2,)), "final": make((h,)),
+                "head": make((h, c.vocab_size))}
+
+    # -- the hooks ---------------------------------------------------------
+    def cache_contract(self):
+        from hetu_tpu.models.cache_contract import kv_contract
+        c = self.config
+        return kv_contract(c.num_hidden_layers, c.num_key_value_heads,
+                           c.head_dim, c.compute_dtype)
+
+    def embed_tokens(self, params, ids, pos_ids):
+        return params["embed"][ids]
+
+    def rope_tables(self, max_len):
+        from hetu_tpu import ops
+        return ops.build_rope_cache(max_len, self.config.head_dim, 1e4)
+
+    def serving_layers(self, params):
+        return [(self.block, params["first"], None),
+                (self.block, params["stack"], 2)]
+
+    def final_hidden(self, params, x):
+        return self.Block.input_norm(params["final"], x)
+
+    def logits(self, params, hidden):
+        return hidden @ params["head"]
+
+    def lm_head_weight(self, params):
+        return params["head"]
+
+    # -- its own dense forward: no cache, no hook of attention -------------
+    def forward(self, params, ids):
+        """Logits [s, vocab] of ONE sequence ids [s]."""
+        c, s = self.config, ids.shape[0]
+        g = c.num_attention_heads // c.num_key_value_heads
+        rope = self.rope_tables(s)
+        pos = jnp.arange(s, dtype=jnp.int32)[None]
+        x = params["embed"][ids][None]
+        layers = [params["first"]] + [
+            jax.tree.map(lambda a: a[i], params["stack"]) for i in range(2)]
+        for lp in layers:
+            q, (k, v) = self.block.attn.project(
+                lp["attn"], self.Block.input_norm(lp["input_norm"], x),
+                rope, pos)
+            k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
+            sc = jnp.einsum("bqnd,bknd->bnqk", q, k) * c.head_dim ** -0.5
+            sc = jnp.where(jnp.tril(jnp.ones((s, s), bool)), sc, -jnp.inf)
+            a = jnp.einsum("bnqk,bknd->bqnd", jax.nn.softmax(sc, -1), v)
+            x = x + a.reshape(1, s, -1) @ lp["attn"]["wo"]
+            x = x + self.block.mlp_stats(
+                lp["mlp"], self.Block.input_norm(lp["post_norm"], x))[0]
+        return self.logits(params, self.final_hidden(params, x))[0]
+
+
+def _family_case(family, hd128):
+    """(model, params, dense: (params, ids [s]) -> logits [s, vocab])."""
+    if family == "hooks":
+        model = _HooksOnlyFamily(128 if hd128 else 16)
+        return model, model.init(jax.random.key(3)), model.forward
+    if family == "kimi":
+        from test_kimi_k2 import build, ref_logits
+        cfg, model, params = build()
+        return model, params, lambda p, ids: ref_logits(p, cfg, ids)
+    if family == "gpt":
+        from hetu_tpu.models.gpt import GPTConfig, GPTLMHeadModel
+        kw = dict(hidden_size=256, num_attention_heads=2) if hd128 else {}
+        model = GPTLMHeadModel(GPTConfig.tiny(
+            remat=False, compute_dtype=jnp.float32, **kw))
+    else:
+        kw = dict(hidden_size=256, num_attention_heads=2,
+                  num_key_value_heads=2) if hd128 else {}
+        model = LlamaLMHeadModel(LlamaConfig.tiny(
+            remat=False, compute_dtype=jnp.float32,
+            use_flash_attention=False, use_scan=family != "llama-unstacked",
+            **kw))
+    return (model, model.init(jax.random.key(1)),
+            lambda p, ids: model(p, ids[None])[0])
+
+
 # -------------------------------------------------------------- engine
-def test_continuous_batching_matches_generate(tiny_llama):
-    """Golden: staggered continuous batching emits token-identical greedy
-    output to per-request sequential generate() — including prompts that
-    take the multi-chunk prefill path."""
-    model, params = tiny_llama
-    arrivals = serving.poisson_arrivals(6, 40.0, seed=2)
-    reqs = serving.synthetic_requests(6, vocab_size=256, prompt_lens=(3, 20),
-                                      max_new=(2, 8), arrivals=arrivals,
-                                      seed=1)
-    assert any(r.prompt_len > 8 for r in reqs), "no chunked-prefill case"
-    eng = _engine(model, params, num_slots=3)
-    results = eng.run(reqs)
-    assert len(results) == len(reqs)
-    for res in results:
-        req = reqs[res.rid]
-        gold = generate(model, params, jnp.asarray(req.prompt[None]),
-                        max_new_tokens=req.max_new_tokens)
-        gold_toks = list(np.asarray(gold)[0, req.prompt_len:])
-        assert res.tokens == gold_toks[: len(res.tokens)], \
-            f"request {res.rid} diverged"
-        assert len(res.tokens) == req.max_new_tokens
+@pytest.mark.parametrize("family,route", [
+    ("hooks", "gather"), ("hooks", "paged"),
+    ("llama", "gather"), ("llama", "paged"), ("llama-unstacked", "gather"),
+    ("gpt", "gather"), ("gpt", "paged"),
+    ("kimi", "xla"), ("kimi", "kernel")])
+def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
+    """Golden, ONE body for every family: staggered continuous batching
+    through the normal path (`run`: scheduler, allocator, page tables,
+    chunked prefill — prompts straddle a page and a chunk — page write,
+    decode) emits, token for token, the argmax of the model's own dense
+    forward given the stream's own prefix (to 2e-4 of logit, what
+    float32 attention in another order may move a near tie by); llama
+    and gpt also `generate()`'s tokens exactly.
+
+    The K/V families over both decode routes the engine has for them:
+    `gather` (dense views of the pages, `decode_step_slots`) and `paged`
+    (the Pallas kernel, interpret mode, walks the page tables:
+    `decode_step_paged`); kimi over its two attentions under the one
+    paged program.  `hooks` is a K/V family defined HERE, outside the
+    package, by the hooks alone: adding an architecture is new files
+    only.  `llama-unstacked` is the Llama block built with
+    use_scan=False: a layer's own arrays, called, never scanned."""
+    monkeypatch.setenv("HETU_TPU_PALLAS",
+                       "1" if route in ("paged", "kernel") else "0")
+    if route == "kernel":
+        monkeypatch.setenv("HETU_TPU_PALLAS_KERNELS", "paged_latent")
+    if route == "paged":
+        monkeypatch.setenv("HETU_TPU_PALLAS_KERNELS", "paged_attn")
+    model, params, dense = _family_case(family, hd128=route == "paged")
+    vocab = model.config.vocab_size
+    rng = np.random.default_rng(1)
+    reqs = [Request(rid=i, prompt=rng.integers(0, vocab, size=n)
+                    .astype(np.int32), max_new_tokens=m, arrival_t=t)
+            for i, (n, m, t) in enumerate(
+                [(5, 6, 0.0), (16, 4, 0.0), (23, 6, 0.02), (40, 5, 0.05),
+                 (17, 6, 0.07), (9, 3, 0.3)])]
+    reg = MetricsRegistry()
+    eng = _engine(model, params, registry=reg, num_slots=4, page_size=8,
+                  max_len=128, prefill_chunk=16, num_pages=64)
+    assert eng.decode_paged == (route != "gather")
+    results = {r.rid: r for r in eng.run(reqs)}
+    if family == "kimi":
+        assert eng.kernel_routes["paged_latent"][
+            "pallas" if route == "kernel" else "xla"]
+    assert sorted(results) == list(range(len(reqs)))
+    for req in reqs:
+        toks = np.asarray(results[req.rid].tokens)
+        assert len(toks) == req.max_new_tokens
+        lg = np.asarray(dense(params, jnp.asarray(
+            np.concatenate([req.prompt, toks[:-1]]))))[req.prompt_len - 1:]
+        gap = lg.max(-1) - lg[np.arange(len(toks)), toks]
+        assert (gap <= 2e-4).all(), (req.rid, gap)
+        if family != "kimi" and route == "gather" and family != "hooks":
+            gold = generate(model, params, jnp.asarray(req.prompt[None]),
+                            max_new_tokens=req.max_new_tokens)
+            assert list(toks) == list(np.asarray(gold)[0, req.prompt_len:])
     eng.scheduler.check_invariants()
     assert eng.pool.free_count == eng.pool.num_pages
+    assert reg.counter_value("serve.decode_context_tokens") > 0
+    if model.STATS:
+        # the programs' stats came back with the tokens
+        n = {k[len("serve.moe_"):]: reg.counter_value(k)
+             for k, _ in model.STATS}
+        assert n["extra_row_blocks"] >= 0
+        assert n["layer_steps"] > 0 \
+            and n["assignments"] > n["local_assignments"]
+        assert n["expert_hits"] <= 4 * n["layer_steps"]
+        assert 0 < n["max_expert_load"] <= 16 * 4
 
 
 def test_chunked_prefill_interleaves_with_decode(tiny_llama):
@@ -467,21 +662,6 @@ def test_no_cross_sequence_leakage(tiny_llama):
         solo = solo_eng.run([Request(rid=req.rid, prompt=req.prompt,
                                      max_new_tokens=req.max_new_tokens)])
         assert batch[i].tokens == solo[0].tokens
-
-
-def test_gpt_family_through_engine():
-    """The engine's family dispatch covers GPT (wpe positions, biased
-    fused QKV) — tokens match sequential generate()."""
-    from hetu_tpu.models.gpt import GPTConfig, GPTLMHeadModel
-    cfg = GPTConfig.tiny(remat=False, compute_dtype=jnp.float32)
-    model = GPTLMHeadModel(cfg)
-    params = model.init(jax.random.key(1))
-    prompt = np.random.default_rng(5).integers(0, 256, 10).astype(np.int32)
-    eng = _engine(model, params, num_slots=2, prefill_chunk=4)
-    res = eng.run([Request(rid=0, prompt=prompt, max_new_tokens=5)])
-    gold = generate(model, params, jnp.asarray(prompt[None]),
-                    max_new_tokens=5)
-    assert res[0].tokens == list(np.asarray(gold)[0, 10:])
 
 
 def test_reshard_hook_fires_on_load(tiny_llama):

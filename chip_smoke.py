@@ -250,19 +250,23 @@ def serve_layers(cfg, serve_cfg, n_requests: int, bytes_limit: int) -> dict:
     compiled programs show (AOT compile for the described chip, PR 21):
     the decode program updates the donated pool in place (PR 25: it held
     it twice while the pool was an xs -> ys of the layer scan), every
-    prefilling request holds one dense [max_len] scratch cache next to
-    the template, and ~1 GiB goes to the programs' other temporaries.
-    15% of the device is left free."""
+    prefilling request holds one dense [max_len] scratch cache of its
+    own, the engine holds the fused qkv and gate/up weights a second
+    time in its serving layout while this script keeps the training
+    tree for its references (`LlamaLMHeadModel.serving_params`, PR 32),
+    and ~1 GiB goes to the programs' other temporaries.  15% of the
+    device is left free."""
     c, s = cfg, serve_cfg
     item = np.dtype(c.compute_dtype).itemsize
     kv = c.num_key_value_heads * c.head_dim
-    per_layer_params = (c.hidden_size * (c.hidden_size + 2 * kv)
-                        + c.hidden_size * c.hidden_size
-                        + 3 * c.hidden_size * c.intermediate_size
+    fused = (c.hidden_size * (c.hidden_size + 2 * kv)
+             + 2 * c.hidden_size * c.intermediate_size)
+    per_layer_params = (2 * fused + c.hidden_size * c.hidden_size
+                        + c.hidden_size * c.intermediate_size
                         + 2 * c.hidden_size) * np.dtype(c.param_dtype).itemsize
     pool = 2 * (s.num_pages + 1) * s.page_size * kv * item
     scratch = 2 * s.max_len * kv * item
-    per_layer = per_layer_params + pool + (1 + n_requests) * scratch
+    per_layer = per_layer_params + pool + n_requests * scratch
     fixed = (2 * c.vocab_size * c.hidden_size
              * np.dtype(c.param_dtype).itemsize) + (1 << 30)
     layers = int((0.85 * bytes_limit - fixed) // per_layer)

@@ -13,6 +13,13 @@ it are the model's hooks:
 
   embed_tokens(params, ids, pos_ids)   -> x [b, s, hidden]
   rope_tables(max_len)                 -> whatever its `project` takes
+  serving_params(params)               -> params     (OPTIONAL)
+      the parameters as these programs read them, where serving wants
+      another layout than training: llama's fused qkv and gate/up
+      weights as matrices a product takes where they lie in the layers'
+      stack (`_walk_layers` says why).  An engine calls it once, at its
+      build, and hands the programs the result; a family without it is
+      served with its parameters as they come, by the same programs.
   serving_layers(params)               -> runs (block, parameters, count)
       in the cache's layer order.  count = n: the parameters are n
       layers' STACKED [n, ...], and the run is scanned; count None: one
@@ -165,37 +172,41 @@ def _layer(block, lp, h, rope, pos_ids, cache_step):
     return (h, st, *rest)
 
 
-def _walk_layers(model, params, x, state, stats, layer, *, sliced=False):
+def _walk_layers(model, params, x, state, stats, layer):
     """THE walk over `model.serving_layers(params)`, in the cache's layer
     order.  A run of stacked parameters [n, ...] is scanned, a layer with
-    arrays of its own is called: a scan over stacked weights slices each
-    layer's out of the stack at every execution (26% of the chat decode
-    program: PERF.md s5), which a family avoids by giving every layer
-    its own arrays (models/kimi_k2: 1.35 GB a layer a step), at the price
-    of a program that grows with the depth.  The model's parameters say
-    which; nothing else does.
+    arrays of its own is called.  The walk moves no bytes a layer does
+    not read, of the weights or of the cache:
+
+    * the weights.  A scanned layer's are the scan's xs, and a product
+      takes its matrix out of the stack [n, h, w] inside its own fusion:
+      a stacked run of plain matrices costs the scan's index arithmetic
+      (0.008 ms of the 5.94 ms InternLM2 decode program: PERF.md s5).
+      A fused weight in the training layout (`[.., 2, I]`, `[.., n_kv,
+      g + 2, hd]`) is instead copied out whole, every layer of every
+      execution, before the product reads it again (2.82 ms of that
+      program before PR 32), which is why a model may hand the serving
+      programs a view of its own (`serving_params`).  A family whose
+      layers differ gives each its own arrays (models/kimi_k2), at the
+      price of a program that grows with the depth.  The model's
+      parameters say which; nothing else does.
+    * the cache.  `state`, arrays whose leading dim is the layer (a
+      paged pool, a dense cache [L, b, M, ...]), is handed to every
+      layer WHOLE with the layer's index, `at` = (l,), and handed on,
+      updated in place: `c[at]` reads and `c.at[at + (...)]` writes the
+      layer's part.  In a scan it is a loop CARRY, never an xs or a ys,
+      which would slice every layer's slab out and stack it back into a
+      fresh buffer (three full-pool copies a step: PERF.md s6, PR 25;
+      the chunk program's dense scratch read and written whole to
+      change one chunk: PR 32).  A caller that donates it gets its own
+      buffers back.
 
     layer(block, lp, h, state, at) -> (h, stats of the layer, state,
-    out).  `state` is the cache, arrays whose leading dim is the layer:
-
-    * as it comes (sliced=False: a paged pool): every layer is handed
-      ALL of it with its index, `at` = (l,), and hands it on, updated
-      in place.
-      In a scan it is a loop CARRY — never an xs or a ys, which would
-      slice every layer's slab out and stack it back into a fresh buffer
-      (three full-pool copies a step: PERF.md s6, PR 25);
-    * sliced=True (a dense cache [L, b, M, ...]): a scanned run's layer
-      is handed its OWN slice and hands back the new one, xs -> ys,
-      `at` = (); a called layer is handed all of it and `at` = (l,),
-      the index of its slice.  `c[at]` reads and `c.at[at + (...)]`
-      writes the layer's part either way (`_at`).
-
-    `out` is whatever a layer hands out besides (a token's entries for a
-    paged pool to scatter; None): stacked over the layers.
+    out).  `out` is whatever a layer hands out besides (a token's
+    entries for a paged pool to scatter; None): stacked over the layers.
     Returns (x, stats, state, out).  The caller opens the `layer` scope
     (a trace's name for the stack: obs.scope_map) around the walk and
     what it does to the state before and after."""
-    num_layers = cache_contract(model).num_layers
     outs, l0 = [], 0
 
     def add(stats, st):
@@ -207,7 +218,7 @@ def _walk_layers(model, params, x, state, stats, layer, *, sliced=False):
                                       (jnp.int32(l0),))
             stats = add(stats, st)
             out = jax.tree.map(lambda a: a[None], out)
-        elif not sliced:
+        else:
             def body(carry, xs, block=block):
                 h, state, stats = carry
                 lp, l = xs
@@ -217,32 +228,11 @@ def _walk_layers(model, params, x, state, stats, layer, *, sliced=False):
             (x, state, stats), out = lax.scan(
                 body, (x, state, stats),
                 (lp, jnp.arange(l0, l0 + count, dtype=jnp.int32)))
-        else:
-            def body(carry, xs, block=block):
-                h, stats = carry
-                lp, own = xs
-                h, st, own, out = layer(block, lp, h, own, ())
-                return (h, add(stats, st)), (own, out)
-
-            whole = count == num_layers
-            own = state if whole else jax.tree.map(
-                lambda c: c[l0:l0 + count], state)
-            (x, stats), (own, out) = lax.scan(body, (x, stats),
-                                              (lp, own))
-            state = own if whole else jax.tree.map(
-                lambda c, n: lax.dynamic_update_slice_in_dim(
-                    c, n, l0, 0), state, own)
         outs.append(out)
         l0 += count or 1
     out = outs[0] if len(outs) == 1 else jax.tree.map(
         lambda *a: jnp.concatenate(a), *outs)
     return x, stats, state, out
-
-
-def _at(c, at):
-    """A layer's part of a cache array: `c` itself where the walk handed
-    the layer its own slice (at = ()), c[l] where it handed it all."""
-    return c[at] if at else c
 
 
 # ---------------------------------------------------------------------------
@@ -275,16 +265,16 @@ def prefill(model, params, input_ids, max_len: int):
                          for e in entries)
 
 
-def _cache_write_token(c, e, positions, uniform: bool, at=()):
-    """Write one token's entry e [b, 1, ...] into the layer's part of a
-    cache array ([b, M, ...] at `at`) at `positions`.  Uniform (scalar)
+def _cache_write_token(c, e, positions, uniform: bool, at):
+    """Write one token's entry e [b, 1, ...] into layer `at` = (l,) of a
+    cache array [L, b, M, ...] at `positions`.  Uniform (scalar)
     positions keep the contiguous dynamic_update_slice lowering — the
     generate() hot loop must not pay batched-scatter cost for a
     broadcast index — per-slot vectors scatter per row (the serving
     form)."""
     if uniform:
         return lax.dynamic_update_slice(
-            c, e.astype(c.dtype).reshape((1,) * len(at) + e.shape),
+            c, e.astype(c.dtype)[None],
             at + (0, positions) + (0,) * (e.ndim - 2))
     return c.at[at + (jnp.arange(e.shape[0]), positions)].set(
         e[:, 0].astype(c.dtype))
@@ -314,14 +304,14 @@ def decode_step_slots(model, params, tokens, cache, positions):
         def step(attn, p, q, entries):
             new = tuple(_cache_write_token(c, e, positions, uniform, at)
                         for c, e in zip(cache, entries))
-            return (attn.attend_dense(p, q, tuple(_at(c, at) for c in new),
+            return (attn.attend_dense(p, q, tuple(c[at] for c in new),
                                       positions),
                     new, tuple(e[:, 0] for e in entries))
         return _layer(block, lp, h, rope, pos_ids, step)
 
     with jax.named_scope("layer"):
         x, _, cache, toks = _walk_layers(model, params, x, tuple(cache),
-                                         None, layer, sliced=True)
+                                         None, layer)
     logits = model.logits(params, model.final_hidden(params, x))[:, 0, :]
     return logits, cache, toks
 
@@ -341,7 +331,9 @@ def extend_cache(model, params, tokens, cache, start, stats=None, *,
     """Advance a dense cache by a whole token block (chunked prefill).
 
     tokens: [b, C] int32 at absolute positions start..start+C-1 (start
-    scalar or [b]); the chunk's entries are written into the cache and
+    scalar or [b]); the chunk's entries are written into the cache, in
+    place where the caller donates it (the cache is a carry of the layer
+    walk: a chunk changes C tokens a layer and moves no others), and
     each query attends causally over cache[:start+i+1].  Returns
     (logits [b, C, vocab], new_cache).  Running consecutive chunks
     through this is numerically the incremental form of `prefill` — the
@@ -369,14 +361,14 @@ def extend_cache(model, params, tokens, cache, start, stats=None, *,
                 new = tuple(
                     c.at[at + (rows[:, None], qpos)].set(e.astype(c.dtype))
                     for c, e in zip(cache, entries))
-            return (attn.attend_dense(p, q, tuple(_at(c, at) for c in new),
+            return (attn.attend_dense(p, q, tuple(c[at] for c in new),
                                       start),
                     new, entries if collect_token_kv else None)
         return _layer(block, lp, h, rope, qpos, step)
 
     with jax.named_scope("layer"):
         x, stats, cache, chunk = _walk_layers(
-            model, params, x, tuple(cache), stats, layer, sliced=True)
+            model, params, x, tuple(cache), stats, layer)
     logits = model.logits(params, model.final_hidden(params, x))
     return ((logits, cache) + ((chunk,) if collect_token_kv else ())
             + (() if stats is None else (stats,)))

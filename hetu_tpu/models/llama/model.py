@@ -121,16 +121,34 @@ class LlamaAttention(Module, KVAttention):
     def project(self, params, hn, rope, pos_ids):
         """hn [b, s, h] (normed) at positions pos_ids [b, s] ->
         (q [b, s, n_q, hd], entries (k, v) [b, s, n_kv, hd]), q and k
-        rotated: the fused projection of `forward`."""
+        rotated: the fused projection of `forward`.  `wqkv` as
+        `serving_view` relaid it, a matrix [h, q heads | k heads | v
+        heads], is multiplied where it lies in the layers' stack, and
+        q, k, v are cut out of the product's OUTPUT."""
         b, s, _ = hn.shape
-        qkv = jnp.einsum("bsh,hkgd->bskgd", hn,
-                         params["wqkv"].astype(hn.dtype))
-        q = qkv[..., : self.group, :].reshape(b, s, self.n_q,
-                                              self.config.head_dim)
-        k = qkv[..., self.group, :]
-        v = qkv[..., self.group + 1, :]
+        hd, w = self.config.head_dim, params["wqkv"].astype(hn.dtype)
+        if w.ndim == 2:
+            qkv = hn @ w
+            q, k, v = (a.reshape(b, s, -1, hd) for a in jnp.split(
+                qkv, [self.n_q * hd, (self.n_q + self.n_kv) * hd], axis=-1))
+        else:
+            qkv = jnp.einsum("bsh,hkgd->bskgd", hn, w)
+            q = qkv[..., : self.group, :].reshape(b, s, self.n_q, hd)
+            k = qkv[..., self.group, :]
+            v = qkv[..., self.group + 1, :]
         q, k = ops.apply_rotary_qk(q, k, *rope, pos_ids)
         return q, (k, v)
+
+    def serving_view(self, wqkv):
+        """`wqkv` [..., h, n_kv, g + 2, hd] as the matrix `project`
+        multiplies in place, [..., h, (n_q + 2 n_kv) * hd]: the same
+        numbers with the columns regrouped q heads | k heads | v heads,
+        q head j staying with kv head j // g."""
+        lead, g = wqkv.shape[:-3], self.group
+        return jnp.concatenate(
+            [wqkv[..., :g, :].reshape(*lead, -1),
+             wqkv[..., g, :].reshape(*lead, -1),
+             wqkv[..., g + 1, :].reshape(*lead, -1)], axis=-1)
 
     def output(self, params, attn):
         return self.o_proj(params["o_proj"], attn)
@@ -160,6 +178,25 @@ class LlamaMLP(Module):
         hidden = ops.swiglu(gu[:, :, 0, :], gu[:, :, 1, :],
                             layout=st.act_inner())
         return self.down_proj(params["down_proj"], hidden)
+
+    # -- the serving programs' form (models/generation.py) ----------------
+    def serve(self, params, x):
+        """`forward`, reading `w_gate_up` as `serving_view` relaid it, a
+        matrix [h, gate columns | up columns] multiplied where it lies in
+        the layers' stack, gate and up cut out of the product's OUTPUT;
+        the training layout goes through `forward` itself."""
+        w = params["w_gate_up"].astype(x.dtype)
+        if w.ndim != 2:
+            return self.forward(params, x)
+        gate, up = jnp.split(x @ w, 2, axis=-1)
+        hidden = ops.swiglu(gate, up, layout=self.strategy.act_inner())
+        return self.down_proj(params["down_proj"], hidden)
+
+    @staticmethod
+    def serving_view(w_gate_up):
+        """`w_gate_up` [..., h, 2, I] as [..., h, 2 * I]: the gate's
+        columns, then the up projection's."""
+        return w_gate_up.reshape(*w_gate_up.shape[:-2], -1)
 
 
 class LlamaBlock(Module):
@@ -192,8 +229,9 @@ class LlamaBlock(Module):
     def mlp_stats(self, params, x):
         """(mlp(x), what the layer counts of itself: nothing) — the
         serving programs' form of the MLP, dense or routed."""
-        y = self.mlp(params, x)
-        return (y[0] if self.config.num_experts > 0 else y), None
+        if self.config.num_experts > 0:
+            return self.mlp(params, x)[0], None
+        return self.mlp.serve(params, x), None
 
     def forward(self, params, x, *, cos, sin, position_ids=None,
                 segment_ids=None, rng=None, deterministic=True,
@@ -457,6 +495,38 @@ class LlamaLMHeadModel(Module):
         c = self.config
         return ops.build_rope_cache(c.max_position_embeddings, c.head_dim,
                                     c.rope_theta)
+
+    def serving_params(self, params):
+        """The parameters as the serving programs read them: the two
+        fused weights of every layer as plain matrices
+        (`LlamaAttention.serving_view`, `LlamaMLP.serving_view`), every
+        other leaf the caller's own.  The training layouts ([.., n_kv,
+        g + 2, hd] for TP over the kv heads, [.., 2, I] for TP over I)
+        cannot be multiplied where they lie in a stack of layers: a
+        layer's 80 MB would be copied out at every layer of every
+        program (models/generation._walk_layers).  Relaid by ONE program
+        on the device; only for parameters that are whole on one device
+        (the view has no sharding specs: `ServingEngine` asks)."""
+        block = self.model.layers.block
+        views = {("attn", "wqkv"): block.attn.serving_view}
+        if self.config.num_experts == 0:
+            views[("mlp", "w_gate_up")] = block.mlp.serving_view
+        # {"layers": the stack} or, built with use_scan=False, a layer each
+        layers = params["model"]["layers"]
+        relaid = jax.jit(lambda fused: {
+            name: {at: views[at](w) for at, w in f.items()}
+            for name, f in fused.items()})(
+                {name: {at: lp[at[0]][at[1]] for at in views}
+                 for name, lp in layers.items()})
+
+        def with_views(lp, new):
+            lp = dict(lp)
+            for (part, leaf), w in new.items():
+                lp[part] = {**lp[part], leaf: w}
+            return lp
+        return {**params, "model": {**params["model"], "layers": {
+            name: with_views(lp, relaid[name])
+            for name, lp in layers.items()}}}
 
     def serving_layers(self, params):
         """Runs (block, parameters, count) in the cache's layer order:

@@ -61,7 +61,7 @@ import numpy as np
 
 from hetu_tpu.models.cache_contract import cache_contract
 from hetu_tpu.models.generation import extend_cache, init_cache
-from hetu_tpu.serving.engine import first_token_from_logits
+from hetu_tpu.serving.engine import first_token_from_logits, serving_view
 from hetu_tpu.serving.kv_pool import dequantize_heads, quantize_heads
 from hetu_tpu.serving.request import Request, RequestResult
 from hetu_tpu.utils.logging import get_logger
@@ -102,7 +102,9 @@ class PrefillWorker:
             raise ValueError(f"max_len {max_len} must be a multiple of "
                              f"prefill_chunk {prefill_chunk}")
         self.model = model
-        self.params = params
+        # the tier's own weights, as the engine's chunk program reads
+        # them (the model's serving view where it brings one)
+        self.params, _, _ = serving_view(model, params)
         self.prefill_chunk = prefill_chunk
         self.max_len = max_len
         self.num_slots = num_slots
@@ -114,12 +116,14 @@ class PrefillWorker:
                 f"{type(model).__name__} keeps a {kind!r} cache; the "
                 "disaggregated prefill tier (serving/disagg.py) ships K/V "
                 "scratch only and is not built for it")
-        self._scratch = init_cache(model, 1, max_len)
+        # as in the engine: every prefill advances zeros of its own in
+        # place (the chunk program donates the scratch)
+        self._fresh_scratch = jax.jit(lambda: init_cache(model, 1, max_len))
 
         def chunk_fn(params, chunk, cache, start):
             return extend_cache(model, params, chunk, cache, start)
 
-        self._chunk_jit = jax.jit(chunk_fn)
+        self._chunk_jit = jax.jit(chunk_fn, donate_argnums=(2,))
         self._queue: Deque[Tuple[Request, int]] = collections.deque()
         self._live: Dict[int, _Prefill] = {}
         self.chunks = 0
@@ -167,7 +171,7 @@ class PrefillWorker:
         while len(self._live) < self.num_slots and self._queue:
             req, attempt = self._queue.popleft()
             self._live[req.rid] = _Prefill(request=req,
-                                           cache=self._scratch,
+                                           cache=self._fresh_scratch(),
                                            attempt=attempt)
         out = []
         for rid in list(self._live.keys()):

@@ -280,6 +280,42 @@ class ServeConfig:
         return ServeConfig(**vals)
 
 
+def serving_view(model, params, reshard=None):
+    """(the parameters the serving programs read, the bytes relaid for
+    them, why).  A model may hold its fused weights for serving in
+    another layout than training's (`serving_params`, the optional hook
+    of models/generation.py): taken ONCE, at an engine's build, the
+    leaves it does not relay shared with the caller's tree, so that no
+    weight is held twice.  The view has no sharding specs: with a
+    `reshard` hook (which re-shards the engine's parameters by the
+    training specs) or a model built for tp > 1 (whose layers constrain
+    by them), as for a family without the hook, the parameters are
+    served as they came and the bytes are 0."""
+    hook = getattr(model, "serving_params", None)
+    tp = getattr(getattr(model, "strategy", None), "tp", 1)
+    if hook is None:
+        why = "the model brings no serving_params hook"
+    elif reshard is not None:
+        why = "the reshard hook re-shards by the training specs"
+    elif tp > 1:
+        why = f"tp = {tp}: the layers constrain by the training specs"
+    else:
+        own = dict(jax.tree.leaves_with_path(params))
+        if any(isinstance(a, jax.ShapeDtypeStruct) for a in own.values()):
+            # abstract parameters (a compile for a described chip) give
+            # an abstract view: a leaf is the caller's where its shape is
+            view = jax.eval_shape(hook, params)
+            same = lambda a, b: b is not None and a.shape == b.shape  # noqa: E731
+        else:
+            view, same = hook(params), lambda a, b: a is b
+        relaid = [a for path, a in jax.tree.leaves_with_path(view)
+                  if not same(a, own.get(path))]
+        return (view, sum(a.size * a.dtype.itemsize for a in relaid),
+                f"{len(relaid)} fused weights held as matrices a product "
+                f"takes in place ({type(model).__name__}.serving_params)")
+    return params, 0, "served as they came: " + why
+
+
 class ServingEngine:
     """Continuous-batching facade over (model, params)."""
 
@@ -290,7 +326,6 @@ class ServingEngine:
                  telemetry=None, drafter=None, draft_model=None,
                  draft_params=None, cost_model=None):
         self.model = model
-        self.params = params
         self.config = config or ServeConfig.from_flags()
         c = model.config
         _check_context_length(c, self.config.max_len)
@@ -371,6 +406,19 @@ class ServingEngine:
         self.slowest_step: Optional[dict] = None
         self.reshard = reshard
         self._registry = registry if registry is not None else get_registry()
+        #: the parameters as the programs read them: the model's serving
+        #: view (`serving_params`) where it brings one, else the caller's
+        self.params, self.relaid_weight_bytes, why = serving_view(
+            model, params, reshard)
+        self._registry.set_gauge("serve.relaid_weight_bytes",
+                                 self.relaid_weight_bytes)
+        # every kernel routing decision of this engine — the static ones
+        # _build_programs takes, then each program's as it is traced —
+        # with its reason (ops/pallas.record_routes), beside what was
+        # relaid for the programs and why
+        self.kernel_routes: dict = {
+            "relaid_weight_bytes": self.relaid_weight_bytes,
+            "relaid_weight_why": why}
         if run_log is None:
             path = default_runlog_path(None)
             run_log = RunLog(path) if path else None
@@ -434,7 +482,7 @@ class ServingEngine:
                                                   quantize_expert_tree)
             bits = 8 if self.config.moe_dispatch == "int8" else 4
             self.params, self._moe_spec = quantize_expert_tree(
-                params, n_exp, bits=bits)
+                self.params, n_exp, bits=bits)
             eb = expert_bytes(self._moe_spec)
             self._registry.set_gauge("serve.moe_expert_bytes",
                                      eb["quantized_bytes"])
@@ -442,9 +490,11 @@ class ServingEngine:
                                      eb["fp_bytes"])
 
         # per-request prefill scratch: a dense [L, 1, max_len] cache the
-        # chunk program advances, one array per array of the contract;
-        # template zeros reused (functionally) for every admission
-        self._scratch = init_cache(model, 1, self.config.max_len)
+        # chunk program advances IN PLACE (it is donated), one array per
+        # array of the contract; every admission is handed zeros of its
+        # own, so no call can consume another's buffer
+        self._fresh_scratch = jax.jit(
+            functools.partial(init_cache, model, 1, self.config.max_len))
         from hetu_tpu.serving.kv_pool import contract_bytes_per_token
         mode = (self.config.kv_quant if self.config.kv_quant != "none" else
                 {2: "bf16", 4: "fp32"}[jnp.dtype(c.compute_dtype).itemsize])
@@ -457,10 +507,6 @@ class ServingEngine:
         #: STATS is empty: its programs carry none
         self._stats_zero = model.zero_stats() if model.STATS else None
         self._stats_acc = self._stats_zero
-        # every kernel routing decision of this engine — the static ones
-        # _build_programs takes, then each program's as it is traced —
-        # with its reason (ops/pallas.record_routes)
-        self.kernel_routes: dict = {}
         with record_routes(self.kernel_routes):
             self._build_programs()
 
@@ -768,7 +814,12 @@ class ServingEngine:
         else:
             self._decode_jit = jax.jit(rec(decode_fn), donate_argnums=(1,))
             self._verify_jit = None
-        self._chunk_jit = jax.jit(rec(chunk_fn))
+        # the scratch is donated too, and a carry of the chunk program's
+        # layer walk: a launch changes one chunk's tokens a layer where
+        # they lie (as an xs -> ys it read and wrote all 201 MB of the
+        # InternLM2 cells' scratch; donated but not carried, or carried
+        # but not donated, a copy stayed: PERF.md s6, PR 30)
+        self._chunk_jit = jax.jit(rec(chunk_fn), donate_argnums=(2,))
         self._write_jit = jax.jit(rec(write_fn), donate_argnums=(0,))
         self._prime_jit = (jax.jit(rec(prime_fn))
                            if self.prefix_cache is not None else None)
@@ -835,10 +886,10 @@ class ServingEngine:
         stats = self._stats_args()
         if program == "prefill_chunk":
             return (self.params, jnp.zeros((1, C), jnp.int32),
-                    self._scratch, jnp.int32(0), *stats)
+                    self._fresh_scratch(), jnp.int32(0), *stats)
         if program == "write_pages":
             return (self.pool.arrays.tree(), jnp.zeros(max_pages, jnp.int32),
-                    *(a[:, 0] for a in self._scratch))
+                    *(a[:, 0] for a in self._fresh_scratch()))
         table = jnp.zeros((S, max_pages), jnp.int32)
         pos = jnp.zeros(S, jnp.int32)
         sample_args = self._sample_args([]) if self.config.sampling else ()
@@ -1628,7 +1679,7 @@ class ServingEngine:
             self._registry.inc("serve.prefix_shared_tokens",
                                value=st.shared_tokens)
         else:
-            st.prefill_cache = self._scratch
+            st.prefill_cache = self._fresh_scratch()
             if self.prefix_cache is not None:
                 self._registry.inc("serve.prefix_misses")
         if self.prefix_cache is not None:

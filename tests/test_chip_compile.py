@@ -425,3 +425,63 @@ def test_decode_program_updates_the_pool_in_place(kv_quant):
     assert not found, found
     # ... and the token IS written there: one in-place scatter per array
     assert scatters == len(pool), scatters
+
+
+def test_serving_programs_move_no_bytes_a_layer_does_not_read():
+    """The InternLM2 serving cells' decode and chunk programs (PR 32).
+
+    * The weights: a product takes its matrix out of the layers' stack
+      inside its own fusion.  In the training layout ([.., 2, I],
+      [.., n_kv, g + 2, hd]) the compiler staged a layer's 64 + 16 MB in
+      a `constant_dynamic-slice_fusion` of their own at every layer of
+      every execution (0.75 + 0.19 s of the chat trace's 4 s: ledger,
+      PR 31); over the model's serving view (`serving_params`) no
+      instruction outside a fusion (copy, dynamic-slice, or a fusion
+      named for one) yields a whole layer's weight.
+    * The scratch: the chunk program's dense cache [L, 1, max_len, ...]
+      is a carry of the layer walk and donated: aliased from argument to
+      result, and no second one among the temporaries."""
+    import re
+    from hetu_tpu.models.llama import LlamaConfig
+    cfg = LlamaConfig(vocab_size=92544, hidden_size=2048,
+                      intermediate_size=8192, num_hidden_layers=24,
+                      num_attention_heads=16, num_key_value_heads=8,
+                      max_position_embeddings=32768, rope_theta=1e6,
+                      param_dtype=BF16)
+    engine = _serving_engine(cfg, num_slots=32, num_pages=2048)
+    assert engine.relaid_weight_bytes == 2_013_265_920
+    assert engine.kernel_routes["relaid_weight_bytes"] == 2_013_265_920
+    programs = engine.lower_programs(sharding=ONE_CHIP)
+    compiled = {name: programs[name].compile()
+                for name in ("decode", "prefill_chunk")}
+
+    # one layer's part of every stacked matrix: [h, w], or [1, h, w]
+    layer = set()
+    for w in jax.tree.leaves(engine.params["model"]["layers"]):
+        if w.ndim >= 3:
+            dims = ",".join(map(str, w.shape[1:]))
+            layer |= {dims, "1," + dims}
+    moves = re.compile(r"copy|dynamic[-_]slice")
+    inst = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = \(?\w+\[([\d,]*)\]"
+                      r"[^ ]* ([\w\-]+)\(")
+    head = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{\s*$")
+    for name, c in compiled.items():
+        found, fused = [], False
+        for line in c.as_text().splitlines():
+            h = head.match(line)
+            if h:
+                fused = "fused_computation" in h.group(1)
+                continue
+            m = inst.match(line)
+            if m and not fused and m.group(2) in layer \
+                    and moves.search(m.group(1) + " " + m.group(3)):
+                found.append(f"{m.group(1)} [{m.group(2)}] {m.group(3)}")
+        assert not found, (name, found)
+
+    scratch = sum(a.size * a.dtype.itemsize
+                  for a in engine._fresh_scratch())
+    assert scratch == 201_326_592
+    mem = compiled["prefill_chunk"].memory_analysis()
+    assert mem.alias_size_in_bytes >= scratch
+    assert mem.temp_size_in_bytes < scratch / 4
+

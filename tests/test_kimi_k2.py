@@ -594,7 +594,8 @@ def test_kv_engines_pools_and_programs_are_as_they_were(make, n_kv, hd):
     eng = make()
     tree = eng.pool.arrays.tree()
     assert [a.shape for a in tree] == [(2, 17, 8, n_kv, hd)] * 2
-    assert [a.shape for a in eng._scratch] == [(2, 1, 64, n_kv, hd)] * 2
+    assert [a.shape for a in eng._fresh_scratch()] == [
+        (2, 1, 64, n_kv, hd)] * 2
     assert eng.cache.kind == "kv" and eng.model.STATS == ()
     assert eng._stats_acc is None
     assert len(eng._dummy_args("decode")) == 5

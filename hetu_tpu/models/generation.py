@@ -26,21 +26,30 @@ it are the model's hooks:
       layer's own arrays, and the layer is called.  `_walk_layers`, the
       one walk over the layers, chooses by that and by nothing else.
   block.input_norm / post_norm / mlp_stats(params, x) -> (y, stats)
-  block.attn.project(p, hn, rope, pos_ids) -> (q, entries)
+  block.window, block.attn_scope         (OPTIONAL)
+      how far back the layer's queries read, their own position counted
+      (None or absent: everything), as the cache contract's `windows`
+      says it per layer: `_layer` hands it to the three attend hooks as
+      `window=`, and `_walk_layers` finds the layer's KIND by it (layers
+      that read as far back share page arrays and a page table:
+      serving/kv_pool.py); and the scope the layer's attention runs
+      under inside `attn`, so that a trace tells the kinds apart
+  block.attn.project(p, hn, rope, pos_ids) -> (q, entries[, aux])
       HOW A TOKEN'S CACHE ENTRY IS MADE: one array per array of the
-      contract, [b, s, *stored shape]
+      contract, [b, s, *stored shape]; what it returns beyond that (a
+      gate on the attention's output) is handed to `output`
   block.attn.attend_paged(p, q, pools, table, positions, base)
   block.attn.attend_dense(p, q, caches, start)
   block.attn.attend_prompt(p, q, entries)
       HOW A QUERY ATTENDS IT: over pages, over a dense per-slot cache,
       and a whole prompt over its own entries (for the K/V kind all
       three are `cache_contract.KVAttention`)
-  block.attn.output(p, attn), final_hidden(params, x), logits(params, h),
-  lm_head_weight(params)
+  block.attn.output(p, attn[, aux]), final_hidden(params, x),
+  logits(params, h), lm_head_weight(params)
 
   STATS, zero_stats(), add_stats(a, b)
       `stats` is a small int32 vector a layer counts of itself (an
-      expert layer's assignments: models/kimi_k2.MOE_STATS); `STATS`
+      expert layer's assignments: nn/moe.MOE_STATS); `STATS`
       names each entry's counter and says whether executions add up or
       take the maximum, and is EMPTY for a model that counts nothing
       (llama, gpt: `mlp_stats` returns None for it, and the other two
@@ -61,6 +70,7 @@ hands it: where the entry is written and how the query attends
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Optional
 
 import jax
@@ -68,6 +78,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from hetu_tpu.models.cache_contract import cache_contract
+
+
+#: float32 score bytes `_attend_cached_chunk` computes at once; over
+#: this it goes KV head by KV head
+_SCORES_AT_ONCE = 256 << 20
 
 
 def _attend_cached(q, ck, cv, pos, scale):
@@ -91,26 +106,50 @@ def _attend_cached(q, ck, cv, pos, scale):
     return _attend_cached_chunk(q, ck, cv, pos, scale)
 
 
-def _attend_cached_chunk(q, ck, cv, start, scale):
+def _attend_cached_chunk(q, ck, cv, start, scale, window=None, first=0):
     """Multi-query cached attention for chunked prefill.  q: [b, C, nq,
     hd] sits at absolute positions start..start+C-1 (start scalar or
     [b]); key position k is visible to query i iff k <= start + i
     (causal within the chunk, full visibility of the already-cached
-    prefix).  Same grouped-GQA contraction as `_attend_cached`."""
+    prefix) and, under a `window`, k > start + i - window.  ck/cv hold
+    the positions first..first+M-1 (`first` = 0: the whole cache; a
+    window layer's chunk hands in the slice it reads:
+    cache_contract.KVAttention.attend_dense).  Same grouped-GQA
+    contraction as `_attend_cached`."""
     b, M, n_kv, hd = ck.shape
     C, nq = q.shape[1], q.shape[2]
     group = nq // n_kv
     qg = q.reshape(b, C, n_kv, group, hd)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
-                   ck.astype(jnp.float32)) * scale
     start = jnp.asarray(start)
     if start.ndim == 0:
         start = start[None]
     qpos = start[:, None] + jnp.arange(C)[None, :]            # [b, C]
-    mask = jnp.arange(M)[None, None, :] <= qpos[..., None]    # [b, C, M]
-    s = jnp.where(mask[:, None, None, :, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", p, cv.astype(jnp.float32))
+    kpos = jnp.arange(M)[None, None, :]
+    if not (isinstance(first, int) and first == 0):
+        kpos = kpos + first
+    mask = kpos <= qpos[..., None]                            # [b, C, M]
+    if window is not None:
+        mask = mask & (kpos > qpos[..., None] - window)
+
+    def attend(qg, ck, cv):
+        s = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
+                       ck.astype(jnp.float32)) * scale
+        s = jnp.where(mask[:, None, None, :, :], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("bhgqk,bkhd->bqhgd", p, cv.astype(jnp.float32))
+
+    if b * nq * C * M * 4 > _SCORES_AT_ONCE and n_kv > 1:
+        # one KV head's group of query heads at a time: the float32
+        # scores of all heads at once would be the program's largest
+        # temporary (0.54 GB, twice, for 32 heads x 512 x 8,192)
+        out = lax.map(
+            lambda x: attend(x[0][:, :, None], x[1][:, :, None],
+                             x[2][:, :, None])[:, :, 0],
+            (jnp.moveaxis(qg, 2, 0), jnp.moveaxis(ck, 2, 0),
+             jnp.moveaxis(cv, 2, 0)))
+        out = jnp.moveaxis(out, 0, 2)
+    else:
+        out = attend(qg, ck, cv)
     return out.reshape(b, C, nq, hd).astype(q.dtype)
 
 
@@ -156,15 +195,25 @@ def _layer(block, lp, h, rope, pos_ids, cache_step):
     residual, under the scopes a device trace is summed by
     (obs.scope_map: `attn`, `attn/kv_write`, `mlp`).
 
-    cache_step(attn module, its params, q, entries) -> (attention
+    cache_step(attn module, its params, q, entries, win) -> (attention
     output [b, s, n_q * hd], *rest) is what a program differs by: where
     the token's entries are written and how the query attends them.
+    `win` is the layer's window as the attend hooks take it: {} for a
+    layer that reads everything (`block.window` None or absent), else
+    {"window": w}; such a block names the scope its attention runs
+    under inside `attn` (`block.attn_scope`), so that a trace tells the
+    kinds of layer apart.  What `project` returns beyond (q, entries)
+    (a gate on the attention's output) is handed to `output`.
     Returns (h, the layer's stats, *rest)."""
-    with jax.named_scope("attn"):
+    window = getattr(block, "window", None)
+    win = {} if window is None else {"window": window}
+    scope = getattr(block, "attn_scope", None)
+    with jax.named_scope("attn"), \
+            (jax.named_scope(scope) if scope else contextlib.nullcontext()):
         hn = block.input_norm(lp["input_norm"], h)
-        q, entries = block.attn.project(lp["attn"], hn, rope, pos_ids)
-        attn, *rest = cache_step(block.attn, lp["attn"], q, entries)
-        h = h + block.attn.output(lp["attn"], attn)
+        q, entries, *aux = block.attn.project(lp["attn"], hn, rope, pos_ids)
+        attn, *rest = cache_step(block.attn, lp["attn"], q, entries, win)
+        h = h + block.attn.output(lp["attn"], attn, *aux)
     with jax.named_scope("mlp"):
         y, st = block.mlp_stats(lp["mlp"],
                                 block.post_norm(lp["post_norm"], h))
@@ -201,28 +250,40 @@ def _walk_layers(model, params, x, state, stats, layer):
       change one chunk: PR 32).  A caller that donates it gets its own
       buffers back.
 
-    layer(block, lp, h, state, at) -> (h, stats of the layer, state,
-    out).  `out` is whatever a layer hands out besides (a token's
+    layer(block, lp, h, state, at, page_at) -> (h, stats of the layer,
+    state, out).  `at` = (l,) is the layer among all layers (a dense
+    cache's leading dim); `page_at` = (kind, (j,)) is the layer's kind
+    by the cache contract (layers that read as far back are one kind,
+    with page arrays and a page table of their own) and its place j
+    among the layers of that kind (a paged pool's leading dim); with one
+    kind j is l.  `out` is whatever a layer hands out besides (a token's
     entries for a paged pool to scatter; None): stacked over the layers.
     Returns (x, stats, state, out).  The caller opens the `layer` scope
     (a trace's name for the stack: obs.scope_map) around the walk and
     what it does to the state before and after."""
     outs, l0 = [], 0
+    kinds = cache_contract(model).kinds
+    seen = [0] * len(kinds)         # layers walked so far, by kind
 
     def add(stats, st):
         return stats if stats is None else model.add_stats(stats, st)
 
     for block, lp, count in model.serving_layers(params):
+        kind = kinds.index(getattr(block, "window", None))
+        j0 = seen[kind]
         if count is None:
             x, st, state, out = layer(block, lp, x, state,
-                                      (jnp.int32(l0),))
+                                      (jnp.int32(l0),),
+                                      (kind, (jnp.int32(j0),)))
             stats = add(stats, st)
             out = jax.tree.map(lambda a: a[None], out)
         else:
-            def body(carry, xs, block=block):
+            def body(carry, xs, block=block, kind=kind, shift=j0 - l0):
                 h, state, stats = carry
                 lp, l = xs
-                h, st, state, out = layer(block, lp, h, state, (l,))
+                h, st, state, out = layer(
+                    block, lp, h, state, (l,),
+                    (kind, (l + shift if shift else l,)))
                 return (h, state, add(stats, st)), out
 
             (x, state, stats), out = lax.scan(
@@ -230,6 +291,7 @@ def _walk_layers(model, params, x, state, stats, layer):
                 (lp, jnp.arange(l0, l0 + count, dtype=jnp.int32)))
         outs.append(out)
         l0 += count or 1
+        seen[kind] += count or 1
     out = outs[0] if len(outs) == 1 else jax.tree.map(
         lambda *a: jnp.concatenate(a), *outs)
     return x, stats, state, out
@@ -250,11 +312,11 @@ def prefill(model, params, input_ids, max_len: int):
     rope = model.rope_tables(max_len)
     x = model.embed_tokens(params, input_ids, pos_ids)
 
-    def layer(block, lp, h, state, at):
+    def layer(block, lp, h, state, at, page_at):
         h, st, entries = _layer(
             block, lp, h, rope, pos_ids,
-            lambda attn, p, q, entries: (attn.attend_prompt(p, q, entries),
-                                         entries))
+            lambda attn, p, q, entries, win: (
+                attn.attend_prompt(p, q, entries, **win), entries))
         return h, st, state, entries
 
     with jax.named_scope("layer"):
@@ -300,12 +362,12 @@ def decode_step_slots(model, params, tokens, cache, positions):
     rope = model.rope_tables(cache[0].shape[2])
     x = model.embed_tokens(params, tokens[:, None], pos_ids)
 
-    def layer(block, lp, h, cache, at):
-        def step(attn, p, q, entries):
+    def layer(block, lp, h, cache, at, page_at):
+        def step(attn, p, q, entries, win):
             new = tuple(_cache_write_token(c, e, positions, uniform, at)
                         for c, e in zip(cache, entries))
             return (attn.attend_dense(p, q, tuple(c[at] for c in new),
-                                      positions),
+                                      positions, **win),
                     new, tuple(e[:, 0] for e in entries))
         return _layer(block, lp, h, rope, pos_ids, step)
 
@@ -355,14 +417,14 @@ def extend_cache(model, params, tokens, cache, start, stats=None, *,
     with jax.named_scope("embed"):
         x = model.embed_tokens(params, tokens, qpos)
 
-    def layer(block, lp, h, cache, at):
-        def step(attn, p, q, entries):
+    def layer(block, lp, h, cache, at, page_at):
+        def step(attn, p, q, entries, win):
             with jax.named_scope("kv_write"):
                 new = tuple(
                     c.at[at + (rows[:, None], qpos)].set(e.astype(c.dtype))
                     for c, e in zip(cache, entries))
             return (attn.attend_dense(p, q, tuple(c[at] for c in new),
-                                      start),
+                                      start, **win),
                     new, entries if collect_token_kv else None)
         return _layer(block, lp, h, rope, qpos, step)
 
@@ -450,7 +512,13 @@ def _paged_forward(model, params, tokens, pool_tree, table, positions,
     [L, P, page_size, *stored shape] per array of the model's cache
     contract, then, for quantized pages (int8, or uint8 nibble pairs of
     int4: the K/V kind only), a plane of per-head-vector f32 scales
-    [L, P, page_size, n_kv] for each.  The block's entries are scattered
+    [L, P, page_size, n_kv] for each.  A model whose layers differ in
+    how far back they read (the contract's `windows`) has one such set
+    of page arrays a KIND of layer, one after the other in the tree,
+    each [layers of the kind, pages of the kind + 1, ...], and `table`
+    is [kinds, S, max_pages]: a layer writes and reads its kind's pages
+    through its kind's table, at its place among the layers of its
+    kind; exact pages only.  The block's entries are scattered
     into each slot's pages BEFORE the query attends (write-then-attend:
     the token sees itself, exactly like the dense path).
 
@@ -472,9 +540,17 @@ def _paged_forward(model, params, tokens, pool_tree, table, positions,
     positions = positions.astype(jnp.int32)
     table = table.astype(jnp.int32)
     C = 1 if tokens.ndim == 1 else tokens.shape[1]
-    n = len(cache_contract(model).token_shapes)
-    pools, scales = tuple(pool_tree[:n]), tuple(pool_tree[n:])
-    L, P, ps = pools[0].shape[:3]
+    contract = cache_contract(model)
+    n, K = len(contract.token_shapes), len(contract.kinds)
+    # one page table a kind: [S, max_pages], or [K, S, max_pages]
+    tables = (table,) if table.ndim == 2 else tuple(table)
+    pools, scales = tuple(pool_tree[:n * K]), tuple(pool_tree[n * K:])
+    if len(tables) != K or (scales and K > 1):
+        raise ValueError(
+            f"{K} kinds of layer need {K} page tables and exact pages, "
+            f"got {len(tables)} and {len(scales)} scale planes")
+    ps = pools[0].shape[2]
+    pages = tuple(pools[k * n].shape[1] for k in range(K))  # +1: the null
     quant = (None if not scales
              else "int4" if pools[0].dtype == jnp.uint8 else "int8")
     bits = 4 if quant == "int4" else 8
@@ -482,16 +558,17 @@ def _paged_forward(model, params, tokens, pool_tree, table, positions,
     with jax.named_scope("embed"):
         x = model.embed_tokens(
             params, tokens[:, None] if tokens.ndim == 1 else tokens, pos_ids)
-    rope = model.rope_tables(table.shape[1] * ps)
+    rope = model.rope_tables(table.shape[-1] * ps)
 
-    def layer(block, lp, h, state, at):
-        flat, scales = state
-        (l,) = at
-        base = l * P
+    def layer(block, lp, h, state, at, page_at):
+        flats, scales = state
+        kind, (l,) = page_at
+        flat, tbl = flats[kind], tables[kind]
+        base = l * pages[kind]
 
-        def step(attn, p, q, entries):
+        def step(attn, p, q, entries, win):
             with jax.named_scope("kv_write"):
-                new = [_paged_write(pool, sc, table, positions, e, l, base,
+                new = [_paged_write(pool, sc, tbl, positions, e, l, base,
                                     bits)
                        for pool, sc, e in zip(flat, scales or (None,) * n,
                                               entries)]
@@ -499,16 +576,20 @@ def _paged_forward(model, params, tokens, pool_tree, table, positions,
                               tuple(w[1] for w in new) if scales else ())
             quantized = (dict(scales=scales_, layer=l, quant=quant)
                          if scales else {})
-            return (attn.attend_paged(p, q, flat_, table, positions, base,
-                                      **quantized), (flat_, scales_))
+            return (attn.attend_paged(p, q, flat_, tbl, positions, base,
+                                      **quantized, **win),
+                    (flats[:kind] + (flat_,) + flats[kind + 1:], scales_))
         h, st, state = _layer(block, lp, h, rope, pos_ids, step)
         return h, st, state, None
 
     with jax.named_scope("layer"):
-        flat = tuple(p.reshape((L * P,) + p.shape[2:]) for p in pools)
-        x, stats, (flat, scales), _ = _walk_layers(
-            model, params, x, (flat, scales), stats, layer)
-        pools = tuple(f.reshape(p.shape) for f, p in zip(flat, pools))
+        flats = tuple(
+            tuple(p.reshape((p.shape[0] * p.shape[1],) + p.shape[2:])
+                  for p in pools[k * n:(k + 1) * n]) for k in range(K))
+        x, stats, (flats, scales), _ = _walk_layers(
+            model, params, x, (flats, scales), stats, layer)
+        pools = tuple(f.reshape(p.shape)
+                      for f, p in zip(sum(flats, ()), pools))
     return model.final_hidden(params, x), pools + scales, stats
 
 

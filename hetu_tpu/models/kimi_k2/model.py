@@ -30,7 +30,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
 from hetu_tpu import ops
@@ -38,28 +37,13 @@ from hetu_tpu.models.cache_contract import CacheContract
 from hetu_tpu.models.kimi_k2.config import KimiK2Config
 from hetu_tpu.nn import initializers as init
 from hetu_tpu.nn.module import Module
-from hetu_tpu.nn.moe import SharedRoutedExperts
+from hetu_tpu.nn.moe import (MOE_STATS, _IS_MAX,  # noqa: F401
+                             SharedRoutedExperts, add_moe_stats,
+                             moe_layer_stats, zero_moe_stats)
 from hetu_tpu.nn.parallel import ParallelRMSNorm, VocabParallelEmbedding
 from hetu_tpu.parallel.strategy import ParallelStrategy
 
-#: what the serving programs count of themselves, in the order of the
-#: int32 vector they carry: (the engine's counter, how executions
-#: combine).  An expert layer's own counts (`SharedRoutedExperts.STATS`)
-#: and the number of expert-layer executions they are over.
-MOE_STATS = tuple(
-    [(f"serve.moe_{name}", "sum")
-     for name in SharedRoutedExperts.STATS[:-1] + ("layer_steps",)]
-    + [(f"serve.moe_{SharedRoutedExperts.STATS[-1]}", "max")])
-_IS_MAX = np.array([how == "max" for _, how in MOE_STATS])
 NEG_INF = -1e30
-
-
-def zero_moe_stats():
-    return jnp.zeros((len(MOE_STATS),), jnp.int32)
-
-
-def add_moe_stats(a, b):
-    return jnp.where(_IS_MAX, jnp.maximum(a, b), a + b)
 
 
 class MLAttention(Module):
@@ -265,8 +249,7 @@ class KimiBlock(Module):
         if not self.moe:
             return self.mlp(params, x), zero_moe_stats()
         y, st = self.mlp(params, x)
-        return y, jnp.concatenate([st[:-1], jnp.ones((1,), jnp.int32),
-                                   st[-1:]])
+        return y, moe_layer_stats(st)
 
     def forward(self, params, x, rope, pos_ids):
         with jax.named_scope("attn"):

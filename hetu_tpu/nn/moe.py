@@ -33,6 +33,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from hetu_tpu import ops
 from hetu_tpu.dstates import DistributedStates as DS
@@ -641,3 +642,29 @@ class SharedRoutedExperts(Module):
             jnp.sum((counts > 0).astype(jnp.int32)), extra,
             jnp.max(counts)])
         return y.reshape(b, s, h), stats
+
+
+#: what the serving programs of a model with such layers count of
+#: themselves, in the order of the int32 vector they carry
+#: (models/generation.py `STATS`): (the engine's counter, how executions
+#: combine).  An expert layer's own counts (`SharedRoutedExperts.STATS`)
+#: and the number of expert-layer executions they are over.
+MOE_STATS = tuple(
+    [(f"serve.moe_{name}", "sum")
+     for name in SharedRoutedExperts.STATS[:-1] + ("layer_steps",)]
+    + [(f"serve.moe_{SharedRoutedExperts.STATS[-1]}", "max")])
+_IS_MAX = np.array([how == "max" for _, how in MOE_STATS])
+
+
+def zero_moe_stats():
+    return jnp.zeros((len(MOE_STATS),), jnp.int32)
+
+
+def add_moe_stats(a, b):
+    return jnp.where(_IS_MAX, jnp.maximum(a, b), a + b)
+
+
+def moe_layer_stats(st):
+    """One execution of a `SharedRoutedExperts` layer (its `stats`) as a
+    MOE_STATS vector: one layer step."""
+    return jnp.concatenate([st[:-1], jnp.ones((1,), jnp.int32), st[-1:]])

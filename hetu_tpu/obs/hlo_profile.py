@@ -159,10 +159,14 @@ def group_of(op_name: str, phases: Tuple[str, ...] = PHASES) -> str:
 #: (`mlp` > `router`, `experts`, `shared_expert`) of models/kimi_k2 and
 #: nn/moe.SharedRoutedExperts.  The innermost known name is the group,
 #: so these split their parent's time and leave in `layer/attn` and
-#: `layer/mlp` what is outside them; no program without these scopes
-#: changes its groups.
+#: `layer/mlp` what is outside them; the attention of a layer that reads
+#: a window only and of one that reads everything, in a model that has
+#: both (`attn` > `attn_window`, `attn_full`: models/generation.py
+#: `_layer`, models/trinity); no program without these scopes changes its
+#: groups.
 SCOPE_MAP_GROUPS = ("kv_write", "loss", "mla_q", "mla_kv", "mla_out",
-                    "router", "experts", "shared_expert")
+                    "router", "experts", "shared_expert",
+                    "attn_window", "attn_full")
 UNSCOPED = "unscoped"
 _INSTR_PAT = re.compile(r'^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=')
 _OPERAND_PAT = re.compile(r'%([\w.\-]+)')

@@ -70,6 +70,19 @@ values offset by +8) plus the same scale plane, unpacked and
 dequantized in-VMEM (``(nibble - 8) * scale``).  Quantized blocks are
 multiplied in float32, as they were.
 
+**A window** (`paged_attention(window=w)`, a static width, for a layer
+that reads only its last w positions, the query's own counted; exact
+pages, single-token).  The walk BEGINS at the block that holds the
+window's first position, `max(positions[s] - w + 1, 0)`, and ends as
+before; of that block no page before the one that holds that position
+is fetched (their table entries are the null page: the scheduler
+released them); the positions of that page which precede the window
+are masked and their value rows zeroed, in a slot's first block (and
+its last, which may be the same) and in no other.  What lies behind a
+window costs nothing, as what lies past a slot's length does.  It is
+the same kernel: with `window=None` the walk, the block update and the
+lowered program are what they were, operation for operation.
+
 `paged_verify` is the multi-query sibling (spec-decode verification):
 q carries C = k+1 query positions per slot, all attending the slot's
 pages in ONE launch with per-position causal masks (query i sees keys
@@ -168,11 +181,15 @@ def _check_pool(rows, q_heads_hd, pool_shape, table_shape, pos_shape, S, *,
 
 
 def check_shapes(q_shape, pool_shape, table_shape, pos_shape, *,
-                  quant: str = "none", pool_dtype=None
+                  quant: str = "none", pool_dtype=None,
+                  window: Optional[int] = None
                   ) -> Tuple[int, int, int, int, int, int]:
     if len(q_shape) != 3 or len(pool_shape) != 4:
         raise ValueError(f"expected q [S, nq, hd] and pool [P, ps, n_kv, "
                          f"hd], got {q_shape} / {pool_shape}")
+    if window is not None and (window < 1 or quant != "none"):
+        raise ValueError(f"a window ({window}) is at least one position "
+                         f"wide and reads exact pages, not {quant!r}")
     S, nq, hd = q_shape
     P, ps, n_kv = _check_pool(nq, (nq, hd), pool_shape, table_shape,
                               pos_shape, S, quant=quant,
@@ -197,10 +214,11 @@ def check_shapes_verify(q_shape, pool_shape, table_shape, pos_shape, *,
 
 
 def compatible(q_shape, pool_shape, table_shape, pos_shape, *,
-               quant: str = "none", pool_dtype=None) -> bool:
+               quant: str = "none", pool_dtype=None,
+               window: Optional[int] = None) -> bool:
     try:
         check_shapes(q_shape, pool_shape, table_shape, pos_shape,
-                      quant=quant, pool_dtype=pool_dtype)
+                      quant=quant, pool_dtype=pool_dtype, window=window)
         return True
     except ValueError:
         return False
@@ -217,7 +235,7 @@ def verify_compatible(q_shape, pool_shape, table_shape, pos_shape, *,
 
 
 def _walk_live_blocks(pages_of, streams, sem, parity, carry, block, *,
-                      ppb):
+                      ppb, first_page_of=None):
     """The page walk: `block(b, buffer, carry, last)` over the live blocks
     of this grid step's slot, each block's pages fetched by per-page
     copies into the double buffers while the block before is computed.
@@ -232,10 +250,22 @@ def _walk_live_blocks(pages_of, streams, sem, parity, carry, block, *,
     by the LAST block of the slot before it, so the slot axis must run
     in order.  A block's copies are issued only for its live pages.
     Returns the carry after the slot's last block, which alone is called
-    with ``last=True``."""
+    with ``last=True``.
+
+    With `first_page_of(s)` (a layer that reads a WINDOW: the page that
+    holds the first position slot s may see) the walk begins at the
+    block that holds that page and fetches nothing before the page: a
+    slot's pages behind its window cost nothing, as those past its
+    length do.  A slot's first block is then called
+    ``block(b, buffer, carry, last, first=True)``: its rows before the
+    window's first page hold whatever they held.  None: from page 0,
+    and no such argument."""
     s_idx, n_slots = pl.program_id(0), pl.num_programs(0)
     n_pages = pages_of(s_idx)
     nb = (n_pages + ppb - 1) // ppb
+    windowed = first_page_of is not None
+    page0 = first_page_of if windowed else (lambda s: 0)
+    b0 = page0(s_idx) // ppb if windowed else 0
 
     def each_live_page(s, live, b, buffer, do):
         # a loop of the block's live pages, not `ppb` unrolled branches:
@@ -247,30 +277,44 @@ def _walk_live_blocks(pages_of, streams, sem, parity, carry, block, *,
                     hbm_ref.at[table_ref[s, b * ppb + j]],
                     vmem_ref.at[buffer, j], sem.at[buffer, i]))
             return carry
-        jax.lax.fori_loop(0, jnp.clip(live - b * ppb, 0, ppb), page, 0)
+        lo = jnp.clip(page0(s) - b * ppb, 0, ppb) if windowed else 0
+        jax.lax.fori_loop(lo, jnp.clip(live - b * ppb, 0, ppb), page, 0)
 
     @pl.when(s_idx == 0)
     def _first_slot():
         parity[0] = 0
-        each_live_page(s_idx, n_pages, 0, 0, lambda c: c.start())
+        each_live_page(s_idx, n_pages, b0, 0, lambda c: c.start())
     first = parity[0]
 
-    def fetch_and(b, carry, last):
-        buffer = jax.lax.rem(first + b, 2)
+    def fetch_and(b, carry, last, **at_first):
+        buffer = jax.lax.rem(first + b - b0, 2)
         # what follows this block: the slot's next one, or the next
         # slot's first (none after the last slot's last)
         s_next = s_idx if not last else jnp.minimum(s_idx + 1, n_slots - 1)
         live_next = (n_pages if not last else
                      jnp.where(s_idx + 1 < n_slots, pages_of(s_next), 0))
-        each_live_page(s_next, live_next, 0 if last else b + 1, 1 - buffer,
+        b_next = (b + 1 if not last else
+                  page0(s_next) // ppb if windowed else 0)
+        each_live_page(s_next, live_next, b_next, 1 - buffer,
                        lambda c: c.start())
         each_live_page(s_idx, n_pages, b, buffer, lambda c: c.wait())
-        return block(b, buffer, carry, last)
+        return block(b, buffer, carry, last, **at_first)
 
-    carry = jax.lax.fori_loop(
-        0, nb - 1, lambda b, c: fetch_and(b, c, False), carry)
-    carry = fetch_and(nb - 1, carry, True)
-    parity[0] = jax.lax.rem(first + nb, 2)
+    if windowed:
+        # the first block apart, where it is not also the last: it alone
+        # (and the last) has rows that nothing was fetched into
+        mid = jnp.minimum(b0 + 1, nb - 1)
+        carry = jax.lax.fori_loop(
+            b0, mid, lambda b, c: fetch_and(b, c, False, first=True), carry)
+        carry = jax.lax.fori_loop(
+            mid, nb - 1, lambda b, c: fetch_and(b, c, False, first=False),
+            carry)
+        carry = fetch_and(nb - 1, carry, True, first=True)
+    else:
+        carry = jax.lax.fori_loop(
+            0, nb - 1, lambda b, c: fetch_and(b, c, False), carry)
+        carry = fetch_and(nb - 1, carry, True)
+    parity[0] = jax.lax.rem(first + nb - b0, 2)
     return carry
 
 
@@ -301,7 +345,7 @@ def _scale_row(scale_ref, buffer):
     return jnp.concatenate([rows[j] for j in range(rows.shape[0])], axis=1)
 
 
-def _kernel(*refs, scale, C, ps, ppb, n_kv, group, mp, quant):
+def _kernel(*refs, scale, C, ps, ppb, n_kv, group, mp, quant, window=None):
     """One grid step = one slot: its ``C * nq`` query rows (row r is query
     position r // nq, head r % nq) against its live blocks."""
     if quant != "none":
@@ -333,7 +377,13 @@ def _kernel(*refs, scale, C, ps, ppb, n_kv, group, mp, quant):
     q_pos = pos + (jax.lax.div(row, nq) if C > 1 else 0)      # [rows, 1]
     own_head = jax.lax.rem(col, n_kv) == jax.lax.div(q_head, group)
 
-    def block(b, buffer, carry, last):
+    def first_page_of(s):
+        # the page that holds the first position the window lets slot s see
+        return jnp.maximum(pos_ref[s] - window + 1, 0) // ps
+    # the first position the window lets this slot see
+    pos0 = None if window is None else jnp.maximum(pos - window + 1, 0)
+
+    def block(b, buffer, carry, last, first=False):
         m_prev, l_prev, acc = carry
         k = _load_block(k_buf, buffer, quant=quant, hd=hd)
         v = _load_block(v_buf, buffer, quant=quant, hd=hd)
@@ -347,12 +397,21 @@ def _kernel(*refs, scale, C, ps, ppb, n_kv, group, mp, quant):
             seen = own_head & (col < (q_pos - b * T + 1) * n_kv)
         else:
             seen = own_head             # a block before the last is all live
-        if last:
-            # rows of the buffer past the slot's last token hold whatever
-            # they held: they must not reach the result through 0 * x
+        if first:
+            # ... and, under a window, at positions > q_pos[r] - window:
+            # all of a block after the slot's first
+            seen = seen & (col >= (q_pos - window + 1 - b * T) * n_kv)
+        if last or first:
+            # rows of the buffer past the slot's last token, or before
+            # the window's first position, hold whatever they held (the
+            # page's own older tokens, or nothing fetched): they must not
+            # reach the result through 0 * x
             v_row = jax.lax.broadcasted_iota(jnp.int32, (T * n_kv, 1), 0)
-            v = jnp.where(v_row < (pos + C - b * T) * n_kv, v,
-                          jnp.zeros_like(v))
+            live = v_row < (pos + C - b * T) * n_kv if last else None
+            if first:
+                fetched = v_row >= (pos0 - b * T) * n_kv
+                live = fetched if live is None else live & fetched
+            v = jnp.where(live, v, jnp.zeros_like(v))
         s = jnp.where(seen, s, NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p_ = jnp.exp(s - m_new)        # an unseen key's is exp(-1e30) = 0
@@ -369,8 +428,9 @@ def _kernel(*refs, scale, C, ps, ppb, n_kv, group, mp, quant):
     carry = (jnp.full((rows, 1), NEG_INF, jnp.float32),
              jnp.zeros((rows, 1), jnp.float32),
              jnp.zeros((rows, hd), jnp.float32))
-    _, l, acc = _walk_live_blocks(pages_of, streams, sem, parity, carry,
-                                  block, ppb=ppb)
+    _, l, acc = _walk_live_blocks(
+        pages_of, streams, sem, parity, carry, block, ppb=ppb,
+        **({} if window is None else {"first_page_of": first_page_of}))
     o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
@@ -408,7 +468,7 @@ def _scalar_prefetch(table, positions, scale_table, k_scale, P, ps, n_kv,
 
 
 def _attend(q, k_pool, v_pool, scalars, k_scale, v_scale, *, C, scale,
-            quant):
+            quant, window=None):
     """q ``[S, C * nq, hd]`` over the pools: the one `pallas_call` of
     this module (decode is C = 1)."""
     S, rows, hd = q.shape
@@ -440,7 +500,8 @@ def _attend(q, k_pool, v_pool, scalars, k_scale, v_scale, *, C, scale,
     return pl.pallas_call(
         functools.partial(_kernel, scale=scale, C=C, ps=ps, ppb=ppb,
                           n_kv=n_kv, group=rows // C // n_kv, mp=mp,
-                          quant=quant),
+                          quant=quant,
+                          **({} if window is None else {"window": window})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, rows, hd), q.dtype),
         # in order: a slot's last block fetches the next slot's first
@@ -453,7 +514,7 @@ def _attend(q, k_pool, v_pool, scalars, k_scale, v_scale, *, C, scale,
 def paged_attention(q, k_pool, v_pool, table, positions, *,
                     softmax_scale: Optional[float] = None,
                     k_scale=None, v_scale=None, quant=None,
-                    scale_table=None):
+                    scale_table=None, window: Optional[int] = None):
     """Decode attention over paged KV.  q: [S, nq, hd] (one token per
     slot); k_pool/v_pool: [P, page_size, n_kv, hd] (page 0 = the null
     page); table: [S, max_pages] int32 page ids; positions: [S] int32 —
@@ -466,18 +527,24 @@ def paged_attention(q, k_pool, v_pool, table, positions, *,
     own, for a caller whose scale planes are not laid out like the
     payload (models/generation hands the kernel every layer's payload
     pages in one array and one layer's scales; ignored for exact pages).
+    ``window`` (a static width, for a layer that reads only its last
+    `window` positions, the query's own counted; exact pages): slot s
+    attends over positions[s] - window < j <= positions[s]; the walk
+    begins at the block that holds the window's first position, fetches
+    no page before that position's and masks what precedes it in its
+    page.  None is the kernel as it was, operation for operation.
     Returns [S, nq, hd].  Raises ValueError on shapes outside
     `compatible` (the dense-gather fallback in models/generation
     handles those)."""
     quant = _resolve_quant(quant, k_scale, v_scale)
     S, nq, hd, P, ps, n_kv = check_shapes(
         q.shape, k_pool.shape, table.shape, positions.shape, quant=quant,
-        pool_dtype=k_pool.dtype)
+        pool_dtype=k_pool.dtype, window=window)
     scalars = _scalar_prefetch(table, positions, scale_table, k_scale,
                                P, ps, n_kv, quant)
     scale = softmax_scale if softmax_scale is not None else hd ** -0.5
     return _attend(q, k_pool, v_pool, scalars, k_scale, v_scale, C=1,
-                   scale=scale, quant=quant)
+                   scale=scale, quant=quant, window=window)
 
 
 def paged_verify(q, k_pool, v_pool, table, positions, *,

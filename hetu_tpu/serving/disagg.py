@@ -110,7 +110,14 @@ class PrefillWorker:
         self.num_slots = num_slots
         self.sampling = sampling
         self._registry = registry
-        kind = cache_contract(model).kind
+        contract = cache_contract(model)
+        kind = contract.kind
+        if any(w is not None for w in contract.kinds):
+            raise NotImplementedError(
+                f"{type(model).__name__} has layers that read a window "
+                "only; the disaggregated prefill tier (serving/disagg.py) "
+                "ships every layer's whole K/V scratch and is not built "
+                "for them")
         if kind != "kv":
             raise NotImplementedError(
                 f"{type(model).__name__} keeps a {kind!r} cache; the "

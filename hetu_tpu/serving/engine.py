@@ -201,6 +201,8 @@ class ServeConfig:
     kv_repage: bool = False
 
     def __post_init__(self):
+        if isinstance(self.num_pages, list):
+            self.num_pages = tuple(self.num_pages)
         if self.max_len % self.page_size:
             raise ValueError(f"max_len {self.max_len} must be a multiple "
                              f"of page_size {self.page_size}")
@@ -333,7 +335,9 @@ class ServingEngine:
         # (models/cache_contract.py): the pool, the prefill scratch and
         # the cache-byte gauges are all sized from this one contract
         self.cache = cache_contract(model)
-        if self.cache.kind != "kv":
+        #: some layer reads a window only: pages by kind of layer
+        self.windowed = any(w is not None for w in self.cache.kinds)
+        if self.cache.kind != "kv" or self.windowed:
             self._refuse_unbuilt(reshard, draft_model, drafter)
         self.pool = PagePool.for_contract(
             self.cache, num_pages=self.config.num_pages,
@@ -501,6 +505,16 @@ class ServingEngine:
         self._registry.set_gauge(
             "serve.kv_bytes_per_token",
             contract_bytes_per_token(self.cache, mode))
+        if self.windowed:
+            # what a token costs in each kind of layer: a window kind's
+            # share is paid for the last `window` positions only
+            per_layer = contract_bytes_per_token(self.cache, mode) \
+                / self.cache.num_layers
+            for k, w in enumerate(self.cache.kinds):
+                self._registry.set_gauge(
+                    "serve.kv_bytes_per_token",
+                    per_layer * len(self.cache.layers_of(k)),
+                    kind="full" if w is None else f"window_{w}")
         #: the running stats vector of the programs of a model that
         #: counts (what `model.STATS` names: an expert model's assignment
         #: counts), on the device between fetches; None for a model whose
@@ -511,9 +525,12 @@ class ServingEngine:
             self._build_programs()
 
     def _refuse_unbuilt(self, reshard, draft_model, drafter):
-        """A model whose cache is not of the K/V kind (latent attention)
-        runs the normal path; what this engine has only for K/V pools is
-        refused here by name, never run as something else."""
+        """A model whose cache is not of the K/V kind (latent attention),
+        or some of whose layers read a window only, runs the normal path;
+        what this engine has only for K/V pools every layer of which
+        keeps everything is refused here by name, never run as something
+        else.  (The disaggregated prefill tier refuses such a model
+        itself: serving/disagg.PrefillWorker, `adopt_prefilled`.)"""
         cfg, name = self.config, type(self.model).__name__
         unbuilt = {
             "speculative decoding (spec_decode / verify_step_*)":
@@ -521,15 +538,23 @@ class ServingEngine:
                 or draft_model is not None,
             "the radix prefix cache (prefix_cache)": cfg.prefix_cache,
             "int8 / int4 pages (kv_quant)": cfg.kv_quant != "none",
-            "resident quantized experts (moe_dispatch int8 / int4, "
-            "serving/experts.py)": cfg.moe_dispatch in ("int8", "int4"),
             "the reshard hook (serving/reshard.py)": reshard is not None,
         }
+        if self.cache.kind != "kv":
+            # (over K/V pages, window layers or not, the store is built)
+            unbuilt["resident quantized experts (moe_dispatch int8 / int4, "
+                    "serving/experts.py)"] = \
+                cfg.moe_dispatch in ("int8", "int4")
         asked = [what for what, on in unbuilt.items() if on]
         if asked:
+            keeps = "; ".join(
+                f"{len(self.cache.layers_of(k))} layers keep "
+                + ("every position" if w is None
+                   else f"the last {w} positions")
+                for k, w in enumerate(self.cache.kinds))
             raise NotImplementedError(
                 f"{name} stores {self.cache.token_shapes} a token a layer "
-                f"(a {self.cache.kind!r} cache, not K/V); not built for it: "
+                f"({self.cache.kind!r}: {keeps}); not built for it: "
                 + "; ".join(asked))
 
     # ------------------------------------------------------------ build
@@ -564,7 +589,7 @@ class ServingEngine:
         S = self.config.num_slots
         hd_p = (self.pool.head_dim // 2 if self.pool.quant == "int4"
                 else self.pool.head_dim)
-        pool_shape = (self.config.num_pages + 1, self.config.page_size,
+        pool_shape = (self.pool.pages_by_kind[0] + 1, self.config.page_size,
                       self.pool.num_kv_heads, hd_p)
         table_shape = (S, self.scheduler.max_pages)
         if self.spec:
@@ -575,6 +600,15 @@ class ServingEngine:
                 table_shape, (S,), quant=self.pool.quant,
                 pool_dtype=self.pool.arrays.k.dtype)
         q_shape = (S, c.num_attention_heads, c.head_dim)
+        if self.windowed:
+            # one decode program: the kernel for every kind of layer, or
+            # the gather route for all
+            return all([resolve_route(
+                "paged_attn", _pa.check_shapes, q_shape,
+                (n + 1,) + pool_shape[1:], table_shape, (S,),
+                pool_dtype=self.pool.arrays.k.dtype, window=w)
+                for n, w in zip(self.pool.pages_by_kind,
+                                self.pool.windows)])
         return resolve_route("paged_attn", _pa.check_shapes, q_shape,
                              pool_shape, table_shape, (S,),
                              quant=self.pool.quant,
@@ -888,9 +922,11 @@ class ServingEngine:
             return (self.params, jnp.zeros((1, C), jnp.int32),
                     self._fresh_scratch(), jnp.int32(0), *stats)
         if program == "write_pages":
-            return (self.pool.arrays.tree(), jnp.zeros(max_pages, jnp.int32),
+            return (self.pool.arrays.tree(),
+                    jax.tree.map(jnp.asarray,
+                                 self.scheduler.null_write_rows()),
                     *(a[:, 0] for a in self._fresh_scratch()))
-        table = jnp.zeros((S, max_pages), jnp.int32)
+        table = jnp.zeros(self.scheduler.page_table.shape, jnp.int32)
         pos = jnp.zeros(S, jnp.int32)
         sample_args = self._sample_args([]) if self.config.sampling else ()
         if program == "decode":
@@ -1001,6 +1037,11 @@ class ServingEngine:
         byte-identical to the single-engine run.  False = no slot/
         reservation/quota headroom right now; the caller retries next
         step (the shipment stays pending, the dedupe seq unburned)."""
+        if self.windowed:
+            raise NotImplementedError(
+                f"{type(self.model).__name__} has layers that read a window "
+                "only; the disaggregated prefill tier (adopt_prefilled, "
+                "serving/disagg.py) is not built for them")
         adm = self.scheduler.admit_direct(req, now)
         if adm is None:
             reason = self.scheduler.last_stall or "none"
@@ -1171,6 +1212,8 @@ class ServingEngine:
                     positions = np.zeros(S, np.int32)
                     for i in active:
                         positions[i] = self.scheduler.slots[i].pos
+                    if self.windowed:
+                        self._release_behind_windows(active, positions)
                     # what this decode step's attention reads: every
                     # slot's cached tokens, the one it writes included
                     self._registry.inc("serve.decode_slot_steps",
@@ -1303,6 +1346,20 @@ class ServingEngine:
                 self._last_clock = clock()
         self._note_step_phases(now, time.perf_counter() - t0, phases)
         return finished
+
+    def _release_behind_windows(self, active, positions):
+        """Before a decode step: every window layer's pages that have
+        fallen wholly behind their slot's window go back to the free
+        list (`Scheduler.advance`), and what the step's window layers
+        read is counted beside `serve.decode_context_tokens`: per slot
+        min(context, window), of the widest window."""
+        released = sum(self.scheduler.advance(i) for i in active)
+        if released:
+            self._registry.inc("serve.window_pages_released", released)
+        w = max(w for w in self.cache.kinds if w is not None)
+        self._registry.inc(
+            "serve.decode_window_context_tokens",
+            int(np.minimum(positions[active] + 1, w).sum()))
 
     def _stats_args(self) -> tuple:
         """The extra argument of the programs of a model that counts
@@ -1546,7 +1603,8 @@ class ServingEngine:
         for every reader."""
         table = np.zeros_like(self.scheduler.page_table)
         for i in active:
-            table[i] = self.scheduler.page_table[i]
+            # [S, max_pages], or [kinds, S, max_pages]
+            table[..., i, :] = self.scheduler.page_table[..., i, :]
         return jnp.asarray(table)
 
     # ------------------------------------------------------ spec decode
@@ -1731,12 +1789,10 @@ class ServingEngine:
             # scratch was primed from) and are read-only to this slot
             # (COW) — their row entries point at the null page so the
             # write lands harmlessly
-            pages_row = np.full(self.scheduler.max_pages,
-                                PagePool.NULL_PAGE, np.int32)
-            pages_row[: len(st.pages)] = st.pages
-            pages_row[: base // self.pool.page_size] = PagePool.NULL_PAGE
+            pages_row = self.scheduler.write_rows(
+                slot_idx, base // self.pool.page_size)
             tree = self._run_write(self.pool.arrays.tree(),
-                                   jnp.asarray(pages_row),
+                                   jax.tree.map(jnp.asarray, pages_row),
                                    *(a[:, 0] for a in st.prefill_cache))
             self.pool.arrays = PoolArrays.from_tree(tree)
             if self.prefix_cache is not None:

@@ -35,6 +35,15 @@ What a token stores comes from the model's cache contract
 above, or ONE array of the model's own token shape (a latent-attention
 model: `[L, num_pages, page_size, 640]`, exact pages only).
 
+How far back each layer READS comes from the contract too.  Layers
+that read everything are one kind of layer and layers that read a window
+of w positions another: the pool holds one set of page arrays, one free
+list and one null page a KIND, and a page id means that page in every
+layer of its kind (`PagePool` says how a window kind's page lives:
+reserved, written, released behind the window, a null table entry from
+then on).  One kind, every layer reading everything, is the pool as it
+always was.
+
 Host side (allocator, free list) is plain Python; device side
 (gather/scatter) is pure-functional jax, jitted by the engine.
 """
@@ -129,21 +138,29 @@ def _tap_kv_snr(x32, q, s, bits: int = 8):
 @dataclasses.dataclass
 class PoolArrays:
     """The device-side pool state threaded through the engine's jitted
-    step (a pytree: quant scales are None in the exact mode)."""
+    step (a pytree: quant scales are None in the exact mode).  A pool
+    with several kinds of layer (exact K/V pages) holds the first kind's
+    arrays as `k`, `v` and the further kinds' as `more`, (k, v) after
+    (k, v)."""
     k: jnp.ndarray
     v: Optional[jnp.ndarray] = None     # None: a one-array (latent) pool
     k_scale: Optional[jnp.ndarray] = None
     v_scale: Optional[jnp.ndarray] = None
+    more: tuple = ()
 
     def tree(self):
         if self.v is None:
             return (self.k,)
         if self.k_scale is None:
-            return (self.k, self.v)
+            return (self.k, self.v) + tuple(self.more)
         return (self.k, self.v, self.k_scale, self.v_scale)
 
     @staticmethod
     def from_tree(t) -> "PoolArrays":
+        t = tuple(t)
+        if len(t) > 2 and t[2].ndim == t[0].ndim:
+            # page arrays of a further kind, not a plane of scales
+            return PoolArrays(t[0], t[1], more=t[2:])
         return PoolArrays(*t)
 
 
@@ -167,36 +184,112 @@ def repage_arrays(arrays: PoolArrays, mesh) -> PoolArrays:
     return PoolArrays.from_tree(new)
 
 
+class _PageList:
+    """The free list and the reference counts of ONE kind's pages (ids
+    1..num_pages; 0 is the kind's null page)."""
+
+    def __init__(self, num_pages: int):
+        if num_pages < 1:
+            raise ValueError("need at least one usable page")
+        self.num_pages = num_pages
+        # LIFO free list: recently freed pages are reused first (their
+        # garbage is overwritten by the next prefill/decode write before
+        # any masked read can see it)
+        self._free: List[int] = list(range(num_pages, 0, -1))
+        # copy-on-write reference counts (serving/prefix_cache.py): a
+        # freshly allocated page has one owner; the radix prefix cache
+        # and every slot sharing the page each hold one more.  A page
+        # returns to the free list when its LAST owner releases it —
+        # `free()` is decref, not destroy.  Without sharing every count
+        # stays 0/1 and the pre-COW semantics are unchanged.
+        self.refcount = np.zeros(num_pages + 1, np.int64)
+        self.allocs = 0
+        self.frees = 0
+
+    def alloc(self, n: int) -> Optional[List[int]]:
+        if n > len(self._free):
+            return None
+        pages = [self._free.pop() for _ in range(n)]
+        self.refcount[pages] = 1
+        self.allocs += n
+        return pages
+
+    def incref(self, pages: List[int]):
+        for p in pages:
+            if not (0 < p <= self.num_pages):
+                raise ValueError(f"incref of invalid page id {p}")
+            if self.refcount[p] < 1:
+                raise ValueError(f"incref of free page {p}")
+        for p in pages:     # per-element (fancy indexing drops dups)
+            self.refcount[p] += 1
+
+    def free(self, pages: List[int]):
+        for p in pages:
+            if not (0 < p <= self.num_pages):
+                raise ValueError(f"freeing invalid page id {p}")
+            if self.refcount[p] < 1 or p in self._free:
+                raise ValueError(f"double free of page {p}")
+        for p in pages:
+            self.refcount[p] -= 1
+            if self.refcount[p] == 0:
+                self._free.append(p)
+                self.frees += 1
+
+
 class PagePool:
     """Host-side allocator + device-side page arrays.
 
     num_pages counts USABLE pages; one extra null page (index 0) is
-    added on top, so the device arrays hold num_pages + 1 pages."""
+    added on top, so the device arrays hold num_pages + 1 pages.
+
+    **Kinds of layer.**  Layers that read equally far back (the cache
+    contract's `windows`) are one kind; the pool holds one set of page
+    arrays `[layers of the kind, pages of the kind + 1, ...]`, one free
+    list and one null page a kind, and every allocator call names the
+    kind (`kind=0`: the only one of a model whose layers all read
+    everything).  A page id means that page in every layer of ITS kind
+    and nothing in the others.  The life of a WINDOW kind's page
+    (`windows[kind]` = w): reserved at admission (a slot holds at most
+    `hold_pages` of them at once: the window and one page, however long
+    the request), written by the page write or a decode step, RELEASED
+    to the free list as soon as every position it holds lies behind
+    `pos - w + 1` (serving/scheduler.py `advance`), its table entry the
+    null page from then on: nothing reads there again (the decode
+    kernel starts its walk at the window's first page, the gather route
+    masks)."""
 
     NULL_PAGE = 0
 
     @classmethod
-    def for_contract(cls, contract, *, num_pages: int, page_size: int,
+    def for_contract(cls, contract, *, num_pages, page_size: int,
                      quant: str = "none", **kw) -> "PagePool":
         """The pool of a model's cache contract
         (models/cache_contract.py): what a token stores comes from the
-        model, not from `num_key_value_heads` x `head_dim`."""
+        model, not from `num_key_value_heads` x `head_dim`, and so does
+        how far back each layer reads.  `num_pages`: usable pages, one
+        number for every kind or one a kind (in the order of
+        `contract.kinds`)."""
         if contract.kind == "kv":
             n_kv, hd = contract.token_shapes[0]
             what = dict(num_kv_heads=n_kv, head_dim=hd)
         else:
             (stored,) = contract.stored_shapes
             what = dict(token_shape=tuple(stored))
+        if len(contract.kinds) > 1 or contract.kinds[0] is not None:
+            what.update(windows=contract.kinds, layers=tuple(
+                contract.layers_of(k) for k in range(len(contract.kinds))))
         return cls(num_layers=contract.num_layers, num_pages=num_pages,
                    page_size=page_size, dtype=contract.dtype, quant=quant,
                    **what, **kw)
 
-    def __init__(self, *, num_layers: int, num_pages: int, page_size: int,
+    def __init__(self, *, num_layers: int, num_pages, page_size: int,
                  num_kv_heads: Optional[int] = None,
                  head_dim: Optional[int] = None,
                  dtype=jnp.float32, quant: str = "none",
                  device_arrays: bool = True,
-                 token_shape: Optional[Tuple[int, ...]] = None):
+                 token_shape: Optional[Tuple[int, ...]] = None,
+                 windows: Tuple[Optional[int], ...] = (None,),
+                 layers: Optional[Tuple[Tuple[int, ...], ...]] = None):
         if quant not in ("none", "int8", "int4"):
             raise ValueError(f"kv quant mode {quant!r} invalid; "
                              "choices: ('none', 'int8', 'int4')")
@@ -214,10 +307,33 @@ class PagePool:
         if quant == "int4" and head_dim % 2:
             raise ValueError(f"int4 pages need an even head_dim, "
                              f"got {head_dim}")
-        if num_pages < 1:
-            raise ValueError("need at least one usable page")
+        #: per kind of layer, how far back it reads (None: everything)
+        self.windows = tuple(windows)
+        #: per kind, its layers among the model's (a dense cache's order)
+        self.layers = (tuple(tuple(x) for x in layers) if layers is not None
+                       else (tuple(range(num_layers)),))
+        K = len(self.windows)
+        if len(self.layers) != K or sorted(sum(self.layers, ())) \
+                != list(range(num_layers)):
+            raise ValueError(f"{K} kinds of layer must part the "
+                             f"{num_layers} layers, got {self.layers}")
+        #: some kind reads a window only: tables, page lists and the page
+        #: write's rows are by kind of layer (serving/scheduler.py)
+        self.windowed = K > 1 or self.windows[0] is not None
+        if self.windowed and (token_shape is not None or quant != "none"):
+            raise ValueError("kinds of layer are built for exact K/V "
+                             "pages")
+        by_kind = (tuple(int(n) for n in num_pages)
+                   if isinstance(num_pages, (tuple, list))
+                   else (int(num_pages),) * K)
+        if len(by_kind) != K:
+            raise ValueError(f"{len(by_kind)} page counts for {K} kinds "
+                             f"of layer")
+        #: usable pages a kind; `num_pages` is their sum
+        self.pages_by_kind = by_kind
+        self.lists = [_PageList(n) for n in by_kind]
         self.num_layers = num_layers
-        self.num_pages = num_pages
+        self.num_pages = sum(by_kind)
         self.page_size = page_size
         self.num_kv_heads = num_kv_heads
         self.head_dim = head_dim
@@ -225,7 +341,7 @@ class PagePool:
         self.quant = quant
         #: payload bit width of the stored pages (8 also covers fp modes)
         self.quant_bits = 4 if quant == "int4" else 8
-        shape = (num_layers, num_pages + 1, page_size) + (
+        shape = (num_layers, by_kind[0] + 1, page_size) + (
             token_shape or (num_kv_heads, head_dim))
         if not device_arrays:
             # host-only pool (serving/fleet.py's discrete-event sim): the
@@ -248,71 +364,66 @@ class PagePool:
                 k_scale=jnp.zeros(shape[:-1], jnp.float32),
                 v_scale=jnp.zeros(shape[:-1], jnp.float32))
         else:
-            self.arrays = PoolArrays(k=jnp.zeros(shape, dtype),
-                                     v=jnp.zeros(shape, dtype))
-        # LIFO free list: recently freed pages are reused first (their
-        # garbage is overwritten by the next prefill/decode write before
-        # any masked read can see it)
-        self._free: List[int] = list(range(num_pages, 0, -1))
-        # copy-on-write reference counts (serving/prefix_cache.py): a
-        # freshly allocated page has one owner; the radix prefix cache
-        # and every slot sharing the page each hold one more.  A page
-        # returns to the free list when its LAST owner releases it —
-        # `free()` is decref, not destroy.  Without sharing every count
-        # stays 0/1 and the pre-COW semantics are unchanged.
-        self.refcount = np.zeros(num_pages + 1, np.int64)
-        self.allocs = 0
-        self.frees = 0
+            self.arrays = PoolArrays.from_tree(tuple(
+                jnp.zeros((len(ls), n + 1) + shape[2:], dtype)
+                for ls, n in zip(self.layers, by_kind) for _ in "kv"))
 
     # ---------------------------------------------------------- allocator
     def pages_for(self, tokens: int) -> int:
         return max(1, math.ceil(tokens / self.page_size))
 
+    def hold_pages(self, tokens: int, kind: int = 0) -> int:
+        """Pages of `kind` a sequence of `tokens` positions holds at
+        most at once: all of them where the kind reads everything; under
+        a window w the pages that the positions t - w + 1 .. t can
+        touch, whatever t."""
+        w = self.windows[kind]
+        n = self.pages_for(tokens)
+        return n if w is None else min(n, -(-(w - 1) // self.page_size) + 1)
+
+    # the first (for most models the only) kind's books under the names
+    # they always had
+    @property
+    def _free(self) -> List[int]:
+        return self.lists[0]._free
+
+    @property
+    def refcount(self):
+        return self.lists[0].refcount
+
+    @property
+    def allocs(self) -> int:
+        return sum(x.allocs for x in self.lists)
+
+    @property
+    def frees(self) -> int:
+        return sum(x.frees for x in self.lists)
+
     @property
     def free_count(self) -> int:
-        return len(self._free)
+        return sum(len(x._free) for x in self.lists)
 
     @property
     def used_count(self) -> int:
-        return self.num_pages - len(self._free)
+        return self.num_pages - self.free_count
 
     @property
     def utilization(self) -> float:
         return self.used_count / self.num_pages
 
-    def alloc(self, n: int) -> Optional[List[int]]:
-        """Pop n pages off the free list (refcount 1 each), or None
-        (caller queues) when the pool cannot satisfy the reservation."""
-        if n > len(self._free):
-            return None
-        pages = [self._free.pop() for _ in range(n)]
-        self.refcount[pages] = 1
-        self.allocs += n
-        return pages
+    def alloc(self, n: int, kind: int = 0) -> Optional[List[int]]:
+        """Pop n pages off the kind's free list (refcount 1 each), or
+        None (caller queues) when it cannot satisfy the reservation."""
+        return self.lists[kind].alloc(n)
 
-    def incref(self, pages: List[int]):
+    def incref(self, pages: List[int], kind: int = 0):
         """Add one owner to each live page (prefix-cache sharing)."""
-        for p in pages:
-            if not (0 < p <= self.num_pages):
-                raise ValueError(f"incref of invalid page id {p}")
-            if self.refcount[p] < 1:
-                raise ValueError(f"incref of free page {p}")
-        for p in pages:     # per-element (fancy indexing drops dups)
-            self.refcount[p] += 1
+        self.lists[kind].incref(pages)
 
-    def free(self, pages: List[int]):
+    def free(self, pages: List[int], kind: int = 0):
         """Release one ownership of each page (decref); a page whose
-        last owner released it returns to the free list."""
-        for p in pages:
-            if not (0 < p <= self.num_pages):
-                raise ValueError(f"freeing invalid page id {p}")
-            if self.refcount[p] < 1 or p in self._free:
-                raise ValueError(f"double free of page {p}")
-        for p in pages:
-            self.refcount[p] -= 1
-            if self.refcount[p] == 0:
-                self._free.append(p)
-                self.frees += 1
+        last owner released it returns to the kind's free list."""
+        self.lists[kind].free(pages)
 
     # ------------------------------------------------------ device ops
     # Pure functions over PoolArrays trees (the engine jits them inside
@@ -322,6 +433,8 @@ class PagePool:
         """Dense per-slot cache views from the pool.  table: [S, mp]
         int32 -> (ck, cv) [L, S, mp*page_size, n_kv, hd] in the compute
         dtype (int8 pages dequantize here)."""
+        if len(self.windows) > 1:
+            return self._gather_kinds(arrays_tree, table)
         a = PoolArrays.from_tree(arrays_tree)
         L = self.num_layers
         S, mp = table.shape
@@ -339,11 +452,40 @@ class PagePool:
 
         return (dense(a.k, a.k_scale), dense(a.v, a.v_scale))
 
+    def _gather_kinds(self, arrays_tree, table):
+        """`gather` over several kinds of layer: each kind's pages by its
+        own table [kinds, S, mp], the layers put back in the model's
+        order.  A released page's entry is the null page: what is read
+        there lies behind the layer's window, which the attention
+        masks."""
+        S, mp = table.shape[1:]
+        M = mp * self.page_size
+        order = np.argsort(np.concatenate(
+            [np.asarray(ls) for ls in self.layers]))
+        parts = [[], []]
+        for kind, ls in enumerate(self.layers):
+            for i, pool in enumerate(arrays_tree[2 * kind: 2 * kind + 2]):
+                parts[i].append(pool[:, table[kind]].reshape(
+                    len(ls), S, M, self.num_kv_heads, self.head_dim))
+        return tuple(jnp.concatenate(p)[order] for p in parts)
+
     def write_token(self, arrays_tree, table, positions, k_toks, v_toks):
         """Scatter one decoded token's K/V into the pool.  positions:
         [S] absolute write positions; k_toks/v_toks: [L, S, n_kv, hd].
         Slots whose table entry is the null page (inactive) dump their
         write harmlessly into it."""
+        if len(self.windows) > 1:
+            # each kind's layers of the token, through the kind's table
+            out, rows = (), jnp.arange(positions.shape[0])
+            off = positions % self.page_size
+            for kind, ls in enumerate(self.layers):
+                page = table[kind][rows, positions // self.page_size]
+                out += tuple(
+                    pool.at[:, page, off].set(
+                        t[np.asarray(ls)].astype(pool.dtype))
+                    for pool, t in zip(arrays_tree[2 * kind: 2 * kind + 2],
+                                       (k_toks, v_toks)))
+            return out
         a = PoolArrays.from_tree(arrays_tree)
         S = positions.shape[0]
         page = table[jnp.arange(S), positions // self.page_size]
@@ -369,6 +511,10 @@ class PagePool:
         [L, S, C, n_kv, hd].  Positions beyond a slot's table row
         (possible only for inactive rows riding along) redirect to the
         null page instead of clamp-corrupting the row's last page."""
+        if len(self.windows) > 1:
+            raise NotImplementedError(
+                "a block of tokens a slot (the verify step) is not built "
+                "for a pool with several kinds of layer")
         a = PoolArrays.from_tree(arrays_tree)
         S, C = positions.shape
         mp = table.shape[1]
@@ -398,7 +544,16 @@ class PagePool:
         pages_row: [mp] int32 page ids (pad unused tail entries with the
         null page — their garbage lands in page 0); ks/vs:
         [L, mp*page_size, n_kv, hd]; a one-array pool takes its one
-        dense cache [L, mp*page_size, *token_shape] as `ks`."""
+        dense cache [L, mp*page_size, *token_shape] as `ks`.
+
+        With a window kind of layer (`windows`), `pages_row` is one entry
+        a kind: [mp] page ids for a kind that reads everything;
+        (ids [n], first) for a window kind: the ids of the n =
+        `hold_pages(max_len, kind)` pages from page `first` on, the only
+        ones whose positions the layer will read again: the part of the
+        scratch before them is not written anywhere."""
+        if self.windowed:
+            return self._write_pages_kinds(arrays_tree, pages_row, ks, vs)
         a = PoolArrays.from_tree(arrays_tree)
         L = self.num_layers
         mp = pages_row.shape[0]
@@ -421,3 +576,29 @@ class PagePool:
         nk, nks = put(a.k, a.k_scale, ks)
         nv, nvs = put(a.v, a.v_scale, vs)
         return PoolArrays(nk, nv, nks, nvs).tree()
+
+    def _write_pages_kinds(self, arrays_tree, rows, ks, vs):
+        """A page at a time, in place (`dynamic_update_slice` in a
+        loop): with few KV heads a row the compiler lays a scatter of
+        whole pages out with the page's tokens second-minor and copies
+        the WHOLE pool into that layout and back at every write (1.5 GB
+        of temporaries at 4 KV heads beside a 5 GB pool: the compile for
+        the described chip, PR 34)."""
+        ps = self.page_size
+        out = ()
+        for kind, ls in enumerate(self.layers):
+            row, first = (rows[kind] if self.windows[kind] is not None
+                          else (rows[kind], 0))
+            layers = np.asarray(ls)
+            for pool, x in zip(arrays_tree[2 * kind: 2 * kind + 2],
+                               (ks, vs)):
+                def page(i, pool, x=x, row=row, first=first, layers=layers):
+                    # the page's tokens of the kind's layers alone: no
+                    # copy of the scratch by kind either
+                    tokens = jax.lax.dynamic_slice_in_dim(
+                        x, (first + i) * ps, ps, axis=1)[layers]
+                    return jax.lax.dynamic_update_slice(
+                        pool, tokens[:, None].astype(pool.dtype),
+                        (0, row[i]) + (0,) * (pool.ndim - 2))
+                out += (jax.lax.fori_loop(0, row.shape[0], page, pool),)
+        return out

@@ -11,8 +11,21 @@ through the pool's free list for the next admission — requests join and
 leave the batch at TOKEN boundaries, nothing waits for a "batch" to
 drain (the Orca/vLLM continuous-batching policy, TPU-shaped).
 
+A model whose layers differ in how far back they read (the cache
+contract's `windows`) has one page table and one page list a slot per
+KIND of layer, over the pool's one allocator (serving/kv_pool.py): a
+kind that reads everything reserves as above; a WINDOW kind reserves
+what a slot holds of it at once (`PagePool.hold_pages`: the window and a
+page), first for the window that ends where the prompt ends (the
+prefill scratch holds the prompt; what lies behind that window is never
+paged), and `advance` releases a page to the free list the step every
+position it holds has fallen behind the window, and takes the page the
+sequence grows into next: never more than it held, so reserve-on-admit's
+guarantee stands.
+
 All state here is host-side Python/numpy — the device only ever sees the
-[slots, max_pages] int32 page table and the per-slot position vector.
+[slots, max_pages] int32 page table ([kinds, slots, max_pages] with more
+than one kind of layer) and the per-slot position vector.
 `check_invariants()` is the correctness contract the fuzz test drives:
 no two live slots share a page, live + free partition the pool, table
 rows mirror the slots' page lists exactly.
@@ -39,7 +52,7 @@ class SlotState:
     earlier request (serving/prefix_cache.py) — prefill starts at that
     boundary and those pages are never written by this slot."""
     request: Request
-    pages: List[int]
+    pages: List[int]             # of the first kind of layer, in order
     pos: int                     # next cache write position (= tokens cached)
     generated: List[int] = field(default_factory=list)
     stats: RequestStats = field(default_factory=RequestStats)
@@ -48,6 +61,18 @@ class SlotState:
     chunks_done: int = 0
     shared_tokens: int = 0
     admit_seq: int = 0                # admission order (preemption ties)
+    #: the pages held of the further kinds of layer, a list a kind, and
+    #: per kind (the first included) the page index of its first held
+    #: page: 0 but under a window, where what lies behind is released
+    more_pages: List[List[int]] = field(default_factory=list)
+    first_page: List[int] = field(default_factory=lambda: [0])
+
+    def pages_of(self, kind: int) -> List[int]:
+        return self.pages if kind == 0 else self.more_pages[kind - 1]
+
+    @property
+    def held(self) -> int:
+        return len(self.pages) + sum(len(m) for m in self.more_pages)
 
 
 class Scheduler:
@@ -75,6 +100,12 @@ class Scheduler:
         self.pool = pool
         self.max_len = max_len
         self.max_pages = max_len // pool.page_size
+        #: kinds of layer (one page table and one page list a slot each)
+        self.kinds = len(pool.windows)
+        if prefix_cache is not None and pool.windowed:
+            raise NotImplementedError(
+                "the radix prefix cache is not built for layers that read "
+                "a window: their pages behind it are released")
         self.prefix_cache = prefix_cache
         self.lookahead = lookahead
         #: per-tenant admission caps (HETU_TPU_SERVE_QUOTAS); tenants
@@ -82,8 +113,12 @@ class Scheduler:
         self.quotas: Dict[str, TenantQuota] = dict(quotas or {})
         self.slots: List[Optional[SlotState]] = [None] * num_slots
         self.queue: Deque[Request] = collections.deque()
-        # the device-facing view: row s = slot s's pages, null-padded
-        self.page_table = np.zeros((num_slots, self.max_pages), np.int32)
+        # the device-facing view: row s = slot s's pages, null-padded;
+        # one table a kind of layer, and with one kind THE table
+        self.page_tables = np.zeros((self.kinds, num_slots, self.max_pages),
+                                    np.int32)
+        self.page_table = (self.page_tables[0] if self.kinds == 1
+                           else self.page_tables)
         self.admitted = 0
         self.released = 0
         self.preempted = 0
@@ -121,6 +156,105 @@ class Scheduler:
         sequence plus the spec-decode write lookahead."""
         return req.total_len + self.lookahead
 
+    def _span(self, req: Request, kind: int) -> Tuple[int, int]:
+        """(index of the first page, number of pages) an admission takes
+        of `kind`: every page of the reservation where the kind reads
+        everything; under a window w the pages from the one that holds
+        position prompt_len - w + 1 on, as many as a slot ever holds at
+        once."""
+        total = self.pool.pages_for(self._reserve_tokens(req))
+        w = self.pool.windows[kind]
+        if w is None:
+            return 0, total
+        first = max(0, req.prompt_len - w + 1) // self.pool.page_size
+        return first, min(total - first, self.pool.hold_pages(
+            self._reserve_tokens(req), kind))
+
+    def _take(self, req: Request, shared: int = 0
+              ) -> Optional[List[List[int]]]:
+        """The admission's fresh pages, a list a kind (`shared` of the
+        first kind's are resident already), or None with nothing taken
+        where any kind's free list is short."""
+        took: List[List[int]] = []
+        for kind in range(self.kinds):
+            n = self._span(req, kind)[1] - (shared if kind == 0 else 0)
+            pages = self.pool.alloc(n, kind)
+            if pages is None:
+                for k, got in enumerate(took):
+                    self.pool.free(got, k)
+                return None
+            took.append(pages)
+        return took
+
+    def _seat(self, slot_idx: int, st: SlotState):
+        """Point the slot's table rows at its pages."""
+        for kind in range(self.kinds):
+            row = self.page_tables[kind, slot_idx]
+            row[:] = PagePool.NULL_PAGE
+            pages, first = st.pages_of(kind), st.first_page[kind]
+            row[first: first + len(pages)] = pages
+
+    def advance(self, slot_idx: int) -> int:
+        """Before the slot's query at `pos`: release to the free list
+        every page of a WINDOW kind whose positions all lie behind
+        pos - window + 1 (its table entry becomes the null page), and
+        take as many of the pages the sequence grows into next, so that
+        the slot never holds more than it was admitted with.  Returns the
+        pages released; 0 for a model without window layers."""
+        st = self.slots[slot_idx]
+        released, ps = 0, self.pool.page_size
+        total = self.pool.pages_for(self._reserve_tokens(st.request))
+        for kind, w in enumerate(self.pool.windows):
+            if w is None:
+                continue
+            pages, first = st.pages_of(kind), st.first_page[kind]
+            n = min(max(0, st.pos - w + 1) // ps - first, len(pages))
+            if n <= 0:
+                continue
+            self.pool.free(pages[:n], kind)
+            end = first + len(pages)
+            new = self.pool.alloc(max(0, min(n, total - end)), kind)
+            row = self.page_tables[kind, slot_idx]
+            row[first: first + n] = PagePool.NULL_PAGE
+            row[end: end + len(new)] = new
+            pages[:] = pages[n:] + new
+            st.first_page[kind] = first + n
+            self.tenant_pages[st.request.tenant] -= n - len(new)
+            released += n
+        return released
+
+    def write_rows(self, slot_idx: int, skip_pages: int = 0):
+        """What the page-write program takes for a slot whose prefill is
+        complete (`PagePool.write_pages`): its table row, the first
+        `skip_pages` entries (a shared prefix, resident already) the
+        null page; with window layers one entry a kind: the row, or
+        under a window (the ids of the pages from `first` on that the
+        slot holds, first)."""
+        if not self.pool.windowed:
+            row = self.page_tables[0, slot_idx].copy()
+            row[:skip_pages] = PagePool.NULL_PAGE
+            return row
+        return self._window_rows(self.page_tables[:, slot_idx])
+
+    def _window_rows(self, rows):
+        out = []
+        for kind, w in enumerate(self.pool.windows):
+            row = rows[kind]
+            if w is None:
+                out.append(row.copy())
+                continue
+            n = self.pool.hold_pages(self.max_len, kind)
+            held = np.flatnonzero(row)
+            first = min(int(held[0]) if len(held) else 0, self.max_pages - n)
+            out.append((row[first: first + n].copy(), np.int32(first)))
+        return tuple(out)
+
+    def null_write_rows(self):
+        """`write_rows` of no slot: every entry the null page."""
+        if not self.pool.windowed:
+            return np.zeros(self.max_pages, np.int32)
+        return self._window_rows(np.zeros_like(self.page_tables[:, 0]))
+
     # ----------------------------------------------------------- queue
     def submit(self, req: Request):
         """Queue a request.  Rejects loudly what could NEVER run (a
@@ -132,12 +266,12 @@ class Scheduler:
                 f"request {req.rid}: prompt {req.prompt_len} + "
                 f"max_new {req.max_new_tokens}{extra} exceeds max_len "
                 f"{self.max_len}")
-        if self.pool.pages_for(self._reserve_tokens(req)) \
-                > self.pool.num_pages:
-            raise ValueError(
-                f"request {req.rid}: needs "
-                f"{self.pool.pages_for(self._reserve_tokens(req))} pages "
-                f"but the pool only has {self.pool.num_pages}")
+        for kind, have in enumerate(self.pool.pages_by_kind):
+            if self._span(req, kind)[1] > have:
+                raise ValueError(
+                    f"request {req.rid}: needs "
+                    f"{self._span(req, kind)[1]} pages "
+                    f"but the pool only has {have}")
         q = self.quotas.get(req.tenant)
         if q is not None and q.max_pages and \
                 self.pool.pages_for(self._reserve_tokens(req)) > q.max_pages:
@@ -199,38 +333,44 @@ class Scheduler:
                 # onto one physical page (caught by the regression
                 # test; released below if the admission still fails)
                 self.pool.incref(shared_pages)
-        need = self.pool.pages_for(self._reserve_tokens(req)) \
-            - len(shared_pages)
-        fresh = self.pool.alloc(need)
+        fresh = self._take(req, len(shared_pages))
         if fresh is None and self.prefix_cache is not None:
+            need = self.pool.pages_for(self._reserve_tokens(req)) \
+                - len(shared_pages)
             self.prefix_cache.evict(need - self.pool.free_count,
                                     require_free=True)
-            fresh = self.pool.alloc(need)
+            fresh = self._take(req, len(shared_pages))
         if fresh is None:
             if shared_pages:
                 self.pool.free(shared_pages)    # unpin the match
             self.last_stall = "no_pages"
             return None
-        pages = list(shared_pages) + fresh
         self.last_stall = None
         self.queue.popleft()
-        slot_idx = free[0]
+        st = self._seat_admission(
+            req, free[0], now, [list(shared_pages) + fresh[0]] + fresh[1:],
+            shared_tokens)
+        return free[0], st
+
+    def _seat_admission(self, req: Request, slot_idx: int, now: float,
+                        pages: List[List[int]],
+                        shared_tokens: int = 0) -> SlotState:
         self._admit_seq += 1
-        st = SlotState(request=req, pages=pages, pos=0,
+        st = SlotState(request=req, pages=pages[0], pos=0,
                        stats=RequestStats(arrival_t=req.arrival_t,
                                           admit_t=now),
                        shared_tokens=shared_tokens,
-                       admit_seq=self._admit_seq)
+                       admit_seq=self._admit_seq, more_pages=pages[1:],
+                       first_page=[self._span(req, k)[0]
+                                   for k in range(self.kinds)])
         st.stats.shared_prefix_tokens = shared_tokens
         self.slots[slot_idx] = st
-        row = self.page_table[slot_idx]
-        row[:] = PagePool.NULL_PAGE
-        row[: len(pages)] = pages
+        self._seat(slot_idx, st)
         self.admitted += 1
         t = req.tenant
         self.tenant_slots[t] = self.tenant_slots.get(t, 0) + 1
-        self.tenant_pages[t] = self.tenant_pages.get(t, 0) + len(pages)
-        return slot_idx, st
+        self.tenant_pages[t] = self.tenant_pages.get(t, 0) + st.held
+        return st
 
     def admit_direct(self, req: Request,
                      now: float) -> Optional[Tuple[int, SlotState]]:
@@ -259,31 +399,17 @@ class Scheduler:
         if not self._quota_admits(req):
             self.last_stall = "quota_exceeded"
             return None
-        need = self.pool.pages_for(self._reserve_tokens(req))
-        fresh = self.pool.alloc(need)
+        fresh = self._take(req)
         if fresh is None and self.prefix_cache is not None:
+            need = self.pool.pages_for(self._reserve_tokens(req))
             self.prefix_cache.evict(need - self.pool.free_count,
                                     require_free=True)
-            fresh = self.pool.alloc(need)
+            fresh = self._take(req)
         if fresh is None:
             self.last_stall = "no_pages"
             return None
         self.last_stall = None
-        slot_idx = free[0]
-        self._admit_seq += 1
-        st = SlotState(request=req, pages=fresh, pos=0,
-                       stats=RequestStats(arrival_t=req.arrival_t,
-                                          admit_t=now),
-                       admit_seq=self._admit_seq)
-        self.slots[slot_idx] = st
-        row = self.page_table[slot_idx]
-        row[:] = PagePool.NULL_PAGE
-        row[: len(fresh)] = fresh
-        self.admitted += 1
-        t = req.tenant
-        self.tenant_slots[t] = self.tenant_slots.get(t, 0) + 1
-        self.tenant_pages[t] = self.tenant_pages.get(t, 0) + len(fresh)
-        return slot_idx, st
+        return free[0], self._seat_admission(req, free[0], now, fresh)
 
     def _quota_admits(self, req: Request) -> bool:
         """Would admitting `req` keep its tenant within quota?  Checked
@@ -296,7 +422,7 @@ class Scheduler:
                 self.tenant_slots.get(req.tenant, 0) + 1 > q.max_slots:
             return False
         if q.max_pages:
-            need = self.pool.pages_for(self._reserve_tokens(req))
+            need = sum(self._span(req, k)[1] for k in range(self.kinds))
             if self.tenant_pages.get(req.tenant, 0) + need > q.max_pages:
                 return False
         return True
@@ -310,13 +436,14 @@ class Scheduler:
         st = self.slots[slot_idx]
         if st is None:
             raise ValueError(f"slot {slot_idx} is not live")
-        self.pool.free(st.pages)
+        for kind in range(self.kinds):
+            self.pool.free(st.pages_of(kind), kind)
         self.slots[slot_idx] = None
-        self.page_table[slot_idx, :] = PagePool.NULL_PAGE
+        self.page_tables[:, slot_idx, :] = PagePool.NULL_PAGE
         self.released += 1
         t = st.request.tenant
         self.tenant_slots[t] -= 1
-        self.tenant_pages[t] -= len(st.pages)
+        self.tenant_pages[t] -= st.held
 
     # ------------------------------------------------------- preemption
     def preempt_victim(self, priority: int) -> Optional[int]:
@@ -446,15 +573,13 @@ class Scheduler:
         * the shipment-dedupe books are coherent: no rid's applied-seq
           history holds a duplicate, and every applied seq is in the
           global seq set."""
-        owners: Dict[int, int] = {}
-        writers: Dict[int, List[int]] = {}   # slots holding p UNSHARED
         tslots: Dict[str, int] = {}
         tpages: Dict[str, int] = {}
         for i, st in enumerate(self.slots):
             if st is not None:
                 t = st.request.tenant
                 tslots[t] = tslots.get(t, 0) + 1
-                tpages[t] = tpages.get(t, 0) + len(st.pages)
+                tpages[t] = tpages.get(t, 0) + st.held
         if {k: v for k, v in self.tenant_slots.items() if v} != tslots:
             raise AssertionError(
                 f"tenant slot usage {self.tenant_slots} != scan {tslots}")
@@ -470,66 +595,8 @@ class Scheduler:
                 raise AssertionError(
                     f"tenant {t!r} holds {tpages[t]} pages over its "
                     f"quota {q.max_pages}")
-        for i, st in enumerate(self.slots):
-            if st is None:
-                if (self.page_table[i] != PagePool.NULL_PAGE).any():
-                    raise AssertionError(f"empty slot {i} has a non-null "
-                                         "table row")
-                continue
-            shared_pages = st.shared_tokens // self.pool.page_size
-            for j, p in enumerate(st.pages):
-                if p == PagePool.NULL_PAGE:
-                    raise AssertionError(f"slot {i} owns the null page")
-                owners[p] = owners.get(p, 0) + 1
-                if j >= shared_pages:
-                    writers.setdefault(p, []).append(i)
-            row = self.page_table[i]
-            want = st.pages + [PagePool.NULL_PAGE] * (self.max_pages
-                                                      - len(st.pages))
-            if list(row) != want:
-                raise AssertionError(f"slot {i} table row {list(row)} != "
-                                     f"pages {want}")
-            if st.pos > len(st.pages) * self.pool.page_size:
-                raise AssertionError(
-                    f"slot {i} position {st.pos} beyond its "
-                    f"{len(st.pages)}-page reservation")
-            if st.pos > self.max_len:
-                raise AssertionError(f"slot {i} position {st.pos} beyond "
-                                     f"max_len {self.max_len}")
-        for p, slots_w in writers.items():
-            # at most one slot may hold a page outside its shared
-            # prefix (the original allocator — the only legal writer);
-            # two writers would be genuine cache-corrupting aliasing
-            if len(slots_w) > 1:
-                raise AssertionError(
-                    f"page {p} aliased OUTSIDE a shared prefix by "
-                    f"slots {slots_w}")
-        if self.prefix_cache is not None:
-            for p in self.prefix_cache.owned_pages():
-                if p == PagePool.NULL_PAGE:
-                    raise AssertionError("prefix cache owns the null page")
-                owners[p] = owners.get(p, 0) + 1
-        free = self.pool._free
-        if len(set(free)) != len(free):
-            raise AssertionError("duplicate pages on the free list")
-        if PagePool.NULL_PAGE in free:
-            raise AssertionError("null page on the free list")
-        overlap = set(free) & set(owners)
-        if overlap:
-            raise AssertionError(f"pages both live and free: {overlap}")
-        if len(owners) + len(free) != self.pool.num_pages:
-            raise AssertionError(
-                f"pool leak: {len(owners)} live + {len(free)} free != "
-                f"{self.pool.num_pages} pages")
-        for p, n in owners.items():
-            if self.pool.refcount[p] != n:
-                raise AssertionError(
-                    f"page {p} refcount {self.pool.refcount[p]} != "
-                    f"{n} owners")
-        stray = [int(p) for p in range(1, self.pool.num_pages + 1)
-                 if self.pool.refcount[p] > 0 and p not in owners]
-        if stray:
-            raise AssertionError(f"refcounted pages with no owner: {stray}")
+        for kind in range(self.kinds):
+            self._check_kind(kind)
         slot_rids = [st.request.rid for st in self.slots
                      if st is not None]
         live_rids = set(slot_rids)
@@ -558,3 +625,74 @@ class Scheduler:
             raise AssertionError(
                 f"replica-loss requeues over the retry budget "
                 f"{self.retry_budget}: {over}")
+
+    def _check_kind(self, kind: int):
+        """The page books of ONE kind of layer (`check_invariants`)."""
+        owners: Dict[int, int] = {}
+        writers: Dict[int, List[int]] = {}   # slots holding p UNSHARED
+        for i, st in enumerate(self.slots):
+            if st is None:
+                if (self.page_tables[kind, i] != PagePool.NULL_PAGE).any():
+                    raise AssertionError(f"empty slot {i} has a non-null "
+                                         "table row")
+                continue
+            shared_pages = st.shared_tokens // self.pool.page_size
+            pages, first = st.pages_of(kind), st.first_page[kind]
+            if len(pages) > self.pool.hold_pages(self.max_len, kind):
+                raise AssertionError(
+                    f"slot {i} holds {len(pages)} pages of kind {kind}, "
+                    "over what a slot may hold at once")
+            for j, p in enumerate(pages):
+                if p == PagePool.NULL_PAGE:
+                    raise AssertionError(f"slot {i} owns the null page")
+                owners[p] = owners.get(p, 0) + 1
+                if j >= shared_pages:
+                    writers.setdefault(p, []).append(i)
+            row = self.page_tables[kind, i]
+            want = ([PagePool.NULL_PAGE] * first + pages
+                    + [PagePool.NULL_PAGE] * (self.max_pages - first
+                                              - len(pages)))
+            if list(row) != want:
+                raise AssertionError(f"slot {i} table row {list(row)} != "
+                                     f"pages {want}")
+            if st.pos > (first + len(pages)) * self.pool.page_size:
+                raise AssertionError(
+                    f"slot {i} position {st.pos} beyond its "
+                    f"{len(pages)}-page reservation")
+            if st.pos > self.max_len:
+                raise AssertionError(f"slot {i} position {st.pos} beyond "
+                                     f"max_len {self.max_len}")
+        for p, slots_w in writers.items():
+            # at most one slot may hold a page outside its shared
+            # prefix (the original allocator — the only legal writer);
+            # two writers would be genuine cache-corrupting aliasing
+            if len(slots_w) > 1:
+                raise AssertionError(
+                    f"page {p} aliased OUTSIDE a shared prefix by "
+                    f"slots {slots_w}")
+        if self.prefix_cache is not None and kind == 0:
+            for p in self.prefix_cache.owned_pages():
+                if p == PagePool.NULL_PAGE:
+                    raise AssertionError("prefix cache owns the null page")
+                owners[p] = owners.get(p, 0) + 1
+        free = self.pool.lists[kind]._free
+        if len(set(free)) != len(free):
+            raise AssertionError("duplicate pages on the free list")
+        if PagePool.NULL_PAGE in free:
+            raise AssertionError("null page on the free list")
+        overlap = set(free) & set(owners)
+        if overlap:
+            raise AssertionError(f"pages both live and free: {overlap}")
+        if len(owners) + len(free) != self.pool.pages_by_kind[kind]:
+            raise AssertionError(
+                f"pool leak: {len(owners)} live + {len(free)} free != "
+                f"{self.pool.pages_by_kind[kind]} pages")
+        for p, n in owners.items():
+            if self.pool.lists[kind].refcount[p] != n:
+                raise AssertionError(
+                    f"page {p} refcount {self.pool.lists[kind].refcount[p]} != "
+                    f"{n} owners")
+        stray = [int(p) for p in range(1, self.pool.pages_by_kind[kind] + 1)
+                 if self.pool.lists[kind].refcount[p] > 0 and p not in owners]
+        if stray:
+            raise AssertionError(f"refcounted pages with no owner: {stray}")

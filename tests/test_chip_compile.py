@@ -332,12 +332,25 @@ def _kimi_block():
                                  prefill_chunk=512)
 
 
+def _trinity_block():
+    """Trinity-Mini at published widths, 16 of 128 experts held, two
+    dense layers and one whole period of expert layers (three that read
+    a window of 2,048 and one that reads everything), at the serving
+    cell's slots, page, chunk and max_len, pages by kind of layer."""
+    from hetu_tpu.models.trinity import TrinityConfig, TrinityLMHeadModel
+    return TrinityLMHeadModel(TrinityConfig(
+        vocab_size=25024, num_hidden_layers=8, experts_held=16,
+        param_dtype=BF16)), dict(num_slots=32, page_size=16, max_len=8192,
+                                 prefill_chunk=512, num_pages=(4000, 3700))
+
+
 #: family -> ((model, ServeConfig overrides) or None for the Llama-2-7B
 #: block, the kernels its decode program must hold as tpu_custom_calls)
 SERVING_FAMILIES = {
     "llama": (None, ("paged_attn", "rotary", "swiglu")),
     "gpt": (_gpt_block, ("paged_attn",)),
     "kimi": (_kimi_block, ("paged_latent",)),
+    "trinity": (_trinity_block, ("paged_attn",)),
 }
 
 
@@ -364,6 +377,20 @@ def test_serving_programs_compile_for_one_v5e(family):
         scope = KERNEL_SCOPES.get(k, f"pallas_{k}_attention")
         assert any(scope in ln for ln in calls), (k, len(calls))
         assert routes[k]["pallas"] and not routes[k]["xla"], routes
+    if family == "trinity":
+        # both kinds of layer decode through the kernel, each under its
+        # own name; no program copies a pool (a scatter of whole pages
+        # at 4 KV heads a row did: serving/kv_pool._write_pages_kinds)
+        # or holds the float32 scores of all heads at once
+        assert routes["paged_attn_window"]["pallas"] == 6
+        assert sum("pallas_paged_attention_window" in ln for ln in calls) \
+            == 6 and len(calls) >= 8
+        pool = sum(a.size * a.dtype.itemsize
+                   for a in engine.pool.arrays.tree())
+        temps = {name: c.memory_analysis().temp_size_in_bytes
+                 for name, c in compiled.items()}
+        assert temps["write_pages"] < 1e6 and temps["decode"] < pool / 20
+        assert temps["prefill_chunk"] < 0.4e9, temps
 
 
 @pytest.mark.parametrize("kv_quant", ["none", "int8"])

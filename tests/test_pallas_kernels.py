@@ -213,7 +213,7 @@ def test_quantize_dispatcher_forced_loud(monkeypatch):
         np.asarray((q.astype(jnp.float32) * s[:, None]).reshape(-1)))
 
 
-def _dense_paged_reference(q, kp, vp, table, positions):
+def _dense_paged_reference(q, kp, vp, table, positions, window=None):
     S, nq, hd = q.shape
     _, ps, n_kv, _ = kp.shape
     mp = table.shape[1]
@@ -228,6 +228,8 @@ def _dense_paged_reference(q, kp, vp, table, positions):
         s = jnp.einsum("qd,kqd->qk", q[si],
                        kg.reshape(M, nq, hd)) * hd ** -0.5
         mask = jnp.arange(M) <= positions[si]
+        if window is not None:
+            mask = mask & (jnp.arange(M) > positions[si] - window)
         s = jnp.where(mask[None, :], s, -1e30)
         p_ = jax.nn.softmax(s, axis=-1)
         outs.append(jnp.einsum("qk,kqd->qd", p_, vg.reshape(M, nq, hd)))
@@ -485,6 +487,123 @@ def test_paged_walk_reads_nothing_past_a_slots_length(kernel, monkeypatch):
         np.asarray(clean),
         np.asarray(ref(q, jnp.asarray(kp), jnp.asarray(vp), table,
                        positions)), atol=FWD_TOL)
+
+
+# a WINDOW (PR 34): the walk begins at the block that holds the window's
+# first position.  (q heads, kv heads, page size, table width, tokens a
+# block holds, tokens each slot holds, window, pool dtype).  The cases of
+# `_WALK_CASES` above are `window=None`, unchanged.
+_WINDOW_CASES = {
+    # g = 1, 4, 8; a block is 3 pages of 8
+    "g1_window_inside_one_block": (4, 4, 8, 8, 24, [21, 5, 38], 6,
+                                   "float32"),
+    "g4_window_starts_mid_page": (32, 8, 8, 8, 24, [21, 1, 60], 19,
+                                  "float32"),
+    "g8_window_starts_mid_page": (32, 4, 8, 8, 24, [21, 1, 60], 19,
+                                  "float32"),
+    # position 47, window 24: first position 24 = the first row of block 1
+    "g8_window_starts_on_a_block": (32, 4, 8, 9, 24, [48, 72, 30], 24,
+                                    "float32"),
+    # first and last block differ, with whole blocks between them
+    "g8_window_over_several_blocks": (32, 4, 8, 16, 24, [128, 100, 77], 70,
+                                      "float32"),
+    "g8_window_of_one_position": (32, 4, 8, 8, 24, [21, 1, 60], 1,
+                                  "float32"),
+    "g8_window_wider_than_any_context": (32, 4, 8, 8, 24, [21, 1, 60], 500,
+                                         "float32"),
+    "g8_one_page_a_block": (32, 4, 8, 8, 8, [21, 1, 64], 13, "float32"),
+    # the cell's own: 16-token pages, the module's block, bfloat16
+    "g8_the_cells_shape_bfloat16": (32, 4, 16, 40, None,
+                                    [300, 1, 257, 640], 200, "bfloat16"),
+}
+
+
+def _window_inputs(case):
+    nq, n_kv, ps, mp, block_tokens, lengths, window, dtype = \
+        _WINDOW_CASES[case]
+    _WALK_CASES["_window"] = (nq, n_kv, ps, mp, block_tokens, lengths,
+                              dtype, True)
+    try:
+        return _walk_inputs("_window", seed=17) + (window,)
+    finally:
+        del _WALK_CASES["_window"]
+
+
+@pytest.mark.parametrize("case", _WINDOW_CASES)
+def test_paged_attention_window_parity(case, monkeypatch):
+    """The walk under a window against the dense gather+mask
+    composition; what lies wholly before the window is the null page in
+    the table, as the scheduler leaves it."""
+    q, kp, vp, table, positions, block_tokens, window = _window_inputs(case)
+    if block_tokens is not None:
+        monkeypatch.setattr(paged_attention, "_BLOCK_TOKENS", block_tokens)
+    ps = kp.shape[1]
+    released = np.asarray(table).copy()
+    for s, pos in enumerate(np.asarray(positions)):
+        released[s, : max(pos - window + 1, 0) // ps] = 0
+    out = paged_attention.paged_attention(q, kp, vp, jnp.asarray(released),
+                                          positions, window=window)
+    f32 = jnp.float32
+    ref = _dense_paged_reference(q.astype(f32), kp.astype(f32),
+                                 vp.astype(f32), table, positions, window)
+    assert out.dtype == q.dtype
+    tol = FWD_TOL if kp.dtype == f32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out.astype(f32)),
+                               np.asarray(ref), atol=tol)
+
+
+def test_paged_walk_under_a_window_reads_nothing_before_it(monkeypatch):
+    """Every page wholly before a slot's window, the part of the
+    window's first page that precedes it, and everything past the slot's
+    length are NaN: the result is what it was, and finite."""
+    monkeypatch.setattr(paged_attention, "_BLOCK_TOKENS", 24)
+    rng = np.random.default_rng(19)
+    S, ps, mp, n_kv, nq, hd, window = 4, 8, 12, 4, 32, 128, 29
+    P = S * mp + 1
+    table = rng.permutation(np.arange(1, P)).reshape(S, mp).astype(np.int32)
+    lengths = np.asarray([21, 1, 90, 58])
+    kp = rng.standard_normal((P, ps, n_kv, hd), dtype=np.float32)
+    vp = rng.standard_normal((P, ps, n_kv, hd), dtype=np.float32)
+    kn, vn = kp.copy(), vp.copy()
+    for s, n in enumerate(lengths):
+        first = max(n - window, 0)              # the window's first position
+        for slot in range(mp):
+            for x in (kn, vn):
+                page = x[table[s, slot]]
+                at = slot * ps + np.arange(ps)
+                page[(at < first) | (at >= n)] = np.nan
+    assert np.isnan(kn).sum() > kn.size // 2
+    positions, table = jnp.asarray(lengths - 1, jnp.int32), jnp.asarray(table)
+    q = jnp.asarray(rng.standard_normal((S, nq, hd), dtype=np.float32))
+    clean = paged_attention.paged_attention(
+        q, jnp.asarray(kp), jnp.asarray(vp), table, positions, window=window)
+    poisoned = paged_attention.paged_attention(
+        q, jnp.asarray(kn), jnp.asarray(vn), table, positions, window=window)
+    assert np.isfinite(np.asarray(poisoned)).all()
+    np.testing.assert_array_equal(np.asarray(poisoned), np.asarray(clean))
+    np.testing.assert_allclose(
+        np.asarray(clean), np.asarray(_dense_paged_reference(
+            q, jnp.asarray(kp), jnp.asarray(vp), table, positions, window)),
+        atol=FWD_TOL)
+
+
+@pytest.mark.parametrize("window,quant", [(8, "none"), (1, "none"),
+                                          (0, "none"), (8, "int8")])
+def test_gate_drift_paged_window(window, quant):
+    """`compatible` under a window says what the kernel does: a window of
+    at least one position over exact pages."""
+    qs, pool_s, ts, pos_s = (3, 4, 128), (9, 8, 2, 128), (3, 4), (3,)
+    q = jnp.zeros(qs, jnp.float32)
+    kp = jnp.zeros(pool_s, jnp.int8 if quant == "int8" else jnp.float32)
+    scales = ({"k_scale": jnp.ones(pool_s[:3]),
+               "v_scale": jnp.ones(pool_s[:3])} if quant == "int8" else {})
+    takes = paged_attention.compatible(qs, pool_s, ts, pos_s, quant=quant,
+                                       window=window)
+    assert takes == (window >= 1 and quant == "none")
+    assert takes == _accepts(
+        lambda *a: paged_attention.paged_attention(*a, window=window,
+                                                   **scales),
+        q, kp, kp, jnp.zeros(ts, jnp.int32), jnp.zeros(pos_s, jnp.int32))
 
 
 # ---------------------------------------------------------------------------

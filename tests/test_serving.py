@@ -467,11 +467,66 @@ class _HooksOnlyFamily:
         return self.logits(params, self.final_hidden(params, x))[0]
 
 
+class _HooksWindowFamily(_HooksOnlyFamily):
+    """`_HooksOnlyFamily` with layers that differ in how far back they
+    read, written by the hooks alone: the first layer (its own arrays)
+    reads everything, the two STACKED layers read the last `WINDOW`
+    positions (`block.window`, and the contract's `windows`): a scanned
+    run of window layers over pages of their own kind."""
+
+    WINDOW = 12
+
+    def __init__(self, head_dim=16):
+        super().__init__(head_dim)
+        self.window_block = self.Block(self.config)
+        self.window_block.window = self.WINDOW
+        self.window_block.attn_scope = "attn_window"
+
+    def cache_contract(self):
+        from hetu_tpu.models.cache_contract import kv_contract
+        c = self.config
+        return kv_contract(c.num_hidden_layers, c.num_key_value_heads,
+                           c.head_dim, c.compute_dtype,
+                           windows=(None, self.WINDOW, self.WINDOW))
+
+    def serving_layers(self, params):
+        return [(self.block, params["first"], None),
+                (self.window_block, params["stack"], 2)]
+
+    def forward(self, params, ids):
+        c, s = self.config, ids.shape[0]
+        g = c.num_attention_heads // c.num_key_value_heads
+        rope = self.rope_tables(s)
+        pos = jnp.arange(s, dtype=jnp.int32)[None]
+        x = params["embed"][ids][None]
+        layers = [params["first"]] + [
+            jax.tree.map(lambda a: a[i], params["stack"]) for i in range(2)]
+        t, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+        for i, lp in enumerate(layers):
+            q, (k, v) = self.block.attn.project(
+                lp["attn"], self.Block.input_norm(lp["input_norm"], x),
+                rope, pos)
+            k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
+            sc = jnp.einsum("bqnd,bknd->bnqk", q, k) * c.head_dim ** -0.5
+            seen = (j <= t) & ((j > t - self.WINDOW) if i else True)
+            sc = jnp.where(seen, sc, -jnp.inf)
+            a = jnp.einsum("bnqk,bknd->bqnd", jax.nn.softmax(sc, -1), v)
+            x = x + a.reshape(1, s, -1) @ lp["attn"]["wo"]
+            x = x + self.block.mlp_stats(
+                lp["mlp"], self.Block.input_norm(lp["post_norm"], x))[0]
+        return self.logits(params, self.final_hidden(params, x))[0]
+
+
 def _family_case(family, hd128):
     """(model, params, dense: (params, ids [s]) -> logits [s, vocab])."""
-    if family == "hooks":
-        model = _HooksOnlyFamily(128 if hd128 else 16)
+    if family in ("hooks", "hooks-window"):
+        model = (_HooksOnlyFamily if family == "hooks"
+                 else _HooksWindowFamily)(128 if hd128 else 16)
         return model, model.init(jax.random.key(3)), model.forward
+    if family == "trinity":
+        from test_trinity import build, ref_logits
+        cfg, model, params = build(head_dim=128 if hd128 else 16)
+        return model, params, lambda p, ids: ref_logits(p, cfg, ids)
     if family == "kimi":
         from test_kimi_k2 import build, ref_logits
         cfg, model, params = build()
@@ -497,7 +552,9 @@ def _family_case(family, hd128):
     ("hooks", "gather"), ("hooks", "paged"),
     ("llama", "gather"), ("llama", "paged"), ("llama-unstacked", "gather"),
     ("gpt", "gather"), ("gpt", "paged"),
-    ("kimi", "xla"), ("kimi", "kernel")])
+    ("kimi", "xla"), ("kimi", "kernel"),
+    ("hooks-window", "gather"), ("hooks-window", "paged"),
+    ("trinity", "gather"), ("trinity", "paged")])
 def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
     """Golden, ONE body for every family: staggered continuous batching
     through the normal path (`run`: scheduler, allocator, page tables,
@@ -513,7 +570,11 @@ def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
     `decode_step_paged`); kimi over its two attentions under the one
     paged program.  `hooks` is a K/V family defined HERE, outside the
     package, by the hooks alone: adding an architecture is new files
-    only.  `llama-unstacked` is the Llama block built with
+    only.  `hooks-window` is that family with two scanned layers that
+    read a window of 12 positions beside one that reads everything, and
+    `trinity` the package's own (window and full layers, each with its
+    own arrays): pages by kind of layer, released behind the window
+    while the request decodes, over both routes.  `llama-unstacked` is the Llama block built with
     use_scan=False: a layer's own arrays, called, never scanned."""
     monkeypatch.setenv("HETU_TPU_PALLAS",
                        "1" if route in ("paged", "kernel") else "0")
@@ -552,6 +613,10 @@ def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
     eng.scheduler.check_invariants()
     assert eng.pool.free_count == eng.pool.num_pages
     assert reg.counter_value("serve.decode_context_tokens") > 0
+    if eng.windowed:
+        assert reg.counter_value("serve.window_pages_released") > 0
+        assert 0 < reg.counter_value("serve.decode_window_context_tokens") \
+            < reg.counter_value("serve.decode_context_tokens")
     if model.STATS:
         # the programs' stats came back with the tokens
         n = {k[len("serve.moe_"):]: reg.counter_value(k)

@@ -161,8 +161,28 @@ class KVAttention:
         queries (the chunk program) read the window + C positions that
         end with the chunk and no more; rows at positions of their own
         (the gather decode route) read by the window's mask.
-        -> [b, C, n_q * hd]."""
+
+        Which shapes take which attention (the route record
+        `kernel_routes["chunk_attn"]` says it per traced layer): ONE
+        row's chunk of C > 1 queries at one start, the chunk program of
+        chunked prefill, takes the blockwise kernel
+        (ops/pallas/chunk_attention: the scores stay on the chip, and
+        only the key blocks the chunk can see are read) where
+        `ops.pallas.resolve_route` and the kernel's gate allow: a TPU,
+        head_dim % 128, C a multiple of the sublane tile, a cache length
+        that divides into key blocks of a multiple of 128, and at least
+        64 MB of float32 scores in the composition (heads x C x cache
+        positions: under that the two tie on a v5e and the composition
+        stays, as at InternLM2's chunk of 128 over 2,048 positions).
+        Everything else keeps the XLA composition
+        `models/generation._attend_cached_chunk`: any shape the gate
+        refuses, every backend but a TPU, a single query (C = 1: the
+        decode step over a dense cache), and rows at depths of their own
+        (start [b > 1]: the gather decode route, the verify step), which
+        no flag forces.  -> [b, C, n_q * hd]."""
         from hetu_tpu.models.generation import _attend_cached_chunk
+        from hetu_tpu.ops.pallas import chunk_attention as _ca
+        from hetu_tpu.ops.pallas import _note_route, resolve_route
         b, C, nq, hd = q.shape
         M, first = caches[0].shape[1], 0
         if window is not None and b == 1 and window + C < M:
@@ -172,9 +192,24 @@ class KVAttention:
             first = jnp.clip(jnp.reshape(start, ()) + C - R, 0, M - R)
             caches = tuple(lax.dynamic_slice_in_dim(c, first, R, axis=1)
                            for c in caches)
-        return _attend_cached_chunk(q, *caches, start, hd ** -0.5,
-                                    window=window, first=first) \
-            .reshape(b, C, nq * hd)
+        if b == 1 and C > 1:
+            kernel = resolve_route(
+                "chunk_attn", _ca.check_route, q.shape, caches[0].shape,
+                jnp.shape(start), window=window, dtype=caches[0].dtype)
+        else:
+            kernel = False
+            _note_route("chunk_attn", False,
+                        "a single query, or rows at depths of their own: "
+                        "the composition")
+        if kernel:
+            with jax.named_scope("pallas_chunk_attention"):
+                out = _ca.chunk_attention(q, *caches, start,
+                                          softmax_scale=hd ** -0.5,
+                                          window=window, first=first)
+        else:
+            out = _attend_cached_chunk(q, *caches, start, hd ** -0.5,
+                                       window=window, first=first)
+        return out.reshape(b, C, nq * hd)
 
     def attend_prompt(self, params, q, entries, window=None):
         """Whole prompts attending their own entries, causally: the

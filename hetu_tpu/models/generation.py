@@ -115,7 +115,18 @@ def _attend_cached_chunk(q, ck, cv, start, scale, window=None, first=0):
     the positions first..first+M-1 (`first` = 0: the whole cache; a
     window layer's chunk hands in the slice it reads:
     cache_contract.KVAttention.attend_dense).  Same grouped-GQA
-    contraction as `_attend_cached`."""
+    contraction as `_attend_cached`.
+
+    THE XLA composition of attention over a dense cache, and the
+    reference of the blockwise kernel (ops/pallas/chunk_attention) that
+    `attend_dense` routes ONE row's chunk of C > 1 queries to on a TPU:
+    what stays here is every other backend, every shape the kernel's
+    gate refuses (head_dim % 128, a chunk or a cache length that does
+    not tile), a single query (`_attend_cached`, the decode step over a
+    dense cache), rows at depths of their own (start [b > 1]: the
+    gather decode route, the verify step) and whole prompts under a
+    window (`attend_prompt`).  It forms the float32 scores of every
+    position it is handed, in HBM."""
     b, M, n_kv, hd = ck.shape
     C, nq = q.shape[1], q.shape[2]
     group = nq // n_kv

@@ -19,6 +19,9 @@ The fused-kernel layer (docs/kernels.md):
   * paged_latent_attention — the paged decode kernel of latent (MLA)
                        attention: one pool of [c_kv | k_rope] vectors,
                        each page read once and used as key and value
+  * chunk_attention  — the chunk program's attention over a dense K/V
+                       cache (chunked prefill): blockwise, online
+                       softmax, only the key blocks a chunk can see
   * sample           — fused last-layer epilogue: lm_head matmul +
                        temperature/top-k/top-p filter + Gumbel draw per
                        row without materializing [rows, vocab] logits
@@ -50,7 +53,8 @@ from typing import FrozenSet, Optional, Tuple
 
 #: every routable kernel name (the HETU_TPU_PALLAS_KERNELS vocabulary)
 KERNEL_NAMES = ("flash", "norm", "swiglu", "rotary", "quant", "paged_attn",
-                "paged_verify", "sample", "adam", "paged_latent")
+                "paged_verify", "sample", "adam", "paged_latent",
+                "chunk_attn")
 
 
 def _interpret() -> bool:

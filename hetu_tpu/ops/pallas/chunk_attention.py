@@ -1,0 +1,331 @@
+"""Chunk attention: the attention of the chunk program (chunked prefill)
+for a K/V cache, blockwise, with its scores on the chip.
+
+One row's chunk of C queries at positions start .. start + C - 1 attends
+the dense cache it has just been written into (`models/generation.
+extend_cache`, through `cache_contract.KVAttention.attend_dense`).  The
+XLA composition (`models/generation._attend_cached_chunk`) forms the
+float32 scores of EVERY position of the cache it is handed, in HBM, and
+crosses them several times; that was 63% of the Trinity cell's chunk
+program (PERF.md s6, PR 35).  Here:
+
+* **the keys are walked in blocks with an online softmax** (the float32
+  running max, sum and accumulator of the flash forward, in VMEM): no
+  score goes to HBM;
+* **only the key blocks a chunk can see are fetched and multiplied.**
+  The first and the number of live blocks are scalar-prefetched from the
+  chunk's traced `start`: from the block that holds position
+  `max(0, start - window + 1)` (a window layer) or from block 0, up to
+  the block that holds `start + C - 1`.  The key index map CLAMPS to the
+  last live block, so a grid step past it names the block the step
+  before already holds and moves no bytes; its arithmetic is skipped by
+  `pl.when`.  What such a step costs is the grid step itself (~0.35 us
+  on a v5e).  A full layer over a scratch of `max_len` positions pays
+  for the prompt's length, not for `max_len`;
+* **the mask inside a block is by global positions**, `_attend_cached_
+  chunk`'s rule: key k is seen by the query at position t iff k <= t
+  and, under a window, k > t - window.  A block every entry of which is
+  seen by every row of the tile (the cached prefix, inside the window)
+  takes no mask at all;
+* **q, K and V are multiplied in the cache's dtype** (bfloat16 in
+  serving) with float32 accumulation, the probabilities cast to V's
+  dtype for p.v as the paged kernels do; the softmax statistics are
+  float32;
+* **one KV head's whole group of query heads** is one tall operand: q
+  is laid out `[n_kv, g * C, hd]`, row gi * C + c the query of head
+  (kvh, gi) at position start + c, and a row tile of it (up to
+  `_ROW_TILE` rows) meets each key block `[kb, hd]` of its KV head in
+  one MXU product.
+
+The grid is (KV heads, row tiles, key blocks), the key blocks innermost
+and in order.  K and V come head-major, `[n_kv, M, hd]`, and a layer's
+slab is `[M, n_kv, hd]`: Mosaic's DMA slices no single head out of a
+packed bfloat16 `[n_kv, hd]` tile, so the slab is relaid first, by a
+small kernel of this module (`_relay_heads`: a block of positions in,
+each head's rows out, the LIVE blocks only; a load `ref[:, h, :]` is what
+Mosaic does take).  Left to XLA as a `transpose`, the relayout became a
+bitcast of a head-major COPY OF THE WHOLE SCRATCH of every layer (84 MB,
+four times a Trinity chunk: compiled for a described v5e and measured,
+PR 35), because a slice at a constant layer index hands its layout up to
+what it slices.
+
+Shape contract (`check_shapes`, drift-tested against `compatible`): ONE
+row (b = 1) at ONE start, C > 1 queries, C a multiple of the sublane
+tile of the dtype, hd a multiple of 128 lanes, q heads a multiple of the
+KV heads, and a cache length M that `fit_block` divides into key blocks
+of a multiple of 128 (a cache shorter than that is one block, if a
+multiple of the sublane tile).  Rows at depths of their own (the verify
+step, the gather decode route) and single queries are refused: they keep
+the composition.  The ROUTE's gate (`check_route`) also refuses what the
+kernel takes but does not pay for: fewer than 64 MB of float32 scores in
+the composition.  Forward only (serving); no vjp.
+"""
+from __future__ import annotations
+
+import functools
+import math
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from hetu_tpu.ops.pallas import _interpret
+from hetu_tpu.ops.pallas.flash_attention import fit_block
+
+NEG_INF = -1e30
+
+# Block sizes, from the shapes alone.  A key block of up to 1,024
+# positions and a row tile of up to 1,024 query rows (PERF.md s6, PR 35:
+# the sweep on a v5e at the serving cells' shapes): the float32 score tile
+# is 4 MB, and a grid step's products (0.54 GFLOP) outweigh its ~0.35 us
+# and the accumulator's rescaling.
+_KEY_BLOCK = 1024
+_ROW_TILE = 1024
+_VMEM_LIMIT = 48 << 20
+#: float32 score bytes of the composition from which the route takes the
+#: kernel (`check_route`)
+_MIN_SCORE_BYTES = 64 << 20
+#: bytes of one array's block of positions in the relayout kernel
+_RELAY_BLOCK_BYTES = 1 << 20
+
+
+def _row_tile(C: int, group: int) -> int:
+    """Rows of `[group * C]` a grid step takes: whole chunks of C rows
+    (as many query heads of the group as `_ROW_TILE` holds) or, for a
+    chunk longer than that, its largest divisor within it that keeps the
+    sublane tiling (a multiple of 16).  Either way a tile's rows are at
+    consecutive positions modulo C."""
+    if C <= _ROW_TILE:
+        heads = max(m for m in range(1, group + 1)
+                    if group % m == 0 and m * C <= _ROW_TILE)
+        return heads * C
+    t = _ROW_TILE - _ROW_TILE % 16
+    while t and C % t:
+        t -= 16
+    return t
+
+
+def check_shapes(q_shape, k_shape, start_shape=(), *, window=None,
+                 dtype=None) -> Tuple[int, int, int, int, int, int, int]:
+    """-> (C, nq, hd, M, n_kv, row tile, key block), or ValueError with
+    the reason the composition takes the shape instead."""
+    if len(q_shape) != 4 or len(k_shape) != 4:
+        raise ValueError(f"expected q [b, C, nq, hd] and k [b, M, n_kv, "
+                         f"hd], got {q_shape} / {k_shape}")
+    b, C, nq, hd = q_shape
+    _, M, n_kv, hd_k = k_shape
+    if b != 1 or k_shape[0] != 1 or math.prod(start_shape) != 1:
+        raise ValueError(f"{b} rows at starts {tuple(start_shape)}: the "
+                         f"kernel takes ONE row's chunk at one start; rows "
+                         f"at depths of their own keep the composition")
+    if C == 1:
+        raise ValueError("C = 1: a single query has no chunk to block; "
+                         "the composition (or the paged kernel) takes it")
+    if hd_k != hd or hd % 128:
+        raise ValueError(f"head dim {hd} (cache {hd_k}) is not "
+                         f"lane-aligned (% 128)")
+    if nq % n_kv:
+        raise ValueError(f"q heads {nq} must divide by kv heads {n_kv}")
+    if window is not None and window < 1:
+        raise ValueError(f"a window ({window}) is at least one position "
+                         f"wide")
+    sub = 32 // (jnp.dtype(dtype).itemsize if dtype is not None else 2)
+    tr = _row_tile(C, nq // n_kv)
+    if C % sub or not tr:
+        raise ValueError(f"a chunk of C = {C} rows does not tile by the "
+                         f"{sub} sublanes of the dtype")
+    kb = fit_block(_KEY_BLOCK, M)
+    if kb % 128 and (M > 128 or M % sub):
+        raise ValueError(f"cache length {M} has no key block that is a "
+                         f"multiple of 128 (best: {kb})")
+    return C, nq, hd, M, n_kv, tr, kb
+
+
+def check_route(q_shape, k_shape, start_shape=(), *, window=None,
+                dtype=None):
+    """The gate `attend_dense` hands to `resolve_route`: the shapes the
+    kernel takes (`check_shapes`) AND for which it pays.  What the
+    composition pays for is the float32 scores of every position it is
+    handed, crossed in HBM several times; the kernel pays two launches
+    and the relayouts of q, K, V and the output whatever the size.  On a
+    v5e they tie at InternLM2's chunk (16 query heads x 128 x 2,048
+    positions = 16.8 MB of scores: 0.09-0.11 ms a layer either way, and
+    the long-prompt cell read 1.6-4.8% slower with the kernel), and the
+    kernel wins 2.7-14 times at Trinity's (168 and 537 MB): PERF.md s6,
+    PR 35.  Forced flags ask neither."""
+    out = check_shapes(q_shape, k_shape, start_shape, window=window,
+                       dtype=dtype)
+    score_bytes = 4 * q_shape[1] * q_shape[2] * k_shape[1]
+    if score_bytes < _MIN_SCORE_BYTES:
+        raise ValueError(
+            f"{q_shape[2]} heads x {q_shape[1]} queries x {k_shape[1]} "
+            f"positions are {score_bytes >> 20} MB of float32 scores, under "
+            f"the {_MIN_SCORE_BYTES >> 20} MB from which the kernel pays: "
+            f"the composition keeps them")
+    return out
+
+
+def compatible(q_shape, k_shape, start_shape=(), *, window=None,
+               dtype=None) -> bool:
+    try:
+        check_shapes(q_shape, k_shape, start_shape, window=window,
+                     dtype=dtype)
+        return True
+    except ValueError:
+        return False
+
+
+def live_blocks(start, C: int, M: int, kb: int, window=None, first=0):
+    """(first live key block, number of live key blocks) of a cache that
+    holds positions first .. first + M - 1, for a chunk at `start`: the
+    blocks from the one that holds position max(0, start - window + 1)
+    (block 0 without a window) to the one that holds start + C - 1, both
+    kept inside the cache."""
+    lo = 0 if window is None else jnp.clip(start - window + 1 - first,
+                                           0, M - 1) // kb
+    hi = jnp.clip(start + C - 1 - first, 0, M - 1) // kb
+    return lo, hi - lo + 1
+
+
+def _kernel(s_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+            scale, window, C, tr, kb):
+    r, j = pl.program_id(1), pl.program_id(2)
+    b0, live, q0 = s_ref[0], s_ref[1], s_ref[2]
+    # the tile's rows sit at positions q0 + c_lo .. q0 + c_hi of the
+    # cache as it was handed in (q0 = start - first)
+    c_lo = (r * tr) % C if tr < C else 0
+    c_hi = c_lo + min(tr, C) - 1
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def update(masked: bool):
+        q, k, v = q_ref[...], k_ref[...], v_ref[...]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if masked:
+            i = jax.lax.broadcasted_iota(jnp.int32, (tr, 1), 0)
+            qpos = q0 + (jax.lax.rem(i, C) if tr > C else c_lo + i)
+            kpos = (b0 + j) * kb + jax.lax.broadcasted_iota(
+                jnp.int32, (1, kb), 1)
+            seen = kpos <= qpos
+            if window is not None:
+                seen = seen & (kpos > qpos - window)
+            s = jnp.where(seen, s, NEG_INF)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # a row none of whose keys it has seen yet holds m = NEG_INF and
+        # p = 1 for them: the first seen key's correction (exp(NEG_INF -
+        # m) = 0) wipes that, and every row sees its own position
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
+
+    k_lo = (b0 + j) * kb
+    clear = k_lo + kb - 1 <= q0 + c_lo
+    if window is not None:
+        clear = clear & (k_lo > q0 + c_hi - window)
+    pl.when((j < live) & clear)(lambda: update(False))
+    pl.when((j < live) & jnp.logical_not(clear))(lambda: update(True))
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _fin():
+        l = l_scr[...]
+        o_ref[...] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)) \
+            .astype(o_ref.dtype)
+
+
+def _relay_heads(scalars, k, v, kb: int):
+    """k, v [M, n_kv, hd] -> each [n_kv, M, hd], the key blocks
+    scalars[0] .. scalars[0] + scalars[1] - 1 (of `kb` positions) only:
+    what lies outside them is not read, and not written either."""
+    M, n_kv, hd = k.shape
+    rb = kb
+    while rb % 2 == 0 and rb * n_kv * hd * k.dtype.itemsize \
+            > _RELAY_BLOCK_BYTES:
+        rb //= 2
+    per = kb // rb          # relayout blocks a key block
+
+    def kernel(s_ref, k_ref, v_ref, ko_ref, vo_ref):
+        @pl.when(pl.program_id(0) < s_ref[1] * per)
+        def _():
+            for h in range(n_kv):
+                ko_ref[h] = k_ref[:, h, :]
+                vo_ref[h] = v_ref[:, h, :]
+
+    def block(j, s):
+        return s[0] * per + jnp.minimum(j, s[1] * per - 1)
+
+    ins = pl.BlockSpec((rb, n_kv, hd), lambda j, s: (block(j, s), 0, 0))
+    outs = pl.BlockSpec((n_kv, rb, hd), lambda j, s: (0, block(j, s), 0))
+    shape = jax.ShapeDtypeStruct((n_kv, M, hd), k.dtype)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(M // rb,),
+            in_specs=[ins, ins], out_specs=[outs, outs]),
+        out_shape=[shape, shape],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_interpret(),
+    )(scalars, k, v)
+
+
+def chunk_attention(q, k, v, start, *, softmax_scale: Optional[float] = None,
+                    window: Optional[int] = None, first=0):
+    """q [1, C, nq, hd] at positions start .. start + C - 1 (start a
+    traced scalar, or [1]); k, v [1, M, n_kv, hd] holding the positions
+    first .. first + M - 1 (`first` = 0: the whole cache; a traced
+    scalar: the slice a window layer reads), every one of them up to
+    start + C - 1 written.  Query i sees key position j iff j <= start +
+    i and, under a `window` (static), j > start + i - window.  Returns
+    [1, C, nq, hd].  Raises ValueError on shapes outside `compatible`
+    (`models/generation._attend_cached_chunk` takes those)."""
+    C, nq, hd, M, n_kv, tr, kb = check_shapes(
+        q.shape, k.shape, jnp.shape(start), window=window, dtype=k.dtype)
+    g = nq // n_kv
+    R = g * C
+    scale = softmax_scale if softmax_scale is not None else hd ** -0.5
+    start = jnp.reshape(jnp.asarray(start, jnp.int32), ())
+    first = jnp.asarray(first, jnp.int32)
+    b0, live = live_blocks(start, C, M, kb, window, first)
+    scalars = jnp.stack([b0, live, start - first]).astype(jnp.int32)
+    # one KV head's group of query heads as one tall operand
+    qh = q[0].reshape(C, n_kv, g, hd).transpose(1, 2, 0, 3) \
+        .reshape(n_kv, R, hd)
+    kh, vh = _relay_heads(scalars, k[0], v[0], kb)
+
+    def key_block(h, r, j, s):
+        return h, s[0] + jnp.minimum(j, s[1] - 1), 0
+
+    rows = pl.BlockSpec((None, tr, hd), lambda h, r, j, s: (h, r, 0))
+    keys = pl.BlockSpec((None, kb, hd), key_block)
+    out = pl.pallas_call(
+        functools.partial(_kernel, scale=scale, window=window, C=C, tr=tr,
+                          kb=kb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n_kv, R // tr, M // kb),
+            in_specs=[rows, keys, keys],
+            out_specs=rows,
+            scratch_shapes=[pltpu.VMEM((tr, 1), jnp.float32),
+                            pltpu.VMEM((tr, 1), jnp.float32),
+                            pltpu.VMEM((tr, hd), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((n_kv, R, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_interpret(),
+    )(scalars, qh.astype(k.dtype), kh, vh)
+    return out.reshape(n_kv, g, C, hd).transpose(2, 0, 1, 3) \
+        .reshape(1, C, nq, hd)

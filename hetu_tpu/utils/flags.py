@@ -426,7 +426,8 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
     Flag("HETU_TPU_PALLAS_KERNELS", "str", "",
          "restrict WHICH Pallas kernels participate in HETU_TPU_PALLAS "
          "routing: comma list over {flash, norm, swiglu, rotary, quant, "
-         "paged_attn, paged_verify, sample, adam}, or 'all' (default: "
+         "paged_attn, paged_verify, sample, adam, paged_latent, "
+         "chunk_attn}, or 'all' (default: "
          "empty = all) / 'none' — lets one kernel be bisected out "
          "without losing the rest",
          identity="all"),

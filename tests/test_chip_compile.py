@@ -168,6 +168,19 @@ def _paged_serving_cell():
                 spec((slots, 128), I32), spec((slots,), I32))
 
 
+def _chunk_attn(C, nq, n_kv, M, window=None):
+    """The chunk program's attention as a serving cell's layer calls it
+    (`KVAttention.attend_dense`): one row's chunk against the layer's
+    slab of the scratch, or the window + C positions sliced out of it."""
+    from hetu_tpu.ops.pallas.chunk_attention import chunk_attention
+    kv = spec((1, M, n_kv, HEAD_DIM), BF16)
+
+    def fn(q, k, v, start, first):
+        return chunk_attention(q, k, v, start, window=window, first=first)
+    return fn, (spec((1, C, nq, HEAD_DIM), BF16), kv, kv, spec((1,), I32),
+                spec((), I32))
+
+
 def _quant(bits):
     from hetu_tpu.ops.pallas.quant import quantize_blockwise_pallas
     return (lambda x: quantize_blockwise_pallas(x, 128, bits=bits),
@@ -193,6 +206,10 @@ KERNEL_CASES = {
     "paged_attention_int8": lambda: _paged(True, HEADS),
     "paged_verify_c5": lambda: _paged(False, HEADS, verify_c=5),
     "paged_attention_serving_cell": _paged_serving_cell,
+    "chunk_attention_trinity_full": lambda: _chunk_attn(512, 32, 4, 8192),
+    "chunk_attention_trinity_window": lambda: _chunk_attn(
+        512, 32, 4, 2560, window=2048),
+    "chunk_attention_internlm2": lambda: _chunk_attn(128, 16, 8, 2048),
     "quant_int8": lambda: _quant(8),
     "quant_int4": lambda: _quant(4),
 }
@@ -377,6 +394,24 @@ def test_serving_programs_compile_for_one_v5e(family):
         scope = KERNEL_SCOPES.get(k, f"pallas_{k}_attention")
         assert any(scope in ln for ln in calls), (k, len(calls))
         assert routes[k]["pallas"] and not routes[k]["xla"], routes
+    # the chunk program of a K/V family asks the blockwise kernel's gate
+    # in every layer it traces: Trinity's 168 / 537 MB of float32 scores
+    # a layer take it (two calls a layer: K and V relaid head-major, then
+    # the attention), 33.5 MB at 32 heads x 128 x 2,048 do not pay for it
+    # and keep the composition; Kimi's has its own `attend_dense`
+    chunk_calls = sum("pallas_chunk_attention" in ln for ln in compiled[
+        "prefill_chunk"].as_text().splitlines()
+        if 'custom_call_target="tpu_custom_call"' in ln)
+    if family == "kimi":
+        assert "chunk_attn" not in routes and not chunk_calls
+    elif family == "trinity":
+        rec = routes["chunk_attn"]
+        assert 2 * rec["pallas"] == chunk_calls == 16 and not rec["xla"]
+        assert list(rec["why"]) == ["shape gate passes"]
+    else:
+        rec = routes["chunk_attn"]
+        assert rec["xla"] and not rec["pallas"] and not chunk_calls, rec
+        assert all("MB of float32 scores" in w for w in rec["why"]), rec
     if family == "trinity":
         # both kinds of layer decode through the kernel, each under its
         # own name; no program copies a pool (a scatter of whole pages
@@ -390,7 +425,9 @@ def test_serving_programs_compile_for_one_v5e(family):
         temps = {name: c.memory_analysis().temp_size_in_bytes
                  for name, c in compiled.items()}
         assert temps["write_pages"] < 1e6 and temps["decode"] < pool / 20
-        assert temps["prefill_chunk"] < 0.4e9, temps
+        # (0.19 GB with the composition's float32 scores, one KV head
+        # at a time: PR 34; 0.10 GB with the blockwise kernel)
+        assert temps["prefill_chunk"] < 0.15e9, temps
 
 
 @pytest.mark.parametrize("kv_quant", ["none", "int8"])

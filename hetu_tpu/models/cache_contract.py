@@ -15,16 +15,21 @@ family, and is written once, here: `KVAttention`.
 **How far back a layer reads** is part of the contract too: `windows`
 gives, per layer, the number of positions a query sees, its own counted
 (key j is seen by query t iff t - window < j <= t), or None for a layer
-that reads everything.  Layers with the same window are one KIND: the
-pool holds one set of page arrays a kind and hands pages out by kind
-(serving/kv_pool.py), because what lies behind a window layer's window
-is never read again and its pages go back to the free list while the
-request lives.  A model all of whose layers read everything has one
-kind, and is served as it always was.
+that reads everything.  And so is **what a token stores in THAT layer**,
+where the layers differ (`layer_token_shapes`: 4 KV heads on the layers
+that read everything, 8 on the window layers; keys of 192 beside values
+of 128).  Layers with the same window that store the same shapes are
+one KIND: the pool holds one set of page arrays a kind, each of the
+kind's own shape, and hands pages out by kind (serving/kv_pool.py),
+because what lies behind a window layer's window is never read again
+and its pages go back to the free list while the request lives.  A
+model all of whose layers read everything and store alike has one kind,
+and is served as it always was.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -53,31 +58,87 @@ class CacheContract:
     #: own counted); None for a layer that reads everything, and None
     #: for the whole tuple where every layer does
     windows: Tuple[Optional[int], ...] = None
+    #: per layer, that layer's `token_shapes` / `stored_shapes`, where
+    #: the layers differ in what a token stores; None: every layer
+    #: stores `token_shapes` (held in `stored_shapes`)
+    layer_token_shapes: Tuple[Tuple[Tuple[int, ...], ...], ...] = None
+    layer_stored_shapes: Tuple[Tuple[Tuple[int, ...], ...], ...] = None
 
     def __post_init__(self):
         if self.stored_shapes is None:
             object.__setattr__(self, "stored_shapes", self.token_shapes)
         if self.windows is None:
             object.__setattr__(self, "windows", (None,) * self.num_layers)
-        if len(self.windows) != self.num_layers:
-            raise ValueError(f"{len(self.windows)} windows for "
-                             f"{self.num_layers} layers")
+        by_layer = self.layer_token_shapes is not None
+        if not by_layer:
+            object.__setattr__(self, "layer_token_shapes",
+                               (self.token_shapes,) * self.num_layers)
+        if self.layer_stored_shapes is None:
+            object.__setattr__(
+                self, "layer_stored_shapes", self.layer_token_shapes
+                if by_layer else (self.stored_shapes,) * self.num_layers)
+        for what in (self.windows, self.layer_token_shapes,
+                     self.layer_stored_shapes):
+            if len(what) != self.num_layers:
+                raise ValueError(f"{len(what)} entries for "
+                                 f"{self.num_layers} layers: {what}")
+        if len({len(s) for s in self.layer_token_shapes
+                + self.layer_stored_shapes} | {len(self.token_shapes)}) != 1:
+            raise ValueError("every layer stores the same NUMBER of arrays "
+                             "a token (K and V, or one latent)")
+
+    def _kind_key(self, layer: int):
+        return (self.windows[layer], self.layer_token_shapes[layer],
+                self.layer_stored_shapes[layer])
+
+    @functools.cached_property
+    def _kinds(self):
+        """The distinct (window, token shapes, stored shapes): layers
+        that read everything first, then by width, then by shape."""
+        return tuple(sorted(
+            {self._kind_key(l) for l in range(self.num_layers)},
+            key=lambda k: (k[0] is not None, k[0] or 0) + k[1:]))
 
     @property
     def kinds(self) -> Tuple[Optional[int], ...]:
-        """The distinct windows, layers that read everything first, then
-        by width: one set of page arrays and one page table each."""
-        return tuple(sorted(set(self.windows),
-                            key=lambda w: (w is not None, w or 0)))
+        """The window of each KIND of layer (a kind is a window and what
+        a token stores there): one set of page arrays and one page table
+        each."""
+        return tuple(k[0] for k in self._kinds)
+
+    @property
+    def by_kind(self) -> bool:
+        """Pages, tables and the prefill scratch are laid out by kind of
+        layer: some kind reads a window only, the kinds differ in what a
+        token stores, or the arrays a token stores (K and V) differ in
+        shape."""
+        return (len(self._kinds) > 1 or self._kinds[0][0] is not None
+                or len(set(self._kinds[0][2])) > 1)
+
+    def kind_of(self, layer: int) -> int:
+        """The kind of one layer."""
+        return self._kinds.index(self._kind_key(layer))
 
     def layers_of(self, kind: int) -> Tuple[int, ...]:
         """The layers of one kind, in the model's layer order."""
-        w = self.kinds[kind]
-        return tuple(l for l, x in enumerate(self.windows) if x == w)
+        key = self._kinds[kind]
+        return tuple(l for l in range(self.num_layers)
+                     if self._kind_key(l) == key)
+
+    def token_shapes_of(self, kind: int):
+        """What a token stores in a layer of `kind`, as the model needs
+        it ..."""
+        return self._kinds[kind][1]
+
+    def stored_shapes_of(self, kind: int):
+        """... and as the pool and the scratch hold it."""
+        return self._kinds[kind][2]
 
     @property
-    def values_per_token_layer(self) -> int:
-        return sum(math.prod(s) for s in self.token_shapes)
+    def values_per_token(self) -> int:
+        """Values one token stores over ALL layers, each layer its own."""
+        return sum(math.prod(s) for shapes in self.layer_token_shapes
+                   for s in shapes)
 
 
 def kv_contract(num_layers: int, num_kv_heads: int, head_dim: int,
@@ -99,24 +160,44 @@ def cache_contract(model) -> CacheContract:
         c.head_dim, c.compute_dtype)
 
 
+def _widen(q, width: int):
+    """Queries as wide as the keys are STORED (zeros beyond the model's
+    own key width: keys of 192 held in 256 lanes)."""
+    if q.shape[-1] == width:
+        return q
+    return jnp.pad(q, ((0, 0),) * (q.ndim - 1)
+                   + ((0, width - q.shape[-1]),))
+
+
 class KVAttention:
     """How a query attends a K/V cache: the `attend_paged`,
     `attend_dense` and `attend_prompt` hooks of an attention module whose
-    `project` makes entries (k, v), each [b, s, n_kv, head_dim], for
-    queries [b, s, n_q, head_dim] (n_q a multiple of n_kv, q head j
-    reading kv head j // group).  A family's own are `project` and
-    `output`.  Each hook takes the layer's `window` (the contract's; None
-    = the layer reads everything): ONE implementation for every family
-    of the K/V kind."""
+    `project` makes entries (k, v), k [b, s, n_kv, d_k] and v [b, s,
+    n_kv, d_v], for queries [b, s, n_q, d_q] (n_q a multiple of n_kv, q
+    head j reading kv head j // group).  d_q is the model's key width
+    and sets the softmax scale; the keys may be STORED wider (the
+    contract's `stored_shapes`: 192 in 256 lanes, zeros beyond), the
+    queries are then widened with zeros here; the values' width is their
+    own, and the hooks return n_q * d_v values a token.  A family's own
+    are `project` and `output`, and `sink`.  Each hook takes the layer's
+    `window` (the contract's; None = the layer reads everything): ONE
+    implementation for every family of the K/V kind."""
+
+    def sink(self, params, window):
+        """A learned scalar a query head [n_q] that stands in the
+        softmax's denominator as one more key would and adds no value
+        (p_tj = exp(s_tj) / (exp(sink) + sum_j' exp(s_tj'))), or None:
+        the family's to override."""
+        return None
 
     def attend_paged(self, params, q, pools, table, positions, base, *,
                      scales=None, layer=None, quant=None, window=None):
         """q: a block of C queries a slot at positions[s] + i, causal
         within the block; pools = (k pages, v pages) of ALL layers of the
-        layer's kind, each [L * P, page_size, n_kv, hd], of which this
-        layer's P pages start at `base` and are read through `table`
-        [S, max_pages] of page ids within a layer.  C = 1 is the decode
-        step's kernel, C > 1 the verify step's
+        layer's kind, [L * P, page_size, n_kv, d_k] and [.., d_v], of
+        which this layer's P pages start at `base` and are read through
+        `table` [S, max_pages] of page ids within a layer.  C = 1 is the
+        decode step's kernel, C > 1 the verify step's
         (ops/pallas/paged_attention).  Under a `window` the walk starts
         at the page that holds position positions[s] - window + 1 (the
         table's entries before it are the null page: their pages were
@@ -124,21 +205,34 @@ class KVAttention:
         `scales`, the planes [L, P, page_size, n_kv] of which `layer`'s
         is handed to the kernel, to read by `table` as it came
         (models/generation._paged_forward says why).
-        -> [S, C, n_q * hd]."""
+        -> [S, C, n_q * d_v]."""
         from hetu_tpu.ops.pallas import _note_route
         from hetu_tpu.ops.pallas.paged_attention import (paged_attention,
                                                          paged_verify)
         S, C, nq, hd = q.shape
-        kw = {}
+        sink, kw = self.sink(params, window), {}
+        refusal = ("a window, a sink and keys wider than the values have "
+                   "the single-token kernel over exact pages; the verify "
+                   "block and quantized pages are not built for them")
+        if (window is not None or sink is not None) and (C > 1 or scales):
+            raise NotImplementedError(refusal)
+        # (quantized pages store K and V alike, int4 at half the width)
+        d_k, d_v = ((hd, hd) if scales else
+                    (pools[0].shape[-1], pools[1].shape[-1]))
+        if d_k != d_v and C > 1:
+            raise NotImplementedError(refusal)
         if window is not None:
-            if C > 1 or scales:
-                raise NotImplementedError(
-                    "a window layer has the single-token kernel over exact "
-                    "pages; the verify block and quantized pages are not "
-                    "built for it")
             kw["window"] = window
             _note_route("paged_attn_window", True,
                         f"decode: the kernel, from position - {window} + 1")
+        if sink is not None:
+            kw["sink"] = sink
+        if sink is not None or d_k != d_v:
+            _note_route(
+                "paged_attn_shapes", True,
+                f"keys {hd} (held in {d_k}) against values {d_v}, groups of "
+                f"{nq // pools[0].shape[-2]}, "
+                f"{'a sink a head' if sink is not None else 'no sink'}")
         if C == 1:
             kernel, scope, q = paged_attention, "pallas_paged_attention", \
                 q[:, 0]
@@ -149,18 +243,21 @@ class KVAttention:
         with jax.named_scope(scope):
             ksl, vsl = ((s[layer] for s in scales) if scales
                         else (None, None))
-            attn = kernel(q, *pools, table + base, positions,
+            attn = kernel(_widen(q, d_k), *pools, table + base, positions,
                           softmax_scale=hd ** -0.5, k_scale=ksl, v_scale=vsl,
                           quant=quant, scale_table=table, **kw)
-        return attn.reshape(S, C, nq * hd)
+        return attn.reshape(S, C, nq * d_v)
 
-    def attend_dense(self, params, q, caches, start, window=None):
+    def attend_dense(self, params, q, caches, start, window=None, first=0):
         """q: C queries a row at positions start[b] + i (start a scalar
-        or [b]); caches = (k, v), each [b, M, n_kv, hd], holding every
-        position the queries may see.  Under a `window`, ONE row's
-        queries (the chunk program) read the window + C positions that
-        end with the chunk and no more; rows at positions of their own
-        (the gather decode route) read by the window's mask.
+        or [b]); caches = (k, v), [b, M, n_kv, d_k] and [.., d_v],
+        holding the positions first .. first + M - 1 (`first` = 0: from
+        the beginning; a window layer's sliding scratch starts where the
+        chunk program says), every position the queries may see among
+        them.  Under a `window`, ONE row's queries (the chunk program)
+        read the window + C positions that end with the chunk and no
+        more; rows at positions of their own (the gather decode route)
+        read by the window's mask.
 
         Which shapes take which attention (the route record
         `kernel_routes["chunk_attn"]` says it per traced layer): ONE
@@ -169,63 +266,81 @@ class KVAttention:
         (ops/pallas/chunk_attention: the scores stay on the chip, and
         only the key blocks the chunk can see are read) where
         `ops.pallas.resolve_route` and the kernel's gate allow: a TPU,
-        head_dim % 128, C a multiple of the sublane tile, a cache length
-        that divides into key blocks of a multiple of 128, and at least
-        64 MB of float32 scores in the composition (heads x C x cache
-        positions: under that the two tie on a v5e and the composition
-        stays, as at InternLM2's chunk of 128 over 2,048 positions).
-        Everything else keeps the XLA composition
+        key and value widths % 128, C a multiple of the sublane tile, a
+        cache length that divides into key blocks of a multiple of 128,
+        and at least 64 MB of float32 scores in the composition (heads x
+        C x cache positions: under that the two tie on a v5e and the
+        composition stays, as at InternLM2's chunk of 128 over 2,048
+        positions).  Everything else keeps the XLA composition
         `models/generation._attend_cached_chunk`: any shape the gate
         refuses, every backend but a TPU, a single query (C = 1: the
         decode step over a dense cache), and rows at depths of their own
         (start [b > 1]: the gather decode route, the verify step), which
-        no flag forces.  -> [b, C, n_q * hd]."""
+        no flag forces.  -> [b, C, n_q * d_v]."""
         from hetu_tpu.models.generation import _attend_cached_chunk
         from hetu_tpu.ops.pallas import chunk_attention as _ca
         from hetu_tpu.ops.pallas import _note_route, resolve_route
         b, C, nq, hd = q.shape
-        M, first = caches[0].shape[1], 0
+        d_k, d_v = caches[0].shape[-1], caches[1].shape[-1]
+        sink = self.sink(params, window)
+        q = _widen(q, d_k)
+        M = caches[0].shape[1]
         if window is not None and b == 1 and window + C < M:
             # keys start + C - (window + C) .. start + C - 1, kept inside
             # the cache at both ends
             R = window + C
-            first = jnp.clip(jnp.reshape(start, ()) + C - R, 0, M - R)
-            caches = tuple(lax.dynamic_slice_in_dim(c, first, R, axis=1)
+            inner = jnp.clip(jnp.reshape(start, ()) - first + C - R, 0, M - R)
+            caches = tuple(lax.dynamic_slice_in_dim(c, inner, R, axis=1)
                            for c in caches)
+            first = first + inner
+        extra = {} if sink is None else {"sink": sink}
         if b == 1 and C > 1:
             kernel = resolve_route(
                 "chunk_attn", _ca.check_route, q.shape, caches[0].shape,
-                jnp.shape(start), window=window, dtype=caches[0].dtype)
+                jnp.shape(start), window=window, dtype=caches[0].dtype,
+                v_shape=caches[1].shape, sink=sink is not None)
         else:
             kernel = False
             _note_route("chunk_attn", False,
                         "a single query, or rows at depths of their own: "
                         "the composition")
+        if sink is not None or d_k != d_v:
+            _note_route(
+                "chunk_attn_shapes", bool(kernel),
+                f"keys {hd} (held in {d_k}) against values {d_v}, groups of "
+                f"{nq // caches[0].shape[-2]}, "
+                f"{'a sink a head' if sink is not None else 'no sink'}")
         if kernel:
             with jax.named_scope("pallas_chunk_attention"):
                 out = _ca.chunk_attention(q, *caches, start,
                                           softmax_scale=hd ** -0.5,
-                                          window=window, first=first)
+                                          window=window, first=first,
+                                          **extra)
         else:
             out = _attend_cached_chunk(q, *caches, start, hd ** -0.5,
-                                       window=window, first=first)
-        return out.reshape(b, C, nq * hd)
+                                       window=window, first=first, **extra)
+        return out.reshape(b, C, nq * d_v)
 
     def attend_prompt(self, params, q, entries, window=None):
         """Whole prompts attending their own entries, causally: the
-        training forward's flash path; under a `window` the XLA
-        composition by the window's mask (ops/pallas/flash_attention has
-        no window).  -> [b, s, n_q * hd]."""
+        training forward's flash path; under a `window`, with a sink or
+        with keys wider than the values the XLA composition by the
+        window's mask (ops/pallas/flash_attention has none of the
+        three).  -> [b, s, n_q * d_v]."""
         from hetu_tpu import ops
         b, s, nq, hd = q.shape
-        if window is not None:
+        d_k, d_v = entries[0].shape[-1], entries[1].shape[-1]
+        sink = self.sink(params, window)
+        if window is not None or sink is not None or d_k != d_v:
             from hetu_tpu.models.generation import _attend_cached_chunk
             from hetu_tpu.ops.pallas import _note_route
             _note_route("flash_attn_window", False,
-                        "whole prompts under a window: the XLA composition "
-                        "(no window in ops/pallas/flash_attention)")
-            return _attend_cached_chunk(q, *entries, 0, hd ** -0.5,
-                                        window=window).reshape(b, s, nq * hd)
+                        "whole prompts under a window, with a sink or with "
+                        "keys wider than the values: the XLA composition "
+                        "(none of them in ops/pallas/flash_attention)")
+            return _attend_cached_chunk(
+                _widen(q, d_k), *entries, 0, hd ** -0.5, window=window,
+                sink=sink).reshape(b, s, nq * d_v)
         attn = ops.flash_attention(
             q, *entries, causal=True,
             use_pallas=None if self.config.use_flash_attention else False)

@@ -30,8 +30,9 @@ it are the model's hooks:
       how far back the layer's queries read, their own position counted
       (None or absent: everything), as the cache contract's `windows`
       says it per layer: `_layer` hands it to the three attend hooks as
-      `window=`, and `_walk_layers` finds the layer's KIND by it (layers
-      that read as far back share page arrays and a page table:
+      `window=`; `_walk_layers` finds the layer's KIND in the contract
+      (`kind_of(layer)`: layers that read as far back AND store the same
+      shapes share cache arrays, page arrays and a page table:
       serving/kv_pool.py); and the scope the layer's attention runs
       under inside `attn`, so that a trace tells the kinds apart
   block.attn.project(p, hn, rope, pos_ids) -> (q, entries[, aux])
@@ -39,11 +40,13 @@ it are the model's hooks:
       contract, [b, s, *stored shape]; what it returns beyond that (a
       gate on the attention's output) is handed to `output`
   block.attn.attend_paged(p, q, pools, table, positions, base)
-  block.attn.attend_dense(p, q, caches, start)
+  block.attn.attend_dense(p, q, caches, start[, first=])
   block.attn.attend_prompt(p, q, entries)
-      HOW A QUERY ATTENDS IT: over pages, over a dense per-slot cache,
-      and a whole prompt over its own entries (for the K/V kind all
-      three are `cache_contract.KVAttention`)
+      HOW A QUERY ATTENDS IT: over pages, over a dense per-slot cache
+      (which begins at position `first` where the chunk program's
+      sliding scratch says so), and a whole prompt over its own entries
+      (for the K/V kind all three are `cache_contract.KVAttention`,
+      which also asks the family for a `sink`)
   block.attn.output(p, attn[, aux]), final_hidden(params, x),
   logits(params, h), lm_head_weight(params)
 
@@ -106,16 +109,20 @@ def _attend_cached(q, ck, cv, pos, scale):
     return _attend_cached_chunk(q, ck, cv, pos, scale)
 
 
-def _attend_cached_chunk(q, ck, cv, start, scale, window=None, first=0):
+def _attend_cached_chunk(q, ck, cv, start, scale, window=None, first=0,
+                         sink=None):
     """Multi-query cached attention for chunked prefill.  q: [b, C, nq,
     hd] sits at absolute positions start..start+C-1 (start scalar or
     [b]); key position k is visible to query i iff k <= start + i
     (causal within the chunk, full visibility of the already-cached
-    prefix) and, under a `window`, k > start + i - window.  ck/cv hold
-    the positions first..first+M-1 (`first` = 0: the whole cache; a
+    prefix) and, under a `window`, k > start + i - window.  ck [b, M,
+    n_kv, hd] / cv [b, M, n_kv, hd_v] (the values' width is their own)
+    hold the positions first..first+M-1 (`first` = 0: the whole cache; a
     window layer's chunk hands in the slice it reads:
-    cache_contract.KVAttention.attend_dense).  Same grouped-GQA
-    contraction as `_attend_cached`.
+    cache_contract.KVAttention.attend_dense).  `sink` [nq]: a scalar a
+    query head that stands in the softmax's denominator as one more key
+    and adds no value.  Same grouped-GQA contraction as
+    `_attend_cached`.  -> [b, C, nq, hd_v].
 
     THE XLA composition of attention over a dense cache, and the
     reference of the blockwise kernel (ops/pallas/chunk_attention) that
@@ -142,26 +149,34 @@ def _attend_cached_chunk(q, ck, cv, start, scale, window=None, first=0):
     if window is not None:
         mask = mask & (kpos > qpos[..., None] - window)
 
-    def attend(qg, ck, cv):
+    def attend(qg, ck, cv, sink=None):
         s = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
                        ck.astype(jnp.float32)) * scale
         s = jnp.where(mask[:, None, None, :, :], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
+        if sink is None:
+            p = jax.nn.softmax(s, axis=-1)
+        else:
+            sk = sink.astype(jnp.float32)[None, :, :, None, None]  # [1,h,g,1,1]
+            m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), sk)
+            e = jnp.exp(s - m)
+            p = e / (jnp.sum(e, axis=-1, keepdims=True) + jnp.exp(sk - m))
         return jnp.einsum("bhgqk,bkhd->bqhgd", p, cv.astype(jnp.float32))
 
+    heads = (qg, ck, cv) if sink is None else (
+        qg, ck, cv, jnp.reshape(sink, (n_kv, group)))
     if b * nq * C * M * 4 > _SCORES_AT_ONCE and n_kv > 1:
         # one KV head's group of query heads at a time: the float32
         # scores of all heads at once would be the program's largest
         # temporary (0.54 GB, twice, for 32 heads x 512 x 8,192)
         out = lax.map(
             lambda x: attend(x[0][:, :, None], x[1][:, :, None],
-                             x[2][:, :, None])[:, :, 0],
-            (jnp.moveaxis(qg, 2, 0), jnp.moveaxis(ck, 2, 0),
-             jnp.moveaxis(cv, 2, 0)))
+                             x[2][:, :, None], *(y[None] for y in x[3:])
+                             )[:, :, 0],
+            tuple(jnp.moveaxis(a, 2, 0) for a in heads[:3]) + heads[3:])
         out = jnp.moveaxis(out, 0, 2)
     else:
-        out = attend(qg, ck, cv)
-    return out.reshape(b, C, nq, hd).astype(q.dtype)
+        out = attend(*heads)
+    return out.reshape(b, C, nq, cv.shape[-1]).astype(q.dtype)
 
 
 
@@ -184,16 +199,43 @@ def _check_context_length(config, max_len: int):
 
 
 
-def init_cache(model, batch: int, max_len: int):
-    """Empty dense cache: one array [L, b, max_len, *stored shape] per
-    array of the model's cache contract ((k, v) of [.., n_kv, hd] for the
-    K/V kind)."""
+def window_reach(window: int, chunk: int, page: int = 1) -> int:
+    """Positions a window layer's SLIDING scratch holds for chunks of
+    `chunk` tokens: the window, up to whole pages (so that a page of the
+    pool is a block of the scratch), before the chunk, and the chunk."""
+    return -(-window // page) * page + chunk
+
+
+def init_cache(model, batch: int, max_len: int, chunk: Optional[int] = None,
+               page: int = 1):
+    """Empty dense cache: per KIND of layer of the model's cache contract
+    (one kind for most models), one array [layers of the kind, b,
+    positions, *stored shape] per array a token stores there ((k, v) of
+    [.., n_kv, hd] for the K/V kind), kind after kind.  `max_len`
+    positions, but for a window kind given `chunk` (the chunk program's
+    scratch, which SLIDES: `extend_cache(slide=True)`): `window_reach`
+    positions, where that is fewer."""
     c = model.config
     _check_context_length(c, max_len)
     contract = cache_contract(model)
+
+    def positions(w):
+        return max_len if w is None or chunk is None else min(
+            max_len, window_reach(w, chunk, page))
     return tuple(
-        jnp.zeros((contract.num_layers, batch, max_len) + tuple(shape),
-                  c.compute_dtype) for shape in contract.stored_shapes)
+        jnp.zeros((len(contract.layers_of(k)), batch, positions(w))
+                  + tuple(shape), c.compute_dtype)
+        for k, w in enumerate(contract.kinds)
+        for shape in contract.stored_shapes_of(k))
+
+
+def _of_kind(arrays, kind: int, n: int):
+    """The `n` arrays of `kind` in a tuple that holds `n` a kind, kind
+    after kind (a dense cache, a pool's pages), and that tuple with
+    others in their place."""
+    def put(new):
+        return arrays[:kind * n] + tuple(new) + arrays[(kind + 1) * n:]
+    return arrays[kind * n:(kind + 1) * n], put
 
 
 # ---------------------------------------------------------------------------
@@ -262,25 +304,28 @@ def _walk_layers(model, params, x, state, stats, layer):
       buffers back.
 
     layer(block, lp, h, state, at, page_at) -> (h, stats of the layer,
-    state, out).  `at` = (l,) is the layer among all layers (a dense
-    cache's leading dim); `page_at` = (kind, (j,)) is the layer's kind
-    by the cache contract (layers that read as far back are one kind,
-    with page arrays and a page table of their own) and its place j
-    among the layers of that kind (a paged pool's leading dim); with one
-    kind j is l.  `out` is whatever a layer hands out besides (a token's
-    entries for a paged pool to scatter; None): stacked over the layers.
-    Returns (x, stats, state, out).  The caller opens the `layer` scope
+    state, out).  `at` = (l,) is the layer among all layers; `page_at` =
+    (kind, (j,)) is the layer's kind by the cache contract (layers that
+    read as far back and store the same shapes are one kind, with cache
+    arrays, page arrays and a page table of their own) and its place j
+    among the layers of that kind (the leading dim of a dense cache's
+    and of a paged pool's arrays); with one kind j is l.  `out` is
+    whatever a layer hands out besides (a token's entries for a paged
+    pool to scatter; None): stacked over the layers of a kind, and with
+    several kinds the kinds' tuples one after the other, as a dense
+    cache lays its arrays out.  Returns (x, stats, state, out).  The caller opens the `layer` scope
     (a trace's name for the stack: obs.scope_map) around the walk and
     what it does to the state before and after."""
-    outs, l0 = [], 0
-    kinds = cache_contract(model).kinds
-    seen = [0] * len(kinds)         # layers walked so far, by kind
+    l0 = 0
+    contract = cache_contract(model)
+    outs = [[] for _ in contract.kinds]
+    seen = [0] * len(outs)          # layers walked so far, by kind
 
     def add(stats, st):
         return stats if stats is None else model.add_stats(stats, st)
 
     for block, lp, count in model.serving_layers(params):
-        kind = kinds.index(getattr(block, "window", None))
+        kind = contract.kind_of(l0)
         j0 = seen[kind]
         if count is None:
             x, st, state, out = layer(block, lp, x, state,
@@ -300,11 +345,13 @@ def _walk_layers(model, params, x, state, stats, layer):
             (x, state, stats), out = lax.scan(
                 body, (x, state, stats),
                 (lp, jnp.arange(l0, l0 + count, dtype=jnp.int32)))
-        outs.append(out)
+        outs[kind].append(out)
         l0 += count or 1
         seen[kind] += count or 1
-    out = outs[0] if len(outs) == 1 else jax.tree.map(
-        lambda *a: jnp.concatenate(a), *outs)
+    outs = [o[0] if len(o) == 1 else jax.tree.map(
+        lambda *a: jnp.concatenate(a), *o) for o in outs]
+    out = outs[0] if len(outs) == 1 else (
+        None if outs[0] is None else sum((tuple(o) for o in outs), ()))
     return x, stats, state, out
 
 
@@ -316,7 +363,7 @@ def prefill(model, params, input_ids, max_len: int):
     """Run whole prompts [b, plen] through the layers, each attending its
     own entries (`attend_prompt`: the flash path for the K/V kind), and
     return (last_logits [b, vocab], cache): the entries of every layer,
-    padded to `max_len` positions."""
+    padded to `max_len` positions (`init_cache`'s arrays)."""
     _check_context_length(model.config, max_len)
     b, plen = input_ids.shape
     pos_ids = jnp.broadcast_to(jnp.arange(plen, dtype=jnp.int32), (b, plen))
@@ -363,23 +410,28 @@ def decode_step_slots(model, params, tokens, cache, positions):
     dynamic_update_slice cache lowering for the uniform-position
     generate() hot loop.  Returns (logits [b, vocab], new_cache, token
     entries): THIS step's entries per layer, one array [L, b, *stored
-    shape] per array of the cache ((k_toks, v_toks) for the K/V kind) —
-    a paged cache scatters them into its pool instead of carrying the
-    dense cache."""
+    shape] per array of the cache ((k_toks, v_toks) for the K/V kind;
+    with several kinds of layer a kind's after a kind's, as the cache
+    is laid out) — a paged cache scatters them into its pool instead of
+    carrying the dense cache."""
     b = tokens.shape[0]
     uniform = jnp.ndim(positions) == 0
     pos_ids = (jnp.broadcast_to(positions, (b, 1)) if uniform
                else positions[:, None])
     rope = model.rope_tables(cache[0].shape[2])
+    n = len(cache_contract(model).token_shapes)
     x = model.embed_tokens(params, tokens[:, None], pos_ids)
 
     def layer(block, lp, h, cache, at, page_at):
+        kind, at = page_at
+        mine, put = _of_kind(cache, kind, n)
+
         def step(attn, p, q, entries, win):
             new = tuple(_cache_write_token(c, e, positions, uniform, at)
-                        for c, e in zip(cache, entries))
+                        for c, e in zip(mine, entries))
             return (attn.attend_dense(p, q, tuple(c[at] for c in new),
                                       positions, **win),
-                    new, tuple(e[:, 0] for e in entries))
+                    put(new), tuple(e[:, 0] for e in entries))
         return _layer(block, lp, h, rope, pos_ids, step)
 
     with jax.named_scope("layer"):
@@ -400,7 +452,8 @@ def decode_step(model, params, token, cache, pos):
 
 
 def extend_cache(model, params, tokens, cache, start, stats=None, *,
-                 collect_token_kv: bool = False):
+                 collect_token_kv: bool = False, slide: bool = False,
+                 max_len: Optional[int] = None):
     """Advance a dense cache by a whole token block (chunked prefill).
 
     tokens: [b, C] int32 at absolute positions start..start+C-1 (start
@@ -413,6 +466,19 @@ def extend_cache(model, params, tokens, cache, start, stats=None, *,
     serving engine uses it so one long prompt never stalls the decode
     batch (docs/serving.md).
 
+    ``slide=True`` (the engine's chunk program: ONE row, consecutive
+    chunks of C tokens from position 0 on): a window kind's arrays hold
+    M = `init_cache(.., chunk=C)`'s positions, not `max_len`, and SLIDE:
+    they hold the positions base .. base + M - 1 with base = max(0,
+    start - (M - C)), the M - C positions before the chunk and the
+    chunk.  At each launch the M - C positions that stay are moved down
+    by what base advanced since the launch before (base(start) -
+    base(start - C), at most C), the chunk is written behind them, and
+    the queries attend the array with `first=base`.  A kind that reads
+    everything keeps `max_len` positions and moves nothing; `max_len`
+    (for the rotation tables) is then that kind's length, and must be
+    given where every kind slides.
+
     ``collect_token_kv=True`` (the `verify_step_slots` path) also
     returns the chunk's entries per layer ((k, v) [L, b, C, n_kv, hd])
     so a paged cache can scatter them into its pool; given the running
@@ -422,21 +488,40 @@ def extend_cache(model, params, tokens, cache, start, stats=None, *,
     rows = jnp.arange(b)
     start = jnp.broadcast_to(jnp.asarray(start, jnp.int32), (b,))
     qpos = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]  # [b, C]
-    rope = model.rope_tables(cache[0].shape[2])
+    rope = model.rope_tables(max_len or max(c.shape[2] for c in cache))
+    n = len(cache_contract(model).token_shapes)
     # the scopes the training programs carry, so that a device trace of
     # the serving programs is summed under the same names (obs.scope_map)
     with jax.named_scope("embed"):
         x = model.embed_tokens(params, tokens, qpos)
 
     def layer(block, lp, h, cache, at, page_at):
+        kind, at = page_at
+        mine, put = _of_kind(cache, kind, n)
+        sliding = slide and getattr(block, "window", None) is not None
+
         def step(attn, p, q, entries, win):
+            where, first = qpos, {}
             with jax.named_scope("kv_write"):
+                if sliding:
+                    keep = mine[0].shape[2] - C
+                    base = jnp.maximum(start[0] - keep, 0)
+                    shift = base - jnp.maximum(start[0] - C - keep, 0)
+                    kept = tuple(
+                        lax.dynamic_update_slice(
+                            c, lax.dynamic_slice_in_dim(
+                                c[at], shift, keep, axis=1)[None],
+                            at + (0,) * (c.ndim - 1)) if keep else c
+                        for c in mine)
+                    where, first = qpos - base, {"first": base}
+                else:
+                    kept = mine
                 new = tuple(
-                    c.at[at + (rows[:, None], qpos)].set(e.astype(c.dtype))
-                    for c, e in zip(cache, entries))
+                    c.at[at + (rows[:, None], where)].set(e.astype(c.dtype))
+                    for c, e in zip(kept, entries))
             return (attn.attend_dense(p, q, tuple(c[at] for c in new),
-                                      start, **win),
-                    new, entries if collect_token_kv else None)
+                                      start, **win, **first),
+                    put(new), entries if collect_token_kv else None)
         return _layer(block, lp, h, rope, qpos, step)
 
     with jax.named_scope("layer"):

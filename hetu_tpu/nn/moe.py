@@ -567,8 +567,10 @@ class SharedRoutedExperts(Module):
     (`first_expert`, `experts_held`), routes every token over all
     `n_routed_experts` (the router keeps its published width), computes
     its own experts' part of sum_i w_i E_i(x) without dropping a token,
-    and adds the shared expert, which every chip computes alike.  With
-    all experts held it is the whole layer; across chips the parts would
+    and adds the shared expert, which every chip computes alike (with
+    `n_shared_experts` 0 the layer has none: no weights, no product, no
+    `shared_expert` scope, and a token none of whose experts is held
+    here gets 0).  With all experts held it is the whole layer; across chips the parts would
     be summed by the exchange this layer does not do (no code stands in
     for absent chips).
 
@@ -613,9 +615,12 @@ class SharedRoutedExperts(Module):
                    dtype=param_dtype)
         self.param("w_down", (experts_held, inter, hidden), w,
                    dtype=param_dtype)
-        si = inter * n_shared_experts
-        self.param("shared_gate_up", (hidden, 2 * si), w, dtype=param_dtype)
-        self.param("shared_down", (si, hidden), w, dtype=param_dtype)
+        self.shared = n_shared_experts > 0
+        if self.shared:
+            si = inter * n_shared_experts
+            self.param("shared_gate_up", (hidden, 2 * si), w,
+                       dtype=param_dtype)
+            self.param("shared_down", (si, hidden), w, dtype=param_dtype)
 
     def route(self, params, xt):
         return noaux_tc_gate(
@@ -632,11 +637,12 @@ class SharedRoutedExperts(Module):
             y, counts, extra = dropless_local_experts(
                 xt, idx, weights, params["w_gate_up"], params["w_down"],
                 first_expert=self.first_expert, share=self.share)
-        with jax.named_scope("shared_expert"):
-            gu = xt @ params["shared_gate_up"].astype(x.dtype)
-            si = gu.shape[-1] // 2
-            y = y + (jax.nn.silu(gu[:, :si]) * gu[:, si:]) \
-                @ params["shared_down"].astype(x.dtype)
+        if self.shared:
+            with jax.named_scope("shared_expert"):
+                gu = xt @ params["shared_gate_up"].astype(x.dtype)
+                si = gu.shape[-1] // 2
+                y = y + (jax.nn.silu(gu[:, :si]) * gu[:, si:]) \
+                    @ params["shared_down"].astype(x.dtype)
         stats = jnp.stack([
             jnp.int32(idx.size), jnp.sum(counts),
             jnp.sum((counts > 0).astype(jnp.int32)), extra,

@@ -49,9 +49,17 @@ four times a Trinity chunk: compiled for a described v5e and measured,
 PR 35), because a slice at a constant layer index hands its layout up to
 what it slices.
 
+**Keys wider than the values, and a sink.**  V's head dim is its own
+(keys of 256 lanes, of which a model may use 192, beside values of 128):
+the key and value blocks, the accumulator and the output take each its
+width.  A `sink` [nq] (a learned scalar a query head) stands in the
+softmax as one more key that has no value: a row's running maximum
+starts at its head's sink and its running sum at 1.  With neither, the
+lowered program is what it was.
+
 Shape contract (`check_shapes`, drift-tested against `compatible`): ONE
 row (b = 1) at ONE start, C > 1 queries, C a multiple of the sublane
-tile of the dtype, hd a multiple of 128 lanes, q heads a multiple of the
+tile of the dtype, the key and the value head dims multiples of 128 lanes, q heads a multiple of the
 KV heads, and a cache length M that `fit_block` divides into key blocks
 of a multiple of 128 (a cache shorter than that is one block, if a
 multiple of the sublane tile).  Rows at depths of their own (the verify
@@ -108,9 +116,12 @@ def _row_tile(C: int, group: int) -> int:
 
 
 def check_shapes(q_shape, k_shape, start_shape=(), *, window=None,
-                 dtype=None) -> Tuple[int, int, int, int, int, int, int]:
+                 dtype=None, v_shape=None, sink: bool = False
+                 ) -> Tuple[int, int, int, int, int, int, int]:
     """-> (C, nq, hd, M, n_kv, row tile, key block), or ValueError with
-    the reason the composition takes the shape instead."""
+    the reason the composition takes the shape instead.  `v_shape`: V's
+    where its head dim is its own (None: K's); `sink`: a sink a query
+    head enters the softmax (any shape the kernel takes, takes one)."""
     if len(q_shape) != 4 or len(k_shape) != 4:
         raise ValueError(f"expected q [b, C, nq, hd] and k [b, M, n_kv, "
                          f"hd], got {q_shape} / {k_shape}")
@@ -126,6 +137,10 @@ def check_shapes(q_shape, k_shape, start_shape=(), *, window=None,
     if hd_k != hd or hd % 128:
         raise ValueError(f"head dim {hd} (cache {hd_k}) is not "
                          f"lane-aligned (% 128)")
+    if v_shape is not None and (tuple(v_shape[:-1]) != tuple(k_shape[:-1])
+                                or v_shape[-1] % 128):
+        raise ValueError(f"v {tuple(v_shape)} must be k's {tuple(k_shape)} "
+                         f"but for a head dim of its own, % 128")
     if nq % n_kv:
         raise ValueError(f"q heads {nq} must divide by kv heads {n_kv}")
     if window is not None and window < 1:
@@ -144,7 +159,7 @@ def check_shapes(q_shape, k_shape, start_shape=(), *, window=None,
 
 
 def check_route(q_shape, k_shape, start_shape=(), *, window=None,
-                dtype=None):
+                dtype=None, v_shape=None, sink: bool = False):
     """The gate `attend_dense` hands to `resolve_route`: the shapes the
     kernel takes (`check_shapes`) AND for which it pays.  What the
     composition pays for is the float32 scores of every position it is
@@ -156,7 +171,7 @@ def check_route(q_shape, k_shape, start_shape=(), *, window=None,
     kernel wins 2.7-14 times at Trinity's (168 and 537 MB): PERF.md s6,
     PR 35.  Forced flags ask neither."""
     out = check_shapes(q_shape, k_shape, start_shape, window=window,
-                       dtype=dtype)
+                       dtype=dtype, v_shape=v_shape, sink=sink)
     score_bytes = 4 * q_shape[1] * q_shape[2] * k_shape[1]
     if score_bytes < _MIN_SCORE_BYTES:
         raise ValueError(
@@ -168,10 +183,10 @@ def check_route(q_shape, k_shape, start_shape=(), *, window=None,
 
 
 def compatible(q_shape, k_shape, start_shape=(), *, window=None,
-               dtype=None) -> bool:
+               dtype=None, v_shape=None, sink: bool = False) -> bool:
     try:
         check_shapes(q_shape, k_shape, start_shape, window=window,
-                     dtype=dtype)
+                     dtype=dtype, v_shape=v_shape, sink=sink)
         return True
     except ValueError:
         return False
@@ -189,8 +204,10 @@ def live_blocks(start, C: int, M: int, kb: int, window=None, first=0):
     return lo, hi - lo + 1
 
 
-def _kernel(s_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            scale, window, C, tr, kb):
+def _kernel(s_ref, q_ref, *refs, scale, window, C, tr, kb, sink=False):
+    # `sink`: one more operand [tr, 1] after q, each row's sink
+    sink_ref, refs = (refs[0], refs[1:]) if sink else (None, refs)
+    k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
     r, j = pl.program_id(1), pl.program_id(2)
     b0, live, q0 = s_ref[0], s_ref[1], s_ref[2]
     # the tile's rows sit at positions q0 + c_lo .. q0 + c_hi of the
@@ -200,8 +217,13 @@ def _kernel(s_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(j == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
+        if sink_ref is None:
+            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
+        else:
+            # the sink as a key seen before any other: exp(0) = 1
+            m_scr[...] = sink_ref[...].astype(jnp.float32)
+            l_scr[...] = jnp.ones_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     def update(masked: bool):
@@ -245,10 +267,12 @@ def _kernel(s_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 def _relay_heads(scalars, k, v, kb: int):
-    """k, v [M, n_kv, hd] -> each [n_kv, M, hd], the key blocks
-    scalars[0] .. scalars[0] + scalars[1] - 1 (of `kb` positions) only:
-    what lies outside them is not read, and not written either."""
+    """k [M, n_kv, hd], v [M, n_kv, hd_v] -> [n_kv, M, hd], [n_kv, M,
+    hd_v], the key blocks scalars[0] .. scalars[0] + scalars[1] - 1 (of
+    `kb` positions) only: what lies outside them is not read, and not
+    written either."""
     M, n_kv, hd = k.shape
+    hd_v = v.shape[-1]
     rb = kb
     while rb % 2 == 0 and rb * n_kv * hd * k.dtype.itemsize \
             > _RELAY_BLOCK_BYTES:
@@ -265,15 +289,19 @@ def _relay_heads(scalars, k, v, kb: int):
     def block(j, s):
         return s[0] * per + jnp.minimum(j, s[1] * per - 1)
 
-    ins = pl.BlockSpec((rb, n_kv, hd), lambda j, s: (block(j, s), 0, 0))
-    outs = pl.BlockSpec((n_kv, rb, hd), lambda j, s: (0, block(j, s), 0))
-    shape = jax.ShapeDtypeStruct((n_kv, M, hd), k.dtype)
+    def ins(d):
+        return pl.BlockSpec((rb, n_kv, d), lambda j, s: (block(j, s), 0, 0))
+
+    def outs(d):
+        return pl.BlockSpec((n_kv, rb, d), lambda j, s: (0, block(j, s), 0))
+
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(M // rb,),
-            in_specs=[ins, ins], out_specs=[outs, outs]),
-        out_shape=[shape, shape],
+            in_specs=[ins(hd), ins(hd_v)], out_specs=[outs(hd), outs(hd_v)]),
+        out_shape=[jax.ShapeDtypeStruct((n_kv, M, hd), k.dtype),
+                   jax.ShapeDtypeStruct((n_kv, M, hd_v), v.dtype)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT),
@@ -282,17 +310,21 @@ def _relay_heads(scalars, k, v, kb: int):
 
 
 def chunk_attention(q, k, v, start, *, softmax_scale: Optional[float] = None,
-                    window: Optional[int] = None, first=0):
+                    window: Optional[int] = None, first=0, sink=None):
     """q [1, C, nq, hd] at positions start .. start + C - 1 (start a
-    traced scalar, or [1]); k, v [1, M, n_kv, hd] holding the positions
+    traced scalar, or [1]); k [1, M, n_kv, hd] and v [1, M, n_kv, hd_v]
+    (hd_v its own, or hd) holding the positions
     first .. first + M - 1 (`first` = 0: the whole cache; a traced
     scalar: the slice a window layer reads), every one of them up to
     start + C - 1 written.  Query i sees key position j iff j <= start +
-    i and, under a `window` (static), j > start + i - window.  Returns
-    [1, C, nq, hd].  Raises ValueError on shapes outside `compatible`
+    i and, under a `window` (static), j > start + i - window.  `sink`
+    [nq]: a scalar a query head in the softmax's denominator.  Returns
+    [1, C, nq, hd_v].  Raises ValueError on shapes outside `compatible`
     (`models/generation._attend_cached_chunk` takes those)."""
     C, nq, hd, M, n_kv, tr, kb = check_shapes(
-        q.shape, k.shape, jnp.shape(start), window=window, dtype=k.dtype)
+        q.shape, k.shape, jnp.shape(start), window=window, dtype=k.dtype,
+        v_shape=v.shape, sink=sink is not None)
+    hd_v = v.shape[-1]
     g = nq // n_kv
     R = g * C
     scale = softmax_scale if softmax_scale is not None else hd ** -0.5
@@ -308,24 +340,35 @@ def chunk_attention(q, k, v, start, *, softmax_scale: Optional[float] = None,
     def key_block(h, r, j, s):
         return h, s[0] + jnp.minimum(j, s[1] - 1), 0
 
-    rows = pl.BlockSpec((None, tr, hd), lambda h, r, j, s: (h, r, 0))
-    keys = pl.BlockSpec((None, kb, hd), key_block)
+    def rows(d):
+        return pl.BlockSpec((None, tr, d), lambda h, r, j, s: (h, r, 0))
+
+    def keys(d):
+        return pl.BlockSpec((None, kb, d), key_block)
+
+    operands, in_specs = [qh.astype(k.dtype)], [rows(hd)]
+    if sink is not None:
+        # row gi * C + c of KV head kvh carries head (kvh, gi)'s sink
+        operands.append(jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(n_kv, g, 1, 1),
+            (n_kv, g, C, 1)).reshape(n_kv, R, 1))
+        in_specs.append(rows(1))
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, window=window, C=C, tr=tr,
-                          kb=kb),
+                          kb=kb, **({} if sink is None else {"sink": True})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n_kv, R // tr, M // kb),
-            in_specs=[rows, keys, keys],
-            out_specs=rows,
+            in_specs=in_specs + [keys(hd), keys(hd_v)],
+            out_specs=rows(hd_v),
             scratch_shapes=[pltpu.VMEM((tr, 1), jnp.float32),
                             pltpu.VMEM((tr, 1), jnp.float32),
-                            pltpu.VMEM((tr, hd), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((n_kv, R, hd), q.dtype),
+                            pltpu.VMEM((tr, hd_v), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((n_kv, R, hd_v), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
-    )(scalars, qh.astype(k.dtype), kh, vh)
-    return out.reshape(n_kv, g, C, hd).transpose(2, 0, 1, 3) \
-        .reshape(1, C, nq, hd)
+    )(scalars, *operands, kh, vh)
+    return out.reshape(n_kv, g, C, hd_v).transpose(2, 0, 1, 3) \
+        .reshape(1, C, nq, hd_v)

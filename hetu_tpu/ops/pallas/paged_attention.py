@@ -90,8 +90,19 @@ at global positions <= positions[s] + i).  It is the same kernel with
 C * nq query rows (decode is C = 1): same walk, same block update, same
 none/int8/int4 page modes.
 
+**Keys wider than the values, and a sink** (`paged_attention`, exact
+pages, single-token).  The V pool's head dim is its own (keys of 256
+lanes, of which a model may use 192, beside values of 128): the K and V
+buffers, the two products and the output take each its width, and
+nothing else of the walk changes.  A `sink` [nq] (float32: a learned
+scalar a query head) stands in the softmax as one more key that has no
+value: the running maximum starts at it and the running sum at 1, where
+they start at -1e30 and 0 without one; the blocks' updates are the same.
+With neither, the lowered program is what it was, operation for
+operation.
+
 Shape contract (drift-tested against `compatible`/`verify_compatible`):
-hd % 128, q heads divide by kv heads, table/positions/q agree on the
+key and value head dims % 128, q heads divide by kv heads, table/positions/q agree on the
 slot count, scales present iff quant, pool head dim halved for int4,
 one page's buffers and temporaries within `_VMEM_LIMIT`, and a page's
 row of KV heads at least one 32-bit word wide (bfloat16 pages of ONE
@@ -121,31 +132,33 @@ _VMEM_LIMIT = 12 << 20
 
 
 def _token_vmem_bytes(rows: int, n_kv: int, hd: int, itemsize: int,
-                      quant: str) -> int:
+                      quant: str, hd_v: Optional[int] = None) -> int:
     """VMEM bytes one cached token of a block takes: K and V in their two
     buffers each, for quantized pages their scale rows (a page's row
     padded to 8 sublanes) and the payload as float32, and the float32
     scores and probabilities of `rows` query rows against its `n_kv`
     keys."""
     hd_p = hd // 2 if quant == "int4" else hd
-    nbytes = 2 * 2 * n_kv * hd_p * itemsize + 3 * rows * n_kv * 4
+    hd_vp = hd_p if hd_v is None else hd_v
+    nbytes = 2 * n_kv * (hd_p + hd_vp) * itemsize + 3 * rows * n_kv * 4
     if quant != "none":
         nbytes += 2 * 2 * 8 * n_kv * 4 + 2 * n_kv * hd * 4
     return nbytes
 
 
 def pages_per_block(rows: int, ps: int, n_kv: int, hd: int, itemsize: int,
-                    max_pages: int, quant: str = "none") -> int:
+                    max_pages: int, quant: str = "none",
+                    hd_v: Optional[int] = None) -> int:
     """Pages the walk fetches and attends at once, from the shapes alone:
     `_BLOCK_TOKENS` tokens where `_VMEM_BUDGET` holds them, fewer where
     it does not, never more than the table is wide, at least one."""
     tokens = min(_BLOCK_TOKENS, _VMEM_BUDGET // _token_vmem_bytes(
-        rows, n_kv, hd, itemsize, quant))
+        rows, n_kv, hd, itemsize, quant, hd_v))
     return max(1, min(tokens // ps, max_pages))
 
 
 def _check_pool(rows, q_heads_hd, pool_shape, table_shape, pos_shape, S, *,
-                quant: str, pool_dtype) -> Tuple[int, int, int]:
+                quant: str, pool_dtype, v_shape=None) -> Tuple[int, int, int]:
     nq, hd = q_heads_hd
     if quant not in ("none", "int8", "int4"):
         raise ValueError(f"paged-attention page mode {quant!r} "
@@ -172,7 +185,18 @@ def _check_pool(rows, q_heads_hd, pool_shape, table_shape, pos_shape, S, *,
         raise ValueError(f"a page's row of {n_kv} KV head(s) of "
                          f"{itemsize}-byte elements is under one 32-bit "
                          f"word; the gather fallback handles it")
-    page_bytes = ps * _token_vmem_bytes(rows, n_kv, hd, itemsize, quant)
+    hd_v = None
+    if v_shape is not None and tuple(v_shape) != tuple(pool_shape):
+        hd_v = v_shape[-1]
+        if tuple(v_shape[:-1]) != (P, ps, n_kv) or hd_v % 128:
+            raise ValueError(f"the V pool {tuple(v_shape)} must be the K "
+                             f"pool's {tuple(pool_shape)} but for a head "
+                             f"dim of its own, % 128")
+        if quant != "none":
+            raise ValueError("keys wider than the values read exact pages, "
+                             f"not {quant!r}")
+    page_bytes = ps * _token_vmem_bytes(rows, n_kv, hd, itemsize, quant,
+                                        hd_v)
     if page_bytes > _VMEM_LIMIT:
         raise ValueError(f"one page of {ps} tokens needs {page_bytes} "
                          f"bytes of VMEM (limit {_VMEM_LIMIT}); the gather "
@@ -182,18 +206,25 @@ def _check_pool(rows, q_heads_hd, pool_shape, table_shape, pos_shape, S, *,
 
 def check_shapes(q_shape, pool_shape, table_shape, pos_shape, *,
                   quant: str = "none", pool_dtype=None,
-                  window: Optional[int] = None
+                  window: Optional[int] = None, v_shape=None,
+                  sink: bool = False
                   ) -> Tuple[int, int, int, int, int, int]:
+    """-> (S, nq, hd, P, ps, n_kv) of q [S, nq, hd] over a K pool [P, ps,
+    n_kv, hd]; `v_shape`: the V pool's where its head dim is its own
+    (None: the K pool's); `sink`: a sink a query head enters the softmax.
+    Both read exact pages."""
     if len(q_shape) != 3 or len(pool_shape) != 4:
         raise ValueError(f"expected q [S, nq, hd] and pool [P, ps, n_kv, "
                          f"hd], got {q_shape} / {pool_shape}")
     if window is not None and (window < 1 or quant != "none"):
         raise ValueError(f"a window ({window}) is at least one position "
                          f"wide and reads exact pages, not {quant!r}")
+    if sink and quant != "none":
+        raise ValueError(f"a sink reads exact pages, not {quant!r}")
     S, nq, hd = q_shape
     P, ps, n_kv = _check_pool(nq, (nq, hd), pool_shape, table_shape,
                               pos_shape, S, quant=quant,
-                              pool_dtype=pool_dtype)
+                              pool_dtype=pool_dtype, v_shape=v_shape)
     return S, nq, hd, P, ps, n_kv
 
 
@@ -215,10 +246,12 @@ def check_shapes_verify(q_shape, pool_shape, table_shape, pos_shape, *,
 
 def compatible(q_shape, pool_shape, table_shape, pos_shape, *,
                quant: str = "none", pool_dtype=None,
-               window: Optional[int] = None) -> bool:
+               window: Optional[int] = None, v_shape=None,
+               sink: bool = False) -> bool:
     try:
         check_shapes(q_shape, pool_shape, table_shape, pos_shape,
-                      quant=quant, pool_dtype=pool_dtype, window=window)
+                      quant=quant, pool_dtype=pool_dtype, window=window,
+                      v_shape=v_shape, sink=sink)
         return True
     except ValueError:
         return False
@@ -345,9 +378,15 @@ def _scale_row(scale_ref, buffer):
     return jnp.concatenate([rows[j] for j in range(rows.shape[0])], axis=1)
 
 
-def _kernel(*refs, scale, C, ps, ppb, n_kv, group, mp, quant, window=None):
+def _kernel(*refs, scale, C, ps, ppb, n_kv, group, mp, quant, window=None,
+            sink=False):
     """One grid step = one slot: its ``C * nq`` query rows (row r is query
-    position r // nq, head r % nq) against its live blocks."""
+    position r // nq, head r % nq) against its live blocks.  `sink`: one
+    more operand [rows, 1] after q, each row's sink."""
+    sink_ref = None
+    if sink:
+        refs = list(refs)
+        sink_ref = refs.pop(3)
     if quant != "none":
         (table_ref, pos_ref, stable_ref, q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm,
          o_ref, k_buf, v_buf, ks_buf, vs_buf, sem, parity) = refs
@@ -360,6 +399,7 @@ def _kernel(*refs, scale, C, ps, ppb, n_kv, group, mp, quant, window=None):
         streams = ((table_ref, k_hbm, k_buf), (table_ref, v_hbm, v_buf))
     T = ppb * ps
     rows, hd = q_ref.shape[1:]
+    hd_v = hd if quant != "none" else v_buf.shape[-1]
     nq = rows // C
     pos = pos_ref[pl.program_id(0)]
 
@@ -386,7 +426,7 @@ def _kernel(*refs, scale, C, ps, ppb, n_kv, group, mp, quant, window=None):
     def block(b, buffer, carry, last, first=False):
         m_prev, l_prev, acc = carry
         k = _load_block(k_buf, buffer, quant=quant, hd=hd)
-        v = _load_block(v_buf, buffer, quant=quant, hd=hd)
+        v = _load_block(v_buf, buffer, quant=quant, hd=hd_v)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [rows, T * n_kv]
@@ -422,12 +462,17 @@ def _kernel(*refs, scale, C, ps, ppb, n_kv, group, mp, quant, window=None):
             p_ = jnp.where(seen, p_ * _scale_row(vs_buf, buffer), 0.0)
         pv = jax.lax.dot_general(
             p_.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [rows, hd]
+            preferred_element_type=jnp.float32)          # [rows, hd_v]
         return m_new, l_new, acc * corr + pv
 
-    carry = (jnp.full((rows, 1), NEG_INF, jnp.float32),
-             jnp.zeros((rows, 1), jnp.float32),
-             jnp.zeros((rows, hd), jnp.float32))
+    if sink_ref is None:
+        m0 = jnp.full((rows, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((rows, 1), jnp.float32)
+    else:
+        # the sink as a key seen before any other: exp(sink - sink) = 1
+        m0 = sink_ref[...].astype(jnp.float32)
+        l0 = jnp.ones((rows, 1), jnp.float32)
+    carry = (m0, l0, jnp.zeros((rows, hd_v), jnp.float32))
     _, l, acc = _walk_live_blocks(
         pages_of, streams, sem, parity, carry, block, ppb=ppb,
         **({} if window is None else {"first_page_of": first_page_of}))
@@ -468,18 +513,21 @@ def _scalar_prefetch(table, positions, scale_table, k_scale, P, ps, n_kv,
 
 
 def _attend(q, k_pool, v_pool, scalars, k_scale, v_scale, *, C, scale,
-            quant, window=None):
+            quant, window=None, sink=None):
     """q ``[S, C * nq, hd]`` over the pools: the one `pallas_call` of
-    this module (decode is C = 1)."""
+    this module (decode is C = 1).  `sink` [rows] float32 or None."""
     S, rows, hd = q.shape
     _, ps, n_kv, hd_p = k_pool.shape
+    hd_vp = v_pool.shape[-1]
+    # the values' own width where it is not the keys' (exact pages)
+    hd_v = hd if hd_vp == hd_p else hd_vp
     mp = scalars[0].shape[1]
     ppb = pages_per_block(rows, ps, n_kv, hd, k_pool.dtype.itemsize, mp,
-                          quant)
+                          quant, None if hd_vp == hd_p else hd_vp)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     operands = [q, k_pool, v_pool]
     scratch = [pltpu.VMEM((2, ppb, ps, n_kv, hd_p), k_pool.dtype),
-               pltpu.VMEM((2, ppb, ps, n_kv, hd_p), v_pool.dtype)]
+               pltpu.VMEM((2, ppb, ps, n_kv, hd_vp), v_pool.dtype)]
     if quant != "none":
         # a page's scales as ONE lane-dense row: Mosaic slices no page
         # out of a plane whose minor dim is the few KV heads
@@ -487,23 +535,29 @@ def _attend(q, k_pool, v_pool, scalars, k_scale, v_scale, *, C, scale,
                      for x in (k_scale, v_scale)]
         scratch += [pltpu.VMEM((2, ppb, 1, ps * n_kv), x.dtype)
                     for x in (k_scale, v_scale)]
+    in_specs = [pl.BlockSpec((1, rows, hd), lambda s, *_: (s, 0, 0))]
+    if sink is not None:
+        # after q; the same rows for every slot
+        operands.insert(1, sink.astype(jnp.float32).reshape(rows, 1))
+        in_specs.append(pl.BlockSpec((rows, 1), lambda s, *_: (0, 0)))
+    n_hbm = len(operands) - len(in_specs)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(S,),
-        in_specs=[pl.BlockSpec((1, rows, hd), lambda s, *_: (s, 0, 0))]
-        + [hbm] * (len(operands) - 1),
-        out_specs=pl.BlockSpec((1, rows, hd), lambda s, *_: (s, 0, 0)),
+        in_specs=in_specs + [hbm] * n_hbm,
+        out_specs=pl.BlockSpec((1, rows, hd_v), lambda s, *_: (s, 0, 0)),
         scratch_shapes=scratch + [
-            pltpu.SemaphoreType.DMA((2, len(operands) - 1)),
+            pltpu.SemaphoreType.DMA((2, n_hbm)),
             pltpu.SMEM((1,), jnp.int32)],
     )
     return pl.pallas_call(
         functools.partial(_kernel, scale=scale, C=C, ps=ps, ppb=ppb,
                           n_kv=n_kv, group=rows // C // n_kv, mp=mp,
                           quant=quant,
-                          **({} if window is None else {"window": window})),
+                          **({} if window is None else {"window": window}),
+                          **({} if sink is None else {"sink": True})),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, rows, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, rows, hd_v), q.dtype),
         # in order: a slot's last block fetches the next slot's first
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
@@ -514,10 +568,11 @@ def _attend(q, k_pool, v_pool, scalars, k_scale, v_scale, *, C, scale,
 def paged_attention(q, k_pool, v_pool, table, positions, *,
                     softmax_scale: Optional[float] = None,
                     k_scale=None, v_scale=None, quant=None,
-                    scale_table=None, window: Optional[int] = None):
+                    scale_table=None, window: Optional[int] = None,
+                    sink=None):
     """Decode attention over paged KV.  q: [S, nq, hd] (one token per
     slot); k_pool/v_pool: [P, page_size, n_kv, hd] (page 0 = the null
-    page); table: [S, max_pages] int32 page ids; positions: [S] int32 —
+    page; the V pool's head dim may be its own, hd_v: exact pages); table: [S, max_pages] int32 page ids; positions: [S] int32 —
     slot s attends over global positions <= positions[s], and only the
     table entries of the pages that hold them are read.  int8 pools
     pass their per-head-vector f32 scales [P, page_size, n_kv] as
@@ -533,18 +588,21 @@ def paged_attention(q, k_pool, v_pool, table, positions, *,
     begins at the block that holds the window's first position, fetches
     no page before that position's and masks what precedes it in its
     page.  None is the kernel as it was, operation for operation.
-    Returns [S, nq, hd].  Raises ValueError on shapes outside
+    ``sink`` [nq] (exact pages): a scalar a query head that stands in
+    the softmax's denominator as one more key and adds no value.
+    Returns [S, nq, hd_v].  Raises ValueError on shapes outside
     `compatible` (the dense-gather fallback in models/generation
     handles those)."""
     quant = _resolve_quant(quant, k_scale, v_scale)
     S, nq, hd, P, ps, n_kv = check_shapes(
         q.shape, k_pool.shape, table.shape, positions.shape, quant=quant,
-        pool_dtype=k_pool.dtype, window=window)
+        pool_dtype=k_pool.dtype, window=window, v_shape=v_pool.shape,
+        sink=sink is not None)
     scalars = _scalar_prefetch(table, positions, scale_table, k_scale,
                                P, ps, n_kv, quant)
     scale = softmax_scale if softmax_scale is not None else hd ** -0.5
     return _attend(q, k_pool, v_pool, scalars, k_scale, v_scale, C=1,
-                   scale=scale, quant=quant, window=window)
+                   scale=scale, quant=quant, window=window, sink=sink)
 
 
 def paged_verify(q, k_pool, v_pool, table, positions, *,

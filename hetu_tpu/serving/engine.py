@@ -335,8 +335,9 @@ class ServingEngine:
         # (models/cache_contract.py): the pool, the prefill scratch and
         # the cache-byte gauges are all sized from this one contract
         self.cache = cache_contract(model)
-        #: some layer reads a window only: pages by kind of layer
-        self.windowed = any(w is not None for w in self.cache.kinds)
+        #: some layer reads a window only, or the layers differ in what a
+        #: token stores (or K is wider than V): pages by kind of layer
+        self.windowed = self.cache.by_kind
         if self.cache.kind != "kv" or self.windowed:
             self._refuse_unbuilt(reshard, draft_model, drafter)
         self.pool = PagePool.for_contract(
@@ -497,24 +498,47 @@ class ServingEngine:
         # chunk program advances IN PLACE (it is donated), one array per
         # array of the contract; every admission is handed zeros of its
         # own, so no call can consume another's buffer
-        self._fresh_scratch = jax.jit(
-            functools.partial(init_cache, model, 1, self.config.max_len))
+        # A WINDOW kind's scratch holds the window and one chunk, and
+        # slides (`extend_cache(slide=True)`), where chunks and pages
+        # line up (a page of the pool is then a block of the scratch)
+        C, ps = self.config.prefill_chunk, self.config.page_size
+        self._slide = self.windowed and C % ps == 0 \
+            and self.config.max_len % ps == 0
+        self._fresh_scratch = jax.jit(functools.partial(
+            init_cache, model, 1, self.config.max_len,
+            **(dict(chunk=C, page=ps) if self._slide else {})))
         from hetu_tpu.serving.kv_pool import contract_bytes_per_token
+        itemsize = jnp.dtype(c.compute_dtype).itemsize
         mode = (self.config.kv_quant if self.config.kv_quant != "none" else
-                {2: "bf16", 4: "fp32"}[jnp.dtype(c.compute_dtype).itemsize])
+                {2: "bf16", 4: "fp32"}[itemsize])
         self._registry.set_gauge(
             "serve.kv_bytes_per_token",
             contract_bytes_per_token(self.cache, mode))
+        #: per kind of layer, the label of its series and the positions
+        #: its prefill scratch holds
+        self._kind_names = tuple("full" if w is None else f"window_{w}"
+                                 for w in self.cache.kinds)
+        self._scratch_positions = tuple(
+            a.shape[2] for a in jax.eval_shape(self._fresh_scratch)[
+                ::len(self.cache.token_shapes)])
         if self.windowed:
-            # what a token costs in each kind of layer: a window kind's
-            # share is paid for the last `window` positions only
-            per_layer = contract_bytes_per_token(self.cache, mode) \
-                / self.cache.num_layers
-            for k, w in enumerate(self.cache.kinds):
+            # what a token costs in each kind of layer, from the kind's
+            # own shapes: a window kind's share is paid for the last
+            # `window` positions only; and what a prefilling request's
+            # scratch takes of each kind
+            for k, kind in enumerate(self._kind_names):
+                layers = len(self.cache.layers_of(k))
                 self._registry.set_gauge(
                     "serve.kv_bytes_per_token",
-                    per_layer * len(self.cache.layers_of(k)),
-                    kind="full" if w is None else f"window_{w}")
+                    itemsize * layers * sum(
+                        math.prod(x) for x in self.cache.token_shapes_of(k)),
+                    kind=kind)
+                self._registry.set_gauge(
+                    "serve.prefill_scratch_bytes",
+                    itemsize * layers * self._scratch_positions[k]
+                    * sum(math.prod(x)
+                          for x in self.cache.stored_shapes_of(k)),
+                    kind=kind)
         #: the running stats vector of the programs of a model that
         #: counts (what `model.STATS` names: an expert model's assignment
         #: counts), on the device between fetches; None for a model whose
@@ -587,11 +611,26 @@ class ServingEngine:
         from hetu_tpu.ops.pallas import resolve_route
         c = self.model.config
         S = self.config.num_slots
+        table_shape = (S, self.scheduler.max_pages)
+        if self.windowed:
+            # one decode program: the kernel for every kind of layer (at
+            # the kind's own shapes), or the gather route for all
+            ok = []
+            for k, (n, w) in enumerate(zip(self.pool.pages_by_kind,
+                                           self.pool.windows)):
+                k_shape, v_shape = (
+                    (n + 1, self.config.page_size) + tuple(x)
+                    for x in self.cache.stored_shapes_of(k))
+                ok.append(resolve_route(
+                    "paged_attn", _pa.check_shapes,
+                    (S, c.num_attention_heads, k_shape[-1]), k_shape,
+                    table_shape, (S,), pool_dtype=self.pool.arrays.k.dtype,
+                    window=w, v_shape=v_shape))
+            return all(ok)
         hd_p = (self.pool.head_dim // 2 if self.pool.quant == "int4"
                 else self.pool.head_dim)
         pool_shape = (self.pool.pages_by_kind[0] + 1, self.config.page_size,
                       self.pool.num_kv_heads, hd_p)
-        table_shape = (S, self.scheduler.max_pages)
         if self.spec:
             q_shape = (S, self.config.spec_k + 1,
                        c.num_attention_heads, c.head_dim)
@@ -600,15 +639,6 @@ class ServingEngine:
                 table_shape, (S,), quant=self.pool.quant,
                 pool_dtype=self.pool.arrays.k.dtype)
         q_shape = (S, c.num_attention_heads, c.head_dim)
-        if self.windowed:
-            # one decode program: the kernel for every kind of layer, or
-            # the gather route for all
-            return all([resolve_route(
-                "paged_attn", _pa.check_shapes, q_shape,
-                (n + 1,) + pool_shape[1:], table_shape, (S,),
-                pool_dtype=self.pool.arrays.k.dtype, window=w)
-                for n, w in zip(self.pool.pages_by_kind,
-                                self.pool.windows)])
         return resolve_route("paged_attn", _pa.check_shapes, q_shape,
                              pool_shape, table_shape, (S,),
                              quant=self.pool.quant,
@@ -653,22 +683,34 @@ class ServingEngine:
                     model, params, tokens, pool_tree, table, positions,
                     *stats)
             else:
-                ck, cv = pool.gather(pool_tree, table)
-                logits, _, (kt, vt) = decode_step_slots(
-                    model, params, tokens, (ck, cv), positions)
+                logits, _, toks = decode_step_slots(
+                    model, params, tokens, pool.gather(pool_tree, table),
+                    positions)
                 with jax.named_scope("kv_write"):
                     pool_tree = pool.write_token(pool_tree, table,
-                                                 positions, kt, vt)
+                                                 positions, *toks)
             nxt = pick_token(logits, positions, sample_args)
             # the stats ride out behind the tokens: one fetch
             return (jnp.concatenate([nxt, *stats]) if stats else nxt,
                     pool_tree)
 
+        slide = (dict(slide=True, max_len=self.config.max_len)
+                 if self._slide else {})
+
         def chunk_fn(params, chunk, cache, start, *stats):
-            return extend_cache(model, params, chunk, cache, start, *stats)
+            return extend_cache(model, params, chunk, cache, start, *stats,
+                                **slide)
+
+        by_kind = self.windowed
 
         def write_fn(pool_tree, pages_row, *caches):
             with jax.named_scope("kv_write"):
+                if by_kind:
+                    # the scratch as the chunk program carries it,
+                    # [layers, 1, positions, ...]: its one row is taken
+                    # HERE, not by an eager slice (and a copy) an array
+                    # at every prompt's end (`_scratch_rows`)
+                    caches = tuple(c[:, 0] for c in caches)
                 return pool.write_pages(pool_tree, pages_row, *caches)
 
         # speculative-decoding verify (serving/spec_decode.py): score
@@ -925,7 +967,7 @@ class ServingEngine:
             return (self.pool.arrays.tree(),
                     jax.tree.map(jnp.asarray,
                                  self.scheduler.null_write_rows()),
-                    *(a[:, 0] for a in self._fresh_scratch()))
+                    *self._scratch_rows(self._fresh_scratch()))
         table = jnp.zeros(self.scheduler.page_table.shape, jnp.int32)
         pos = jnp.zeros(S, jnp.int32)
         sample_args = self._sample_args([]) if self.config.sampling else ()
@@ -1361,6 +1403,37 @@ class ServingEngine:
             "serve.decode_window_context_tokens",
             int(np.minimum(positions[active] + 1, w).sum()))
 
+    def _scratch_rows(self, scratch):
+        """A request's prefill scratch as the page-write program takes
+        it: by kind of layer whole (the program takes the one row), else
+        the row of each array [L, positions, ...], as `adopt_prefilled`
+        ships it."""
+        return scratch if self.windowed else tuple(a[:, 0] for a in scratch)
+
+    def _scratch_bases(self, start: int):
+        """Per kind of layer, the position its prefill scratch begins at
+        when the chunk program has run the chunk at `start`
+        (`models/generation.extend_cache`: a window kind's slides)."""
+        C = self.config.prefill_chunk
+        return [0 if w is None or not self._slide else max(0, start - (M - C))
+                for w, M in zip(self.cache.kinds, self._scratch_positions)]
+
+    def _count_attended_keys(self, start: int, C: int):
+        """`serve.prefill_attended_keys{kind}`: the (query, key) pairs
+        ONE layer of the kind attends in a chunk launch of C rows at
+        `start`: C * start + C (C + 1) / 2 where the kind reads
+        everything, sum_i min(start + i + 1, window) under a window (a
+        chunk's padding rows count: the program computes them)."""
+        for kind, w in zip(self._kind_names, self.cache.kinds):
+            if w is None:
+                pairs = C * start + C * (C + 1) // 2
+            else:
+                # the first `a` rows see start + i + 1 keys, the rest w
+                a = min(max(w - start - 1, 0), C)
+                pairs = a * (start + 1) + a * (a - 1) // 2 + (C - a) * w
+            self._registry.inc("serve.prefill_attended_keys", pairs,
+                               kind=kind)
+
     def _stats_args(self) -> tuple:
         """The extra argument of the programs of a model that counts
         (`model.STATS`): the running stats vector; none otherwise."""
@@ -1771,6 +1844,8 @@ class ServingEngine:
             st.stats.prefill_chunks += 1
             self._registry.inc("serve.prefill_chunks")
             self._registry.inc("serve.prefill_tokens", len(seg))
+            if self.windowed:
+                self._count_attended_keys(s, C)
             if s + C < padded:
                 if self.tracer is not None:
                     self.tracer.on_chunk(req, clock(), st.chunks_done)
@@ -1790,10 +1865,11 @@ class ServingEngine:
             # (COW) — their row entries point at the null page so the
             # write lands harmlessly
             pages_row = self.scheduler.write_rows(
-                slot_idx, base // self.pool.page_size)
+                slot_idx, base // self.pool.page_size,
+                bases=self._scratch_bases(s))
             tree = self._run_write(self.pool.arrays.tree(),
                                    jax.tree.map(jnp.asarray, pages_row),
-                                   *(a[:, 0] for a in st.prefill_cache))
+                                   *self._scratch_rows(st.prefill_cache))
             self.pool.arrays = PoolArrays.from_tree(tree)
             if self.prefix_cache is not None:
                 # index the finished prompt: full page-blocks not yet

@@ -70,14 +70,20 @@ def contract_bytes_per_token(contract, mode: str = "fp32") -> float:
     token STORES (1,152 B a layer for a latent of 576 in bf16), not the
     lanes the device pads them to.  K/V contracts go through
     `kv_bytes_per_token`, which knows the quantized page modes."""
-    if contract.kind == "kv":
-        n_kv, hd = contract.token_shapes[0]
-        return kv_bytes_per_token(contract.num_layers, n_kv, hd, mode)
+    if contract.kind == "kv" and mode in ("int8", "int4"):
+        # one scale a head-vector: K and V of one width
+        return sum(
+            kv_bytes_per_token(len(contract.layers_of(k)),
+                               *contract.token_shapes_of(k)[0], mode)
+            for k in range(len(contract.kinds)))
     if mode not in _ELEM_BYTES:
-        raise ValueError(f"a {len(contract.token_shapes)}-array cache "
-                         f"contract has exact pages only, not {mode!r}")
-    return (contract.num_layers * contract.values_per_token_layer
-            * _ELEM_BYTES[mode])
+        raise ValueError(
+            f"a {len(contract.token_shapes)}-array cache contract has exact "
+            f"pages only, not {mode!r}" if contract.kind != "kv" else
+            f"unknown kv mode {mode!r}; "
+            f"known: {sorted(_ELEM_BYTES)} + ['int8', 'int4']")
+    # every layer its own: the kinds may differ in what a token stores
+    return contract.values_per_token * _ELEM_BYTES[mode]
 
 
 def kv_bytes_per_token(num_layers: int, num_kv_heads: int, head_dim: int,
@@ -141,7 +147,7 @@ class PoolArrays:
     step (a pytree: quant scales are None in the exact mode).  A pool
     with several kinds of layer (exact K/V pages) holds the first kind's
     arrays as `k`, `v` and the further kinds' as `more`, (k, v) after
-    (k, v)."""
+    (k, v), each kind's of its own shape."""
     k: jnp.ndarray
     v: Optional[jnp.ndarray] = None     # None: a one-array (latent) pool
     k_scale: Optional[jnp.ndarray] = None
@@ -269,15 +275,21 @@ class PagePool:
         how far back each layer reads.  `num_pages`: usable pages, one
         number for every kind or one a kind (in the order of
         `contract.kinds`)."""
+        K = len(contract.kinds)
         if contract.kind == "kv":
-            n_kv, hd = contract.token_shapes[0]
+            n_kv, hd = contract.stored_shapes_of(0)[0]
             what = dict(num_kv_heads=n_kv, head_dim=hd)
+            stored = tuple(contract.stored_shapes_of(k) for k in range(K))
+            if any(s != ((n_kv, hd), (n_kv, hd)) for s in stored):
+                # what a token stores differs by kind of layer, or K and
+                # V differ in width: each kind's pages of its own shapes
+                what.update(kind_shapes=stored)
         else:
             (stored,) = contract.stored_shapes
             what = dict(token_shape=tuple(stored))
-        if len(contract.kinds) > 1 or contract.kinds[0] is not None:
+        if K > 1 or contract.kinds[0] is not None:
             what.update(windows=contract.kinds, layers=tuple(
-                contract.layers_of(k) for k in range(len(contract.kinds))))
+                contract.layers_of(k) for k in range(K)))
         return cls(num_layers=contract.num_layers, num_pages=num_pages,
                    page_size=page_size, dtype=contract.dtype, quant=quant,
                    **what, **kw)
@@ -289,7 +301,8 @@ class PagePool:
                  device_arrays: bool = True,
                  token_shape: Optional[Tuple[int, ...]] = None,
                  windows: Tuple[Optional[int], ...] = (None,),
-                 layers: Optional[Tuple[Tuple[int, ...], ...]] = None):
+                 layers: Optional[Tuple[Tuple[int, ...], ...]] = None,
+                 kind_shapes=None):
         if quant not in ("none", "int8", "int4"):
             raise ValueError(f"kv quant mode {quant!r} invalid; "
                              "choices: ('none', 'int8', 'int4')")
@@ -317,9 +330,11 @@ class PagePool:
                 != list(range(num_layers)):
             raise ValueError(f"{K} kinds of layer must part the "
                              f"{num_layers} layers, got {self.layers}")
-        #: some kind reads a window only: tables, page lists and the page
-        #: write's rows are by kind of layer (serving/scheduler.py)
-        self.windowed = K > 1 or self.windows[0] is not None
+        #: some kind reads a window only, or stores shapes of its own:
+        #: tables, page lists and the page write's rows are by kind of
+        #: layer (serving/scheduler.py)
+        self.windowed = K > 1 or self.windows[0] is not None \
+            or kind_shapes is not None
         if self.windowed and (token_shape is not None or quant != "none"):
             raise ValueError("kinds of layer are built for exact K/V "
                              "pages")
@@ -341,6 +356,13 @@ class PagePool:
         self.quant = quant
         #: payload bit width of the stored pages (8 also covers fp modes)
         self.quant_bits = 4 if quant == "int4" else 8
+        # `kind_shapes`: per kind, the (K, V) shapes a token is stored in,
+        # where the kinds differ or K is wider than V (exact pages);
+        # None: every kind's K and V are `num_kv_heads` x `head_dim`
+        if kind_shapes is not None and (quant != "none"
+                                        or len(kind_shapes) != K):
+            raise ValueError("shapes by kind of layer are one (K, V) pair "
+                             "a kind, over exact pages")
         shape = (num_layers, by_kind[0] + 1, page_size) + (
             token_shape or (num_kv_heads, head_dim))
         if not device_arrays:
@@ -364,9 +386,11 @@ class PagePool:
                 k_scale=jnp.zeros(shape[:-1], jnp.float32),
                 v_scale=jnp.zeros(shape[:-1], jnp.float32))
         else:
+            shapes = kind_shapes or (((num_kv_heads, head_dim),) * 2,) * K
             self.arrays = PoolArrays.from_tree(tuple(
-                jnp.zeros((len(ls), n + 1) + shape[2:], dtype)
-                for ls, n in zip(self.layers, by_kind) for _ in "kv"))
+                jnp.zeros((len(ls), n + 1, page_size) + tuple(one), dtype)
+                for ls, n, kv in zip(self.layers, by_kind, shapes)
+                for one in kv))
 
     # ---------------------------------------------------------- allocator
     def pages_for(self, tokens: int) -> int:
@@ -433,7 +457,7 @@ class PagePool:
         """Dense per-slot cache views from the pool.  table: [S, mp]
         int32 -> (ck, cv) [L, S, mp*page_size, n_kv, hd] in the compute
         dtype (int8 pages dequantize here)."""
-        if len(self.windows) > 1:
+        if self.windowed:
             return self._gather_kinds(arrays_tree, table)
         a = PoolArrays.from_tree(arrays_tree)
         L = self.num_layers
@@ -453,39 +477,38 @@ class PagePool:
         return (dense(a.k, a.k_scale), dense(a.v, a.v_scale))
 
     def _gather_kinds(self, arrays_tree, table):
-        """`gather` over several kinds of layer: each kind's pages by its
-        own table [kinds, S, mp], the layers put back in the model's
-        order.  A released page's entry is the null page: what is read
-        there lies behind the layer's window, which the attention
-        masks."""
-        S, mp = table.shape[1:]
+        """`gather` by kind of layer: each kind's pages by its own table
+        [kinds, S, mp] -> (k, v) of [layers of the kind, S, mp *
+        page_size, ...] a kind, kind after kind: a dense cache as
+        `models/generation.init_cache` lays it out.  A released page's
+        entry is the null page: what is read there lies behind the
+        layer's window, which the attention masks."""
+        tables = table if table.ndim == 3 else table[None]
+        S, mp = tables.shape[1:]
         M = mp * self.page_size
-        order = np.argsort(np.concatenate(
-            [np.asarray(ls) for ls in self.layers]))
-        parts = [[], []]
-        for kind, ls in enumerate(self.layers):
-            for i, pool in enumerate(arrays_tree[2 * kind: 2 * kind + 2]):
-                parts[i].append(pool[:, table[kind]].reshape(
-                    len(ls), S, M, self.num_kv_heads, self.head_dim))
-        return tuple(jnp.concatenate(p)[order] for p in parts)
+        return tuple(
+            pool[:, tables[kind]].reshape(
+                (pool.shape[0], S, M) + pool.shape[3:])
+            for kind in range(len(self.layers))
+            for pool in arrays_tree[2 * kind: 2 * kind + 2])
 
-    def write_token(self, arrays_tree, table, positions, k_toks, v_toks):
+    def write_token(self, arrays_tree, table, positions, *toks):
         """Scatter one decoded token's K/V into the pool.  positions:
-        [S] absolute write positions; k_toks/v_toks: [L, S, n_kv, hd].
-        Slots whose table entry is the null page (inactive) dump their
-        write harmlessly into it."""
-        if len(self.windows) > 1:
+        [S] absolute write positions; toks = (k_toks, v_toks), each
+        [L, S, n_kv, hd]; by kind of layer (k, v) of [layers of the kind,
+        S, ...] a kind, kind after kind, as `decode_step_slots` hands
+        them out.  Slots whose table entry is the null page (inactive)
+        dump their write harmlessly into it."""
+        if self.windowed:
             # each kind's layers of the token, through the kind's table
-            out, rows = (), jnp.arange(positions.shape[0])
+            tables = table if table.ndim == 3 else table[None]
+            rows = jnp.arange(positions.shape[0])
             off = positions % self.page_size
-            for kind, ls in enumerate(self.layers):
-                page = table[kind][rows, positions // self.page_size]
-                out += tuple(
-                    pool.at[:, page, off].set(
-                        t[np.asarray(ls)].astype(pool.dtype))
-                    for pool, t in zip(arrays_tree[2 * kind: 2 * kind + 2],
-                                       (k_toks, v_toks)))
-            return out
+            return tuple(
+                pool.at[:, tables[i // 2][rows, positions // self.page_size],
+                        off].set(t.astype(pool.dtype))
+                for i, (pool, t) in enumerate(zip(arrays_tree, toks)))
+        k_toks, v_toks = toks
         a = PoolArrays.from_tree(arrays_tree)
         S = positions.shape[0]
         page = table[jnp.arange(S), positions // self.page_size]
@@ -511,10 +534,10 @@ class PagePool:
         [L, S, C, n_kv, hd].  Positions beyond a slot's table row
         (possible only for inactive rows riding along) redirect to the
         null page instead of clamp-corrupting the row's last page."""
-        if len(self.windows) > 1:
+        if self.windowed:
             raise NotImplementedError(
                 "a block of tokens a slot (the verify step) is not built "
-                "for a pool with several kinds of layer")
+                "for a pool with kinds of layer")
         a = PoolArrays.from_tree(arrays_tree)
         S, C = positions.shape
         mp = table.shape[1]
@@ -539,21 +562,26 @@ class PagePool:
         nv, nvs = put(a.v, a.v_scale, v_toks)
         return PoolArrays(nk, nv, nks, nvs).tree()
 
-    def write_pages(self, arrays_tree, pages_row, ks, vs=None):
+    def write_pages(self, arrays_tree, pages_row, ks, vs=None, *more):
         """Bulk-write a prefilled sequence's K/V into its pages.
         pages_row: [mp] int32 page ids (pad unused tail entries with the
         null page — their garbage lands in page 0); ks/vs:
         [L, mp*page_size, n_kv, hd]; a one-array pool takes its one
         dense cache [L, mp*page_size, *token_shape] as `ks`.
 
-        With a window kind of layer (`windows`), `pages_row` is one entry
-        a kind: [mp] page ids for a kind that reads everything;
-        (ids [n], first) for a window kind: the ids of the n =
-        `hold_pages(max_len, kind)` pages from page `first` on, the only
-        ones whose positions the layer will read again: the part of the
-        scratch before them is not written anywhere."""
+        With a window kind of layer (`windows`), the scratch comes by
+        kind ((k, v) of [layers of the kind, positions, ...] a kind, kind
+        after kind) and `pages_row` is one entry a kind: [mp] page ids
+        for a kind that reads everything; (ids [n], first, base) for a
+        window kind: the ids of the n = `hold_pages(max_len, kind)`
+        pages from page `first` on, the only ones whose positions the
+        layer will read again (the part of the scratch before them is
+        not written anywhere), and the position the kind's scratch
+        begins at (0, or where the chunk program's sliding scratch
+        stood at the prompt's last chunk)."""
         if self.windowed:
-            return self._write_pages_kinds(arrays_tree, pages_row, ks, vs)
+            return self._write_pages_kinds(arrays_tree, pages_row,
+                                           (ks, vs) + more)
         a = PoolArrays.from_tree(arrays_tree)
         L = self.num_layers
         mp = pages_row.shape[0]
@@ -577,7 +605,7 @@ class PagePool:
         nv, nvs = put(a.v, a.v_scale, vs)
         return PoolArrays(nk, nv, nks, nvs).tree()
 
-    def _write_pages_kinds(self, arrays_tree, rows, ks, vs):
+    def _write_pages_kinds(self, arrays_tree, rows, caches):
         """A page at a time, in place (`dynamic_update_slice` in a
         loop): with few KV heads a row the compiler lays a scatter of
         whole pages out with the page's tokens second-minor and copies
@@ -586,17 +614,14 @@ class PagePool:
         the described chip, PR 34)."""
         ps = self.page_size
         out = ()
-        for kind, ls in enumerate(self.layers):
-            row, first = (rows[kind] if self.windows[kind] is not None
-                          else (rows[kind], 0))
-            layers = np.asarray(ls)
+        for kind in range(len(self.layers)):
+            row, first, base = (rows[kind] if self.windows[kind] is not None
+                                else (rows[kind], 0, 0))
             for pool, x in zip(arrays_tree[2 * kind: 2 * kind + 2],
-                               (ks, vs)):
-                def page(i, pool, x=x, row=row, first=first, layers=layers):
-                    # the page's tokens of the kind's layers alone: no
-                    # copy of the scratch by kind either
+                               caches[2 * kind: 2 * kind + 2]):
+                def page(i, pool, x=x, row=row, first=first, base=base):
                     tokens = jax.lax.dynamic_slice_in_dim(
-                        x, (first + i) * ps, ps, axis=1)[layers]
+                        x, (first + i) * ps - base, ps, axis=1)
                     return jax.lax.dynamic_update_slice(
                         pool, tokens[:, None].astype(pool.dtype),
                         (0, row[i]) + (0,) * (pool.ndim - 2))

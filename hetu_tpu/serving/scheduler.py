@@ -223,20 +223,21 @@ class Scheduler:
             released += n
         return released
 
-    def write_rows(self, slot_idx: int, skip_pages: int = 0):
+    def write_rows(self, slot_idx: int, skip_pages: int = 0, bases=None):
         """What the page-write program takes for a slot whose prefill is
         complete (`PagePool.write_pages`): its table row, the first
         `skip_pages` entries (a shared prefix, resident already) the
-        null page; with window layers one entry a kind: the row, or
+        null page; by kind of layer one entry a kind: the row, or
         under a window (the ids of the pages from `first` on that the
-        slot holds, first)."""
+        slot holds, first, the position the kind's scratch begins at:
+        `bases`, a position a kind, else 0)."""
         if not self.pool.windowed:
             row = self.page_tables[0, slot_idx].copy()
             row[:skip_pages] = PagePool.NULL_PAGE
             return row
-        return self._window_rows(self.page_tables[:, slot_idx])
+        return self._window_rows(self.page_tables[:, slot_idx], bases)
 
-    def _window_rows(self, rows):
+    def _window_rows(self, rows, bases=None):
         out = []
         for kind, w in enumerate(self.pool.windows):
             row = rows[kind]
@@ -246,7 +247,8 @@ class Scheduler:
             n = self.pool.hold_pages(self.max_len, kind)
             held = np.flatnonzero(row)
             first = min(int(held[0]) if len(held) else 0, self.max_pages - n)
-            out.append((row[first: first + n].copy(), np.int32(first)))
+            out.append((row[first: first + n].copy(), np.int32(first),
+                        np.int32(bases[kind] if bases else 0)))
         return tuple(out)
 
     def null_write_rows(self):

@@ -361,6 +361,21 @@ def _trinity_block():
                                  prefill_chunk=512, num_pages=(4000, 3700))
 
 
+def _mimo_block():
+    """MiMo-V2-Flash at published widths, 16 of 256 experts held: the
+    dense layer that reads everything (4 KV heads), a window layer and a
+    full layer over experts (window 128 with a sink, 8 KV heads; keys of
+    192 stored in 256 lanes beside values of 128), at the serving cell's
+    slots, page, chunk and max_len: pages and scratch by kind of layer,
+    each kind its own shapes."""
+    from hetu_tpu.models.mimo_v2 import MiMoV2Config, MiMoV2LMHeadModel
+    return MiMoV2LMHeadModel(MiMoV2Config(
+        vocab_size=19072, num_hidden_layers=3, experts_held=16,
+        hybrid_layer_pattern=(0, 1, 0), moe_layer_freq=(0, 1, 1),
+        param_dtype=BF16)), dict(num_slots=16, page_size=64, max_len=16384,
+                                 prefill_chunk=512, num_pages=(4096, 48))
+
+
 #: family -> ((model, ServeConfig overrides) or None for the Llama-2-7B
 #: block, the kernels its decode program must hold as tpu_custom_calls)
 SERVING_FAMILIES = {
@@ -368,6 +383,7 @@ SERVING_FAMILIES = {
     "gpt": (_gpt_block, ("paged_attn",)),
     "kimi": (_kimi_block, ("paged_latent",)),
     "trinity": (_trinity_block, ("paged_attn",)),
+    "mimo": (_mimo_block, ("paged_attn",)),
 }
 
 
@@ -408,6 +424,20 @@ def test_serving_programs_compile_for_one_v5e(family):
         rec = routes["chunk_attn"]
         assert 2 * rec["pallas"] == chunk_calls == 16 and not rec["xla"]
         assert list(rec["why"]) == ["shape gate passes"]
+    elif family == "mimo":
+        # both kinds of layer take both kernels at their own shapes: keys
+        # of 256 lanes against values of 128, groups of 16 and 8, the
+        # window layer with its sink; the window layer's scratch holds
+        # 128 + 512 positions, the full layers' 16,384
+        rec = routes["chunk_attn"]
+        assert 2 * rec["pallas"] == chunk_calls == 6 and not rec["xla"]
+        assert routes["paged_attn_window"]["pallas"] == 1
+        why = routes["paged_attn_shapes"]["why"]
+        assert sorted(why.values()) == [1, 2] and any(
+            "groups of 8, a sink a head" in w for w in why)
+        assert engine._scratch_positions == (16384, 640)
+        assert compiled["prefill_chunk"].memory_analysis() \
+            .temp_size_in_bytes < 0.15e9
     else:
         rec = routes["chunk_attn"]
         assert rec["xla"] and not rec["pallas"] and not chunk_calls, rec

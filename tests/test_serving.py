@@ -531,6 +531,11 @@ def _family_case(family, hd128):
         from test_kimi_k2 import build, ref_logits
         cfg, model, params = build()
         return model, params, lambda p, ids: ref_logits(p, cfg, ids)
+    if family == "mimo":
+        from test_mimo_v2 import build, ref_logits
+        cfg, model, params = build(**(dict(head_dim=192, v_head_dim=128)
+                                      if hd128 else {}))
+        return model, params, lambda p, ids: ref_logits(p, cfg, ids)
     if family == "gpt":
         from hetu_tpu.models.gpt import GPTConfig, GPTLMHeadModel
         kw = dict(hidden_size=256, num_attention_heads=2) if hd128 else {}
@@ -554,7 +559,8 @@ def _family_case(family, hd128):
     ("gpt", "gather"), ("gpt", "paged"),
     ("kimi", "xla"), ("kimi", "kernel"),
     ("hooks-window", "gather"), ("hooks-window", "paged"),
-    ("trinity", "gather"), ("trinity", "paged")])
+    ("trinity", "gather"), ("trinity", "paged"),
+    ("mimo", "gather"), ("mimo", "paged")])
 def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
     """Golden, ONE body for every family: staggered continuous batching
     through the normal path (`run`: scheduler, allocator, page tables,
@@ -574,7 +580,12 @@ def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
     read a window of 12 positions beside one that reads everything, and
     `trinity` the package's own (window and full layers, each with its
     own arrays): pages by kind of layer, released behind the window
-    while the request decodes, over both routes.  `llama-unstacked` is the Llama block built with
+    while the request decodes, over both routes.  `mimo` is the family
+    whose kinds of layer also differ in what a token STORES (1 KV head
+    against 2, keys wider than values; 192 / 128 under the kernel), with
+    a sink in the window layers' softmax, a window smaller than a page
+    pair and than the chunk, a sliding prefill scratch and no shared
+    expert.  `llama-unstacked` is the Llama block built with
     use_scan=False: a layer's own arrays, called, never scanned."""
     monkeypatch.setenv("HETU_TPU_PALLAS",
                        "1" if route in ("paged", "kernel") else "0")

@@ -165,10 +165,10 @@ def _prefill_then_decode(model, params, seq, plen):
         table = jnp.asarray(sched.page_table)
         tokens, positions = np.zeros(3, np.int32), np.zeros(3, np.int32)
         tokens[slot], positions[slot] = seq[t], t
-        lg, _, (kt, vt) = step(model, params, jnp.asarray(tokens),
-                               pool.gather(tree, table),
-                               jnp.asarray(positions))
-        tree = pool.write_token(tree, table, jnp.asarray(positions), kt, vt)
+        lg, _, toks = step(model, params, jnp.asarray(tokens),
+                           pool.gather(tree, table),
+                           jnp.asarray(positions))
+        tree = pool.write_token(tree, table, jnp.asarray(positions), *toks)
         logits.append(np.asarray(lg[slot])[None])
         st.pos = t + 1
     return np.concatenate(logits), held, st, sched

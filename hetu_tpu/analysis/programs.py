@@ -178,7 +178,6 @@ def serving_decode_text(*, optimized: bool = False) -> str:
     XLA compile and returns the post-optimization HLO (the lints'
     input); the default traced text is the sweep's fingerprint
     surface."""
-    import jax.numpy as jnp
     from hetu_tpu.models.llama import LlamaConfig, LlamaLMHeadModel
     from hetu_tpu.serving import ServeConfig, ServingEngine
     cfg = LlamaConfig(vocab_size=256, hidden_size=64,
@@ -192,12 +191,9 @@ def serving_decode_text(*, optimized: bool = False) -> str:
     eng = ServingEngine(model, params, ServeConfig.from_flags(
         page_size=8, max_len=32, prefill_chunk=8))
     try:
-        slots = eng.scheduler.num_slots
-        table = jnp.zeros((slots, eng.scheduler.max_pages), jnp.int32)
-        toks = jnp.zeros(slots, jnp.int32)
-        pos = jnp.zeros(slots, jnp.int32)
-        lowered = eng._decode_jit.lower(
-            params, eng.pool.arrays.tree(), table, toks, pos)
+        args = list(eng._dummy_args("decode"))
+        args[0] = params
+        lowered = eng._decode_jit.lower(*args)
         return (lowered.compile().as_text() if optimized
                 else lowered.as_text())
     finally:

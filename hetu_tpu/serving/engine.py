@@ -84,17 +84,39 @@ logger = get_logger("serving.engine")
 #: the host phase spans of one `ServingEngine.step`, in the order a step
 #: enters them, all nested in `serve.step`.  The two marked SYNC wait
 #: for the device; the others only dispatch to it or work on the host.
+#: Both waits come AFTER the step's dispatches (docs/serving.md).
 STEP_PHASES = (
     "serve.admit",            # fault results, deadlines, admission, stalls
     "serve.prefill_chunk",    # per prefilling slot: ids + chunk dispatch
-    "serve.first_token",      # SYNC: the last chunk's first token
     "serve.page_write",       # scratch -> pages dispatch, prefix insert
     "serve.decode_build",     # positions, tokens, page table, sampling
     "serve.decode_dispatch",  # the decode (or verify) program
-    "serve.token_fetch",      # SYNC: the step's tokens to the host
+    "serve.token_fetch",      # SYNC: the decode BEFORE this step's, to the host
+    "serve.first_token",      # SYNC: this step's prompts' first tokens
     "serve.emit",             # per-token bookkeeping, finishes
     "serve.housekeeping",     # numerics, gauges, health, brownout, reshard
 )
+
+
+@dataclasses.dataclass
+class _InFlight:
+    """A decode (or verify) program dispatched whose tokens the host has
+    not fetched: its output on the device, the (slot index, SlotState)
+    rows of its batch, and when the host began to build it."""
+    out: tuple
+    rows: list
+    t0: float
+
+
+@dataclasses.dataclass
+class _PromptEnd:
+    """A prompt whose last chunk is dispatched and whose first token the
+    host has not fetched: the chunk program's greedy token and, for a
+    sampled one, the logits row it is drawn from, on the device."""
+    slot: int
+    st: object
+    first: object
+    logits_row: object
 
 
 def first_token_from_logits(req, logits_row, position: int, *,
@@ -545,6 +567,14 @@ class ServingEngine:
         #: STATS is empty: its programs carry none
         self._stats_zero = model.zero_stats() if model.STATS else None
         self._stats_acc = self._stats_zero
+        #: the decode program dispatched last whose tokens the host has
+        #: not fetched (`step`: one step stays queued on the device), and
+        #: what the next program reads in its place when there is none
+        self._inflight: Optional[_InFlight] = None
+        self._no_tokens = jnp.zeros(
+            self.config.num_slots + len(model.STATS), jnp.int32)
+        #: perf_counter at the last emit of a decode program's tokens
+        self._landed_t = 0.0
         with record_routes(self.kernel_routes):
             self._build_programs()
 
@@ -670,8 +700,14 @@ class ServingEngine:
 
         paged = self.decode_paged
 
-        def decode_fn(params, pool_tree, table, tokens, positions, *rest):
+        def decode_fn(params, pool_tree, table, tokens, positions, prev,
+                      *rest):
             stats, sample_args = rest[:counts], rest[counts:]
+            # a slot's token is the host's where the host knows it; -1
+            # where it is what the decode program before this one gave
+            # the slot (`prev`, that program's output, never fetched in
+            # between: `ServingEngine.step`)
+            tokens = jnp.where(tokens < 0, prev[:tokens.shape[0]], tokens)
             if paged:
                 # gather-free: the model's `attend_paged` walks the page
                 # table directly; this token's entries are scattered
@@ -697,9 +733,14 @@ class ServingEngine:
         slide = (dict(slide=True, max_len=self.config.max_len)
                  if self._slide else {})
 
-        def chunk_fn(params, chunk, cache, start, *stats):
-            return extend_cache(model, params, chunk, cache, start, *stats,
-                                **slide)
+        def chunk_fn(params, chunk, cache, start, row, *stats):
+            logits, cache, *stats = extend_cache(
+                model, params, chunk, cache, start, *stats, **slide)
+            # the greedy first token of a prompt that ends on `row` of
+            # this chunk: taken here, fetched at the step's end
+            with jax.named_scope("lm_head"):
+                first = jnp.argmax(logits[0, row], axis=-1).astype(jnp.int32)
+            return (logits, first, cache, *stats)
 
         by_kind = self.windowed
 
@@ -830,9 +871,9 @@ class ServingEngine:
                                       pool_tree, table, tokens, positions,
                                       *sample_args)
 
-            def chunk_fn(params, chunk, cache, start):
+            def chunk_fn(params, chunk, cache, start, row):
                 return base_chunk_fp(dequantize_expert_tree(params, spec),
-                                     chunk, cache, start)
+                                     chunk, cache, start, row)
 
             def verify_fn(params, pool_tree, table, tokens, positions,
                           *sample_args):
@@ -962,7 +1003,8 @@ class ServingEngine:
         stats = self._stats_args()
         if program == "prefill_chunk":
             return (self.params, jnp.zeros((1, C), jnp.int32),
-                    self._fresh_scratch(), jnp.int32(0), *stats)
+                    self._fresh_scratch(), jnp.int32(0), jnp.int32(0),
+                    *stats)
         if program == "write_pages":
             return (self.pool.arrays.tree(),
                     jax.tree.map(jnp.asarray,
@@ -973,7 +1015,8 @@ class ServingEngine:
         sample_args = self._sample_args([]) if self.config.sampling else ()
         if program == "decode":
             return (self.params, self.pool.arrays.tree(), table,
-                    jnp.zeros(S, jnp.int32), pos, *stats, *sample_args)
+                    jnp.zeros(S, jnp.int32), pos, self._no_tokens, *stats,
+                    *sample_args)
         if program != "verify":
             raise ValueError(f"unknown program {program!r}")
         K1 = self.config.spec_k + 1
@@ -1015,9 +1058,15 @@ class ServingEngine:
             nxt, _, tree = self._run_verify(*self._dummy_args("verify"))
         else:
             nxt, tree = self._run_decode(*self._dummy_args("decode"))
+            # and as the queued step dispatches it: the tokens of the
+            # decode before it an output still on the device
+            self.pool.arrays = PoolArrays.from_tree(tree)
+            args = list(self._dummy_args("decode"))
+            args[5] = nxt
+            nxt, tree = self._run_decode(*args)
         self.pool.arrays = PoolArrays.from_tree(tree)
-        lg, cache = self._chunk_jit(
-            *self._dummy_args("prefill_chunk"))[:2]
+        lg, _, cache = self._chunk_jit(
+            *self._dummy_args("prefill_chunk"))[:3]
         tree = self._run_write(*self._dummy_args("write_pages"))
         self.pool.arrays = PoolArrays.from_tree(tree)
         if self._prime_jit is not None:
@@ -1084,6 +1133,9 @@ class ServingEngine:
                 f"{type(self.model).__name__} has layers that read a window "
                 "only; the disaggregated prefill tier (adopt_prefilled, "
                 "serving/disagg.py) is not built for them")
+        # the shipment's pages and first token are written from the host:
+        # with nothing queued, and every finish seen (a slot may be free)
+        self._drain("adopt", lambda: now)
         adm = self.scheduler.admit_direct(req, now)
         if adm is None:
             reason = self.scheduler.last_stall or "none"
@@ -1171,11 +1223,48 @@ class ServingEngine:
     def step(self, now: float) -> List[RequestResult]:
         """One engine iteration at driver time `now`: admit every
         admissible queued request (reservation only), advance each
-        PREFILLING slot by exactly ONE chunk, then one decode step over
-        the slots whose prefill is complete.  One-chunk-per-step is the
-        disaggregation contract: a long prompt adds engine steps for its
-        own slot, never a multi-chunk stall to the decode batch's
-        inter-token gap.  Returns requests that finished this step.
+        PREFILLING slot by exactly ONE chunk, dispatch one decode step
+        over the slots whose prefill is complete, and only then wait for
+        the device.  One-chunk-per-step is the disaggregation contract: a
+        long prompt adds engine steps for its own slot, never a
+        multi-chunk stall to the decode batch's inter-token gap.  Returns
+        requests that finished this step.
+
+        **One step stays queued on the device.**  Step k dispatches its
+        chunk programs and decode k, and then fetches the tokens of
+        decode k-1 (`serve.token_fetch`) and the first tokens of the
+        prompts that ended in step k (`serve.first_token`): while the
+        host emits, tidies, returns to its caller, admits and builds
+        step k+1, the device runs decode k.  Decode k needs nothing the
+        host has not seen: a slot's position is the host's own count
+        (`SlotState.pos + inflight`), and its token is decode k-1's
+        output, selected per slot ON THE DEVICE against the host's
+        vector (a slot whose token the host knows: a first token; -1
+        otherwise).  A slot whose tokens are all dispatched
+        (`len(generated) + inflight == max_new_tokens`) is not in the
+        next batch, so no row is computed past a length finish; an EOS is
+        seen one fetch late, and the one row already dispatched for that
+        slot is discarded (`serve.overrun_rows`): it wrote at a position
+        the slot's own reservation covers.  A prompt's first token is the
+        chunk program's own argmax of the row the prompt ends on, fetched
+        at the step's end; its slot joins the NEXT step's decode from
+        the host's vector.  A slot is in the scheduler until its last
+        token is emitted, and past its prefill only with a first token.
+
+        **The pool needs no ordering of its own.**  Every program takes
+        the pool tree and returns it (donated), so the device runs them
+        in dispatch order; pages the host releases (`_maybe_finish`,
+        `_release_behind_windows`) while a program that reads them is in
+        flight can only be written by programs dispatched later.
+
+        **The same loop drains** (`_drain`: fetch and emit at once;
+        `serve.pipeline_drains{why}`) where what comes next depends on
+        values the host has not seen, or rewrites slots, pages or
+        parameters under the programs: speculative decoding (the accepted
+        count sets the positions: every step), a sampled first token,
+        a preemption, `fail_over`, a deadline that expires a live slot,
+        brownout pressure, a reshard, `adopt_prefilled`, a step with
+        nothing to dispatch, `close` and the end of `run`.
 
         Every dispatch, sync and bookkeeping block runs inside one of the
         host phase spans of `STEP_PHASES` (utils/profiling.phase_span): a
@@ -1191,141 +1280,39 @@ class ServingEngine:
             return now + (time.perf_counter() - t0)
 
         phases: dict = {}
+        finished: List[RequestResult] = []
         with jax.profiler.TraceAnnotation("serve.step"):
-            with phase_span("serve.admit", phases):
-                finished: List[RequestResult] = []
-                if self._fault_results:
-                    finished.extend(self._fault_results)
-                    self._fault_results.clear()
-                if self.config.deadline:
-                    # before admissions: an expired queued request must
-                    # not grab a slot on the step it dies
-                    self._expire_deadlines(clock(), finished)
-                while True:
-                    t_adm = clock()
-                    adm = self.scheduler.admit_next(t_adm)
-                    if adm is None:
-                        # SLO-class preemption (HETU_TPU_SERVE_PREEMPT):
-                        # a stalled strictly-higher-priority head may
-                        # evict the lowest-priority live slot and retry
-                        # the admission
-                        if (self.config.preempt and self.scheduler.queue
-                                and self._try_preempt(clock())):
-                            continue
-                        break
-                    slot_idx, st = adm
-                    st.prefilling = True
-                    if self.ledger is not None:
-                        self.ledger.on_admit(st.request.rid, len(st.pages),
-                                             t_adm)
-                    self._start_prefill(slot_idx, st, t_adm)
-                    if self.tracer is not None:
-                        self.tracer.on_admit(st.request, slot_idx, t_adm,
-                                             shared_tokens=st.shared_tokens)
-                if self.scheduler.queue:
-                    # admission declined with work queued: count the
-                    # stall and stamp the scheduler's reserve-on-admit
-                    # attribution on every waiting request (the counter
-                    # must not depend on the tracing flag — it is the
-                    # registry's stall signal)
-                    reason = self.scheduler.last_stall or "none"
-                    self._registry.inc("serve.admission_stalls",
-                                       reason=reason)
-                    if self.tracer is not None:
-                        self.tracer.on_stall(
-                            [r.rid for r in self.scheduler.queue], reason)
+            while True:
+                with phase_span("serve.admit", phases):
+                    why = self._admit(clock, finished)
+                if why is None:
+                    break
+                self._drain(why, clock, finished, phases)
 
+            ends: List[_PromptEnd] = []
             for i in self.scheduler.active_slots():
                 st = self.scheduler.slots[i]
                 if st is not None and st.prefilling:
-                    self._advance_prefill(i, st, clock, finished, phases)
+                    self._advance_prefill(i, st, clock, ends, phases)
 
-            active = [i for i in self.scheduler.active_slots()
-                      if not self.scheduler.slots[i].prefilling]
-            if active:
-                td = time.perf_counter()
-                with phase_span("serve.decode_build", phases):
-                    # the decode batch's inputs are DERIVED from scheduler
-                    # state every step (single source of truth): last
-                    # emitted token + next write position per decoding
-                    # slot; empty/prefilling rows ride along at (0, 0)
-                    # writing into their masked region
-                    S = self.config.num_slots
-                    positions = np.zeros(S, np.int32)
-                    for i in active:
-                        positions[i] = self.scheduler.slots[i].pos
-                    if self.windowed:
-                        self._release_behind_windows(active, positions)
-                    # what this decode step's attention reads: every
-                    # slot's cached tokens, the one it writes included
-                    self._registry.inc("serve.decode_slot_steps",
-                                       len(active))
-                    self._registry.inc("serve.decode_context_tokens",
-                                       int(positions.sum()) + len(active))
-                    sample_args = (self._sample_args(active)
-                                   if self.config.sampling else ())
-                if self.spec:
-                    emitted = self._spec_decode_step(active, positions,
-                                                     sample_args, phases)
-                else:
-                    with phase_span("serve.decode_build", phases):
-                        tokens = np.zeros(S, np.int32)
-                        for i in active:
-                            tokens[i] = \
-                                self.scheduler.slots[i].generated[-1]
-                        decode_args = (
-                            self.params, self.pool.arrays.tree(),
-                            self._decode_table(active),
-                            jnp.asarray(tokens), jnp.asarray(positions),
-                            *self._stats_args(), *sample_args)
-                    with phase_span("serve.decode_dispatch", phases):
-                        nxt, pool_tree = self._run_decode(*decode_args)
-                    with phase_span("serve.token_fetch", phases):
-                        # the step's one wait for the device
-                        nxt = np.asarray(nxt)
-                    with phase_span("serve.emit", phases):
-                        self._note_program_stats(nxt[S:])
-                        self.pool.arrays = PoolArrays.from_tree(pool_tree)
-                        emitted = {i: [int(nxt[i])] for i in active}
-                with phase_span("serve.emit", phases):
-                    decode_wall = time.perf_counter() - td
-                    self._registry.inc("serve.decode_steps")
-                    # token_latency_s is the USER-visible inter-token
-                    # gap: every active slot advances >= one token per
-                    # decode step, so the gap IS the step wall.  The
-                    # amortized per-token engine cost (wall / tokens
-                    # emitted — the throughput number) is its own
-                    # series; conflating them would understate latency
-                    # by up to num_slots x.
-                    n_emitted = sum(len(v) for v in emitted.values())
-                    self._registry.observe("serve.token_latency_s",
-                                           decode_wall)
-                    self._registry.observe("serve.token_cost_s",
-                                           decode_wall / max(n_emitted, 1))
-                    tnow = clock()
-                    n_done0 = len(finished)
-                    for i in active:
-                        st = self.scheduler.slots[i]
-                        for tok in emitted[i]:
-                            st.generated.append(tok)
-                            st.pos += 1
-                            st.stats.token_ts.append(tnow)
-                            self._registry.inc("serve.tokens_out")
-                            if self.tracer is not None:
-                                self.tracer.on_token(st.request, tnow)
-                            self._maybe_finish(i, st, tok, tnow, finished)
-                            if self.scheduler.slots[i] is None:
-                                break    # finished: drop surplus drafts
-                    if self.tracer is not None and len(finished) > n_done0:
-                        # an eviction changed the batch composition:
-                        # split the survivors' decode segments so the
-                        # boundary is visible
-                        survivors = [
-                            self.scheduler.slots[i].request.rid
-                            for i in self.scheduler.active_slots()
-                            if not self.scheduler.slots[i].prefilling]
-                        if survivors:
-                            self.tracer.on_split(survivors, tnow, "evict")
+            # every slot past its prefill whose tokens are not all
+            # dispatched: a length finish is known here, without a fetch
+            batch = [i for i, st in enumerate(self.scheduler.slots)
+                     if st is not None and not st.prefilling
+                     and len(st.generated) + st.inflight
+                     < st.request.max_new_tokens]
+            older, self._inflight = self._inflight, None
+            if batch:
+                self._inflight = self._dispatch_decode(batch, older, phases)
+            if older is not None:
+                if self._inflight is None:
+                    self._registry.inc("serve.pipeline_drains", why="idle")
+                self._land(older, clock, finished, phases,
+                           behind=self._inflight is not None)
+            if ends:
+                self._land_first_tokens(ends, clock, finished, phases)
+            if self.spec:
+                self._drain("spec", clock, finished, phases)
 
             with phase_span("serve.housekeeping", phases):
                 self.steps_done += 1
@@ -1351,11 +1338,12 @@ class ServingEngine:
                         queue_depth=self.scheduler.queue_depth,
                         page_util=self.pool.utilization, t=clock())
                 if self.config.brownout:
-                    self._maybe_brownout(clock(), finished)
+                    self._maybe_brownout(clock, finished, phases)
 
                 if self.reshard is not None:
                     tier = self.reshard.observe(self.scheduler.queue_depth)
                     if tier is not None:
+                        self._drain("reshard", clock, finished, phases)
                         t_pause0 = clock()
                         with self._registry.timer("serve.reshard_s"):
                             self.params = self.reshard.reshard(
@@ -1388,6 +1376,194 @@ class ServingEngine:
                 self._last_clock = clock()
         self._note_step_phases(now, time.perf_counter() - t0, phases)
         return finished
+
+    def _admit(self, clock, finished) -> Optional[str]:
+        """The step's `serve.admit` phase: between-step fault results,
+        deadline expiry, the admission loop, the stall stamp.  Returns
+        why the step has to drain first (a deadline is about to expire a
+        live slot, a preemption to evict one: each leaves with every
+        token it was given, as if nothing had been queued), to be called
+        again after the drain; else None."""
+        if self._fault_results:
+            finished.extend(self._fault_results)
+            self._fault_results.clear()
+        if self.config.deadline:
+            # before admissions: an expired queued request must
+            # not grab a slot on the step it dies
+            t_dead = clock()
+            if self._inflight is not None and self._overdue_slots(t_dead):
+                return "deadline"
+            self._expire_deadlines(t_dead, finished)
+        while True:
+            t_adm = clock()
+            adm = self.scheduler.admit_next(t_adm)
+            if adm is None:
+                # SLO-class preemption (HETU_TPU_SERVE_PREEMPT):
+                # a stalled strictly-higher-priority head may
+                # evict the lowest-priority live slot and retry
+                # the admission
+                if self.config.preempt and self.scheduler.queue:
+                    if (self._inflight is not None
+                            and self.scheduler.preempt_victim(
+                                self.scheduler.queue[0].slo.priority)
+                            is not None):
+                        return "preempt"
+                    if self._try_preempt(clock()):
+                        continue
+                break
+            slot_idx, st = adm
+            st.prefilling = True
+            if self.ledger is not None:
+                self.ledger.on_admit(st.request.rid, len(st.pages), t_adm)
+            self._start_prefill(slot_idx, st, t_adm)
+            if self.tracer is not None:
+                self.tracer.on_admit(st.request, slot_idx, t_adm,
+                                     shared_tokens=st.shared_tokens)
+        if self.scheduler.queue:
+            # admission declined with work queued: count the
+            # stall and stamp the scheduler's reserve-on-admit
+            # attribution on every waiting request (the counter
+            # must not depend on the tracing flag — it is the
+            # registry's stall signal)
+            reason = self.scheduler.last_stall or "none"
+            self._registry.inc("serve.admission_stalls", reason=reason)
+            if self.tracer is not None:
+                self.tracer.on_stall(
+                    [r.rid for r in self.scheduler.queue], reason)
+        return None
+
+    def _dispatch_decode(self, batch, older, phases) -> _InFlight:
+        """Build and dispatch one decode (or verify) program over the
+        slots of `batch`, behind `older` (the decode before it, unfetched,
+        or None): nothing here waits for the device."""
+        t0 = time.perf_counter()
+        slots = self.scheduler.slots
+        with phase_span("serve.decode_build", phases):
+            # the decode batch's inputs are DERIVED from scheduler
+            # state every step (single source of truth): next write
+            # position per decoding slot, by the host's own count of the
+            # rows it has dispatched; empty/prefilling rows ride along at
+            # (0, 0) writing into their masked region
+            S = self.config.num_slots
+            positions = np.zeros(S, np.int32)
+            for i in batch:
+                positions[i] = slots[i].pos + slots[i].inflight
+            if self.windowed:
+                self._release_behind_windows(batch, positions)
+            # what this decode step's attention reads: every
+            # slot's cached tokens, the one it writes included
+            self._registry.inc("serve.decode_steps")
+            if older is not None:
+                self._registry.inc("serve.decode_steps_overlapped")
+            self._registry.inc("serve.decode_slot_steps", len(batch))
+            self._registry.inc("serve.decode_context_tokens",
+                               int(positions.sum()) + len(batch))
+            sample_args = (self._sample_args(batch)
+                           if self.config.sampling else ())
+        if self.spec:
+            out = self._spec_dispatch(batch, positions, sample_args, phases)
+        else:
+            with phase_span("serve.decode_build", phases):
+                # last emitted token per slot; -1 where that token is
+                # `older`'s, on the device: the program takes it there
+                tokens = np.zeros(S, np.int32)
+                for i in batch:
+                    tokens[i] = (-1 if slots[i].inflight
+                                 else slots[i].generated[-1])
+                decode_args = (
+                    self.params, self.pool.arrays.tree(),
+                    self._decode_table(batch),
+                    jnp.asarray(tokens), jnp.asarray(positions),
+                    self._no_tokens if older is None else older.out[0],
+                    *self._stats_args(), *sample_args)
+            with phase_span("serve.decode_dispatch", phases):
+                nxt, pool_tree = self._run_decode(*decode_args)
+                self.pool.arrays = PoolArrays.from_tree(pool_tree)
+                # the stats ride out behind this program's tokens: what
+                # is dispatched after it counts from zero again
+                self._stats_acc = self._stats_zero
+            out = (nxt,)
+        rows = [(i, slots[i]) for i in batch]
+        for _, st in rows:
+            st.inflight += 1
+        return _InFlight(out, rows, t0)
+
+    def _land(self, flight: _InFlight, clock, finished, phases,
+              behind: bool = False):
+        """Fetch a dispatched decode program's tokens and emit them to
+        their requests.  `behind`: a later decode is dispatched behind it
+        (the deferred fetch: `serve.fetch_wait_s` is what the host waits
+        there; near zero, the host sets the pace)."""
+        with phase_span("serve.token_fetch", phases):
+            tw = time.perf_counter()
+            host = jax.device_get(flight.out)
+            if behind:
+                self._registry.observe("serve.fetch_wait_s",
+                                       time.perf_counter() - tw)
+        with phase_span("serve.emit", phases):
+            if self.spec:
+                emitted = self._spec_accept(flight.rows, *host)
+            else:
+                nxt, = host
+                self._note_program_stats(nxt[self.config.num_slots:])
+                emitted = {i: [int(nxt[i])] for i, _ in flight.rows}
+            # token_latency_s is the USER-visible inter-token
+            # gap: every active slot advances >= one token per
+            # decode step, so the gap IS the time from the emit before
+            # this one (or from this program's build, if later).  The
+            # amortized per-token engine cost (wall / tokens
+            # emitted — the throughput number) is its own
+            # series; conflating them would understate latency
+            # by up to num_slots x.
+            t = time.perf_counter()
+            decode_wall = t - max(flight.t0, self._landed_t)
+            self._landed_t = t
+            n_emitted = sum(len(v) for v in emitted.values())
+            self._registry.observe("serve.token_latency_s", decode_wall)
+            self._registry.observe("serve.token_cost_s",
+                                   decode_wall / max(n_emitted, 1))
+            tnow = clock()
+            n_done0 = len(finished)
+            for i, st in flight.rows:
+                if self.scheduler.slots[i] is not st:
+                    # the slot ended by an EOS the host saw after this
+                    # row was dispatched: computed, counted, discarded
+                    self._registry.inc("serve.overrun_rows")
+                    continue
+                st.inflight -= 1
+                for tok in emitted[i]:
+                    st.generated.append(tok)
+                    st.pos += 1
+                    st.stats.token_ts.append(tnow)
+                    self._registry.inc("serve.tokens_out")
+                    if self.tracer is not None:
+                        self.tracer.on_token(st.request, tnow)
+                    self._maybe_finish(i, st, tok, tnow, finished)
+                    if self.scheduler.slots[i] is None:
+                        break    # finished: drop surplus drafts
+            if self.tracer is not None and len(finished) > n_done0:
+                # an eviction changed the batch composition:
+                # split the survivors' decode segments so the
+                # boundary is visible
+                survivors = [
+                    self.scheduler.slots[i].request.rid
+                    for i in self.scheduler.active_slots()
+                    if not self.scheduler.slots[i].prefilling]
+                if survivors:
+                    self.tracer.on_split(survivors, tnow, "evict")
+
+    def _drain(self, why: str, clock, finished=None, phases=None):
+        """Fetch and emit what is queued on the device, now (nothing
+        queued: nothing done, nothing counted).  Between steps
+        (`finished` None) a finish parks with the between-step fault
+        results, for the next step to return."""
+        flight, self._inflight = self._inflight, None
+        if flight is None:
+            return
+        self._registry.inc("serve.pipeline_drains", why=why)
+        self._land(flight, clock,
+                   self._fault_results if finished is None else finished,
+                   {} if phases is None else phases)
 
     def _release_behind_windows(self, active, positions):
         """Before a decode step: every window layer's pages that have
@@ -1440,19 +1616,20 @@ class ServingEngine:
         return () if self._stats_acc is None else (self._stats_acc,)
 
     def _note_program_stats(self, values):
-        """The programs' running stats vector, fetched behind the
-        step's tokens (empty for a model that counts nothing), into the
-        counters the MODEL names
+        """The programs' running stats vector, fetched behind a decode
+        program's tokens (empty for a model that counts nothing), into
+        the counters the MODEL names
         (`model.STATS`: (counter, "sum" | "max") per entry): a sum as an
-        increment, a maximum as the running maximum; the device-side
-        vector starts again from zero."""
+        increment, a maximum as the running maximum.  The device-side
+        vector started again from zero when that program was DISPATCHED
+        (`_dispatch_decode`), so each fetched vector holds what ran since
+        the decode before it."""
         for (name, how), v in zip(self.model.STATS, values):
             more = int(v)
             if how == "max":
                 more -= self._registry.counter_value(name)
             if more > 0:
                 self._registry.inc(name, more)
-        self._stats_acc = self._stats_zero
 
     def _note_step_phases(self, now: float, step_s: float, phases: dict):
         """The finished step's phase record into the registry, and into
@@ -1525,6 +1702,9 @@ class ServingEngine:
         is the REQUEST-state recovery.  Returns
         ``{"requeued": [rids], "exhausted": [rids]}``."""
         now = self._last_clock if now is None else now
+        # what the dying replica had queued on its device is fetched
+        # first: a request is discarded with every token it was given
+        self._drain("fail_over", lambda: now)
         requeued: List[int] = []
         exhausted: List[int] = []
         self._registry.inc("serve.failovers")
@@ -1579,6 +1759,14 @@ class ServingEngine:
                         queue_depth=self.scheduler.queue_depth)
         return {"requeued": requeued, "exhausted": exhausted}
 
+    def _overdue_slots(self, now: float) -> List[int]:
+        """The live slots whose request is older than its SLO class
+        deadline."""
+        return [i for i in self.scheduler.active_slots()
+                if (d := self.scheduler.slots[i].request.slo.deadline_s)
+                is not None
+                and now - self.scheduler.slots[i].request.arrival_t > d]
+
     def _expire_deadlines(self, now: float, finished):
         """Terminate every queued or live request older than its SLO
         class deadline (HETU_TPU_SERVE_DEADLINE) as `deadline_exceeded`
@@ -1595,12 +1783,9 @@ class ServingEngine:
             self._finish_faulted(req, now, finished,
                                  reason="deadline_exceeded",
                                  event="expired", tokens=[])
-        for i in list(self.scheduler.active_slots()):
+        for i in self._overdue_slots(now):
             st = self.scheduler.slots[i]
             req = st.request
-            d = req.slo.deadline_s
-            if d is None or now - req.arrival_t <= d:
-                continue
             if self.tracer is not None:
                 self.tracer.on_expire(req, now,
                                       tokens=len(st.generated),
@@ -1612,7 +1797,7 @@ class ServingEngine:
                                  event="expired", tokens=tokens,
                                  st=st, slot=i)
 
-    def _maybe_brownout(self, now: float, finished):
+    def _maybe_brownout(self, clock, finished, phases):
         """Sustained-pressure shedding (HETU_TPU_SERVE_BROWNOUT): page
         utilization >= brownout_page_high with >= brownout_queue_min
         queued for brownout_streak consecutive steps sheds the
@@ -1620,12 +1805,19 @@ class ServingEngine:
         priority order: smaller SLOClass.priority = less important),
         metered through the health monitor when one is attached.
         Deterministic by construction — driven only by pool and queue
-        state, never the wall clock."""
+        state, never the wall clock: under pressure the step drains
+        first, so that the pool is judged with every finish in it."""
         c = self.config
-        if not (self.pool.utilization >= c.brownout_page_high
-                and self.scheduler.queue_depth >= c.brownout_queue_min):
+
+        def hot():
+            return (self.pool.utilization >= c.brownout_page_high
+                    and self.scheduler.queue_depth >= c.brownout_queue_min)
+        if hot():
+            self._drain("brownout", clock, finished, phases)
+        if not hot():
             self._brownout_hot = 0
             return
+        now = clock()
         self._brownout_hot += 1
         if self._brownout_hot < c.brownout_streak:
             return
@@ -1681,14 +1873,16 @@ class ServingEngine:
         return jnp.asarray(table)
 
     # ------------------------------------------------------ spec decode
-    def _spec_decode_step(self, active, positions, sample_args, phases):
+    def _spec_dispatch(self, active, positions, sample_args, phases):
         """One speculative decode step over the active slots: draft k
         tokens per slot on the host, verify all k+1 in ONE batched
         forward, accept by sample-then-match — or by the full
         stochastic p/q rejection rule when the drafter reports its
-        proposal distribution (serving/spec_decode.py).  Returns
-        {slot: emitted tokens} (>= 1 per active slot).  Drafting is this
-        step's `serve.decode_build` phase, acceptance part of its
+        proposal distribution (serving/spec_decode.py).  Returns the
+        verify program's (targets, emit counts), on the device: the
+        accepted count sets the next step's positions, so `step` drains
+        them at once (`_spec_accept`).  Drafting is this step's
+        `serve.decode_build` phase, acceptance part of its
         `serve.emit`."""
         with phase_span("serve.decode_build", phases):
             S, k = self.config.num_slots, self.config.spec_k
@@ -1726,21 +1920,22 @@ class ServingEngine:
                 *sample_args)
         with phase_span("serve.decode_dispatch", phases):
             targets, n_emit, pool_tree = self._run_verify(*verify_args)
-        with phase_span("serve.token_fetch", phases):
-            targets = np.asarray(targets)
-            n_emit = np.asarray(n_emit)
-        with phase_span("serve.emit", phases):
             self.pool.arrays = PoolArrays.from_tree(pool_tree)
-            emitted = {}
-            for i in active:
-                n = int(n_emit[i])
-                emitted[i] = [int(t) for t in targets[i, :n]]
-                st = self.scheduler.slots[i]
-                st.stats.spec_proposed += k
-                st.stats.spec_accepted += n - 1
-                self._registry.inc("serve.spec_proposed", value=k)
-                self._registry.inc("serve.spec_accepted", value=n - 1)
-                self._registry.observe("serve.spec_emitted", float(n))
+        return targets, n_emit
+
+    def _spec_accept(self, rows, targets, n_emit) -> dict:
+        """{slot: emitted tokens} (>= 1 per slot) of a fetched verify
+        step, and its acceptance counts."""
+        k = self.config.spec_k
+        emitted = {}
+        for i, st in rows:
+            n = int(n_emit[i])
+            emitted[i] = [int(t) for t in targets[i, :n]]
+            st.stats.spec_proposed += k
+            st.stats.spec_accepted += n - 1
+            self._registry.inc("serve.spec_proposed", value=k)
+            self._registry.inc("serve.spec_accepted", value=n - 1)
+            self._registry.observe("serve.spec_emitted", float(n))
         return emitted
 
     # ------------------------------------------------------- preemption
@@ -1817,10 +2012,12 @@ class ServingEngine:
             self._registry.set_gauge("serve.prefix_cache_pages",
                                      self.prefix_cache.num_pages)
 
-    def _advance_prefill(self, slot_idx: int, st, clock, finished, phases):
+    def _advance_prefill(self, slot_idx: int, st, clock, ends, phases):
         """Run ONE prefill chunk for a prefilling slot; on the last
-        chunk, scatter the scratch K/V into the slot's pages, emit the
-        first token, and join the decode batch.  A radix-cache hit
+        chunk, scatter the scratch K/V into the slot's pages and leave
+        the prompt's end in `ends`: its first token is fetched at the
+        step's end (`_land_first_tokens`), where the slot joins the
+        decode batch.  A radix-cache hit
         starts chunking at the shared boundary (`st.shared_tokens` —
         the primed prefix is already in the scratch) and never
         re-writes the shared pages."""
@@ -1831,13 +2028,18 @@ class ServingEngine:
             base = st.shared_tokens
             padded = base + math.ceil((plen - base) / C) * C
             s = base + st.chunks_done * C
+            last = s + C >= padded
+            # the last VALID prompt position of the final chunk (padding
+            # tail positions carry garbage): the row the program takes
+            # the first token at
+            row = plen - 1 - s if last else 0
             ids = np.zeros(C, np.int32)
             seg = req.prompt[s: min(s + C, plen)]
             ids[: len(seg)] = seg
             out = self._chunk_jit(
                 self.params, jnp.asarray(ids[None]), st.prefill_cache,
-                jnp.int32(s), *self._stats_args())
-            logits, st.prefill_cache, *stats = out
+                jnp.int32(s), jnp.int32(row), *self._stats_args())
+            logits, first, st.prefill_cache, *stats = out
             if stats:
                 (self._stats_acc,) = stats
             st.chunks_done += 1
@@ -1846,17 +2048,10 @@ class ServingEngine:
             self._registry.inc("serve.prefill_tokens", len(seg))
             if self.windowed:
                 self._count_attended_keys(s, C)
-            if s + C < padded:
+            if not last:
                 if self.tracer is not None:
                     self.tracer.on_chunk(req, clock(), st.chunks_done)
                 return                    # more chunks: next engine step
-        with phase_span("serve.first_token", phases):
-            # first generated token: at the last VALID prompt position of
-            # the final chunk (padding tail positions carry garbage) —
-            # argmax, or the seeded sampler for sampling requests (same
-            # key derivation as the decode program: position plen).  The
-            # host waits here for the chunk it has just dispatched.
-            t1 = self._first_token(req, logits[0, plen - 1 - s], plen)
 
         with phase_span("serve.page_write", phases):
             # scatter only the FRESHLY prefilled pages; shared-prefix
@@ -1871,47 +2066,76 @@ class ServingEngine:
                                    jax.tree.map(jnp.asarray, pages_row),
                                    *self._scratch_rows(st.prefill_cache))
             self.pool.arrays = PoolArrays.from_tree(tree)
+            st.prefill_cache = None
             if self.prefix_cache is not None:
                 # index the finished prompt: full page-blocks not yet
                 # cached adopt this request's pages (incref — the slot
                 # keeps its own reference and releases it on finish)
                 self.prefix_cache.insert(req.prompt, st.pages, clock())
+            # the row's logits are kept where the first token is SAMPLED
+            # from them (on the host), not the chunk program's argmax
+            drawn = self.config.sampling and req.sampling.temperature > 0
+            ends.append(_PromptEnd(slot_idx, st, first,
+                                   logits[0, row] if drawn else None))
 
+    def _land_first_tokens(self, ends, clock, finished, phases):
+        """The step's second wait for the device, after its dispatches:
+        the first generated token of every prompt that ended in this
+        step — the chunk program's argmax, or the seeded sampler for
+        sampling requests (same key derivation as the decode program:
+        position plen) — emitted to its request, whose slot is past its
+        prefill from here on."""
+        if any(e.logits_row is not None for e in ends):
+            # the sampler runs eagerly, behind everything dispatched: the
+            # same wait under its name, and what it fetched emitted first
+            self._drain("sampling", clock, finished, phases)
+        with phase_span("serve.first_token", phases):
+            firsts = [
+                self._first_token(e.st.request, e.logits_row,
+                                  e.st.request.prompt_len)
+                if e.logits_row is not None else int(t)
+                for e, t in zip(ends, jax.device_get(
+                    [e.first for e in ends]))]
         with phase_span("serve.emit", phases):
-            st.prefilling = False
-            st.prefill_cache = None
-            st.pos = plen
-            st.generated.append(t1)
-            tnow = clock()
-            st.stats.first_token_t = tnow
-            st.stats.token_ts.append(tnow)
-            ttft = st.stats.ttft_s
-            self._registry.observe("serve.ttft_s", ttft)
-            self._registry.observe("serve.ttft_s_class", ttft,
-                                   slo_class=req.slo.name)
-            if st.stats.queue_wait_s is not None:
-                self._registry.observe("serve.queue_wait_s",
-                                       st.stats.queue_wait_s)
-            self._registry.inc("serve.tokens_out")
-            if self.tracer is not None:
-                self.tracer.on_first_token(req, slot_idx, tnow,
-                                           chunk=st.chunks_done)
-            if self.health is not None:
-                self.health.observe_ttft(ttft, step=self.steps_done,
-                                         t=tnow)
-            if self._sampled(req.rid):
-                self._log_serve(event="admit", req=req.rid,
-                                slot=slot_idx, prompt_len=plen,
-                                chunks=st.stats.prefill_chunks,
-                                ttft_s=ttft,
-                                queue_wait_s=st.stats.queue_wait_s,
-                                now=tnow,
-                                slo_class=req.slo.name, tenant=req.tenant,
-                                shared_tokens=st.shared_tokens,
-                                queue_depth=self.scheduler.queue_depth,
-                                page_util=self.pool.utilization,
-                                **self._weight_fields())
-            self._maybe_finish(slot_idx, st, t1, tnow, finished)
+            for e, t1 in zip(ends, firsts):
+                self._emit_first_token(e.slot, e.st, t1, clock(), finished)
+
+    def _emit_first_token(self, slot_idx: int, st, t1: int, tnow: float,
+                          finished):
+        """A prompt's first generated token to its request: from here the
+        slot is past its prefill, and has its `first_token_t`."""
+        req = st.request
+        st.prefilling = False
+        st.pos = req.prompt_len
+        st.generated.append(t1)
+        st.stats.first_token_t = tnow
+        st.stats.token_ts.append(tnow)
+        ttft = st.stats.ttft_s
+        self._registry.observe("serve.ttft_s", ttft)
+        self._registry.observe("serve.ttft_s_class", ttft,
+                               slo_class=req.slo.name)
+        if st.stats.queue_wait_s is not None:
+            self._registry.observe("serve.queue_wait_s",
+                                   st.stats.queue_wait_s)
+        self._registry.inc("serve.tokens_out")
+        if self.tracer is not None:
+            self.tracer.on_first_token(req, slot_idx, tnow,
+                                       chunk=st.chunks_done)
+        if self.health is not None:
+            self.health.observe_ttft(ttft, step=self.steps_done, t=tnow)
+        if self._sampled(req.rid):
+            self._log_serve(event="admit", req=req.rid,
+                            slot=slot_idx, prompt_len=req.prompt_len,
+                            chunks=st.stats.prefill_chunks,
+                            ttft_s=ttft,
+                            queue_wait_s=st.stats.queue_wait_s,
+                            now=tnow,
+                            slo_class=req.slo.name, tenant=req.tenant,
+                            shared_tokens=st.shared_tokens,
+                            queue_depth=self.scheduler.queue_depth,
+                            page_util=self.pool.utilization,
+                            **self._weight_fields())
+        self._maybe_finish(slot_idx, st, t1, tnow, finished)
 
     # ----------------------------------------------------------- finish
     def _maybe_finish(self, slot_idx: int, st, tok: int, tnow: float,
@@ -2012,6 +2236,8 @@ class ServingEngine:
             results.extend(self.step(now))
             now += time.perf_counter() - t0
             step_idx += 1
+        # (what is left queued is a row past an EOS: no slot holds it)
+        self._drain("idle", lambda: now, results)
         if self.run_log is not None or self.telemetry is not None:
             n_tokens = sum(len(r.tokens) for r in results)
             elapsed = max(now - start, 1e-9)
@@ -2023,5 +2249,6 @@ class ServingEngine:
         return sorted(results, key=lambda r: r.rid)
 
     def close(self):
+        self._drain("close", lambda: self._last_clock)
         if self._owns_runlog and self.run_log is not None:
             self.run_log.close()

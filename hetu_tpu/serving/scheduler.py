@@ -55,6 +55,11 @@ class SlotState:
     pages: List[int]             # of the first kind of layer, in order
     pos: int                     # next cache write position (= tokens cached)
     generated: List[int] = field(default_factory=list)
+    #: decode rows dispatched for the slot whose tokens the engine has not
+    #: emitted yet (the engine keeps one step queued on the device): the
+    #: next row's position is `pos + inflight`, and the host's own count
+    #: of the slot's tokens `len(generated) + inflight`
+    inflight: int = 0
     stats: RequestStats = field(default_factory=RequestStats)
     prefilling: bool = False
     prefill_cache: object = None      # scratch KV carry while prefilling
@@ -195,7 +200,8 @@ class Scheduler:
             row[first: first + len(pages)] = pages
 
     def advance(self, slot_idx: int) -> int:
-        """Before the slot's query at `pos`: release to the free list
+        """Before the slot's query at `pos` (the next row to DISPATCH:
+        `st.pos + st.inflight`): release to the free list
         every page of a WINDOW kind whose positions all lie behind
         pos - window + 1 (its table entry becomes the null page), and
         take as many of the pages the sequence grows into next, so that
@@ -208,7 +214,8 @@ class Scheduler:
             if w is None:
                 continue
             pages, first = st.pages_of(kind), st.first_page[kind]
-            n = min(max(0, st.pos - w + 1) // ps - first, len(pages))
+            n = min(max(0, st.pos + st.inflight - w + 1) // ps - first,
+                    len(pages))
             if n <= 0:
                 continue
             self.pool.free(pages[:n], kind)

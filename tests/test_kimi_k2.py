@@ -598,8 +598,8 @@ def test_kv_engines_pools_and_programs_are_as_they_were(make, n_kv, hd):
         (2, 1, 64, n_kv, hd)] * 2
     assert eng.cache.kind == "kv" and eng.model.STATS == ()
     assert eng._stats_acc is None
-    assert len(eng._dummy_args("decode")) == 5
-    assert len(eng._dummy_args("prefill_chunk")) == 4
+    assert len(eng._dummy_args("decode")) == 6      # + the decode before
+    assert len(eng._dummy_args("prefill_chunk")) == 5   # + the token's row
     assert len(eng._dummy_args("write_pages")) == 4
     low = eng.lower_programs()
     assert sorted(low) == ["decode", "prefill_chunk", "write_pages"]

@@ -1112,11 +1112,8 @@ def test_flag_off_serving_decode_hlo_identical(monkeypatch):
                             ServeConfig(num_slots=2, page_size=8,
                                         max_len=32, prefill_chunk=8))
         assert eng.decode_paged is False
-        table = jnp.zeros((2, eng.scheduler.max_pages), jnp.int32)
-        toks = jnp.zeros(2, jnp.int32)
-        pos = jnp.zeros(2, jnp.int32)
         texts[flag] = eng._decode_jit.lower(
-            params, eng.pool.arrays.tree(), table, toks, pos).as_text()
+            *eng._dummy_args("decode")).as_text()
         eng.close()
     assert texts["0"] == texts[None]
 

@@ -651,9 +651,11 @@ def test_chunked_prefill_interleaves_with_decode(tiny_llama):
     long = Request(rid=1, prompt=np.arange(1, 25, dtype=np.int32),
                    max_new_tokens=4)    # 24 tokens = 3 chunks of 8
     eng.submit(short, now=0.0)
-    eng.step(0.0)    # short: prefill completes -> joins decode same step
+    eng.step(0.0)    # short: prefill completes, its first token fetched
     st0 = eng.scheduler.slots[0]
-    assert not st0.prefilling and len(st0.generated) == 2
+    assert not st0.prefilling and len(st0.generated) == 1
+    eng.step(0.5)    # its first decode is dispatched, and stays queued
+    assert len(st0.generated) == 1 and st0.inflight == 1
     eng.submit(long, now=1.0)
     for k in range(1, 4):
         eng.step(float(k))
@@ -662,8 +664,9 @@ def test_chunked_prefill_interleaves_with_decode(tiny_llama):
             assert st1.prefilling and st1.chunks_done == k
         else:       # chunk 3 lands: first token emitted, joins decode
             assert not st1.prefilling
-        # ...while the short request gained a token EVERY step
-        assert len(st0.generated) == 2 + k
+        # ...while the short request gained a token EVERY step (the one
+        # dispatched the step before), with the next one queued
+        assert len(st0.generated) == 1 + k and st0.inflight == 1
     # both finish cleanly and the long one's tokens match generate()
     results = []
     now = 4.0

@@ -426,7 +426,11 @@ def test_chaos_serve_failover_flake_checked(tmp_path):
     report's failover section carries the retry accounting per class."""
     from hetu_tpu.chaos.harness import named_plan, run_serving_chaos_demo
     for seed in range(5):
-        plan = named_plan("serve-failover")
+        # before the fifth step: the first burst is decoding at every
+        # seed (at the schedule's own step 6, seed 2's only live slot has
+        # its last token queued on the device: `fail_over` fetches it
+        # first, and the kill then finds no slot to requeue)
+        plan = named_plan("serve-failover", at_step=4)
         report = run_serving_chaos_demo(
             str(tmp_path / f"s{seed}"), plan, requests=10, rate=80.0,
             burst=5, retry_budget=2, seed=seed)

@@ -282,14 +282,16 @@ def test_every_admission_advances_zeros_of_its_own():
     ids = jnp.asarray(np.arange(8, dtype=np.int32)[None] + 1)
     a, b = eng._fresh_scratch(), eng._fresh_scratch()
     assert all(not np.asarray(x).any() for x in a + b)
-    _, a2 = eng._chunk_jit(eng.params, ids, a, jnp.int32(0))
+    _, _, a2 = eng._chunk_jit(eng.params, ids, a, jnp.int32(0), jnp.int32(0))
     assert all(np.asarray(x[:, :, :8]).any() for x in a2)
     assert all(not np.asarray(x[:, :, 8:]).any() for x in a2)
     # the other prefill's buffer and the next admission's are untouched
     assert all(not np.asarray(x).any() for x in b)
     assert all(not np.asarray(x).any() for x in eng._fresh_scratch())
-    _, b2 = eng._chunk_jit(eng.params, ids + 9, b, jnp.int32(0))
-    _, a3 = eng._chunk_jit(eng.params, ids + 20, a2, jnp.int32(8))
+    _, _, b2 = eng._chunk_jit(eng.params, ids + 9, b, jnp.int32(0),
+                               jnp.int32(0))
+    _, _, a3 = eng._chunk_jit(eng.params, ids + 20, a2, jnp.int32(8),
+                               jnp.int32(0))
     for x, y in zip(a3, b2):
         assert not np.array_equal(np.asarray(x[:, :, :8]),
                                   np.asarray(y[:, :, :8]))

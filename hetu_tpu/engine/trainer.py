@@ -37,7 +37,7 @@ logger = get_logger("trainer")
 
 
 from hetu_tpu.utils.profiling import device_mem_bytes as _device_mem_bytes
-from hetu_tpu.utils.profiling import phase_span
+from hetu_tpu.utils.profiling import StepRecorder, phase_span
 
 
 class Trainer:
@@ -198,6 +198,15 @@ class Trainer:
         from hetu_tpu.obs.metrics import get_registry
         from hetu_tpu.obs.runlog import RunLog, default_runlog_path
         self._registry = get_registry()
+        #: the record `train_step` keeps of itself, as the serving
+        #: engine's does (utils/profiling.StepRecorder: counters
+        #: `trainer.steps`, `.step_wall_s`, `.phase_s{phase}`,
+        #: `.caller_s`, `.stalled_steps{phase}`, ...); the full records
+        #: of its last stalled steps, and of its slowest so far (None
+        #: before the first; a caller may set it to None again)
+        self._step_record = StepRecorder("trainer", self._registry)
+        self.slow_steps = self._step_record.slow_steps
+        self.slowest_step: Optional[dict] = None
         rl_path = default_runlog_path(config.ckpt_dir)
         # one writer per run: in multi-process runs only process 0 logs
         # (the same gate the checkpoint writer uses) — N appenders to one
@@ -313,6 +322,8 @@ class Trainer:
     def build(self, rng: Optional[jax.Array] = None):
         """Materialize sharded params/opt state and compile the step."""
         c, mesh = self.config, self.mesh
+        # a loop that starts again: its first step follows no other
+        self._step_record.reset()
         rng = rng if rng is not None else jax.random.key(c.seed)
 
         with use_mesh(mesh):
@@ -1122,8 +1133,14 @@ class Trainer:
         """One step, dispatched and not waited for.  Its two host phases
         are spans on the profiler's clock (`trainer.prepare_batch`,
         `trainer.dispatch`, inside a `trainer.step` step annotation) and
-        durations in `trainer.step_phase_s{phase}` (docs/observability.md)."""
-        phases: Dict[str, float] = {}
+        seconds in the step's record (`self._step_record`: the counters
+        `trainer.steps`, `trainer.tokens`, `trainer.phase_s{phase}`, ...,
+        the histogram `trainer.step_phase_s{phase}`, `slow_steps`,
+        `slowest_step`; docs/observability.md).  The step only
+        dispatches, so the record judges the interval from the step
+        before's return to this one's (`caller`: the time between them,
+        where a loop waits for a step in flight)."""
+        phases = self._step_record.begin()
         with jax.profiler.StepTraceAnnotation("trainer.step",
                                               step_num=self.global_step):
             with phase_span("trainer.prepare_batch", phases):
@@ -1137,9 +1154,10 @@ class Trainer:
                             self.params, self.opt_state, batches, rng,
                             self.scaler_state,
                             strategy_id=self._plan_dispatch_key())
-        for name, dt in phases.items():
-            self._registry.observe("trainer.step_phase_s", dt, phase=name)
         self.global_step += 1
+        self._registry.inc("trainer.tokens", host_batch["input_ids"].size)
+        self.slowest_step = self._step_record.end(
+            self.global_step, time.time(), self.slowest_step)
         return metrics
 
     def train(self, batches: Iterable[Dict[str, np.ndarray]],
@@ -1162,8 +1180,6 @@ class Trainer:
             step_s = self.profiler.last_step_s
             batch_tokens = int(np.prod(host_batch["input_ids"].shape))
             tokens += batch_tokens
-            self._registry.inc("trainer.steps")
-            self._registry.inc("trainer.tokens", batch_tokens)
             self._registry.observe("trainer.step_time_s", step_s)
             log_boundary = (self.global_step % c.log_every) == 0
             loss = None
@@ -1210,6 +1226,10 @@ class Trainer:
                     device_mem_bytes=mem,
                     plan=self._plan_fingerprint(host_batch))
             if self._ckpt and (self.global_step % c.ckpt_every) == 0:
+                # the loop's own work, not a stall between two steps:
+                # `trainer.empty_s`, and the step after it is not judged
+                # by this gap
+                self._step_record.idle()
                 self.save()
         self._flush_scaler()
         self.profiler.close()

@@ -133,10 +133,34 @@ class MetricsRegistry:
     def observe(self, name: str, value: float, **labels):
         key = (name, _label_key(labels))
         with self._lock:
-            h = self._hists.get(key)
-            if h is None:
-                h = self._hists[key] = Histogram()
-            h.observe(value)
+            self._observe(key, value)
+
+    def _observe(self, key, value: float):
+        """One observation into the histogram of `key`; the lock held."""
+        h = self._hists.get(key)
+        if h is None:
+            h = self._hists[key] = Histogram()
+        h.observe(value)
+
+    @staticmethod
+    def series(name: str, **labels) -> Tuple[str, _LabelKey]:
+        """The key of one series, for `record()`: a writer of many series
+        a step forms each key once."""
+        return (name, _label_key(labels))
+
+    def record(self, counts=(), observations=()):
+        """`inc` and `observe` for many series under ONE lock, each named
+        by its `series()` key: `counts` and `observations` are iterables
+        of (key, value).  What a step loop's recorder writes at the end
+        of every step (utils/profiling.StepRecorder: 11 counters and 8
+        histograms of an engine step), at half of what as many single
+        calls cost (PERF.md s6)."""
+        with self._lock:
+            counters = self._counters
+            for key, value in counts:
+                counters[key] = counters.get(key, 0.0) + value
+            for key, value in observations:
+                self._observe(key, value)
 
     def timer(self, name: str, **labels):
         """Context manager observing wall seconds into histogram `name`."""

@@ -77,7 +77,7 @@ from hetu_tpu.serving.request import (Request, RequestResult,
 from hetu_tpu.serving.scheduler import Scheduler
 from hetu_tpu.serving.tracing import maybe_tracer
 from hetu_tpu.utils.logging import get_logger
-from hetu_tpu.utils.profiling import phase_span
+from hetu_tpu.utils.profiling import StepRecorder, phase_span
 
 logger = get_logger("serving.engine")
 
@@ -428,11 +428,21 @@ class ServingEngine:
         #: driver-clock time at the end of the last step — the default
         #: timestamp for between-step fault events (fail_over)
         self._last_clock = 0.0
-        #: the slowest `step()` so far: {"step", "now", "step_s",
-        #: "phases": seconds per host phase} (None before the first)
-        self.slowest_step: Optional[dict] = None
         self.reshard = reshard
         self._registry = registry if registry is not None else get_registry()
+        #: the record `step()` keeps of itself (utils/profiling.
+        #: StepRecorder: counters `serve.step_wall_s`, `.phase_s{phase}`,
+        #: `.empty_s`, `.stalled_steps{phase}`, ...), the full records of
+        #: its last stalled steps, and the slowest `step()` so far:
+        #: {"step", "now", "step_s", "phases": seconds per host phase,
+        #: "cpu_s", "gap_s", "gc", "compiles", "compile_s", "dispatched",
+        #: "fetch_wait_s", "median_s", "stalled", "bytes_in_use", ...}
+        #: (None before the first; a caller may set it to None again)
+        self._step_record = StepRecorder("serve", self._registry)
+        self.slow_steps = self._step_record.slow_steps
+        self.slowest_step: Optional[dict] = None
+        #: seconds this step's deferred fetches waited for the device
+        self._fetch_wait = 0.0
         #: the parameters as the programs read them: the model's serving
         #: view (`serving_params`) where it brings one, else the caller's
         self.params, self.relaid_weight_bytes, why = serving_view(
@@ -575,6 +585,17 @@ class ServingEngine:
             self.config.num_slots + len(model.STATS), jnp.int32)
         #: perf_counter at the last emit of a decode program's tokens
         self._landed_t = 0.0
+        # what the engine holds AT REST, to lay a stalled step's
+        # `bytes_in_use` and a run's peak against: the parameters as the
+        # programs read them, the page pool, and the prefill scratch ONE
+        # prefilling request holds (a slot in prefill has its own)
+        for what, tree in (("weights", self.params),
+                           ("pool", self.pool.arrays.tree()),
+                           ("scratch", jax.eval_shape(self._fresh_scratch))):
+            self._registry.set_gauge(
+                "serve.held_bytes",
+                sum(a.size * a.dtype.itemsize
+                    for a in jax.tree.leaves(tree)), what=what)
         with record_routes(self.kernel_routes):
             self._build_programs()
 
@@ -1269,18 +1290,21 @@ class ServingEngine:
         Every dispatch, sync and bookkeeping block runs inside one of the
         host phase spans of `STEP_PHASES` (utils/profiling.phase_span): a
         TraceAnnotation on the profiler's clock, nested in `serve.step`,
-        and the phase's seconds in this step's record, which ends in
-        `serve.step_phase_s{phase}`, `serve.step_s` and, for the slowest
-        step so far, `self.slowest_step` (docs/serving.md).  The loop
-        over the slots between the blocks and `_note_step_phases` itself
-        are in no phase, so the phases sum to a little under the step."""
+        and the phase's seconds in this step's record (`self._step_record`,
+        utils/profiling.StepRecorder), which ends in the counters and
+        histograms of `_note_step_phases`, in `self.slow_steps` for a
+        stalled step and, for the slowest step so far, in
+        `self.slowest_step` (docs/serving.md).  The loop over the slots
+        between the blocks is in no phase, so the phases sum to a little
+        under the step."""
         t0 = time.perf_counter()
 
         def clock() -> float:
             return now + (time.perf_counter() - t0)
 
-        phases: dict = {}
+        self._fetch_wait = 0.0
         finished: List[RequestResult] = []
+        phases = self._step_record.begin()
         with jax.profiler.TraceAnnotation("serve.step"):
             while True:
                 with phase_span("serve.admit", phases):
@@ -1290,10 +1314,12 @@ class ServingEngine:
                 self._drain(why, clock, finished, phases)
 
             ends: List[_PromptEnd] = []
+            chunks = 0
             for i in self.scheduler.active_slots():
                 st = self.scheduler.slots[i]
                 if st is not None and st.prefilling:
                     self._advance_prefill(i, st, clock, ends, phases)
+                    chunks += 1
 
             # every slot past its prefill whose tokens are not all
             # dispatched: a length finish is known here, without a fetch
@@ -1373,8 +1399,17 @@ class ServingEngine:
                             queue_depth=self.scheduler.queue_depth,
                             **({"kv_repage": True}
                                if self.config.kv_repage else {}))
+                # what the step's record takes of the engine: read here,
+                # inside a phase, so that closing the record is no part
+                # of the step
+                sched = self.scheduler
+                empty = (self._inflight is None and not sched.queue
+                         and all(st is None for st in sched.slots))
+                dispatched = {
+                    "chunk_launches": chunks, "decode_batch": len(batch),
+                    "fetch_behind": bool(batch) and older is not None}
                 self._last_clock = clock()
-        self._note_step_phases(now, time.perf_counter() - t0, phases)
+        self._note_step_phases(now, empty, dispatched)
         return finished
 
     def _admit(self, clock, finished) -> Optional[str]:
@@ -1498,8 +1533,9 @@ class ServingEngine:
             tw = time.perf_counter()
             host = jax.device_get(flight.out)
             if behind:
-                self._registry.observe("serve.fetch_wait_s",
-                                       time.perf_counter() - tw)
+                waited = time.perf_counter() - tw
+                self._fetch_wait += waited
+                self._registry.inc("serve.fetch_wait_total_s", waited)
         with phase_span("serve.emit", phases):
             if self.spec:
                 emitted = self._spec_accept(flight.rows, *host)
@@ -1631,16 +1667,23 @@ class ServingEngine:
             if more > 0:
                 self._registry.inc(name, more)
 
-    def _note_step_phases(self, now: float, step_s: float, phases: dict):
-        """The finished step's phase record into the registry, and into
-        `slowest_step` if no step of this engine took longer: so the
-        slowest step of any run, traced or not, names its phase."""
-        for name, dt in phases.items():
-            self._registry.observe("serve.step_phase_s", dt, phase=name)
-        self._registry.observe("serve.step_s", step_s)
-        if self.slowest_step is None or step_s > self.slowest_step["step_s"]:
-            self.slowest_step = {"step": self.steps_done, "now": now,
-                                 "step_s": step_s, "phases": phases}
+    def _note_step_phases(self, now: float, empty: bool, dispatched: dict):
+        """Close the step's record (`StepRecorder.end`: the counters and
+        histograms of the step, the stall rule), with what the engine
+        alone knows: what the step dispatched, what its deferred fetch
+        waited, and whether it leaves the engine EMPTY (no slot active,
+        nothing queued, nothing in flight: the gap to the next step is
+        then `serve.empty_s`, else the caller's, `serve.caller_s`).  A
+        step is judged stalled among the steps with as many chunk
+        launches as it has: a chunk program can take five decode
+        programs' time (MiMo: 39 ms beside 8).  The
+        record becomes `slowest_step` if no step since that was last
+        cleared took longer: so the slowest step of any run, traced or
+        not, names its phase and what held it (docs/serving.md)."""
+        self.slowest_step = self._step_record.end(
+            self.steps_done, now, self.slowest_step, empty=empty,
+            kind=dispatched["chunk_launches"], dispatched=dispatched,
+            fetch_wait_s=self._fetch_wait)
 
     # ----------------------------------------------------------- faults
     def _finish_faulted(self, req, now: float, finished, *, reason: str,

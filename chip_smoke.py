@@ -49,8 +49,9 @@ KERNEL_SCOPES = {
     "paged_attn": "pallas_paged_attention",
 }
 
-#: the kernels the train step runs on a TPU, under any mesh
-TRAIN_KERNELS = ("adam", "flash", "norm", "rotary", "swiglu")
+#: the kernels the train step runs on a TPU, under any mesh (the AdamW
+#: update is XLA's: `ops/pallas.AUTO_KEEPS_XLA`)
+TRAIN_KERNELS = ("flash", "norm", "rotary", "swiglu")
 
 #: sharded-vs-single first-step loss: the bound __graft_entry__'s
 #: multichip dry run uses.  Both programs run the same kernels (the

@@ -19,7 +19,21 @@ every other hyperparameter-shaped knob).
 Shape contract (drift-tested against `compatible`): the four leaf
 buffers share one shape whose element count is lane-aligned (% 128) and
 whose lane rows block legally (<= 256 rows, or a multiple-of-8 divisor);
-ragged leaves (biases, norm gains) keep the XLA path."""
+ragged leaves (biases, norm gains) keep the XLA path.
+
+**`auto` does not route this kernel** (`ops/pallas.AUTO_KEEPS_XLA`,
+PR 39); it runs where `HETU_TPU_PALLAS=1` forces it.  The kernel sees a
+leaf as `[n/128, 128]`, and on a TPU that reshape is no view: an array
+is tiled `T(8,128)` over its LAST TWO dimensions, so
+`[2, 4096, 28672] -> [1835008, 128]` is a COPY of the leaf, for p, g, m
+and v on the way in and p', m', v' on the way out (seven `reshape`
+instructions a leaf in the optimized HLO).  The kernel proper ran at
+~90% of its bytes' floor, the copies took three quarters of the
+`pallas_adam` scope, and XLA's chain, which reads a leaf where it lies
+and is ONE fusion with the rescale that makes the gradient, was the
+faster in both train cells (PERF.md s6, PR 39).  What would let a
+kernel win: the leaf taken in its own layout AND the rescale's factor
+as a fourth scalar (ROADMAP queue 1 item 3)."""
 from __future__ import annotations
 
 import functools

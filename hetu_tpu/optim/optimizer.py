@@ -126,6 +126,11 @@ class AdamW(Optimizer):
             # fused Adam kernel (ops/pallas/adam.py): one read of
             # p/g/m/v, one write of p'/m'/v' per lane-aligned leaf;
             # ragged leaves (biases, gains) keep the XLA chain below.
+            # `auto` keeps the chain for EVERY leaf
+            # (ops/pallas.AUTO_KEEPS_XLA): XLA fuses it with the rescale
+            # that made `g` and reads each leaf where it lies, and the
+            # chip read it faster in both train cells (PR 39); the kernel
+            # runs where HETU_TPU_PALLAS=1 forces it.
             # Under a multi-device mesh it runs per shard of the STATE
             # layout: p and g are sliced to the shard the update is owed
             # on, and the fresh p is gathered back by the step's

@@ -21,6 +21,7 @@ fixture steers that one call — in the test, not through an option of
 the program.
 """
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
 # libtpu lets one process at a time load it (/tmp/libtpu_lockfile).  Nothing
@@ -240,6 +241,57 @@ def test_paged_kernel_takes_the_serving_cells_pool_as_it_lies():
 
 
 # ---------------------------------------------------------------------------
+# the AdamW update as `auto` runs it: XLA's chain, a leaf where it lies
+# ---------------------------------------------------------------------------
+
+#: the leaves of both train cells as the update sees them: Mistral-7B's
+#: (2 layers stacked) whole, InternLM2-1.8B's (16 layers) as ONE SHARD of
+#: dp2 x tp2 + ZeRO holds them
+ADAM_CELL_LEAVES = {
+    "mistral_lm_head": (4096, 32768),
+    "mistral_embed": (32768, 4096),
+    "mistral_o_proj": (2, 4096, 4096),
+    "mistral_wqkv": (2, 4096, 8, 6, 128),
+    "mistral_down_proj": (2, 14336, 4096),
+    "mistral_w_gate_up": (2, 4096, 2, 14336),
+    "mistral_norm": (2, 4096),
+    "mistral_final_norm": (4096,),
+    "internlm2_shard_w_gate_up": (8, 2048, 2, 4096),
+    "internlm2_shard_down_proj": (8, 4096, 2048),
+    "internlm2_shard_wqkv": (8, 2048, 4, 4, 128),
+    "internlm2_shard_o_proj": (8, 1024, 2048),
+    "internlm2_shard_embed": (46272, 1024),
+    "internlm2_shard_lm_head": (1024, 46272),
+    "internlm2_shard_norm": (8, 2048),
+    "internlm2_shard_final_norm": (1024,),
+}
+
+
+@pytest.mark.parametrize("leaf", ADAM_CELL_LEAVES)
+def test_adamw_update_reads_a_cell_leaf_where_it_lies(leaf):
+    """`AdamW.update` over each leaf of the two train cells, compiled for
+    the described v5e with nothing forced: XLA's chain (no kernel), and
+    nothing that moves the leaf — no `reshape`, `copy` or `transpose`
+    instruction, no temporary to speak of.  (Behind the kernel a leaf's
+    p, g, m and v were each copied to `[n/128, 128]` and the three
+    results copied back: 3.29 GB of temporaries for three leaves, three
+    quarters of the optimizer's time, PR 39.)"""
+    from hetu_tpu.optim.optimizer import AdamW
+    opt = AdamW(lr=3e-4, weight_decay=0.1)
+    tree = lambda dt: {"w": spec(ADAM_CELL_LEAVES[leaf], dt)}
+    state = {"step": spec((), I32), "m": tree(F32), "v": tree(F32)}
+    compiled = jax.jit(
+        lambda params, grads, state: opt.update(grads, state, params),
+        donate_argnums=(0, 2)).lower(tree(BF16), tree(F32), state).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    # (of an array: the step counter, a scalar, is copied to scalar memory)
+    assert not re.findall(
+        r"= \w+\[\d[\d,]*\]\S* (?:reshape|copy|transpose)\(.*", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+# ---------------------------------------------------------------------------
 # whole programs, as chip_smoke.py runs them
 # ---------------------------------------------------------------------------
 
@@ -263,22 +315,32 @@ def _train_step(strategy, batch=2):
 
 
 def _assert_every_train_kernel(trainer, compiled):
-    """flash, norm, swiglu, rotary and adam: each routed to Pallas by the
-    shape gate (nothing forced), each a tpu_custom_call in the program."""
+    """flash, norm, swiglu and rotary: each routed to Pallas by the shape
+    gate (nothing forced), each a tpu_custom_call in the program.  The
+    AdamW update is XLA's (`ops/pallas.AUTO_KEEPS_XLA`, PR 39): no
+    instruction of the program carries the `pallas_adam` scope, so there
+    is no kernel and no `reshape`, `copy` or `transpose` of a leaf to
+    `[n/128, 128]` and back around one."""
     from chip_smoke import TRAIN_KERNELS, kernels_in
-    routes = trainer.kernel_routes
+    from hetu_tpu.ops.pallas import AUTO_KEEPS_XLA
+    routes = dict(trainer.kernel_routes)
+    adam = routes.pop("adam")
+    assert adam["xla"] and not adam["pallas"], adam
+    assert list(adam["why"]) == [AUTO_KEEPS_XLA["adam"]], adam
     assert sorted(routes) == sorted(TRAIN_KERNELS), routes
     for name, rec in routes.items():
         assert rec["pallas"] and not rec["xla"], (name, rec)
         assert list(rec["why"]) == ["shape gate passes"], (name, rec)
-    found = kernels_in(compiled.as_text())
+    text = compiled.as_text()
+    found = kernels_in(text)
     assert all(found[k] for k in TRAIN_KERNELS), found
+    assert "pallas_adam" not in text
 
 
 def test_train_step_compiles_for_one_v5e_with_every_kernel():
     """The donated AdamW step `chip_smoke.py`'s train phase runs, with
-    flash, norm, swiglu, rotary and adam all routed to Pallas, inside one
-    chip's 16 GB."""
+    flash, norm, swiglu and rotary routed to Pallas and the update left
+    to XLA, inside one chip's 16 GB."""
     from hetu_tpu.parallel import ParallelStrategy
     trainer, compiled = _train_step(ParallelStrategy())
     _assert_every_train_kernel(trainer, compiled)
@@ -291,7 +353,7 @@ def test_sharded_train_step_compiles_for_four_v5e_with_every_kernel():
     `chip_smoke.py --chips 4`.  GSPMD cannot partition a Mosaic call (the
     lowering raises), so each kernel runs once per shard inside a
     shard_map over the layouts the model declares (ops/pallas.per_shard):
-    the same five kernels as on one chip, and the collectives of the
+    the same four kernels as on one chip, and the collectives of the
     partitioned program around them."""
     from hetu_tpu.core.mesh import MeshConfig
     from hetu_tpu.parallel import ParallelStrategy

@@ -1007,6 +1007,34 @@ def test_routes_are_recorded_with_their_reasons(monkeypatch, devices):
     assert sum(rec["why"].values()) == 5
 
 
+@pytest.mark.parametrize("flag,routed", [(None, False), ("1", True)])
+def test_auto_leaves_adam_to_xla_on_a_tpu(monkeypatch, flag, routed):
+    """`auto` on a TPU backend keeps XLA's chain for the AdamW update and
+    says why (`AUTO_KEEPS_XLA`: the chip read the chain faster in both
+    train cells, PR 39), while a kernel not listed there is still taken;
+    the forced flag routes the kernel as before."""
+    from hetu_tpu.ops import pallas as pk
+    from hetu_tpu.ops.pallas import adam as padam
+    if flag is None:
+        monkeypatch.delenv("HETU_TPU_PALLAS", raising=False)
+    else:
+        monkeypatch.setenv("HETU_TPU_PALLAS", flag)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shape = (8, 128)
+    assert padam.compatible(shape)
+    with record_routes() as log:
+        assert pk.resolve_route("adam", padam.check_shapes,
+                                *(shape,) * 4) is routed
+        g = jnp.zeros((2, 128, 512), jnp.float32)
+        jax.eval_shape(lambda: ops.swiglu(g, g))
+    assert log["swiglu"]["pallas"] == 1
+    assert (log["adam"]["pallas"], log["adam"]["xla"]) == (routed,
+                                                           not routed)
+    assert list(log["adam"]["why"]) == [
+        "forced on by HETU_TPU_PALLAS=1" if routed
+        else pk.AUTO_KEEPS_XLA["adam"]]
+
+
 def test_fused_sample_token_identity(monkeypatch):
     """The fused sampling epilogue picks the IDENTICAL tokens as the XLA
     path (both consume the same hash-Gumbel words and the same exact

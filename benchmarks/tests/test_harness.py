@@ -126,8 +126,8 @@ def test_unknown_device_kind_is_an_error():
     ("tiny-train-4dev", 4, 1, {"train_stall_pct", "train_step_median_ms",
                                "train.compiles_in_window"}),
     ("tiny-chat", 1, 0, {"norm_latency_mean_ms", "setup_s"}),
-    ("tiny-batch", 1, 1, {"batch.prefill_token_share_inside",
-                          "batch.stall_pct", "batch.compiles_in_window"}),
+    ("tiny-batch", 1, 1, {"prefill_token_share_inside", "stall_pct",
+                          "compiles_in_window"}),
     ("tiny-gpt2-chat", 1, 0, {"norm_latency_mean_ms", "setup_s"}),
     ("tiny-gpt2-train", 1, 1, {"train_stall_pct", "train_step_median_ms",
                                "train.compiles_in_window"}),

@@ -29,14 +29,23 @@ CELL = "mimo-v2-flash-serve-long-context"
 CONFIG = "mimo-v2-flash-ep16-depth7"
 BENCHMARK = os.path.join(ROOT, "BENCHMARK.json")
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+# what the cell reports without a device plane (a rule file's `device`
+# false): the names carry no cell's prefix where the rule is shared;
+# four of them (`stall_pct`, `local_assignment_pct`,
+# `experts_extra_blocks_pct`, `decode_window_ctx_ktokens_step`) the cell
+# got from a list in PR 40, PR 36 having had no room
 COUNTER_METRICS = {
-    "mimo.experts_hit_per_layer_step", "mimo.decode_ctx_ktokens_step",
-    "mimo.window_pages_released_step", "mimo.prefill_attended_kkeys_token",
-    "mimo.decode_batch_inside", "mimo.prefill_token_share_inside",
-    "mimo.host_work_ms_step", "mimo.peak_hbm_gb", "mimo.compiles_in_window"}
-ROOFLINES = {"mimo.paged_attn_roofline": "paged_attn_cost",
+    "experts_hit_per_layer_step", "decode_ctx_ktokens_step",
+    "window_pages_released_step", "mimo.prefill_attended_kkeys_token",
+    "decode_batch_inside", "prefill_token_share_inside",
+    "host_work_ms_step", "peak_hbm_gb", "compiles_in_window", "stall_pct",
+    "local_assignment_pct", "experts_extra_blocks_pct",
+    "decode_window_ctx_ktokens_step",
+    "engine_empty_pct", "stalled_steps_pct",
+    "fetch_wait_ms_step", "decode_overlap_pct"}
+ROOFLINES = {"paged_attn_roofline": "paged_attn_cost",
              "mimo.chunk_attn_roofline": "chunk_attn_cost",
-             "mimo.grouped_matmul_roofline": "grouped_matmul_cost"}
+             "grouped_matmul_roofline": "grouped_matmul_cost"}
 
 
 def run(*args):
@@ -75,12 +84,12 @@ def test_tiny_mimo_rehearses_correct(trace_on):
     if trace_on:
         m = {k.removeprefix("cpu_rehearsal."): v["value"]
              for k, v in line["metrics"].items()}
-        assert 0 < m["mimo.experts_hit_per_layer_step"] <= 4
-        assert m["mimo.window_pages_released_step"] > 0
+        assert 0 < m["experts_hit_per_layer_step"] <= 4
+        assert m["window_pages_released_step"] > 0
         # a prompt token's query attends at most the window in a window
         # layer and at most 104 + 16 keys in a full one
         assert 0.012 < m["mimo.prefill_attended_kkeys_token"] < 0.132
-        assert m["mimo.compiles_in_window"] == 0
+        assert m["compiles_in_window"] == 0
 
 
 def test_the_cell_and_its_files():
@@ -154,13 +163,16 @@ def test_the_cell_and_its_files():
     assert max(p) + max(o) <= sv["max_len"]
     # 97.5% prompt tokens
     assert sum(p) / (sum(p) + sum(o)) == pytest.approx(0.975, abs=0.001)
-    mine = [m for m in b["per_layer"] if m["name"].startswith("mimo.")]
-    assert len(mine) == 23
-    with open(REHEARSAL) as f:      # every one of them is rehearsed
-        assert [m["name"] for m in json.load(f)["per_layer"]] == \
-            [m["name"] for m in mine]
+    # what is reported IN the cell, wherever the entries stand and
+    # whichever other cells share them
+    mine = [m for m in b["per_layer"] if CELL in m["workloads"]]
+    assert COUNTER_METRICS < {m["name"] for m in mine}
+    with open(REHEARSAL) as f:      # exactly those are rehearsed
+        rehearsed = json.load(f)["per_layer"]
+    assert sorted(m["name"] for m in rehearsed) == \
+        sorted(m["name"] for m in mine)
+    assert all(m["workloads"] == ["tiny-mimo-long"] for m in rehearsed)
     for m in mine:
-        assert m["workloads"] == [CELL]
         assert m["moves"] == "serve_tokens_per_s"
         spec = traffic.load_json("metrics", m["name"])
         assert spec["reduce"]["rule"] in trace.RULES
@@ -173,6 +185,51 @@ def test_the_cell_and_its_files():
                if m["name"] == "serve_tokens_per_s")
     assert CELL in e2e["workloads"] and e2e["bound"] == 0.055
     assert 0 < cfg["router_tie_logit"] <= 0.2
+
+
+def test_every_scope_rule_of_the_cell_finds_its_scope_in_the_programs():
+    """The cell's device metrics that select by scope (rules `scope_ms`,
+    among them the two it got from a list in PR 40: `decode_kv_write_` and
+    `decode_unscoped_dev_ms`) each find something to read in the programs
+    the engine compiles for the tiny configuration: a trace with every
+    instruction of every program once, a microsecond each.  What the
+    scopes say of the program; no time of a device."""
+    import jax
+    from benchmarks import run as runner
+    from benchmarks.families import mimo_v2 as fam
+    from hetu_tpu.obs.metrics import MetricsRegistry
+    from hetu_tpu.serving.engine import ServingEngine
+    cfg = dict(traffic.load_json("configs", "tiny-mimo"))
+    model = fam.build_model(cfg, cfg["serving"])
+    engine = ServingEngine(model, model.init(jax.random.key(1)),
+                           fam.serve_config(cfg), registry=MetricsRegistry())
+    texts = [low.compile().as_text()
+             for low in engine.lower_programs().values()]
+    engine.close()
+    dev, ops, mods, t = "/device:TPU:0", [], [], 0.0
+    for text in texts:
+        module, index = trace.scope_index(text)
+        start = t
+        for name in index:
+            ops.append(trace.Event(name, t, 1e-6))
+            t += 1e-6
+        mods.append(trace.Event(module + "(1)", start, t - start))
+        t += 1e-3
+    ctx = {"config": cfg, "family": fam, "hlo_texts": texts,
+           "counters": {}, "registry": {},
+           "window_counts": {"steps": 1, "counters": {}}}
+    cell = runner.load_cell(BENCHMARK, CELL)
+    by_scope = [m["name"] for m in cell["per_layer"]
+                if runner.metric_spec(m["name"])["reduce"]["rule"]
+                == "scope_ms"]
+    assert {"decode_kv_write_dev_ms", "decode_unscoped_dev_ms",
+            "decode_window_attn_dev_ms", "decode_full_attn_dev_ms",
+            "decode_experts_dev_ms"} <= set(by_scope)
+    tr = trace.Trace({dev: ops}, {dev: mods}, [])
+    for name in by_scope:
+        value = trace.reduce_metric(runner.metric_spec(name), tr,
+                                    (0.0, t), ctx)
+        assert value is not None and value > 0, name
 
 
 def test_cost_functions_count_what_the_model_needs():

@@ -315,11 +315,22 @@ def snapshot(**counters):
 START = snapshot(serve__decode_steps=10, serve__decode_slot_steps=30,
                  serve__decode_context_tokens=3000, serve__prefill_tokens=500,
                  serve__tokens_out=30,
-                 serve__admission_stalls={"no_pages": 1})
+                 serve__admission_stalls={"no_pages": 1},
+                 serve__steps=20, serve__step_wall_s=1.0,
+                 serve__caller_s=0.5, serve__empty_s=0.25,
+                 serve__fetch_wait_total_s=0.1,
+                 serve__decode_steps_overlapped=9)
+# `serve.stalled_steps` is labelled by phase in the program; any label
+# does here: the bare name is the sum over the series
 END = snapshot(serve__decode_steps=12, serve__decode_slot_steps=40,
                serve__decode_context_tokens=6000, serve__prefill_tokens=628,
                serve__tokens_out=40,
-               serve__admission_stalls={"no_pages": 1, "no_slot": 1})
+               serve__admission_stalls={"no_pages": 1, "no_slot": 1},
+               serve__steps=24, serve__step_wall_s=1.6,
+               serve__caller_s=0.6, serve__empty_s=0.55,
+               serve__fetch_wait_total_s=0.112,
+               serve__decode_steps_overlapped=10,
+               serve__stalled_steps={"token_fetch": 1})
 
 
 def window_ctx():
@@ -341,12 +352,21 @@ def test_counter_values_are_flat_by_name_and_by_series():
 
 
 @pytest.mark.parametrize("metric,expect", [
-    # the four readings `run_inside.window_counters` made by hand (PR 24),
+    # the readings `run_inside.window_counters` made by hand (PR 24),
     # now one rule and a data file each
-    ("chat.admit_stall_pct", 50.0),
     ("chat.decode_ctx_ktokens_step", 1.5),
     ("chat.decode_batch_inside", 5.0),
-    ("batch.prefill_token_share_inside", 100.0 * 128 / (128 + 10)),
+    ("prefill_token_share_inside", 100.0 * 128 / (128 + 10)),
+    # what the step record's counters (PRs 37, 38) were built for: each a
+    # ratio, so that a counter the window never touched counts 0
+    ("engine_empty_pct", 100.0 * 0.3 / (0.6 + 0.1 + 0.3)),
+    ("chat.engine_empty_pct", 30.0),
+    ("stalled_steps_pct", 25.0),
+    ("chat.stalled_steps_pct", 25.0),
+    ("fetch_wait_ms_step", 3.0),
+    ("chat.fetch_wait_ms_step", 3.0),
+    ("decode_overlap_pct", 50.0),
+    ("chat.decode_overlap_pct", 50.0),
 ])
 def test_counter_rule_files_read_the_registrys_difference(metric, expect):
     spec = traffic.load_json("metrics", metric)
@@ -356,6 +376,19 @@ def test_counter_rule_files_read_the_registrys_difference(metric, expect):
     # a program that has no such counter: nothing to read, no error
     assert trace.reduce_metric(spec, None, None,
                                {"counters": {}, "registry": {}}) is None
+
+
+def test_a_ratio_counts_an_untouched_counter_as_nought():
+    """A window in which the engine was never empty and no step stalled
+    has no `serve.empty_s` and no `serve.stalled_steps` in the registry's
+    difference: the two metrics read 0, they are not missing from the
+    run's line (a metric missing where its list names the cell is a
+    fault of the run)."""
+    ctx = {"counters": {}, "registry": {
+        "serve.steps": 8.0, "serve.step_wall_s": 0.9, "serve.caller_s": 0.1}}
+    for name in ("engine_empty_pct", "stalled_steps_pct"):
+        spec = traffic.load_json("metrics", name)
+        assert trace.reduce_metric(spec, None, None, ctx) == 0.0
 
 
 def test_counter_rule_by_labels_and_with_an_untouched_numerator():
@@ -377,11 +410,11 @@ def test_counter_rule_by_labels_and_with_an_untouched_numerator():
 def test_every_metric_of_the_benchmark_is_rehearsed():
     """The rehearsal files (`rehearsal.json`, and one more a family that
     needs a configuration of its own: `rehearsal-kimi.json`) list between
-    them exactly the per-layer metrics of `BENCHMARK.json` (77 at PR 31:
-    the 26 folded in by PR 26 and the 22 `kimi.*` of PR 27 among them),
-    each as the benchmark has it, on cells that report the end-to-end
-    metric it moves, and each has its file.  A later PR's metrics come
-    with a rehearsal file of their own."""
+    them exactly the per-layer metrics of `BENCHMARK.json` (84 of the 128
+    it may hold since PR 40 folded the entries that repeated a rule once
+    a cell), each as the benchmark has it, on cells that report the
+    end-to-end metric it moves, and each has its file.  A later PR's
+    metrics come with a rehearsal file of their own."""
     import glob
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
@@ -389,10 +422,15 @@ def test_every_metric_of_the_benchmark_is_rehearsed():
     for path in sorted(glob.glob(os.path.join(HERE, "rehearsal*.json"))):
         with open(path) as f:
             rehearsals.append(json.load(f))
-    assert len(rehearsals) >= 2 and len(bench["per_layer"]) >= 77
+    assert len(rehearsals) >= 2 and len(bench["per_layer"]) <= 128
     rehearsed = [m for r in rehearsals for m in r["per_layer"]]
-    assert sorted(m["name"] for m in rehearsed) == \
-        sorted(m["name"] for m in bench["per_layer"])
+    # an entry whose list names several cells is rehearsed once in the
+    # file of each family it reads (PR 40): sets, not counts
+    assert {m["name"] for m in rehearsed} == \
+        {m["name"] for m in bench["per_layer"]}
+    for r in rehearsals:
+        names = [m["name"] for m in r["per_layer"]]
+        assert len(names) == len(set(names))
     for f in [bench] + rehearsals:
         cells = {w["name"] for w in f["workloads"]}
         for m in f["per_layer"]:
@@ -403,21 +441,22 @@ def test_every_metric_of_the_benchmark_is_rehearsed():
             assert m["workloads"] and set(m["workloads"]) <= cells \
                 & set(moved.get("workloads", cells)), m["name"]
     same = ("unit", "better", "source", "layer", "moves")
-    as_rehearsed = {m["name"]: m for m in rehearsed}
-    for a in bench["per_layer"]:
-        b = as_rehearsed[a["name"]]
-        assert [a[k] for k in same] == [b[k] for k in same], a["name"]
+    as_benched = {m["name"]: m for m in bench["per_layer"]}
+    for b in rehearsed:
+        a = as_benched[b["name"]]
+        assert [a[k] for k in same] == [b[k] for k in same], b["name"]
 
 
 @pytest.mark.parametrize("cell,present,ratio", [
     ("tiny-chat", ("chat.host_work_ms_step", "chat.token_gap_p99_ms",
-                   "chat.admit_stall_pct", "chat.decode_ctx_ktokens_step"),
+                   "chat.engine_empty_pct", "chat.decode_ctx_ktokens_step"),
      ("chat.decode_batch_inside", "serve.decode_slot_steps",
       ("serve.decode_steps",), 1.0)),
-    ("tiny-batch", ("batch.host_work_ms_step",),
-     ("batch.prefill_token_share_inside", "serve.prefill_tokens",
+    ("tiny-batch", ("host_work_ms_step", "engine_empty_pct",
+                    "stalled_steps_pct", "decode_overlap_pct"),
+     ("prefill_token_share_inside", "serve.prefill_tokens",
       ("serve.prefill_tokens", "serve.tokens_out"), 100.0)),
-    ("tiny-gpt2-chat", ("chat.host_work_ms_step", "chat.admit_stall_pct"),
+    ("tiny-gpt2-chat", ("chat.host_work_ms_step", "chat.stalled_steps_pct"),
      ("chat.decode_batch_inside", "serve.decode_slot_steps",
       ("serve.decode_steps",), 1.0)),
 ])

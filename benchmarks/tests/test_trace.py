@@ -516,9 +516,16 @@ def serving_ctx(steps, hlo_texts=()):
     counters = {"serve.decode_context_tokens": 2600.0 * steps,
                 "serve.decode_slot_steps": 4.6 * steps,
                 "serve.decode_steps": float(steps),
+                "serve.decode_steps_overlapped": float(steps),
                 "serve.admission_stalls": 0.0,
                 "serve.prefill_tokens": 640.0 * steps,
-                "serve.tokens_out": 4.6 * steps}
+                "serve.tokens_out": 4.6 * steps,
+                # the step record (PR 38): no step stalled, the engine
+                # was never empty, so those two counters are not there
+                "serve.steps": float(steps),
+                "serve.step_wall_s": 5e-4 * steps,
+                "serve.caller_s": 6e-5 * steps,
+                "serve.fetch_wait_total_s": 1e-4 * steps}
     return {"steps": steps, "family": llama, "config": config,
             "peaks": peaks.peaks_for("TPU v5 lite"),
             "counters": {"engine_steps": steps, "peak_hbm_gb": 8.5},
@@ -586,10 +593,13 @@ def test_a_trace_with_the_programs_spans_reads_as_head_read_it(tmp_path):
     w = trace.window_of(tr)
     assert list(w) == head["window"]
     assert close(trace.busy_seconds(tr, w), head["busy_s"])
+    # `chat.admit_stall_pct` was read then and is retired since (PR 40:
+    # it never read anything but 0, and reads 0.0 in this file too)
+    assert head["metrics"].pop("chat.admit_stall_pct") == 0.0
     for name, was in head["metrics"].items():
         spec = traffic.load_json("metrics", name)
         assert close(trace.reduce_metric(spec, tr, w, ctx), was), name
-    assert sum(v is not None for v in head["metrics"].values()) == 22
+    assert sum(v is not None for v in head["metrics"].values()) == 21
     assert close([list(x) for x in trace.top_device_ops(tr, w)],
                  head["device_ops"])
     assert close([list(x) for x in trace.attribute_gaps(tr, w)],

@@ -25,6 +25,17 @@ because what lies behind a window layer's window is never read again
 and its pages go back to the free list while the request lives.  A
 model all of whose layers read everything and store alike has one kind,
 and is served as it always was.
+
+**What a SEQUENCE stores** is the third part of a kind: a layer whose
+cache is a fixed state a sequence, whatever its length (a linear-attention
+layer's recurrent state and the last positions of its short convolution),
+says so by `state_shapes` and stores nothing a token.  Such a layer holds
+no pages: the pool keeps, beside the page arrays of the page kinds, one
+array a state kind and a state array of it, `[layers of the kind, slots +
+1, *shape]`, indexed by SLOT (serving/kv_pool.py), and the programs carry
+it as they carry the pages (models/generation.py: `state_chunk`,
+`state_step`).  `kinds` names the kinds that hold pages; the state kinds
+follow them in `kind_of`'s numbering.
 """
 from __future__ import annotations
 
@@ -63,6 +74,13 @@ class CacheContract:
     #: stores `token_shapes` (held in `stored_shapes`)
     layer_token_shapes: Tuple[Tuple[Tuple[int, ...], ...], ...] = None
     layer_stored_shapes: Tuple[Tuple[Tuple[int, ...], ...], ...] = None
+    #: per layer, what a SEQUENCE stores there whatever its length: one
+    #: (shape, dtype name) per state array (((32, 128, 128), "float32"),
+    #: ((3, 12288), "bfloat16")), or None for a layer of pages; None for
+    #: the whole tuple where every layer is one.  A state layer stores
+    #: nothing a token: its `layer_token_shapes` entry is ()
+    state_shapes: Tuple[Optional[Tuple[Tuple[Tuple[int, ...], str], ...]],
+                        ...] = None
 
     def __post_init__(self):
         if self.stored_shapes is None:
@@ -77,43 +95,108 @@ class CacheContract:
             object.__setattr__(
                 self, "layer_stored_shapes", self.layer_token_shapes
                 if by_layer else (self.stored_shapes,) * self.num_layers)
+        if self.state_shapes is None:
+            object.__setattr__(self, "state_shapes",
+                               (None,) * self.num_layers)
+        else:
+            # a state layer stores nothing a token, whatever was given
+            for name in ("layer_token_shapes", "layer_stored_shapes"):
+                object.__setattr__(self, name, tuple(
+                    s if st is None else ()
+                    for s, st in zip(getattr(self, name),
+                                     self.state_shapes)))
         for what in (self.windows, self.layer_token_shapes,
-                     self.layer_stored_shapes):
+                     self.layer_stored_shapes, self.state_shapes):
             if len(what) != self.num_layers:
                 raise ValueError(f"{len(what)} entries for "
                                  f"{self.num_layers} layers: {what}")
-        if len({len(s) for s in self.layer_token_shapes
-                + self.layer_stored_shapes} | {len(self.token_shapes)}) != 1:
+        paged = [s for shapes, st in zip(
+            zip(self.layer_token_shapes, self.layer_stored_shapes),
+            self.state_shapes) if st is None for s in shapes]
+        if not paged:
+            raise ValueError("some layer has to hold pages: a slot is live "
+                             "where it holds one (models/generation.py)")
+        if len({len(s) for s in paged} | {len(self.token_shapes)}) != 1:
             raise ValueError("every layer stores the same NUMBER of arrays "
                              "a token (K and V, or one latent)")
+        if any(st is not None and w is not None
+               for st, w in zip(self.state_shapes, self.windows)):
+            raise ValueError("a state layer reads no window: its state is "
+                             "the whole sequence")
 
     def _kind_key(self, layer: int):
         return (self.windows[layer], self.layer_token_shapes[layer],
-                self.layer_stored_shapes[layer])
+                self.layer_stored_shapes[layer], self.state_shapes[layer])
 
     @functools.cached_property
     def _kinds(self):
-        """The distinct (window, token shapes, stored shapes): layers
-        that read everything first, then by width, then by shape."""
+        """The distinct (window, token shapes, stored shapes, state
+        shapes): the kinds that hold pages first (layers that read
+        everything, then by width, then by shape), then the state
+        kinds."""
         return tuple(sorted(
             {self._kind_key(l) for l in range(self.num_layers)},
-            key=lambda k: (k[0] is not None, k[0] or 0) + k[1:]))
+            key=lambda k: (k[3] is not None, k[0] is not None, k[0] or 0)
+            + k[1:3] + (k[3] or (),)))
 
     @property
     def kinds(self) -> Tuple[Optional[int], ...]:
-        """The window of each KIND of layer (a kind is a window and what
-        a token stores there): one set of page arrays and one page table
-        each."""
-        return tuple(k[0] for k in self._kinds)
+        """The window of each KIND of layer that holds PAGES (a kind is a
+        window, what a token stores there and what a sequence stores):
+        one set of page arrays and one page table each."""
+        return tuple(k[0] for k in self._kinds if k[3] is None)
+
+    @property
+    def state_kinds(self) -> Tuple[Tuple[Tuple[Tuple[int, ...], str], ...],
+                                   ...]:
+        """What a sequence stores in each STATE kind of layer, one
+        (shape, dtype name) a state array; the kinds `kind_of` numbers
+        from `len(kinds)` on.  Empty for a model all of whose layers
+        hold pages."""
+        return tuple(k[3] for k in self._kinds if k[3] is not None)
+
+    def is_state(self, kind: int) -> bool:
+        return kind >= len(self.kinds)
+
+    @property
+    def page_layers(self) -> int:
+        """Layers that hold pages (the leading dim of a one-kind pool)."""
+        return sum(st is None for st in self.state_shapes)
+
+    def state_arrays_of(self, kind: int) -> slice:
+        """Where a STATE kind's arrays lie among the state arrays, which
+        hold each state kind's, kind after kind (a pool's `state`)."""
+        before = self.state_kinds[:kind - len(self.kinds)]
+        lo = sum(len(s) for s in before)
+        return slice(lo, lo + len(self.state_kinds[len(before)]))
+
+    def arrays_of(self, kind: int) -> slice:
+        """Where `kind`'s arrays lie in a flat tuple that holds each page
+        kind's arrays (as many as a token stores), kind after kind, and
+        then the state arrays: a dense cache with the state arrays behind
+        it, a pool's `tree()`."""
+        n, K = len(self.token_shapes), len(self.kinds)
+        if kind < K:
+            return slice(kind * n, (kind + 1) * n)
+        at = self.state_arrays_of(kind)
+        return slice(n * K + at.start, n * K + at.stop)
+
+    def state_bytes_per_slot(self, kind: int) -> int:
+        """Bytes ONE sequence holds in all layers of a state kind."""
+        return len(self.layers_of(kind)) * sum(
+            math.prod(shape) * jnp.dtype(dt).itemsize
+            for shape, dt in self._kinds[kind][3])
 
     @property
     def by_kind(self) -> bool:
         """Pages, tables and the prefill scratch are laid out by kind of
         layer: some kind reads a window only, the kinds differ in what a
         token stores, or the arrays a token stores (K and V) differ in
-        shape."""
-        return (len(self._kinds) > 1 or self._kinds[0][0] is not None
-                or len(set(self._kinds[0][2])) > 1)
+        shape.  (Of the kinds that hold pages: a state kind holds
+        none.)"""
+        pages = [k for k in self._kinds if k[3] is None]
+        return (len(pages) > 1 or pages[0][0] is not None
+                or len(set(pages[0][2])) > 1)
 
     def kind_of(self, layer: int) -> int:
         """The kind of one layer."""

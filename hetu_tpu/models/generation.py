@@ -49,6 +49,24 @@ it are the model's hooks:
       which also asks the family for a `sink`)
   block.attn.output(p, attn[, aux]), final_hidden(params, x),
   logits(params, h), lm_head_weight(params)
+  block.attn.state_chunk(p, hn, state, start, valid) -> (out, state')
+  block.attn.state_step(p, hn, state, live)          -> (out, state')
+      A STATE LAYER'S WHOLE ATTENTION, in place of `project` /
+      `attend_*` / `output`: a layer whose kind, by the cache contract,
+      stores a fixed state a SEQUENCE and nothing a token (`state_shapes`:
+      a linear-attention layer).  hn [b, s, hidden] (normed); `state`
+      one array [b, *shape] per state array of the kind, the rows'
+      own; `out` [b, s, hidden], the residual's addend.  `state_chunk`
+      advances each row by the first `valid[b]` of its s positions, which
+      begin at position start[b]: the rows past `valid` (a chunk's
+      padding) must leave the state as the last valid row left it.
+      `state_step` advances the rows where `live[b]` by ONE position
+      and leaves the others' state untouched (the decode pass's idle
+      slots, and slots whose prompt is still being prefilled).  The
+      programs hand a chunk that starts at position 0 zeros for its
+      state (`extend_cache`), slice the rows out of the carried state
+      arrays and write them back in place; `_walk_layers` finds the
+      layer's kind in the contract and nothing else chooses.
 
   STATS, zero_stats(), add_stats(a, b)
       `stats` is a small int32 vector a layer counts of itself (an
@@ -233,16 +251,49 @@ def _of_kind(arrays, kind: int, n: int):
     """The `n` arrays of `kind` in a tuple that holds `n` a kind, kind
     after kind (a dense cache, a pool's pages), and that tuple with
     others in their place."""
+    return _at(arrays, slice(kind * n, (kind + 1) * n))
+
+
+def _at(arrays, where: slice):
+    """The arrays at `where` of a tuple, and that tuple with others in
+    their place."""
     def put(new):
-        return arrays[:kind * n] + tuple(new) + arrays[(kind + 1) * n:]
-    return arrays[kind * n:(kind + 1) * n], put
+        return arrays[:where.start] + tuple(new) + arrays[where.stop:]
+    return arrays[where], put
+
+
+def _state_rows(arrays, at, row0, b: int):
+    """Rows row0 .. row0 + b - 1 of ONE layer (`at` = (j,)) of a state
+    kind's carried arrays [layers, rows, ...], and the arrays with these
+    rows written back where they lay."""
+    rows = tuple(lax.dynamic_slice_in_dim(a[at], row0, b, axis=0)
+                 for a in arrays)
+
+    def put(new):
+        return tuple(
+            lax.dynamic_update_slice(
+                a, n.astype(a.dtype)[None],
+                at + (row0,) + (0,) * (a.ndim - 2))
+            for a, n in zip(arrays, new))
+    return rows, put
+
+
+def _refuse_state(model, what: str):
+    """The programs over a dense cache that `generate()` runs are not
+    built for a state kind of layer."""
+    if cache_contract(model).state_kinds:
+        raise NotImplementedError(
+            f"{type(model).__name__} has layers that keep a state a "
+            f"sequence (the cache contract's state_shapes); {what} is not "
+            "built for them: the chunk program (extend_cache) and the "
+            "paged decode step carry the state")
 
 
 # ---------------------------------------------------------------------------
 # One decoder layer, one walk over the layers
 # ---------------------------------------------------------------------------
 
-def _layer(block, lp, h, rope, pos_ids, cache_step):
+def _layer(block, lp, h, rope, pos_ids, cache_step, state_step=None):
     """THE decoder layer of every program below: norm, projection,
     attention over the cache, output and residual, then the MLP and its
     residual, under the scopes a device trace is summed by
@@ -257,6 +308,12 @@ def _layer(block, lp, h, rope, pos_ids, cache_step):
     under inside `attn` (`block.attn_scope`), so that a trace tells the
     kinds of layer apart.  What `project` returns beyond (q, entries)
     (a gate on the attention's output) is handed to `output`.
+
+    A STATE layer (the walk's caller saw its kind in the contract) is
+    given `state_step(attn module, its params, hn) -> (the residual's
+    addend [b, s, hidden], *rest)` instead: the model's `state_chunk` or
+    `state_step` hook over the rows of the carried state, in place of
+    projection, cache and output.
     Returns (h, the layer's stats, *rest)."""
     window = getattr(block, "window", None)
     win = {} if window is None else {"window": window}
@@ -264,9 +321,14 @@ def _layer(block, lp, h, rope, pos_ids, cache_step):
     with jax.named_scope("attn"), \
             (jax.named_scope(scope) if scope else contextlib.nullcontext()):
         hn = block.input_norm(lp["input_norm"], h)
-        q, entries, *aux = block.attn.project(lp["attn"], hn, rope, pos_ids)
-        attn, *rest = cache_step(block.attn, lp["attn"], q, entries, win)
-        h = h + block.attn.output(lp["attn"], attn, *aux)
+        if state_step is not None:
+            out, *rest = state_step(block.attn, lp["attn"], hn)
+            h = h + out
+        else:
+            q, entries, *aux = block.attn.project(lp["attn"], hn, rope,
+                                                  pos_ids)
+            attn, *rest = cache_step(block.attn, lp["attn"], q, entries, win)
+            h = h + block.attn.output(lp["attn"], attn, *aux)
     with jax.named_scope("mlp"):
         y, st = block.mlp_stats(lp["mlp"],
                                 block.post_norm(lp["post_norm"], h))
@@ -309,7 +371,10 @@ def _walk_layers(model, params, x, state, stats, layer):
     read as far back and store the same shapes are one kind, with cache
     arrays, page arrays and a page table of their own) and its place j
     among the layers of that kind (the leading dim of a dense cache's
-    and of a paged pool's arrays); with one kind j is l.  `out` is
+    and of a paged pool's arrays, and of a state kind's state arrays:
+    the state kinds are numbered behind the kinds that hold pages, and
+    `contract.is_state(kind)` is how a program's `layer` knows to give
+    `_layer` a `state_step`); with one kind j is l.  `out` is
     whatever a layer hands out besides (a token's entries for a paged
     pool to scatter; None): stacked over the layers of a kind, and with
     several kinds the kinds' tuples one after the other, as a dense
@@ -318,8 +383,9 @@ def _walk_layers(model, params, x, state, stats, layer):
     what it does to the state before and after."""
     l0 = 0
     contract = cache_contract(model)
-    outs = [[] for _ in contract.kinds]
-    seen = [0] * len(outs)          # layers walked so far, by kind
+    outs = [[] for _ in contract.kinds]      # (of the kinds with pages)
+    # layers walked so far, by kind: the state kinds behind the others
+    seen = [0] * (len(outs) + len(contract.state_kinds))
 
     def add(stats, st):
         return stats if stats is None else model.add_stats(stats, st)
@@ -345,7 +411,8 @@ def _walk_layers(model, params, x, state, stats, layer):
             (x, state, stats), out = lax.scan(
                 body, (x, state, stats),
                 (lp, jnp.arange(l0, l0 + count, dtype=jnp.int32)))
-        outs[kind].append(out)
+        if kind < len(outs):        # (a state layer hands nothing out)
+            outs[kind].append(out)
         l0 += count or 1
         seen[kind] += count or 1
     outs = [o[0] if len(o) == 1 else jax.tree.map(
@@ -365,6 +432,7 @@ def prefill(model, params, input_ids, max_len: int):
     return (last_logits [b, vocab], cache): the entries of every layer,
     padded to `max_len` positions (`init_cache`'s arrays)."""
     _check_context_length(model.config, max_len)
+    _refuse_state(model, "prefill of whole prompts into a dense cache")
     b, plen = input_ids.shape
     pos_ids = jnp.broadcast_to(jnp.arange(plen, dtype=jnp.int32), (b, plen))
     rope = model.rope_tables(max_len)
@@ -414,6 +482,7 @@ def decode_step_slots(model, params, tokens, cache, positions):
     with several kinds of layer a kind's after a kind's, as the cache
     is laid out) — a paged cache scatters them into its pool instead of
     carrying the dense cache."""
+    _refuse_state(model, "the decode step over a dense cache")
     b = tokens.shape[0]
     uniform = jnp.ndim(positions) == 0
     pos_ids = (jnp.broadcast_to(positions, (b, 1)) if uniform
@@ -453,7 +522,7 @@ def decode_step(model, params, token, cache, pos):
 
 def extend_cache(model, params, tokens, cache, start, stats=None, *,
                  collect_token_kv: bool = False, slide: bool = False,
-                 max_len: Optional[int] = None):
+                 max_len: Optional[int] = None, state_row=0, valid=None):
     """Advance a dense cache by a whole token block (chunked prefill).
 
     tokens: [b, C] int32 at absolute positions start..start+C-1 (start
@@ -479,6 +548,19 @@ def extend_cache(model, params, tokens, cache, start, stats=None, *,
     (for the rotation tables) is then that kind's length, and must be
     given where every kind slides.
 
+    **A model with STATE layers** (the contract's `state_shapes`): the
+    state arrays [layers of the kind, rows, *shape] follow the page
+    kinds' arrays in `cache` (the engine hands in the pool's own,
+    `serving/kv_pool.PagePool.state`, donated with the scratch) and are carried, read and
+    written in place like them: the b rows from `state_row` on are the
+    sequences' (the engine: one row, the slot's).  `valid` [b] (default
+    C) is how many of a row's C tokens are the prompt's: the rows past it
+    are the chunk's padding, which a page layer's causal mask keeps from
+    the rows before them and which a state layer must not take into its
+    state (`state_chunk`).  A row whose chunk starts at position 0 starts
+    from ZERO state, whatever the arrays held: a slot is reset by its
+    next prompt's first chunk and by nothing else.
+
     ``collect_token_kv=True`` (the `verify_step_slots` path) also
     returns the chunk's entries per layer ((k, v) [L, b, C, n_kv, hd])
     so a paged cache can scatter them into its pool; given the running
@@ -488,8 +570,14 @@ def extend_cache(model, params, tokens, cache, start, stats=None, *,
     rows = jnp.arange(b)
     start = jnp.broadcast_to(jnp.asarray(start, jnp.int32), (b,))
     qpos = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]  # [b, C]
-    rope = model.rope_tables(max_len or max(c.shape[2] for c in cache))
-    n = len(cache_contract(model).token_shapes)
+    contract = cache_contract(model)
+    n = len(contract.token_shapes)
+    n_paged = n * len(contract.kinds)
+    rope = model.rope_tables(
+        max_len or max(c.shape[2] for c in cache[:n_paged]))
+    if contract.state_kinds:
+        valid = jnp.broadcast_to(
+            jnp.asarray(C if valid is None else valid, jnp.int32), (b,))
     # the scopes the training programs carry, so that a device trace of
     # the serving programs is summed under the same names (obs.scope_map)
     with jax.named_scope("embed"):
@@ -497,6 +585,18 @@ def extend_cache(model, params, tokens, cache, start, stats=None, *,
 
     def layer(block, lp, h, cache, at, page_at):
         kind, at = page_at
+        if contract.is_state(kind):
+            arrays, put_kind = _at(cache, contract.arrays_of(kind))
+
+            def state_step(attn, p, hn):
+                mine, put = _state_rows(arrays, at, state_row, b)
+                fresh = start == 0
+                mine = tuple(
+                    jnp.where(fresh.reshape((b,) + (1,) * (a.ndim - 1)),
+                              jnp.zeros((), a.dtype), a) for a in mine)
+                out, new = attn.state_chunk(p, hn, mine, start, valid)
+                return out, put_kind(put(new)), None
+            return _layer(block, lp, h, rope, qpos, None, state_step)
         mine, put = _of_kind(cache, kind, n)
         sliding = slide and getattr(block, "window", None) is not None
 
@@ -640,7 +740,17 @@ def _paged_forward(model, params, tokens, pool_tree, table, positions,
     n, K = len(contract.token_shapes), len(contract.kinds)
     # one page table a kind: [S, max_pages], or [K, S, max_pages]
     tables = (table,) if table.ndim == 2 else tuple(table)
-    pools, scales = tuple(pool_tree[:n * K]), tuple(pool_tree[n * K:])
+    n_state = sum(len(s) for s in contract.state_kinds)
+    pool_tree = tuple(pool_tree)
+    pool_tree, states = (pool_tree[:len(pool_tree) - n_state],
+                         pool_tree[len(pool_tree) - n_state:])
+    pools, scales = pool_tree[:n * K], pool_tree[n * K:]
+    if n_state and (C > 1 or scales):
+        raise NotImplementedError(
+            "a block of tokens a slot (the verify step) and quantized "
+            "pages are not built for a model with state layers")
+    # a slot is live where it holds a page
+    live = jnp.any(tables[0] != 0, axis=-1) if n_state else None
     if len(tables) != K or (scales and K > 1):
         raise ValueError(
             f"{K} kinds of layer need {K} page tables and exact pages, "
@@ -657,8 +767,18 @@ def _paged_forward(model, params, tokens, pool_tree, table, positions,
     rope = model.rope_tables(table.shape[-1] * ps)
 
     def layer(block, lp, h, state, at, page_at):
-        flats, scales = state
+        flats, scales, states = state
         kind, (l,) = page_at
+        if contract.is_state(kind):
+            arrays, put_kind = _at(states, contract.state_arrays_of(kind))
+
+            def state_step(attn, p, hn):
+                mine, put = _state_rows(arrays, (l,), 0, hn.shape[0])
+                out, new = attn.state_step(p, hn, mine, live)
+                return out, (flats, scales, put_kind(put(new)))
+            h, st, state = _layer(block, lp, h, rope, pos_ids, None,
+                                  state_step)
+            return h, st, state, None
         flat, tbl = flats[kind], tables[kind]
         base = l * pages[kind]
 
@@ -674,7 +794,8 @@ def _paged_forward(model, params, tokens, pool_tree, table, positions,
                          if scales else {})
             return (attn.attend_paged(p, q, flat_, tbl, positions, base,
                                       **quantized, **win),
-                    (flats[:kind] + (flat_,) + flats[kind + 1:], scales_))
+                    (flats[:kind] + (flat_,) + flats[kind + 1:], scales_,
+                     states))
         h, st, state = _layer(block, lp, h, rope, pos_ids, step)
         return h, st, state, None
 
@@ -682,11 +803,11 @@ def _paged_forward(model, params, tokens, pool_tree, table, positions,
         flats = tuple(
             tuple(p.reshape((p.shape[0] * p.shape[1],) + p.shape[2:])
                   for p in pools[k * n:(k + 1) * n]) for k in range(K))
-        x, stats, (flats, scales), _ = _walk_layers(
-            model, params, x, (flats, scales), stats, layer)
+        x, stats, (flats, scales, states), _ = _walk_layers(
+            model, params, x, (flats, scales, states), stats, layer)
         pools = tuple(f.reshape(p.shape)
                       for f, p in zip(sum(flats, ()), pools))
-    return model.final_hidden(params, x), pools + scales, stats
+    return model.final_hidden(params, x), pools + scales + states, stats
 
 
 def decode_step_paged(model, params, tokens, pool_tree, table, positions,

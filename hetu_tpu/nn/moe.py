@@ -480,18 +480,33 @@ class MoELayer(Module):
 # ---------------------------------------------------------------------------
 
 def noaux_tc_gate(x, w_gate, bias, *, top_k: int, norm_topk_prob: bool,
-                  routed_scaling_factor: float):
-    """The published `noaux_tc` gate with `n_group` = `topk_group` = 1,
-    in float32 as the published code computes it.  x [T, h];
-    w_gate [h, E]; bias [E] (`e_score_correction_bias`, a buffer).
-    The `top_k` experts of a token are the largest of sigmoid(x W_g) + b;
-    their weights are the sigmoid scores WITHOUT b at those experts,
-    divided by their sum (`norm_topk_prob`), times the scaling factor.
+                  routed_scaling_factor: float, n_group: int = 1,
+                  topk_group: int = 1):
+    """The published `noaux_tc` gate, in float32 as the published code
+    computes it.  x [T, h]; w_gate [h, E]; bias [E]
+    (`e_score_correction_bias`, a buffer).  The `top_k` experts of a
+    token are the largest of sigmoid(x W_g) + b; their weights are the
+    sigmoid scores WITHOUT b at those experts, divided by their sum
+    (`norm_topk_prob`), times the scaling factor.  With `n_group` > 1 the
+    experts lie in `n_group` groups of E / n_group neighbours; a group's
+    score is the sum of its two largest s + b, the `topk_group` best
+    groups stay, and the `top_k` are chosen inside them (a deployment
+    that holds a group a chip sends a token to `topk_group` chips at
+    most).  `n_group` = `topk_group` = 1 is the gate as it always was.
     Returns (expert ids [T, k] int32, weights [T, k] float32)."""
     scores = jax.nn.sigmoid(jnp.einsum(
         "th,he->te", x.astype(jnp.float32), w_gate.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    choice = scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        T, E = choice.shape
+        by_group = choice.reshape(T, n_group, E // n_group)
+        group_score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+        _, keep = jax.lax.top_k(group_score, topk_group)        # [T, kg]
+        kept = jnp.any(keep[:, :, None] == jnp.arange(n_group), axis=1)
+        choice = jnp.where(kept[:, :, None], by_group,
+                           -jnp.inf).reshape(T, E)
+    _, idx = jax.lax.top_k(choice, top_k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if norm_topk_prob:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
@@ -588,8 +603,14 @@ class SharedRoutedExperts(Module):
                  n_shared_experts: int, norm_topk_prob: bool,
                  routed_scaling_factor: float, param_dtype=jnp.float32,
                  initializer_range: float = 0.02,
-                 bias_range: float = 0.02):
+                 bias_range: float = 0.02, n_group: int = 1,
+                 topk_group: int = 1):
         super().__init__()
+        if n_routed_experts % n_group or not 0 < topk_group <= n_group:
+            raise ValueError(f"{n_routed_experts} experts in {n_group} "
+                             f"groups, {topk_group} of them kept")
+        self.groups = (dict(n_group=n_group, topk_group=topk_group)
+                       if n_group > 1 else {})
         if not 0 <= first_expert <= n_routed_experts - experts_held:
             raise ValueError(
                 f"experts {first_expert}..{first_expert + experts_held - 1}"
@@ -626,7 +647,7 @@ class SharedRoutedExperts(Module):
         return noaux_tc_gate(
             xt, params["w_gate"], params["e_score_correction_bias"],
             top_k=self.top_k, norm_topk_prob=self.norm,
-            routed_scaling_factor=self.scaling)
+            routed_scaling_factor=self.scaling, **self.groups)
 
     def forward(self, params, x):
         b, s, h = x.shape

@@ -162,11 +162,17 @@ def group_of(op_name: str, phases: Tuple[str, ...] = PHASES) -> str:
 #: `layer/mlp` what is outside them; the attention of a layer that reads
 #: a window only and of one that reads everything, in a model that has
 #: both (`attn` > `attn_window`, `attn_full`: models/generation.py
-#: `_layer`, models/trinity); no program without these scopes changes its
-#: groups.
+#: `_layer`, models/trinity); a linear-attention layer and its parts
+#: (`attn` > `kda` > `kda_proj`, `kda_conv`, `kda_scan` in the chunk
+#: program / `kda_step` in the decode program, `kda_out`:
+#: models/bailing_hybrid; `kda` alone keeps the input norm and the
+#: state's rows written back in place); no program without these scopes
+#: changes its groups.
 SCOPE_MAP_GROUPS = ("kv_write", "loss", "mla_q", "mla_kv", "mla_out",
                     "router", "experts", "shared_expert",
-                    "attn_window", "attn_full")
+                    "attn_window", "attn_full",
+                    "kda", "kda_proj", "kda_conv", "kda_scan", "kda_step",
+                    "kda_out")
 UNSCOPED = "unscoped"
 _INSTR_PAT = re.compile(r'^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=')
 _OPERAND_PAT = re.compile(r'%([\w.\-]+)')

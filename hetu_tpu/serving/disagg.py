@@ -118,6 +118,12 @@ class PrefillWorker:
                 "only; the disaggregated prefill tier (serving/disagg.py) "
                 "ships every layer's whole K/V scratch and is not built "
                 "for them")
+        if contract.state_kinds:
+            raise NotImplementedError(
+                f"{type(model).__name__} has layers that keep a state a "
+                "sequence; the disaggregated prefill tier "
+                "(serving/disagg.py) ships K/V scratch only and is not "
+                "built for them")
         if kind != "kv":
             raise NotImplementedError(
                 f"{type(model).__name__} keeps a {kind!r} cache; the "

@@ -360,11 +360,16 @@ class ServingEngine:
         #: some layer reads a window only, or the layers differ in what a
         #: token stores (or K is wider than V): pages by kind of layer
         self.windowed = self.cache.by_kind
-        if self.cache.kind != "kv" or self.windowed:
+        #: some layer keeps a state a sequence and no pages (the
+        #: contract's `state_shapes`): the pool holds it by slot, and the
+        #: chunk and decode programs carry it
+        self.stateful = bool(self.cache.state_kinds)
+        if self.cache.kind != "kv" or self.windowed or self.stateful:
             self._refuse_unbuilt(reshard, draft_model, drafter)
         self.pool = PagePool.for_contract(
             self.cache, num_pages=self.config.num_pages,
-            page_size=self.config.page_size, quant=self.config.kv_quant)
+            page_size=self.config.page_size, quant=self.config.kv_quant,
+            num_slots=self.config.num_slots)
         # radix prefix cache (serving/prefix_cache.py): shared prompt
         # prefixes admit with their pages already resident
         self.prefix_cache = None
@@ -553,24 +558,38 @@ class ServingEngine:
         self._scratch_positions = tuple(
             a.shape[2] for a in jax.eval_shape(self._fresh_scratch)[
                 ::len(self.cache.token_shapes)])
-        if self.windowed:
+        for k, kind in enumerate(self._kind_names
+                                 if self.windowed or self.stateful else ()):
             # what a token costs in each kind of layer, from the kind's
             # own shapes: a window kind's share is paid for the last
             # `window` positions only; and what a prefilling request's
             # scratch takes of each kind
-            for k, kind in enumerate(self._kind_names):
-                layers = len(self.cache.layers_of(k))
-                self._registry.set_gauge(
-                    "serve.kv_bytes_per_token",
-                    itemsize * layers * sum(
-                        math.prod(x) for x in self.cache.token_shapes_of(k)),
-                    kind=kind)
+            layers = len(self.cache.layers_of(k))
+            self._registry.set_gauge(
+                "serve.kv_bytes_per_token",
+                itemsize * layers * sum(
+                    math.prod(x) for x in self.cache.token_shapes_of(k)),
+                kind=kind)
+            if self.windowed:
                 self._registry.set_gauge(
                     "serve.prefill_scratch_bytes",
                     itemsize * layers * self._scratch_positions[k]
                     * sum(math.prod(x)
                           for x in self.cache.stored_shapes_of(k)),
                     kind=kind)
+        if self.stateful:
+            # a state kind costs a token nothing and a slot its state,
+            # however long the sequence
+            K = len(self.cache.kinds)
+            per_slot = [self.cache.state_bytes_per_slot(K + i)
+                        for i in range(len(self.cache.state_kinds))]
+            self._state_bytes_per_slot = sum(per_slot)
+            for i, nbytes in enumerate(per_slot):
+                kind = "state" if i == 0 else f"state_{i}"
+                self._registry.set_gauge("serve.kv_bytes_per_token", 0,
+                                         kind=kind)
+                self._registry.set_gauge("serve.state_bytes_per_slot",
+                                         nbytes, kind=kind)
         #: the running stats vector of the programs of a model that
         #: counts (what `model.STATS` names: an expert model's assignment
         #: counts), on the device between fetches; None for a model whose
@@ -591,7 +610,9 @@ class ServingEngine:
         # prefilling request holds (a slot in prefill has its own)
         for what, tree in (("weights", self.params),
                            ("pool", self.pool.arrays.tree()),
-                           ("scratch", jax.eval_shape(self._fresh_scratch))):
+                           ("scratch", jax.eval_shape(self._fresh_scratch)),
+                           *([("state", self.pool.state)]
+                             if self.stateful else [])):
             self._registry.set_gauge(
                 "serve.held_bytes",
                 sum(a.size * a.dtype.itemsize
@@ -620,13 +641,21 @@ class ServingEngine:
             unbuilt["resident quantized experts (moe_dispatch int8 / int4, "
                     "serving/experts.py)"] = \
                 cfg.moe_dispatch in ("int8", "int4")
+        if self.stateful:
+            # no page holds a state layer's past: a shared prefix has no
+            # state to start from, a draft block none to fall back to
+            unbuilt["moving the pool with the parameters (kv_repage)"] = \
+                cfg.kv_repage
         asked = [what for what, on in unbuilt.items() if on]
         if asked:
             keeps = "; ".join(
-                f"{len(self.cache.layers_of(k))} layers keep "
-                + ("every position" if w is None
-                   else f"the last {w} positions")
-                for k, w in enumerate(self.cache.kinds))
+                [f"{len(self.cache.layers_of(k))} layers keep "
+                 + ("every position" if w is None
+                    else f"the last {w} positions")
+                 for k, w in enumerate(self.cache.kinds)]
+                + [f"{len(self.cache.layers_of(len(self.cache.kinds) + i))} "
+                   f"layers keep a state a sequence {shapes}"
+                   for i, shapes in enumerate(self.cache.state_kinds)])
             raise NotImplementedError(
                 f"{name} stores {self.cache.token_shapes} a token a layer "
                 f"({self.cache.kind!r}: {keeps}); not built for it: "
@@ -754,9 +783,17 @@ class ServingEngine:
         slide = (dict(slide=True, max_len=self.config.max_len)
                  if self._slide else {})
 
-        def chunk_fn(params, chunk, cache, start, row, *stats):
+        # with state layers `cache` is the request's scratch and, behind
+        # it, the pool's state arrays (both donated, both carried), and
+        # two more arguments say which row of them is the slot's and how
+        # many of the chunk's rows are the prompt's
+        state_args = ("state_row", "valid") if self.stateful else ()
+
+        def chunk_fn(params, chunk, cache, start, row, *rest):
+            state = dict(zip(state_args, rest))
             logits, cache, *stats = extend_cache(
-                model, params, chunk, cache, start, *stats, **slide)
+                model, params, chunk, cache, start, *rest[len(state):],
+                **slide, **state)
             # the greedy first token of a prompt that ends on `row` of
             # this chunk: taken here, fetched at the step's end
             with jax.named_scope("lm_head"):
@@ -1023,9 +1060,12 @@ class ServingEngine:
         max_pages = self.scheduler.max_pages
         stats = self._stats_args()
         if program == "prefill_chunk":
+            # (state layers: the null slot's row, every row the prompt's)
             return (self.params, jnp.zeros((1, C), jnp.int32),
-                    self._fresh_scratch(), jnp.int32(0), jnp.int32(0),
-                    *stats)
+                    tuple(self._fresh_scratch()) + self.pool.state,
+                    jnp.int32(0), jnp.int32(0),
+                    *((jnp.int32(self.pool.null_slot), jnp.int32(C))
+                      if self.stateful else ()), *stats)
         if program == "write_pages":
             return (self.pool.arrays.tree(),
                     jax.tree.map(jnp.asarray,
@@ -1035,7 +1075,7 @@ class ServingEngine:
         pos = jnp.zeros(S, jnp.int32)
         sample_args = self._sample_args([]) if self.config.sampling else ()
         if program == "decode":
-            return (self.params, self.pool.arrays.tree(), table,
+            return (self.params, self.pool.tree(), table,
                     jnp.zeros(S, jnp.int32), pos, self._no_tokens, *stats,
                     *sample_args)
         if program != "verify":
@@ -1081,13 +1121,16 @@ class ServingEngine:
             nxt, tree = self._run_decode(*self._dummy_args("decode"))
             # and as the queued step dispatches it: the tokens of the
             # decode before it an output still on the device
-            self.pool.arrays = PoolArrays.from_tree(tree)
+            self.pool.commit(tree)
             args = list(self._dummy_args("decode"))
             args[5] = nxt
             nxt, tree = self._run_decode(*args)
-        self.pool.arrays = PoolArrays.from_tree(tree)
+        self.pool.commit(tree)
         lg, _, cache = self._chunk_jit(
             *self._dummy_args("prefill_chunk"))[:3]
+        if self.stateful:
+            # (the state arrays were donated behind the scratch)
+            self.pool.state = tuple(cache[len(cache) - len(self.pool.state):])
         tree = self._run_write(*self._dummy_args("write_pages"))
         self.pool.arrays = PoolArrays.from_tree(tree)
         if self._prime_jit is not None:
@@ -1149,11 +1192,12 @@ class ServingEngine:
         byte-identical to the single-engine run.  False = no slot/
         reservation/quota headroom right now; the caller retries next
         step (the shipment stays pending, the dedupe seq unburned)."""
-        if self.windowed:
+        if self.windowed or self.stateful:
             raise NotImplementedError(
                 f"{type(self.model).__name__} has layers that read a window "
-                "only; the disaggregated prefill tier (adopt_prefilled, "
-                "serving/disagg.py) is not built for them")
+                "only or keep a state a sequence; the disaggregated prefill "
+                "tier (adopt_prefilled, serving/disagg.py) is not built for "
+                "them")
         # the shipment's pages and first token are written from the host:
         # with nothing queued, and every finish seen (a slot may be free)
         self._drain("adopt", lambda: now)
@@ -1505,15 +1549,21 @@ class ServingEngine:
                 for i in batch:
                     tokens[i] = (-1 if slots[i].inflight
                                  else slots[i].generated[-1])
+                if self.stateful:
+                    # what the state layers read and write for the rows
+                    # that decode: a slot's whole state, once each
+                    self._registry.inc(
+                        "serve.kda_state_bytes",
+                        2 * len(batch) * self._state_bytes_per_slot)
                 decode_args = (
-                    self.params, self.pool.arrays.tree(),
+                    self.params, self.pool.tree(),
                     self._decode_table(batch),
                     jnp.asarray(tokens), jnp.asarray(positions),
                     self._no_tokens if older is None else older.out[0],
                     *self._stats_args(), *sample_args)
             with phase_span("serve.decode_dispatch", phases):
                 nxt, pool_tree = self._run_decode(*decode_args)
-                self.pool.arrays = PoolArrays.from_tree(pool_tree)
+                self.pool.commit(pool_tree)
                 # the stats ride out behind this program's tokens: what
                 # is dispatched after it counts from zero again
                 self._stats_acc = self._stats_zero
@@ -2079,10 +2129,22 @@ class ServingEngine:
             ids = np.zeros(C, np.int32)
             seg = req.prompt[s: min(s + C, plen)]
             ids[: len(seg)] = seg
+            # (with state layers the pool's state arrays ride behind the
+            # scratch, and the chunk at position 0 starts the slot's row
+            # of them from zeros)
+            scratch = tuple(st.prefill_cache)
             out = self._chunk_jit(
-                self.params, jnp.asarray(ids[None]), st.prefill_cache,
-                jnp.int32(s), jnp.int32(row), *self._stats_args())
-            logits, first, st.prefill_cache, *stats = out
+                self.params, jnp.asarray(ids[None]),
+                scratch + self.pool.state, jnp.int32(s), jnp.int32(row),
+                *((jnp.int32(slot_idx), jnp.int32(len(seg)))
+                  if self.stateful else ()), *self._stats_args())
+            logits, first, cache, *stats = out
+            st.prefill_cache, self.pool.state = (cache[:len(scratch)],
+                                                 cache[len(scratch):])
+            if self.stateful:
+                if s == 0:
+                    self._registry.inc("serve.state_resets")
+                self._registry.inc("serve.chunk_padded_rows", C - len(seg))
             if stats:
                 (self._stats_acc,) = stats
             st.chunks_done += 1

@@ -44,6 +44,17 @@ reserved, written, released behind the window, a null table entry from
 then on).  One kind, every layer reading everything, is the pool as it
 always was.
 
+**State beside pages.**  A layer whose cache is a fixed state a SEQUENCE
+(the contract's `state_shapes`: a linear-attention layer) holds no pages.
+The pool keeps one array a state kind and a state array of it,
+`[layers of the kind, slots + 1, *shape]`, zeros at the start, indexed by
+SLOT and owned by whoever holds the slot: never handed out, never paged,
+never released.  The last row is the NULL slot, the page pool's null page
+for state: the programs' warm-up and `lower_programs` write there.  The
+programs carry the state arrays behind the page arrays (`tree()`,
+`commit()`); a prompt's first chunk starts from zeros
+(models/generation.extend_cache), so a slot needs no reset of its own.
+
 Host side (allocator, free list) is plain Python; device side
 (gather/scatter) is pure-functional jax, jitted by the engine.
 """
@@ -276,6 +287,12 @@ class PagePool:
         number for every kind or one a kind (in the order of
         `contract.kinds`)."""
         K = len(contract.kinds)
+        if contract.state_kinds:
+            # what a sequence stores in each state kind, and in how many
+            # layers: arrays [layers, slots + 1, ...] beside the pages
+            kw["state_kinds"] = tuple(
+                (len(contract.layers_of(K + i)), shapes)
+                for i, shapes in enumerate(contract.state_kinds))
         if contract.kind == "kv":
             n_kv, hd = contract.stored_shapes_of(0)[0]
             what = dict(num_kv_heads=n_kv, head_dim=hd)
@@ -288,9 +305,13 @@ class PagePool:
             (stored,) = contract.stored_shapes
             what = dict(token_shape=tuple(stored))
         if K > 1 or contract.kinds[0] is not None:
+            # (a kind's layers numbered among the layers that hold pages)
+            paged = [l for l in range(contract.num_layers)
+                     if contract.state_shapes[l] is None]
             what.update(windows=contract.kinds, layers=tuple(
-                contract.layers_of(k) for k in range(K)))
-        return cls(num_layers=contract.num_layers, num_pages=num_pages,
+                tuple(paged.index(l) for l in contract.layers_of(k))
+                for k in range(K)))
+        return cls(num_layers=contract.page_layers, num_pages=num_pages,
                    page_size=page_size, dtype=contract.dtype, quant=quant,
                    **what, **kw)
 
@@ -302,7 +323,8 @@ class PagePool:
                  token_shape: Optional[Tuple[int, ...]] = None,
                  windows: Tuple[Optional[int], ...] = (None,),
                  layers: Optional[Tuple[Tuple[int, ...], ...]] = None,
-                 kind_shapes=None):
+                 kind_shapes=None, state_kinds=(),
+                 num_slots: Optional[int] = None):
         if quant not in ("none", "int8", "int4"):
             raise ValueError(f"kv quant mode {quant!r} invalid; "
                              "choices: ('none', 'int8', 'int4')")
@@ -391,6 +413,35 @@ class PagePool:
                 jnp.zeros((len(ls), n + 1, page_size) + tuple(one), dtype)
                 for ls, n, kv in zip(self.layers, by_kind, shapes)
                 for one in kv))
+        #: per state kind (layers, ((shape, dtype name), ...)), and the
+        #: state arrays [layers, slots + 1, *shape], kind after kind (the
+        #: last row the null slot); () for a pool of pages alone
+        self.state_kinds = tuple(state_kinds)
+        self.state: tuple = ()
+        if self.state_kinds:
+            if quant != "none" or num_slots is None:
+                raise ValueError("state beside pages is built for exact "
+                                 "pages, and is sized by the slots "
+                                 "(num_slots)")
+            self.null_slot = num_slots
+            if device_arrays:
+                self.state = tuple(
+                    jnp.zeros((n, num_slots + 1) + tuple(shape),
+                              jnp.dtype(dt))
+                    for n, shapes in self.state_kinds
+                    for shape, dt in shapes)
+
+    def tree(self) -> tuple:
+        """What the decode program carries: the page arrays, then the
+        state arrays."""
+        return self.arrays.tree() + self.state
+
+    def commit(self, tree):
+        """Take back what a program that was handed `tree()` (donated)
+        returned."""
+        n = len(tree) - len(self.state)
+        self.arrays = PoolArrays.from_tree(tree[:n])
+        self.state = tuple(tree[n:])
 
     # ---------------------------------------------------------- allocator
     def pages_for(self, tokens: int) -> int:

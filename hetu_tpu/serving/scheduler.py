@@ -581,7 +581,14 @@ class Scheduler:
           dedupe gate exists to prevent),
         * the shipment-dedupe books are coherent: no rid's applied-seq
           history holds a duplicate, and every applied seq is in the
-          global seq set."""
+          global seq set,
+        * a pool that holds state beside its pages holds a row of it
+          for every slot and one for the null slot."""
+        for a in self.pool.state:
+            # state beside pages: a row a slot and the null slot's
+            if a.shape[1] != self.num_slots + 1:
+                raise AssertionError(
+                    f"state array {a.shape} for {self.num_slots} slots")
         tslots: Dict[str, int] = {}
         tpages: Dict[str, int] = {}
         for i, st in enumerate(self.slots):

@@ -1,0 +1,65 @@
+"""The tier-1 twin of `benchmarks/tests/test_registry.py` (PERF.md s7:
+owed since PR 40): the registry of per-layer metrics, `BENCHMARK.json`'s
+`per_layer` list and the rule files under `benchmarks/metrics/`, held to
+each other, to the limits of the file and to the cost functions of each
+listed cell's family, where the driver counts; and the newest family's
+files with its CPU rehearsal, from `benchmarks/tests/test_ling_family.py`.
+The tests are the benchmark's own, imported and called: nothing is written
+twice."""
+import importlib.util
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+# a family may bring a reduction rule of its own, registered at import, as
+# `run.load_cell` imports a cell's family before any metric is reduced
+# (benchmarks/tests/conftest.py does the same for that directory)
+import benchmarks.families.bailing_hybrid  # noqa: E402,F401
+
+
+def _load(name):
+    spec = importlib.util.spec_from_file_location(
+        "benchmarks_tests_" + name,
+        os.path.join(ROOT, "benchmarks", "tests", name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+registry = _load("test_registry")
+ling = _load("test_ling_family")
+
+
+@pytest.mark.parametrize("name", [
+    n for n in dir(registry) if n.startswith("test_")])
+def test_registry(name):
+    getattr(registry, name)()
+
+
+@pytest.mark.parametrize("name", [
+    "test_the_cell_and_its_files",
+    "test_every_scope_rule_of_the_cell_finds_its_scope_in_the_programs",
+    "test_cost_functions_count_what_the_model_needs",
+    "test_near_tie_passes_know_both_edges_and_change_only_their_rows"])
+def test_ling_family(name):
+    getattr(ling, name)()
+
+
+@pytest.mark.parametrize("control", ["unmasked", "bf16_state"])
+def test_a_control_comes_out_not_correct(control):
+    ling.test_a_control_comes_out_not_correct(control)
+
+
+@pytest.mark.parametrize("trace_on", [0, 1])
+def test_tiny_ling_rehearses_correct(trace_on):
+    """`benchmarks/run.py --rehearse` on `tiny-ling-long-tail`: the cell's
+    whole path on the CPU, the check under the near-tie passes."""
+    ling.test_tiny_ling_rehearses_correct(trace_on)
+
+
+def test_the_parent_fails_at_once_on_the_new_cell(tmp_path):
+    ling.test_the_parent_fails_at_once_without_the_family_module(tmp_path)

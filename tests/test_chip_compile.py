@@ -438,6 +438,21 @@ def _mimo_block():
                                  prefill_chunk=512, num_pages=(4096, 48))
 
 
+def _ling_block():
+    """Ling-3.0-flash at published widths, one routing group of 64 of
+    512 experts held: the dense KDA layer, a KDA layer and the MLA layer
+    over experts (layer_group_size 3 puts the MLA layer third), at the
+    serving cell's slots, page, chunk and max_len: the state of 32 slots
+    beside the latent pages of one layer."""
+    from hetu_tpu.models.bailing_hybrid import (BailingHybridConfig,
+                                                BailingHybridLMHeadModel)
+    return BailingHybridLMHeadModel(BailingHybridConfig(
+        vocab_size=19648, num_hidden_layers=3, first_k_dense_replace=1,
+        layer_group_size=3, experts_held=64, param_dtype=BF16)), dict(
+            num_slots=32, page_size=256, max_len=32768, prefill_chunk=1024,
+            num_pages=4112)
+
+
 #: family -> ((model, ServeConfig overrides) or None for the Llama-2-7B
 #: block, the kernels its decode program must hold as tpu_custom_calls)
 SERVING_FAMILIES = {
@@ -446,6 +461,7 @@ SERVING_FAMILIES = {
     "kimi": (_kimi_block, ("paged_latent",)),
     "trinity": (_trinity_block, ("paged_attn",)),
     "mimo": (_mimo_block, ("paged_attn",)),
+    "ling": (_ling_block, ("paged_latent",)),
 }
 
 
@@ -480,7 +496,26 @@ def test_serving_programs_compile_for_one_v5e(family):
     chunk_calls = sum("pallas_chunk_attention" in ln for ln in compiled[
         "prefill_chunk"].as_text().splitlines()
         if 'custom_call_target="tpu_custom_call"' in ln)
-    if family == "kimi":
+    if family == "ling":
+        # state beside pages: both programs take the state arrays as
+        # donated arguments and hand them back in place, with the pool
+        # (the decode program) or the scratch (the chunk program): no
+        # copy of either among the temporaries
+        assert "chunk_attn" not in routes and not chunk_calls
+        nbytes = lambda tree: sum(  # noqa: E731
+            a.size * a.dtype.itemsize for a in tree)
+        state, pool = nbytes(engine.pool.state), nbytes(
+            engine.pool.arrays.tree())
+        assert state == 33 * 2 * (2_097_152 + 73_728)
+        mem = {name: c.memory_analysis() for name, c in compiled.items()}
+        assert mem["decode"].alias_size_in_bytes >= pool + state
+        assert mem["decode"].temp_size_in_bytes < 64e6
+        assert mem["prefill_chunk"].alias_size_in_bytes >= state + 32768 * 1280
+        assert mem["prefill_chunk"].temp_size_in_bytes < 0.3e9
+        text = compiled["decode"].as_text()
+        assert "kda_step" in text and "kda_scan" in compiled[
+            "prefill_chunk"].as_text()
+    elif family == "kimi":
         assert "chunk_attn" not in routes and not chunk_calls
     elif family == "trinity":
         rec = routes["chunk_attn"]

@@ -536,6 +536,10 @@ def _family_case(family, hd128):
         cfg, model, params = build(**(dict(head_dim=192, v_head_dim=128)
                                       if hd128 else {}))
         return model, params, lambda p, ids: ref_logits(p, cfg, ids)
+    if family == "ling":
+        from test_bailing_hybrid import build, ref_logits
+        cfg, model, params = build()
+        return model, params, lambda p, ids: ref_logits(p, cfg, ids)
     if family == "gpt":
         from hetu_tpu.models.gpt import GPTConfig, GPTLMHeadModel
         kw = dict(hidden_size=256, num_attention_heads=2) if hd128 else {}
@@ -560,7 +564,8 @@ def _family_case(family, hd128):
     ("kimi", "xla"), ("kimi", "kernel"),
     ("hooks-window", "gather"), ("hooks-window", "paged"),
     ("trinity", "gather"), ("trinity", "paged"),
-    ("mimo", "gather"), ("mimo", "paged")])
+    ("mimo", "gather"), ("mimo", "paged"),
+    ("ling", "xla"), ("ling", "kernel")])
 def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
     """Golden, ONE body for every family: staggered continuous batching
     through the normal path (`run`: scheduler, allocator, page tables,
@@ -585,7 +590,12 @@ def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
     against 2, keys wider than values; 192 / 128 under the kernel), with
     a sink in the window layers' softmax, a window smaller than a page
     pair and than the chunk, a sliding prefill scratch and no shared
-    expert.  `llama-unstacked` is the Llama block built with
+    expert.  `ling` is the family with a STATE kind of layer: six
+    linear-attention layers that store nothing a token and a fixed state
+    a sequence, held by slot beside the one latent layer's pages and
+    carried by the chunk and the decode program (`state_chunk`,
+    `state_step`), over the latent layer's two attentions as kimi.
+    `llama-unstacked` is the Llama block built with
     use_scan=False: a layer's own arrays, called, never scanned."""
     monkeypatch.setenv("HETU_TPU_PALLAS",
                        "1" if route in ("paged", "kernel") else "0")
@@ -606,9 +616,12 @@ def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
                   max_len=128, prefill_chunk=16, num_pages=64)
     assert eng.decode_paged == (route != "gather")
     results = {r.rid: r for r in eng.run(reqs)}
-    if family == "kimi":
+    if family in ("kimi", "ling"):
         assert eng.kernel_routes["paged_latent"][
             "pallas" if route == "kernel" else "xla"]
+    if family == "ling":
+        assert eng.stateful and len(eng.pool.state) == 2
+        assert reg.counter_value("serve.state_resets") == len(reqs)
     assert sorted(results) == list(range(len(reqs)))
     for req in reqs:
         toks = np.asarray(results[req.rid].tokens)
@@ -617,7 +630,8 @@ def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
             np.concatenate([req.prompt, toks[:-1]]))))[req.prompt_len - 1:]
         gap = lg.max(-1) - lg[np.arange(len(toks)), toks]
         assert (gap <= 2e-4).all(), (req.rid, gap)
-        if family != "kimi" and route == "gather" and family != "hooks":
+        if family not in ("kimi", "ling") and route == "gather" \
+                and family != "hooks":
             gold = generate(model, params, jnp.asarray(req.prompt[None]),
                             max_new_tokens=req.max_new_tokens)
             assert list(toks) == list(np.asarray(gold)[0, req.prompt_len:])

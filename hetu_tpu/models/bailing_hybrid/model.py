@@ -106,16 +106,19 @@ class KDAttention(Module):
             hn.shape[:-1] + (nh, hd))
         return g, beta, z
 
-    def _qkv(self, y):
+    def _qkv(self, y, unit=True):
         """Convolved channels y [.., 3 nh hd] -> q, k, v [.., nh, hd]
-        float32: SiLU, then q and k of unit length a head, q scaled."""
+        float32: SiLU, then (`unit`) q and k of unit length a head, q
+        scaled; the chunk program leaves that to the scan, which has a
+        head's 128 lanes loaded (`delta_rule.chunk_scan`'s `qk_scale`)."""
         c = self.config
         nh, hd = c.num_attention_heads, c.head_dim
-        y = jax.nn.silu(y.astype(F32)).reshape(y.shape[:-1] + (3, nh, hd))
-        q, k, v = y[..., 0, :, :], y[..., 1, :, :], y[..., 2, :, :]
-        unit = lambda x: x * lax.rsqrt(  # noqa: E731
-            jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
-        return unit(q) * hd ** -0.5, unit(k), v
+        q, k, v = (x.reshape(x.shape[:-1] + (nh, hd)) for x in jnp.split(
+            jax.nn.silu(y.astype(F32)), 3, axis=-1))
+        if unit:
+            q = delta_rule.unit_length(q) * hd ** -0.5
+            k = delta_rule.unit_length(k)
+        return q, k, v
 
     def _out(self, params, o, z):
         """o [.., nh, hd] float32, z the gate's pre-activation ->
@@ -131,9 +134,10 @@ class KDAttention(Module):
         """hn [b, C, hidden] (normed); state = (S [b, nh, hd, hd]
         float32, conv [b, K - 1, channels]): the rows' own, as the last
         chunk left them (zeros where this is the first).  The first
-        valid[b] positions are the sequence's; the rest are padding: beta
-        = 0, g = 0, and the convolution's tail is taken where the valid
-        rows end.  -> (out [b, C, hidden], state')."""
+        valid[b] positions are the sequence's; the rest are padding: the
+        scan leaves them out of the state (`chunk_scan`'s `valid`), and
+        the convolution's tail is taken where the valid rows end.
+        -> (out [b, C, hidden], state')."""
         c = self.config
         S, conv = state
         b, C = hn.shape[:2]
@@ -148,13 +152,11 @@ class KDAttention(Module):
             # the last K - 1 inputs up to the last VALID position
             conv = jax.vmap(lambda a, n: lax.dynamic_slice_in_dim(
                 a, n, K - 1, axis=0))(xx, valid).astype(conv.dtype)
-            q, k, v = self._qkv(y)
+            q, k, v = self._qkv(y, unit=False)
         with jax.named_scope("kda_scan"):
-            real = (jnp.arange(C)[None, :] < valid[:, None])  # [b, C]
-            g = jnp.where(real[..., None, None], g, 0.0)
-            beta = jnp.where(real[..., None], beta, 0.0)
-            o, S = jax.vmap(lambda *a: delta_rule.chunk_scan(
-                *a, g_floor=c.kda_lower_bound))(S, q, k, v, g, beta)
+            o, S = delta_rule.chunk_scan(S, q, k, v, g, beta, valid=valid,
+                                         g_floor=c.kda_lower_bound,
+                                         qk_scale=c.head_dim ** -0.5)
         return self._out(params, o, z), (S, conv)
 
     def state_step(self, params, hn, state, live):

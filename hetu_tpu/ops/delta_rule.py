@@ -1,6 +1,15 @@
-"""The gated delta rule with a decay per key channel (KDA), as XLA
-compositions: the chunkwise form for a block of positions (prefill) and
-the single-position update (decode).
+"""The gated delta rule with a decay per key channel (KDA): the
+chunkwise form for a block of positions (prefill) and the
+single-position update (decode).
+
+Which entry runs what: `recurrence` and `step` are XLA compositions on
+every backend (`step` is the decode program's; it stands at 71% of its
+bytes' floor and has no kernel).  `chunk_scan` is the ONE entry of the
+chunkwise form and asks `ops.pallas.resolve_route("kda_scan")`: on a TPU,
+for the shapes its gate takes, `ops/pallas/kda_scan.py` walks a head's
+blocks inside one launch with the state in VMEM; every other backend and
+shape runs the XLA composition `_chunk_scan_xla` below, the form the
+kernel is tested against.
 
 Per head, state S in R^{dk x dv} (float32), per position t a query q_t and
 a key k_t in R^dk, a value v_t in R^dv, a log-decay g_t in R^dk (<= 0) and
@@ -12,7 +21,7 @@ a write strength beta_t in [0, 1]:
 `recurrence` is that, position by position (`lax.scan`): the definition
 the other two are tested against.
 
-**Chunkwise** (`chunk_scan`).  With u_t = beta_t (v_t - (Diag(exp g_t)
+**Chunkwise** (`chunk_scan`; as written here, `_chunk_scan_xla`).  With u_t = beta_t (v_t - (Diag(exp g_t)
 S_{t-1})^T k_t) the step is S_t = Diag(exp g_t) S_{t-1} + k_t u_t^T, so
 inside a block of L positions that enters with S_0, with G_i = sum_{j<=i}
 g_j:
@@ -36,8 +45,8 @@ which the triangle drops), so with |g| < 5.5 neither leaves float32's
 range (88); a decay bounded below is what makes that possible.
 
 A position whose `beta` is 0 and `g` is 0 leaves the state as it found
-it: that is how a chunk's padding rows and a decode pass's idle slots are
-masked (the callers set them).
+it: that is how a chunk's padding rows (`chunk_scan`'s `valid`) and a
+decode pass's idle slots (the caller of `step` sets them) are masked.
 """
 from __future__ import annotations
 
@@ -63,6 +72,15 @@ def step(S, q, k, v, g, beta):
     u = beta[..., None] * (v - kS)
     o = qS + jnp.sum(q * k, axis=-1, keepdims=True) * u
     return o, Sd + k[..., None] * u[..., None, :]
+
+
+#: under the root of `unit_length`
+UNIT_EPS = 1e-6
+
+
+def unit_length(x):
+    """x [..., d] scaled to unit length along d (the QK norm a head)."""
+    return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + UNIT_EPS)
 
 
 def recurrence(S, q, k, v, g, beta):
@@ -96,20 +114,73 @@ def _unit_lower_inverse(N):
     return T
 
 
-#: positions of a block of `chunk_scan` (module docstring).  On the chip a
-#: chunk of 1,024 rows took 1.46 ms a layer in blocks of 16 and 1.72 in
-#: blocks of 64 whose system was solved in sub-blocks of 16 (my chip runs,
-#: PR 41: PERF.md s4)
+#: positions of a block of `chunk_scan`, and of a diagonal block of the
+#: kernel (module docstring: the size at which the triangular system is
+#: exact).  The benchmark's cost function divides by it.  PR 41's sweep
+#: of the COMPOSITION (1.46 ms a layer for 1,024 rows in blocks of 16,
+#: 1.72 in blocks of 64 solved in sub-blocks of 16) and PR 42's of the
+#: kernel's tile are in PERF.md s6
 BLOCK = 16
 
 
-def chunk_scan(S, q, k, v, g, beta, *, g_floor: float = -5.0):
+def chunk_scan(S, q, k, v, g, beta, *, g_floor: float = -5.0, valid=None,
+               qk_scale=None):
     """`recurrence` over s positions in blocks of `BLOCK` (no g under
-    `g_floor`; positions past the last whole block are padded with
-    beta = 0, g = 0).  Same arguments and results.  Every matrix product
-    is float32 (`HIGHEST`: six bfloat16 passes on a TPU): one pass moves
-    o and the state by 0.6% a call and six by 0.01% (my chip run, PR 41),
-    for 0.3 ms of a layer's 1.5."""
+    `g_floor`).  Same arguments and results for one sequence, or a batch
+    of them: a leading b on every argument.  `valid` (a count, [b] for a
+    batch; default s): only a sequence's first `valid` positions are its
+    own; the rest leave the state as it was and their o is zeros.
+    `qk_scale` (default None: q and k come ready): q and k come as the
+    activation left them and the scan takes `unit_length(q) * qk_scale`
+    and `unit_length(k)` for them.  The norm is a sum over a head's 128
+    lanes: made before the call, XLA needs q and k as [s, heads, 128]
+    for it, and [s, heads, 128] -> [s, heads * 128] moves bytes in a
+    TPU's tiled layout (5.7 ms a Ling chunk: PERF.md s6, PR 42); the
+    kernel makes it on the columns it has already loaded.
+
+    **The one entry, two routes** (`ops.pallas.resolve_route("kda_scan")`,
+    recorded in `kernel_routes`): on a TPU, for shapes the kernel's gate
+    takes (dk = dv a multiple of 128, s a multiple of its tile, float32
+    state, `g_floor` inside the range rule), `ops/pallas/kda_scan.py`
+    under the scope `pallas_kda_scan`; everywhere else the XLA
+    composition below, which is also what the kernel is tested against.
+    Both are float32 with every matrix product `HIGHEST` (six bfloat16
+    passes on a TPU): one pass moves o and the state by 0.6% a call and
+    six by 0.01% (my chip run, PR 41)."""
+    from hetu_tpu.ops import pallas as _pl
+    from hetu_tpu.ops.pallas import kda_scan as _ks
+    one = q.ndim == 3
+    if one:
+        S, q, k, v, g, beta = (x[None] for x in (S, q, k, v, g, beta))
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    valid = jnp.broadcast_to(
+        jnp.asarray(s if valid is None else valid, jnp.int32), (b,))
+    if _pl.resolve_route("kda_scan", _ks.check_shapes, S.shape,
+                         (b, s, h * dk), (b, s, h * dv), beta.shape,
+                         g_floor=g_floor, state_dtype=S.dtype):
+        flat = lambda x: x.astype(F32).reshape(b, s, -1)  # noqa: E731
+        with jax.named_scope("pallas_kda_scan"):
+            o, S = _ks.kda_scan(S, flat(q), flat(k), flat(v), flat(g),
+                                beta.astype(F32), valid, g_floor=g_floor,
+                                qk_scale=qk_scale)
+        o = o.reshape(b, s, h, dv)
+    else:
+        if qk_scale is not None:
+            q, k = unit_length(q.astype(F32)) * qk_scale, unit_length(
+                k.astype(F32))
+        real = jnp.arange(s)[None, :] < valid[:, None]           # [b, s]
+        g = jnp.where(real[..., None, None], g, 0.0)
+        beta = jnp.where(real[..., None], beta, 0.0)
+        o, S = jax.vmap(lambda *a: _chunk_scan_xla(*a, g_floor=g_floor))(
+            S, q, k, v, g, beta)
+        o = jnp.where(real[..., None, None], o, 0.0)
+    return (o[0], S[0]) if one else (o, S)
+
+
+def _chunk_scan_xla(S, q, k, v, g, beta, *, g_floor: float):
+    """One sequence by the XLA composition (positions past the last
+    whole block are padded with beta = 0, g = 0)."""
     s, h, dk = q.shape
     dv = v.shape[-1]
     cap = BLOCK * abs(g_floor)

@@ -545,3 +545,10 @@ def test_both_programs_carry_the_scopes_and_alias_the_state():
         state = sum(a.size * a.dtype.itemsize for a in eng.pool.state)
         assert compiled.memory_analysis().alias_size_in_bytes >= state
     assert "write_pages" in lowered
+    # the chunk program asks the scan's route once a KDA layer it traces
+    # (the tiny configuration scans its layers: one trace for the run of
+    # them; 16-wide heads on a CPU keep the composition), the decode
+    # program asks for none
+    rec = eng.kernel_routes["kda_scan"]
+    assert rec["xla"] >= 1 and not rec["pallas"]
+    assert list(rec["why"]) == ["not a TPU backend"]

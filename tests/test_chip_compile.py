@@ -182,6 +182,17 @@ def _chunk_attn(C, nq, n_kv, M, window=None):
                 spec((), I32))
 
 
+def _kda_scan():
+    """The chunkwise delta rule at the Ling cell's shape: one chunk of
+    2,048 rows, 32 heads' 128 x 128 states, the operands as the
+    projections leave them, q and k made unit length inside."""
+    from hetu_tpu.ops.pallas.kda_scan import kda_scan
+    cols = spec((1, 2048, 32 * 128), F32)
+    return (lambda *a: kda_scan(*a, g_floor=-5.0, qk_scale=128 ** -0.5),
+            (spec((1, 32, 128, 128), F32), cols, cols, cols, cols,
+             spec((1, 2048, 32), F32), spec((1,), I32)))
+
+
 def _quant(bits):
     from hetu_tpu.ops.pallas.quant import quantize_blockwise_pallas
     return (lambda x: quantize_blockwise_pallas(x, 128, bits=bits),
@@ -211,6 +222,7 @@ KERNEL_CASES = {
     "chunk_attention_trinity_window": lambda: _chunk_attn(
         512, 32, 4, 2560, window=2048),
     "chunk_attention_internlm2": lambda: _chunk_attn(128, 16, 8, 2048),
+    "kda_scan_ling_chunk": _kda_scan,
     "quant_int8": lambda: _quant(8),
     "quant_int4": lambda: _quant(4),
 }
@@ -513,8 +525,18 @@ def test_serving_programs_compile_for_one_v5e(family):
         assert mem["prefill_chunk"].alias_size_in_bytes >= state + 32768 * 1280
         assert mem["prefill_chunk"].temp_size_in_bytes < 0.3e9
         text = compiled["decode"].as_text()
-        assert "kda_step" in text and "kda_scan" in compiled[
-            "prefill_chunk"].as_text()
+        assert "kda_step" in text
+        # the chunk program walks each KDA layer's blocks in one kernel
+        # under the `kda_scan` scope; the decode program takes none
+        rec = routes["kda_scan"]
+        assert rec["pallas"] == 2 and not rec["xla"], rec
+        assert list(rec["why"]) == ["shape gate passes"]
+        scans = [ln for ln in compiled["prefill_chunk"].as_text().splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in ln
+                 and "pallas_kda_scan" in ln]
+        assert len(scans) == 2 and all("kda_scan/pallas_kda_scan" in ln
+                                       for ln in scans)
+        assert not any("pallas_kda_scan" in ln for ln in calls)
     elif family == "kimi":
         assert "chunk_attn" not in routes and not chunk_calls
     elif family == "trinity":

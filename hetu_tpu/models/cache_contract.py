@@ -36,6 +36,16 @@ array a state kind and a state array of it, `[layers of the kind, slots +
 it as they carry the pages (models/generation.py: `state_chunk`,
 `state_step`).  `kinds` names the kinds that hold pages; the state kinds
 follow them in `kind_of`'s numbering.
+
+**A layer may keep NOTHING**: the third answer a layer gives (`reads`).
+It names the layer whose entries it attends (a cross-attention layer over
+the keys and values an earlier layer wrote: it has no `project` of
+entries, no pages, no row in any table of its own, and the walk hands it
+the kind, the place and so the page table of the layer it reads), or
+nobody's (`NO_CACHE`: a gate on an activation an earlier layer handed on;
+it attends nothing).  The pool, the scratch and every byte count are
+sized from the layers that STORE; `kinds`, `kind_of`, `layers_of` and
+`values_per_token` count those alone.
 """
 from __future__ import annotations
 
@@ -49,9 +59,14 @@ import jax.numpy as jnp
 from jax import lax
 
 
+#: `CacheContract.reads` of a layer that stores nothing and attends nothing
+NO_CACHE = -1
+
+
 @dataclasses.dataclass(frozen=True)
 class CacheContract:
-    #: layers that keep a cache (the pool's leading dim)
+    #: the model's layers; those that keep a cache (`reads` None) are the
+    #: pool's leading dims
     num_layers: int
     #: per token, per layer: the shape of each array the pool holds
     #: ((n_kv, hd), (n_kv, hd)) for K and V; ((576,),) for a latent
@@ -81,6 +96,13 @@ class CacheContract:
     #: nothing a token: its `layer_token_shapes` entry is ()
     state_shapes: Tuple[Optional[Tuple[Tuple[Tuple[int, ...], str], ...]],
                         ...] = None
+    #: per layer, whose entries the layer attends: None = its own (it
+    #: keeps a cache: pages or a state); k = layer k's (it stores NOTHING
+    #: and reads what the earlier page layer k wrote, through k's kind,
+    #: place and page table); NO_CACHE = nobody's (it stores nothing and
+    #: attends nothing).  None for the whole tuple: every layer keeps its
+    #: own
+    reads: Tuple[Optional[int], ...] = None
 
     def __post_init__(self):
         if self.stored_shapes is None:
@@ -98,33 +120,60 @@ class CacheContract:
         if self.state_shapes is None:
             object.__setattr__(self, "state_shapes",
                                (None,) * self.num_layers)
-        else:
-            # a state layer stores nothing a token, whatever was given
-            for name in ("layer_token_shapes", "layer_stored_shapes"):
-                object.__setattr__(self, name, tuple(
-                    s if st is None else ()
-                    for s, st in zip(getattr(self, name),
-                                     self.state_shapes)))
+        if self.reads is None:
+            object.__setattr__(self, "reads", (None,) * self.num_layers)
         for what in (self.windows, self.layer_token_shapes,
-                     self.layer_stored_shapes, self.state_shapes):
+                     self.layer_stored_shapes, self.state_shapes,
+                     self.reads):
             if len(what) != self.num_layers:
                 raise ValueError(f"{len(what)} entries for "
                                  f"{self.num_layers} layers: {what}")
-        paged = [s for shapes, st in zip(
+        # a state layer stores nothing a token, a layer that keeps no
+        # cache nothing at all, whatever was given
+        for name in ("layer_token_shapes", "layer_stored_shapes"):
+            object.__setattr__(self, name, tuple(
+                s if st is None and r is None else ()
+                for s, st, r in zip(getattr(self, name), self.state_shapes,
+                                    self.reads)))
+        for l, r in enumerate(self.reads):
+            if r is None:
+                continue
+            if self.state_shapes[l] is not None or self.windows[l] is not None:
+                raise ValueError(f"layer {l} keeps no cache (reads {r}): it "
+                                 "has no state and no window of its own")
+            if r != NO_CACHE and not (
+                    0 <= r < l and self.reads[r] is None
+                    and self.state_shapes[r] is None):
+                raise ValueError(f"layer {l} reads layer {r}: that has to "
+                                 "be an EARLIER layer that holds pages")
+        paged = [s for shapes, st, r in zip(
             zip(self.layer_token_shapes, self.layer_stored_shapes),
-            self.state_shapes) if st is None for s in shapes]
+            self.state_shapes, self.reads)
+            if st is None and r is None for s in shapes]
         if not paged:
-            raise ValueError("some layer has to hold pages: a slot is live "
-                             "where it holds one (models/generation.py)")
+            raise ValueError("some storing layer has to hold pages: a slot "
+                             "is live where it holds one "
+                             "(models/generation.py)")
         if len({len(s) for s in paged} | {len(self.token_shapes)}) != 1:
-            raise ValueError("every layer stores the same NUMBER of arrays "
-                             "a token (K and V, or one latent)")
+            raise ValueError("every storing layer that holds pages stores "
+                             "the same NUMBER of arrays a token (K and V, "
+                             "or one latent)")
         if any(st is not None and w is not None
                for st, w in zip(self.state_shapes, self.windows)):
             raise ValueError("a state layer reads no window: its state is "
                              "the whole sequence")
 
+    def stores(self, layer: int) -> bool:
+        """The layer keeps a cache of its own (pages or a state)."""
+        return self.reads[layer] is None
+
     def _kind_key(self, layer: int):
+        """Of a layer that stores, or of the layer a reading layer reads;
+        None for a layer that keeps and reads nothing."""
+        if not self.stores(layer):
+            layer = self.reads[layer]
+            if layer == NO_CACHE:
+                return None
         return (self.windows[layer], self.layer_token_shapes[layer],
                 self.layer_stored_shapes[layer], self.state_shapes[layer])
 
@@ -135,7 +184,8 @@ class CacheContract:
         everything, then by width, then by shape), then the state
         kinds."""
         return tuple(sorted(
-            {self._kind_key(l) for l in range(self.num_layers)},
+            {self._kind_key(l) for l in range(self.num_layers)
+             if self.stores(l)},
             key=lambda k: (k[3] is not None, k[0] is not None, k[0] or 0)
             + k[1:3] + (k[3] or (),)))
 
@@ -161,7 +211,17 @@ class CacheContract:
     @property
     def page_layers(self) -> int:
         """Layers that hold pages (the leading dim of a one-kind pool)."""
-        return sum(st is None for st in self.state_shapes)
+        return sum(st is None and r is None
+                   for st, r in zip(self.state_shapes, self.reads))
+
+    def readers_of(self, layer: int) -> Tuple[int, ...]:
+        """The layers that keep nothing and attend `layer`'s entries."""
+        return tuple(l for l, r in enumerate(self.reads) if r == layer)
+
+    @property
+    def borrows(self) -> bool:
+        """Some layer keeps no cache of its own (`reads`)."""
+        return any(r is not None for r in self.reads)
 
     def state_arrays_of(self, kind: int) -> slice:
         """Where a STATE kind's arrays lie among the state arrays, which
@@ -198,15 +258,26 @@ class CacheContract:
         return (len(pages) > 1 or pages[0][0] is not None
                 or len(set(pages[0][2])) > 1)
 
-    def kind_of(self, layer: int) -> int:
-        """The kind of one layer."""
-        return self._kinds.index(self._kind_key(layer))
+    def kind_of(self, layer: int) -> Optional[int]:
+        """The kind of one layer: its own where it stores, that of the
+        layer it reads where it stores nothing; None where it neither
+        stores nor reads."""
+        key = self._kind_key(layer)
+        return None if key is None else self._kinds.index(key)
 
     def layers_of(self, kind: int) -> Tuple[int, ...]:
-        """The layers of one kind, in the model's layer order."""
+        """The layers of one kind that STORE, in the model's layer order:
+        the leading dim of the kind's arrays."""
         key = self._kinds[kind]
         return tuple(l for l in range(self.num_layers)
-                     if self._kind_key(l) == key)
+                     if self.stores(l) and self._kind_key(l) == key)
+
+    def place_of(self, layer: int) -> int:
+        """Where a layer's entries lie among the layers of its kind: its
+        own place where it stores, the place of the layer it reads where
+        it does not."""
+        at = layer if self.stores(layer) else self.reads[layer]
+        return self.layers_of(self.kind_of(at)).index(at)
 
     def token_shapes_of(self, kind: int):
         """What a token stores in a layer of `kind`, as the model needs
@@ -273,6 +344,21 @@ class KVAttention:
         the family's to override."""
         return None
 
+    def softmax_scale(self, width: int) -> float:
+        """What the scores are multiplied by, for queries `width` wide as
+        `project` hands them out: width^-1/2, the family's to override
+        (queries of 64 laid in rows of 128 beside zeros keep 64^-1/2)."""
+        return width ** -0.5
+
+    #: True: where the paged kernel's gate refuses the layer's shapes or
+    #: the backend is no TPU, `attend_paged` attends the slot's pages
+    #: gathered through the table by the XLA composition, in place of the
+    #: kernel.  For a family that has no gather decode route to fall back
+    #: to as a whole (state layers, layers that read another's pages:
+    #: `ServingEngine._use_paged_kernel`); False: the engine chose the
+    #: program, and `attend_paged` is the kernel
+    paged_composition = False
+
     def attend_paged(self, params, q, pools, table, positions, base, *,
                      scales=None, layer=None, quant=None, window=None):
         """q: a block of C queries a slot at positions[s] + i, causal
@@ -304,6 +390,10 @@ class KVAttention:
                     (pools[0].shape[-1], pools[1].shape[-1]))
         if d_k != d_v and C > 1:
             raise NotImplementedError(refusal)
+        if self.paged_composition and not self._paged_kernel_takes(
+                q, pools, table, positions, window):
+            return self._attend_gathered(params, q, pools, table, positions,
+                                         base, window)
         if window is not None:
             kw["window"] = window
             _note_route("paged_attn_window", True,
@@ -327,9 +417,38 @@ class KVAttention:
             ksl, vsl = ((s[layer] for s in scales) if scales
                         else (None, None))
             attn = kernel(_widen(q, d_k), *pools, table + base, positions,
-                          softmax_scale=hd ** -0.5, k_scale=ksl, v_scale=vsl,
-                          quant=quant, scale_table=table, **kw)
+                          softmax_scale=self.softmax_scale(hd), k_scale=ksl,
+                          v_scale=vsl, quant=quant, scale_table=table, **kw)
         return attn.reshape(S, C, nq * d_v)
+
+    def _paged_kernel_takes(self, q, pools, table, positions, window):
+        """The rule `ServingEngine._use_paged_kernel` asks of a whole
+        model, for this layer's shapes (exact pages, one query a slot)."""
+        from hetu_tpu.ops.pallas import paged_attention as _pa
+        from hetu_tpu.ops.pallas import resolve_route
+        S, C, nq, _ = q.shape
+        k_shape, v_shape = pools[0].shape, pools[1].shape
+        return C == 1 and resolve_route(
+            "paged_attn", _pa.check_shapes, (S, nq, k_shape[-1]), k_shape,
+            table.shape, (S,), pool_dtype=pools[0].dtype, window=window,
+            v_shape=v_shape)
+
+    def _attend_gathered(self, params, q, pools, table, positions, base,
+                         window, rows=tuple):
+        """`attend_paged` without the kernel: each slot's pages of this
+        layer gathered through `table` into a dense [S, max_pages *
+        page_size, ...] view (read as `rows` makes of the stored rows: as
+        they are), attended by the XLA composition under the causal (and
+        the window's) mask; a released or unheld page's entry is the
+        null page, whose positions the masks never let through."""
+        from hetu_tpu.models.generation import _attend_cached_chunk
+        S, C, nq, hd = q.shape
+        k, v = rows(p[table + base].reshape((S, -1) + p.shape[2:])
+                    for p in pools)
+        out = _attend_cached_chunk(
+            _widen(q, k.shape[-1]), k, v, positions, self.softmax_scale(hd),
+            window=window, sink=self.sink(params, window))
+        return out.reshape(S, C, nq * v.shape[-1])
 
     def attend_dense(self, params, q, caches, start, window=None, first=0):
         """q: C queries a row at positions start[b] + i (start a scalar
@@ -396,11 +515,12 @@ class KVAttention:
         if kernel:
             with jax.named_scope("pallas_chunk_attention"):
                 out = _ca.chunk_attention(q, *caches, start,
-                                          softmax_scale=hd ** -0.5,
+                                          softmax_scale=self.softmax_scale(hd),
                                           window=window, first=first,
                                           **extra)
         else:
-            out = _attend_cached_chunk(q, *caches, start, hd ** -0.5,
+            out = _attend_cached_chunk(q, *caches, start,
+                                       self.softmax_scale(hd),
                                        window=window, first=first, **extra)
         return out.reshape(b, C, nq * d_v)
 
@@ -422,8 +542,8 @@ class KVAttention:
                         "keys wider than the values: the XLA composition "
                         "(none of them in ops/pallas/flash_attention)")
             return _attend_cached_chunk(
-                _widen(q, d_k), *entries, 0, hd ** -0.5, window=window,
-                sink=sink).reshape(b, s, nq * d_v)
+                _widen(q, d_k), *entries, 0, self.softmax_scale(hd),
+                window=window, sink=sink).reshape(b, s, nq * d_v)
         attn = ops.flash_attention(
             q, *entries, causal=True,
             use_pallas=None if self.config.use_flash_attention else False)

@@ -66,7 +66,26 @@ it are the model's hooks:
       programs hand a chunk that starts at position 0 zeros for its
       state (`extend_cache`), slice the rows out of the carried state
       arrays and write them back in place; `_walk_layers` finds the
-      layer's kind in the contract and nothing else chooses.
+      layer's kind in the contract and nothing else chooses.  A hook may
+      return ONE value more: what the layer hands on (below).
+  block.attn.mix(p, hn[, handed=])                   -> out
+      THE WHOLE ATTENTION OF A LAYER THAT KEEPS AND READS NO CACHE (the
+      contract's `reads`: NO_CACHE), in place of the hooks above.
+  block.hands_on, block.takes_handed                 (OPTIONAL)
+      An activation carried across layers beside h (`_layer`'s `handed`):
+      a state block that says `hands_on` replaces it by the third value
+      its `state_chunk` / `state_step` returned; a block that says
+      `takes_handed` is given it as `handed=`.
+  A LAYER THAT READS ANOTHER LAYER'S ENTRIES (the contract's `reads[l]` =
+      k) has `project` return no entries, `()`: the programs write none
+      and hand its `attend_*` hooks layer k's cache arrays, or its pages
+      and page table.
+  serving_layers: A RUN MAY BE A PERIOD: block and parameters tuples, one
+      entry a layer of the period, each entry's parameters stacked
+      [count, ...] (`_walk_layers`).
+  model.read_rows_from                                (OPTIONAL)
+      The layer from whose attention on only the rows whose logits are
+      read need computing (`extend_cache(read_row=)`).
 
   STATS, zero_stats(), add_stats(a, b)
       `stats` is a small int32 vector a layer counts of itself (an
@@ -278,22 +297,55 @@ def _state_rows(arrays, at, row0, b: int):
     return rows, put
 
 
+def unpaged_layers(contract) -> str:
+    """The layers of a contract that do not keep pages of their own, by
+    kind of layer and by name: "" for a model all of whose layers do,
+    else "layers 0, 2 keep a state a sequence; layers 19, 21 read layer
+    17's entries; layers 18, 20 keep and read no cache"."""
+    from hetu_tpu.models.cache_contract import NO_CACHE
+    by = {}
+    for l in range(contract.num_layers):
+        r = contract.reads[l]
+        what = ("keep a state a sequence" if contract.state_shapes[l]
+                is not None else None if r is None else
+                "keep and read no cache" if r == NO_CACHE else
+                f"read layer {r}'s entries")
+        if what:
+            by.setdefault(what, []).append(str(l))
+    return "; ".join(f"layers {', '.join(ls)} {what}"
+                     for what, ls in by.items())
+
+
 def _refuse_state(model, what: str):
-    """The programs over a dense cache that `generate()` runs are not
-    built for a state kind of layer."""
-    if cache_contract(model).state_kinds:
+    """The programs over a dense cache that `generate()` runs are built
+    for layers that each keep pages of their own: not for a state kind
+    of layer, nor for a layer that keeps nothing and reads another's."""
+    unpaged = unpaged_layers(cache_contract(model))
+    if unpaged:
         raise NotImplementedError(
-            f"{type(model).__name__} has layers that keep a state a "
-            f"sequence (the cache contract's state_shapes); {what} is not "
-            "built for them: the chunk program (extend_cache) and the "
-            "paged decode step carry the state")
+            f"{type(model).__name__}: {unpaged} (the cache contract's "
+            f"state_shapes / reads); {what} is not built for them: the "
+            "chunk program (extend_cache) and the paged decode step carry "
+            "the state and hand a reading layer the pages it reads")
 
 
 # ---------------------------------------------------------------------------
 # One decoder layer, one walk over the layers
 # ---------------------------------------------------------------------------
 
-def _layer(block, lp, h, rope, pos_ids, cache_step, state_step=None):
+def _attn_scopes(block):
+    """The scopes a layer's attention runs under: `attn` and, inside it,
+    the one the block names for its kind of layer (`block.attn_scope`)."""
+    stack = contextlib.ExitStack()
+    stack.enter_context(jax.named_scope("attn"))
+    scope = getattr(block, "attn_scope", None)
+    if scope:
+        stack.enter_context(jax.named_scope(scope))
+    return stack
+
+
+def _layer(block, lp, h, rope, pos_ids, cache_step, state_step=None,
+           handed=None):
     """THE decoder layer of every program below: norm, projection,
     attention over the cache, output and residual, then the MLP and its
     residual, under the scopes a device trace is summed by
@@ -311,19 +363,32 @@ def _layer(block, lp, h, rope, pos_ids, cache_step, state_step=None):
 
     A STATE layer (the walk's caller saw its kind in the contract) is
     given `state_step(attn module, its params, hn) -> (the residual's
-    addend [b, s, hidden], *rest)` instead: the model's `state_chunk` or
-    `state_step` hook over the rows of the carried state, in place of
-    projection, cache and output.
-    Returns (h, the layer's stats, *rest)."""
+    addend [b, s, hidden], what the layer hands on or None, *rest)`
+    instead: the model's `state_chunk` or `state_step` hook over the rows
+    of the carried state, in place of projection, cache and output.
+
+    A layer that keeps and reads NO cache (the contract's `NO_CACHE`) is
+    given neither: its `mix` hook is its whole attention.
+
+    `handed` is what an earlier layer handed on (None where none has): a
+    block that says `takes_handed` is given it (`handed=` of `mix`), a
+    state block that says `hands_on` replaces it by what its hook returns
+    third, every other block passes it on untouched.
+    Returns (h, the layer's stats, handed, *rest)."""
     window = getattr(block, "window", None)
     win = {} if window is None else {"window": window}
-    scope = getattr(block, "attn_scope", None)
-    with jax.named_scope("attn"), \
-            (jax.named_scope(scope) if scope else contextlib.nullcontext()):
+    with _attn_scopes(block):
         hn = block.input_norm(lp["input_norm"], h)
         if state_step is not None:
-            out, *rest = state_step(block.attn, lp["attn"], hn)
+            out, new, *rest = state_step(block.attn, lp["attn"], hn)
+            if getattr(block, "hands_on", False):
+                handed = new
             h = h + out
+        elif cache_step is None:
+            rest = []
+            h = h + block.attn.mix(
+                lp["attn"], hn, **({"handed": handed} if getattr(
+                    block, "takes_handed", False) else {}))
         else:
             q, entries, *aux = block.attn.project(lp["attn"], hn, rope,
                                                   pos_ids)
@@ -333,10 +398,11 @@ def _layer(block, lp, h, rope, pos_ids, cache_step, state_step=None):
         y, st = block.mlp_stats(lp["mlp"],
                                 block.post_norm(lp["post_norm"], h))
         h = h + y
-    return (h, st, *rest)
+    return (h, st, handed, *rest)
 
 
-def _walk_layers(model, params, x, state, stats, layer):
+def _walk_layers(model, params, x, state, stats, layer, *, handed=None,
+                 layers=None):
     """THE walk over `model.serving_layers(params)`, in the cache's layer
     order.  A run of stacked parameters [n, ...] is scanned, a layer with
     arrays of its own is called.  The walk moves no bytes a layer does
@@ -352,8 +418,15 @@ def _walk_layers(model, params, x, state, stats, layer):
       program before PR 32), which is why a model may hand the serving
       programs a view of its own (`serving_params`).  A family whose
       layers differ gives each its own arrays (models/kimi_k2), at the
-      price of a program that grows with the depth.  The model's
-      parameters say which; nothing else does.
+      price of a program that grows with the depth, or, where they
+      differ with a PERIOD (a state layer, then a window layer, eight
+      times), hands a run whose block and parameters are TUPLES, one
+      entry a layer of the period, each entry's parameters stacked
+      [periods, ...]: the scan's body is then the period's layers one
+      after the other, each with its own kind and place in the
+      contract, and the program holds one body a distinct layer of the
+      period (models/phi4_flash).  The model's parameters say which;
+      nothing else does.
     * the cache.  `state`, arrays whose leading dim is the layer (a
       paged pool, a dense cache [L, b, M, ...]), is handed to every
       layer WHOLE with the layer's index, `at` = (l,), and handed on,
@@ -365,61 +438,128 @@ def _walk_layers(model, params, x, state, stats, layer):
       change one chunk: PR 32).  A caller that donates it gets its own
       buffers back.
 
-    layer(block, lp, h, state, at, page_at) -> (h, stats of the layer,
-    state, out).  `at` = (l,) is the layer among all layers; `page_at` =
-    (kind, (j,)) is the layer's kind by the cache contract (layers that
-    read as far back and store the same shapes are one kind, with cache
-    arrays, page arrays and a page table of their own) and its place j
-    among the layers of that kind (the leading dim of a dense cache's
-    and of a paged pool's arrays, and of a state kind's state arrays:
-    the state kinds are numbered behind the kinds that hold pages, and
-    `contract.is_state(kind)` is how a program's `layer` knows to give
-    `_layer` a `state_step`); with one kind j is l.  `out` is
+    layer(block, lp, h, state, at, page_at, handed) -> (h, stats of the
+    layer, state, out, handed).  `at` = (l,) is the layer among all
+    layers; `page_at` = (kind, (j,)) is the layer's kind by the cache
+    contract (layers that read as far back and store the same shapes are
+    one kind, with cache arrays, page arrays and a page table of their
+    own) and its place j among the layers of that kind (the leading dim
+    of a dense cache's and of a paged pool's arrays, and of a state
+    kind's state arrays: the state kinds are numbered behind the kinds
+    that hold pages, and `contract.is_state(kind)` is how a program's
+    `layer` knows to give `_layer` a `state_step`); with one kind j is l.
+    A layer that stores NOTHING and reads another layer's entries (the
+    contract's `reads`) is handed the kind and place of THAT layer, and
+    hands nothing out; one that reads nobody's is handed `page_at` None.
+    `handed` is the activation a layer hands on to later layers beside
+    h (`_layer`), a carry of a scanned run like h.  `out` is
     whatever a layer hands out besides (a token's entries for a paged
     pool to scatter; None): stacked over the layers of a kind, and with
     several kinds the kinds' tuples one after the other, as a dense
-    cache lays its arrays out.  Returns (x, stats, state, out).  The caller opens the `layer` scope
-    (a trace's name for the stack: obs.scope_map) around the walk and
-    what it does to the state before and after."""
+    cache lays its arrays out.  `layers` = (lo, hi) walks only the runs
+    that lie in layers lo .. hi - 1 (a run may not straddle an end).
+    Returns (x, stats, state, out, handed).  The caller opens the
+    `layer` scope (a trace's name for the stack: obs.scope_map) around
+    the walk and what it does to the state before and after."""
     l0 = 0
     contract = cache_contract(model)
+    lo, hi = layers or (0, contract.num_layers)
     outs = [[] for _ in contract.kinds]      # (of the kinds with pages)
-    # layers walked so far, by kind: the state kinds behind the others
-    seen = [0] * (len(outs) + len(contract.state_kinds))
 
     def add(stats, st):
         return stats if stats is None else model.add_stats(stats, st)
 
-    for block, lp, count in model.serving_layers(params):
-        kind = contract.kind_of(l0)
-        j0 = seen[kind]
-        if count is None:
-            x, st, state, out = layer(block, lp, x, state,
-                                      (jnp.int32(l0),),
-                                      (kind, (jnp.int32(j0),)))
-            stats = add(stats, st)
-            out = jax.tree.map(lambda a: a[None], out)
-        else:
-            def body(carry, xs, block=block, kind=kind, shift=j0 - l0):
-                h, state, stats = carry
-                lp, l = xs
-                h, st, state, out = layer(
-                    block, lp, h, state, (l,),
-                    (kind, (l + shift if shift else l,)))
-                return (h, state, add(stats, st)), out
+    def page_at(l: int):
+        kind = contract.kind_of(l)
+        return kind, (None if kind is None else contract.place_of(l))
 
-            (x, state, stats), out = lax.scan(
-                body, (x, state, stats),
-                (lp, jnp.arange(l0, l0 + count, dtype=jnp.int32)))
-        if kind < len(outs):        # (a state layer hands nothing out)
+    def note(l: int, out):
+        """A storing page layer's `out`, in the layer order of its
+        kind."""
+        kind = contract.kind_of(l)
+        if contract.stores(l) and not contract.is_state(kind):
             outs[kind].append(out)
-        l0 += count or 1
-        seen[kind] += count or 1
-    outs = [o[0] if len(o) == 1 else jax.tree.map(
+
+    for block, lp, count in model.serving_layers(params):
+        n = (count or 1) * (len(block) if isinstance(block, tuple) else 1)
+        first, l0 = l0, l0 + n
+        if first >= hi or l0 <= lo:
+            continue
+        if first < lo or l0 > hi:
+            raise ValueError(f"layers {lo}..{hi - 1} cut the run of layers "
+                             f"{first}..{l0 - 1}")
+        if count is None:
+            kind, j0 = page_at(first)
+            x, st, state, out, handed = layer(
+                block, lp, x, state, (jnp.int32(first),),
+                None if kind is None else (kind, (jnp.int32(j0),)), handed)
+            stats = add(stats, st)
+            note(first, jax.tree.map(lambda a: a[None], out))
+        elif isinstance(block, tuple):
+            # a period of layers, scanned over the periods: layer p of
+            # period i is layer first + i * P + p, of the kind of layer
+            # first + p, at a place that advances evenly with i
+            P = len(block)
+            where = []
+            for p in range(P):
+                at = [page_at(first + i * P + p) for i in range(count)]
+                kind, j0 = at[0]
+                step = at[1][1] - j0 if count > 1 and kind is not None else 0
+                if any(a != (kind, None if kind is None else j0 + i * step)
+                       for i, a in enumerate(at)):
+                    raise ValueError(
+                        f"layer {p} of the period at layer {first} changes "
+                        f"its kind or steps unevenly over the periods: {at}")
+                where.append((kind, j0, step))
+
+            def body(carry, xs, block=block, where=where, first=first, P=P):
+                h, state, stats, handed = carry
+                lps, i = xs
+                got = []
+                for p, (blk, lp, (kind, j0, step)) in enumerate(
+                        zip(block, lps, where)):
+                    h, st, state, out, handed = layer(
+                        blk, lp, h, state, (first + i * P + p,),
+                        None if kind is None else
+                        (kind, (j0 + i * step if step else jnp.int32(j0),)),
+                        handed)
+                    stats = add(stats, st)
+                    got.append(out)
+                return (h, state, stats, handed), tuple(got)
+
+            (x, state, stats, handed), got = lax.scan(
+                body, (x, state, stats, handed),
+                (tuple(lp), jnp.arange(count, dtype=jnp.int32)))
+            paged = [contract.kind_of(first + p) for p in range(P)
+                     if contract.stores(first + p)
+                     and not contract.is_state(contract.kind_of(first + p))]
+            if len(set(paged)) < len(paged) and any(
+                    g is not None for g in got):
+                raise NotImplementedError(
+                    "two storing layers of one kind in a period hand "
+                    "entries out: their order among the kind's is not kept")
+            for p in range(P):
+                note(first + p, got[p])
+        else:
+            kind, j0 = page_at(first)
+
+            def body(carry, xs, block=block, kind=kind, shift=j0 - first):
+                h, state, stats, handed = carry
+                lp, l = xs
+                h, st, state, out, handed = layer(
+                    block, lp, h, state, (l,),
+                    (kind, (l + shift if shift else l,)), handed)
+                return (h, state, add(stats, st), handed), out
+
+            (x, state, stats, handed), out = lax.scan(
+                body, (x, state, stats, handed),
+                (lp, jnp.arange(first, first + count, dtype=jnp.int32)))
+            note(first, out)
+    outs = [None if not o else o[0] if len(o) == 1 else jax.tree.map(
         lambda *a: jnp.concatenate(a), *o) for o in outs]
     out = outs[0] if len(outs) == 1 else (
         None if outs[0] is None else sum((tuple(o) for o in outs), ()))
-    return x, stats, state, out
+    return x, stats, state, out, handed
 
 
 # ---------------------------------------------------------------------------
@@ -438,15 +578,16 @@ def prefill(model, params, input_ids, max_len: int):
     rope = model.rope_tables(max_len)
     x = model.embed_tokens(params, input_ids, pos_ids)
 
-    def layer(block, lp, h, state, at, page_at):
-        h, st, entries = _layer(
+    def layer(block, lp, h, state, at, page_at, handed):
+        h, st, handed, entries = _layer(
             block, lp, h, rope, pos_ids,
             lambda attn, p, q, entries, win: (
                 attn.attend_prompt(p, q, entries, **win), entries))
-        return h, st, state, entries
+        return h, st, state, entries, handed
 
     with jax.named_scope("layer"):
-        x, _, _, entries = _walk_layers(model, params, x, None, None, layer)
+        x, _, _, entries, _ = _walk_layers(model, params, x, None, None,
+                                           layer)
     logits = model.logits(params, model.final_hidden(params, x))[:, -1, :]
     pad = ((0, 0), (0, 0), (0, max_len - plen))
     return logits, tuple(jnp.pad(e, pad + ((0, 0),) * (e.ndim - 3))
@@ -491,7 +632,7 @@ def decode_step_slots(model, params, tokens, cache, positions):
     n = len(cache_contract(model).token_shapes)
     x = model.embed_tokens(params, tokens[:, None], pos_ids)
 
-    def layer(block, lp, h, cache, at, page_at):
+    def layer(block, lp, h, cache, at, page_at, handed):
         kind, at = page_at
         mine, put = _of_kind(cache, kind, n)
 
@@ -501,11 +642,13 @@ def decode_step_slots(model, params, tokens, cache, positions):
             return (attn.attend_dense(p, q, tuple(c[at] for c in new),
                                       positions, **win),
                     put(new), tuple(e[:, 0] for e in entries))
-        return _layer(block, lp, h, rope, pos_ids, step)
+        h, st, handed, cache, toks = _layer(block, lp, h, rope, pos_ids,
+                                            step)
+        return h, st, cache, toks, handed
 
     with jax.named_scope("layer"):
-        x, _, cache, toks = _walk_layers(model, params, x, tuple(cache),
-                                         None, layer)
+        x, _, cache, toks, _ = _walk_layers(model, params, x, tuple(cache),
+                                            None, layer)
     logits = model.logits(params, model.final_hidden(params, x))[:, 0, :]
     return logits, cache, toks
 
@@ -522,7 +665,8 @@ def decode_step(model, params, token, cache, pos):
 
 def extend_cache(model, params, tokens, cache, start, stats=None, *,
                  collect_token_kv: bool = False, slide: bool = False,
-                 max_len: Optional[int] = None, state_row=0, valid=None):
+                 max_len: Optional[int] = None, state_row=0, valid=None,
+                 read_row=None):
     """Advance a dense cache by a whole token block (chunked prefill).
 
     tokens: [b, C] int32 at absolute positions start..start+C-1 (start
@@ -561,6 +705,22 @@ def extend_cache(model, params, tokens, cache, start, stats=None, *,
     from ZERO state, whatever the arrays held: a slot is reset by its
     next prompt's first chunk and by nothing else.
 
+    **Rows whose logits nobody reads** (``read_row``, a traced scalar:
+    the ONE row of every sequence's chunk whose logits the caller reads,
+    or negative: none).  A model all of whose layers from some layer k on
+    keep no cache of their own says so (`model.read_rows_from` = k: layer
+    k itself holds pages, every later layer reads another's or none):
+    those layers are then worth running only where logits are read.  The
+    walk runs layers 0 .. k - 1 for every row, layer k's norm, projection
+    and cache write for every row, and (under the scope `tail`, inside a
+    `lax.cond` on read_row >= 0: a chunk that does not end its prompt
+    runs none of it) layer k's attention, output and MLP and layers
+    k + 1 .. for that row alone, one query at position start + read_row
+    over the cache as the chunk left it.  Returns logits [b, 1, vocab]
+    (zeros where read_row < 0).  A model without `read_rows_from`, or a
+    call without `read_row`, runs every layer for every row: every
+    family that stands.
+
     ``collect_token_kv=True`` (the `verify_step_slots` path) also
     returns the chunk's entries per layer ((k, v) [L, b, C, n_kv, hd])
     so a paged cache can scatter them into its pool; given the running
@@ -583,51 +743,137 @@ def extend_cache(model, params, tokens, cache, start, stats=None, *,
     with jax.named_scope("embed"):
         x = model.embed_tokens(params, tokens, qpos)
 
-    def layer(block, lp, h, cache, at, page_at):
+    stop = None if read_row is None else getattr(model, "read_rows_from",
+                                                 None)
+
+    def layer_at(start, qpos, write: bool = True):
+        """`_walk_layers`'s layer for queries at `qpos` [b, s], the
+        first at `start` [b].  `write` False (the tail: the rows' entries
+        are in the cache): a layer attends and writes nothing."""
+        def layer(block, lp, h, cache, at, page_at, handed):
+            if page_at is None:     # keeps and reads no cache
+                h, st, handed = _layer(block, lp, h, rope, qpos, None,
+                                       handed=handed)
+                return h, st, cache, None, handed
+            kind, at = page_at
+            if contract.is_state(kind):
+                if not write:
+                    raise NotImplementedError(
+                        "a state layer behind `read_rows_from`: its state "
+                        "needs every row")
+                arrays, put_kind = _at(cache, contract.arrays_of(kind))
+
+                def state_step(attn, p, hn):
+                    mine, put = _state_rows(arrays, at, state_row, b)
+                    fresh = start == 0
+                    mine = tuple(
+                        jnp.where(fresh.reshape((b,) + (1,) * (a.ndim - 1)),
+                                  jnp.zeros((), a.dtype), a) for a in mine)
+                    out, new, *more = attn.state_chunk(p, hn, mine, start,
+                                                       valid)
+                    return (out, more[0] if more else None,
+                            put_kind(put(new)), None)
+                h, st, handed, cache, out = _layer(
+                    block, lp, h, rope, qpos, None, state_step, handed)
+                return h, st, cache, out, handed
+            mine, put = _of_kind(cache, kind, n)
+            sliding = slide and getattr(block, "window", None) is not None
+
+            def step(attn, p, q, entries, win):
+                # (a layer that reads another layer's entries projects
+                # none of its own: nothing to write)
+                where, first, new = qpos, {}, mine
+                if write and entries:
+                    with jax.named_scope("kv_write"):
+                        if sliding:
+                            keep = mine[0].shape[2] - C
+                            base = jnp.maximum(start[0] - keep, 0)
+                            shift = base - jnp.maximum(
+                                start[0] - C - keep, 0)
+                            new = tuple(
+                                lax.dynamic_update_slice(
+                                    c, lax.dynamic_slice_in_dim(
+                                        c[at], shift, keep, axis=1)[None],
+                                    at + (0,) * (c.ndim - 1)) if keep else c
+                                for c in mine)
+                            where, first = qpos - base, {"first": base}
+                        new = tuple(
+                            c.at[at + (rows[:, None], where)].set(
+                                e.astype(c.dtype))
+                            for c, e in zip(new, entries))
+                elif sliding:
+                    raise NotImplementedError(
+                        "a window layer that attends a sliding scratch "
+                        "without writing its chunk")
+                return (attn.attend_dense(p, q, tuple(c[at] for c in new),
+                                          start, **win, **first),
+                        put(new), entries if collect_token_kv and write
+                        and entries else None)
+            h, st, handed, cache, out = _layer(block, lp, h, rope, qpos,
+                                               step, handed=handed)
+            return h, st, cache, out, handed
+        return layer
+
+    def entries_only(block, lp, h, cache, at, page_at, handed):
+        """Layer `stop` for the rows nobody reads: the norm, the
+        projection and the cache write; h goes on as it came."""
         kind, at = page_at
-        if contract.is_state(kind):
-            arrays, put_kind = _at(cache, contract.arrays_of(kind))
-
-            def state_step(attn, p, hn):
-                mine, put = _state_rows(arrays, at, state_row, b)
-                fresh = start == 0
-                mine = tuple(
-                    jnp.where(fresh.reshape((b,) + (1,) * (a.ndim - 1)),
-                              jnp.zeros((), a.dtype), a) for a in mine)
-                out, new = attn.state_chunk(p, hn, mine, start, valid)
-                return out, put_kind(put(new)), None
-            return _layer(block, lp, h, rope, qpos, None, state_step)
         mine, put = _of_kind(cache, kind, n)
-        sliding = slide and getattr(block, "window", None) is not None
-
-        def step(attn, p, q, entries, win):
-            where, first = qpos, {}
+        with _attn_scopes(block):
+            entries = block.attn.project(
+                lp["attn"], block.input_norm(lp["input_norm"], h), rope,
+                qpos)[1]
             with jax.named_scope("kv_write"):
-                if sliding:
-                    keep = mine[0].shape[2] - C
-                    base = jnp.maximum(start[0] - keep, 0)
-                    shift = base - jnp.maximum(start[0] - C - keep, 0)
-                    kept = tuple(
-                        lax.dynamic_update_slice(
-                            c, lax.dynamic_slice_in_dim(
-                                c[at], shift, keep, axis=1)[None],
-                            at + (0,) * (c.ndim - 1)) if keep else c
-                        for c in mine)
-                    where, first = qpos - base, {"first": base}
-                else:
-                    kept = mine
                 new = tuple(
-                    c.at[at + (rows[:, None], where)].set(e.astype(c.dtype))
-                    for c, e in zip(kept, entries))
-            return (attn.attend_dense(p, q, tuple(c[at] for c in new),
-                                      start, **win, **first),
-                    put(new), entries if collect_token_kv else None)
-        return _layer(block, lp, h, rope, qpos, step)
+                    c.at[at + (rows[:, None], qpos)].set(e.astype(c.dtype))
+                    for c, e in zip(mine, entries))
+        return (h, None if stats is None else model.zero_stats(), put(new),
+                None, handed)
 
+    cache = tuple(cache)
     with jax.named_scope("layer"):
-        x, stats, cache, chunk = _walk_layers(
-            model, params, x, tuple(cache), stats, layer)
-    logits = model.logits(params, model.final_hidden(params, x))
+        if stop is None:
+            x, stats, cache, chunk, _ = _walk_layers(
+                model, params, x, cache, stats, layer_at(start, qpos))
+        else:
+            if ((slide and contract.windows[stop] is not None)
+                    or collect_token_kv
+                    or any(contract.stores(l) for l in range(
+                        stop + 1, contract.num_layers))):
+                raise NotImplementedError(
+                    f"read_rows_from {stop}: layer {stop} has to hold pages "
+                    "of every position and no later layer may store")
+            x, stats, cache, _, handed = _walk_layers(
+                model, params, x, cache, stats, layer_at(start, qpos),
+                layers=(0, stop))
+            x, stats, cache, _, handed = _walk_layers(
+                model, params, x, cache, stats, entries_only, handed=handed,
+                layers=(stop, stop + 1))
+            chunk = None
+
+            def tail(x, handed, stats):
+                r = jnp.maximum(jnp.asarray(read_row, jnp.int32), 0)
+                x, handed = jax.tree.map(
+                    lambda a: lax.dynamic_slice_in_dim(a, r, 1, axis=1),
+                    (x, handed))
+                at = start + r
+                with jax.named_scope("tail"):
+                    x, stats, _, _, _ = _walk_layers(
+                        model, params, x, cache, stats,
+                        layer_at(at, at[:, None], write=False),
+                        handed=handed,
+                        layers=(stop, contract.num_layers))
+                    return (model.logits(params,
+                                         model.final_hidden(params, x)),
+                            stats)
+            skipped = jax.tree.map(
+                lambda a: jnp.zeros(a.shape, a.dtype),
+                jax.eval_shape(tail, x, handed, stats))
+            logits, stats = lax.cond(
+                jnp.asarray(read_row) >= 0, tail,
+                lambda *_: skipped, x, handed, stats)
+    if stop is None:
+        logits = model.logits(params, model.final_hidden(params, x))
     return ((logits, cache) + ((chunk,) if collect_token_kv else ())
             + (() if stats is None else (stats,)))
 
@@ -745,10 +991,11 @@ def _paged_forward(model, params, tokens, pool_tree, table, positions,
     pool_tree, states = (pool_tree[:len(pool_tree) - n_state],
                          pool_tree[len(pool_tree) - n_state:])
     pools, scales = pool_tree[:n * K], pool_tree[n * K:]
-    if n_state and (C > 1 or scales):
+    if (n_state or contract.borrows) and (C > 1 or scales):
         raise NotImplementedError(
             "a block of tokens a slot (the verify step) and quantized "
-            "pages are not built for a model with state layers")
+            "pages are not built for a model with state layers or layers "
+            f"that keep no cache ({unpaged_layers(contract)})")
     # a slot is live where it holds a page
     live = jnp.any(tables[0] != 0, axis=-1) if n_state else None
     if len(tables) != K or (scales and K > 1):
@@ -766,23 +1013,33 @@ def _paged_forward(model, params, tokens, pool_tree, table, positions,
             params, tokens[:, None] if tokens.ndim == 1 else tokens, pos_ids)
     rope = model.rope_tables(table.shape[-1] * ps)
 
-    def layer(block, lp, h, state, at, page_at):
+    def layer(block, lp, h, state, at, page_at, handed):
         flats, scales, states = state
+        if page_at is None:         # keeps and reads no cache
+            h, st, handed = _layer(block, lp, h, rope, pos_ids, None,
+                                   handed=handed)
+            return h, st, state, None, handed
         kind, (l,) = page_at
         if contract.is_state(kind):
             arrays, put_kind = _at(states, contract.state_arrays_of(kind))
 
             def state_step(attn, p, hn):
                 mine, put = _state_rows(arrays, (l,), 0, hn.shape[0])
-                out, new = attn.state_step(p, hn, mine, live)
-                return out, (flats, scales, put_kind(put(new)))
-            h, st, state = _layer(block, lp, h, rope, pos_ids, None,
-                                  state_step)
-            return h, st, state, None
+                out, new, *more = attn.state_step(p, hn, mine, live)
+                return (out, more[0] if more else None,
+                        (flats, scales, put_kind(put(new))))
+            h, st, handed, state = _layer(block, lp, h, rope, pos_ids, None,
+                                          state_step, handed)
+            return h, st, state, None, handed
         flat, tbl = flats[kind], tables[kind]
         base = l * pages[kind]
 
         def step(attn, p, q, entries, win):
+            if not entries:
+                # a layer that reads another layer's entries: that
+                # layer's pages, through that layer's table, as they lie
+                return (attn.attend_paged(p, q, flat, tbl, positions, base,
+                                          **win), state)
             with jax.named_scope("kv_write"):
                 new = [_paged_write(pool, sc, tbl, positions, e, l, base,
                                     bits)
@@ -796,14 +1053,15 @@ def _paged_forward(model, params, tokens, pool_tree, table, positions,
                                       **quantized, **win),
                     (flats[:kind] + (flat_,) + flats[kind + 1:], scales_,
                      states))
-        h, st, state = _layer(block, lp, h, rope, pos_ids, step)
-        return h, st, state, None
+        h, st, handed, state = _layer(block, lp, h, rope, pos_ids, step,
+                                      handed=handed)
+        return h, st, state, None, handed
 
     with jax.named_scope("layer"):
         flats = tuple(
             tuple(p.reshape((p.shape[0] * p.shape[1],) + p.shape[2:])
                   for p in pools[k * n:(k + 1) * n]) for k in range(K))
-        x, stats, (flats, scales, states), _ = _walk_layers(
+        x, stats, (flats, scales, states), _, _ = _walk_layers(
             model, params, x, (flats, scales, states), stats, layer)
         pools = tuple(f.reshape(p.shape)
                       for f, p in zip(sum(flats, ()), pools))

@@ -166,13 +166,25 @@ def group_of(op_name: str, phases: Tuple[str, ...] = PHASES) -> str:
 #: (`attn` > `kda` > `kda_proj`, `kda_conv`, `kda_scan` in the chunk
 #: program / `kda_step` in the decode program, `kda_out`:
 #: models/bailing_hybrid; `kda` alone keeps the input norm and the
-#: state's rows written back in place); no program without these scopes
-#: changes its groups.
+#: state's rows written back in place); the attention of a layer that
+#: keeps no cache and reads another layer's pages (`attn_cross`), a
+#: Mamba-1 layer and its parts (`attn` > `ssm` > `ssm_proj`, `ssm_conv`,
+#: `ssm_scan` in the chunk program / `ssm_step` in the decode program,
+#: `ssm_out`), a gated memory unit (`gmu`: models/phi4_flash), and what
+#: the chunk program runs for the rows whose logits are read alone and
+#: that no inner scope names (`tail`: models/generation.extend_cache; a
+#: layer's own scopes inside the tail keep their groups).  `diff_out`,
+#: what follows the attention kernel in a differential-attention layer,
+#: is a scope of the operations' paths and NOT a group: its time stays
+#: with its kind of layer.  No program without these scopes changes its
+#: groups.
 SCOPE_MAP_GROUPS = ("kv_write", "loss", "mla_q", "mla_kv", "mla_out",
                     "router", "experts", "shared_expert",
                     "attn_window", "attn_full",
                     "kda", "kda_proj", "kda_conv", "kda_scan", "kda_step",
-                    "kda_out")
+                    "kda_out",
+                    "attn_cross", "ssm", "ssm_proj", "ssm_conv", "ssm_scan",
+                    "ssm_step", "ssm_out", "gmu", "tail")
 UNSCOPED = "unscoped"
 _INSTR_PAT = re.compile(r'^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=')
 _OPERAND_PAT = re.compile(r'%([\w.\-]+)')

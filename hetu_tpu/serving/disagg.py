@@ -124,6 +124,12 @@ class PrefillWorker:
                 "sequence; the disaggregated prefill tier "
                 "(serving/disagg.py) ships K/V scratch only and is not "
                 "built for them")
+        if contract.borrows:
+            from hetu_tpu.models.generation import unpaged_layers
+            raise NotImplementedError(
+                f"{type(model).__name__}: {unpaged_layers(contract)}; the "
+                "disaggregated prefill tier (serving/disagg.py) ships a "
+                "scratch a layer and is not built for them")
         if kind != "kv":
             raise NotImplementedError(
                 f"{type(model).__name__} keeps a {kind!r} cache; the "

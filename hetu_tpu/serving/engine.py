@@ -156,6 +156,13 @@ class ServeConfig:
     prefill_chunk: int = 32
     num_pages: int = 0
     kv_quant: str = "none"      # "none" (exact, default) | "int8" | "int4"
+    #: at most so many slots are in prefill at once, each holding a
+    #: prefill scratch of its own (a dense cache of max_len positions in
+    #: every layer that reads everything: 126 MB a request where one
+    #: layer of 10 x 128 K and V rows reads 24,576 positions); a request
+    #: beyond that waits in the queue (stall reason "prefill_scratch").
+    #: 0 (default): as many as there are slots
+    max_prefilling: int = 0
     # MoE serving (HETU_TPU_MOE_DISPATCH, serving/experts.py): int8/int4
     # store the stacked [E, ...] expert weights resident-quantized
     # (KV-pool-style blockwise payloads + f32 scales, dequantized inside
@@ -364,7 +371,25 @@ class ServingEngine:
         #: contract's `state_shapes`): the pool holds it by slot, and the
         #: chunk and decode programs carry it
         self.stateful = bool(self.cache.state_kinds)
-        if self.cache.kind != "kv" or self.windowed or self.stateful:
+        #: some layer keeps NO cache of its own (the contract's `reads`):
+        #: it attends the pages of the layer it names through that
+        #: layer's table, or attends nothing; the pool holds nothing for it
+        self.borrows = self.cache.borrows
+        #: the layer from whose attention on only the rows whose logits
+        #: are read are computed (`extend_cache`'s `read_row`), where the
+        #: model's later layers keep no cache; None: every row, every layer
+        self._tail_from = getattr(model, "read_rows_from", None)
+        #: the kinds of page layer that attend for that row alone, and,
+        #: a reading layer each, how far back the layer it reads does
+        self._tail_kinds = frozenset(
+            k for k in range(len(self.cache.kinds))
+            if self._tail_from is not None
+            and min(self.cache.layers_of(k)) >= self._tail_from)
+        self._read_windows = tuple(
+            self.cache.windows[r] for r in self.cache.reads
+            if r is not None and r >= 0)
+        if (self.cache.kind != "kv" or self.windowed or self.stateful
+                or self.borrows):
             self._refuse_unbuilt(reshard, draft_model, drafter)
         self.pool = PagePool.for_contract(
             self.cache, num_pages=self.config.num_pages,
@@ -641,13 +666,16 @@ class ServingEngine:
             unbuilt["resident quantized experts (moe_dispatch int8 / int4, "
                     "serving/experts.py)"] = \
                 cfg.moe_dispatch in ("int8", "int4")
-        if self.stateful:
+        if self.stateful or self.borrows:
             # no page holds a state layer's past: a shared prefix has no
-            # state to start from, a draft block none to fall back to
+            # state to start from, a draft block none to fall back to;
+            # a layer that reads another's pages has no table of its own
+            # to share, verify or move
             unbuilt["moving the pool with the parameters (kv_repage)"] = \
                 cfg.kv_repage
         asked = [what for what, on in unbuilt.items() if on]
         if asked:
+            from hetu_tpu.models.generation import unpaged_layers
             keeps = "; ".join(
                 [f"{len(self.cache.layers_of(k))} layers keep "
                  + ("every position" if w is None
@@ -655,7 +683,9 @@ class ServingEngine:
                  for k, w in enumerate(self.cache.kinds)]
                 + [f"{len(self.cache.layers_of(len(self.cache.kinds) + i))} "
                    f"layers keep a state a sequence {shapes}"
-                   for i, shapes in enumerate(self.cache.state_kinds)])
+                   for i, shapes in enumerate(self.cache.state_kinds)]
+                + ([unpaged_layers(self.cache)]
+                   if self.stateful or self.borrows else []))
             raise NotImplementedError(
                 f"{name} stores {self.cache.token_shapes} a token a layer "
                 f"({self.cache.kind!r}: {keeps}); not built for it: "
@@ -682,10 +712,13 @@ class ServingEngine:
         causally-masked query positions per slot per launch.  Evaluated
         once at build: the decision is static, like every other program
         shape."""
-        if self.cache.kind != "kv":
-            # no gather route for such a pool: one decode program over
-            # the page table, and which attention it calls there is the
-            # model's route (`attend_paged`)
+        if self.cache.kind != "kv" or self.stateful or self.borrows:
+            # no gather route for such a pool, for state beside it or for
+            # a layer that reads another layer's pages: one decode
+            # program over the page table, and which attention it calls
+            # there is the model's route (`attend_paged`; a K/V layer's
+            # falls back to the composition over gathered pages where
+            # the kernel's gate refuses: KVAttention.paged_composition)
             return True
         from hetu_tpu.ops.pallas import paged_attention as _pa
         from hetu_tpu.ops.pallas import resolve_route
@@ -789,15 +822,22 @@ class ServingEngine:
         # many of the chunk's rows are the prompt's
         state_args = ("state_row", "valid") if self.stateful else ()
 
+        # a model whose later layers keep no cache runs them for the ONE
+        # row a finished prompt samples from (`row`; negative: the chunk
+        # does not end its prompt and runs none of them), and hands back
+        # that row's logits alone, [1, 1, vocab]
+        tail = self._tail_from is not None
+
         def chunk_fn(params, chunk, cache, start, row, *rest):
             state = dict(zip(state_args, rest))
             logits, cache, *stats = extend_cache(
                 model, params, chunk, cache, start, *rest[len(state):],
-                **slide, **state)
+                **slide, **state, **({"read_row": row} if tail else {}))
             # the greedy first token of a prompt that ends on `row` of
             # this chunk: taken here, fetched at the step's end
             with jax.named_scope("lm_head"):
-                first = jnp.argmax(logits[0, row], axis=-1).astype(jnp.int32)
+                first = jnp.argmax(logits[0, 0 if tail else row],
+                                   axis=-1).astype(jnp.int32)
             return (logits, first, cache, *stats)
 
         by_kind = self.windowed
@@ -1192,12 +1232,14 @@ class ServingEngine:
         byte-identical to the single-engine run.  False = no slot/
         reservation/quota headroom right now; the caller retries next
         step (the shipment stays pending, the dedupe seq unburned)."""
-        if self.windowed or self.stateful:
+        if self.windowed or self.stateful or self.borrows:
+            from hetu_tpu.models.generation import unpaged_layers
             raise NotImplementedError(
                 f"{type(self.model).__name__} has layers that read a window "
-                "only or keep a state a sequence; the disaggregated prefill "
-                "tier (adopt_prefilled, serving/disagg.py) is not built for "
-                "them")
+                "only, keep a state a sequence or keep no cache of their "
+                f"own ({unpaged_layers(self.cache) or 'window layers'}); the "
+                "disaggregated prefill tier (adopt_prefilled, "
+                "serving/disagg.py) is not built for them")
         # the shipment's pages and first token are written from the host:
         # with nothing queued, and every finish seen (a slot may be free)
         self._drain("adopt", lambda: now)
@@ -1475,6 +1517,13 @@ class ServingEngine:
             self._expire_deadlines(t_dead, finished)
         while True:
             t_adm = clock()
+            cap = self.config.max_prefilling
+            if cap and self.scheduler.queue and sum(
+                    st is not None and st.prefilling
+                    for st in self.scheduler.slots) >= cap:
+                # every scratch the configuration allows is in use
+                self.scheduler.last_stall = "prefill_scratch"
+                break
             adm = self.scheduler.admit_next(t_adm)
             if adm is None:
                 # SLO-class preemption (HETU_TPU_SERVE_PREEMPT):
@@ -1551,10 +1600,19 @@ class ServingEngine:
                                  else slots[i].generated[-1])
                 if self.stateful:
                     # what the state layers read and write for the rows
-                    # that decode: a slot's whole state, once each
+                    # that decode: a slot's whole state, once each (under
+                    # the name the model gives: `state_counter`)
                     self._registry.inc(
-                        "serve.kda_state_bytes",
+                        getattr(self.model, "state_counter",
+                                "serve.kda_state_bytes"),
                         2 * len(batch) * self._state_bytes_per_slot)
+                if self.borrows:
+                    # positions read from a page set by layers that keep
+                    # none of their own: each reader reads what the layer
+                    # it names holds for the slot
+                    self._registry.inc(
+                        "serve.shared_kv_positions",
+                        self._borrowed_positions(positions[batch] + 1))
                 decode_args = (
                     self.params, self.pool.tree(),
                     self._decode_table(batch),
@@ -1665,6 +1723,16 @@ class ServingEngine:
             "serve.decode_window_context_tokens",
             int(np.minimum(positions[active] + 1, w).sum()))
 
+    def _borrowed_positions(self, contexts) -> int:
+        """Positions the reading layers of one decode pass attend in
+        pages that are not theirs, for slots at `contexts` (cached
+        tokens, the one written included): a reader of a layer that
+        reads everything reads the context, of a window layer
+        min(context, window)."""
+        return sum(int((contexts if w is None
+                        else np.minimum(contexts, w)).sum())
+                   for w in self._read_windows)
+
     def _scratch_rows(self, scratch):
         """A request's prefill scratch as the page-write program takes
         it: by kind of layer whole (the program takes the one row), else
@@ -1680,14 +1748,19 @@ class ServingEngine:
         return [0 if w is None or not self._slide else max(0, start - (M - C))
                 for w, M in zip(self.cache.kinds, self._scratch_positions)]
 
-    def _count_attended_keys(self, start: int, C: int):
+    def _count_attended_keys(self, start: int, C: int, row: int = 0):
         """`serve.prefill_attended_keys{kind}`: the (query, key) pairs
         ONE layer of the kind attends in a chunk launch of C rows at
         `start`: C * start + C (C + 1) / 2 where the kind reads
         everything, sum_i min(start + i + 1, window) under a window (a
-        chunk's padding rows count: the program computes them)."""
-        for kind, w in zip(self._kind_names, self.cache.kinds):
-            if w is None:
+        chunk's padding rows count: the program computes them).  A kind
+        whose layers attend for the read row alone (`read_rows_from`):
+        that row's start + row + 1 keys, none where `row` is negative."""
+        for k, (kind, w) in enumerate(zip(self._kind_names,
+                                          self.cache.kinds)):
+            if k in self._tail_kinds:
+                pairs = start + row + 1 if row >= 0 else 0
+            elif w is None:
                 pairs = C * start + C * (C + 1) // 2
             else:
                 # the first `a` rows see start + i + 1 keys, the rest w
@@ -2125,7 +2198,10 @@ class ServingEngine:
             # the last VALID prompt position of the final chunk (padding
             # tail positions carry garbage): the row the program takes
             # the first token at
-            row = plen - 1 - s if last else 0
+            # (a model that runs its cache-less layers for that row alone
+            # is told by a negative row that this chunk has none)
+            tail = self._tail_from is not None
+            row = plen - 1 - s if last else -1 if tail else 0
             ids = np.zeros(C, np.int32)
             seg = req.prompt[s: min(s + C, plen)]
             ids[: len(seg)] = seg
@@ -2147,12 +2223,15 @@ class ServingEngine:
                 self._registry.inc("serve.chunk_padded_rows", C - len(seg))
             if stats:
                 (self._stats_acc,) = stats
+            if tail:
+                # rows the layers from `read_rows_from` on were run for
+                self._registry.inc("serve.prefill_tail_rows", int(last))
             st.chunks_done += 1
             st.stats.prefill_chunks += 1
             self._registry.inc("serve.prefill_chunks")
             self._registry.inc("serve.prefill_tokens", len(seg))
             if self.windowed:
-                self._count_attended_keys(s, C)
+                self._count_attended_keys(s, C, row)
             if not last:
                 if self.tracer is not None:
                     self.tracer.on_chunk(req, clock(), st.chunks_done)
@@ -2181,7 +2260,8 @@ class ServingEngine:
             # from them (on the host), not the chunk program's argmax
             drawn = self.config.sampling and req.sampling.temperature > 0
             ends.append(_PromptEnd(slot_idx, st, first,
-                                   logits[0, row] if drawn else None))
+                                   logits[0, 0 if tail else row]
+                                   if drawn else None))
 
     def _land_first_tokens(self, ends, clock, finished, phases):
         """The step's second wait for the device, after its dispatches:

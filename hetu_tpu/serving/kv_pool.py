@@ -305,9 +305,11 @@ class PagePool:
             (stored,) = contract.stored_shapes
             what = dict(token_shape=tuple(stored))
         if K > 1 or contract.kinds[0] is not None:
-            # (a kind's layers numbered among the layers that hold pages)
+            # (a kind's layers numbered among the layers that hold pages:
+            # not a state layer, not a layer that keeps no cache)
             paged = [l for l in range(contract.num_layers)
-                     if contract.state_shapes[l] is None]
+                     if contract.state_shapes[l] is None
+                     and contract.stores(l)]
             what.update(windows=contract.kinds, layers=tuple(
                 tuple(paged.index(l) for l in contract.layers_of(k))
                 for k in range(K)))

@@ -465,6 +465,21 @@ def _ling_block():
             num_pages=4112)
 
 
+def _phi4flash_block():
+    """Phi-4-mini-flash at published widths, 8 of 32 layers (one (Mamba,
+    window) pair, the memory layer, the full layer, two (GMU, cross)
+    pairs) and a cut vocabulary, at the serving cell's slots, page,
+    chunk and max_len: Mamba state by slot beside window and full pages,
+    K and V stored as 2 rows of 640 lanes."""
+    from hetu_tpu.models.phi4_flash import (Phi4FlashConfig,
+                                            Phi4FlashLMHeadModel)
+    return Phi4FlashLMHeadModel(Phi4FlashConfig(
+        vocab_size=20480, num_hidden_layers=8, param_dtype=BF16,
+        compute_dtype=BF16)), dict(
+            num_slots=32, page_size=128, max_len=24576, prefill_chunk=512,
+            num_pages=(6160, 176), max_prefilling=4)
+
+
 #: family -> ((model, ServeConfig overrides) or None for the Llama-2-7B
 #: block, the kernels its decode program must hold as tpu_custom_calls)
 SERVING_FAMILIES = {
@@ -474,6 +489,7 @@ SERVING_FAMILIES = {
     "trinity": (_trinity_block, ("paged_attn",)),
     "mimo": (_mimo_block, ("paged_attn",)),
     "ling": (_ling_block, ("paged_latent",)),
+    "phi4flash": (_phi4flash_block, ("paged_attn",)),
 }
 
 
@@ -537,6 +553,34 @@ def test_serving_programs_compile_for_one_v5e(family):
         assert len(scans) == 2 and all("kda_scan/pallas_kda_scan" in ln
                                        for ln in scans)
         assert not any("pallas_kda_scan" in ln for ln in calls)
+    elif family == "phi4flash":
+        # a decode pass: the paged kernel once a distinct attention layer
+        # of the program (window, full, cross), over stored rows of 640
+        # lanes: 10 rows of 128 are refused by its page copies (Mosaic:
+        # "must be aligned to tiling (8)": the first compile of PR 43).
+        # The chunk program: the blockwise kernel in the window layers
+        # (every row), the composition for the ONE row that reads the
+        # full layer's cache in the tail; both carry the Mamba state in
+        # place and hold one body a layer of a period, not one a layer
+        assert len(calls) == 3 and sum(
+            "pallas_paged_attention_window" in ln for ln in calls) == 1
+        assert routes["paged_attn"]["pallas"] and not routes[
+            "paged_attn"]["xla"]
+        rec = routes["chunk_attn"]
+        assert 2 * rec["pallas"] == chunk_calls == 2 and rec["xla"]
+        assert engine.pool.arrays.k.shape == (1, 6161, 128, 2, 640)
+        nbytes = lambda tree: sum(  # noqa: E731
+            a.size * a.dtype.itemsize for a in tree)
+        state, pool = nbytes(engine.pool.state), nbytes(
+            engine.pool.arrays.tree())
+        assert state == 33 * 3 * (327_680 + 30_720)
+        mem = {name: c.memory_analysis() for name, c in compiled.items()}
+        assert mem["decode"].alias_size_in_bytes >= pool + state
+        assert mem["decode"].temp_size_in_bytes < 64e6
+        assert mem["prefill_chunk"].temp_size_in_bytes < 0.3e9
+        text = compiled["prefill_chunk"].as_text()
+        assert "ssm_scan" in text and "tail" in text
+        assert "ssm_step" in compiled["decode"].as_text()
     elif family == "kimi":
         assert "chunk_attn" not in routes and not chunk_calls
     elif family == "trinity":

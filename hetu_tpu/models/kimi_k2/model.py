@@ -90,14 +90,52 @@ class MLAttention(Module):
         return (q[..., :dn], q_rope), (latent,)
 
     # -- how a query attends the cache --------------------------------------
-    def attend_dense(self, params, q, caches, start, block: int = 512):
+    def attend_dense(self, params, q, caches, start):
         """EXPANDED form.  q of a C-token block at positions
         start[b] + i; caches = (latents [b, M, stored],) holding every
-        position <= start + C - 1.  Keys are walked in blocks of `block`
-        cached positions up to the last one any query sees (a loop with
-        a data-dependent trip count, online softmax), each block's
-        k_nope and v made from its latents by W_kvb.  Returns
-        [b, C, nh * dv]."""
+        position <= start + C - 1.  Returns [b, C, nh * dv].
+
+        Which shapes take which attention (the route record
+        `kernel_routes["latent_chunk_attn"]` says it per traced layer):
+        ONE row's chunk of C > 1 queries at one start, the chunk program
+        of chunked prefill, takes the blockwise kernel
+        (ops/pallas/latent_chunk_attention: a head's k_nope | v made
+        from a key block's latents inside the kernel, the scores on the
+        chip, only the key blocks the chunk can see read) where
+        `ops.pallas.resolve_route` and the kernel's gate allow.
+        Everything else keeps the XLA composition `_attend_composed`:
+        any shape the gate refuses, every backend but a TPU, a single
+        query (C = 1: the decode step over a dense cache) and rows at
+        depths of their own (start [b > 1]: the verify step), which no
+        flag forces.  Both are the same arithmetic in the same
+        precisions; the online softmax of the kernel is
+        `ops/pallas/chunk_attention`'s, of the composition its own loop."""
+        from hetu_tpu.ops.pallas import latent_chunk_attention as _lca
+        from hetu_tpu.ops.pallas import _note_route, resolve_route
+        q_nope, q_rope = q
+        (lat,) = caches
+        if q_nope.shape[0] == 1 and q_nope.shape[1] > 1:
+            kernel = resolve_route(
+                "latent_chunk_attn", _lca.check_route, q_nope.shape,
+                q_rope.shape, lat.shape, params["wkv_b"].shape,
+                jnp.shape(start), dtype=lat.dtype)
+        else:
+            kernel = False
+            _note_route("latent_chunk_attn", False,
+                        "a single query, or rows at depths of their own: "
+                        "the composition")
+        if not kernel:
+            return self._attend_composed(params, q, caches, start)
+        with jax.named_scope("pallas_latent_chunk_attention"):
+            return _lca.latent_chunk_attention(
+                q_nope, q_rope, lat, params["wkv_b"], start,
+                softmax_scale=self.config.softmax_scale)
+
+    def _attend_composed(self, params, q, caches, start, block: int = 512):
+        """`attend_dense` as an XLA composition: keys are walked in
+        blocks of `block` cached positions up to the last one any query
+        sees (a loop with a data-dependent trip count, online softmax),
+        each block's k_nope and v made from its latents by W_kvb."""
         c = self.config
         q_nope, q_rope = q
         (lat,) = caches
@@ -187,11 +225,13 @@ class MLAttention(Module):
             return attn @ params["wo"].astype(attn.dtype)
 
     def attend_prompt(self, params, q, entries):
-        """Whole prompts attending their own entries, causally."""
+        """Whole prompts attending their own entries, causally: the
+        composition (`prefill` and `forward`: on no cell's path, and what
+        the tests compare the chunk program with)."""
         b, s = entries[0].shape[:2]
-        return self.attend_dense(params, q, entries,
-                                 jnp.zeros((b,), jnp.int32),
-                                 block=math.gcd(s, 512))
+        return self._attend_composed(params, q, entries,
+                                     jnp.zeros((b,), jnp.int32),
+                                     block=math.gcd(s, 512))
 
     def forward(self, params, hn, rope, pos_ids):
         """Whole sequences hn [b, s, h] at positions 0..s-1."""

@@ -22,6 +22,10 @@ The fused-kernel layer (docs/kernels.md):
   * chunk_attention  — the chunk program's attention over a dense K/V
                        cache (chunked prefill): blockwise, online
                        softmax, only the key blocks a chunk can see
+  * latent_chunk_attention — the same over a dense LATENT cache (MLA):
+                       a head's k_nope | v made from a key block's
+                       latents inside the kernel; shares chunk_
+                       attention's online softmax
   * kda_scan         — the chunkwise gated delta rule of a linear-
                        attention layer (ops/delta_rule.chunk_scan): a
                        head's blocks walked inside one launch, its state
@@ -58,7 +62,7 @@ from typing import FrozenSet, Optional, Tuple
 #: every routable kernel name (the HETU_TPU_PALLAS_KERNELS vocabulary)
 KERNEL_NAMES = ("flash", "norm", "swiglu", "rotary", "quant", "paged_attn",
                 "paged_verify", "sample", "adam", "paged_latent",
-                "chunk_attn", "kda_scan")
+                "chunk_attn", "kda_scan", "latent_chunk_attn")
 
 
 #: kernels `auto` leaves to XLA, and why: each was timed on the chip against

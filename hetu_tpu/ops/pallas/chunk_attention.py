@@ -37,6 +37,13 @@ program (PERF.md s6, PR 35).  Here:
   `_ROW_TILE` rows) meets each key block `[kb, hd]` of its KV head in
   one MXU product.
 
+**This module owns the online softmax** (`softmax_init`,
+`softmax_step`, `softmax_finish`: the statistics of a row tile over its
+key blocks).  `latent_chunk_attention.py`, the same walk over a LATENT
+cache (MLA: the keys and values of a block are made from its latents
+inside that kernel), imports the three, `live_blocks` and the score
+gate `require_score_bytes`; there is no second copy.
+
 The grid is (KV heads, row tiles, key blocks), the key blocks innermost
 and in order.  K and V come head-major, `[n_kv, M, hd]`, and a layer's
 slab is `[M, n_kv, hd]`: Mosaic's DMA slices no single head out of a
@@ -172,14 +179,21 @@ def check_route(q_shape, k_shape, start_shape=(), *, window=None,
     PR 35.  Forced flags ask neither."""
     out = check_shapes(q_shape, k_shape, start_shape, window=window,
                        dtype=dtype, v_shape=v_shape, sink=sink)
-    score_bytes = 4 * q_shape[1] * q_shape[2] * k_shape[1]
+    require_score_bytes(q_shape[2], q_shape[1], k_shape[1])
+    return out
+
+
+def require_score_bytes(heads: int, C: int, M: int):
+    """ValueError where the composition's float32 scores (heads x C
+    queries x M cache positions) are under `_MIN_SCORE_BYTES`: the part
+    of a route's gate this kernel and `latent_chunk_attention` share."""
+    score_bytes = 4 * C * heads * M
     if score_bytes < _MIN_SCORE_BYTES:
         raise ValueError(
-            f"{q_shape[2]} heads x {q_shape[1]} queries x {k_shape[1]} "
+            f"{heads} heads x {C} queries x {M} "
             f"positions are {score_bytes >> 20} MB of float32 scores, under "
             f"the {_MIN_SCORE_BYTES >> 20} MB from which the kernel pays: "
             f"the composition keeps them")
-    return out
 
 
 def compatible(q_shape, k_shape, start_shape=(), *, window=None,
@@ -204,6 +218,50 @@ def live_blocks(start, C: int, M: int, kb: int, window=None, first=0):
     return lo, hi - lo + 1
 
 
+# -- the online softmax of a row tile over its key blocks -------------------
+# The one copy: this module's kernel and `latent_chunk_attention`'s walk
+# their key blocks with these three, the float32 running maximum `m_scr`
+# [rows, 1], sum `l_scr` [rows, 1] and accumulator `acc_scr` [rows, dv]
+# in VMEM scratch from a tile's first key block to its last.
+
+def softmax_init(m_scr, l_scr, acc_scr, sink_ref=None):
+    """Before a tile's first key block.  `sink_ref` [rows, 1]: each
+    row's sink, a key seen before any other that has no value."""
+    if sink_ref is None:
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+    else:
+        # the sink as a key seen before any other: exp(0) = 1
+        m_scr[...] = sink_ref[...].astype(jnp.float32)
+        l_scr[...] = jnp.ones_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
+def softmax_step(s, v, m_scr, l_scr, acc_scr):
+    """One key block: s [rows, kb] its float32 scores, scaled and masked
+    with NEG_INF; v [kb, dv] its values, which the probabilities are
+    cast to the dtype of for p.v (float32 accumulation)."""
+    m_prev = m_scr[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    # a row none of whose keys it has seen yet holds m = NEG_INF and
+    # p = 1 for them: the first seen key's correction (exp(NEG_INF -
+    # m) = 0) wipes that, and every row sees its own position
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_scr[...] = m_new
+
+
+def softmax_finish(o_ref, m_scr, l_scr, acc_scr):
+    """After a tile's last key block: the one division."""
+    l = l_scr[...]
+    o_ref[...] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)) \
+        .astype(o_ref.dtype)
+
+
 def _kernel(s_ref, q_ref, *refs, scale, window, C, tr, kb, sink=False):
     # `sink`: one more operand [tr, 1] after q, each row's sink
     sink_ref, refs = (refs[0], refs[1:]) if sink else (None, refs)
@@ -215,16 +273,8 @@ def _kernel(s_ref, q_ref, *refs, scale, window, C, tr, kb, sink=False):
     c_lo = (r * tr) % C if tr < C else 0
     c_hi = c_lo + min(tr, C) - 1
 
-    @pl.when(j == 0)
-    def _init():
-        if sink_ref is None:
-            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-            l_scr[...] = jnp.zeros_like(l_scr)
-        else:
-            # the sink as a key seen before any other: exp(0) = 1
-            m_scr[...] = sink_ref[...].astype(jnp.float32)
-            l_scr[...] = jnp.ones_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    stats = (m_scr, l_scr, acc_scr)
+    pl.when(j == 0)(lambda: softmax_init(*stats, sink_ref))
 
     def update(masked: bool):
         q, k, v = q_ref[...], k_ref[...], v_ref[...]
@@ -239,18 +289,7 @@ def _kernel(s_ref, q_ref, *refs, scale, window, C, tr, kb, sink=False):
             if window is not None:
                 seen = seen & (kpos > qpos - window)
             s = jnp.where(seen, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # a row none of whose keys it has seen yet holds m = NEG_INF and
-        # p = 1 for them: the first seen key's correction (exp(NEG_INF -
-        # m) = 0) wipes that, and every row sees its own position
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        softmax_step(s, v, *stats)
 
     k_lo = (b0 + j) * kb
     clear = k_lo + kb - 1 <= q0 + c_lo
@@ -259,11 +298,8 @@ def _kernel(s_ref, q_ref, *refs, scale, window, C, tr, kb, sink=False):
     pl.when((j < live) & clear)(lambda: update(False))
     pl.when((j < live) & jnp.logical_not(clear))(lambda: update(True))
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _fin():
-        l = l_scr[...]
-        o_ref[...] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)) \
-            .astype(o_ref.dtype)
+    pl.when(j == pl.num_programs(2) - 1)(
+        lambda: softmax_finish(o_ref, *stats))
 
 
 def _relay_heads(scalars, k, v, kb: int):

@@ -182,6 +182,19 @@ def _chunk_attn(C, nq, n_kv, M, window=None):
                 spec((), I32))
 
 
+def _latent_chunk_attn(C, nh, M):
+    """The chunk program's attention over a latent cache as a serving
+    cell's MLA layer calls it (`kimi_k2.MLAttention.attend_dense`): one
+    row's chunk against the scratch's latents of 576 values in 640
+    lanes, a head's k_nope | v made inside the kernel by W_kvb."""
+    from hetu_tpu.ops.pallas.latent_chunk_attention import \
+        latent_chunk_attention
+    return (lambda *a: latent_chunk_attention(*a, softmax_scale=192 ** -0.5),
+            (spec((1, C, nh, 128), BF16), spec((1, C, nh, 64), BF16),
+             spec((1, M, 640), BF16), spec((512, nh, 256), BF16),
+             spec((1,), I32)))
+
+
 def _kda_scan():
     """The chunkwise delta rule at the Ling cell's shape: one chunk of
     2,048 rows, 32 heads' 128 x 128 states, the operands as the
@@ -222,6 +235,9 @@ KERNEL_CASES = {
     "chunk_attention_trinity_window": lambda: _chunk_attn(
         512, 32, 4, 2560, window=2048),
     "chunk_attention_internlm2": lambda: _chunk_attn(128, 16, 8, 2048),
+    "latent_chunk_attention_ling": lambda: _latent_chunk_attn(
+        2048, 32, 32768),
+    "latent_chunk_attention_kimi": lambda: _latent_chunk_attn(512, 64, 4096),
     "kda_scan_ling_chunk": _kda_scan,
     "quant_int8": lambda: _quant(8),
     "quant_int4": lambda: _quant(4),
@@ -520,10 +536,25 @@ def test_serving_programs_compile_for_one_v5e(family):
     # in every layer it traces: Trinity's 168 / 537 MB of float32 scores
     # a layer take it (two calls a layer: K and V relaid head-major, then
     # the attention), 33.5 MB at 32 heads x 128 x 2,048 do not pay for it
-    # and keep the composition; Kimi's has its own `attend_dense`
-    chunk_calls = sum("pallas_chunk_attention" in ln for ln in compiled[
-        "prefill_chunk"].as_text().splitlines()
-        if 'custom_call_target="tpu_custom_call"' in ln)
+    # and keep the composition; a latent cache has a kernel and a route
+    # of its own (`MLAttention.attend_dense`): one call an MLA layer,
+    # under `attn`, nothing relaid around it
+    chunk_text = compiled["prefill_chunk"].as_text().splitlines()
+    chunk_calls = sum("pallas_chunk_attention" in ln for ln in chunk_text
+                      if 'custom_call_target="tpu_custom_call"' in ln)
+    latent_calls = [ln for ln in chunk_text
+                    if 'custom_call_target="tpu_custom_call"' in ln
+                    and "pallas_latent_chunk_attention" in ln]
+    if family in ("kimi", "ling"):
+        rec = routes["latent_chunk_attn"]
+        assert rec["pallas"] == len(latent_calls) == (
+            2 if family == "kimi" else 1) and not rec["xla"], rec
+        assert list(rec["why"]) == ["shape gate passes"]
+        assert all("attn/pallas_latent_chunk_attention" in ln
+                   for ln in latent_calls)
+        assert not any("pallas_latent_chunk_attention" in ln for ln in calls)
+    else:
+        assert "latent_chunk_attn" not in routes and not latent_calls
     if family == "ling":
         # state beside pages: both programs take the state arrays as
         # donated arguments and hand them back in place, with the pool

@@ -175,8 +175,7 @@ def test_absorbed_decode_is_expanded_attention(rng):
     pos = jnp.arange(M, dtype=jnp.int32)[None]
     q, (lat,) = attn.project(ap, hn, rope, pos)
     one = jax.tree.map(lambda a: a[:, n - 1:n], q)
-    expanded = attn.attend_dense(ap, one, (lat,), jnp.asarray([n - 1]),
-                                 block=M)
+    expanded = attn.attend_dense(ap, one, (lat,), jnp.asarray([n - 1]))
     pool = jnp.concatenate([jnp.zeros((1, 8, lat.shape[-1]), F32),
                             lat[0].reshape(M // 8, 8, -1)])
     absorbed = attn.attend_paged(ap, one, (pool,),

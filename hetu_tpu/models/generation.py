@@ -85,7 +85,9 @@ it are the model's hooks:
       [count, ...] (`_walk_layers`).
   model.read_rows_from                                (OPTIONAL)
       The layer from whose attention on only the rows whose logits are
-      read need computing (`extend_cache(read_row=)`).
+      read need computing (`extend_cache(read_row=)`).  Unnamed, it is
+      the number of layers: every layer runs for every row, and only
+      the final norm and the head run for the read row alone.
 
   STATS, zero_stats(), add_stats(a, b)
       `stats` is a small int32 vector a layer counts of itself (an
@@ -707,19 +709,24 @@ def extend_cache(model, params, tokens, cache, start, stats=None, *,
 
     **Rows whose logits nobody reads** (``read_row``, a traced scalar:
     the ONE row of every sequence's chunk whose logits the caller reads,
-    or negative: none).  A model all of whose layers from some layer k on
-    keep no cache of their own says so (`model.read_rows_from` = k: layer
-    k itself holds pages, every later layer reads another's or none):
-    those layers are then worth running only where logits are read.  The
-    walk runs layers 0 .. k - 1 for every row, layer k's norm, projection
-    and cache write for every row, and (under the scope `tail`, inside a
-    `lax.cond` on read_row >= 0: a chunk that does not end its prompt
-    runs none of it) layer k's attention, output and MLP and layers
-    k + 1 .. for that row alone, one query at position start + read_row
-    over the cache as the chunk left it.  Returns logits [b, 1, vocab]
-    (zeros where read_row < 0).  A model without `read_rows_from`, or a
-    call without `read_row`, runs every layer for every row: every
-    family that stands.
+    or negative: none).  The final norm and the head then run for that
+    row alone, inside a `lax.cond` on read_row >= 0 (a chunk that does
+    not end its prompt runs neither), and the call returns logits
+    [b, 1, vocab], zeros where read_row < 0: the head's scope stays
+    `lm_head` at the program's top level.  A model all of whose layers
+    from some layer k on keep no cache of their own says so
+    (`model.read_rows_from` = k: layer k itself holds pages, every later
+    layer reads another's or none; the default is the number of layers:
+    every layer stores or carries what later rows need, so every layer
+    runs for every row): those layers too are then worth running only
+    where logits are read.  The walk runs layers 0 .. k - 1 for every
+    row, layer k's norm, projection and cache write for every row, and
+    inside the same conditional, under the scope `layer/tail`, layer k's
+    attention, output and MLP and layers k + 1 .. for that row alone, one
+    query at position start + read_row over the cache as the chunk left
+    it.  A call without `read_row` (`verify_step_slots`,
+    serving/disagg.py) runs every layer and the head for every row and
+    returns [b, C, vocab].
 
     ``collect_token_kv=True`` (the `verify_step_slots` path) also
     returns the chunk's entries per layer ((k, v) [L, b, C, n_kv, hd])
@@ -743,8 +750,10 @@ def extend_cache(model, params, tokens, cache, start, stats=None, *,
     with jax.named_scope("embed"):
         x = model.embed_tokens(params, tokens, qpos)
 
-    stop = None if read_row is None else getattr(model, "read_rows_from",
-                                                 None)
+    # the layer from whose attention on only the read row is computed:
+    # the model's, or no layer (the norm and the head alone)
+    stop = None if read_row is None else getattr(
+        model, "read_rows_from", contract.num_layers)
 
     def layer_at(start, qpos, write: bool = True):
         """`_walk_layers`'s layer for queries at `qpos` [b, s], the
@@ -832,9 +841,10 @@ def extend_cache(model, params, tokens, cache, start, stats=None, *,
 
     cache = tuple(cache)
     with jax.named_scope("layer"):
-        if stop is None:
+        if stop in (None, contract.num_layers):
             x, stats, cache, chunk, _ = _walk_layers(
                 model, params, x, cache, stats, layer_at(start, qpos))
+            handed = None               # (no layer is left to take it)
         else:
             if ((slide and contract.windows[stop] is not None)
                     or collect_token_kv
@@ -850,30 +860,29 @@ def extend_cache(model, params, tokens, cache, start, stats=None, *,
                 model, params, x, cache, stats, entries_only, handed=handed,
                 layers=(stop, stop + 1))
             chunk = None
-
-            def tail(x, handed, stats):
-                r = jnp.maximum(jnp.asarray(read_row, jnp.int32), 0)
-                x, handed = jax.tree.map(
-                    lambda a: lax.dynamic_slice_in_dim(a, r, 1, axis=1),
-                    (x, handed))
+    if stop is None:
+        logits = model.logits(params, model.final_hidden(params, x))
+    else:
+        def tail(x, handed, stats):
+            r = jnp.maximum(jnp.asarray(read_row, jnp.int32), 0)
+            x, handed = jax.tree.map(
+                lambda a: lax.dynamic_slice_in_dim(a, r, 1, axis=1),
+                (x, handed))
+            if stop < contract.num_layers:
                 at = start + r
-                with jax.named_scope("tail"):
+                with jax.named_scope("layer"), jax.named_scope("tail"):
                     x, stats, _, _, _ = _walk_layers(
                         model, params, x, cache, stats,
                         layer_at(at, at[:, None], write=False),
-                        handed=handed,
-                        layers=(stop, contract.num_layers))
-                    return (model.logits(params,
-                                         model.final_hidden(params, x)),
-                            stats)
-            skipped = jax.tree.map(
-                lambda a: jnp.zeros(a.shape, a.dtype),
-                jax.eval_shape(tail, x, handed, stats))
-            logits, stats = lax.cond(
-                jnp.asarray(read_row) >= 0, tail,
-                lambda *_: skipped, x, handed, stats)
-    if stop is None:
-        logits = model.logits(params, model.final_hidden(params, x))
+                        handed=handed, layers=(stop, contract.num_layers))
+            return (model.logits(params, model.final_hidden(params, x)),
+                    stats)
+        none = jax.eval_shape(tail, x, handed, stats)[0]
+        logits, stats = lax.cond(
+            jnp.asarray(read_row) >= 0, tail,
+            lambda x, handed, stats: (jnp.zeros(none.shape, none.dtype),
+                                      stats),
+            x, handed, stats)
     return ((logits, cache) + ((chunk,) if collect_token_kv else ())
             + (() if stats is None else (stats,)))
 

@@ -375,16 +375,16 @@ class ServingEngine:
         #: it attends the pages of the layer it names through that
         #: layer's table, or attends nothing; the pool holds nothing for it
         self.borrows = self.cache.borrows
-        #: the layer from whose attention on only the rows whose logits
-        #: are read are computed (`extend_cache`'s `read_row`), where the
-        #: model's later layers keep no cache; None: every row, every layer
-        self._tail_from = getattr(model, "read_rows_from", None)
-        #: the kinds of page layer that attend for that row alone, and,
-        #: a reading layer each, how far back the layer it reads does
+        #: the kinds of page layer that attend for the ONE row of a chunk
+        #: whose logits are read (`extend_cache`'s `read_row`): those of a
+        #: model whose later layers keep no cache (`read_rows_from`; none
+        #: where every layer runs for every row and the head alone for
+        #: the one), and, a reading layer each, how far back the layer it
+        #: reads does
+        tail_from = getattr(model, "read_rows_from", self.cache.num_layers)
         self._tail_kinds = frozenset(
             k for k in range(len(self.cache.kinds))
-            if self._tail_from is not None
-            and min(self.cache.layers_of(k)) >= self._tail_from)
+            if min(self.cache.layers_of(k)) >= tail_from)
         self._read_windows = tuple(
             self.cache.windows[r] for r in self.cache.reads
             if r is not None and r >= 0)
@@ -822,22 +822,20 @@ class ServingEngine:
         # many of the chunk's rows are the prompt's
         state_args = ("state_row", "valid") if self.stateful else ()
 
-        # a model whose later layers keep no cache runs them for the ONE
-        # row a finished prompt samples from (`row`; negative: the chunk
-        # does not end its prompt and runs none of them), and hands back
-        # that row's logits alone, [1, 1, vocab]
-        tail = self._tail_from is not None
-
+        # the head (and the layers of a model whose later layers keep no
+        # cache: `read_rows_from`) runs for the ONE row a finished prompt
+        # samples from (`row`; negative: the chunk does not end its
+        # prompt and runs none of it), and the program hands back that
+        # row's logits alone, [1, 1, vocab]
         def chunk_fn(params, chunk, cache, start, row, *rest):
             state = dict(zip(state_args, rest))
             logits, cache, *stats = extend_cache(
                 model, params, chunk, cache, start, *rest[len(state):],
-                **slide, **state, **({"read_row": row} if tail else {}))
+                **slide, **state, read_row=row)
             # the greedy first token of a prompt that ends on `row` of
             # this chunk: taken here, fetched at the step's end
             with jax.named_scope("lm_head"):
-                first = jnp.argmax(logits[0, 0 if tail else row],
-                                   axis=-1).astype(jnp.int32)
+                first = jnp.argmax(logits[0, 0], axis=-1).astype(jnp.int32)
             return (logits, first, cache, *stats)
 
         by_kind = self.windowed
@@ -2196,12 +2194,11 @@ class ServingEngine:
             s = base + st.chunks_done * C
             last = s + C >= padded
             # the last VALID prompt position of the final chunk (padding
-            # tail positions carry garbage): the row the program takes
-            # the first token at
-            # (a model that runs its cache-less layers for that row alone
-            # is told by a negative row that this chunk has none)
-            tail = self._tail_from is not None
-            row = plen - 1 - s if last else -1 if tail else 0
+            # tail positions carry garbage): the row the program runs the
+            # head for and takes the first token at; negative: this chunk
+            # has none (a prefix-cache hit leaves the last chunk a row:
+            # `RadixPrefixCache.match` stops at plen - 1)
+            row = plen - 1 - s if last else -1
             ids = np.zeros(C, np.int32)
             seg = req.prompt[s: min(s + C, plen)]
             ids[: len(seg)] = seg
@@ -2223,9 +2220,9 @@ class ServingEngine:
                 self._registry.inc("serve.chunk_padded_rows", C - len(seg))
             if stats:
                 (self._stats_acc,) = stats
-            if tail:
-                # rows the layers from `read_rows_from` on were run for
-                self._registry.inc("serve.prefill_tail_rows", int(last))
+            # rows the head (and the layers from `read_rows_from` on)
+            # ran for
+            self._registry.inc("serve.prefill_tail_rows", int(last))
             st.chunks_done += 1
             st.stats.prefill_chunks += 1
             self._registry.inc("serve.prefill_chunks")
@@ -2260,8 +2257,7 @@ class ServingEngine:
             # from them (on the host), not the chunk program's argmax
             drawn = self.config.sampling and req.sampling.temperature > 0
             ends.append(_PromptEnd(slot_idx, st, first,
-                                   logits[0, 0 if tail else row]
-                                   if drawn else None))
+                                   logits[0, 0] if drawn else None))
 
     def _land_first_tokens(self, ends, clock, finished, phases):
         """The step's second wait for the device, after its dispatches:

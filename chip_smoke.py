@@ -350,12 +350,15 @@ def serve_phase(cfg, serve_cfg, *, prompt_lens, max_new: int, seed: int,
             "sample", sample.check_shapes,
             (S * (sc.spec_k + 1), c.hidden_size),
             (c.hidden_size, c.vocab_size))
-    check(engine.decode_paged == bool(routes["paged_attn"]["pallas"]),
-          f"{name}: engine.decode_paged={engine.decode_paged} disagrees "
-          f"with what it recorded: {routes['paged_attn']}")
+    # which attention each traced K/V layer of the decode program took
+    # (`KVAttention.attend_paged`: the route is the layer's own)
+    paged = routes["paged_attn"]
+    check(paged["pallas"] + paged["xla"] > 0,
+          f"{name}: no layer of the decode program asked for its route")
     if jax.default_backend() == "tpu":
-        check(engine.decode_paged, f"{name}: on a TPU decode should run the "
-              f"paged-attention kernel: {routes['paged_attn']}")
+        check(paged["pallas"] and not paged["xla"],
+              f"{name}: on a TPU every decode layer should run the "
+              f"paged-attention kernel: {paged}")
     step_hist = registry.histogram("serve.token_latency_s")
     decode_steps = int(registry.counter_value("serve.decode_steps"))
     memory_serving = device_memory([device])

@@ -193,7 +193,7 @@ def serving_decode_text(*, optimized: bool = False) -> str:
     try:
         args = list(eng._dummy_args("decode"))
         args[0] = params
-        lowered = eng._decode_jit.lower(*args)
+        lowered = eng._jits["decode"].lower(*args)
         return (lowered.compile().as_text() if optimized
                 else lowered.as_text())
     finally:

@@ -10,7 +10,10 @@ and its contract is read off its config.
 
 How a query ATTENDS what is stored is the model's as well (the hooks of
 models/generation.py).  For the K/V kind that is the same for every
-family, and is written once, here: `KVAttention`.
+family, and is written once, here: `KVAttention`, whose `attend_paged`
+is also the ONE place that chooses a K/V layer's decode attention (the
+paged kernel, or the composition over the slot's gathered pages): the
+serving engine builds one decode program and asks nothing.
 
 **How far back a layer reads** is part of the contract too: `windows`
 gives, per layer, the number of positions a query sees, its own counted
@@ -76,8 +79,8 @@ class CacheContract:
     stored_shapes: Tuple[Tuple[int, ...], ...] = None
     dtype: object = None
     #: "kv": K and V arrays of `n_kv x head_dim`, which the paged K/V
-    #: kernels, the gather route, speculative decoding, the prefix cache
-    #: and the quantized page modes are built for; any other name
+    #: kernels, speculative decoding, the prefix cache and the quantized
+    #: page modes are built for; any other name
     #: ("latent"): ONE array of the model's own shape, exact pages only
     kind: str = "kv"
     #: per layer, how far back the layer reads (positions, the query's
@@ -350,50 +353,84 @@ class KVAttention:
         (queries of 64 laid in rows of 128 beside zeros keep 64^-1/2)."""
         return width ** -0.5
 
-    #: True: where the paged kernel's gate refuses the layer's shapes or
-    #: the backend is no TPU, `attend_paged` attends the slot's pages
-    #: gathered through the table by the XLA composition, in place of the
-    #: kernel.  For a family that has no gather decode route to fall back
-    #: to as a whole (state layers, layers that read another's pages:
-    #: `ServingEngine._use_paged_kernel`); False: the engine chose the
-    #: program, and `attend_paged` is the kernel
-    paged_composition = False
-
     def attend_paged(self, params, q, pools, table, positions, base, *,
                      scales=None, layer=None, quant=None, window=None):
         """q: a block of C queries a slot at positions[s] + i, causal
         within the block; pools = (k pages, v pages) of ALL layers of the
         layer's kind, [L * P, page_size, n_kv, d_k] and [.., d_v], of
         which this layer's P pages start at `base` and are read through
-        `table` [S, max_pages] of page ids within a layer.  C = 1 is the
-        decode step's kernel, C > 1 the verify step's
-        (ops/pallas/paged_attention).  Under a `window` the walk starts
-        at the page that holds position positions[s] - window + 1 (the
-        table's entries before it are the null page: their pages were
-        released).  Quantized pages (`quant`: "int8" | "int4") bring
-        `scales`, the planes [L, P, page_size, n_kv] of which `layer`'s
-        is handed to the kernel, to read by `table` as it came
-        (models/generation._paged_forward says why).
-        -> [S, C, n_q * d_v]."""
+        `table` [S, max_pages] of page ids within a layer.  Quantized
+        pages (`quant`: "int8" | "int4") bring `scales`, the planes
+        [L, P, page_size, n_kv] of which `layer`'s is read by `table` as
+        it came (models/generation._paged_forward says why).
+
+        THE decider of a K/V layer's decode attention, for its own shapes
+        and nobody else's (`kernel_routes["paged_attn"]`, or
+        `["paged_verify"]` for a block, says it per traced layer, with
+        the reason): the Pallas kernel that walks the page table
+        (`_attend_paged_kernel`) where `ops.pallas.resolve_route` and the
+        kernel's gate allow, else the XLA composition over the slot's
+        gathered pages (`_attend_gathered`): every backend but a TPU,
+        every shape the gate refuses, and what the kernels are not built
+        for (a window or a sink over a block of queries or quantized
+        pages).  -> [S, C, n_q * d_v]."""
+        if self._paged_kernel_takes(params, q, pools, table, window, quant):
+            return self._attend_paged_kernel(
+                params, q, pools, table, positions, base, scales=scales,
+                layer=layer, quant=quant, window=window)
+        return self._attend_gathered(params, q, pools, table, positions,
+                                     base, window, scales=scales,
+                                     layer=layer, quant=quant)
+
+    def _paged_kernel_takes(self, params, q, pools, table, window,
+                            quant=None) -> bool:
+        """The route of this layer's decode attention, by the one routing
+        rule (`ops.pallas.resolve_route`: forced flags win) over the
+        kernel's own gate for this layer's shapes: `paged_attention`'s
+        for one query a slot, `paged_verify`'s for a block (which has no
+        window, no sink and no keys wider than the values: the gate is
+        told, and refuses)."""
+        from hetu_tpu.ops.pallas import paged_attention as _pa
+        from hetu_tpu.ops.pallas import resolve_route
+        S, C, nq, hd = q.shape
+        k_shape, v_shape = pools[0].shape, pools[1].shape
+        sink = self.sink(params, window) is not None
+        # (quantized pages store K and V alike, int4 at half the width;
+        # exact keys may be STORED wider than the queries come)
+        width = hd if quant else k_shape[-1]
+        kw = dict(quant=quant or "none", pool_dtype=pools[0].dtype)
+        if C == 1:
+            return resolve_route(
+                "paged_attn", _pa.check_shapes, (S, nq, width), k_shape,
+                table.shape, (S,), window=window, v_shape=v_shape,
+                sink=sink, **kw)
+
+        def check(*shapes, **kw):
+            if window is not None or sink or k_shape != v_shape:
+                raise ValueError(
+                    "a window, a sink and keys wider than the values have "
+                    "the single-token kernel; the verify block is not "
+                    "built for them")
+            return _pa.check_shapes_verify(*shapes, **kw)
+        return resolve_route("paged_verify", check, (S, C, nq, width),
+                             k_shape, table.shape, (S,), **kw)
+
+    def _attend_paged_kernel(self, params, q, pools, table, positions, base,
+                             *, scales=None, layer=None, quant=None,
+                             window=None):
+        """`attend_paged` by the kernel (ops/pallas/paged_attention): C =
+        1 is the decode step's, C > 1 the verify step's.  Under a
+        `window` the walk starts at the page that holds position
+        positions[s] - window + 1 (the table's entries before it are the
+        null page: their pages were released)."""
         from hetu_tpu.ops.pallas import _note_route
         from hetu_tpu.ops.pallas.paged_attention import (paged_attention,
                                                          paged_verify)
         S, C, nq, hd = q.shape
         sink, kw = self.sink(params, window), {}
-        refusal = ("a window, a sink and keys wider than the values have "
-                   "the single-token kernel over exact pages; the verify "
-                   "block and quantized pages are not built for them")
-        if (window is not None or sink is not None) and (C > 1 or scales):
-            raise NotImplementedError(refusal)
         # (quantized pages store K and V alike, int4 at half the width)
         d_k, d_v = ((hd, hd) if scales else
                     (pools[0].shape[-1], pools[1].shape[-1]))
-        if d_k != d_v and C > 1:
-            raise NotImplementedError(refusal)
-        if self.paged_composition and not self._paged_kernel_takes(
-                q, pools, table, positions, window):
-            return self._attend_gathered(params, q, pools, table, positions,
-                                         base, window)
         if window is not None:
             kw["window"] = window
             _note_route("paged_attn_window", True,
@@ -421,30 +458,33 @@ class KVAttention:
                           v_scale=vsl, quant=quant, scale_table=table, **kw)
         return attn.reshape(S, C, nq * d_v)
 
-    def _paged_kernel_takes(self, q, pools, table, positions, window):
-        """The rule `ServingEngine._use_paged_kernel` asks of a whole
-        model, for this layer's shapes (exact pages, one query a slot)."""
-        from hetu_tpu.ops.pallas import paged_attention as _pa
-        from hetu_tpu.ops.pallas import resolve_route
-        S, C, nq, _ = q.shape
-        k_shape, v_shape = pools[0].shape, pools[1].shape
-        return C == 1 and resolve_route(
-            "paged_attn", _pa.check_shapes, (S, nq, k_shape[-1]), k_shape,
-            table.shape, (S,), pool_dtype=pools[0].dtype, window=window,
-            v_shape=v_shape)
-
     def _attend_gathered(self, params, q, pools, table, positions, base,
-                         window, rows=tuple):
+                         window, rows=tuple, *, scales=None, layer=None,
+                         quant=None):
         """`attend_paged` without the kernel: each slot's pages of this
         layer gathered through `table` into a dense [S, max_pages *
         page_size, ...] view (read as `rows` makes of the stored rows: as
-        they are), attended by the XLA composition under the causal (and
-        the window's) mask; a released or unheld page's entry is the
-        null page, whose positions the masks never let through."""
+        they are; quantized pages dequantized by the layer's scale plane,
+        the arithmetic of `serving/kv_pool.PagePool.gather`), attended by
+        the XLA composition under the causal (and the window's) mask,
+        a block of C > 1 queries at positions of their own like one; a
+        released or unheld page's entry is the null page, whose positions
+        the masks never let through."""
         from hetu_tpu.models.generation import _attend_cached_chunk
         S, C, nq, hd = q.shape
-        k, v = rows(p[table + base].reshape((S, -1) + p.shape[2:])
-                    for p in pools)
+
+        def dense(pool, scale):
+            g = pool[table + base]              # [S, mp, ps, n_kv, hd(/2)]
+            if quant == "int4":
+                from hetu_tpu.ops.quantization import unpack_nibbles
+                g = unpack_nibbles(g, even_high=False).astype(jnp.int32) - 8
+            g = g.reshape((S, -1) + g.shape[3:])
+            if scale is None:
+                return g
+            sc = scale[layer][table].reshape(g.shape[:-1])
+            return (g.astype(jnp.float32) * sc[..., None]).astype(q.dtype)
+        k, v = rows(dense(p, s) for p, s in zip(pools,
+                                                scales or (None, None)))
         out = _attend_cached_chunk(
             _widen(q, k.shape[-1]), k, v, positions, self.softmax_scale(hd),
             window=window, sink=self.sink(params, window))
@@ -458,8 +498,9 @@ class KVAttention:
         chunk program says), every position the queries may see among
         them.  Under a `window`, ONE row's queries (the chunk program)
         read the window + C positions that end with the chunk and no
-        more; rows at positions of their own (the gather decode route)
-        read by the window's mask.
+        more; rows at positions of their own (the tests' reference
+        `decode_step_slots` over gathered views) read by the window's
+        mask.
 
         Which shapes take which attention (the route record
         `kernel_routes["chunk_attn"]` says it per traced layer): ONE
@@ -477,7 +518,7 @@ class KVAttention:
         `models/generation._attend_cached_chunk`: any shape the gate
         refuses, every backend but a TPU, a single query (C = 1: the
         decode step over a dense cache), and rows at depths of their own
-        (start [b > 1]: the gather decode route, the verify step), which
+        (start [b > 1]: `decode_step_slots` / `verify_step_slots`), which
         no flag forces.  -> [b, C, n_q * d_v]."""
         from hetu_tpu.models.generation import _attend_cached_chunk
         from hetu_tpu.ops.pallas import chunk_attention as _ca

@@ -102,13 +102,17 @@ it are the model's hooks:
 
 The programs: `prefill` (a whole prompt into a fresh dense cache),
 `decode_step_slots` (one token a row over a dense cache; `decode_step`
-at one position for all rows), `extend_cache` (a chunk of tokens a row
-over a dense cache: chunked prefill, and the gather route's verify step
-`verify_step_slots`), and `decode_step_paged` / `verify_step_paged` (one
-token, or a block, a slot, attending a paged pool where it lies).  Every
-one runs `_layer`, the one decoder layer, and differs in the two lines it
-hands it: where the entry is written and how the query attends
-(docs/serving.md).
+at one position for all rows: `generate()`'s loop), `extend_cache` (a
+chunk of tokens a row over a dense cache: chunked prefill), and
+`decode_step_paged` / `verify_step_paged` (one token, or a block, a
+slot, over a paged pool where it lies: THE decode and verify step of
+the serving engine, for every cache kind; which attention a layer runs
+there, a kernel that walks the page table or the XLA composition over
+the slot's gathered pages, is its `attend_paged` hook's choice).
+`verify_step_slots` (a block a row over a dense cache) is the tests'
+reference of `verify_step_paged`.  Every one runs `_layer`, the one
+decoder layer, and differs in the two lines it hands it: where the entry
+is written and how the query attends (docs/serving.md).
 """
 from __future__ import annotations
 
@@ -169,8 +173,9 @@ def _attend_cached_chunk(q, ck, cv, start, scale, window=None, first=0,
     what stays here is every other backend, every shape the kernel's
     gate refuses (head_dim % 128, a chunk or a cache length that does
     not tile), a single query (`_attend_cached`, the decode step over a
-    dense cache), rows at depths of their own (start [b > 1]: the
-    gather decode route, the verify step) and whole prompts under a
+    dense cache), rows at depths of their own (start [b > 1]: a layer's
+    gathered pages in the paged decode and verify steps,
+    `KVAttention._attend_gathered`) and whole prompts under a
     window (`attend_prompt`).  It forms the float32 scores of every
     position it is handed, in HBM."""
     b, M, n_kv, hd = ck.shape
@@ -612,9 +617,11 @@ def _cache_write_token(c, e, positions, uniform: bool, at):
 
 
 def decode_step_slots(model, params, tokens, cache, positions):
-    """One token step with PER-SLOT positions (the serving engine's
-    gather route: each batch row is an independent sequence at its own
-    depth) over a dense cache (`init_cache`'s arrays).
+    """One token step with PER-SLOT positions (each batch row is an
+    independent sequence at its own depth) over a dense cache
+    (`init_cache`'s arrays): `generate()`'s step, and with
+    `PagePool.gather` the tests' reference of `decode_step_paged`; no
+    program of the serving engine calls it.
 
     tokens: [b] int32; positions: [b] int32 (this token's absolute
     position per slot) — or a scalar, which keeps the contiguous
@@ -728,7 +735,8 @@ def extend_cache(model, params, tokens, cache, start, stats=None, *,
     serving/disagg.py) runs every layer and the head for every row and
     returns [b, C, vocab].
 
-    ``collect_token_kv=True`` (the `verify_step_slots` path) also
+    ``collect_token_kv=True`` (`verify_step_slots`, the tests'
+    reference of `verify_step_paged`: no program of the engine asks) also
     returns the chunk's entries per layer ((k, v) [L, b, C, n_kv, hd])
     so a paged cache can scatter them into its pool; given the running
     `stats` vector of a model that counts (`model.STATS`), the advanced
@@ -888,8 +896,11 @@ def extend_cache(model, params, tokens, cache, start, stats=None, *,
 
 
 def verify_step_slots(model, params, tokens, cache, positions):
-    """The speculative-decoding VERIFY step: advance every slot by a
-    whole [k+1]-token block in ONE forward (serving/spec_decode.py).
+    """The tests' reference of `verify_step_paged` (over
+    `PagePool.gather`'s dense views, with `PagePool.write_tokens`); no
+    program of the engine calls it.  The speculative-decoding VERIFY
+    step: advance every slot by a whole [k+1]-token block in ONE forward
+    (serving/spec_decode.py).
 
     tokens: [S, k+1] int32 — per slot, the last emitted token followed
     by the k draft tokens; positions: [S] int32 — the slot's current
@@ -944,8 +955,8 @@ def _paged_write(pool, scale, table, positions, t, layer, base, bits):
         off = pos % ps
     if scale is None:
         return pool.at[base + page, off].set(t.astype(pool.dtype)), None
-    # the SAME primitives as the gather route's writes (kv_pool.write_*):
-    # pool contents are bit-identical across the decode programs
+    # the SAME primitives as the page write's and the tests' reference
+    # (kv_pool.write_*): pool contents are bit-identical to theirs
     from hetu_tpu.serving.kv_pool import quantize_heads
     q, s = quantize_heads(t.astype(jnp.float32), bits)
     return pool.at[base + page, off].set(q.astype(pool.dtype)), \
@@ -1079,9 +1090,12 @@ def _paged_forward(model, params, tokens, pool_tree, table, positions,
 
 def decode_step_paged(model, params, tokens, pool_tree, table, positions,
                       stats=None):
-    """One decode step attending DIRECTLY over a paged pool — the
-    gather-free form of `decode_step_slots` (ops/pallas/paged_attention;
-    the serving engine's paged decode program).
+    """One decode step over a paged pool where it lies: the serving
+    engine's decode program, for every cache kind.  Each layer scatters
+    the token's entries into the slot's page and attends by its own
+    `attend_paged` (a kernel that walks the page table,
+    ops/pallas/paged_attention, or the composition over the slot's
+    gathered pages: cache_contract.KVAttention says which and why).
 
     tokens: [S] int32; pool_tree: the pool's arrays (`_paged_forward`
     says which), page 0 of a layer the null page; table: [S, max_pages]
@@ -1097,10 +1111,12 @@ def decode_step_paged(model, params, tokens, pool_tree, table, positions,
 
 def verify_step_paged(model, params, tokens, pool_tree, table, positions,
                       *, return_hidden: bool = False):
-    """The speculative VERIFY step attending DIRECTLY over a paged KV
-    pool — `verify_step_slots` without the gather (ops/pallas/
-    paged_attention.paged_verify: all k+1 query positions walk the
-    slot's pages in one launch with per-position causal masks).
+    """The speculative VERIFY step over a paged KV pool where it lies:
+    the engine's verify program (ops/pallas/paged_attention.paged_verify
+    where a layer's `attend_paged` takes the kernel: all k+1 query
+    positions walk the slot's pages in one launch with per-position
+    causal masks; else the composition over the slot's gathered
+    pages).
 
     tokens: [S, C] int32 (last emitted token + k drafts per slot);
     positions: [S] int32 — token i of the block sits at positions[s]+i;

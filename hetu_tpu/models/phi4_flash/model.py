@@ -223,10 +223,6 @@ class DiffAttention(KVAttention, Module):
     everything), `cross` a layer with W_q and W_o only, whose `project`
     makes no entries: it attends another layer's."""
 
-    #: no gather decode route beside state layers and reading layers: the
-    #: composition over gathered pages where the paged kernel is refused
-    paged_composition = True
-
     def __init__(self, config: Phi4FlashConfig, window: Optional[int],
                  cross: bool = False):
         Module.__init__(self)
@@ -306,8 +302,8 @@ class DiffAttention(KVAttention, Module):
         """The paged pool is read where it lies, in stored rows: q
         [S, 1, nq, 2 hd] laid at its pair's place in a stored row's
         width, the pair's 2 hd values cut out of what comes back.  Where
-        the kernel is refused (`paged_composition`): the slot's pages
-        gathered and read as the pairs' rows."""
+        the kernel is refused (`KVAttention.attend_paged`): the slot's
+        pages gathered and read as the pairs' rows."""
         c = self.config
         S, C, nq, hd = q.shape
         if c.kv_fold == 1:
@@ -316,12 +312,11 @@ class DiffAttention(KVAttention, Module):
         place = self._place().astype(q.dtype)                 # [nq, fold]
         wide = (q[..., None, :] * place[:, :, None]).reshape(
             S, C, nq, c.kv_fold * hd)
-        if not self._paged_kernel_takes(wide, pools, table, positions,
-                                        window):
+        if not self._paged_kernel_takes(params, wide, pools, table, window):
             return self._attend_gathered(params, q, pools, table, positions,
                                          base, window, rows=self._pair_rows)
-        out = super().attend_paged(params, wide, pools, table, positions,
-                                   base, window=window)
+        out = self._attend_paged_kernel(params, wide, pools, table,
+                                        positions, base, window=window)
         out = out.reshape(S, C, nq, c.kv_fold, hd)
         return jnp.einsum("scnfd,nf->scnd", out, place.astype(out.dtype)
                           ).reshape(S, C, nq * hd)

@@ -14,16 +14,18 @@ one compile each, no shape-bucket churn):
                       other requests' inter-token gap.
   * prefill write   — scatter the scratch K/V into the slot's pool pages
                       (quantizing in the int8 page mode).
-  * decode step     — gather every slot's pages to dense views, run
-                      `decode_step_slots` over the full slot batch with
-                      per-slot positions, scatter the new token K/V back
-                      into the pool, argmax.  Inactive slots ride along
-                      pointing at the null page.  Under HETU_TPU_PALLAS
-                      (exact fp pages + passing shape gate) the program
-                      is the GATHER-FREE form instead: the Pallas
-                      paged-attention kernel walks the page tables
-                      directly (`models/generation.decode_step_paged`,
-                      ops/pallas/paged_attention, docs/kernels.md).
+  * decode step     — `models/generation.decode_step_paged` over the
+                      full slot batch with per-slot positions: each
+                      layer scatters the token's K/V into the slot's
+                      page and attends the pool where it lies, through
+                      the page table; argmax.  Inactive slots ride along
+                      pointing at the null page.  ONE program for every
+                      cache kind; which attention a layer calls there is
+                      the layer's own hook's choice (`attend_paged`: the
+                      Pallas paged-attention kernel where HETU_TPU_PALLAS
+                      and its shape gate allow, else the XLA composition
+                      over the slot's gathered pages; `kernel_routes`
+                      says which, a traced layer; docs/kernels.md).
 
 Between device steps the host-side `Scheduler` admits/evicts at token
 granularity and the engine stamps SLO metrics into the `obs` registry
@@ -51,11 +53,12 @@ See docs/serving.md for the architecture and known limits.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
 import time
-from typing import List, Optional, Sequence
+from typing import Callable, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -63,10 +66,9 @@ import numpy as np
 
 from hetu_tpu.models.cache_contract import cache_contract
 from hetu_tpu.models.generation import (_check_context_length,
-                                        decode_step_paged, decode_step_slots,
-                                        extend_cache, init_cache,
-                                        lm_head_weight, verify_step_paged,
-                                        verify_step_slots)
+                                        decode_step_paged, extend_cache,
+                                        init_cache, lm_head_weight,
+                                        verify_step_paged)
 from hetu_tpu.obs.health import maybe_serving_health_monitor
 from hetu_tpu.obs.metrics import MetricsRegistry, get_registry
 from hetu_tpu.obs.runlog import RunLog, default_runlog_path
@@ -106,6 +108,22 @@ class _InFlight:
     out: tuple
     rows: list
     t0: float
+
+
+@dataclasses.dataclass(frozen=True)
+class _Program:
+    """One row of the table of an engine's programs
+    (`ServingEngine._build_programs`): the body, the argument it
+    donates, what builds its arguments aimed at the null page, and which
+    of `ServingEngine._program`'s wrappers can apply to it."""
+    body: Callable
+    donate: int
+    null_args: Callable
+    #: its first argument is the parameters (resident int experts are
+    #: dequantized there)
+    takes_params: bool = True
+    #: it has quantize sites for the numerics observatory to collect
+    numerics: bool = True
 
 
 @dataclasses.dataclass
@@ -692,74 +710,50 @@ class ServingEngine:
                 + "; ".join(asked))
 
     # ------------------------------------------------------------ build
-    def _recording(self, fn):
-        """`fn`, noting the kernel routes its trace takes in
-        `self.kernel_routes`."""
-        @functools.wraps(fn)
-        def traced(*args):
-            with record_routes(self.kernel_routes):
-                return fn(*args)
-        return traced
+    def _program(self, name: str):
+        """The jitted program of `self._table[name]`: its body and what
+        the engine does to EVERY body, written once, in this order from
+        the body outwards: resident int experts dequantized on entry (so
+        only the transient working copy is fp: the decode step's expert
+        HBM read is the quantized payload), the numerics collector (the
+        stats pytree rides out as one more output, LAST: `_call` peels
+        it), the kernel routes the trace takes noted in
+        `self.kernel_routes`, and the donation.  Nothing here names an
+        argument but the first.  Where neither wrapper applies the body
+        IS the program (the HETU_TPU_NUMERICS=0 and moe_dispatch="gspmd"
+        identity contracts hold by construction), and the compiled
+        module keeps the body's name (`jit_decode_fn`: what a device
+        trace finds a program's events by, benchmarks/metrics)."""
+        from hetu_tpu.obs.numerics import collecting
+        from hetu_tpu.serving.experts import dequantize_expert_tree
+        p = self._table[name]
+        spec = self._moe_spec if p.takes_params else None
+        collect = self._numerics and p.numerics
 
-    def _use_paged_kernel(self) -> bool:
-        """Route the decode program through the gather-free Pallas
-        paged-attention kernel (ops/pallas/paged_attention) when the
-        HETU_TPU_PALLAS surface and the kernel's shape gate allow.
-        int8/int4 pages dequantize IN-KERNEL (the scales ride in as
-        extra operands; int4 pages store packed nibble pairs, so the
-        stored head dim is head_dim // 2).  Speculative decoding routes
-        the multi-query `paged_verify` kernel instead — same pages, k+1
-        causally-masked query positions per slot per launch.  Evaluated
-        once at build: the decision is static, like every other program
-        shape."""
-        if self.cache.kind != "kv" or self.stateful or self.borrows:
-            # no gather route for such a pool, for state beside it or for
-            # a layer that reads another layer's pages: one decode
-            # program over the page table, and which attention it calls
-            # there is the model's route (`attend_paged`; a K/V layer's
-            # falls back to the composition over gathered pages where
-            # the kernel's gate refuses: KVAttention.paged_composition)
-            return True
-        from hetu_tpu.ops.pallas import paged_attention as _pa
-        from hetu_tpu.ops.pallas import resolve_route
-        c = self.model.config
-        S = self.config.num_slots
-        table_shape = (S, self.scheduler.max_pages)
-        if self.windowed:
-            # one decode program: the kernel for every kind of layer (at
-            # the kind's own shapes), or the gather route for all
-            ok = []
-            for k, (n, w) in enumerate(zip(self.pool.pages_by_kind,
-                                           self.pool.windows)):
-                k_shape, v_shape = (
-                    (n + 1, self.config.page_size) + tuple(x)
-                    for x in self.cache.stored_shapes_of(k))
-                ok.append(resolve_route(
-                    "paged_attn", _pa.check_shapes,
-                    (S, c.num_attention_heads, k_shape[-1]), k_shape,
-                    table_shape, (S,), pool_dtype=self.pool.arrays.k.dtype,
-                    window=w, v_shape=v_shape))
-            return all(ok)
-        hd_p = (self.pool.head_dim // 2 if self.pool.quant == "int4"
-                else self.pool.head_dim)
-        pool_shape = (self.pool.pages_by_kind[0] + 1, self.config.page_size,
-                      self.pool.num_kv_heads, hd_p)
-        if self.spec:
-            q_shape = (S, self.config.spec_k + 1,
-                       c.num_attention_heads, c.head_dim)
-            return resolve_route(
-                "paged_verify", _pa.check_shapes_verify, q_shape, pool_shape,
-                table_shape, (S,), quant=self.pool.quant,
-                pool_dtype=self.pool.arrays.k.dtype)
-        q_shape = (S, c.num_attention_heads, c.head_dim)
-        return resolve_route("paged_attn", _pa.check_shapes, q_shape,
-                             pool_shape, table_shape, (S,),
-                             quant=self.pool.quant,
-                             pool_dtype=self.pool.arrays.k.dtype)
+        @functools.wraps(p.body)
+        def program(*args):
+            with record_routes(self.kernel_routes), (
+                    collecting() if collect
+                    else contextlib.nullcontext()) as col:
+                if spec is not None:
+                    args = (dequantize_expert_tree(args[0], spec), *args[1:])
+                out = p.body(*args)
+                return (out, col.finalize()) if collect else out
+        return jax.jit(program, donate_argnums=(p.donate,))
+
+    def _call(self, name: str, *args):
+        """Dispatch one of the engine's programs, peeling the numerics
+        stats output where `_program` added it (latest wins until
+        recorded)."""
+        out = self._jits[name](*args)
+        if self._numerics and self._table[name].numerics:
+            out, stats = out
+            if stats:
+                self._numerics_stats = stats
+        return out
 
     def _build_programs(self):
         model, pool = self.model, self.pool
-        self.decode_paged = self._use_paged_kernel()
         sampling_on = self.config.sampling
 
         def pick_token(logits, positions, sample_args):
@@ -781,8 +775,6 @@ class ServingEngine:
         #: (`model.STATS`), as one more argument and result; else 0
         counts = int(bool(model.STATS))
 
-        paged = self.decode_paged
-
         def decode_fn(params, pool_tree, table, tokens, positions, prev,
                       *rest):
             stats, sample_args = rest[:counts], rest[counts:]
@@ -791,23 +783,12 @@ class ServingEngine:
             # the slot (`prev`, that program's output, never fetched in
             # between: `ServingEngine.step`)
             tokens = jnp.where(tokens < 0, prev[:tokens.shape[0]], tokens)
-            if paged:
-                # gather-free: the model's `attend_paged` walks the page
-                # table directly; this token's entries are scattered
-                # inside the step (the write_token scatter is folded
-                # into the program).  The tree is the pool's own:
-                # int8/int4 pools carry (k, v, k_scale, v_scale) — the
-                # kernel dequantizes pages in-VMEM
-                logits, pool_tree, *stats = decode_step_paged(
-                    model, params, tokens, pool_tree, table, positions,
-                    *stats)
-            else:
-                logits, _, toks = decode_step_slots(
-                    model, params, tokens, pool.gather(pool_tree, table),
-                    positions)
-                with jax.named_scope("kv_write"):
-                    pool_tree = pool.write_token(pool_tree, table,
-                                                 positions, *toks)
+            # each layer scatters this token's entries into the slot's
+            # page and attends the pool where it lies, by the attention
+            # its own `attend_paged` chooses.  The tree is the pool's
+            # own: int8/int4 pools carry (k, v, k_scale, v_scale)
+            logits, pool_tree, *stats = decode_step_paged(
+                model, params, tokens, pool_tree, table, positions, *stats)
             nxt = pick_token(logits, positions, sample_args)
             # the stats ride out behind the tokens: one fetch
             return (jnp.concatenate([nxt, *stats]) if stats else nxt,
@@ -851,21 +832,19 @@ class ServingEngine:
                 return pool.write_pages(pool_tree, pages_row, *caches)
 
         # speculative-decoding verify (serving/spec_decode.py): score
-        # the last token + k drafts in one multi-query forward —
-        # `verify_step_paged` (the fused Pallas kernel chain) when the
-        # paged_verify route is on, the gather machinery
-        # (models/generation.verify_step_slots) otherwise — scatter the
-        # block's K/V, and compute the acceptance in-graph; the host
-        # only reads [S, k+1] target tokens and [S] emit counts, never
-        # the logits.  When the fused `sample` kernel also routes, the
-        # paged forward returns last-layer HIDDEN rows and the lm_head
-        # matmul + filter + draw fuse into one epilogue launch — the
+        # the last token + k drafts in one multi-query forward
+        # (`verify_step_paged`: each layer scatters the block's K/V and
+        # attends by its own `attend_paged`, the multi-query kernel or
+        # the composition), and compute the acceptance in-graph; the
+        # host only reads [S, k+1] target tokens and [S] emit counts,
+        # never the logits.  When the fused `sample` kernel routes, the
+        # forward returns last-layer HIDDEN rows and the lm_head matmul +
+        # filter + draw fuse into one epilogue launch — the
         # [S, k+1, vocab] logits plane never touches HBM.
         K1 = self.config.spec_k + 1
-        verify_paged = self.spec and self.decode_paged
         stochastic = self.spec_stochastic
         self.verify_fused_sample = False
-        if verify_paged and not stochastic:
+        if self.spec and not stochastic:
             from hetu_tpu.ops.pallas import resolve_route
             from hetu_tpu.ops.pallas import sample as _psample
             mc = model.config
@@ -874,20 +853,6 @@ class ServingEngine:
                 (self.config.num_slots * K1, mc.hidden_size),
                 (mc.hidden_size, mc.vocab_size))
         fused_sample = self.verify_fused_sample
-
-        def verify_forward(params, pool_tree, table, tokens, positions,
-                           pos_grid, want_hidden):
-            """-> (logits_or_hidden [S, K1, ...], new pool tree)."""
-            if verify_paged:
-                return verify_step_paged(
-                    model, params, tokens, pool_tree, table, positions,
-                    return_hidden=want_hidden)
-            ck, cv = pool.gather(pool_tree, table)
-            logits, _, (kc, vc) = verify_step_slots(
-                model, params, tokens, (ck, cv), positions)
-            new_tree = pool.write_tokens(pool_tree, table, pos_grid,
-                                         kc, vc)
-            return logits, new_tree
 
         def full_sample_args(tokens, sample_args):
             """The per-slot sampling vectors, or the all-greedy ones
@@ -902,49 +867,40 @@ class ServingEngine:
                     jnp.zeros((S,), jnp.int32),
                     jnp.zeros((S,), jnp.float32))
 
-        if stochastic:
-            def verify_fn(params, pool_tree, table, tokens, positions,
-                          q_probs, *sample_args):
+        def verify_fn(params, pool_tree, table, tokens, positions, *rest):
+            # (stochastic acceptance: the drafter's distributions ride
+            # in before the sampling vectors)
+            q_probs, sample_args = (rest[:int(stochastic)],
+                                    rest[int(stochastic):])
+            pos_grid = positions[:, None] + jnp.arange(K1, dtype=jnp.int32)
+            out, new_tree = verify_step_paged(
+                model, params, tokens, pool_tree, table, positions,
+                return_hidden=fused_sample)
+            if stochastic:
                 from hetu_tpu.serving.spec_decode import stochastic_verify
-                pos_grid = positions[:, None] + jnp.arange(
-                    K1, dtype=jnp.int32)
-                logits, new_tree = verify_forward(
-                    params, pool_tree, table, tokens, positions,
-                    pos_grid, False)
                 seeds, temps, top_ks, top_ps = full_sample_args(
                     tokens, sample_args)
                 targets, n_emit = stochastic_verify(
-                    logits, q_probs, tokens[:, 1:], seeds, pos_grid + 1,
+                    out, *q_probs, tokens[:, 1:], seeds, pos_grid + 1,
                     temps, top_ks, top_ps)
                 return targets, n_emit, new_tree
-        else:
-            def verify_fn(params, pool_tree, table, tokens, positions,
-                          *sample_args):
-                pos_grid = positions[:, None] + jnp.arange(
-                    K1, dtype=jnp.int32)
-                out, new_tree = verify_forward(
-                    params, pool_tree, table, tokens, positions,
-                    pos_grid, fused_sample)
-                if fused_sample:
-                    from hetu_tpu.serving.sampling import \
-                        sample_hidden_grid
-                    seeds, temps, top_ks, top_ps = full_sample_args(
-                        tokens, sample_args)
-                    targets = sample_hidden_grid(
-                        out, lm_head_weight(model, params), seeds,
-                        pos_grid + 1, temps, top_ks, top_ps)
-                elif sampling_on:
-                    from hetu_tpu.serving.sampling import \
-                        sample_token_grid
-                    seeds, temps, top_ks, top_ps = sample_args
-                    targets = sample_token_grid(out, seeds, pos_grid + 1,
-                                                temps, top_ks, top_ps)
-                else:
-                    targets = jnp.argmax(out, axis=-1).astype(jnp.int32)
-                match = (targets[:, :-1] == tokens[:, 1:]) \
-                    .astype(jnp.int32)
-                n_emit = jnp.cumprod(match, axis=1).sum(axis=1) + 1  # [S]
-                return targets, n_emit.astype(jnp.int32), new_tree
+            if fused_sample:
+                from hetu_tpu.serving.sampling import sample_hidden_grid
+                seeds, temps, top_ks, top_ps = full_sample_args(
+                    tokens, sample_args)
+                targets = sample_hidden_grid(
+                    out, lm_head_weight(model, params), seeds,
+                    pos_grid + 1, temps, top_ks, top_ps)
+            elif sampling_on:
+                from hetu_tpu.serving.sampling import sample_token_grid
+                seeds, temps, top_ks, top_ps = sample_args
+                targets = sample_token_grid(out, seeds, pos_grid + 1,
+                                            temps, top_ks, top_ps)
+            else:
+                targets = jnp.argmax(out, axis=-1).astype(jnp.int32)
+            match = (targets[:, :-1] == tokens[:, 1:]).astype(jnp.int32)
+            n_emit = jnp.cumprod(match, axis=1).sum(axis=1) + 1      # [S]
+            return targets, n_emit.astype(jnp.int32), new_tree
 
         # prefix-cache prime (serving/prefix_cache.py): gather a slot's
         # resident shared-prefix pages into the dense prefill scratch so
@@ -952,64 +908,10 @@ class ServingEngine:
         def prime_fn(pool_tree, pages_row):
             return pool.gather(pool_tree, pages_row[None])
 
-        if self._moe_spec is not None:
-            # resident int experts: the programs dequantize on entry, so
-            # only the transient working copy is fp (the decode step's
-            # expert HBM read is the quantized payload)
-            from hetu_tpu.serving.experts import dequantize_expert_tree
-            spec = self._moe_spec
-            base_decode_fp, base_chunk_fp = decode_fn, chunk_fn
-            base_verify_fp = verify_fn
-
-            def decode_fn(params, pool_tree, table, tokens, positions,
-                          *sample_args):
-                return base_decode_fp(dequantize_expert_tree(params, spec),
-                                      pool_tree, table, tokens, positions,
-                                      *sample_args)
-
-            def chunk_fn(params, chunk, cache, start, row):
-                return base_chunk_fp(dequantize_expert_tree(params, spec),
-                                     chunk, cache, start, row)
-
-            def verify_fn(params, pool_tree, table, tokens, positions,
-                          *sample_args):
-                return base_verify_fp(dequantize_expert_tree(params, spec),
-                                      pool_tree, table, tokens, positions,
-                                      *sample_args)
-
-        if self._numerics:
-            # wrap the programs that contain quantize sites in a
-            # numerics collector; their stats pytree rides out as one
-            # extra output (empty when KV pages are exact).  The
-            # unwrapped functions above ARE the unset-flag programs —
-            # byte-identity by construction.
-            from hetu_tpu.obs import numerics as _numerics
-            base_decode, base_write = decode_fn, write_fn
-            base_verify = verify_fn
-
-            def decode_fn(params, pool_tree, table, tokens, positions,
-                          *sample_args):
-                with _numerics.collecting() as col:
-                    out = base_decode(params, pool_tree, table, tokens,
-                                      positions, *sample_args)
-                    stats = col.finalize()
-                return out + (stats,)
-
-            def verify_fn(params, pool_tree, table, tokens, positions,
-                          *sample_args):
-                with _numerics.collecting() as col:
-                    out = base_verify(params, pool_tree, table, tokens,
-                                      positions, *sample_args)
-                    stats = col.finalize()
-                return out + (stats,)
-
-            def write_fn(pool_tree, pages_row, ks, vs):
-                with _numerics.collecting() as col:
-                    tree = base_write(pool_tree, pages_row, ks, vs)
-                    stats = col.finalize()
-                return tree, stats
-
-        # the pool tree is donated: the KV pool is the engine's dominant
+        # THE table of the engine's programs: `_program` builds each
+        # once, `_dummy_args` / `lower_programs` / `warmup` read it; an
+        # argument a family brings is added at the body and at the call.
+        # The pool tree is donated: the KV pool is the engine's dominant
         # allocation and it flows through every step — without donation
         # XLA would copy the whole pool to update one token per slot
         # (the engine always reassigns self.pool.arrays from the
@@ -1020,56 +922,27 @@ class ServingEngine:
         # tests/test_chip_compile.py holds the compiled program to it).
         # With speculative decoding on, the verify program IS the
         # decode-step program (there is no single-token decode to build).
-        rec = self._recording
-        if self.spec:
-            self._decode_jit = None
-            self._verify_jit = jax.jit(rec(verify_fn), donate_argnums=(1,))
-        else:
-            self._decode_jit = jax.jit(rec(decode_fn), donate_argnums=(1,))
-            self._verify_jit = None
-        # the scratch is donated too, and a carry of the chunk program's
+        # The scratch is donated too, and a carry of the chunk program's
         # layer walk: a launch changes one chunk's tokens a layer where
         # they lie (as an xs -> ys it read and wrote all 201 MB of the
         # InternLM2 cells' scratch; donated but not carried, or carried
-        # but not donated, a copy stayed: PERF.md s6, PR 30)
-        self._chunk_jit = jax.jit(rec(chunk_fn), donate_argnums=(2,))
-        self._write_jit = jax.jit(rec(write_fn), donate_argnums=(0,))
-        self._prime_jit = (jax.jit(rec(prime_fn))
+        # but not donated, a copy stayed: PERF.md s6, PR 30).  The chunk
+        # program has no quantize site to collect; the page write takes
+        # no parameters.
+        self._table = {
+            "verify" if self.spec else "decode": _Program(
+                verify_fn if self.spec else decode_fn, 1,
+                self._null_step_args),
+            "prefill_chunk": _Program(chunk_fn, 2, self._null_chunk_args,
+                                      numerics=False),
+            "write_pages": _Program(write_fn, 0, self._null_write_args,
+                                    takes_params=False),
+        }
+        self._jits = {name: self._program(name) for name in self._table}
+        self._prime_jit = (jax.jit(prime_fn)
                            if self.prefix_cache is not None else None)
 
     # ---------------------------------------------------- numerics taps
-    def _run_decode(self, *args):
-        """Dispatch the decode program, peeling the numerics stats
-        output when the observatory wrapped it."""
-        out = self._decode_jit(*args)
-        if self._numerics:
-            nxt, tree, stats = out
-            self._note_numerics(stats)
-            return nxt, tree
-        return out
-
-    def _run_verify(self, *args):
-        """Dispatch the spec-decode verify program (same numerics
-        peel)."""
-        out = self._verify_jit(*args)
-        if self._numerics:
-            targets, n_emit, tree, stats = out
-            self._note_numerics(stats)
-            return targets, n_emit, tree
-        return out
-
-    def _run_write(self, *args):
-        out = self._write_jit(*args)
-        if self._numerics:
-            tree, stats = out
-            self._note_numerics(stats)
-            return tree
-        return out
-
-    def _note_numerics(self, stats):
-        if stats:
-            self._numerics_stats = stats   # latest wins until recorded
-
     def _maybe_record_numerics(self):
         """Every HETU_TPU_NUMERICS_EVERY engine steps, host-fetch the
         latest stats pytree and fan it out through the one numerics
@@ -1089,42 +962,46 @@ class ServingEngine:
         if self._num_health is not None:
             self._num_health.observe(self.steps_done, host)
 
+    # ------------------------------------- arguments at the null page
+    def _null_step_args(self):
+        """The decode program's or, with speculative decoding, the verify
+        program's: every slot at the null page (a zero table), the
+        sampling vectors of no request."""
+        S, K1 = self.config.num_slots, self.config.spec_k + 1
+        head = (self.params, self.pool.tree(),
+                jnp.zeros(self.scheduler.page_table.shape, jnp.int32))
+        pos = jnp.zeros(S, jnp.int32)
+        sample_args = self._sample_args([]) if self.config.sampling else ()
+        if not self.spec:
+            return (*head, jnp.zeros(S, jnp.int32), pos, self._no_tokens,
+                    *self._stats_args(), *sample_args)
+        vocab = self.model.config.vocab_size
+        return (*head, jnp.zeros((S, K1), jnp.int32), pos,
+                *((jnp.full((S, K1 - 1, vocab), 1.0 / vocab, jnp.float32),)
+                  if self.spec_stochastic else ()), *sample_args)
+
+    def _null_chunk_args(self):
+        # (state layers: the null slot's row, every row the prompt's)
+        C = self.config.prefill_chunk
+        return (self.params, jnp.zeros((1, C), jnp.int32),
+                tuple(self._fresh_scratch()) + self.pool.state,
+                jnp.int32(0), jnp.int32(0),
+                *((jnp.int32(self.pool.null_slot), jnp.int32(C))
+                  if self.stateful else ()), *self._stats_args())
+
+    def _null_write_args(self):
+        return (self.pool.arrays.tree(),
+                jax.tree.map(jnp.asarray, self.scheduler.null_write_rows()),
+                *self._scratch_rows(self._fresh_scratch()))
+
     def _dummy_args(self, program: str):
         """Arguments of the engine's own shapes for one of its programs
         ("decode" | "verify", "prefill_chunk", "write_pages"), all aimed
         at the null page (zero table/row): what `warmup` runs and
         `lower_programs` abstracts."""
-        S, C = self.config.num_slots, self.config.prefill_chunk
-        max_pages = self.scheduler.max_pages
-        stats = self._stats_args()
-        if program == "prefill_chunk":
-            # (state layers: the null slot's row, every row the prompt's)
-            return (self.params, jnp.zeros((1, C), jnp.int32),
-                    tuple(self._fresh_scratch()) + self.pool.state,
-                    jnp.int32(0), jnp.int32(0),
-                    *((jnp.int32(self.pool.null_slot), jnp.int32(C))
-                      if self.stateful else ()), *stats)
-        if program == "write_pages":
-            return (self.pool.arrays.tree(),
-                    jax.tree.map(jnp.asarray,
-                                 self.scheduler.null_write_rows()),
-                    *self._scratch_rows(self._fresh_scratch()))
-        table = jnp.zeros(self.scheduler.page_table.shape, jnp.int32)
-        pos = jnp.zeros(S, jnp.int32)
-        sample_args = self._sample_args([]) if self.config.sampling else ()
-        if program == "decode":
-            return (self.params, self.pool.tree(), table,
-                    jnp.zeros(S, jnp.int32), pos, self._no_tokens, *stats,
-                    *sample_args)
-        if program != "verify":
+        if program not in self._table:
             raise ValueError(f"unknown program {program!r}")
-        K1 = self.config.spec_k + 1
-        extra = ()
-        if self.spec_stochastic:
-            vocab = self.model.config.vocab_size
-            extra = (jnp.full((S, K1 - 1, vocab), 1.0 / vocab, jnp.float32),)
-        return (self.params, self.pool.arrays.tree(), table,
-                jnp.zeros((S, K1), jnp.int32), pos, *extra, *sample_args)
+        return self._table[program].null_args()
 
     def lower_programs(self, sharding=None) -> dict:
         """{program: jax.stages.Lowered} for the decode step ("verify"
@@ -1139,12 +1016,8 @@ class ServingEngine:
                 lambda a: jax.ShapeDtypeStruct(
                     a.shape, a.dtype,
                     sharding=sharding or getattr(a, "sharding", None)), tree)
-        step = "verify" if self.spec else "decode"
-        jits = {step: self._verify_jit if self.spec else self._decode_jit,
-                "prefill_chunk": self._chunk_jit,
-                "write_pages": self._write_jit}
         return {name: fn.lower(*abstract(self._dummy_args(name)))
-                for name, fn in jits.items()}
+                for name, fn in self._jits.items()}
 
     def warmup(self):
         """Compile all three programs so the first request's TTFT is not
@@ -1153,23 +1026,22 @@ class ServingEngine:
         trees are donated through the calls, so the returned trees must
         be committed back (discarding them would leave self.pool.arrays
         pointing at deleted buffers on donating backends)."""
-        if self.spec:
-            nxt, _, tree = self._run_verify(*self._dummy_args("verify"))
-        else:
-            nxt, tree = self._run_decode(*self._dummy_args("decode"))
+        step = "verify" if self.spec else "decode"
+        nxt, *_, tree = self._call(step, *self._dummy_args(step))
+        self.pool.commit(tree)
+        if not self.spec:
             # and as the queued step dispatches it: the tokens of the
             # decode before it an output still on the device
-            self.pool.commit(tree)
             args = list(self._dummy_args("decode"))
             args[5] = nxt
-            nxt, tree = self._run_decode(*args)
-        self.pool.commit(tree)
-        lg, _, cache = self._chunk_jit(
-            *self._dummy_args("prefill_chunk"))[:3]
+            nxt, tree = self._call("decode", *args)
+            self.pool.commit(tree)
+        lg, _, cache = self._call(
+            "prefill_chunk", *self._dummy_args("prefill_chunk"))[:3]
         if self.stateful:
             # (the state arrays were donated behind the scratch)
             self.pool.state = tuple(cache[len(cache) - len(self.pool.state):])
-        tree = self._run_write(*self._dummy_args("write_pages"))
+        tree = self._call("write_pages", *self._dummy_args("write_pages"))
         self.pool.arrays = PoolArrays.from_tree(tree)
         if self._prime_jit is not None:
             jax.block_until_ready(self._prime_jit(
@@ -1256,7 +1128,7 @@ class ServingEngine:
         pages_row = np.full(self.scheduler.max_pages, PagePool.NULL_PAGE,
                             np.int32)
         pages_row[: len(st.pages)] = st.pages
-        tree = self._run_write(self.pool.arrays.tree(),
+        tree = self._call("write_pages", self.pool.arrays.tree(),
                                jnp.asarray(pages_row),
                                jnp.asarray(ks), jnp.asarray(vs))
         self.pool.arrays = PoolArrays.from_tree(tree)
@@ -1618,7 +1490,7 @@ class ServingEngine:
                     self._no_tokens if older is None else older.out[0],
                     *self._stats_args(), *sample_args)
             with phase_span("serve.decode_dispatch", phases):
-                nxt, pool_tree = self._run_decode(*decode_args)
+                nxt, pool_tree = self._call("decode", *decode_args)
                 self.pool.commit(pool_tree)
                 # the stats ride out behind this program's tokens: what
                 # is dispatched after it counts from zero again
@@ -2083,7 +1955,7 @@ class ServingEngine:
                 jnp.asarray(tokens), jnp.asarray(positions), *extra,
                 *sample_args)
         with phase_span("serve.decode_dispatch", phases):
-            targets, n_emit, pool_tree = self._run_verify(*verify_args)
+            targets, n_emit, pool_tree = self._call("verify", *verify_args)
             self.pool.arrays = PoolArrays.from_tree(pool_tree)
         return targets, n_emit
 
@@ -2206,8 +2078,8 @@ class ServingEngine:
             # scratch, and the chunk at position 0 starts the slot's row
             # of them from zeros)
             scratch = tuple(st.prefill_cache)
-            out = self._chunk_jit(
-                self.params, jnp.asarray(ids[None]),
+            out = self._call(
+                "prefill_chunk", self.params, jnp.asarray(ids[None]),
                 scratch + self.pool.state, jnp.int32(s), jnp.int32(row),
                 *((jnp.int32(slot_idx), jnp.int32(len(seg)))
                   if self.stateful else ()), *self._stats_args())
@@ -2243,7 +2115,7 @@ class ServingEngine:
             pages_row = self.scheduler.write_rows(
                 slot_idx, base // self.pool.page_size,
                 bases=self._scratch_bases(s))
-            tree = self._run_write(self.pool.arrays.tree(),
+            tree = self._call("write_pages", self.pool.arrays.tree(),
                                    jax.tree.map(jnp.asarray, pages_row),
                                    *self._scratch_rows(st.prefill_cache))
             self.pool.arrays = PoolArrays.from_tree(tree)

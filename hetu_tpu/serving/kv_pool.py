@@ -272,8 +272,8 @@ class PagePool:
     to the free list as soon as every position it holds lies behind
     `pos - w + 1` (serving/scheduler.py `advance`), its table entry the
     null page from then on: nothing reads there again (the decode
-    kernel starts its walk at the window's first page, the gather route
-    masks)."""
+    kernel starts its walk at the window's first page, the composition
+    over gathered pages masks)."""
 
     NULL_PAGE = 0
 
@@ -509,7 +509,11 @@ class PagePool:
     def gather(self, arrays_tree, table):
         """Dense per-slot cache views from the pool.  table: [S, mp]
         int32 -> (ck, cv) [L, S, mp*page_size, n_kv, hd] in the compute
-        dtype (int8 pages dequantize here)."""
+        dtype (int8 pages dequantize here).  The prefix cache's prime
+        (a shared prefix into a prefill scratch) and the tests'
+        reference; the decode program gathers nothing through it (a
+        layer whose `attend_paged` takes the composition gathers its own
+        pages: models/cache_contract.KVAttention._attend_gathered)."""
         if self.windowed:
             return self._gather_kinds(arrays_tree, table)
         a = PoolArrays.from_tree(arrays_tree)
@@ -546,7 +550,9 @@ class PagePool:
             for pool in arrays_tree[2 * kind: 2 * kind + 2])
 
     def write_token(self, arrays_tree, table, positions, *toks):
-        """Scatter one decoded token's K/V into the pool.  positions:
+        """The tests' reference of `decode_step_paged`'s write (with
+        `gather` and `decode_step_slots`); no program of the engine calls
+        it.  Scatter one decoded token's K/V into the pool.  positions:
         [S] absolute write positions; toks = (k_toks, v_toks), each
         [L, S, n_kv, hd]; by kind of layer (k, v) of [layers of the kind,
         S, ...] a kind, kind after kind, as `decode_step_slots` hands
@@ -581,7 +587,9 @@ class PagePool:
         return PoolArrays(nk, nv, nks, nvs).tree()
 
     def write_tokens(self, arrays_tree, table, positions, k_toks, v_toks):
-        """Scatter a BLOCK of tokens' K/V into the pool — the
+        """The tests' reference of `verify_step_paged`'s write (with
+        `gather` and `verify_step_slots`); no program of the engine calls
+        it.  Scatter a BLOCK of tokens' K/V into the pool — the
         spec-decode verify step's write (k+1 tokens per slot per step).
         positions: [S, C] absolute write positions; k_toks/v_toks:
         [L, S, C, n_kv, hd].  Positions beyond a slot's table row

@@ -4,9 +4,9 @@ batched forward.
 The decode-step cost of a serving engine is HBM-bound: every step reads
 the full parameter set once no matter how many tokens it emits.
 Speculative decoding amortizes that read — a cheap DRAFTER proposes k
-tokens per slot, and ONE `verify_step_slots` forward
-(models/generation.py — the `extend_cache` machinery with per-slot
-depths) scores all k+1 positions.  Accepted drafts emit in bulk; the
+tokens per slot, and ONE `verify_step_paged` forward
+(models/generation.py — the paged decode step over a block of k+1
+queries a slot) scores all k+1 positions.  Accepted drafts emit in bulk; the
 roofline win is provable hardware-free (`roofline_report`, the
 comm/wire.py discipline; bench.py detail.serving records it).
 

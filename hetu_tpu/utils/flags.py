@@ -243,7 +243,7 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
          "speculative decoding (serving/spec_decode.py): ngram drafts "
          "HETU_TPU_SPEC_K tokens per slot per step (prompt-lookup, "
          "host-side, model-free) and ONE batched verify forward "
-         "(models/generation.verify_step_slots) scores all k+1 "
+         "(models/generation.verify_step_paged) scores all k+1 "
          "positions; acceptance is sample-then-match — the exact "
          "rejection rule for a deterministic drafter, so greedy output "
          "is token-identical to sequential generate() and sampled "

@@ -410,15 +410,21 @@ def _serving_engine(cfg=None, num_slots=8, model=None, **serve):
                                max_len=2048, prefill_chunk=128), **serve})
     params = jax.tree.map(lambda a: spec(a.shape, a.dtype),
                           model.abstract_params())
-    engine = ServingEngine(model, params, sc)
-    assert engine.decode_paged
-    return engine
+    return ServingEngine(model, params, sc)
+
+
+def _took_paged_kernel(engine) -> bool:
+    """Every K/V layer the engine's LOWERED programs traced took the
+    paged kernel (the route is a layer's own: `attend_paged`)."""
+    took = engine.kernel_routes["paged_attn"]
+    return bool(took["pallas"]) and not took["xla"]
 
 
 def _gpt_block():
     """Two GPT blocks (LayerNorm, learned positions, biased fused QKV,
     GELU) at 16 heads of 128: the paged kernel's lane gate refuses
-    GPT-2's own head_dim of 64, which the gather route serves."""
+    GPT-2's own head_dim of 64, whose layers take the composition over
+    gathered pages."""
     from hetu_tpu.models.gpt import GPTConfig, GPTLMHeadModel
     return GPTLMHeadModel(GPTConfig(
         vocab_size=50304, hidden_size=2048, num_hidden_layers=2,
@@ -687,6 +693,7 @@ def test_decode_program_updates_the_pool_in_place(kv_quant):
                              kv_quant=kv_quant)
     pool = list(engine.pool.arrays.tree())
     compiled = engine.lower_programs(sharding=ONE_CHIP)["decode"].compile()
+    assert _took_paged_kernel(engine)
     pool_bytes = sum(a.size * a.dtype.itemsize for a in pool)
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 4
 
@@ -740,6 +747,7 @@ def test_serving_programs_move_no_bytes_a_layer_does_not_read():
     assert engine.relaid_weight_bytes == 2_013_265_920
     assert engine.kernel_routes["relaid_weight_bytes"] == 2_013_265_920
     programs = engine.lower_programs(sharding=ONE_CHIP)
+    assert _took_paged_kernel(engine)
     compiled = {name: programs[name].compile()
                 for name in ("decode", "prefill_chunk")}
 

@@ -219,8 +219,7 @@ def test_serving_with_the_chunk_kernel_serves_the_same_tokens(
     own) at head_dim 128: the engine serves the same tokens with the
     chunk kernel forced on (interpret mode) and off, prompts that span
     several chunks and reach past the window; `kernel_routes` counts the
-    chunk program's traced layers on the kernel, and the gather decode
-    route's single queries on the composition."""
+    chunk program's traced layers on the kernel and nothing else."""
     model, params, traced_layers = _model(family)
     vocab = model.config.vocab_size
     rng = np.random.default_rng(2)
@@ -244,8 +243,8 @@ def test_serving_with_the_chunk_kernel_serves_the_same_tokens(
     assert sorted(on) == list(range(len(lens)))
     assert on == off
     assert routes_on["pallas"] == traced_layers and not routes_off["pallas"]
-    # the gather decode route's single queries: the composition, forced
-    # or not
-    assert routes_on["xla"] and all("single query" in w or "forced on" in w
-                                    for w in routes_on["why"])
+    # (the decode program asks nothing of this route: its layers attend
+    # the pages where they lie, `attend_paged`)
+    assert not routes_on["xla"] and all("forced on" in w
+                                        for w in routes_on["why"])
     assert routes_off["xla"] >= traced_layers

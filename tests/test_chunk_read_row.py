@@ -256,7 +256,7 @@ def test_the_chunk_program_holds_the_head_inside_one_conditional(family):
     assert lowered.out_info[0].shape == (1, 1, vocab)
     text = lowered.as_text()
     assert text.count("stablehlo.case") + text.count("stablehlo.if") == 1
-    traced = eng._chunk_jit.trace(*eng._dummy_args("prefill_chunk"))
+    traced = eng._jits["prefill_chunk"].trace(*eng._dummy_args("prefill_chunk"))
     heads = [(eqn, path, in_cond)
              for eqn, path, in_cond in _eqns(traced.jaxpr.jaxpr)
              if eqn.primitive.name == "dot_general" and "lm_head" in path]

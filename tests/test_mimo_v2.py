@@ -465,7 +465,7 @@ def test_gauges_and_counters_read_the_kinds_own_shapes(rng):
                              kind=f"window_{WINDOW}") == want_win
     routes = eng.kernel_routes
     assert "chunk_attn" in routes and "paged_attn" in routes
-    # (a CPU: the composition and the gather route; the lines say the
+    # (a CPU: the composition in both programs; the lines say the
     # widths, the group and whether a sink entered all the same)
     assert sorted(routes["chunk_attn_shapes"]["why"]) == [
         "keys 24 (held in 128) against values 16, groups of 2, a sink a head",

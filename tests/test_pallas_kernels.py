@@ -1126,8 +1126,9 @@ def test_flag_off_train_step_hlo_identical(family, monkeypatch):
 
 
 def test_flag_off_serving_decode_hlo_identical(monkeypatch):
-    """The serving decode program (gather path) is byte-identical with
-    the flag off vs unset — and the engine reports the gather route."""
+    """The serving decode program (every layer's attention the
+    composition over gathered pages) is byte-identical with the flag off
+    vs unset — and the engine's routes say so, a traced layer."""
     from hetu_tpu.serving import ServeConfig, ServingEngine
     model, params = _tiny_llama()
     texts = {}
@@ -1139,16 +1140,17 @@ def test_flag_off_serving_decode_hlo_identical(monkeypatch):
         eng = ServingEngine(model, params,
                             ServeConfig(num_slots=2, page_size=8,
                                         max_len=32, prefill_chunk=8))
-        assert eng.decode_paged is False
-        texts[flag] = eng._decode_jit.lower(
-            *eng._dummy_args("decode")).as_text()
+        texts[flag] = eng.lower_programs()["decode"].as_text()
+        took = eng.kernel_routes["paged_attn"]
+        assert took["xla"] and not took["pallas"]
         eng.close()
     assert texts["0"] == texts[None]
 
 
 def test_serving_paged_decode_token_identical(monkeypatch):
-    """The gather-free Pallas decode program (interpret mode) emits the
-    SAME tokens as the gather path over a multi-request trace — the
+    """The decode program whose layers take the Pallas kernel (interpret
+    mode) emits the SAME tokens as the one whose layers take the
+    composition over gathered pages, over a multi-request trace — the
     PR 7 follow-up contract."""
     import copy
     from hetu_tpu.serving import Request, ServeConfig, ServingEngine
@@ -1160,29 +1162,34 @@ def test_serving_paged_decode_token_identical(monkeypatch):
                     max_new_tokens=5, arrival_t=0.0) for i in range(4)]
     monkeypatch.setenv("HETU_TPU_PALLAS", "0")
     eng0 = ServingEngine(model, params, ServeConfig(**sc))
+    def kernel(eng):
+        """Whether the traced layers took the kernel (all or none)."""
+        took = eng.kernel_routes["paged_attn"]
+        assert bool(took["pallas"]) != bool(took["xla"])
+        return bool(took["pallas"])
     r0 = eng0.run([copy.deepcopy(r) for r in reqs])
-    assert eng0.decode_paged is False
+    assert not kernel(eng0)
     eng0.close()
     monkeypatch.setenv("HETU_TPU_PALLAS", "1")
     eng1 = ServingEngine(model, params, ServeConfig(**sc))
-    assert eng1.decode_paged is True
     r1 = eng1.run([copy.deepcopy(r) for r in reqs])
+    assert kernel(eng1)
     eng1.close()
     assert [r.tokens for r in r0] == [r.tokens for r in r1]
     # int8 page mode routes too (PR 15: in-kernel dequantize closed the
-    # exact-fp-pages-only gap) and matches the int8 GATHER path
-    # token-for-token — both programs quantize through the same
+    # exact-fp-pages-only gap) and matches the int8 composition
+    # token-for-token — the one program quantizes through the same
     # blockwise primitives, so pool contents are bit-identical
     eng2 = ServingEngine(model, params,
                          ServeConfig(kv_quant="int8", **sc))
-    assert eng2.decode_paged is True
     r2 = eng2.run([copy.deepcopy(r) for r in reqs])
+    assert kernel(eng2)
     eng2.close()
     monkeypatch.setenv("HETU_TPU_PALLAS", "0")
     eng3 = ServingEngine(model, params,
                          ServeConfig(kv_quant="int8", **sc))
-    assert eng3.decode_paged is False
     r3 = eng3.run([copy.deepcopy(r) for r in reqs])
+    assert not kernel(eng3)
     eng3.close()
     assert [r.tokens for r in r2] == [r.tokens for r in r3]
 
@@ -1222,14 +1229,19 @@ def _paged_step_case(family, quant):
     return model, params, pool, tree, table
 
 
+@pytest.mark.parametrize("attention", ["kernel", "composition"])
 @pytest.mark.parametrize("step", ["decode", "verify"])
 @pytest.mark.parametrize("quant", ["none", "int8", "int4"])
 @pytest.mark.parametrize("family", ["llama", "gpt"])
-def test_paged_step_writes_the_pool_in_place(family, quant, step):
+def test_paged_step_writes_the_pool_in_place(family, quant, step, attention,
+                                             monkeypatch):
     """One `decode_step_paged` / `verify_step_paged` (the pool a loop
-    carry, layer l's pages at flat ids l * P + p) against the gather
-    path (`decode_step_slots` / `verify_step_slots` over `pool.gather`,
-    then `pool.write_token` / `write_tokens`):
+    carry, layer l's pages at flat ids l * P + p), its layers attending
+    by the Pallas kernel or by the composition over the slot's gathered
+    pages (`KVAttention.attend_paged`'s two routes: exact, int8 and int4
+    pages, one query a slot and a verify block), against the reference
+    over dense views (`decode_step_slots` / `verify_step_slots` over
+    `pool.gather`, then `pool.write_token` / `write_tokens`):
 
       * every pool entry but the written (layer, page, offset) ones is
         untouched, BIT FOR BIT, payload and scale planes alike — a wrong
@@ -1246,6 +1258,10 @@ def test_paged_step_writes_the_pool_in_place(family, quant, step):
       * logits of the active slots match."""
     from hetu_tpu.models import generation as G
     from hetu_tpu.serving.kv_pool import dequantize_heads
+    from hetu_tpu.ops.pallas import record_routes
+    monkeypatch.setenv("HETU_TPU_PALLAS",
+                       "1" if attention == "kernel" else "0")
+    monkeypatch.setenv("HETU_TPU_PALLAS_KERNELS", "paged_attn,paged_verify")
     model, params, pool, tree, table = _paged_step_case(family, quant)
     L, ps, mp = 3, 8, table.shape[1]
     ck, cv = pool.gather(tree, table)
@@ -1256,8 +1272,9 @@ def test_paged_step_writes_the_pool_in_place(family, quant, step):
         ref_logits, _, (kt, vt) = G.decode_step_slots(
             model, params, tokens, (ck, cv), positions)
         ref = pool.write_token(tree, table, positions, kt, vt)
-        logits, new = G.decode_step_paged(
-            model, params, tokens, tree, table, positions)
+        with record_routes() as took:
+            logits, new = G.decode_step_paged(
+                model, params, tokens, tree, table, positions)
     else:
         # slot 0's block crosses into its second page; the inactive
         # slot's runs past the table's reach (positions 30, 31 | 32)
@@ -1268,8 +1285,12 @@ def test_paged_step_writes_the_pool_in_place(family, quant, step):
         ref_logits, _, (kt, vt) = G.verify_step_slots(
             model, params, tokens, (ck, cv), positions)
         ref = pool.write_tokens(tree, table, jnp.asarray(pos_grid), kt, vt)
-        logits, new = G.verify_step_paged(
-            model, params, tokens, tree, table, positions)
+        with record_routes() as took:
+            logits, new = G.verify_step_paged(
+                model, params, tokens, tree, table, positions)
+    took = took["paged_attn" if step == "decode" else "paged_verify"]
+    assert took["pallas" if attention == "kernel" else "xla"] \
+        and not took["xla" if attention == "kernel" else "pallas"]
     assert len(new) == len(tree)
     new, ref, old = ([np.asarray(x) for x in t] for t in (new, ref, tree))
 

@@ -10,7 +10,6 @@ import jax
 import jax.numpy as jnp
 
 from hetu_tpu import serving
-from hetu_tpu.models.cache_contract import KVAttention
 from hetu_tpu.models.generation import generate, prefill, decode_step
 from hetu_tpu.models.llama import LlamaConfig, LlamaLMHeadModel
 from hetu_tpu.obs.metrics import MetricsRegistry
@@ -343,316 +342,7 @@ def test_page_reservation_gates_admission():
     sched.check_invariants()
 
 
-class _HooksOnlyFamily:
-    """A K/V family that lives OUTSIDE the package, defined by the hooks
-    `models/generation.py` lists and nothing else (no Module, no edit
-    under hetu_tpu/): pre-norm blocks of rotary multi-query-group
-    attention and a GELU MLP, the first layer with arrays of its own and
-    the other two STACKED, so `ServingEngine` both calls and scans it.
-    How a query attends the cached K/V is the package's `KVAttention`,
-    as for llama and gpt; its own dense `forward` uses none of it."""
-
-    STATS = ()
-
-    class Attn(KVAttention):
-        def __init__(self, config):
-            self.config = config
-
-        def project(self, p, hn, rope, pos_ids):
-            from hetu_tpu import ops
-            c, (b, s, _) = self.config, hn.shape
-            q = (hn @ p["wq"]).reshape(b, s, c.num_attention_heads,
-                                       c.head_dim)
-            k = (hn @ p["wk"]).reshape(b, s, c.num_key_value_heads,
-                                       c.head_dim)
-            v = (hn @ p["wv"]).reshape(k.shape)
-            return (ops.apply_rotary(q, *rope, pos_ids),
-                    (ops.apply_rotary(k, *rope, pos_ids), v))
-
-        def output(self, p, attn):
-            return attn @ p["wo"]
-
-    class Block:
-        def __init__(self, config):
-            self.attn = _HooksOnlyFamily.Attn(config)
-
-        @staticmethod
-        def input_norm(p, x):
-            return x * jax.lax.rsqrt(
-                jnp.mean(x * x, -1, keepdims=True) + 1e-6) * p
-        post_norm = input_norm
-
-        def mlp_stats(self, p, x):
-            return jax.nn.gelu(x @ p["up"]) @ p["down"], None
-
-    def __init__(self, head_dim=16):
-        import types
-        self.config = c = types.SimpleNamespace(
-            vocab_size=256, hidden_size=2 * head_dim * 2,
-            num_hidden_layers=3, num_attention_heads=4,
-            num_key_value_heads=2, head_dim=head_dim,
-            max_position_embeddings=256, compute_dtype=jnp.float32,
-            use_flash_attention=False)
-        self.block = self.Block(c)
-
-    def init(self, key):
-        c = self.config
-        h, kv = c.hidden_size, c.num_key_value_heads * c.head_dim
-        shapes = {"input_norm": (h,), "post_norm": (h,),
-                  "attn": {"wq": (h, h), "wk": (h, kv), "wv": (h, kv),
-                           "wo": (h, h)},
-                  "mlp": {"up": (h, 2 * h), "down": (2 * h, h)}}
-        keys = iter(jax.random.split(key, 64))
-
-        def make(shape, lead=()):
-            if len(shape) == 1:
-                return jnp.ones(lead + shape, jnp.float32)
-            return 0.05 * jax.random.normal(next(keys), lead + shape)
-
-        def layer(lead=()):
-            return jax.tree.map(lambda sh: make(sh, lead), shapes,
-                                is_leaf=lambda x: isinstance(x, tuple))
-        return {"embed": make((c.vocab_size, h)), "first": layer(),
-                "stack": layer((2,)), "final": make((h,)),
-                "head": make((h, c.vocab_size))}
-
-    # -- the hooks ---------------------------------------------------------
-    def cache_contract(self):
-        from hetu_tpu.models.cache_contract import kv_contract
-        c = self.config
-        return kv_contract(c.num_hidden_layers, c.num_key_value_heads,
-                           c.head_dim, c.compute_dtype)
-
-    def embed_tokens(self, params, ids, pos_ids):
-        return params["embed"][ids]
-
-    def rope_tables(self, max_len):
-        from hetu_tpu import ops
-        return ops.build_rope_cache(max_len, self.config.head_dim, 1e4)
-
-    def serving_layers(self, params):
-        return [(self.block, params["first"], None),
-                (self.block, params["stack"], 2)]
-
-    def final_hidden(self, params, x):
-        return self.Block.input_norm(params["final"], x)
-
-    def logits(self, params, hidden):
-        return hidden @ params["head"]
-
-    def lm_head_weight(self, params):
-        return params["head"]
-
-    # -- its own dense forward: no cache, no hook of attention -------------
-    def forward(self, params, ids):
-        """Logits [s, vocab] of ONE sequence ids [s]."""
-        c, s = self.config, ids.shape[0]
-        g = c.num_attention_heads // c.num_key_value_heads
-        rope = self.rope_tables(s)
-        pos = jnp.arange(s, dtype=jnp.int32)[None]
-        x = params["embed"][ids][None]
-        layers = [params["first"]] + [
-            jax.tree.map(lambda a: a[i], params["stack"]) for i in range(2)]
-        for lp in layers:
-            q, (k, v) = self.block.attn.project(
-                lp["attn"], self.Block.input_norm(lp["input_norm"], x),
-                rope, pos)
-            k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
-            sc = jnp.einsum("bqnd,bknd->bnqk", q, k) * c.head_dim ** -0.5
-            sc = jnp.where(jnp.tril(jnp.ones((s, s), bool)), sc, -jnp.inf)
-            a = jnp.einsum("bnqk,bknd->bqnd", jax.nn.softmax(sc, -1), v)
-            x = x + a.reshape(1, s, -1) @ lp["attn"]["wo"]
-            x = x + self.block.mlp_stats(
-                lp["mlp"], self.Block.input_norm(lp["post_norm"], x))[0]
-        return self.logits(params, self.final_hidden(params, x))[0]
-
-
-class _HooksWindowFamily(_HooksOnlyFamily):
-    """`_HooksOnlyFamily` with layers that differ in how far back they
-    read, written by the hooks alone: the first layer (its own arrays)
-    reads everything, the two STACKED layers read the last `WINDOW`
-    positions (`block.window`, and the contract's `windows`): a scanned
-    run of window layers over pages of their own kind."""
-
-    WINDOW = 12
-
-    def __init__(self, head_dim=16):
-        super().__init__(head_dim)
-        self.window_block = self.Block(self.config)
-        self.window_block.window = self.WINDOW
-        self.window_block.attn_scope = "attn_window"
-
-    def cache_contract(self):
-        from hetu_tpu.models.cache_contract import kv_contract
-        c = self.config
-        return kv_contract(c.num_hidden_layers, c.num_key_value_heads,
-                           c.head_dim, c.compute_dtype,
-                           windows=(None, self.WINDOW, self.WINDOW))
-
-    def serving_layers(self, params):
-        return [(self.block, params["first"], None),
-                (self.window_block, params["stack"], 2)]
-
-    def forward(self, params, ids):
-        c, s = self.config, ids.shape[0]
-        g = c.num_attention_heads // c.num_key_value_heads
-        rope = self.rope_tables(s)
-        pos = jnp.arange(s, dtype=jnp.int32)[None]
-        x = params["embed"][ids][None]
-        layers = [params["first"]] + [
-            jax.tree.map(lambda a: a[i], params["stack"]) for i in range(2)]
-        t, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
-        for i, lp in enumerate(layers):
-            q, (k, v) = self.block.attn.project(
-                lp["attn"], self.Block.input_norm(lp["input_norm"], x),
-                rope, pos)
-            k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
-            sc = jnp.einsum("bqnd,bknd->bnqk", q, k) * c.head_dim ** -0.5
-            seen = (j <= t) & ((j > t - self.WINDOW) if i else True)
-            sc = jnp.where(seen, sc, -jnp.inf)
-            a = jnp.einsum("bnqk,bknd->bqnd", jax.nn.softmax(sc, -1), v)
-            x = x + a.reshape(1, s, -1) @ lp["attn"]["wo"]
-            x = x + self.block.mlp_stats(
-                lp["mlp"], self.Block.input_norm(lp["post_norm"], x))[0]
-        return self.logits(params, self.final_hidden(params, x))[0]
-
-
-def _family_case(family, hd128):
-    """(model, params, dense: (params, ids [s]) -> logits [s, vocab])."""
-    if family in ("hooks", "hooks-window"):
-        model = (_HooksOnlyFamily if family == "hooks"
-                 else _HooksWindowFamily)(128 if hd128 else 16)
-        return model, model.init(jax.random.key(3)), model.forward
-    if family == "trinity":
-        from test_trinity import build, ref_logits
-        cfg, model, params = build(head_dim=128 if hd128 else 16)
-        return model, params, lambda p, ids: ref_logits(p, cfg, ids)
-    if family == "kimi":
-        from test_kimi_k2 import build, ref_logits
-        cfg, model, params = build()
-        return model, params, lambda p, ids: ref_logits(p, cfg, ids)
-    if family == "mimo":
-        from test_mimo_v2 import build, ref_logits
-        cfg, model, params = build(**(dict(head_dim=192, v_head_dim=128)
-                                      if hd128 else {}))
-        return model, params, lambda p, ids: ref_logits(p, cfg, ids)
-    if family == "ling":
-        from test_bailing_hybrid import build, ref_logits
-        cfg, model, params = build()
-        return model, params, lambda p, ids: ref_logits(p, cfg, ids)
-    if family == "gpt":
-        from hetu_tpu.models.gpt import GPTConfig, GPTLMHeadModel
-        kw = dict(hidden_size=256, num_attention_heads=2) if hd128 else {}
-        model = GPTLMHeadModel(GPTConfig.tiny(
-            remat=False, compute_dtype=jnp.float32, **kw))
-    else:
-        kw = dict(hidden_size=256, num_attention_heads=2,
-                  num_key_value_heads=2) if hd128 else {}
-        model = LlamaLMHeadModel(LlamaConfig.tiny(
-            remat=False, compute_dtype=jnp.float32,
-            use_flash_attention=False, use_scan=family != "llama-unstacked",
-            **kw))
-    return (model, model.init(jax.random.key(1)),
-            lambda p, ids: model(p, ids[None])[0])
-
-
 # -------------------------------------------------------------- engine
-@pytest.mark.parametrize("family,route", [
-    ("hooks", "gather"), ("hooks", "paged"),
-    ("llama", "gather"), ("llama", "paged"), ("llama-unstacked", "gather"),
-    ("gpt", "gather"), ("gpt", "paged"),
-    ("kimi", "xla"), ("kimi", "kernel"),
-    ("hooks-window", "gather"), ("hooks-window", "paged"),
-    ("trinity", "gather"), ("trinity", "paged"),
-    ("mimo", "gather"), ("mimo", "paged"),
-    ("ling", "xla"), ("ling", "kernel")])
-def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
-    """Golden, ONE body for every family: staggered continuous batching
-    through the normal path (`run`: scheduler, allocator, page tables,
-    chunked prefill — prompts straddle a page and a chunk — page write,
-    decode) emits, token for token, the argmax of the model's own dense
-    forward given the stream's own prefix (to 2e-4 of logit, what
-    float32 attention in another order may move a near tie by); llama
-    and gpt also `generate()`'s tokens exactly.
-
-    The K/V families over both decode routes the engine has for them:
-    `gather` (dense views of the pages, `decode_step_slots`) and `paged`
-    (the Pallas kernel, interpret mode, walks the page tables:
-    `decode_step_paged`); kimi over its two attentions under the one
-    paged program.  `hooks` is a K/V family defined HERE, outside the
-    package, by the hooks alone: adding an architecture is new files
-    only.  `hooks-window` is that family with two scanned layers that
-    read a window of 12 positions beside one that reads everything, and
-    `trinity` the package's own (window and full layers, each with its
-    own arrays): pages by kind of layer, released behind the window
-    while the request decodes, over both routes.  `mimo` is the family
-    whose kinds of layer also differ in what a token STORES (1 KV head
-    against 2, keys wider than values; 192 / 128 under the kernel), with
-    a sink in the window layers' softmax, a window smaller than a page
-    pair and than the chunk, a sliding prefill scratch and no shared
-    expert.  `ling` is the family with a STATE kind of layer: six
-    linear-attention layers that store nothing a token and a fixed state
-    a sequence, held by slot beside the one latent layer's pages and
-    carried by the chunk and the decode program (`state_chunk`,
-    `state_step`), over the latent layer's two attentions as kimi.
-    `llama-unstacked` is the Llama block built with
-    use_scan=False: a layer's own arrays, called, never scanned."""
-    monkeypatch.setenv("HETU_TPU_PALLAS",
-                       "1" if route in ("paged", "kernel") else "0")
-    if route == "kernel":
-        monkeypatch.setenv("HETU_TPU_PALLAS_KERNELS", "paged_latent")
-    if route == "paged":
-        monkeypatch.setenv("HETU_TPU_PALLAS_KERNELS", "paged_attn")
-    model, params, dense = _family_case(family, hd128=route == "paged")
-    vocab = model.config.vocab_size
-    rng = np.random.default_rng(1)
-    reqs = [Request(rid=i, prompt=rng.integers(0, vocab, size=n)
-                    .astype(np.int32), max_new_tokens=m, arrival_t=t)
-            for i, (n, m, t) in enumerate(
-                [(5, 6, 0.0), (16, 4, 0.0), (23, 6, 0.02), (40, 5, 0.05),
-                 (17, 6, 0.07), (9, 3, 0.3)])]
-    reg = MetricsRegistry()
-    eng = _engine(model, params, registry=reg, num_slots=4, page_size=8,
-                  max_len=128, prefill_chunk=16, num_pages=64)
-    assert eng.decode_paged == (route != "gather")
-    results = {r.rid: r for r in eng.run(reqs)}
-    if family in ("kimi", "ling"):
-        assert eng.kernel_routes["paged_latent"][
-            "pallas" if route == "kernel" else "xla"]
-    if family == "ling":
-        assert eng.stateful and len(eng.pool.state) == 2
-        assert reg.counter_value("serve.state_resets") == len(reqs)
-    assert sorted(results) == list(range(len(reqs)))
-    for req in reqs:
-        toks = np.asarray(results[req.rid].tokens)
-        assert len(toks) == req.max_new_tokens
-        lg = np.asarray(dense(params, jnp.asarray(
-            np.concatenate([req.prompt, toks[:-1]]))))[req.prompt_len - 1:]
-        gap = lg.max(-1) - lg[np.arange(len(toks)), toks]
-        assert (gap <= 2e-4).all(), (req.rid, gap)
-        if family not in ("kimi", "ling") and route == "gather" \
-                and family != "hooks":
-            gold = generate(model, params, jnp.asarray(req.prompt[None]),
-                            max_new_tokens=req.max_new_tokens)
-            assert list(toks) == list(np.asarray(gold)[0, req.prompt_len:])
-    eng.scheduler.check_invariants()
-    assert eng.pool.free_count == eng.pool.num_pages
-    assert reg.counter_value("serve.decode_context_tokens") > 0
-    if eng.windowed:
-        assert reg.counter_value("serve.window_pages_released") > 0
-        assert 0 < reg.counter_value("serve.decode_window_context_tokens") \
-            < reg.counter_value("serve.decode_context_tokens")
-    if model.STATS:
-        # the programs' stats came back with the tokens
-        n = {k[len("serve.moe_"):]: reg.counter_value(k)
-             for k, _ in model.STATS}
-        assert n["extra_row_blocks"] >= 0
-        assert n["layer_steps"] > 0 \
-            and n["assignments"] > n["local_assignments"]
-        assert n["expert_hits"] <= 4 * n["layer_steps"]
-        assert 0 < n["max_expert_load"] <= 16 * 4
-
-
 def test_chunked_prefill_interleaves_with_decode(tiny_llama):
     """Prefill/decode disaggregation contract: a multi-chunk prompt
     advances ONE chunk per engine step while already-running slots keep
@@ -869,3 +559,63 @@ def test_acceptance_16_requests_staggered(tiny_llama, tmp_path):
     summary = tools_obs_report.summarize(records)
     assert summary["serving"]["requests_done"] == 16
     assert summary["serving"]["ttft_s"]["p95"] is not None
+
+
+# ------------------------------------------- one builder of every program
+@pytest.mark.parametrize("case", ["plain", "numerics", "spec",
+                                  "spec-numerics", "experts-numerics"])
+def test_a_program_is_its_body_under_one_builder(case, monkeypatch):
+    """`ServingEngine._program` builds each program of the engine's table
+    once: whatever it wraps a body in (resident int8 experts dequantized
+    on entry, the numerics collector, both at once), the module a device
+    trace finds the program by keeps the BODY's name (`jit_decode_fn`,
+    `jit_chunk_fn`, `jit_write_fn`, `jit_verify_fn`: benchmarks/metrics
+    read `"program": "decode_fn"`), the null-page arguments of the table
+    lower and run, and the tokens are the unwrapped engine's.
+
+    (Resident experts beside a model that carries `STATS` — the arity
+    the wrappers of old got wrong — is reached by no configuration: the
+    resident store reads `config.num_experts`, which only the Llama
+    block has, and its `STATS` is empty; the families that count,
+    Trinity, MiMo, Kimi and Ling, name their experts otherwise and keep
+    them as they come.  The one builder passes `*args` through, so there
+    is no arity left to hold.)"""
+    if "numerics" in case:
+        monkeypatch.setenv("HETU_TPU_NUMERICS", "1")
+    moe = dict(num_experts=4, moe_top_k=2, moe_capacity_factor=4.0) \
+        if "experts" in case else {}
+    model = LlamaLMHeadModel(LlamaConfig.tiny(
+        remat=False, compute_dtype=jnp.float32, use_flash_attention=False,
+        **moe))
+    params = model.init(jax.random.key(0))
+    kw = dict(kv_quant="int8")
+    if "spec" in case:
+        kw.update(spec_decode="ngram", spec_k=2)
+    if moe:
+        kw.update(moe_dispatch="int8")
+    eng = _engine(model, params, **kw)
+    assert (eng._moe_spec is not None) == bool(moe)
+    step = "verify" if "spec" in case else "decode"
+    bodies = {step: f"{step}_fn", "prefill_chunk": "chunk_fn",
+              "write_pages": "write_fn"}
+    lowered = eng.lower_programs()
+    assert sorted(lowered) == sorted(bodies)
+    for name, body in bodies.items():
+        assert f"module @jit_{body} " in lowered[name].as_text()[:200], name
+    reqs = lambda: [Request(  # noqa: E731
+        rid=i, prompt=np.arange(1, 6 + 3 * i, dtype=np.int32),
+        max_new_tokens=4) for i in range(3)]
+    got = [r.tokens for r in eng.warmup().run(reqs())]
+    assert all(len(t) == 4 for t in got)
+    if "numerics" in case:
+        # the collector's stats ride out last (`_call` peels them): the
+        # page write's hold the int8 pages' round-trip error
+        assert eng._numerics
+        tree, stats = lowered["write_pages"].out_info
+        assert len(tree) == 4 and "kv_pages" in str(stats)
+        assert len(lowered[step].out_info) == 2
+        assert len(lowered["prefill_chunk"].out_info) == 3
+        monkeypatch.setenv("HETU_TPU_NUMERICS", "0")
+        plain = _engine(model, params, **kw)
+        assert not plain._numerics
+        assert got == [r.tokens for r in plain.run(reqs())]

@@ -221,6 +221,13 @@ def hd128_llama():
     return model, model.init(jax.random.key(2))
 
 
+def _kernel_took(eng, name: str) -> bool:
+    """Every layer the engine's programs traced took the kernel `name`
+    (the route is a layer's own, asked when its program is traced)."""
+    took = eng.kernel_routes[name]
+    return bool(took["pallas"]) and not took["xla"]
+
+
 def test_spec_decode_fused_kernels_greedy_identity(hd128_llama,
                                                    monkeypatch):
     """The tentpole acceptance golden: greedy speculative decoding
@@ -232,8 +239,9 @@ def test_spec_decode_fused_kernels_greedy_identity(hd128_llama,
     vocab = model.config.vocab_size
     reqs = _reqs(vocab, n=4, seed=13, max_new=6)
     eng = _engine(model, params, spec_decode="ngram", spec_k=3)
-    assert eng.decode_paged and eng.verify_fused_sample
+    assert eng.verify_fused_sample
     res = eng.run(reqs)
+    assert _kernel_took(eng, "paged_verify")
     eng.scheduler.check_invariants()
     for r in reqs:
         out = generate(model, params, jnp.asarray(r.prompt)[None],
@@ -255,11 +263,12 @@ def test_spec_decode_fused_kernels_int8_matches_gather(hd128_llama,
     vocab = model.config.vocab_size
     spec = _engine(model, params, kv_quant="int8", spec_decode="ngram",
                    spec_k=3)
-    assert spec.decode_paged and spec.verify_fused_sample
+    assert spec.verify_fused_sample
     r1 = spec.run(_reqs(vocab, n=4, seed=13, max_new=6))
     base = _engine(model, params, kv_quant="int8")
-    assert base.decode_paged
     r2 = base.run(_reqs(vocab, n=4, seed=13, max_new=6))
+    assert _kernel_took(spec, "paged_verify") \
+        and _kernel_took(base, "paged_attn")
     for a, b in zip(r1, r2):
         assert a.tokens == b.tokens, a.rid
 
@@ -389,7 +398,8 @@ def test_stochastic_verify_analytic_acceptance():
 # ---------------------------------------------------------------- int4 KV
 def test_int4_kv_engine_decode(hd128_llama, monkeypatch):
     """int4 KV end to end: the engine decodes over nibble-packed pages
-    on both the gather path and the paged kernels, and each path is a
+    by the composition over gathered pages and by the paged kernels,
+    and each is a
     pure function of the request (restart/slot-shape invariant).  Token
     parity vs fp32 is deliberately NOT asserted — int4 is a lossy
     cache; the documented tolerance is pinned at the kernel-vs-dense
@@ -407,8 +417,9 @@ def test_int4_kv_engine_decode(hd128_llama, monkeypatch):
     spec = _engine(model, params, kv_quant="int4", spec_decode="ngram",
                    spec_k=3)
     # the int4 pool (packed head_dim 64) routes the int4 kernels
-    assert spec.decode_paged and spec.verify_fused_sample
+    assert spec.verify_fused_sample
     k1 = spec.run(mk())
+    assert _kernel_took(spec, "paged_verify")
     spec.scheduler.check_invariants()
     k2 = _engine(model, params, kv_quant="int4", num_slots=2,
                  spec_decode="ngram", spec_k=3).run(mk())

@@ -28,7 +28,8 @@ from hetu_tpu.obs.metrics import MetricsRegistry
 from hetu_tpu.parallel.strategy import ParallelStrategy
 from hetu_tpu.serving.engine import serving_view
 from hetu_tpu.serving.request import Request
-from test_serving import _HooksOnlyFamily, _engine
+from test_serving import _engine
+from test_serving_families import _HooksOnlyFamily
 
 #: (q heads a kv head, kv heads): MHA, GQA, and one kv head for all
 HEADS = [(g, n_kv) for g in (1, 2, 4) for n_kv in (1, 8)]
@@ -152,7 +153,7 @@ def test_chunked_prefill_over_the_view_equals_prefill_whole():
     for a, b in zip(got, cache):
         np.testing.assert_allclose(np.asarray(a[:, :, :24]),
                                    np.asarray(b[:, :, :24]), atol=2e-5)
-    # the verify step (the gather route's speculative block) too
+    # the verify step's reference over a dense cache too
     lv, _, kv = verify_step_slots(model, view, ids[:, 16:], got,
                                   jnp.array([16]))
     lt, _, kt = verify_step_slots(model, params, ids[:, 16:], got,
@@ -282,15 +283,15 @@ def test_every_admission_advances_zeros_of_its_own():
     ids = jnp.asarray(np.arange(8, dtype=np.int32)[None] + 1)
     a, b = eng._fresh_scratch(), eng._fresh_scratch()
     assert all(not np.asarray(x).any() for x in a + b)
-    _, _, a2 = eng._chunk_jit(eng.params, ids, a, jnp.int32(0), jnp.int32(0))
+    _, _, a2 = eng._jits["prefill_chunk"](eng.params, ids, a, jnp.int32(0), jnp.int32(0))
     assert all(np.asarray(x[:, :, :8]).any() for x in a2)
     assert all(not np.asarray(x[:, :, 8:]).any() for x in a2)
     # the other prefill's buffer and the next admission's are untouched
     assert all(not np.asarray(x).any() for x in b)
     assert all(not np.asarray(x).any() for x in eng._fresh_scratch())
-    _, _, b2 = eng._chunk_jit(eng.params, ids + 9, b, jnp.int32(0),
+    _, _, b2 = eng._jits["prefill_chunk"](eng.params, ids + 9, b, jnp.int32(0),
                                jnp.int32(0))
-    _, _, a3 = eng._chunk_jit(eng.params, ids + 20, a2, jnp.int32(8),
+    _, _, a3 = eng._jits["prefill_chunk"](eng.params, ids + 20, a2, jnp.int32(8),
                                jnp.int32(0))
     for x, y in zip(a3, b2):
         assert not np.array_equal(np.asarray(x[:, :, :8]),
@@ -354,9 +355,10 @@ def test_a_radix_primed_prompt_gives_the_tokens_it_gave():
 
 
 def test_speculative_verify_gives_the_tokens_it_gave():
-    """The gather route's verify step (`verify_step_slots`: the dense
-    cache as the same carry) accepts to the same greedy tokens as plain
-    decoding, over the view and over the training layout."""
+    """The verify step (`verify_step_paged`, the block's layers
+    attending the slot's gathered pages by the composition here) accepts
+    to the same greedy tokens as plain decoding, over the view and over
+    the training layout."""
     model, params = _model()
     plain, _ = _model(TrainingLayoutLlama)
     gold = _tokens(_engine(model, params), _requests(256))

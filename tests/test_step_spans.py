@@ -888,9 +888,10 @@ def test_scope_map_decode_program(monkeypatch):
         model, model.abstract_params(),
         serving.ServeConfig(num_slots=8, page_size=8, max_len=64,
                             prefill_chunk=8), registry=MetricsRegistry())
-    assert engine.decode_paged
     texts = {name: low.compile().as_text()
              for name, low in engine.lower_programs().items()}
+    took = engine.kernel_routes["paged_attn"]
+    assert took["pallas"] and not took["xla"]
     decode = hp.scope_map(texts["decode"])
     names = _instruction_names(texts["decode"])
     assert len(names) == len(set(names)) == len(decode)

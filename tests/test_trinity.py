@@ -453,12 +453,34 @@ def test_the_prefill_tier_refuses_window_layers():
                                     max_new_tokens=2), None, None, 0, 0.0)
 
 
-def test_a_verify_block_under_a_window_is_refused():
+def test_a_verify_block_under_a_window_takes_the_composition(monkeypatch,
+                                                            rng):
+    """The kernels have no window over a block of queries: on a TPU the
+    layer's own gate says so and the layer attends its gathered pages by
+    the composition, which gives each query of the block what the
+    single-token step gives it alone at its position."""
+    from hetu_tpu.ops.pallas import record_routes
     attn = KVAttention()
-    with pytest.raises(NotImplementedError, match="verify"):
-        attn.attend_paged(None, jnp.zeros((2, 3, 4, 128)), (None, None),
-                          jnp.zeros((2, 4), jnp.int32),
-                          jnp.zeros(2, jnp.int32), 0, window=8)
+    S, C, P, ps, mp = 2, 3, 9, 8, 4
+    pools = tuple(jnp.asarray(rng.standard_normal((2 * P, ps, 2, 128)),
+                              jnp.float32) for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((S, C, 4, 128)), jnp.float32)
+    table = jnp.asarray([[3, 5, 1, 0], [2, 4, 6, 8]], jnp.int32)
+    positions = jnp.asarray([9, 20], jnp.int32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with record_routes() as routes:
+        block = attn.attend_paged(None, q, pools, table, positions, P,
+                                  window=8)
+    monkeypatch.undo()
+    took = routes["paged_verify"]
+    assert took["xla"] == 1 and not took["pallas"]
+    assert "the verify block is not built for them" in list(took["why"])[0]
+    monkeypatch.setenv("HETU_TPU_PALLAS", "0")
+    for i in range(C):
+        one = attn.attend_paged(None, q[:, i: i + 1], pools, table,
+                                positions + i, P, window=8)
+        np.testing.assert_allclose(np.asarray(block[:, i]),
+                                   np.asarray(one[:, 0]), atol=1e-6)
 
 
 # ------------------------------------------------- gauges, routes, scopes
@@ -476,8 +498,8 @@ def test_gauges_routes_and_scopes_tell_the_kinds_of_layer_apart(monkeypatch):
                            kind=f"window_{WINDOW}") == 5 * per_layer
     compiled = {k: v.compile() for k, v in eng.lower_programs().items()}
     groups = {g for g, _ in hp.scope_map(compiled["decode"]).values()}
-    # (the gather route's decode program, which is what a CPU runs)
-    assert {"layer/attn_window", "layer/attn_full", "kv_write",
+    # (the one decode program; on a CPU its layers take the composition)
+    assert {"layer/attn_window", "layer/attn_full", "layer/kv_write",
             "layer/router", "layer/experts", "layer/shared_expert",
             "layer/mlp", "lm_head"} <= groups
     assert {"layer/attn_window", "layer/attn_full"} <= {

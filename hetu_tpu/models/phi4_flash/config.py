@@ -116,9 +116,8 @@ class Phi4FlashConfig:
 
     @property
     def state_shapes(self):
-        """What a sequence stores in a Mamba layer: the float32 state,
-        the channels in the lanes, and the convolution's last
-        `mamba_d_conv` - 1 inputs."""
-        return (((self.mamba_d_state, self.d_inner), "float32"),
-                ((self.mamba_d_conv - 1, self.d_inner),
-                 jnp.dtype(self.compute_dtype).name))
+        """What a sequence stores in a Mamba layer
+        (`nn/mamba.state_shapes`)."""
+        from hetu_tpu.nn.mamba import state_shapes
+        return state_shapes(self.d_inner, self.mamba_d_state,
+                            self.mamba_d_conv, self.compute_dtype)

@@ -12,17 +12,14 @@ One layer, pre-norm (LayerNorm with bias), x one token's normed hidden
 state; every layer ends in the same bias-free SwiGLU MLP; no positional
 encoding of any kind; embedding and head tied.
 
-* Mamba-1 (`MambaMixer`; layers 0, 2, .., L/2): [u, z] = x W_in; u' =
-  silu(conv_K(u) + b_c), causal and depthwise; [dt_r, B_t, C_t] = u' W_x;
-  Delta_t = softplus(dt_r W_dt + b_dt); A = -exp(A_log);
-  h_t = exp(Delta_t A) h_{t-1} + (Delta_t u'_t) B_t^T; y_t = h_t C_t +
-  D u'_t; out = W_out [y_t * silu(z_t)].  **A SEQUENCE's cache is h
-  (float32, [d_state, d_inner]: the channels in the lanes) and the
-  convolution's last K - 1 inputs; a token stores nothing** (the
-  contract's `state_shapes`): `state_chunk` / `state_step`, over
-  `ops/selective_scan`.  The hooks return y_t third: layer L/2, the
-  block that says `hands_on`, hands it to the later layers as the MEMORY
-  (before the gate).
+* Mamba-1 (layers 0, 2, .., L/2): `nn/mamba.MambaMixer`, the mixer
+  this family shares with models/jamba, without inner norms; its
+  equations, its state a SEQUENCE (h float32 [d_state, d_inner] and the
+  convolution's last K - 1 inputs; a token stores nothing: the
+  contract's `state_shapes`) and its scopes are written there.  Its
+  hooks return the scan's output y_t third: layer L/2, the block that
+  says `hands_on`, hands it to the later layers as the MEMORY (before
+  the gate).
 * Gated memory unit (`GatedMemoryUnit`; layers L/2 + 2, + 4, ..):
   out = W_2 [m_t * silu(x W_1)], m_t the memory AT THE SAME TOKEN.  No
   recurrence, no cache (the contract's `NO_CACHE`): the `mix` hook.
@@ -62,7 +59,6 @@ encoding of any kind; embedding and head tied.
 """
 from __future__ import annotations
 
-import math
 from typing import Optional
 
 import jax
@@ -74,130 +70,22 @@ from hetu_tpu.models.cache_contract import (NO_CACHE, CacheContract,
 from hetu_tpu.models.phi4_flash.config import (CROSS, FULL, GMU, SSM, WINDOW,
                                                Phi4FlashConfig)
 from hetu_tpu.nn import initializers as init
+from hetu_tpu.nn.mamba import MambaMixer
 from hetu_tpu.nn.module import Module, stack_param_specs
 from hetu_tpu.nn.parallel import ParallelLayerNorm, VocabParallelEmbedding
-from hetu_tpu.ops import selective_scan
 from hetu_tpu.parallel.strategy import ParallelStrategy
 
 F32 = jnp.float32
 
 
-def _dt_bias(key, shape, dtype=F32):
-    """b_dt with softplus(b_dt) log-uniform in [0.001, 0.1], as Mamba
-    initialises it."""
-    dt = jnp.exp(jax.random.uniform(key, shape, F32)
-                 * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
-    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
-
-
-def _a_log(key, shape, dtype=F32):
-    """A_log [d_state, d_inner] = log(1 .. d_state) a channel."""
-    return jnp.broadcast_to(
-        jnp.log(jnp.arange(1, shape[0] + 1, dtype=F32))[:, None],
-        shape).astype(dtype)
-
-
-class MambaMixer(Module):
-    """The Mamba-1 mixer (module docstring).  Its hooks take the layer's
-    whole attention: normed hidden states in, the residual's addend out,
-    the sequence's state in and out, and the scan's output y third."""
-
-    def __init__(self, config: Phi4FlashConfig):
-        super().__init__()
-        self.config = c = config
-        w = init.normal(c.initializer_range)
-        h, di, N, K, R, dt = (c.hidden_size, c.d_inner, c.mamba_d_state,
-                              c.mamba_d_conv, c.mamba_dt_rank, c.param_dtype)
-        self.param("w_in", (h, 2 * di), w, dtype=dt)          # u | z
-        # tap i multiplies the input i - (K - 1) positions back
-        self.param("conv_w", (K, di), init.uniform(K ** -0.5), dtype=dt)
-        self.param("conv_b", (di,), init.normal(0.1), dtype=dt)
-        self.param("w_x", (di, R + 2 * N), w, dtype=dt)       # dt_r | B | C
-        self.param("w_dt", (R, di), init.uniform(R ** -0.5), dtype=dt)
-        # float32 whatever the model's dtype: the step is exponentiated
-        # over thousands of positions
-        self.param("dt_bias", (di,), _dt_bias, dtype=F32)
-        self.param("A_log", (N, di), _a_log, dtype=F32)
-        self.param("D", (di,), init.ones, dtype=F32)
-        self.param("w_out", (di, h), w, dtype=dt)
-
-    def _inputs(self, params, hn, conv):
-        """hn [b, s, hidden], conv [b, K - 1, d_inner] (the inputs before
-        the first position) -> (u' [b, s, di], z, Delta float32, B, C
-        [b, s, N], the inputs [b, K - 1 + s, di])."""
-        c = self.config
-        di, N, R, K = c.d_inner, c.mamba_d_state, c.mamba_dt_rank, \
-            c.mamba_d_conv
-        s = hn.shape[1]
-        with jax.named_scope("ssm_proj"):
-            uz = hn @ params["w_in"].astype(hn.dtype)
-            u, z = uz[..., :di], uz[..., di:]
-        with jax.named_scope("ssm_conv"):
-            xx = jnp.concatenate([conv.astype(u.dtype), u], axis=1)
-            w = params["conv_w"].astype(F32)
-            y = sum(w[i] * xx[:, i: i + s].astype(F32) for i in range(K))
-            u1 = jax.nn.silu(y + params["conv_b"].astype(F32)).astype(
-                hn.dtype)
-        with jax.named_scope("ssm_proj"):
-            x = u1 @ params["w_x"].astype(hn.dtype)
-            delta = jax.nn.softplus(
-                (x[..., :R] @ params["w_dt"].astype(hn.dtype)).astype(F32)
-                + params["dt_bias"])
-        return u1, z, delta, x[..., R: R + N], x[..., R + N:], xx
-
-    def _out(self, params, y, z):
-        with jax.named_scope("ssm_out"):
-            g = y * jax.nn.silu(z.astype(F32))
-            return g.astype(z.dtype) @ params["w_out"].astype(z.dtype)
-
-    # -- the hooks (models/generation.py) ---------------------------------
-    def state_chunk(self, params, hn, state, start, valid):
-        """hn [b, C, hidden] (normed); state = (h [b, N, di] float32,
-        conv [b, K - 1, di]): the rows' own, as the last chunk left them
-        (zeros where this is the first).  The first valid[b] positions
-        are the sequence's; the rest are padding, which the scan leaves
-        out of the state (`chunk_scan`'s `valid`), and the convolution's
-        tail is taken where the valid rows end.
-        -> (out [b, C, hidden], state', y [b, C, di] in hn's dtype)."""
-        h, conv = state
-        K = self.config.mamba_d_conv
-        u1, z, delta, B, C, xx = self._inputs(params, hn, conv)
-        with jax.named_scope("ssm_conv"):
-            conv = jax.vmap(lambda a, n: lax.dynamic_slice_in_dim(
-                a, n, K - 1, axis=0))(xx, valid).astype(conv.dtype)
-        with jax.named_scope("ssm_scan"):
-            y, h = selective_scan.chunk_scan(
-                h, u1, delta, -jnp.exp(params["A_log"]), B, C, params["D"],
-                valid=valid)
-        return self._out(params, y, z), (h, conv), y.astype(hn.dtype)
-
-    def state_step(self, params, hn, state, live):
-        """One position a row: hn [b, 1, hidden]; rows where `live` [b]
-        is False (idle slots) leave their state as it is.
-        -> (out [b, 1, hidden], state', y [b, 1, di])."""
-        h, conv = state
-        u1, z, delta, B, C, xx = self._inputs(params, hn, conv)
-        with jax.named_scope("ssm_conv"):
-            conv = jnp.where(live[:, None, None], xx[:, 1:],
-                             conv.astype(xx.dtype)).astype(conv.dtype)
-        with jax.named_scope("ssm_step"):
-            y, h = selective_scan.step(
-                h, u1[:, 0], delta[:, 0], -jnp.exp(params["A_log"]),
-                B[:, 0], C[:, 0], params["D"], live=live)
-        y = y[:, None]
-        return self._out(params, y, z), (h, conv), y.astype(hn.dtype)
-
-    def zero_state(self, b: int, dtype):
-        return tuple(jnp.zeros((b,) + shape, dt if dt == "float32" else dtype)
-                     for shape, dt in self.config.state_shapes)
-
-    def forward(self, params, hn):
-        """Whole sequences hn [b, s, h] from zero state -> (out, y)."""
-        b, s = hn.shape[:2]
-        out, _, y = self.state_chunk(
-            params, hn, self.zero_state(b, hn.dtype),
-            jnp.zeros((b,), jnp.int32), jnp.full((b,), s, jnp.int32))
-        return out, y
+def _mamba(c: Phi4FlashConfig) -> MambaMixer:
+    """The shared Mamba-1 mixer at this configuration's sizes, without
+    inner norms."""
+    return MambaMixer(c.hidden_size, c.d_inner, c.mamba_d_state,
+                      c.mamba_d_conv, c.mamba_dt_rank,
+                      param_dtype=c.param_dtype,
+                      compute_dtype=c.compute_dtype,
+                      initializer_range=c.initializer_range)
 
 
 class GatedMemoryUnit(Module):
@@ -385,7 +273,7 @@ class Phi4Block(Module):
         self.takes_handed = mixer == GMU
         norm = dict(eps=c.layer_norm_eps, param_dtype=c.param_dtype)
         self.input_norm = ParallelLayerNorm(c.hidden_size, strategy, **norm)
-        self.attn = (MambaMixer(c) if mixer == SSM else
+        self.attn = (_mamba(c) if mixer == SSM else
                      GatedMemoryUnit(c) if mixer == GMU else
                      DiffAttention(c, self.window, cross=mixer == CROSS))
         self.post_norm = ParallelLayerNorm(c.hidden_size, strategy, **norm)

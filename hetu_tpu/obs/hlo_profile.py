@@ -169,8 +169,9 @@ def group_of(op_name: str, phases: Tuple[str, ...] = PHASES) -> str:
 #: state's rows written back in place); the attention of a layer that
 #: keeps no cache and reads another layer's pages (`attn_cross`), a
 #: Mamba-1 layer and its parts (`attn` > `ssm` > `ssm_proj`, `ssm_conv`,
+#: `ssm_norm` where the family norms dt, B and C: models/jamba,
 #: `ssm_scan` in the chunk program / `ssm_step` in the decode program,
-#: `ssm_out`), a gated memory unit (`gmu`: models/phi4_flash), and what
+#: `ssm_out`: nn/mamba), a gated memory unit (`gmu`: models/phi4_flash), and what
 #: the chunk program runs for the rows whose logits are read alone and
 #: that no inner scope names (`tail`: models/generation.extend_cache; a
 #: layer's own scopes inside the tail keep their groups).  `diff_out`,
@@ -184,7 +185,7 @@ SCOPE_MAP_GROUPS = ("kv_write", "loss", "mla_q", "mla_kv", "mla_out",
                     "kda", "kda_proj", "kda_conv", "kda_scan", "kda_step",
                     "kda_out",
                     "attn_cross", "ssm", "ssm_proj", "ssm_conv", "ssm_scan",
-                    "ssm_step", "ssm_out", "gmu", "tail")
+                    "ssm_step", "ssm_out", "gmu", "tail", "ssm_norm")
 UNSCOPED = "unscoped"
 _INSTR_PAT = re.compile(r'^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=')
 _OPERAND_PAT = re.compile(r'%([\w.\-]+)')

@@ -50,7 +50,8 @@ slab is `[M, n_kv, hd]`: Mosaic's DMA slices no single head out of a
 packed bfloat16 `[n_kv, hd]` tile, so the slab is relaid first, by a
 small kernel of this module (`_relay_heads`: a block of positions in,
 each head's rows out, the LIVE blocks only; a load `ref[:, h, :]` is what
-Mosaic does take).  Left to XLA as a `transpose`, the relayout became a
+Mosaic does take; ONE K/V head needs none: `[M, 1, hd]` is `[1, M, hd]`
+by a reshape).  Left to XLA as a `transpose`, the relayout became a
 bitcast of a head-major COPY OF THE WHOLE SCRATCH of every layer (84 MB,
 four times a Trinity chunk: compiled for a described v5e and measured,
 PR 35), because a slice at a constant layer index hands its layout up to
@@ -371,7 +372,11 @@ def chunk_attention(q, k, v, start, *, softmax_scale: Optional[float] = None,
     # one KV head's group of query heads as one tall operand
     qh = q[0].reshape(C, n_kv, g, hd).transpose(1, 2, 0, 3) \
         .reshape(n_kv, R, hd)
-    kh, vh = _relay_heads(scalars, k[0], v[0], kb)
+    if n_kv == 1:
+        # one K/V head: the slab [M, 1, hd] IS head-major
+        kh, vh = k[0].reshape(1, M, hd), v[0].reshape(1, M, hd_v)
+    else:
+        kh, vh = _relay_heads(scalars, k[0], v[0], kb)
 
     def key_block(h, r, j, s):
         return h, s[0] + jnp.minimum(j, s[1] - 1), 0

@@ -101,12 +101,26 @@ they start at -1e30 and 0 without one; the blocks' updates are the same.
 With neither, the lowered program is what it was, operation for
 operation.
 
+**One K/V head** (multi-query attention: exact pages).  A token's row
+`[1, hd]` of two-byte elements is under one 32-bit word, and Mosaic
+slices no page `[page_size, 1, hd]` out of such a pool (this module
+refused the shape until PR 47).  But with one head the head axis says
+nothing: the pool `[P, page_size, 1, hd]` IS `[P, page_size, hd]` (XLA
+holds the four-dim array with the unit axis outermost of the page, at
+its own bytes: no second head of zeros, and the reshape is a bitcast),
+a page is a dense `[page_size, hd]` tile, and a block of the walk is by
+a free reshape the same flat axis of T keys the kernel scores anyway
+(column j = token j, KV head 0: `n_kv` = 1 in every mask).  So the
+wrapper hands the kernel the three-dim view and buffers of `[2,
+pages_per_block, page_size, hd]`; the walk, the block update and the
+masks are what they are for any `n_kv`, and any group of query heads
+(20 to the one head: no power of two) is the tall operand's rows.  The
+pool, the contract's stored shape and the page write keep `[.., 1, hd]`.
+
 Shape contract (drift-tested against `compatible`/`verify_compatible`):
 key and value head dims % 128, q heads divide by kv heads, table/positions/q agree on the
 slot count, scales present iff quant, pool head dim halved for int4,
-one page's buffers and temporaries within `_VMEM_LIMIT`, and a page's
-row of KV heads at least one 32-bit word wide (bfloat16 pages of ONE
-KV head are refused: Mosaic cannot slice such a page out of the pool)."""
+and one page's buffers and temporaries within `_VMEM_LIMIT`."""
 from __future__ import annotations
 
 import functools
@@ -127,6 +141,12 @@ NEG_INF = -1e30
 # take of the 16 MiB a kernel gets by default, and what ONE page may need
 # before the shape gate sends the shape to the gather fallback.
 _BLOCK_TOKENS = 256
+# ... and with ONE K/V head, whose token is one key of a block's flat
+# axis where the serving cells' is 2 to 8: the same 2,048 keys a block.
+# On a v5e, 128 slots of 300-4,400 tokens, 20 query heads (my chip run,
+# PR 47): blocks of 256 / 512 / 1,024 / 2,048 / 4,096 tokens take 0.60 /
+# 0.40 / 0.31 / 0.28 / 0.29 ms, 31 / 47 / 60 / 66 / 63% of the bytes' floor.
+_BLOCK_TOKENS_ONE_HEAD = 2048
 _VMEM_BUDGET = 6 << 20
 _VMEM_LIMIT = 12 << 20
 
@@ -146,13 +166,21 @@ def _token_vmem_bytes(rows: int, n_kv: int, hd: int, itemsize: int,
     return nbytes
 
 
+def _one_head(n_kv: int, quant: str) -> bool:
+    """Exact pages of ONE K/V head: read as `[page_size, hd]` tiles
+    (module docstring, "One K/V head")."""
+    return n_kv == 1 and quant == "none"
+
+
 def pages_per_block(rows: int, ps: int, n_kv: int, hd: int, itemsize: int,
                     max_pages: int, quant: str = "none",
                     hd_v: Optional[int] = None) -> int:
     """Pages the walk fetches and attends at once, from the shapes alone:
-    `_BLOCK_TOKENS` tokens where `_VMEM_BUDGET` holds them, fewer where
-    it does not, never more than the table is wide, at least one."""
-    tokens = min(_BLOCK_TOKENS, _VMEM_BUDGET // _token_vmem_bytes(
+    `_BLOCK_TOKENS` tokens (`_BLOCK_TOKENS_ONE_HEAD` of one K/V head's
+    exact pages) where `_VMEM_BUDGET` holds them, fewer where it does
+    not, never more than the table is wide, at least one."""
+    want = _BLOCK_TOKENS_ONE_HEAD if _one_head(n_kv, quant) else _BLOCK_TOKENS
+    tokens = min(want, _VMEM_BUDGET // _token_vmem_bytes(
         rows, n_kv, hd, itemsize, quant, hd_v))
     return max(1, min(tokens // ps, max_pages))
 
@@ -181,10 +209,6 @@ def _check_pool(rows, q_heads_hd, pool_shape, table_shape, pos_shape, S, *,
     # a page mode stores (float32 pages, one-byte quantized payloads)
     itemsize = (jnp.dtype(pool_dtype).itemsize if pool_dtype is not None
                 else 4 if quant == "none" else 1)
-    if n_kv * itemsize < 4 and quant == "none":
-        raise ValueError(f"a page's row of {n_kv} KV head(s) of "
-                         f"{itemsize}-byte elements is under one 32-bit "
-                         f"word; the gather fallback handles it")
     hd_v = None
     if v_shape is not None and tuple(v_shape) != tuple(pool_shape):
         hd_v = v_shape[-1]
@@ -352,7 +376,8 @@ def _walk_live_blocks(pages_of, streams, sem, parity, carry, block, *,
 
 
 def _load_block(page_ref, buffer, *, quant, hd):
-    """A fetched block ``[ppb, ps, n_kv, hd_p]`` -> ``[T * n_kv, hd]`` keys
+    """A fetched block ``[ppb, ps, n_kv, hd_p]`` (one K/V head of exact
+    pages: ``[ppb, ps, hd_p]``) -> ``[T * n_kv, hd]`` keys
     (or values) in the dtype they are multiplied in: the pool's own for
     exact pages, the integer payload as float32 for int8/int4 (its scales
     go on the scores and the probabilities: `_scale_row`)."""
@@ -519,15 +544,19 @@ def _attend(q, k_pool, v_pool, scalars, k_scale, v_scale, *, C, scale,
     S, rows, hd = q.shape
     _, ps, n_kv, hd_p = k_pool.shape
     hd_vp = v_pool.shape[-1]
+    # what ONE token holds in a page array: its heads' rows, or the one
+    # head's row alone
+    heads = () if _one_head(n_kv, quant) else (n_kv,)
     # the values' own width where it is not the keys' (exact pages)
     hd_v = hd if hd_vp == hd_p else hd_vp
     mp = scalars[0].shape[1]
     ppb = pages_per_block(rows, ps, n_kv, hd, k_pool.dtype.itemsize, mp,
                           quant, None if hd_vp == hd_p else hd_vp)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    operands = [q, k_pool, v_pool]
-    scratch = [pltpu.VMEM((2, ppb, ps, n_kv, hd_p), k_pool.dtype),
-               pltpu.VMEM((2, ppb, ps, n_kv, hd_vp), v_pool.dtype)]
+    operands = [q] + [x.reshape(x.shape[:2] + heads + x.shape[3:])
+                      for x in (k_pool, v_pool)]
+    scratch = [pltpu.VMEM((2, ppb, ps) + heads + (hd_p,), k_pool.dtype),
+               pltpu.VMEM((2, ppb, ps) + heads + (hd_vp,), v_pool.dtype)]
     if quant != "none":
         # a page's scales as ONE lane-dense row: Mosaic slices no page
         # out of a plane whose minor dim is the few KV heads

@@ -1,6 +1,8 @@
-"""The selective scan of a Mamba-1 layer: the walk over a block of
-positions (prefill) and the single-position update (decode).  Both are
-XLA compositions on every backend; there is no kernel yet (ROADMAP).
+"""The selective scan of a Mamba-1 layer (`nn/mamba.MambaMixer`: the
+Mamba layers of models/phi4_flash and of models/jamba): the walk over a
+block of positions (prefill) and the single-position update (decode).
+Both are XLA compositions on every backend; there is no kernel yet
+(ROADMAP queue 1 item 4 (b)).
 
 Per channel d of `d_inner` and state lane n of `d_state`, state h in
 R^{N x D} (float32, the channels in the LANES: [16, 5120] tiles whole,

@@ -2099,8 +2099,7 @@ class ServingEngine:
             st.stats.prefill_chunks += 1
             self._registry.inc("serve.prefill_chunks")
             self._registry.inc("serve.prefill_tokens", len(seg))
-            if self.windowed:
-                self._count_attended_keys(s, C, row)
+            self._count_attended_keys(s, C, row)
             if not last:
                 if self.tracer is not None:
                     self.tracer.on_chunk(req, clock(), st.chunks_done)

@@ -2,9 +2,10 @@
 owed since PR 40): the registry of per-layer metrics, `BENCHMARK.json`'s
 `per_layer` list and the rule files under `benchmarks/metrics/`, held to
 each other, to the limits of the file and to the cost functions of each
-listed cell's family, where the driver counts; and the two newest families'
-files with their CPU rehearsals, from `benchmarks/tests/test_ling_family.py`
-and `benchmarks/tests/test_phi4flash_family.py`.
+listed cell's family, where the driver counts; and the three newest families'
+files with their CPU rehearsals, from `benchmarks/tests/test_ling_family.py`,
+`benchmarks/tests/test_phi4flash_family.py` and
+`benchmarks/tests/test_jamba_family.py`.
 The tests are the benchmark's own, imported and called: nothing is written
 twice."""
 import importlib.util
@@ -34,6 +35,7 @@ def _load(name):
 registry = _load("test_registry")
 ling = _load("test_ling_family")
 phi4 = _load("test_phi4flash_family")
+jamba = _load("test_jamba_family")
 
 
 @pytest.mark.parametrize("name", [
@@ -85,3 +87,23 @@ def test_tiny_phi4flash_rehearses_correct(trace_on):
 
 def test_the_parent_fails_at_once_on_the_phi4flash_cell(tmp_path):
     phi4.test_the_parent_fails_at_once_without_the_family_module(tmp_path)
+
+
+@pytest.mark.parametrize("name", [
+    "test_the_cell_and_its_files",
+    "test_every_scope_rule_of_the_cell_finds_its_scope_in_the_programs",
+    "test_cost_functions_count_what_the_model_needs",
+    "test_a_control_comes_out_not_correct"])
+def test_jamba_family(name):
+    getattr(jamba, name)()
+
+
+@pytest.mark.parametrize("trace_on", [0, 1])
+def test_tiny_jamba_rehearses_correct(trace_on):
+    """`benchmarks/run.py --rehearse` on `tiny-jamba-concurrent-turns`:
+    the cell's whole path on the CPU."""
+    jamba.test_tiny_jamba_rehearses_correct(trace_on)
+
+
+def test_the_parent_fails_at_once_on_the_jamba_cell(tmp_path):
+    jamba.test_the_parent_fails_at_once_without_the_family_module(tmp_path)

@@ -502,6 +502,18 @@ def _phi4flash_block():
             num_pages=(6160, 176), max_prefilling=4)
 
 
+def _jamba_whole():
+    """AI21-Jamba2-3B WHOLE at published widths (28 layers: runs of 7,
+    13 and 6 Mamba-1 layers scanned, layers 7 and 21 attention over ONE
+    K/V head), at the serving cell's slots, page, chunk and max_len: 26
+    layers of state by slot beside two layers of one-head pages."""
+    from hetu_tpu.models.jamba import JambaConfig, JambaLMHeadModel
+    return JambaLMHeadModel(JambaConfig(
+        param_dtype=BF16, compute_dtype=BF16)), dict(
+            num_slots=128, page_size=128, max_len=4608, prefill_chunk=512,
+            num_pages=4624, max_prefilling=8)
+
+
 #: family -> ((model, ServeConfig overrides) or None for the Llama-2-7B
 #: block, the kernels its decode program must hold as tpu_custom_calls)
 SERVING_FAMILIES = {
@@ -512,6 +524,7 @@ SERVING_FAMILIES = {
     "mimo": (_mimo_block, ("paged_attn",)),
     "ling": (_ling_block, ("paged_latent",)),
     "phi4flash": (_phi4flash_block, ("paged_attn",)),
+    "jamba": (_jamba_whole, ("paged_attn",)),
 }
 
 
@@ -618,6 +631,31 @@ def test_serving_programs_compile_for_one_v5e(family):
         text = compiled["prefill_chunk"].as_text()
         assert "ssm_scan" in text and "tail" in text
         assert "ssm_step" in compiled["decode"].as_text()
+    elif family == "jamba":
+        # ONE K/V head of bfloat16: the paged kernel takes both attention
+        # layers (it refused such pages until PR 47: a token's row is
+        # under a 32-bit word) over pages of [128, 128] at the model's
+        # own bytes, and the chunk kernel the group of 20 query heads as
+        # one tall operand, nothing relaid (one call a layer); the pool
+        # and the 26 layers of state are carried in place
+        assert len(calls) == 2 and all(
+            "attn_full/pallas_paged_attention" in ln for ln in calls)
+        rec = routes["chunk_attn"]
+        assert rec["pallas"] == chunk_calls == 2 and not rec["xla"], rec
+        assert engine.pool.arrays.k.shape == (2, 4625, 128, 1, 128)
+        nbytes = lambda tree: sum(  # noqa: E731
+            a.size * a.dtype.itemsize for a in tree)
+        state, pool = nbytes(engine.pool.state), nbytes(
+            engine.pool.arrays.tree())
+        assert state == 129 * 26 * (327_680 + 30_720)
+        assert pool == 2 * 2 * 4625 * 128 * 128 * 2
+        mem = {name: c.memory_analysis() for name, c in compiled.items()}
+        assert mem["decode"].alias_size_in_bytes >= pool + state
+        assert mem["decode"].temp_size_in_bytes < 0.2e9
+        assert mem["prefill_chunk"].alias_size_in_bytes >= state
+        assert mem["prefill_chunk"].temp_size_in_bytes < 0.5e9
+        assert "ssm_norm" in compiled["prefill_chunk"].as_text()
+        assert "ssm_norm" in compiled["decode"].as_text()
     elif family == "kimi":
         assert "chunk_attn" not in routes and not chunk_calls
     elif family == "trinity":

@@ -348,13 +348,15 @@ def test_each_kind_of_layer_answers_the_kernels_gate_for_itself(monkeypatch):
     layer's own (`KVAttention.attend_paged`), not one answer for the
     whole model.  MiMo in bfloat16 at widths the kernel reads (keys 192
     in 256 lanes, values 128): a layer that reads everything stores ONE
-    KV head a token, 2 bytes an element, under the 32-bit word a page's
-    row has to fill (refused: the composition over gathered pages); a
-    window layer stores two (the kernel, interpret mode here, behind a
-    gate that is asked as on a TPU).  Both routes stand on the one
-    `kernel_routes` line, each with its reason, and the tokens' first
-    (the chunk program's, the same under both) are those of the engine
-    all of whose layers take the composition."""
+    KV head a token, which the kernel reads as pages of [page_size, hd]
+    (it refused two-byte pages of one head until PR 47; interpret mode
+    here, behind a gate that is asked as on a TPU); a window layer stores
+    two, and here a page of them is over the kernel's VMEM limit, set
+    between the two kinds' pages (refused: the composition over gathered
+    pages).  Both routes stand on the one `kernel_routes` line, each
+    with its reason, and the tokens' first (the chunk program's, the same
+    under both) are those of the engine all of whose layers take the
+    composition."""
     from hetu_tpu.ops.pallas import paged_attention as pa
     from test_mimo_v2 import build, tiny_cfg
     cfg, model, params = build(
@@ -376,6 +378,12 @@ def test_each_kind_of_layer_answers_the_kernels_gate_for_itself(monkeypatch):
     monkeypatch.setenv("HETU_TPU_PALLAS_KERNELS", "paged_attn")
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(pa, "_interpret", lambda: True)
+    c = model.config
+    page = [8 * pa._token_vmem_bytes(c.num_attention_heads, n_kv, 256, 2,
+                                     "none", 128)
+            for n_kv in (c.num_key_value_heads, c.swa_num_key_value_heads)]
+    assert c.num_key_value_heads == 1 and page[0] < page[1]
+    monkeypatch.setattr(pa, "_VMEM_LIMIT", sum(page) // 2)
     rng = np.random.default_rng(2)
     eng = _engine(model, params, **kw)
     got = eng.run(reqs())
@@ -383,9 +391,11 @@ def test_each_kind_of_layer_answers_the_kernels_gate_for_itself(monkeypatch):
     assert took["pallas"] and took["xla"], took
     assert sorted(why.split(":")[0] for why in took["why"]) == [
         "shape gate", "shape gate passes"], took
-    assert any("under one 32-bit word" in why for why in took["why"])
-    # the window layers' kernel took its window
-    assert eng.kernel_routes["paged_attn_window"]["pallas"]
+    assert any("bytes of VMEM" in why for why in took["why"])
+    # the kernel took the full kind's ONE head (keys wider than values),
+    # and no window layer
+    assert eng.kernel_routes["paged_attn_shapes"]["pallas"]
+    assert "paged_attn_window" not in eng.kernel_routes
     assert [len(r.tokens) for r in got] == [5, 5, 5]
     assert [r.tokens[0] for r in got] == [r.tokens[0] for r in want]
     eng.scheduler.check_invariants()

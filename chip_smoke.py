@@ -336,7 +336,8 @@ def serve_phase(cfg, serve_cfg, *, prompt_lens, max_new: int, seed: int,
               f"{len(r.tokens)} tokens")
     chunks = {r.rid: r.stats.prefill_chunks for r in results}
     check(max(chunks.values()) > 1,
-          f"{name}: no prompt spanned several prefill chunks: {chunks}")
+          f"{name}: no prompt took several launches of the chunk program: "
+          f"{chunks}")
 
     # the decode program's compiled text (jit keeps its executable to
     # itself, so this compiles it a second time: a compile-cache hit)

@@ -5,13 +5,17 @@ The engine runs THREE jitted programs, all static-shape (TPU-shaped —
 one compile each, no shape-bucket churn):
 
   * prefill chunk   — `models/generation.extend_cache` over a
-                      [1, prefill_chunk] token block into a per-request
-                      scratch cache.  Prefill is its OWN program
-                      (disaggregated from decode) and advances ONE chunk
-                      per engine step, interleaved with the decode
-                      batch: a long prompt costs extra engine steps for
-                      its own slot, never a multi-chunk stall in the
-                      other requests' inter-token gap.
+                      [1, k x prefill_chunk] token block into a
+                      per-request scratch cache.  Prefill is its OWN
+                      program (disaggregated from decode), interleaved
+                      with the decode batch: a step computes one chunk's
+                      rows a prefilling slot and no more, so a long
+                      prompt costs extra engine steps, never a
+                      multi-chunk stall in the other requests'
+                      inter-token gap.  Those rows go to the OLDEST
+                      prefilling slot first, in launches of k = 1 to 4
+                      chunks (`plan_prefill_launches`; one compile a
+                      launch shape, at most `PREFILL_LAUNCH_ROWS` rows).
   * prefill write   — scatter the scratch K/V into the slot's pool pages
                       (quantizing in the int8 page mode).
   * decode step     — `models/generation.decode_step_paged` over the
@@ -100,6 +104,58 @@ STEP_PHASES = (
 )
 
 
+#: the most prompt rows ONE launch of the chunk program carries.  A launch
+#: streams the whole stack's weights whatever its rows, so on the chip
+#: `benchmarks/peaks.py` describes (TPU v5e: 197 TFLOP/s bf16, 819 GB/s)
+#: its matrix products take longer than its weights' streaming only from
+#: 197e12 x 2 B / (2 x 819e9) = ~240 rows on: under that the MXU waits
+#: for weights.  Twice that, and no more: Phi-4-mini-flash's chunk at
+#: 1,024 rows read WORSE than two of 512 (PERF.md s5), the attention's
+#: scores and the scan's temporaries grow with the rows.
+PREFILL_LAUNCH_ROWS = 512
+
+
+def launch_multiples(chunk: int, slides: bool) -> tuple:
+    """(the numbers of chunks k ONE launch of the chunk program may carry,
+    ascending; why).  1 to 4 chunks while k x `chunk` rows stay within
+    `PREFILL_LAUNCH_ROWS` (a program a shape, so a few shapes: of
+    InternLM2's, alone on a v5e, 128 rows take 6.2 ms, 256 7.7, 384 10.9
+    and 512 13.4: PERF.md s6, PR 48); (1,) where one chunk already fills a
+    launch, and where a window kind's scratch is sized by the chunk and
+    slides by it (`extend_cache(slide=True)`: a launch of other rows
+    would need a scratch of its own)."""
+    if slides:
+        return (1,), ("a window kind's prefill scratch holds the window "
+                      "and ONE chunk and slides by it")
+    ks = tuple(k for k in (1, 2, 3, 4)
+               if k == 1 or k * chunk <= PREFILL_LAUNCH_ROWS)
+    if ks == (1,):
+        return ks, (f"two chunks of {chunk} rows pass the "
+                    f"{PREFILL_LAUNCH_ROWS} rows a launch carries")
+    return ks, (f"up to {PREFILL_LAUNCH_ROWS} rows a launch: its weights "
+                "are streamed once for them")
+
+
+def plan_prefill_launches(chunks_left: Sequence[int],
+                          multiples: Sequence[int]) -> List[int]:
+    """How one step spends its prompt rows: the chunks each prefilling
+    slot's ONE launch carries, given the chunks each padded prompt has
+    left, OLDEST ADMISSION FIRST.  The step's budget is one chunk a
+    prefilling slot (what a launch a slot computed); the slot at the
+    head takes the largest k of `multiples` within what it has left and
+    what is left of the budget, then the next, until the budget is spent;
+    a slot that gets 0 waits a step.  Every slot has a chunk left and 1
+    is a multiple, so the budget is always spent exactly; with
+    `multiples` = (1,) the plan is one chunk a slot."""
+    budget = len(chunks_left)
+    plan = []
+    for left in chunks_left:
+        k = max((m for m in multiples if m <= min(left, budget)), default=0)
+        plan.append(k)
+        budget -= k
+    return plan
+
+
 @dataclasses.dataclass
 class _InFlight:
     """A decode (or verify) program dispatched whose tokens the host has
@@ -124,6 +180,11 @@ class _Program:
     takes_params: bool = True
     #: it has quantize sites for the numerics observatory to collect
     numerics: bool = True
+
+
+def _chunk_program(k: int) -> str:
+    """The table's name of the chunk program that carries k chunks."""
+    return "prefill_chunk" if k == 1 else f"prefill_chunk_x{k}"
 
 
 @dataclasses.dataclass
@@ -576,8 +637,9 @@ class ServingEngine:
 
         # per-request prefill scratch: a dense [L, 1, max_len] cache the
         # chunk program advances IN PLACE (it is donated), one array per
-        # array of the contract; every admission is handed zeros of its
-        # own, so no call can consume another's buffer
+        # array of the contract; every request is handed zeros of its
+        # own at its first launch, so no call can consume another's
+        # buffer
         # A WINDOW kind's scratch holds the window and one chunk, and
         # slides (`extend_cache(slide=True)`), where chunks and pages
         # line up (a page of the pool is then a block of the scratch)
@@ -587,6 +649,12 @@ class ServingEngine:
         self._fresh_scratch = jax.jit(functools.partial(
             init_cache, model, 1, self.config.max_len,
             **(dict(chunk=C, page=ps) if self._slide else {})))
+        #: the chunks ONE launch of the chunk program may carry (a
+        #: program a shape: `_chunk_program`), by what is observed here
+        #: alone: the chunk's rows and whether the scratch slides
+        self._launch_multiples, why = launch_multiples(C, self._slide)
+        self.kernel_routes["prefill_launch_rows"] = {
+            "rows": [k * C for k in self._launch_multiples], "why": why}
         from hetu_tpu.serving.kv_pool import contract_bytes_per_token
         itemsize = jnp.dtype(c.compute_dtype).itemsize
         mode = (self.config.kv_quant if self.config.kv_quant != "none" else
@@ -807,7 +875,8 @@ class ServingEngine:
         # cache: `read_rows_from`) runs for the ONE row a finished prompt
         # samples from (`row`; negative: the chunk does not end its
         # prompt and runs none of it), and the program hands back that
-        # row's logits alone, [1, 1, vocab]
+        # row's logits alone, [1, 1, vocab].  `chunk` is [1, k x C]: one
+        # program a launch shape (the table below), this one body
         def chunk_fn(params, chunk, cache, start, row, *rest):
             state = dict(zip(state_args, rest))
             logits, cache, *stats = extend_cache(
@@ -933,11 +1002,18 @@ class ServingEngine:
             "verify" if self.spec else "decode": _Program(
                 verify_fn if self.spec else decode_fn, 1,
                 self._null_step_args),
-            "prefill_chunk": _Program(chunk_fn, 2, self._null_chunk_args,
-                                      numerics=False),
             "write_pages": _Program(write_fn, 0, self._null_write_args,
                                     takes_params=False),
         }
+        for k in self._launch_multiples:
+            # a launch of k chunks is a row, and a compile, of its own;
+            # the module keeps the body's name at every shape
+            # (`jit_chunk_fn`: a device trace's median of the chunk
+            # program is over all its launches), the largest LAST: of two
+            # texts of one name benchmarks/trace.py joins the later
+            self._table[_chunk_program(k)] = _Program(
+                chunk_fn, 2, functools.partial(self._null_chunk_args, k),
+                numerics=False)
         self._jits = {name: self._program(name) for name in self._table}
         self._prime_jit = (jax.jit(prime_fn)
                            if self.prefix_cache is not None else None)
@@ -980,13 +1056,14 @@ class ServingEngine:
                 *((jnp.full((S, K1 - 1, vocab), 1.0 / vocab, jnp.float32),)
                   if self.spec_stochastic else ()), *sample_args)
 
-    def _null_chunk_args(self):
-        # (state layers: the null slot's row, every row the prompt's)
-        C = self.config.prefill_chunk
-        return (self.params, jnp.zeros((1, C), jnp.int32),
+    def _null_chunk_args(self, k: int):
+        # (a launch of k chunks; state layers: the null slot's row, every
+        # row the prompt's)
+        R = k * self.config.prefill_chunk
+        return (self.params, jnp.zeros((1, R), jnp.int32),
                 tuple(self._fresh_scratch()) + self.pool.state,
                 jnp.int32(0), jnp.int32(0),
-                *((jnp.int32(self.pool.null_slot), jnp.int32(C))
+                *((jnp.int32(self.pool.null_slot), jnp.int32(R))
                   if self.stateful else ()), *self._stats_args())
 
     def _null_write_args(self):
@@ -996,8 +1073,9 @@ class ServingEngine:
 
     def _dummy_args(self, program: str):
         """Arguments of the engine's own shapes for one of its programs
-        ("decode" | "verify", "prefill_chunk", "write_pages"), all aimed
-        at the null page (zero table/row): what `warmup` runs and
+        ("decode" | "verify", "write_pages", "prefill_chunk" and, a
+        launch of k > 1 chunks, "prefill_chunk_x<k>"), all aimed at the
+        null page (zero table/row): what `warmup` runs and
         `lower_programs` abstracts."""
         if program not in self._table:
             raise ValueError(f"unknown program {program!r}")
@@ -1005,8 +1083,10 @@ class ServingEngine:
 
     def lower_programs(self, sharding=None) -> dict:
         """{program: jax.stages.Lowered} for the decode step ("verify"
-        with speculative decoding on), the prefill chunk and the page
-        write, lowered for ABSTRACT arguments of the engine's own shapes —
+        with speculative decoding on), the page write and the prefill
+        chunk at every launch shape the engine can issue
+        ("prefill_chunk", "prefill_chunk_x2", ...: `launch_multiples`),
+        lowered for ABSTRACT arguments of the engine's own shapes —
         `.compile().as_text()` is the program the engine runs.  `sharding`
         places the arguments (default: where the arrays are); a described
         device's compiles the programs for a chip that is not attached
@@ -1020,8 +1100,9 @@ class ServingEngine:
                 for name, fn in self._jits.items()}
 
     def warmup(self):
-        """Compile all three programs so the first request's TTFT is not
-        a compile.  The dummy decode/write still target the null page
+        """Compile every program, the chunk program at every launch shape
+        it can be issued at, so that no request's TTFT is a compile.
+        The dummy decode/write still target the null page
         (zero table/row), so pool CONTENT is untouched — but the pool
         trees are donated through the calls, so the returned trees must
         be committed back (discarding them would leave self.pool.arrays
@@ -1036,11 +1117,12 @@ class ServingEngine:
             args[5] = nxt
             nxt, tree = self._call("decode", *args)
             self.pool.commit(tree)
-        lg, _, cache = self._call(
-            "prefill_chunk", *self._dummy_args("prefill_chunk"))[:3]
-        if self.stateful:
-            # (the state arrays were donated behind the scratch)
-            self.pool.state = tuple(cache[len(cache) - len(self.pool.state):])
+        for name in map(_chunk_program, self._launch_multiples):
+            lg, _, cache = self._call(name, *self._dummy_args(name))[:3]
+            if self.stateful:
+                # (the state arrays were donated behind the scratch)
+                self.pool.state = tuple(
+                    cache[len(cache) - len(self.pool.state):])
         tree = self._call("write_pages", *self._dummy_args("write_pages"))
         self.pool.arrays = PoolArrays.from_tree(tree)
         if self._prime_jit is not None:
@@ -1199,13 +1281,34 @@ class ServingEngine:
     # ------------------------------------------------------------- step
     def step(self, now: float) -> List[RequestResult]:
         """One engine iteration at driver time `now`: admit every
-        admissible queued request (reservation only), advance each
-        PREFILLING slot by exactly ONE chunk, dispatch one decode step
+        admissible queued request (reservation only), spend the step's
+        prompt rows on the PREFILLING slots, dispatch one decode step
         over the slots whose prefill is complete, and only then wait for
-        the device.  One-chunk-per-step is the disaggregation contract: a
-        long prompt adds engine steps for its own slot, never a
-        multi-chunk stall to the decode batch's inter-token gap.  Returns
-        requests that finished this step.
+        the device.  Returns requests that finished this step.
+
+        **A step's prompt rows: one chunk's a prefilling slot, spent
+        oldest first.**  With n slots in prefill a step computes n x
+        `prefill_chunk` prompt rows, never more: that budget is the
+        disaggregation contract (a long prompt adds engine steps, never
+        a multi-chunk stall to the decode batch's inter-token gap).  It
+        is not spent one chunk a slot: the slots are taken in admission
+        order (`SlotState.admit_seq`), and each takes ONE launch of the
+        largest k of `launch_multiples` chunks within what its padded
+        prompt has left and what is left of the budget
+        (`plan_prefill_launches`), until the budget is spent; a slot left
+        with nothing waits a step.  A launch streams the whole stack's
+        weights whatever its rows, so the same rows in fewer, larger
+        launches (at most `PREFILL_LAUNCH_ROWS`: the derivation is at the
+        constant) are the same work in less time; and first come, first
+        served inside a step lowers the mean time to a first token and
+        raises none past what the budget already implied.  Refused, by
+        what the engine observes and with the reason on the
+        `kernel_routes` line (`"prefill_launch_rows"`): a chunk of more
+        than `PREFILL_LAUNCH_ROWS` / 2 rows (k = 1: one chunk a slot a
+        step, in admission order), and a window kind's sliding scratch,
+        which holds the window and ONE chunk.  `serve.prefill_chunks`
+        counts the LAUNCHES, `serve.prefill_launches{rows}` each shape's,
+        `serve.prefill_tokens` the prompt rows.
 
         **One step stays queued on the device.**  Step k dispatches its
         chunk programs and decode k, and then fetches the tokens of
@@ -1250,9 +1353,9 @@ class ServingEngine:
         utils/profiling.StepRecorder), which ends in the counters and
         histograms of `_note_step_phases`, in `self.slow_steps` for a
         stalled step and, for the slowest step so far, in
-        `self.slowest_step` (docs/serving.md).  The loop over the slots
-        between the blocks is in no phase, so the phases sum to a little
-        under the step."""
+        `self.slowest_step` (docs/serving.md).  The plan and the loop
+        over the slots between the blocks are in no phase, so the phases
+        sum to a little under the step."""
         t0 = time.perf_counter()
 
         def clock() -> float:
@@ -1270,12 +1373,16 @@ class ServingEngine:
                 self._drain(why, clock, finished, phases)
 
             ends: List[_PromptEnd] = []
-            chunks = 0
-            for i in self.scheduler.active_slots():
-                st = self.scheduler.slots[i]
-                if st is not None and st.prefilling:
-                    self._advance_prefill(i, st, clock, ends, phases)
-                    chunks += 1
+            prefilling = sorted(
+                ((i, st) for i, st in enumerate(self.scheduler.slots)
+                 if st is not None and st.prefilling),
+                key=lambda slot: slot[1].admit_seq)
+            plan = plan_prefill_launches(
+                [self._chunks_left(st) for _, st in prefilling],
+                self._launch_multiples)
+            for (i, st), k in zip(prefilling, plan):
+                if k:
+                    self._advance_prefill(i, st, k, clock, ends, phases)
 
             # every slot past its prefill whose tokens are not all
             # dispatched: a length finish is known here, without a fetch
@@ -1362,7 +1469,9 @@ class ServingEngine:
                 empty = (self._inflight is None and not sched.queue
                          and all(st is None for st in sched.slots))
                 dispatched = {
-                    "chunk_launches": chunks, "decode_batch": len(batch),
+                    "chunk_launches": sum(map(bool, plan)),
+                    "prefill_chunks": sum(plan),
+                    "decode_batch": len(batch),
                     "fetch_behind": bool(batch) and older is not None}
                 self._last_clock = clock()
         self._note_step_phases(now, empty, dispatched)
@@ -1667,15 +1776,16 @@ class ServingEngine:
         waited, and whether it leaves the engine EMPTY (no slot active,
         nothing queued, nothing in flight: the gap to the next step is
         then `serve.empty_s`, else the caller's, `serve.caller_s`).  A
-        step is judged stalled among the steps with as many chunk
-        launches as it has: a chunk program can take five decode
-        programs' time (MiMo: 39 ms beside 8).  The
+        step is judged stalled among the steps that spent as many chunks
+        of prompt rows as it has (however many launches carried them): a
+        chunk program can take five decode programs' time (MiMo: 39 ms
+        beside 8).  The
         record becomes `slowest_step` if no step since that was last
         cleared took longer: so the slowest step of any run, traced or
         not, names its phase and what held it (docs/serving.md)."""
         self.slowest_step = self._step_record.end(
             self.steps_done, now, self.slowest_step, empty=empty,
-            kind=dispatched["chunk_launches"], dispatched=dispatched,
+            kind=dispatched["prefill_chunks"], dispatched=dispatched,
             fetch_wait_s=self._fetch_wait)
 
     # ----------------------------------------------------------- faults
@@ -2024,12 +2134,15 @@ class ServingEngine:
 
     # ---------------------------------------------------------- prefill
     def _start_prefill(self, slot_idx: int, st, now: float):
-        """Attach the prefill scratch to a freshly admitted slot.  With
-        a radix-cache hit the scratch is PRIMED: the shared pages
+        """A freshly admitted slot's prefill scratch.  With a
+        radix-cache hit the scratch is PRIMED here: the shared pages
         gather into positions [0, shared_tokens) (exact in the fp page
         mode — the bytes written at caching time), so suffix chunks
         attend over the resident prefix and prefill FLOPs drop to the
-        unshared suffix."""
+        unshared suffix.  Without one the slot holds NO scratch until its
+        first launch (`_advance_prefill`): a step's rows go to the oldest
+        slots first, and one that waits its turn waits without 201 MB
+        (the InternLM2 cells' scratch) of zeros."""
         if st.shared_tokens:
             row = np.full(self.scheduler.max_pages, PagePool.NULL_PAGE,
                           np.int32)
@@ -2040,19 +2153,24 @@ class ServingEngine:
             self._registry.inc("serve.prefix_hits")
             self._registry.inc("serve.prefix_shared_tokens",
                                value=st.shared_tokens)
-        else:
-            st.prefill_cache = self._fresh_scratch()
-            if self.prefix_cache is not None:
-                self._registry.inc("serve.prefix_misses")
+        elif self.prefix_cache is not None:
+            self._registry.inc("serve.prefix_misses")
         if self.prefix_cache is not None:
             self._registry.set_gauge("serve.prefix_cache_pages",
                                      self.prefix_cache.num_pages)
 
-    def _advance_prefill(self, slot_idx: int, st, clock, ends, phases):
-        """Run ONE prefill chunk for a prefilling slot; on the last
-        chunk, scatter the scratch K/V into the slot's pages and leave
-        the prompt's end in `ends`: its first token is fetched at the
-        step's end (`_land_first_tokens`), where the slot joins the
+    def _chunks_left(self, st) -> int:
+        """The chunks a prefilling slot's padded prompt has left."""
+        left = st.request.prompt_len - st.shared_tokens
+        return math.ceil(left / self.config.prefill_chunk) - st.chunks_done
+
+    def _advance_prefill(self, slot_idx: int, st, k: int, clock, ends,
+                         phases):
+        """Run ONE launch of the chunk program, the next `k` chunks of a
+        prefilling slot's prompt (`plan_prefill_launches`); where they
+        end the prompt, scatter the scratch K/V into the slot's pages and
+        leave the prompt's end in `ends`: its first token is fetched at
+        the step's end (`_land_first_tokens`), where the slot joins the
         decode batch.  A radix-cache hit
         starts chunking at the shared boundary (`st.shared_tokens` —
         the primed prefix is already in the scratch) and never
@@ -2060,11 +2178,10 @@ class ServingEngine:
         with phase_span("serve.prefill_chunk", phases):
             req = st.request
             plen = req.prompt_len
-            C = self.config.prefill_chunk
-            base = st.shared_tokens
-            padded = base + math.ceil((plen - base) / C) * C
-            s = base + st.chunks_done * C
-            last = s + C >= padded
+            # the launch's rows: C where a chunk stood
+            C = k * self.config.prefill_chunk
+            s = st.shared_tokens + st.chunks_done * self.config.prefill_chunk
+            last = k == self._chunks_left(st)
             # the last VALID prompt position of the final chunk (padding
             # tail positions carry garbage): the row the program runs the
             # head for and takes the first token at; negative: this chunk
@@ -2077,9 +2194,11 @@ class ServingEngine:
             # (with state layers the pool's state arrays ride behind the
             # scratch, and the chunk at position 0 starts the slot's row
             # of them from zeros)
+            if st.prefill_cache is None:    # its first launch: zeros
+                st.prefill_cache = self._fresh_scratch()
             scratch = tuple(st.prefill_cache)
             out = self._call(
-                "prefill_chunk", self.params, jnp.asarray(ids[None]),
+                _chunk_program(k), self.params, jnp.asarray(ids[None]),
                 scratch + self.pool.state, jnp.int32(s), jnp.int32(row),
                 *((jnp.int32(slot_idx), jnp.int32(len(seg)))
                   if self.stateful else ()), *self._stats_args())
@@ -2095,9 +2214,12 @@ class ServingEngine:
             # rows the head (and the layers from `read_rows_from` on)
             # ran for
             self._registry.inc("serve.prefill_tail_rows", int(last))
-            st.chunks_done += 1
+            st.chunks_done += k
+            # (launches, the request's and the engine's: rows a launch is
+            # `serve.prefill_tokens` over `serve.prefill_chunks`)
             st.stats.prefill_chunks += 1
             self._registry.inc("serve.prefill_chunks")
+            self._registry.inc("serve.prefill_launches", rows=C)
             self._registry.inc("serve.prefill_tokens", len(seg))
             self._count_attended_keys(s, C, row)
             if not last:
@@ -2112,7 +2234,7 @@ class ServingEngine:
             # (COW) — their row entries point at the null page so the
             # write lands harmlessly
             pages_row = self.scheduler.write_rows(
-                slot_idx, base // self.pool.page_size,
+                slot_idx, st.shared_tokens // self.pool.page_size,
                 bases=self._scratch_bases(s))
             tree = self._call("write_pages", self.pool.arrays.tree(),
                                    jax.tree.map(jnp.asarray, pages_row),

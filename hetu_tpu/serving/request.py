@@ -259,6 +259,7 @@ class RequestStats:
     admit_t: Optional[float] = None
     first_token_t: Optional[float] = None
     done_t: Optional[float] = None
+    #: launches of the chunk program for the prompt (1 to 4 chunks each)
     prefill_chunks: int = 0
     #: speculative decoding (serving/spec_decode.py): draft tokens
     #: proposed / accepted over the request's verify steps

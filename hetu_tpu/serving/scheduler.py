@@ -45,9 +45,12 @@ from hetu_tpu.serving.request import Request, RequestStats, TenantQuota
 @dataclass
 class SlotState:
     """One live decode slot.  A freshly admitted slot spends its first
-    engine steps PREFILLING (one chunk per step, interleaved with the
-    decode batch — the engine drives these fields); it joins the decode
-    batch when the last chunk lands.  ``shared_tokens`` > 0 means the
+    engine steps PREFILLING, interleaved with the decode batch (the
+    engine drives these fields: a step's prompt rows, one chunk's a
+    prefilling slot, go to the oldest ``admit_seq`` first, up to four
+    chunks in one launch, so ``chunks_done`` advances by 0 to 4 a step:
+    `ServingEngine.step`); it joins the decode batch when the last
+    chunk lands.  ``shared_tokens`` > 0 means the
     leading pages of ``pages`` are radix-cache pages resident from an
     earlier request (serving/prefix_cache.py) — prefill starts at that
     boundary and those pages are never written by this slot."""
@@ -62,10 +65,12 @@ class SlotState:
     inflight: int = 0
     stats: RequestStats = field(default_factory=RequestStats)
     prefilling: bool = False
-    prefill_cache: object = None      # scratch KV carry while prefilling
+    #: scratch KV carry while prefilling (None until the first launch)
+    prefill_cache: object = None
     chunks_done: int = 0
     shared_tokens: int = 0
-    admit_seq: int = 0                # admission order (preemption ties)
+    #: admission order (preemption ties; who prefills first in a step)
+    admit_seq: int = 0
     #: the pages held of the further kinds of layer, a list a kind, and
     #: per kind (the first included) the page index of its first held
     #: page: 0 but under a window, where what lies behind is released

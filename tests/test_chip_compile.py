@@ -542,7 +542,16 @@ def test_serving_programs_compile_for_one_v5e(family):
     model, serve = make() if make else (None, {})
     engine = _serving_engine(model=model, **serve)
     programs = engine.lower_programs(sharding=ONE_CHIP)
-    assert sorted(programs) == ["decode", "prefill_chunk", "write_pages"]
+    # the chunk program at every launch shape the engine can issue: one
+    # to four chunks of the Llama and GPT blocks' 128 rows, one chunk of
+    # the families' own 512 to 2,048 (`engine.launch_multiples`)
+    larger = [f"prefill_chunk_x{k}" for k in (2, 3, 4)
+              if k * engine.config.prefill_chunk <= 512]
+    assert bool(larger) == (family in ("llama", "gpt"))
+    assert sorted(programs) == sorted(
+        ["decode", "prefill_chunk", "write_pages", *larger])
+    assert engine.kernel_routes["prefill_launch_rows"]["rows"] == [
+        k * engine.config.prefill_chunk for k in range(1, len(larger) + 2)]
     compiled = {name: low.compile() for name, low in programs.items()}
     calls = [ln for ln in compiled["decode"].as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
@@ -677,9 +686,27 @@ def test_serving_programs_compile_for_one_v5e(family):
         assert compiled["prefill_chunk"].memory_analysis() \
             .temp_size_in_bytes < 0.15e9
     else:
+        # one gate for every launch shape: the float32 scores of a layer,
+        # heads x rows x 2,048 positions, from 64 MB on take the kernel
+        # (two calls a traced layer) and under it keep the composition.
+        # Llama's 32 heads: 33.5 MB at one chunk, 67-134 MB at two to
+        # four; the GPT block's 16: the four-chunk launch alone (67 MB)
         rec = routes["chunk_attn"]
-        assert rec["xla"] and not rec["pallas"] and not chunk_calls, rec
-        assert all("MB of float32 scores" in w for w in rec["why"]), rec
+        heads = engine.model.config.num_attention_heads
+        pays = {name: 4 * heads * rows * 2048 >= 64 << 20 for name, rows in
+                zip(["prefill_chunk", *larger],
+                    routes["prefill_launch_rows"]["rows"])}
+        assert not pays["prefill_chunk"] and pays["prefill_chunk_x4"]
+        assert rec["pallas"] == sum(pays.values())
+        assert rec["xla"] == len(pays) - rec["pallas"], rec
+        assert len(rec["why"]) == 1 + rec["xla"] and all(
+            w == "shape gate passes" or "MB of float32 scores" in w
+            for w in rec["why"]), rec
+        for name, kernel in pays.items():
+            text = compiled[name].as_text().splitlines()
+            assert sum("pallas_chunk_attention" in ln for ln in text
+                       if 'custom_call_target="tpu_custom_call"' in ln) \
+                == 2 * kernel, name
     if family == "trinity":
         # both kinds of layer decode through the kernel, each under its
         # own name; no program copies a pool (a scatter of whole pages

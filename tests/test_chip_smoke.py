@@ -45,7 +45,12 @@ def test_serve_phase_control_flow(capsys):
         ServeConfig(num_slots=4, page_size=8, max_len=128, prefill_chunk=16),
         prompt_lens=(5, 40, 70, 40), max_new=6, seed=0)
     assert rec["streams_matching_generate"] == 4
-    assert max(s["prefill_chunks"] for s in rec["streams"]) == 5
+    # a prompt's launches of the chunk program: one chunk each where it
+    # prefilled alone, up to four where a step's rows came to it first
+    for s in rec["streams"]:
+        chunks = -(-s["prompt_len"] // 16)
+        assert -(-chunks // 4) <= s["prefill_chunks"] <= chunks, s
+    assert max(s["prefill_chunks"] for s in rec["streams"]) >= 2
     assert _records(capsys)[-1]["phase"] == "serve"
 
 

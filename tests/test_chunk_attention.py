@@ -28,6 +28,7 @@ from hetu_tpu.ops.pallas import chunk_attention as ca  # noqa: E402
 from hetu_tpu.ops.pallas import record_routes  # noqa: E402
 from hetu_tpu.serving.engine import ServeConfig, ServingEngine  # noqa: E402
 from hetu_tpu.serving.request import Request  # noqa: E402
+from test_serving import launches  # noqa: E402
 
 HD, KB = 128, 128
 F32, BF16 = jnp.float32, jnp.bfloat16
@@ -219,7 +220,8 @@ def test_serving_with_the_chunk_kernel_serves_the_same_tokens(
     own) at head_dim 128: the engine serves the same tokens with the
     chunk kernel forced on (interpret mode) and off, prompts that span
     several chunks and reach past the window; `kernel_routes` counts the
-    chunk program's traced layers on the kernel and nothing else."""
+    chunk programs' traced layers on the kernel (a program a launch
+    shape) and nothing else."""
     model, params, traced_layers = _model(family)
     vocab = model.config.vocab_size
     rng = np.random.default_rng(2)
@@ -227,21 +229,30 @@ def test_serving_with_the_chunk_kernel_serves_the_same_tokens(
 
     def serve(on):
         _force(monkeypatch, on)
+        reg = MetricsRegistry()
         eng = ServingEngine(model, params, ServeConfig(
             num_slots=3, page_size=8, max_len=256, prefill_chunk=16,
             num_pages=(96, 64) if family == "trinity" else 96),
-            registry=MetricsRegistry())
+            registry=reg)
         reqs = [Request(rid=i, prompt=p, max_new_tokens=m, arrival_t=0.0)
                 for i, (p, m) in enumerate(prompts)]
         out = {r.rid: list(r.tokens) for r in eng.run(reqs)}
+        # a chunk program a launch shape issued: Trinity's window scratch
+        # slides, so its launches are one chunk (ONE program); the
+        # llama's three slots prefill in launches of one to three chunks
+        shapes.append(len(launches(reg)))
         return out, eng.kernel_routes["chunk_attn"]
 
     prompts = [(rng.integers(0, vocab, size=n).astype(np.int32), m)
                for n, m in lens]
+    shapes = []
     off, routes_off = serve(False)
     on, routes_on = serve(True)
     assert sorted(on) == list(range(len(lens)))
     assert on == off
+    assert shapes[0] == shapes[1] and (shapes[0] == 1) == (
+        family == "trinity")
+    traced_layers *= shapes[0]
     assert routes_on["pallas"] == traced_layers and not routes_off["pallas"]
     # (the decode program asks nothing of this route: its layers attend
     # the pages where they lie, `attend_paged`)

@@ -30,6 +30,7 @@ from hetu_tpu.obs.metrics import MetricsRegistry  # noqa: E402
 from hetu_tpu.serving.engine import ServingEngine  # noqa: E402
 from hetu_tpu.serving.request import Request, SamplingParams  # noqa: E402
 from hetu_tpu.serving.sampling import sample_tokens  # noqa: E402
+from test_serving import launches  # noqa: E402
 
 ROW_ATOL = 2e-5
 LOGIT_ATOL = 2e-4
@@ -41,10 +42,10 @@ FAMILIES = {"llama": "tiny", "kimi_k2": "tiny-kimi-k2",
             "bailing_hybrid": "tiny-ling", "phi4flash": "tiny-phi4flash"}
 
 
-def build(family):
+def build(family, config=None):
     fam = importlib.import_module(f"benchmarks.families.{family}")
     with open(os.path.join(ROOT, "benchmarks", "configs",
-                           FAMILIES[family] + ".json")) as f:
+                           (config or FAMILIES[family]) + ".json")) as f:
         cfg = json.load(f)
     cfg.pop("router_tie_logit", None)   # the reference's plain forward
     if family == "mimo_v2":
@@ -150,6 +151,25 @@ def _requests(rng, cfg, plens, sampled):
         if sampled else SamplingParams()) for i, n in enumerate(plens)]
 
 
+def _holds_the_launch_counters(eng, reg, chunks, tokens):
+    """`serve.prefill_chunks` counts LAUNCHES, each of a shape the engine
+    warms up; together they carried `chunks` chunks of rows, `tokens` of
+    them the prompts'.  A window scratch that slides keeps one chunk a
+    launch; elsewhere slots that prefill in one step share its rows
+    oldest first, so some launch carried more."""
+    by_rows = launches(reg)
+    assert sum(by_rows.values()) == reg.counter_value("serve.prefill_chunks")
+    assert sum(r * n for r, n in by_rows.items()) == chunks * CHUNK
+    assert reg.counter_value("serve.prefill_tokens") == tokens
+    shapes = eng.kernel_routes["prefill_launch_rows"]
+    assert set(by_rows) <= set(shapes["rows"])
+    if eng._slide:
+        assert shapes["rows"] == [CHUNK] and "slides" in shapes["why"]
+    else:
+        assert shapes["rows"] == [k * CHUNK for k in (1, 2, 3, 4)]
+        assert max(by_rows) > CHUNK
+
+
 def _holds_against_reference(fam, cfg, params, req, tokens):
     """Every served token against the plain reference's logits of the
     stream's own prefix: within LOGIT_ATOL of the largest (greedy), or
@@ -187,7 +207,7 @@ def test_served_tokens_are_the_references(family, sampled, rng):
         _holds_against_reference(fam, cfg, params, req,
                                  results[req.rid].tokens)
     assert reg.counter_value("serve.prefill_tail_rows") == len(plens)
-    assert reg.counter_value("serve.prefill_chunks") == 1 + 2 + 3
+    _holds_the_launch_counters(eng, reg, 1 + 2 + 3, sum(plens))
 
 
 @pytest.mark.parametrize("sampled", [False, True],
@@ -215,7 +235,9 @@ def test_llama_tokens_are_generates_and_a_prefix_hit_leaves_a_row(
     assert got == want
     assert reg.counter_value("serve.prefix_hits") == 2
     # the hits prefilled one chunk each from the shared boundary on
-    assert reg.counter_value("serve.prefill_chunks") == 2 + 3 + 1 + 1 + 1
+    shared = reg.counter_value("serve.prefix_shared_tokens")
+    _holds_the_launch_counters(cached, reg, 2 + 3 + 1 + 1 + 1,
+                               sum(r.prompt_len for r in reqs) - shared)
     assert reg.counter_value("serve.prefill_tail_rows") == len(reqs)
     for req in reqs:
         _holds_against_reference(fam, cfg, params, req, got[req.rid])

@@ -601,7 +601,12 @@ def test_kv_engines_pools_and_programs_are_as_they_were(make, n_kv, hd):
     assert len(eng._dummy_args("prefill_chunk")) == 5   # + the token's row
     assert len(eng._dummy_args("write_pages")) == 4
     low = eng.lower_programs()
-    assert sorted(low) == ["decode", "prefill_chunk", "write_pages"]
+    # (the chunk program at each launch shape: one to four chunks of 16)
+    assert sorted(low) == ["decode", "prefill_chunk", "prefill_chunk_x2",
+                           "prefill_chunk_x3", "prefill_chunk_x4",
+                           "write_pages"]
+    assert [low[n].args_info[0][1].shape for n in sorted(low)[1:5]] == [
+        (1, 16 * k) for k in (1, 2, 3, 4)]
     # 2 layers x (K + V) x n_kv x hd float32 values a token
     assert eng._registry.snapshot()["gauges"] and any(
         g["name"] == "serve.kv_bytes_per_token"

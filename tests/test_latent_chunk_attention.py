@@ -30,6 +30,7 @@ from hetu_tpu.ops.pallas import record_routes  # noqa: E402
 from hetu_tpu.parallel.strategy import ParallelStrategy  # noqa: E402
 from hetu_tpu.serving.engine import ServeConfig, ServingEngine  # noqa: E402
 from hetu_tpu.serving.request import Request  # noqa: E402
+from test_serving import launches  # noqa: E402
 
 KB = 128
 DN, DR, DV = 128, 64, 128
@@ -297,7 +298,7 @@ def test_serving_with_the_latent_kernel_serves_the_same_tokens(
     one gated MLA layer's pages): the engine serves the same tokens with
     the kernel forced on (interpret mode) and off, prompts that span
     several chunks and key blocks; `kernel_routes` counts the chunk
-    program's traced MLA layers on the kernel."""
+    programs' traced MLA layers on the kernel, a program a launch shape."""
     monkeypatch.setattr(lca, "_KEY_BLOCK", KB)
     model, params, traced_layers = _model(family)
     vocab = model.config.vocab_size
@@ -308,18 +309,25 @@ def test_serving_with_the_latent_kernel_serves_the_same_tokens(
 
     def serve(on):
         _force(monkeypatch, on)
+        reg = MetricsRegistry()
         eng = ServingEngine(model, params, ServeConfig(
             num_slots=3, page_size=8, max_len=256, prefill_chunk=16,
-            num_pages=96), registry=MetricsRegistry())
+            num_pages=96), registry=reg)
         reqs = [Request(rid=i, prompt=p, max_new_tokens=m, arrival_t=0.0)
                 for i, (p, m) in enumerate(prompts)]
         out = {r.rid: list(r.tokens) for r in eng.run(reqs)}
-        return out, eng.kernel_routes["latent_chunk_attn"]
+        # (a chunk program a launch shape issued: three slots prefill at
+        # once, so launches of one to three chunks)
+        shapes = len(launches(reg))
+        assert shapes > 1
+        return out, eng.kernel_routes["latent_chunk_attn"], shapes
 
-    off, routes_off = serve(False)
-    on, routes_on = serve(True)
+    off, routes_off, shapes_off = serve(False)
+    on, routes_on, shapes = serve(True)
     assert sorted(on) == list(range(len(lens)))
-    assert on == off
-    assert routes_on["pallas"] == traced_layers and not routes_on["xla"]
+    assert on == off and shapes == shapes_off
+    assert routes_on["pallas"] == shapes * traced_layers
+    assert not routes_on["xla"]
     assert list(routes_on["why"]) == ["forced on by HETU_TPU_PALLAS=1"]
-    assert routes_off["xla"] == traced_layers and not routes_off["pallas"]
+    assert routes_off["xla"] == shapes * traced_layers
+    assert not routes_off["pallas"]

@@ -41,6 +41,14 @@ def _engine(model, params, registry=None, run_log=None, **cfg_kw):
         registry=registry or MetricsRegistry(), run_log=run_log)
 
 
+def launches(reg):
+    """{rows: launches of the chunk program that carried so many}
+    (`serve.prefill_launches{rows}`)."""
+    return {int(c["labels"]["rows"]): int(c["value"])
+            for c in reg.snapshot()["counters"]
+            if c["name"] == "serve.prefill_launches"}
+
+
 # ---------------------------------------------------------------- pool
 def test_pool_alloc_free_recycle():
     pool = _pool(num_pages=6)
@@ -343,13 +351,18 @@ def test_page_reservation_gates_admission():
 
 
 # -------------------------------------------------------------- engine
-def test_chunked_prefill_interleaves_with_decode(tiny_llama):
-    """Prefill/decode disaggregation contract: a multi-chunk prompt
-    advances ONE chunk per engine step while already-running slots keep
-    producing a token every step — a long admission never stalls the
-    decode batch."""
+@pytest.mark.parametrize("others", [0, 1], ids=["alone", "shared"])
+def test_chunked_prefill_interleaves_with_decode(tiny_llama, others):
+    """Prefill/decode disaggregation contract: a step computes ONE
+    chunk's prompt rows a prefilling slot, so a multi-chunk prompt
+    prefilling alone advances one chunk per engine step, while
+    already-running slots keep producing a token every step — a long
+    admission never stalls the decode batch.  With another prompt in
+    prefill beside it (`shared`) the step's rows, two chunks', go to the
+    OLDER slot first, in one launch: it advances two chunks a step and
+    the younger waits, then prefills alone."""
     model, params = tiny_llama
-    eng = _engine(model, params, num_slots=2, prefill_chunk=8)
+    eng = _engine(model, params, num_slots=3, prefill_chunk=8)
     short = Request(rid=0, prompt=np.arange(1, 5, dtype=np.int32),
                     max_new_tokens=12)
     long = Request(rid=1, prompt=np.arange(1, 25, dtype=np.int32),
@@ -361,19 +374,28 @@ def test_chunked_prefill_interleaves_with_decode(tiny_llama):
     eng.step(0.5)    # its first decode is dispatched, and stays queued
     assert len(st0.generated) == 1 and st0.inflight == 1
     eng.submit(long, now=1.0)
-    for k in range(1, 4):
+    if others:       # admitted behind it, in the same step
+        eng.submit(Request(rid=2, prompt=np.arange(3, 22, dtype=np.int32),
+                           max_new_tokens=2), now=1.0)
+    #: (the long prompt's, the other's) chunks done after each step
+    want = {0: [(1, 0), (2, 0), (3, 0)],
+            1: [(2, 0), (3, 1), (3, 2), (3, 3)]}[others]
+    for k, (mine, theirs) in enumerate(want, start=1):
         eng.step(float(k))
         st1 = eng.scheduler.slots[1]
-        if k < 3:   # chunks 1..2 of 3: still prefilling...
-            assert st1.prefilling and st1.chunks_done == k
-        else:       # chunk 3 lands: first token emitted, joins decode
-            assert not st1.prefilling
+        assert st1.chunks_done == mine and st1.prefilling == (mine < 3)
+        if others:
+            st2 = eng.scheduler.slots[2]
+            assert st2.chunks_done == theirs
+            assert st2.prefilling == (theirs < 3)
         # ...while the short request gained a token EVERY step (the one
         # dispatched the step before), with the next one queued
         assert len(st0.generated) == 1 + k and st0.inflight == 1
+    assert launches(eng._registry) == (
+        {8: 5, 16: 1} if others else {8: 4})
     # both finish cleanly and the long one's tokens match generate()
     results = []
-    now = 4.0
+    now = 5.0
     while eng.scheduler.active_slots():
         results.extend(eng.step(now))
         now += 1.0
@@ -598,6 +620,11 @@ def test_a_program_is_its_body_under_one_builder(case, monkeypatch):
     step = "verify" if "spec" in case else "decode"
     bodies = {step: f"{step}_fn", "prefill_chunk": "chunk_fn",
               "write_pages": "write_fn"}
+    # (the chunk program a launch shape, one to four chunks, is the ONE
+    # body under the one name: a trace's `chunk_fn` is all its launches)
+    bodies.update({f"prefill_chunk_x{k}": "chunk_fn"
+                   for k in eng._launch_multiples[1:]})
+    assert len(bodies) == 6
     lowered = eng.lower_programs()
     assert sorted(lowered) == sorted(bodies)
     for name, body in bodies.items():

@@ -416,8 +416,8 @@ def test_a_stalled_step_is_counted_kept_logged_and_says_why(
         rec["step_s"] + rec["gap_s"] - rec["median_s"])
     assert rec["step_s"] > profiling.STALL_FACTOR * rec["median_s"]
     assert max(rec["phases"], key=rec["phases"].get) == "admit"
-    assert set(rec["dispatched"]) == {"chunk_launches", "decode_batch",
-                                      "fetch_behind"}
+    assert set(rec["dispatched"]) == {"chunk_launches", "prefill_chunks",
+                                      "decode_batch", "fetch_behind"}
     assert rec["dispatched"]["decode_batch"] == 3
     assert {"cpu_s", "gap_s", "gc", "compiles", "compile_s",
             "fetch_wait_s", "bytes_in_use", "median_s"} <= set(rec)
@@ -464,7 +464,8 @@ def test_a_step_is_judged_among_the_steps_of_its_kind():
     """Steps that dispatch more take longer and are not stalled for it
     (on the chip one window for all steps counted a hundred three-chunk
     steps of a run as stalled decode steps): a step is judged against
-    the steps of its `kind`, the engine's being its chunk launches."""
+    the steps of its `kind`, the engine's being the chunks of prompt rows
+    its chunk launches carried."""
     registry = MetricsRegistry()
     rec, step = _timed_steps(registry)
     for _ in range(profiling.STALL_MIN_STEPS + 2):

@@ -507,7 +507,10 @@ class KVAttention:
         row's chunk of C > 1 queries at one start, the chunk program of
         chunked prefill, takes the blockwise kernel
         (ops/pallas/chunk_attention: the scores stay on the chip, and
-        only the key blocks the chunk can see are read) where
+        only the key blocks the chunk can see are read; under a window
+        much narrower than the slice, only the band of key blocks each
+        block of positions can see: `kernel_routes["chunk_attn_band"]`
+        says which tiling a traced layer took and why) where
         `ops.pallas.resolve_route` and the kernel's gate allow: a TPU,
         key and value widths % 128, C a multiple of the sublane tile, a
         cache length that divides into key blocks of a multiple of 128,
@@ -554,6 +557,12 @@ class KVAttention:
                 f"{nq // caches[0].shape[-2]}, "
                 f"{'a sink a head' if sink is not None else 'no sink'}")
         if kernel:
+            # which tiling the kernel takes, from the shapes it is handed
+            plan = _ca.check_shapes(
+                q.shape, caches[0].shape, jnp.shape(start), window=window,
+                dtype=caches[0].dtype, v_shape=caches[1].shape,
+                sink=sink is not None)
+            _note_route("chunk_attn_band", bool(plan.pb), plan.why)
             with jax.named_scope("pallas_chunk_attention"):
                 out = _ca.chunk_attention(q, *caches, start,
                                           softmax_scale=self.softmax_scale(hd),

@@ -22,6 +22,24 @@ program (PERF.md s6, PR 35).  Here:
   `pl.when`.  What such a step costs is the grid step itself (~0.35 us
   on a v5e).  A full layer over a scratch of `max_len` positions pays
   for the prompt's length, not for `max_len`;
+* **under a window far narrower than the slice, a row tile walks its OWN
+  band of key blocks** (the band form, `band_plan`).  A tile of one
+  head's whole chunk can see every key of the `window + C` positions a
+  window layer is handed, so all of them are multiplied and masked:
+  MiMo's 1,024 queries that see 128 keys each paid for 1,152 (PERF.md
+  s6, PR 49).  In the band form q is laid `[n_kv, (C // pb) * g * pb,
+  hd]`, row `(c // pb) * g * pb + gi * pb + c % pb`: a tile is ALL g
+  query heads of the KV head at ONE block of `pb` consecutive positions,
+  so its rows share one band of `pb + window - 1` keys.  The key index
+  map takes the tile's index: from the block that holds position
+  `q0 + r * pb - window + 1` to the block of `q0 + r * pb + pb - 1`,
+  kept inside the chunk's live blocks (`_tile_blocks`, from the same
+  three prefetched scalars), and the grid's third extent is the STATIC
+  number of key blocks a band can touch, not `M // kb`.  A step past the
+  band is clamped and skipped as a dead step is.  Same body, same
+  `pallas_call`; the form is chosen from `window`, C, M and the group
+  alone (the band's key blocks must fit `_BAND_PAYS` times in M), and
+  `check_shapes` returns it with the reason (`Plan`);
 * **the mask inside a block is by global positions**, `_attend_cached_
   chunk`'s rule: key k is seen by the query at position t iff k <= t
   and, under a window, k > t - window.  A block every entry of which is
@@ -33,9 +51,9 @@ program (PERF.md s6, PR 35).  Here:
   float32;
 * **one KV head's whole group of query heads** is one tall operand: q
   is laid out `[n_kv, g * C, hd]`, row gi * C + c the query of head
-  (kvh, gi) at position start + c, and a row tile of it (up to
-  `_ROW_TILE` rows) meets each key block `[kb, hd]` of its KV head in
-  one MXU product.
+  (kvh, gi) at position start + c (the band form's order: above), and a
+  row tile of it (up to `_ROW_TILE` rows) meets each key block `[kb,
+  hd]` of its KV head in one MXU product.
 
 **This module owns the online softmax** (`softmax_init`,
 `softmax_step`, `softmax_finish`: the statistics of a row tile over its
@@ -67,10 +85,10 @@ lowered program is what it was.
 
 Shape contract (`check_shapes`, drift-tested against `compatible`): ONE
 row (b = 1) at ONE start, C > 1 queries, C a multiple of the sublane
-tile of the dtype, the key and the value head dims multiples of 128 lanes, q heads a multiple of the
-KV heads, and a cache length M that `fit_block` divides into key blocks
-of a multiple of 128 (a cache shorter than that is one block, if a
-multiple of the sublane tile).  Rows at depths of their own (the verify
+tile of the dtype, the key and the value head dims multiples of 128
+lanes, q heads a multiple of the KV heads, and a cache length M that
+`fit_block` divides into key blocks of a multiple of 128 (a cache
+shorter than that is one block, if a multiple of the sublane tile).  Rows at depths of their own (the verify
 step, the gather decode route) and single queries are refused: they keep
 the composition.  The ROUTE's gate (`check_route`) also refuses what the
 kernel takes but does not pay for: fewer than 64 MB of float32 scores in
@@ -80,7 +98,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -105,6 +123,11 @@ _VMEM_LIMIT = 48 << 20
 _MIN_SCORE_BYTES = 64 << 20
 #: bytes of one array's block of positions in the relayout kernel
 _RELAY_BLOCK_BYTES = 1 << 20
+#: the band form (a window layer whose row tiles are blocks of positions):
+#: its key block, and how many times a tile's band of key blocks has to fit
+#: in the M keys the whole-chunk tile multiplies before the band is taken
+_BAND_KEY_BLOCK = 128
+_BAND_PAYS = 2
 
 
 def _row_tile(C: int, group: int) -> int:
@@ -112,7 +135,10 @@ def _row_tile(C: int, group: int) -> int:
     (as many query heads of the group as `_ROW_TILE` holds) or, for a
     chunk longer than that, its largest divisor within it that keeps the
     sublane tiling (a multiple of 16).  Either way a tile's rows are at
-    consecutive positions modulo C."""
+    consecutive positions modulo the tile's span of positions (C, or the
+    tile itself): row i of a tile stands at position `c_lo + i % span`,
+    which holds of the band form's tiles (`band_plan`: every head of the
+    group at ONE block of `pb` positions, span `pb`) as well."""
     if C <= _ROW_TILE:
         heads = max(m for m in range(1, group + 1)
                     if group % m == 0 and m * C <= _ROW_TILE)
@@ -123,13 +149,57 @@ def _row_tile(C: int, group: int) -> int:
     return t
 
 
+class Plan(NamedTuple):
+    """What `check_shapes` makes of a call's shapes: the sizes, the row
+    tile `tr` and key block `kb`, the key blocks a row tile walks
+    (`steps`, the grid's third extent) and, in the band form, the
+    positions a tile spans (`pb`; 0: the present tiling), with the
+    reason for the form either way (`why`: the `kernel_routes` line's)."""
+    C: int
+    nq: int
+    hd: int
+    M: int
+    n_kv: int
+    tr: int
+    kb: int
+    steps: int
+    pb: int
+    why: str
+
+
+def band_plan(C: int, M: int, group: int, window) -> Tuple[int, int, int, str]:
+    """-> (pb, kb, steps, why): the band form's tiling, from the shapes
+    alone, or pb = 0 where the present tiling stays.  A tile of the band
+    form is every head of the group at ONE block of `pb` consecutive
+    positions (the largest multiple of 128 that divides C and keeps
+    `group * pb` within `_ROW_TILE`), so its rows share a band of
+    `pb + window - 1` keys, which touches at most `steps` key blocks of
+    `kb` wherever it begins.  It is taken where a tile's band
+    (`steps * kb` keys) fits `_BAND_PAYS` times in the M keys a tile of
+    the whole chunk multiplies."""
+    if window is None:
+        return 0, 0, 0, "no window: a tile of the whole chunk sees every " \
+                        "key up to its own, the present tiling"
+    pb = max((p for p in range(128, C + 1, 128)
+              if C % p == 0 and group * p <= _ROW_TILE), default=0)
+    kb = fit_block(_BAND_KEY_BLOCK, M)
+    if not pb or kb % 128:
+        return 0, 0, 0, (f"no block of positions (C {C}, groups of {group}) "
+                         f"or of keys (M {M}) that is a multiple of 128")
+    steps = (pb + window - 3) // kb + 2
+    said = (f"{pb} positions a tile see {pb + window - 1} keys: {steps} "
+            f"key blocks of {kb} against the chunk's {M}")
+    if _BAND_PAYS * steps * kb > M:
+        return 0, 0, 0, f"{said}, over 1/{_BAND_PAYS}: the present tiling"
+    return pb, kb, steps, f"{said}, pb {pb}, kb {kb}, steps {steps}"
+
+
 def check_shapes(q_shape, k_shape, start_shape=(), *, window=None,
-                 dtype=None, v_shape=None, sink: bool = False
-                 ) -> Tuple[int, int, int, int, int, int, int]:
-    """-> (C, nq, hd, M, n_kv, row tile, key block), or ValueError with
-    the reason the composition takes the shape instead.  `v_shape`: V's
-    where its head dim is its own (None: K's); `sink`: a sink a query
-    head enters the softmax (any shape the kernel takes, takes one)."""
+                 dtype=None, v_shape=None, sink: bool = False) -> Plan:
+    """-> the `Plan`, or ValueError with the reason the composition
+    takes the shape instead.  `v_shape`: V's where its head dim is its
+    own (None: K's); `sink`: a sink a query head enters the softmax (any
+    shape the kernel takes, takes one)."""
     if len(q_shape) != 4 or len(k_shape) != 4:
         raise ValueError(f"expected q [b, C, nq, hd] and k [b, M, n_kv, "
                          f"hd], got {q_shape} / {k_shape}")
@@ -163,7 +233,10 @@ def check_shapes(q_shape, k_shape, start_shape=(), *, window=None,
     if kb % 128 and (M > 128 or M % sub):
         raise ValueError(f"cache length {M} has no key block that is a "
                          f"multiple of 128 (best: {kb})")
-    return C, nq, hd, M, n_kv, tr, kb
+    pb, band_kb, steps, why = band_plan(C, M, nq // n_kv, window)
+    if pb:
+        tr, kb = nq // n_kv * pb, band_kb
+    return Plan(C, nq, hd, M, n_kv, tr, kb, steps or M // kb, pb, why)
 
 
 def check_route(q_shape, k_shape, start_shape=(), *, window=None,
@@ -263,16 +336,40 @@ def softmax_finish(o_ref, m_scr, l_scr, acc_scr):
         .astype(o_ref.dtype)
 
 
-def _kernel(s_ref, q_ref, *refs, scale, window, C, tr, kb, sink=False):
+def _tile_blocks(s, r, pb: int, window, kb: int):
+    """(first, number) of the key blocks row tile `r` walks, from the
+    prefetched s = (b0, live, q0): the chunk's live blocks b0 .. b0 +
+    live - 1 (`live_blocks`) or, in the band form (`pb`), those of them
+    from the block that holds the window's first key of the tile's first
+    position q0 + r * pb to the block that holds its last position.  The
+    kernel and its key index map both ask here."""
+    if not pb:
+        return s[0], s[1]
+    c_lo = s[2] + r * pb
+    last = s[0] + s[1] - 1
+    lo = jnp.clip(jax.lax.div(jnp.maximum(c_lo - window + 1, 0), kb),
+                  s[0], last)
+    hi = jnp.clip(jax.lax.div(jnp.maximum(c_lo + pb - 1, 0), kb), s[0], last)
+    return lo, hi - lo + 1
+
+
+def _kernel(s_ref, q_ref, *refs, scale, window, C, tr, kb, sink=False, pb=0):
     # `sink`: one more operand [tr, 1] after q, each row's sink
     sink_ref, refs = (refs[0], refs[1:]) if sink else (None, refs)
     k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
     r, j = pl.program_id(1), pl.program_id(2)
     b0, live, q0 = s_ref[0], s_ref[1], s_ref[2]
     # the tile's rows sit at positions q0 + c_lo .. q0 + c_hi of the
-    # cache as it was handed in (q0 = start - first)
-    c_lo = (r * tr) % C if tr < C else 0
-    c_hi = c_lo + min(tr, C) - 1
+    # cache as it was handed in (q0 = start - first), row i at
+    # c_lo + i % span; it walks `live` key blocks from block b0
+    if pb:
+        # the band form: every head of the group at positions r * pb ..
+        c_lo, span = r * pb, pb
+    else:
+        c_lo = (r * tr) % C if tr < C else 0
+        span = min(tr, C)
+    c_hi = c_lo + span - 1
+    b0, live = _tile_blocks((b0, live, q0), r, pb, window, kb)
 
     stats = (m_scr, l_scr, acc_scr)
     pl.when(j == 0)(lambda: softmax_init(*stats, sink_ref))
@@ -283,7 +380,11 @@ def _kernel(s_ref, q_ref, *refs, scale, window, C, tr, kb, sink=False):
                                 preferred_element_type=jnp.float32) * scale
         if masked:
             i = jax.lax.broadcasted_iota(jnp.int32, (tr, 1), 0)
-            qpos = q0 + (jax.lax.rem(i, C) if tr > C else c_lo + i)
+            if tr > span:
+                # several heads a tile: of whole chunks (c_lo = 0), or
+                # of one block of positions each (the band form)
+                i = jax.lax.rem(i, span)
+            qpos = q0 + (i if tr > span and not pb else c_lo + i)
             kpos = (b0 + j) * kb + jax.lax.broadcasted_iota(
                 jnp.int32, (1, kb), 1)
             seen = kpos <= qpos
@@ -358,7 +459,7 @@ def chunk_attention(q, k, v, start, *, softmax_scale: Optional[float] = None,
     [nq]: a scalar a query head in the softmax's denominator.  Returns
     [1, C, nq, hd_v].  Raises ValueError on shapes outside `compatible`
     (`models/generation._attend_cached_chunk` takes those)."""
-    C, nq, hd, M, n_kv, tr, kb = check_shapes(
+    C, nq, hd, M, n_kv, tr, kb, steps, pb, _ = check_shapes(
         q.shape, k.shape, jnp.shape(start), window=window, dtype=k.dtype,
         v_shape=v.shape, sink=sink is not None)
     hd_v = v.shape[-1]
@@ -369,9 +470,14 @@ def chunk_attention(q, k, v, start, *, softmax_scale: Optional[float] = None,
     first = jnp.asarray(first, jnp.int32)
     b0, live = live_blocks(start, C, M, kb, window, first)
     scalars = jnp.stack([b0, live, start - first]).astype(jnp.int32)
-    # one KV head's group of query heads as one tall operand
-    qh = q[0].reshape(C, n_kv, g, hd).transpose(1, 2, 0, 3) \
-        .reshape(n_kv, R, hd)
+    # one KV head's group of query heads as one tall operand, [n_kv, R,
+    # hd]: row gi * C + c, or in the band form (c // pb) * g * pb +
+    # gi * pb + c % pb, carries head (kvh, gi) at position start + c
+    if pb:
+        qh = q[0].reshape(C // pb, pb, n_kv, g, hd).transpose(2, 0, 3, 1, 4)
+    else:
+        qh = q[0].reshape(C, n_kv, g, hd).transpose(1, 2, 0, 3)
+    qh = qh.reshape(n_kv, R, hd)
     if n_kv == 1:
         # one K/V head: the slab [M, 1, hd] IS head-major
         kh, vh = k[0].reshape(1, M, hd), v[0].reshape(1, M, hd_v)
@@ -379,7 +485,8 @@ def chunk_attention(q, k, v, start, *, softmax_scale: Optional[float] = None,
         kh, vh = _relay_heads(scalars, k[0], v[0], kb)
 
     def key_block(h, r, j, s):
-        return h, s[0] + jnp.minimum(j, s[1] - 1), 0
+        lo, n = _tile_blocks(s, r, pb, window, kb)
+        return h, lo + jnp.minimum(j, n - 1), 0
 
     def rows(d):
         return pl.BlockSpec((None, tr, d), lambda h, r, j, s: (h, r, 0))
@@ -389,17 +496,21 @@ def chunk_attention(q, k, v, start, *, softmax_scale: Optional[float] = None,
 
     operands, in_specs = [qh.astype(k.dtype)], [rows(hd)]
     if sink is not None:
-        # row gi * C + c of KV head kvh carries head (kvh, gi)'s sink
-        operands.append(jnp.broadcast_to(
-            sink.astype(jnp.float32).reshape(n_kv, g, 1, 1),
-            (n_kv, g, C, 1)).reshape(n_kv, R, 1))
-        in_specs.append(rows(1))
+        # a row carries its head's sink, in q's row order; the band
+        # form's tiles of one KV head all hold the same column of g x pb
+        # rows: one block a head, fetched once
+        sk = sink.astype(jnp.float32).reshape(n_kv, g, 1, 1)
+        operands.append(jnp.broadcast_to(sk, (n_kv, g, pb or C, 1))
+                        .reshape(n_kv, -1, 1))
+        in_specs.append(pl.BlockSpec((None, tr, 1), lambda h, r, j, s:
+                                     (h, 0, 0)) if pb else rows(1))
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, window=window, C=C, tr=tr,
-                          kb=kb, **({} if sink is None else {"sink": True})),
+                          kb=kb, **({} if sink is None else {"sink": True}),
+                          pb=pb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(n_kv, R // tr, M // kb),
+            grid=(n_kv, R // tr, steps),
             in_specs=in_specs + [keys(hd), keys(hd_v)],
             out_specs=rows(hd_v),
             scratch_shapes=[pltpu.VMEM((tr, 1), jnp.float32),
@@ -411,5 +522,8 @@ def chunk_attention(q, k, v, start, *, softmax_scale: Optional[float] = None,
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
     )(scalars, *operands, kh, vh)
-    return out.reshape(n_kv, g, C, hd_v).transpose(2, 0, 1, 3) \
-        .reshape(1, C, nq, hd_v)
+    if pb:
+        out = out.reshape(n_kv, C // pb, g, pb, hd_v).transpose(1, 3, 0, 2, 4)
+    else:
+        out = out.reshape(n_kv, g, C, hd_v).transpose(2, 0, 1, 3)
+    return out.reshape(1, C, nq, hd_v)

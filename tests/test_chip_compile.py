@@ -169,17 +169,19 @@ def _paged_serving_cell():
                 spec((slots, 128), I32), spec((slots,), I32))
 
 
-def _chunk_attn(C, nq, n_kv, M, window=None):
+def _chunk_attn(C, nq, n_kv, M, window=None, hd=HEAD_DIM, sink=False):
     """The chunk program's attention as a serving cell's layer calls it
     (`KVAttention.attend_dense`): one row's chunk against the layer's
-    slab of the scratch, or the window + C positions sliced out of it."""
+    slab of the scratch, or the window + C positions sliced out of it;
+    `hd`: keys wider than the values; `sink`: one a query head."""
     from hetu_tpu.ops.pallas.chunk_attention import chunk_attention
-    kv = spec((1, M, n_kv, HEAD_DIM), BF16)
 
-    def fn(q, k, v, start, first):
-        return chunk_attention(q, k, v, start, window=window, first=first)
-    return fn, (spec((1, C, nq, HEAD_DIM), BF16), kv, kv, spec((1,), I32),
-                spec((), I32))
+    def fn(q, k, v, start, first, *sinks):
+        return chunk_attention(q, k, v, start, window=window, first=first,
+                               **({"sink": sinks[0]} if sinks else {}))
+    return fn, (spec((1, C, nq, hd), BF16), spec((1, M, n_kv, hd), BF16),
+                spec((1, M, n_kv, HEAD_DIM), BF16), spec((1,), I32),
+                spec((), I32), *((spec((nq,), F32),) if sink else ()))
 
 
 def _latent_chunk_attn(C, nh, M):
@@ -235,6 +237,9 @@ KERNEL_CASES = {
     "chunk_attention_trinity_window": lambda: _chunk_attn(
         512, 32, 4, 2560, window=2048),
     "chunk_attention_internlm2": lambda: _chunk_attn(128, 16, 8, 2048),
+    # MiMo's window layers: the band form (tiles of 8 heads x 128 positions)
+    "chunk_attention_mimo_window_band": lambda: _chunk_attn(
+        1024, 64, 8, 1152, window=128, hd=256, sink=True),
     "latent_chunk_attention_ling": lambda: _latent_chunk_attn(
         2048, 32, 32768),
     "latent_chunk_attention_kimi": lambda: _latent_chunk_attn(512, 64, 4096),
@@ -469,7 +474,7 @@ def _mimo_block():
         vocab_size=19072, num_hidden_layers=3, experts_held=16,
         hybrid_layer_pattern=(0, 1, 0), moe_layer_freq=(0, 1, 1),
         param_dtype=BF16)), dict(num_slots=16, page_size=64, max_len=16384,
-                                 prefill_chunk=512, num_pages=(4096, 48))
+                                 prefill_chunk=1024, num_pages=(4096, 48))
 
 
 def _ling_block():
@@ -675,16 +680,25 @@ def test_serving_programs_compile_for_one_v5e(family):
         # both kinds of layer take both kernels at their own shapes: keys
         # of 256 lanes against values of 128, groups of 16 and 8, the
         # window layer with its sink; the window layer's scratch holds
-        # 128 + 512 positions, the full layers' 16,384
+        # 128 + 1,024 positions, the full layers' 16,384.  The window
+        # layer's chunk attention takes the band form (a tile is its 8
+        # heads at ONE block of 128 positions and walks 3 key blocks of
+        # 128, not the slice's 1,152 keys); the two full layers say why not
         rec = routes["chunk_attn"]
         assert 2 * rec["pallas"] == chunk_calls == 6 and not rec["xla"]
+        band = routes["chunk_attn_band"]
+        assert (band["pallas"], band["xla"]) == (1, 2), band
+        assert sorted(w.split(":")[0] for w in band["why"]) == [
+            "128 positions a tile see 255 keys", "no window"]
+        assert any(w.endswith("pb 128, kb 128, steps 3") for w in band["why"])
         assert routes["paged_attn_window"]["pallas"] == 1
         why = routes["paged_attn_shapes"]["why"]
         assert sorted(why.values()) == [1, 2] and any(
             "groups of 8, a sink a head" in w for w in why)
-        assert engine._scratch_positions == (16384, 640)
+        assert engine._scratch_positions == (16384, 1152)
+        # (154 MB at the cell's 1,024 rows, in either tiling)
         assert compiled["prefill_chunk"].memory_analysis() \
-            .temp_size_in_bytes < 0.15e9
+            .temp_size_in_bytes < 0.2e9
     else:
         # one gate for every launch shape: the float32 scores of a layer,
         # heads x rows x 2,048 positions, from 64 MB on take the kernel

@@ -10,6 +10,7 @@ served with the kernel forced on and off.
 """
 from __future__ import annotations
 
+import functools
 import os
 import sys
 
@@ -59,39 +60,96 @@ CASES = {
 }
 
 
-@pytest.mark.parametrize("case", CASES)
+#: the band form (a window far narrower than the slice: a row tile is
+#: the group's heads at ONE block of 128 positions and walks its own
+#: band of key blocks): name -> (C, start, sink).  Window 128 over the
+#: slab of window + C positions `attend_dense` cuts, n_kv 2, groups of
+#: 4, keys of 256 lanes beside values of 128; the starts clip the band
+#: at the cache's head (0, 5), leave it unaligned to the key blocks (5,
+#: 127: three live blocks a tile) and whole (128, 1000: two).
+BAND_WINDOW, BAND_HD_K = 128, 256
+BAND_CASES = {
+    f"band_c{C}_start{start}{'_sink' if sink else ''}": (C, start, sink)
+    for C in (256, 384) for start in (0, 5, 127, 128, 1000)
+    for sink in (False, True)}
+
+
+def _case(name):
+    """-> (C, g, n_kv, M, start, window, first, dtype, row tile, key
+    width, sink, times the band has to fit) of a case of either table."""
+    if name in CASES:
+        return CASES[name] + (HD, False, 2)
+    C, start, sink = BAND_CASES[name]
+    M = BAND_WINDOW + C
+    return (C, 4, 2, M, start, BAND_WINDOW, max(0, start + C - M),
+            BF16 if sink and C == 384 else F32, 512, BAND_HD_K, sink, 1)
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted(window, *patched):
+    """One jit a (window, patched constants): the constants are read
+    while tracing, so a trace is good for the values it was made under."""
+    return jax.jit(lambda q, k, v, s, f, sink: ca.chunk_attention(
+        q, k, v, s, window=window, first=f, sink=sink))
+
+
+@pytest.mark.parametrize("case", list(CASES) + list(BAND_CASES))
 def test_chunk_kernel_is_the_composition(case, monkeypatch):
     """ONE body: the kernel's output is `_attend_cached_chunk`'s (to
     float32 reassociation; to bfloat16 rounding of the probabilities for
     bfloat16 caches), and every key block the chunk cannot see (past
     the block of position start + C - 1; before the block of the
     window's first position) is never read: filled with NaN, it changes
-    nothing."""
-    C, g, n_kv, M, start, window, first, dtype, tile = CASES[case]
+    nothing.  In the band form a TILE reads its own band alone: with
+    every block outside the last tile's band NaN, that tile's rows are
+    still the composition's."""
+    (C, g, n_kv, M, start, window, first, dtype, tile, hd_k, has_sink,
+     pays) = _case(case)
     monkeypatch.setattr(ca, "_KEY_BLOCK", KB)
     monkeypatch.setattr(ca, "_ROW_TILE", tile)
-    ks = jax.random.split(jax.random.key(hash(case) % 1000), 3)
-    q = jax.random.normal(ks[0], (1, C, g * n_kv, HD), F32).astype(dtype)
-    k = jax.random.normal(ks[1], (1, M, n_kv, HD), F32).astype(dtype)
+    monkeypatch.setattr(ca, "_BAND_PAYS", pays)
+    ks = jax.random.split(jax.random.key(hash(case) % 1000), 4)
+    q = jax.random.normal(ks[0], (1, C, g * n_kv, hd_k), F32).astype(dtype)
+    k = jax.random.normal(ks[1], (1, M, n_kv, hd_k), F32).astype(dtype)
     v = jax.random.normal(ks[2], (1, M, n_kv, HD), F32).astype(dtype)
-    want = _attend_cached_chunk(q, k, v, start, HD ** -0.5, window=window,
-                                first=first)
-    lo = 0 if window is None else max(0, start - window + 1 - first) // KB
-    hi = (start + C - 1 - first) // KB
+    sink = jax.random.normal(ks[3], (g * n_kv,), F32) if has_sink else None
+    plan = ca.check_shapes(q.shape, k.shape, (), window=window, dtype=dtype,
+                           v_shape=v.shape, sink=has_sink)
+    band = case in BAND_CASES
+    assert (plan.pb, plan.tr, plan.kb, plan.steps) == (
+        (128, g * 128, KB, 3) if band else (0, plan.tr, KB, M // KB))
+    want = np.asarray(_attend_cached_chunk(
+        q, k, v, start, hd_k ** -0.5, window=window, first=first,
+        **({"sink": sink} if has_sink else {})), np.float32)
+    fn = _jitted(window, tile, pays)
     pos = np.arange(M) // KB
-    dead = jnp.asarray((pos < lo) | (pos > hi))[None, :, None, None]
-    assert dead.any() or M // KB == hi - lo + 1
-    got = jax.jit(lambda q, k, v, s, f: ca.chunk_attention(
-        q, k, v, s, window=window, first=f))(
-            q, jnp.where(dead, jnp.nan, k), jnp.where(dead, jnp.nan, v),
-            jnp.int32(start), jnp.int32(first))
-    assert got.shape == q.shape and got.dtype == q.dtype
+    atol = 2e-5 if dtype == F32 else 2e-2
+
+    def got(lo_pos, hi_pos):
+        """the kernel's, with every key block outside those of cache
+        positions lo_pos .. hi_pos (relative to `first`) NaN"""
+        lo, hi = max(0, lo_pos) // KB, hi_pos // KB
+        dead = jnp.asarray((pos < lo) | (pos > hi))[None, :, None, None]
+        assert dead.any() or M // KB == hi - lo + 1
+        out = fn(q, jnp.where(dead, jnp.nan, k), jnp.where(dead, jnp.nan, v),
+                 jnp.int32(start), jnp.int32(first), sink)
+        assert out.shape == (1, C, g * n_kv, HD) and out.dtype == q.dtype
+        return np.asarray(out, np.float32)
+
+    q0 = start - first
     np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(want, np.float32),
-        atol=2e-5 if dtype == F32 else 2e-2, rtol=0)
+        got(0 if window is None else q0 - window + 1, q0 + C - 1), want,
+        atol=atol, rtol=0)
+    if band:
+        t0 = C - plan.pb        # the last tile's first position
+        np.testing.assert_allclose(
+            got(q0 + t0 - window + 1, q0 + C - 1)[:, t0:], want[:, t0:],
+            atol=atol, rtol=0)
 
 
-#: the benchmark's cells: (q, k, start shapes), window, (row tile, key block)
+#: the benchmark's cells: (q, k, start shapes), window, (row tile, key
+#: block), and where a cell's keys are wider than its values, V's shape;
+#: the LAST entry of a band-form plan is (pb, steps)
 CELL_SHAPES = {
     "trinity_full": (((1, 512, 32, 128), (1, 8192, 4, 128), (1,)), None,
                      (1024, 1024)),
@@ -101,6 +159,20 @@ CELL_SHAPES = {
                   (256, 1024)),
     # a cache shorter than a lane tile is one key block
     "cache_of_64": (((1, 16, 4, 128), (1, 64, 2, 128), ()), None, (32, 64)),
+    # the rule of the band form, from window, C, M and the group alone:
+    # MiMo's window layers (128 positions a tile see 2 x 128 of the 1,152
+    # keys) take it; a window as wide as the chunk or wider (Phi, Trinity
+    # above) and every call without a window keep the present tiling
+    "mimo_window_slab_band": (((1, 1024, 64, 256), (1, 1152, 8, 256), (1,)),
+                              128, (1024, 128), (1, 1152, 8, 128), (128, 3)),
+    "mimo_full": (((1, 1024, 64, 256), (1, 16384, 4, 256), (1,)), None,
+                  (1024, 1024), (1, 16384, 4, 128)),
+    "phi_window_slab": (((1, 512, 40, 128), (1, 1024, 10, 128), (1,)), 512,
+                        (1024, 1024)),
+    "internlm2_512_rows": (((1, 512, 16, 128), (1, 2048, 8, 128), (1,)),
+                           None, (1024, 1024)),
+    "jamba_one_kv_head": (((1, 512, 20, 128), (1, 4608, 1, 128), (1,)), None,
+                          (1024, 768)),
 }
 
 #: what the gate refuses, and a word of its reason
@@ -130,18 +202,23 @@ def test_gate_drift_chunk_attention(case):
     then carries, and the kernel itself raises the same (`check_route`,
     the gate the hook hands to `resolve_route`, raises it too)."""
     if case in CELL_SHAPES:
-        shapes, window, tiles = CELL_SHAPES[case]
-        assert ca.compatible(*shapes, window=window, dtype=BF16)
-        assert ca.check_shapes(*shapes, window=window,
-                               dtype=BF16)[-2:] == tiles
+        shapes, window, tiles, *more = CELL_SHAPES[case]
+        kw = dict(window=window, dtype=BF16,
+                  v_shape=more[0] if more else None)
+        pb, steps = more[1] if more[1:] else (0, shapes[1][1] // tiles[1])
+        assert ca.compatible(*shapes, **kw)
+        plan = ca.check_shapes(*shapes, **kw)
+        assert (plan.tr, plan.kb, plan.pb, plan.steps) == (*tiles, pb, steps)
+        assert ("pb 128, kb 128, steps 3" in plan.why) == bool(pb)
+        assert ("no window" in plan.why) == (window is None)
         # the ROUTE's gate also asks whether the kernel pays: Trinity's
-        # 168 / 537 MB of float32 scores do, InternLM2's 16.8 MB do not
-        if case.startswith("trinity"):
-            assert ca.check_route(*shapes, window=window,
-                                  dtype=BF16)[-2:] == tiles
-        else:
+        # 168 / 537 MB of float32 scores do (MiMo's 302 MB, and exactly
+        # 64 MB in the 512-row launches), InternLM2's 16.8 MB do not
+        if case in ("internlm2", "cache_of_64"):
             with pytest.raises(ValueError, match="MB of float32 scores"):
-                ca.check_route(*shapes, window=window, dtype=BF16)
+                ca.check_route(*shapes, **kw)
+        else:
+            assert ca.check_route(*shapes, **kw) == plan
         return
     shapes, word = REFUSED[case]
     assert not ca.compatible(*shapes, dtype=F32)
@@ -158,17 +235,22 @@ def _force(monkeypatch, on: bool):
     monkeypatch.setenv("HETU_TPU_PALLAS_KERNELS", "chunk_attn")
 
 
-@pytest.mark.parametrize("call", ["chunk", "chunk_window", "decode_rows",
-                                  "verify_rows", "not_a_tpu"])
+@pytest.mark.parametrize("call", ["chunk", "chunk_window", "chunk_band",
+                                  "decode_rows", "verify_rows", "not_a_tpu"])
 def test_attend_dense_routes_by_what_it_observes(call, monkeypatch):
     """The hook asks the one routing rule for ONE row's chunk and for
     nothing else: a single query and rows at depths of their own keep
     the composition whatever the flags say, and so does every backend
-    but a TPU; the record says which and why.  Routed or not, the values
-    are the composition's."""
+    but a TPU; the record says which and why, and of a layer the kernel
+    takes also which tiling (`chunk_attn_band`: the band form with its
+    pb, kb and steps, or why not).  Routed or not, the values are the
+    composition's."""
     b, C, window, start = {
         "chunk": (1, 16, None, jnp.asarray([40], jnp.int32)),
         "chunk_window": (1, 16, 112, jnp.asarray([130], jnp.int32)),
+        # groups of 8 at 640 rows under a window of 128: blocks of 128
+        # positions see 3 x 128 of the 768 keys `attend_dense` slices
+        "chunk_band": (1, 640, 128, jnp.asarray([300], jnp.int32)),
         "decode_rows": (3, 1, None, jnp.asarray([5, 40, 17], jnp.int32)),
         "verify_rows": (3, 4, None, jnp.asarray([5, 40, 17], jnp.int32)),
         "not_a_tpu": (1, 16, None, jnp.asarray([40], jnp.int32)),
@@ -177,23 +259,36 @@ def test_attend_dense_routes_by_what_it_observes(call, monkeypatch):
         monkeypatch.delenv("HETU_TPU_PALLAS", raising=False)
     else:
         _force(monkeypatch, True)
+    nq, n_kv, M = (8, 1, 1024) if call == "chunk_band" else (4, 2, 256)
     ks = jax.random.split(jax.random.key(5), 3)
-    q = jax.random.normal(ks[0], (b, C, 4, HD), F32)
-    k = jax.random.normal(ks[1], (b, 256, 2, HD), F32)
-    v = jax.random.normal(ks[2], (b, 256, 2, HD), F32)
+    q = jax.random.normal(ks[0], (b, C, nq, HD), F32)
+    k = jax.random.normal(ks[1], (b, M, n_kv, HD), F32)
+    v = jax.random.normal(ks[2], (b, M, n_kv, HD), F32)
     kw = {} if window is None else {"window": window}
     with record_routes() as routes:
         got = KVAttention().attend_dense(None, q, (k, v), start, **kw)
     rec = routes["chunk_attn"]
-    kernel = call in ("chunk", "chunk_window")
+    kernel = call.startswith("chunk")
     assert (rec["pallas"], rec["xla"]) == ((1, 0) if kernel else (0, 1))
     why, = rec["why"]
     assert {"chunk": "forced on", "chunk_window": "forced on",
+            "chunk_band": "forced on",
             "decode_rows": "single query", "verify_rows": "single query",
             "not_a_tpu": "not a TPU backend"}[call] in why
+    # the tiling is a question of the kernel's: no line where it is not taken
+    assert ("chunk_attn_band" in routes) == kernel
+    if kernel:
+        band = routes["chunk_attn_band"]
+        assert (band["pallas"], band["xla"]) == (
+            (1, 0) if call == "chunk_band" else (0, 1))
+        why, = band["why"]
+        assert {"chunk": "no window",
+                "chunk_window": "no block of positions (C 16, groups of 2)",
+                "chunk_band": "3 key blocks of 128 against the chunk's 768, "
+                              "pb 128, kb 128, steps 3"}[call] in why
     want = _attend_cached_chunk(q, k, v, start, HD ** -0.5, window=window)
     np.testing.assert_allclose(np.asarray(got),
-                               np.asarray(want).reshape(b, C, 4 * HD),
+                               np.asarray(want).reshape(b, C, nq * HD),
                                atol=2e-5, rtol=0)
 
 

@@ -76,6 +76,12 @@ it are the model's hooks:
       a state block that says `hands_on` replaces it by the third value
       its `state_chunk` / `state_step` returned; a block that says
       `takes_handed` is given it as `handed=`.
+  block.mlp_hands_on, block.mlp_takes_handed         (OPTIONAL)
+      The same carry from the MLP side: a block that says `mlp_hands_on`
+      has `mlp_stats` return (y, stats, what it hands on), one that says
+      `mlp_takes_handed` has it given what was handed, as `handed=` (a
+      branch computed from one layer's post-attention norm and added
+      after a later layer's MLP: a shortcut-connected expert layer).
   A LAYER THAT READS ANOTHER LAYER'S ENTRIES (the contract's `reads[l]` =
       k) has `project` return no entries, `()`: the programs write none
       and hand its `attend_*` hooks layer k's cache arrays, or its pages
@@ -380,7 +386,10 @@ def _layer(block, lp, h, rope, pos_ids, cache_step, state_step=None,
     `handed` is what an earlier layer handed on (None where none has): a
     block that says `takes_handed` is given it (`handed=` of `mix`), a
     state block that says `hands_on` replaces it by what its hook returns
-    third, every other block passes it on untouched.
+    third, every other block passes it on untouched.  The MLP side may
+    do both as well: `mlp_takes_handed` gives `mlp_stats` what was handed
+    (`handed=`), `mlp_hands_on` replaces it by what `mlp_stats` returns
+    third.
     Returns (h, the layer's stats, handed, *rest)."""
     window = getattr(block, "window", None)
     win = {} if window is None else {"window": window}
@@ -402,8 +411,12 @@ def _layer(block, lp, h, rope, pos_ids, cache_step, state_step=None,
             attn, *rest = cache_step(block.attn, lp["attn"], q, entries, win)
             h = h + block.attn.output(lp["attn"], attn, *aux)
     with jax.named_scope("mlp"):
-        y, st = block.mlp_stats(lp["mlp"],
-                                block.post_norm(lp["post_norm"], h))
+        y, st, *new = block.mlp_stats(
+            lp["mlp"], block.post_norm(lp["post_norm"], h),
+            **({"handed": handed} if getattr(
+                block, "mlp_takes_handed", False) else {}))
+        if getattr(block, "mlp_hands_on", False):
+            (handed,) = new
         h = h + y
     return (h, st, handed, *rest)
 

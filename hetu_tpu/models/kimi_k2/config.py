@@ -38,6 +38,11 @@ class KimiK2Config:
     rope_scaling: Optional[dict] = None
     tie_word_embeddings: bool = False
     initializer_range: float = 0.02
+    #: what `MLAttention.project` multiplies the low-rank query and the
+    #: normed latent by (no key of the published config: the published
+    #: model has neither; models/longcat_flash states both)
+    mla_q_lora_scale: float = 1.0
+    mla_kv_lora_scale: float = 1.0
     #: the experts this chip holds of each layer's `n_routed_experts`
     #: (None = all of them: the whole layer)
     first_expert: int = 0

@@ -37,9 +37,8 @@ from hetu_tpu.models.cache_contract import CacheContract
 from hetu_tpu.models.kimi_k2.config import KimiK2Config
 from hetu_tpu.nn import initializers as init
 from hetu_tpu.nn.module import Module
-from hetu_tpu.nn.moe import (MOE_STATS, _IS_MAX,  # noqa: F401
-                             SharedRoutedExperts, add_moe_stats,
-                             moe_layer_stats, zero_moe_stats)
+from hetu_tpu.nn.moe import (MOE_STATS, SharedRoutedExperts,
+                             add_moe_stats, moe_layer_stats, zero_moe_stats)
 from hetu_tpu.nn.parallel import ParallelRMSNorm, VocabParallelEmbedding
 from hetu_tpu.parallel.strategy import ParallelStrategy
 
@@ -62,13 +61,20 @@ class MLAttention(Module):
         self.param("wkv_b", (c.kv_lora_rank, nh,
                              c.qk_nope_head_dim + c.v_head_dim), w, dtype=dt)
         self.param("wo", (nh * c.v_head_dim, c.hidden_size), w, dtype=dt)
+        #: factors on the low-rank query after W_qb and on the normed
+        #: latent before W_kvb and the cache (LongCat-Flash's
+        #: `mla_scale_q_lora` / `mla_scale_kv_lora`); at 1.0, Kimi's, no
+        #: product
+        self.q_lora_scale = c.mla_q_lora_scale
+        self.kv_lora_scale = c.mla_kv_lora_scale
 
     # -- how a token's cache entry is made ---------------------------------
     def project(self, params, hn, rope, pos_ids):
         """hn [b, s, h] (normed) at positions pos_ids [b, s] ->
         (q, entries): q = (q_nope [b, s, nh, dn], q_rope [b, s, nh, dr],
         rotated); entries = (latent [b, s, stored],), the token's cache
-        entry [RMSNorm(c_kv) | RoPE(k_rope) | 0 ...]."""
+        entry [RMSNorm(c_kv) | RoPE(k_rope) | 0 ...] (the normed latent
+        times `kv_lora_scale`: what is cached is what W_kvb takes)."""
         c = self.config
         cos, sin = rope
         r, dn = c.kv_lora_rank, c.qk_nope_head_dim
@@ -77,13 +83,20 @@ class MLAttention(Module):
                              hn @ params["wq_a"].astype(hn.dtype))
             q = (cq @ params["wq_b"].astype(hn.dtype)).reshape(
                 cq.shape[:-1] + (c.num_attention_heads, c.qk_head_dim))
+            if self.q_lora_scale != 1.0:
+                q = (q.astype(jnp.float32) * self.q_lora_scale).astype(q.dtype)
             q_rope = ops.apply_rotary(q[..., dn:], cos, sin, pos_ids)
         with jax.named_scope("mla_kv"):
             ckv = hn @ params["wkv_a"].astype(hn.dtype)
             k_rope = ops.apply_rotary(ckv[..., None, r:], cos, sin,
                                       pos_ids)[..., 0, :]
+            c_kv = self.kv_norm(params["kv_norm"], ckv[..., :r])
+            if self.kv_lora_scale != 1.0:
+                # (in float32: sqrt(12) is no bfloat16 number)
+                c_kv = (c_kv.astype(jnp.float32)
+                        * self.kv_lora_scale).astype(c_kv.dtype)
             latent = jnp.concatenate(
-                [self.kv_norm(params["kv_norm"], ckv[..., :r]), k_rope]
+                [c_kv, k_rope]
                 + ([jnp.zeros(ckv.shape[:-1] + (
                     c.latent_stored_dim - c.latent_dim,), ckv.dtype)]
                    if c.latent_stored_dim > c.latent_dim else []), axis=-1)

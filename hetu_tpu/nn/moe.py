@@ -506,11 +506,40 @@ def noaux_tc_gate(x, w_gate, bias, *, top_k: int, norm_topk_prob: bool,
         kept = jnp.any(keep[:, :, None] == jnp.arange(n_group), axis=1)
         choice = jnp.where(kept[:, :, None], by_group,
                            -jnp.inf).reshape(T, E)
+    return _top_k_weights(scores, choice, top_k, norm_topk_prob,
+                          routed_scaling_factor)
+
+
+def _top_k_weights(scores, choice, top_k, norm_topk_prob, factor):
+    """The `top_k` largest of `choice` [T, E] and their weights: `scores`
+    there, over their sum where `norm_topk_prob`, times `factor`."""
     _, idx = jax.lax.top_k(choice, top_k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if norm_topk_prob:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    return idx.astype(jnp.int32), w * routed_scaling_factor
+    return idx.astype(jnp.int32), w * factor
+
+
+def softmax_gate(x, w_gate, bias, *, top_k: int, norm_topk_prob: bool,
+                 routed_scaling_factor: float):
+    """`noaux_tc_gate` scored by SOFTMAX over all of the router's outputs
+    (LongCat-Flash: 512 routed + 256 identity experts in one softmax), in
+    float32 as the published code computes it.  The `top_k` outputs of a
+    token are the largest of softmax(x W_g) + b; their weights are the
+    softmax scores WITHOUT b at those outputs, over their sum where
+    `norm_topk_prob`, times the scaling factor.  No groups: a softmax
+    over all outputs has none in any published model
+    (`SharedRoutedExperts` refuses them).
+    Returns (output ids [T, k] int32, weights [T, k] float32)."""
+    scores = jax.nn.softmax(jnp.einsum(
+        "th,he->te", x.astype(jnp.float32), w_gate.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    return _top_k_weights(scores, scores + bias.astype(jnp.float32), top_k,
+                          norm_topk_prob, routed_scaling_factor)
+
+
+#: the router's scoring function by its published name
+GATES_BY_SCORING = {"sigmoid": noaux_tc_gate, "softmax": softmax_gate}
 
 
 def dropless_local_experts(x, idx, weights, w_gate_up, w_down, *,
@@ -593,10 +622,22 @@ class SharedRoutedExperts(Module):
     order of `STATS`: (token, expert) pairs chosen, pairs on held
     experts, held experts with at least one token, row blocks the
     grouped product walked beyond the first (`dropless_local_experts`),
-    the largest load of a held expert)."""
+    the largest load of a held expert).
+
+    **Identity experts** (`n_zero_experts` > 0: LongCat-Flash's
+    zero-compute experts): the router has that many outputs MORE, behind
+    the routed ones (ids `n_routed_experts` ..), chosen and weighted in
+    the same top-k; a chosen identity expert returns its input, so a
+    token's addend is (the sum of its weights on them) x the token,
+    computed where the token lives, under the scope `zero_experts`: no
+    weights, no exchange, all of them "held" by every chip.  `share`,
+    which sizes the grouped product's row blocks, is then the held
+    experts over ALL the router's outputs, and `stats` has one entry
+    more at its end (`ZERO_STATS`): the pairs on identity experts."""
 
     STATS = ("assignments", "local_assignments", "expert_hits",
              "extra_row_blocks", "max_expert_load")
+    ZERO_STATS = STATS + ("zero_assignments",)
 
     def __init__(self, hidden: int, inter: int, *, n_routed_experts: int,
                  experts_held: int, first_expert: int, top_k: int,
@@ -604,30 +645,35 @@ class SharedRoutedExperts(Module):
                  routed_scaling_factor: float, param_dtype=jnp.float32,
                  initializer_range: float = 0.02,
                  bias_range: float = 0.02, n_group: int = 1,
-                 topk_group: int = 1):
+                 topk_group: int = 1, scoring: str = "sigmoid",
+                 n_zero_experts: int = 0):
         super().__init__()
+        self.gate = GATES_BY_SCORING[scoring]
+        self.n_routed, self.n_zero = n_routed_experts, n_zero_experts
+        outputs = n_routed_experts + n_zero_experts
         if n_routed_experts % n_group or not 0 < topk_group <= n_group:
             raise ValueError(f"{n_routed_experts} experts in {n_group} "
                              f"groups, {topk_group} of them kept")
         self.groups = (dict(n_group=n_group, topk_group=topk_group)
                        if n_group > 1 else {})
+        if self.groups and scoring != "sigmoid":
+            raise ValueError(f"a {scoring} gate limited to groups")
         if not 0 <= first_expert <= n_routed_experts - experts_held:
             raise ValueError(
                 f"experts {first_expert}..{first_expert + experts_held - 1}"
                 f" are not among the router's {n_routed_experts}")
         self.first_expert, self.held = first_expert, experts_held
-        self.share = experts_held / n_routed_experts
+        self.share = experts_held / outputs
         self.top_k, self.norm = top_k, norm_topk_prob
         self.scaling = routed_scaling_factor
         w = init.normal(initializer_range)
         # the router's weights are float32 whatever the model's dtype:
         # the published gate is computed in float32
-        self.param("w_gate", (hidden, n_routed_experts), w,
-                   dtype=jnp.float32)
+        self.param("w_gate", (hidden, outputs), w, dtype=jnp.float32)
         # a buffer of the published model (its update rule is not part of
         # `config`); random, small and non-zero here so that choosing by
         # s + b and weighting by s differ
-        self.param("e_score_correction_bias", (n_routed_experts,),
+        self.param("e_score_correction_bias", (outputs,),
                    init.normal(bias_range), dtype=jnp.float32)
         # gate|up fused as [.., hidden, 2 I] (gate columns, then up): a
         # [.., hidden, 2, I] weight is tiled (2, 128) on the chip and
@@ -644,7 +690,7 @@ class SharedRoutedExperts(Module):
             self.param("shared_down", (si, hidden), w, dtype=param_dtype)
 
     def route(self, params, xt):
-        return noaux_tc_gate(
+        return self.gate(
             xt, params["w_gate"], params["e_score_correction_bias"],
             top_k=self.top_k, norm_topk_prob=self.norm,
             routed_scaling_factor=self.scaling, **self.groups)
@@ -664,10 +710,17 @@ class SharedRoutedExperts(Module):
                 si = gu.shape[-1] // 2
                 y = y + (jax.nn.silu(gu[:, :si]) * gu[:, si:]) \
                     @ params["shared_down"].astype(x.dtype)
-        stats = jnp.stack([
-            jnp.int32(idx.size), jnp.sum(counts),
-            jnp.sum((counts > 0).astype(jnp.int32)), extra,
-            jnp.max(counts)])
+        stats = [jnp.int32(idx.size), jnp.sum(counts),
+                 jnp.sum((counts > 0).astype(jnp.int32)), extra,
+                 jnp.max(counts)]
+        if self.n_zero:
+            with jax.named_scope("zero_experts"):
+                zero = idx >= self.n_routed
+                w_zero = jnp.sum(jnp.where(zero, weights, 0.0), axis=-1)
+                y = y + (w_zero[:, None] * xt.astype(jnp.float32)) \
+                    .astype(x.dtype)
+            stats.append(jnp.sum(zero.astype(jnp.int32)))
+        stats = jnp.stack(stats)
         return y.reshape(b, s, h), stats
 
 
@@ -680,18 +733,29 @@ MOE_STATS = tuple(
     [(f"serve.moe_{name}", "sum")
      for name in SharedRoutedExperts.STATS[:-1] + ("layer_steps",)]
     + [(f"serve.moe_{SharedRoutedExperts.STATS[-1]}", "max")])
-_IS_MAX = np.array([how == "max" for _, how in MOE_STATS])
+#: the same with identity experts (`SharedRoutedExperts.ZERO_STATS`): the
+#: pairs on them, behind the others
+ZERO_MOE_STATS = MOE_STATS + (
+    (f"serve.moe_{SharedRoutedExperts.ZERO_STATS[-1]}", "sum"),)
 
 
-def zero_moe_stats():
-    return jnp.zeros((len(MOE_STATS),), jnp.int32)
+def stats_ops(stats):
+    """(zero_stats, add_stats) of a model whose `STATS` is `stats`."""
+    is_max = np.array([how == "max" for _, how in stats])
+
+    def zero():
+        return jnp.zeros((len(stats),), jnp.int32)
+
+    def add(a, b):
+        return jnp.where(is_max, jnp.maximum(a, b), a + b)
+    return zero, add
 
 
-def add_moe_stats(a, b):
-    return jnp.where(_IS_MAX, jnp.maximum(a, b), a + b)
+zero_moe_stats, add_moe_stats = stats_ops(MOE_STATS)
 
 
 def moe_layer_stats(st):
-    """One execution of a `SharedRoutedExperts` layer (its `stats`) as a
-    MOE_STATS vector: one layer step."""
-    return jnp.concatenate([st[:-1], jnp.ones((1,), jnp.int32), st[-1:]])
+    """One execution of a `SharedRoutedExperts` layer (its `stats`, with
+    or without the identity experts' count at the end) as a MOE_STATS /
+    ZERO_MOE_STATS vector: one layer step, behind the four sums."""
+    return jnp.concatenate([st[:4], jnp.ones((1,), jnp.int32), st[4:]])

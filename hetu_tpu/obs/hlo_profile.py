@@ -174,7 +174,9 @@ def group_of(op_name: str, phases: Tuple[str, ...] = PHASES) -> str:
 #: `ssm_out`: nn/mamba), a gated memory unit (`gmu`: models/phi4_flash), and what
 #: the chunk program runs for the rows whose logits are read alone and
 #: that no inner scope names (`tail`: models/generation.extend_cache; a
-#: layer's own scopes inside the tail keep their groups).  `diff_out`,
+#: layer's own scopes inside the tail keep their groups); the addend of
+#: the identity experts a token chose (`mlp` > `zero_experts`:
+#: nn/moe.SharedRoutedExperts told of them, models/longcat_flash).  `diff_out`,
 #: what follows the attention kernel in a differential-attention layer,
 #: is a scope of the operations' paths and NOT a group: its time stays
 #: with its kind of layer.  No program without these scopes changes its
@@ -185,7 +187,8 @@ SCOPE_MAP_GROUPS = ("kv_write", "loss", "mla_q", "mla_kv", "mla_out",
                     "kda", "kda_proj", "kda_conv", "kda_scan", "kda_step",
                     "kda_out",
                     "attn_cross", "ssm", "ssm_proj", "ssm_conv", "ssm_scan",
-                    "ssm_step", "ssm_out", "gmu", "tail", "ssm_norm")
+                    "ssm_step", "ssm_out", "gmu", "tail", "ssm_norm",
+                    "zero_experts")
 UNSCOPED = "unscoped"
 _INSTR_PAT = re.compile(r'^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=')
 _OPERAND_PAT = re.compile(r'%([\w.\-]+)')

@@ -2,10 +2,12 @@
 owed since PR 40): the registry of per-layer metrics, `BENCHMARK.json`'s
 `per_layer` list and the rule files under `benchmarks/metrics/`, held to
 each other, to the limits of the file and to the cost functions of each
-listed cell's family, where the driver counts; and the three newest families'
-files with their CPU rehearsals, from `benchmarks/tests/test_ling_family.py`,
+listed cell's family, where the driver counts; and three families' files
+with their CPU rehearsals, from `benchmarks/tests/test_ling_family.py`,
 `benchmarks/tests/test_phi4flash_family.py` and
-`benchmarks/tests/test_jamba_family.py`.
+`benchmarks/tests/test_jamba_family.py` (the newest family's twin is
+`tests/test_benchmark_longcat.py`: a file is what one worker of the
+tier-1 run takes whole).
 The tests are the benchmark's own, imported and called: nothing is written
 twice."""
 import importlib.util

@@ -519,6 +519,19 @@ def _jamba_whole():
             num_pages=4624, max_prefilling=8)
 
 
+def _longcat_cut():
+    """LongCat-Flash-Chat as its cell serves it: the WHOLE cut (4
+    published layers = 8 latent cache layers, 16 of 512 routed experts
+    held, all 256 identity experts, 16,384 vocabulary rows) at published
+    widths, at the cell's slots, page, chunk and max_len."""
+    from benchmarks import traffic
+    from benchmarks.families import longcat_flash
+    cfg = traffic.load_json("configs", "longcat-flash-ep32-depth4")
+    sv = cfg["serving"]
+    return longcat_flash.build_model(cfg, sv), {k: sv[k] for k in (
+        "num_slots", "page_size", "max_len", "prefill_chunk", "num_pages")}
+
+
 #: family -> ((model, ServeConfig overrides) or None for the Llama-2-7B
 #: block, the kernels its decode program must hold as tpu_custom_calls)
 SERVING_FAMILIES = {
@@ -530,10 +543,16 @@ SERVING_FAMILIES = {
     "ling": (_ling_block, ("paged_latent",)),
     "phi4flash": (_phi4flash_block, ("paged_attn",)),
     "jamba": (_jamba_whole, ("paged_attn",)),
+    "longcat": (_longcat_cut, ("paged_latent",)),
 }
+#: the families whose case runs from a file of its own
+#: (tests/test_chip_compile_longcat.py): a file is what one worker of the
+#: tier-1 run takes whole, and this one is among the longest
+ELSEWHERE = ("longcat",)
 
 
-@pytest.mark.parametrize("family", SERVING_FAMILIES)
+@pytest.mark.parametrize("family", [
+    f for f in SERVING_FAMILIES if f not in ELSEWHERE])
 def test_serving_programs_compile_for_one_v5e(family):
     """The engine's decode step (with the family's paged-attention kernel
     walking the page tables), prefill chunk and page write, for 8 slots x
@@ -578,10 +597,11 @@ def test_serving_programs_compile_for_one_v5e(family):
     latent_calls = [ln for ln in chunk_text
                     if 'custom_call_target="tpu_custom_call"' in ln
                     and "pallas_latent_chunk_attention" in ln]
-    if family in ("kimi", "ling"):
+    if family in ("kimi", "ling", "longcat"):
         rec = routes["latent_chunk_attn"]
-        assert rec["pallas"] == len(latent_calls) == (
-            2 if family == "kimi" else 1) and not rec["xla"], rec
+        assert rec["pallas"] == len(latent_calls) == {
+            "kimi": 2, "ling": 1, "longcat": 8}[family] \
+            and not rec["xla"], rec
         assert list(rec["why"]) == ["shape gate passes"]
         assert all("attn/pallas_latent_chunk_attention" in ln
                    for ln in latent_calls)
@@ -672,6 +692,31 @@ def test_serving_programs_compile_for_one_v5e(family):
         assert "ssm_norm" in compiled["decode"].as_text()
     elif family == "kimi":
         assert "chunk_attn" not in routes and not chunk_calls
+    elif family == "longcat":
+        # TWO latent cache layers a published layer: the paged latent
+        # kernel 8 times a decode pass, the latent chunk kernel 8 times
+        # a chunk, one expert layer a published layer (4 routers); the
+        # pool (976 pages x 8 cache layers of 640 lanes) is carried in
+        # place, and weights + pool + the largest program's temporaries
+        # fit the chip
+        assert "chunk_attn" not in routes and not chunk_calls
+        assert sum("pallas_paged_latent_attention" in ln
+                   for ln in calls) == 8 == routes["paged_latent"]["pallas"]
+        assert engine.pool.arrays.k.shape == (8, 977, 256, 640)
+        pool = sum(a.size * a.dtype.itemsize
+                   for a in engine.pool.arrays.tree())
+        assert pool == 8 * 977 * 256 * 640 * 2
+        mem = {name: c.memory_analysis() for name, c in compiled.items()}
+        assert mem["decode"].alias_size_in_bytes >= pool
+        assert mem["decode"].temp_size_in_bytes < 0.3e9
+        assert mem["prefill_chunk"].temp_size_in_bytes < 1.0e9
+        weights = 2 * engine.model.num_params() + 4 * 2 * 6144 * 768
+        assert all(m.argument_size_in_bytes + m.temp_size_in_bytes
+                   < 15.5e9 for m in mem.values())
+        assert mem["decode"].argument_size_in_bytes > weights + pool
+        for name in ("decode", "prefill_chunk"):
+            text = compiled[name].as_text()
+            assert text.count("zero_experts") and "router" in text
     elif family == "trinity":
         rec = routes["chunk_attn"]
         assert 2 * rec["pallas"] == chunk_calls == 16 and not rec["xla"]

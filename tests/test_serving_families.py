@@ -215,6 +215,10 @@ def _family_case(family, hd128):
         from test_bailing_hybrid import build, ref_logits
         cfg, model, params = build()
         return model, params, lambda p, ids: ref_logits(p, cfg, ids)
+    if family == "longcat":
+        from test_longcat import build, ref_logits
+        cfg, model, params = build()
+        return model, params, lambda p, ids: ref_logits(p, cfg, ids)
     if family == "gpt":
         from hetu_tpu.models.gpt import GPTConfig, GPTLMHeadModel
         kw = dict(hidden_size=256, num_attention_heads=2) if hd128 else {}
@@ -231,16 +235,26 @@ def _family_case(family, hd128):
             jax.jit(lambda p, ids: model(p, ids[None])[0]))
 
 
-@pytest.mark.parametrize("family,route", [
+#: the families whose cache is a latent a token (models/kimi_k2.MLAttention)
+LATENT = ("kimi", "ling", "longcat")
+
+
+#: the cases of `test_a_family_is_served_by_its_hooks` by the file that
+#: runs them: a file is what one worker of the tier-1 run takes whole, and
+#: all of them in this one made it the run's last, alone, for minutes
+#: (tests/test_serving_families_latent.py, .._window.py)
+HERE = [
     ("hooks", "composition"), ("hooks", "paged"),
     ("llama", "composition"), ("llama", "paged"),
     ("llama-unstacked", "composition"),
     ("gpt", "composition"), ("gpt", "paged"),
-    ("kimi", "xla"), ("kimi", "kernel"),
-    ("hooks-window", "composition"), ("hooks-window", "paged"),
-    ("trinity", "composition"), ("trinity", "paged"),
-    ("mimo", "composition"), ("mimo", "paged"),
-    ("ling", "xla"), ("ling", "kernel")])
+    ("hooks-window", "composition"), ("hooks-window", "paged")]
+LATENT_CASES = [(f, r) for f in LATENT for r in ("xla", "kernel")]
+WINDOW_CASES = [(f, r) for f in ("trinity", "mimo")
+                for r in ("composition", "paged")]
+
+
+@pytest.mark.parametrize("family,route", HERE)
 def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
     """Golden, ONE body for every family: staggered continuous batching
     through the normal path (`run`: scheduler, allocator, page tables,
@@ -271,6 +285,11 @@ def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
     a sequence, held by slot beside the one latent layer's pages and
     carried by the chunk and the decode program (`state_chunk`,
     `state_step`), over the latent layer's two attentions as kimi.
+    `longcat` is the family whose published layer is TWO latent cache
+    layers with ONE expert branch handed from the first sublayer's MLP
+    side to the second's (`mlp_hands_on` / `mlp_takes_handed`), routed by
+    softmax over routed and identity experts, over the two latent
+    attentions as kimi.
     `llama-unstacked` is the Llama block built with
     use_scan=False: a layer's own arrays, called, never scanned."""
     monkeypatch.setenv("HETU_TPU_PALLAS",
@@ -291,7 +310,7 @@ def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
     eng = _engine(model, params, registry=reg, num_slots=4, page_size=8,
                   max_len=128, prefill_chunk=16, num_pages=64)
     results = {r.rid: r for r in eng.run(reqs)}
-    if family not in ("kimi", "ling"):
+    if family not in LATENT:
         # what every traced K/V layer chose, and why
         took = eng.kernel_routes["paged_attn"]
         if route == "paged":
@@ -300,7 +319,7 @@ def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
             assert took["xla"] and not took["pallas"]
             assert list(took["why"]) == [
                 "switched off by HETU_TPU_PALLAS / HETU_TPU_PALLAS_KERNELS"]
-    if family in ("kimi", "ling"):
+    if family in LATENT:
         assert eng.kernel_routes["paged_latent"][
             "pallas" if route == "kernel" else "xla"]
     if family == "ling":
@@ -314,7 +333,7 @@ def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
             np.concatenate([req.prompt, toks[:-1]]))))[req.prompt_len - 1:]
         gap = lg.max(-1) - lg[np.arange(len(toks)), toks]
         assert (gap <= 2e-4).all(), (req.rid, gap)
-        if family not in ("kimi", "ling") and route == "composition" \
+        if family not in LATENT and route == "composition" \
                 and family != "hooks":
             # (one program a prompt length: run op by op, `generate()`'s
             # prefill was most of these cases' seconds)

@@ -412,7 +412,7 @@ def test_the_eight_shares_sum_to_the_uncut_layer(rng):
 def test_moe_stats_live_beside_the_layer_and_kimi_re_exports_them():
     from hetu_tpu.models.kimi_k2 import model as kimi
     from hetu_tpu.nn import moe
-    for name in ("MOE_STATS", "zero_moe_stats", "add_moe_stats", "_IS_MAX"):
+    for name in ("MOE_STATS", "zero_moe_stats", "add_moe_stats"):
         assert getattr(kimi, name) is getattr(moe, name)
     _, model, _ = build()
     assert model.STATS is moe.MOE_STATS

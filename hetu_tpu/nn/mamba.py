@@ -22,7 +22,9 @@ later layers read it (Phi-4-flash's gated memory units).
 
 Scopes, inside the layer's own (`attn` > `ssm`): `ssm_proj` (W_in, W_x,
 W_dt), `ssm_conv`, `ssm_norm` (the three inner norms, where the family
-has them), `ssm_scan` (a chunk) / `ssm_step` (one position), `ssm_out`.
+has them), `ssm_scan` (a chunk; on a TPU `ssm_scan/pallas_selective_scan`,
+the kernel of `ops/pallas/selective_scan.py`) / `ssm_step` (one position),
+`ssm_out`.
 """
 from __future__ import annotations
 

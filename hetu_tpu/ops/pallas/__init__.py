@@ -30,6 +30,11 @@ The fused-kernel layer (docs/kernels.md):
                        attention layer (ops/delta_rule.chunk_scan): a
                        head's blocks walked inside one launch, its state
                        resident in VMEM
+  * selective_scan   — the selective scan of a Mamba-1 layer
+                       (ops/selective_scan.chunk_scan): a chunk's
+                       positions walked inside one launch, the state
+                       resident in VMEM and, 512 lanes at a time, in
+                       registers
   * sample           — fused last-layer epilogue: lm_head matmul +
                        temperature/top-k/top-p filter + Gumbel draw per
                        row without materializing [rows, vocab] logits
@@ -62,7 +67,8 @@ from typing import FrozenSet, Optional, Tuple
 #: every routable kernel name (the HETU_TPU_PALLAS_KERNELS vocabulary)
 KERNEL_NAMES = ("flash", "norm", "swiglu", "rotary", "quant", "paged_attn",
                 "paged_verify", "sample", "adam", "paged_latent",
-                "chunk_attn", "kda_scan", "latent_chunk_attn")
+                "chunk_attn", "kda_scan", "latent_chunk_attn",
+                "selective_scan")
 
 
 #: kernels `auto` leaves to XLA, and why: each was timed on the chip against

@@ -208,6 +208,18 @@ def _kda_scan():
              spec((1, 2048, 32), F32), spec((1,), I32)))
 
 
+def _selective_scan():
+    """The Mamba-1 scan at the Jamba and Phi cells' shape: one chunk of
+    512 rows, 5,120 channels of 16 state lanes, u', B and C in bfloat16
+    as the projections leave them."""
+    from hetu_tpu.ops.pallas.selective_scan import selective_scan
+    cols = lambda dt: spec((1, 512, 5120), dt)  # noqa: E731
+    return selective_scan, (
+        spec((1, 16, 5120), F32), cols(BF16), cols(F32),
+        spec((16, 5120), F32), spec((1, 512, 16), BF16),
+        spec((1, 512, 16), BF16), spec((5120,), F32), spec((1,), I32))
+
+
 def _quant(bits):
     from hetu_tpu.ops.pallas.quant import quantize_blockwise_pallas
     return (lambda x: quantize_blockwise_pallas(x, 128, bits=bits),
@@ -244,6 +256,7 @@ KERNEL_CASES = {
         2048, 32, 32768),
     "latent_chunk_attention_kimi": lambda: _latent_chunk_attn(512, 64, 4096),
     "kda_scan_ling_chunk": _kda_scan,
+    "selective_scan_jamba_chunk": _selective_scan,
     "quant_int8": lambda: _quant(8),
     "quant_int4": lambda: _quant(4),
 }
@@ -532,6 +545,20 @@ def _longcat_cut():
         "num_slots", "page_size", "max_len", "prefill_chunk", "num_pages")}
 
 
+def _scan_is_the_kernel(routes, chunk_text, decode_calls):
+    """A Mamba family's chunk program runs `ops/pallas/selective_scan.py`
+    once a traced Mamba layer body, under `ssm_scan`; its decode program
+    keeps `selective_scan.step`, the composition."""
+    rec = routes["selective_scan"]
+    scans = [ln for ln in chunk_text
+             if 'custom_call_target="tpu_custom_call"' in ln
+             and "pallas_selective_scan" in ln]
+    assert rec["pallas"] == len(scans) > 0 and not rec["xla"], rec
+    assert list(rec["why"]) == ["shape gate passes"]
+    assert all("ssm_scan/pallas_selective_scan" in ln for ln in scans)
+    assert not any("pallas_selective_scan" in ln for ln in decode_calls)
+
+
 #: family -> ((model, ServeConfig overrides) or None for the Llama-2-7B
 #: block, the kernels its decode program must hold as tpu_custom_calls)
 SERVING_FAMILIES = {
@@ -665,6 +692,10 @@ def test_serving_programs_compile_for_one_v5e(family):
         text = compiled["prefill_chunk"].as_text()
         assert "ssm_scan" in text and "tail" in text
         assert "ssm_step" in compiled["decode"].as_text()
+        # the scan is the kernel in every Mamba layer body of the chunk
+        # program (a period's scanned layers and the tail's: a decision a
+        # traced body), never in the decode program
+        _scan_is_the_kernel(routes, chunk_text, calls)
     elif family == "jamba":
         # ONE K/V head of bfloat16: the paged kernel takes both attention
         # layers (it refused such pages until PR 47: a token's row is
@@ -690,6 +721,7 @@ def test_serving_programs_compile_for_one_v5e(family):
         assert mem["prefill_chunk"].temp_size_in_bytes < 0.5e9
         assert "ssm_norm" in compiled["prefill_chunk"].as_text()
         assert "ssm_norm" in compiled["decode"].as_text()
+        _scan_is_the_kernel(routes, chunk_text, calls)
     elif family == "kimi":
         assert "chunk_attn" not in routes and not chunk_calls
     elif family == "longcat":

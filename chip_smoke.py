@@ -339,8 +339,8 @@ def serve_phase(cfg, serve_cfg, *, prompt_lens, max_new: int, seed: int,
           f"{name}: no prompt took several launches of the chunk program: "
           f"{chunks}")
 
-    # the decode program's compiled text (jit keeps its executable to
-    # itself, so this compiles it a second time: a compile-cache hit)
+    # the decode program's compiled text: lowered for the arguments the
+    # engine calls it with, so this is the executable `warmup` built
     decode_text = engine.lower_programs()["decode"].compile().as_text()
     # what the engine recorded while its programs were traced, and one
     # question nobody asked: the fused sampling epilogue's gate, as the

@@ -36,7 +36,8 @@ from hetu_tpu.obs.comm import (collective_report,  # noqa: F401
 from hetu_tpu.obs.hlo_profile import (PROFILE_SCHEMA,  # noqa: F401
                                       analytic_peak_hbm, layer_profile,
                                       layer_table, peak_hbm_estimate,
-                                      profile_record, scope_map)
+                                      profile_record, scope_map,
+                                      scope_sources)
 from hetu_tpu.obs.health import (HealthMonitor,  # noqa: F401
                                  NumericsHealthMonitor,
                                  ServingHealthMonitor,
@@ -71,6 +72,7 @@ __all__ = [
     "collective_report", "collective_table",
     "layer_table", "layer_profile", "peak_hbm_estimate",
     "analytic_peak_hbm", "profile_record", "scope_map",
+    "scope_sources",
     "PROFILE_SCHEMA",
     "PerfBudget", "BudgetError", "check_absolute", "diff_metrics",
     "extract_metrics",

@@ -23,11 +23,45 @@ text walk:
   over the hardware profile: PREDICTED times.
 
 * **the join key for MEASURED times** (`scope_map`) — {instruction
-  name: (group, pass)} from the same `op_name` metadata.  A profiler
-  trace names each device event by its HLO instruction and carries no
-  `op_name`; `benchmarks/scopes.py` looks the instruction up here and
-  sums measured device time per scope, forward, backward and
-  recomputed forward apart.
+  name: (group, pass)} over every instruction of the module.  A
+  profiler trace names each device event by its HLO instruction and
+  carries no `op_name`; `benchmarks/trace.py` looks the instruction up
+  here and sums measured device time per scope, forward, backward and
+  recomputed forward apart.  ONE resolver (`_resolve`) places every
+  instruction in exactly one group, in a fixed order:
+
+  (i)   its own `op_name`, where the path names a scope;
+  (ii)  a `fusion`, `call`, `while` or `conditional` that (i) leaves
+        without a group (the compiler's `.clone` fusions carry no
+        metadata at all): its called computations' instructions — in a
+        fusion the `convolution` / `dot` / `custom-call`'s group (a
+        product sets a fusion's cost), else the group most scoped
+        instructions of the body name, ties to the innermost scope;
+  (iii) what is still without (`copy`, `copy-start` / `-done`,
+        `bitcast_fusion`, a scan's slices, the gathers ZeRO's refresh
+        becomes): the group of its first scoped USER in the same
+        computation, seen through a `bitcast`; else, for an instruction
+        the compiler made (no `op_name`) and for a product that lost its
+        path (`ragged-dot-none`), of its first scoped OPERAND;
+  (iv)  else `unscoped`.
+
+  The pass comes from the instruction that gave the group.  What runs
+  nothing (`parameter`, `constant`, `tuple`, `get-tuple-element`,
+  `bitcast`) keeps what (i) gave it.  An inferred group is never a
+  kernel's row (`.../pallas_<kernel>`): only a kernel's own
+  instructions enter it, so a kernel's measured time and roofline share
+  mean what they meant.  `scope_sources` says which step placed each
+  instruction ("own" | "body" | "user" | "operand" | "none"): "own" is
+  what the program said, the others are INFERRED and can be wrong in
+  known ways — a relayout two scopes read goes to the first reader in
+  the text's order; a fusion whose body mixes two scopes goes whole to
+  the product's (or the majority's); a value that reaches its reader
+  through a `tuple` into a `while` (a weight copied once before a layer
+  scan) finds no reader in its own computation and stays `unscoped`;
+  an operation with a path but no scope (the final norm, a scan's own
+  stacking) may go to its reader but never to what fed it.  Programs
+  whose instructions all name their scope map exactly as by (i) alone.
+  `layer_table` takes its groups from the same resolver.
 
 ALL HLO-text parsing primitives (line anatomy, shapes, collectives,
 while-trip/call-graph multipliers, dot FLOPs, donation contracts) live
@@ -68,8 +102,9 @@ from __future__ import annotations
 import re
 from typing import Any, Dict, List, Optional, Tuple
 
-from hetu_tpu.obs.hlo_text import (BRANCH_PAT, DEF_PAT, OP_NAME_PAT,
-                                   OUT_PAT, REF_PAT, as_hlo_text,
+from hetu_tpu.obs.hlo_text import (BRANCH_PAT, CALLEE_PAT, DEF_PAT,
+                                   INSTR_PAT, OP_NAME_PAT, OUT_PAT, REF_PAT,
+                                   as_hlo_text,
                                    call_multipliers, dot_flops,
                                    entry_computation, line_wire_bytes,
                                    shape_bytes, split_computations)
@@ -190,9 +225,20 @@ SCOPE_MAP_GROUPS = ("kv_write", "loss", "mla_q", "mla_kv", "mla_out",
                     "ssm_step", "ssm_out", "gmu", "tail", "ssm_norm",
                     "zero_experts")
 UNSCOPED = "unscoped"
-_INSTR_PAT = re.compile(r'^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=')
-_OPERAND_PAT = re.compile(r'%([\w.\-]+)')
-_RAGGED_DOT = "ragged-dot"
+#: what `scope_sources` says of an instruction: its own `op_name` named
+#: the group; its called computation's instructions did; its first
+#: scoped user did; its first scoped operand did; nothing did
+OWN, BODY, USER, OPERAND, NONE = "own", "body", "user", "operand", "none"
+#: opcodes whose called computations decide their group in step (ii)
+_CALLERS = ("fusion", "call", "while", "conditional")
+#: a product sets its fusion's cost, so it names the fusion's group;
+#: and a product that lost its path (`ragged-dot-none`) belongs with
+#: the rows it multiplies where nothing reads it under a scope
+_PRODUCTS = ("convolution", "dot", "custom-call")
+#: opcodes that run nothing: a trace never shows them and steps (ii)
+#: and (iii) leave them as (i) left them
+_ALIASES = ("parameter", "constant", "get-tuple-element", "tuple",
+            "bitcast")
 
 
 def pass_of(op_name: str) -> str:
@@ -206,40 +252,191 @@ def pass_of(op_name: str) -> str:
     return "bwd" if "transpose(" in op_name else "fwd"
 
 
+def _layer_part(group: str) -> str:
+    """`group` without a kernel's segment: what an INFERRED placement
+    may enter (a copy that a Pallas call reads is the layer part's; only
+    a kernel's own instructions are its row)."""
+    return "/".join(seg for seg in group.split("/")
+                    if not seg.startswith(KERNEL_SCOPE_PREFIX)) or group
+
+
+class _Instr:
+    """One instruction line as the resolver reads it, and where it ends:
+    `group`, `ps` (the pass), `source`, `via` (the `op_name` that named
+    the group: its own, or that of the instruction it took it from) and
+    `depth`, how far down that path the scope that named the group
+    stands (a tie's innermost)."""
+    __slots__ = ("name", "opcode", "op_name", "operands", "callees",
+                 "group", "ps", "source", "via", "depth")
+
+    def __init__(self, name: str, line: str):
+        self.name = name
+        dm = DEF_PAT.search(line)
+        self.opcode = dm.group(3) if dm is not None else ""
+        om = OP_NAME_PAT.search(line)
+        self.op_name = om.group(1) if om is not None else ""
+        # the operands end where the attributes begin: `calls=%...`
+        # names a computation, never a value
+        rest = line[dm.end():] if dm is not None else ""
+        cut = rest.find("), ")
+        self.operands = REF_PAT.findall(rest if cut < 0 else rest[:cut])
+        self.callees = [c.group(1) for c in CALLEE_PAT.finditer(line)]
+        bm = BRANCH_PAT.search(line)
+        if bm is not None:
+            self.callees += REF_PAT.findall(bm.group(1))
+        self.group, self.source, self.depth = UNSCOPED, NONE, 0
+        self.ps, self.via = pass_of(self.op_name), self.op_name
+
+    def take(self, other: "_Instr", source: str):
+        """The group and pass of `other`, inferred: never its kernel's
+        row (`_layer_part`)."""
+        self.group = _layer_part(other.group)
+        self.ps, self.depth, self.source = other.ps, other.depth, source
+        self.via = other.via
+
+    def group_under(self, phases: Tuple[str, ...]) -> str:
+        """The group as `group_of` names it with `phases` alone known
+        (the static profile's coarser rows; "other" for `unscoped`):
+        read from the SAME path that named `group`, so the two cannot
+        disagree on where the instruction belongs."""
+        if self.source == NONE:
+            return "other"
+        group = group_of(self.via, phases)
+        return group if self.source == OWN else _layer_part(group)
+
+
+#: every scope the resolver knows: the measured join's groups
+_KNOWN = (*PHASES, *SCOPE_MAP_GROUPS)
+
+
+def _resolve(compiled_or_text,
+             comps: Optional[Dict[str, List[str]]] = None
+             ) -> Dict[str, _Instr]:
+    """THE attribution of every instruction of a module to one group:
+    `scope_map`, `scope_sources`, `layer_table` and `profile_record`
+    read this one walk, in the module docstring's fixed order, with
+    every group the measured join knows (`_KNOWN`).  A computation is
+    resolved whole, its callees first, before an instruction that calls
+    it looks at its body."""
+    comps = comps if comps is not None else split_computations(
+        as_hlo_text(compiled_or_text))
+    known = (*_KNOWN, *EXTRA_GROUPS)
+    parsed: Dict[str, List[_Instr]] = {}
+    for cname, lines in comps.items():
+        rows = parsed[cname] = []
+        for line in lines:
+            m = INSTR_PAT.match(line)
+            if m is not None:
+                rows.append(_Instr(m.group(1), line))
+    out: Dict[str, _Instr] = {}
+    done: set = set()
+
+    def from_body(ins: _Instr):
+        """(ii): the product's group where a fusion's body holds one,
+        else the group most scoped instructions of the called
+        computations name, ties to the innermost scope."""
+        body = [b for c in ins.callees for b in parsed.get(c, ())
+                if b.source in (OWN, BODY)]
+        pick = next((b for b in body if b.opcode in _PRODUCTS), None) \
+            if ins.opcode == "fusion" else None
+        if pick is None and body:
+            votes: Dict[str, List[_Instr]] = {}
+            for b in body:
+                votes.setdefault(b.group, []).append(b)
+            pick = max(votes.values(), key=lambda v: (
+                len(v), max(b.depth for b in v)))[0]
+        if pick is not None:
+            ins.take(pick, BODY)
+
+    def resolve(cname: str):
+        if cname in done:
+            return
+        done.add(cname)
+        rows = parsed.get(cname, ())
+        for ins in rows:
+            group = group_of(ins.op_name, _KNOWN)       # (i)
+            if group != "other":
+                ins.group, ins.source = group, OWN
+                ins.depth = max(i for i, s in enumerate(
+                    scope_segments(ins.op_name))
+                    if s in known or _LAYER_SEG_PAT.match(s)
+                    or s.startswith(KERNEL_SCOPE_PREFIX))
+            elif ins.opcode in _CALLERS:
+                for c in ins.callees:
+                    resolve(c)
+                from_body(ins)
+        # (iii) the first scoped user, the last instruction first (a
+        # module's text lists a value before what reads it, so a
+        # `copy-start` finds its `copy-done` placed), seen through a
+        # bitcast; then, for what the compiler made (no `op_name`) and
+        # for a product, the first scoped operand, first to last
+        by_name = {ins.name: ins for ins in rows}
+        users: Dict[str, List[_Instr]] = {}
+        for ins in rows:
+            for o in ins.operands:
+                if o in by_name and o != ins.name:
+                    users.setdefault(o, []).append(ins)
+
+        def readers(ins: _Instr):
+            for u in users.get(ins.name, ()):
+                if u.opcode == "bitcast" and u.source == NONE:
+                    yield from readers(u)
+                else:
+                    yield u
+
+        for ins in reversed(rows):
+            if ins.source == NONE and ins.opcode not in _ALIASES:
+                hit = next((u for u in readers(ins) if u.source != NONE),
+                           None)
+                if hit is not None:
+                    ins.take(hit, USER)
+        for ins in rows:
+            if ins.source == NONE and ins.opcode not in _ALIASES and (
+                    not ins.op_name or ins.opcode in _PRODUCTS):
+                hit = next((by_name[o] for o in ins.operands
+                            if o in by_name
+                            and by_name[o].source != NONE), None)
+                if hit is not None:
+                    ins.take(hit, OPERAND)
+        for ins in rows:
+            out[ins.name] = ins
+
+    for cname in comps:
+        resolve(cname)
+    return out
+
+
 def scope_map(compiled_or_text) -> Dict[str, Tuple[str, str]]:
     """{instruction name: (group, pass)} over EVERY instruction of the
-    post-optimization HLO module, fused ones included (a fusion carries
-    the `op_name` of its root).  `group` is `group_of`'s key with the
-    groups of SCOPE_MAP_GROUPS known too -- `layer/attn`,
-    `layer/attn/pallas_flash_attention`, `layer/kv_write`, `lm_head`,
-    `optimizer/pallas_adam`, ... -- and `unscoped` where the instruction
-    has no `op_name` or its path names no scope (the compiler's own
-    copies, a scan's slicing and stacking of its operands, the loss
-    scaling around the micro-batch loop).  `pass` is `pass_of`.
-    The TPU compiler lowers `jax.lax.ragged_dot` to custom calls whose
-    `op_name` is "ragged-dot-none", the jit path dropped: such a call
-    takes the group and pass of its first operand that has a scope (the
-    rows it multiplies), so a grouped product stays in the scope that
-    made its input.
+    post-optimization HLO module, fused ones included.  `group` is
+    `group_of`'s key with the groups of SCOPE_MAP_GROUPS known too --
+    `layer/attn`, `layer/attn/pallas_flash_attention`,
+    `layer/kv_write`, `lm_head`, `optimizer/pallas_adam`, ... -- found
+    in the module docstring's fixed order: the instruction's own
+    `op_name`; for a fusion, call, while or conditional the compiler
+    left without one, its body's; for what is still without (the
+    compiler's copies and relayouts, a scan's slices, the collectives
+    of a ZeRO gather, the custom calls `jax.lax.ragged_dot` becomes,
+    whose `op_name` is "ragged-dot-none", the jit path dropped) its
+    first scoped user's, else its first scoped operand's; and
+    `unscoped` where none of these names a scope (the loop counters,
+    the loss scaling around the micro-batch loop).  `pass` is `pass_of`
+    of the instruction that gave the group.  `scope_sources` says which
+    step placed each.
     Instruction names are unique within a module, not across modules:
     keep one map per program."""
-    phases = (*PHASES, *SCOPE_MAP_GROUPS)
-    out: Dict[str, Tuple[str, str]] = {}
-    for line in as_hlo_text(compiled_or_text).splitlines():
-        m = _INSTR_PAT.match(line)
-        if m is None:
-            continue
-        om = OP_NAME_PAT.search(line)
-        op_name = om.group(1) if om is not None else ""
-        group = group_of(op_name, phases)
-        out[m.group(1)] = (UNSCOPED if group == "other" else group,
-                           pass_of(op_name))
-        if op_name.startswith(_RAGGED_DOT):
-            out[m.group(1)] = next(
-                (out[o] for o in _OPERAND_PAT.findall(line[m.end():])
-                 if out.get(o, (UNSCOPED,))[0] != UNSCOPED),
-                out[m.group(1)])
-    return out
+    return {name: (ins.group, ins.ps)
+            for name, ins in _resolve(compiled_or_text).items()}
+
+
+def scope_sources(compiled_or_text) -> Dict[str, str]:
+    """{instruction name: "own" | "body" | "user" | "operand" | "none"}:
+    the step of `scope_map`'s order that placed the instruction.  "own"
+    is what the program said itself; "body", "user" and "operand" are
+    inferred from the instructions around it (a relayout two scopes
+    read goes to the first reader), and "none" is `unscoped`."""
+    return {name: ins.source
+            for name, ins in _resolve(compiled_or_text).items()}
 
 
 # ---------------------------------------------------------------------------
@@ -248,12 +445,18 @@ def scope_map(compiled_or_text) -> Dict[str, Tuple[str, str]]:
 
 def layer_table(compiled_or_text, *, phases: Tuple[str, ...] = PHASES,
                 default_world: int = 1,
-                apply_multipliers: bool = True
+                apply_multipliers: bool = True,
+                placed: Optional[Dict[str, _Instr]] = None
                 ) -> Dict[str, Dict[str, float]]:
     """{group: {"instructions", "dots", "flops", "out_bytes",
     "wire_bytes"}} over the optimized HLO, execution multipliers
     applied (scanned layers count trip-count times).  Groups are
-    `group_of` keys; an extra "_meta" entry carries
+    `group_of` keys with `phases` known, each read from the path that
+    placed the instruction in `scope_map`'s resolver (an `op_name`
+    whose path names no scope goes to its body's or its reader's
+    group, else to "other"; `placed`: that walk's result, where the
+    caller holds it already); an extra
+    "_meta" entry carries
     {"dynamic_trip_count"} when some loop's trip was unresolvable.
 
     apply_multipliers=False counts each instruction ONCE (static) —
@@ -267,7 +470,22 @@ def layer_table(compiled_or_text, *, phases: Tuple[str, ...] = PHASES,
     comps = split_computations(txt)
     mults = (call_multipliers(comps) if apply_multipliers
              else {name: (1.0, False) for name in comps})
+    placed = placed if placed is not None else _resolve(txt, comps)
     out: Dict[str, Dict[str, float]] = {}
+    under: Dict[Tuple[str, str], str] = {}      # (path, source) -> group
+
+    def group_at(line: str) -> str:
+        """Where the ONE resolver put the line's instruction (`other`
+        is this table's name for `unscoped`): the static profile and
+        the measured join agree on where an instruction belongs."""
+        m = INSTR_PAT.match(line)
+        ins = placed.get(m.group(1)) if m is not None else None
+        if ins is None:
+            return "other"
+        key = (ins.via, ins.source)
+        if key not in under:
+            under[key] = ins.group_under(phases)
+        return under[key]
     dynamic = False
     conv_unparsed = False
 
@@ -283,16 +501,17 @@ def layer_table(compiled_or_text, *, phases: Tuple[str, ...] = PHASES,
                 # phase accounting (phase_breakdown skips them too — the
                 # static-sum contract), but a GSPMD-inserted collective
                 # without metadata still moves real bytes: count its
-                # wire bytes into "other" so wire sums reconcile with
+                # wire bytes where the resolver put it ("other" where
+                # nothing places it) so wire sums reconcile with
                 # obs.comm.collective_report on EVERY program
                 wb = line_wire_bytes(line, default_world)
                 if wb > 0:
-                    out.setdefault("other", new_row())["wire_bytes"] += \
-                        wb * mult
+                    out.setdefault(group_at(line), new_row())[
+                        "wire_bytes"] += wb * mult
                     dynamic = dynamic or dyn
                 continue
             dynamic = dynamic or dyn
-            rec = out.setdefault(group_of(m.group(1), phases), new_row())
+            rec = out.setdefault(group_at(line), new_row())
             rec["instructions"] += mult
             if " dot(" in line or " convolution(" in line:
                 rec["dots"] += mult
@@ -360,19 +579,21 @@ def _layer_sort_key(group: str):
 
 def layer_profile(compiled_or_text, *, hw: Optional[Dict] = None,
                   phases: Tuple[str, ...] = PHASES,
-                  default_world: int = 1) -> Dict[str, Any]:
+                  default_world: int = 1,
+                  placed: Optional[Dict[str, _Instr]] = None
+                  ) -> Dict[str, Any]:
     """Roofline-price the per-group attribution: each group's predicted
     time is max(flops/compute, out_bytes/hbm) + wire_bytes/ici over the
     hardware profile's rates.  Returns {"groups": {group: {...,
     "time_s", "bound"}}, "totals", "estimated_step_s", "top"} with
     groups in model order (embed, layer_0..n / scanned layer, lm_head,
-    grad_sync, optimizer, other)."""
+    grad_sync, optimizer, other).  `placed` as in `layer_table`."""
     from hetu_tpu.obs.mfu import _rates, load_hardware_profile
     hw = hw if hw is not None else load_hardware_profile()
     compute, hbm, _peak = _rates(hw)
     ici = float(hw.get("ici_allreduce_gbps", 45.0)) * 1e9
     table = layer_table(compiled_or_text, phases=phases,
-                        default_world=default_world)
+                        default_world=default_world, placed=placed)
     meta = table.pop("_meta", None)
     groups: Dict[str, Dict[str, float]] = {}
     totals = {"instructions": 0.0, "dots": 0.0, "flops": 0.0,
@@ -661,6 +882,21 @@ def analytic_peak_hbm(num_params: float, *, batch: int, seq: int,
 # the schema-versioned profile record
 # ---------------------------------------------------------------------------
 
+def _sources_summary(placed: Dict[str, _Instr]
+                     ) -> Dict[str, Dict[str, Any]]:
+    """{source: {"instructions": n, "groups": [...]}} over a module's
+    `_resolve`: how much of `scope_map` the program said itself ("own")
+    and how much was inferred, for a reader of a run's record."""
+    out: Dict[str, Dict[str, Any]] = {}
+    for ins in placed.values():
+        rec = out.setdefault(ins.source, {"instructions": 0, "groups": set()})
+        rec["instructions"] += 1
+        rec["groups"].add(ins.group)
+    return {src: {"instructions": rec["instructions"],
+                  "groups": sorted(rec["groups"])}
+            for src, rec in sorted(out.items())}
+
+
 def profile_record(compiled_or_text, *, hw: Optional[Dict] = None,
                    top_k: int = 8, default_world: int = 1,
                    profile: Optional[Dict[str, Any]] = None,
@@ -669,17 +905,20 @@ def profile_record(compiled_or_text, *, hw: Optional[Dict] = None,
     {"profile_schema": 1, "top": top-k groups by predicted time,
     "groups": <count>, "estimated_step_s", "total_flops",
     "total_wire_bytes", "peak_hbm_bytes", "peak_hbm_vs_xla",
-    "hbm_headroom_frac"} — small enough to ride every fresh compile.
+    "hbm_headroom_frac", "scope_sources": per source of
+    `scope_sources` how many instructions it placed and in which
+    groups} — small enough to ride every fresh compile.
 
-    The HLO text is materialized ONCE and shared by the attribution and
-    peak walks; callers that already hold a `layer_profile` report
-    and/or the text (the trainer's compile hook) pass them in to
-    skip the re-walk."""
+    The HLO text is materialized ONCE and resolved ONCE: the attribution
+    and the sources read the same walk, the peak walk the same text;
+    callers that already hold a `layer_profile` report and/or the text
+    (the trainer's compile hook) pass them in to skip the re-walk."""
     txt = text if text is not None else (
         compiled_or_text if isinstance(compiled_or_text, str)
         else compiled_or_text.as_text())
+    placed = _resolve(txt)
     prof = profile if profile is not None else layer_profile(
-        txt, hw=hw, default_world=default_world)
+        txt, hw=hw, default_world=default_world, placed=placed)
     peak = peak_hbm_estimate(compiled_or_text, hw=hw, text=txt)
     rec: Dict[str, Any] = {
         "profile_schema": PROFILE_SCHEMA,
@@ -693,6 +932,7 @@ def profile_record(compiled_or_text, *, hw: Optional[Dict] = None,
         "total_out_bytes": prof["totals"]["out_bytes"],
         "total_wire_bytes": prof["totals"]["wire_bytes"],
         "peak_hbm_bytes": peak["peak_bytes"],
+        "scope_sources": _sources_summary(placed),
     }
     for caveat in ("dynamic_trip_count", "conv_flops_unparsed"):
         if prof.get(caveat):

@@ -8,7 +8,8 @@ regex set; a parse fix (tuple outputs, iota replica_groups, async
 `-start` payloads, nested while trips) had to land three times or the
 byte models silently drifted apart.  This module owns the shared layer:
 
-* **line anatomy** — `parse_def` splits `%name = <shapes> opcode(...)`
+* **line anatomy** — `INSTR_PAT` finds an instruction's own line and
+  name; `parse_def` splits `%name = <shapes> opcode(...)`
   into (name, output-shape section, opcode); `shape_bytes` /
   `component_bytes` price a shape section (operand shapes live INSIDE
   the call parens and must never count — summing them overcounts
@@ -63,6 +64,9 @@ DEF_PAT = re.compile(r'%([\w.\-]+)\s*=\s*(.*?)\s*([a-z][a-z0-9_.-]*)\(')
 SHAPE_PAT = re.compile(r'\b([a-z][a-z0-9]*)\[([0-9,]*)\]')
 OUT_PAT = re.compile(r'=\s*(.*?)\s*[a-z][a-z0-9_.-]*\(')
 REF_PAT = re.compile(r'%([\w.\-]+)')
+#: an instruction's own line, `[ROOT] %name = ...` (never a computation's
+#: header, which has no `=` after its name): group 1 is the name
+INSTR_PAT = re.compile(r'^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=')
 OP_NAME_PAT = re.compile(r'op_name="([^"]+)"')
 GROUPS_PAT = re.compile(r'replica_groups=\{(\{[0-9,{} ]*\})\}')
 IOTA_GROUPS_PAT = re.compile(

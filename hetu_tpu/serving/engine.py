@@ -60,6 +60,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import hashlib
 import math
 import time
 from typing import Callable, List, Optional, Sequence
@@ -74,6 +75,7 @@ from hetu_tpu.models.generation import (_check_context_length,
                                         init_cache, lm_head_weight,
                                         verify_step_paged)
 from hetu_tpu.obs.health import maybe_serving_health_monitor
+from hetu_tpu.obs.hlo_text import INSTR_PAT
 from hetu_tpu.obs.metrics import MetricsRegistry, get_registry
 from hetu_tpu.obs.runlog import RunLog, default_runlog_path
 from hetu_tpu.ops.pallas import record_routes
@@ -1085,19 +1087,49 @@ class ServingEngine:
         """{program: jax.stages.Lowered} for the decode step ("verify"
         with speculative decoding on), the page write and the prefill
         chunk at every launch shape the engine can issue
-        ("prefill_chunk", "prefill_chunk_x2", ...: `launch_multiples`),
-        lowered for ABSTRACT arguments of the engine's own shapes —
-        `.compile().as_text()` is the program the engine runs.  `sharding`
-        places the arguments (default: where the arrays are); a described
-        device's compiles the programs for a chip that is not attached
+        ("prefill_chunk", "prefill_chunk_x2", ...: `launch_multiples`).
+        By default lowered for the arguments `warmup` called the
+        programs with, as they are: `.compile()` is then the executable
+        the engine's own jitted function built and runs (one lowering in
+        JAX's caches, no second compile), and `.as_text()` names its
+        instructions as a device trace of the engine does.  (Abstract
+        arguments that state a placement are another lowering: the
+        compiler numbered its fusions otherwise, and a trace's events
+        missed the text: PERF.md s6, PR 53.)  `sharding` instead places
+        ABSTRACT arguments of the engine's shapes on a described device
+        and compiles the programs for a chip that is not attached
         (tests/test_chip_compile.py)."""
         def abstract(tree):
-            return jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(
-                    a.shape, a.dtype,
-                    sharding=sharding or getattr(a, "sharding", None)), tree)
-        return {name: fn.lower(*abstract(self._dummy_args(name)))
-                for name, fn in self._jits.items()}
+            return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=sharding), tree)
+        return {name: fn.lower(*(
+            self._dummy_args(name) if sharding is None
+            else abstract(self._dummy_args(name))))
+            for name, fn in self._jits.items()}
+
+    def _note_programs(self):
+        """Which text a reader of this engine's device trace joins: per
+        program its instructions and the text's fingerprint (the first
+        12 hex digits of its SHA-1), on the `kernel_routes` line; with
+        HETU_TPU_PROFILE and a RunLog, each program's `profile` record
+        (obs.hlo_profile.profile_record: the static profile and how
+        `scope_map` placed its instructions)."""
+        from hetu_tpu.utils import flags as _flags
+        profile = (self.run_log is not None
+                   and _flags.bool_flag("HETU_TPU_PROFILE"))
+        noted = self.kernel_routes["programs"] = {}
+        for name, low in self.lower_programs().items():
+            compiled = low.compile()
+            text = compiled.as_text()
+            noted[name] = {
+                "instructions": sum(
+                    INSTR_PAT.match(ln) is not None
+                    for ln in text.splitlines()),
+                "fingerprint": hashlib.sha1(text.encode()).hexdigest()[:12]}
+            if profile:
+                from hetu_tpu.obs.hlo_profile import profile_record
+                self.run_log.log("profile", name=name, **noted[name],
+                                 **profile_record(compiled, text=text))
 
     def warmup(self):
         """Compile every program, the chunk program at every launch shape
@@ -1130,6 +1162,7 @@ class ServingEngine:
                 self.pool.arrays.tree(),
                 jnp.zeros(self.scheduler.max_pages, jnp.int32)))
         jax.block_until_ready((nxt, lg, cache))
+        self._note_programs()
         return self
 
     # ----------------------------------------------------------- intake

@@ -109,3 +109,14 @@ def test_tiny_jamba_rehearses_correct(trace_on):
 
 def test_the_parent_fails_at_once_on_the_jamba_cell(tmp_path):
     jamba.test_the_parent_fails_at_once_without_the_family_module(tmp_path)
+
+
+def test_the_recorded_spans_trace_reads_as_the_scope_map_places_it(tmp_path):
+    """`benchmarks/tests/test_trace_spans_scopes.py` (PR 53): the trace
+    recorded on a v5e reduced with `obs.scope_map` as it resolves now,
+    every guard of the standing `head` test kept standing where the
+    driver counts (what reads no scope as `head` has it, the rest as
+    `tiny-chat-spans.scopes-pr53.json`, the rows' sums unmoved)."""
+    _load("test_trace_spans_scopes") \
+        .test_the_spans_trace_reads_as_head_but_for_what_the_map_now_places(
+            tmp_path)

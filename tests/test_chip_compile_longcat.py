@@ -6,9 +6,11 @@ a described TPU v5e.  The case and its assertions are that file's
 it runs from a file of its own because a file is what one worker of the
 tier-1 run takes whole."""
 import test_chip_compile as described
-from test_chip_compile import described_chip  # noqa: F401  (the fixture)
+from test_chip_compile import (decode_text,  # noqa: F401  (the fixtures)
+                               described_chip)
 
 
-def test_serving_programs_compile_for_one_v5e_longcat():
+def test_serving_programs_compile_for_one_v5e_longcat(decode_text):  # noqa: F811
     assert "longcat" in described.ELSEWHERE
-    described.test_serving_programs_compile_for_one_v5e("longcat")
+    described.test_serving_programs_compile_for_one_v5e("longcat",
+                                                        decode_text)

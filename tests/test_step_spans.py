@@ -825,7 +825,7 @@ def test_train_loop_step_s_is_the_completion_interval(tmp_path):
 # ---------------------------------------------------------------------------
 
 def _instruction_names(text):
-    return [m.group(1) for m in map(hp._INSTR_PAT.match, text.splitlines())
+    return [m.group(1) for m in map(hp.INSTR_PAT.match, text.splitlines())
             if m]
 
 
@@ -906,6 +906,76 @@ def test_scope_map_decode_program(monkeypatch):
         texts["write_pages"]).values()}
 
 
+def test_lower_programs_hands_out_the_executables_that_ran():
+    """A miss is not a scope (PR 53): the text a reader joins a device
+    trace with is the text of the executable the engine's own jitted
+    function built in `warmup`, not a second lowering that the compiler
+    may number otherwise.  Nothing compiles again, and the
+    `kernel_routes` line says per program which text that is."""
+    import hashlib
+    cfg = LlamaConfig.tiny(
+        vocab_size=256, hidden_size=64, intermediate_size=64,
+        num_attention_heads=2, num_key_value_heads=2, remat=False)
+    model = LlamaLMHeadModel(cfg)
+    engine = serving.ServingEngine(
+        model, model.init(jax.random.key(0)),
+        serving.ServeConfig(num_slots=4, page_size=8, max_len=32,
+                            prefill_chunk=8), registry=MetricsRegistry())
+    engine.warmup()
+    compiles = []
+
+    def on_compile(event, duration_secs, **_kw):
+        if event == profiling.COMPILE_EVENT:
+            compiles.append(duration_secs)
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        texts = {name: low.compile().as_text()
+                 for name, low in engine.lower_programs().items()}
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+    assert not compiles
+    noted = engine.kernel_routes["programs"]
+    assert set(noted) == set(texts) >= {"decode", "prefill_chunk",
+                                        "write_pages"}
+    for name, text in texts.items():
+        assert noted[name] == {
+            "instructions": len(hp.scope_map(text)),
+            "fingerprint": hashlib.sha1(text.encode()).hexdigest()[:12]}
+
+
+def test_obs_report_says_how_each_program_was_placed(tmp_path, monkeypatch):
+    """With HETU_TPU_PROFILE and a RunLog the engine leaves a `profile`
+    record a program at warm-up; `tools_obs_report.py` prints per
+    program its instructions, its text's fingerprint (the one on the
+    `kernel_routes` line) and what each source of `scope_sources`
+    placed."""
+    import tools_obs_report
+    from hetu_tpu.obs.runlog import RunLog
+    monkeypatch.setenv("HETU_TPU_PROFILE", "1")
+    path = str(tmp_path / "runlog.jsonl")
+    cfg = LlamaConfig.tiny(
+        vocab_size=256, hidden_size=64, intermediate_size=64,
+        num_attention_heads=2, num_key_value_heads=2, remat=False)
+    model = LlamaLMHeadModel(cfg)
+    engine = serving.ServingEngine(
+        model, model.init(jax.random.key(0)),
+        serving.ServeConfig(num_slots=4, page_size=8, max_len=32,
+                            prefill_chunk=8), registry=MetricsRegistry(),
+        run_log=RunLog(path))
+    engine.warmup()
+    noted = engine.kernel_routes["programs"]
+    engine.close()
+    engine.run_log.close()
+    scopes = tools_obs_report.summarize(RunLog.read(path))["scopes"]
+    assert set(scopes) == set(noted)
+    for name, rec in scopes.items():
+        assert {k: rec[k] for k in noted[name]} == noted[name]
+        assert sum(r["instructions"] for r in rec["placed_by"].values()) \
+            == rec["instructions"]
+    assert "layer/attn" in scopes["decode"]["placed_by"]["own"]["groups"]
+    assert scopes["decode"]["placed_by"]["none"]["groups"] == [hp.UNSCOPED]
+
+
 @pytest.mark.parametrize("op_name,expect", [
     ("jit(_train_step)/while/body/closed_call/jvp()/while/body/"
      "closed_call/layer/attn/pallas_flash_attention/pallas_call",
@@ -930,3 +1000,169 @@ def test_scope_map_reads_compiled_v5e_op_names(op_name, expect):
     line = (f'  %fusion.7 = f32[8]{{0}} fusion(%p), kind=kLoop, '
             f'metadata={{op_name="{op_name}"}}')
     assert hp.scope_map(line) == {"fusion.7": expect}
+
+
+# what the compiler left without a name: lines as programs compiled for a
+# described v5e hold them (Phi's and Jamba's decode programs, the dp2 x
+# tp2 step), cut to what a rule reads
+_D = "jit(decode_fn)/layer/while/body/closed_call"
+_T = "jit(_train_step)/while/body/closed_call/transpose(jvp())/while/body"
+
+
+def _meta(path):
+    return f', metadata={{op_name="{path}"}}'
+
+
+RESOLVED = f"""HloModule jit_decode_fn, is_scheduled=true
+
+%fused_computation.286.clone (p0: bf16[32,2560], p1: bf16[2560,10240]) -> bf16[32,10240] {{
+  %p0 = bf16[32,2560]{{1,0}} parameter(0)
+  %p1 = bf16[2560,10240]{{1,0}} parameter(1)
+  %convolution.9 = bf16[32,10240]{{1,0}} convolution(%p0, %p1), dim_labels=bf_io->bf{_meta(_D + "/mlp/dot_general")}
+  %multiply.3 = bf16[32,10240]{{1,0}} multiply(%convolution.9, %convolution.9){_meta(_D + "/attn/ssm/ssm_out/mul")}
+  ROOT %add.4 = bf16[32,10240]{{1,0}} add(%multiply.3, %multiply.3){_meta(_D + "/attn/ssm/ssm_out/add")}
+}}
+
+%fused_computation.7 (p0.1: f32[32,5120]) -> f32[32,5120] {{
+  %p0.1 = f32[32,5120]{{1,0}} parameter(0)
+  %exp.1 = f32[32,5120]{{1,0}} exponential(%p0.1){_meta(_D + "/attn/ssm/ssm_conv/exp")}
+  %mul.1 = f32[32,5120]{{1,0}} multiply(%exp.1, %p0.1){_meta(_D + "/attn/ssm/ssm_conv/mul")}
+  ROOT %add.1 = f32[32,5120]{{1,0}} add(%mul.1, %p0.1){_meta(_D + "/attn/ssm/add")}
+}}
+
+%fused_computation.8 (p0.2: f32[32,5120]) -> f32[32,5120] {{
+  %p0.2 = f32[32,5120]{{1,0}} parameter(0)
+  %mul.2 = f32[32,5120]{{1,0}} multiply(%p0.2, %p0.2){_meta(_D + "/attn/ssm/mul")}
+  ROOT %add.2 = f32[32,5120]{{1,0}} add(%mul.2, %p0.2){_meta(_D + "/attn/ssm/ssm_proj/add")}
+}}
+
+%bitcast_fusion.2 (bitcast_input.2: bf16[2560,10240]) -> bf16[2560,10240] {{
+  %bitcast_input.2 = bf16[2560,10240]{{1,0}} parameter(0)
+  ROOT %bitcast.2 = bf16[2560,10240]{{1,0}} bitcast(%bitcast_input.2)
+}}
+
+ENTRY %main (w: bf16[2560,10240], x: bf16[32,2560], w2: bf16[2560,2560], q: bf16[16,2560], pool: bf16[2,65,8,2,16]) -> (bf16[32,10240], s32[]) {{
+  %w = bf16[2560,10240]{{1,0}} parameter(0){_meta("params['mlp']['w_gate_up']")}
+  %x = bf16[32,2560]{{1,0}} parameter(1)
+  %w2 = bf16[2560,2560]{{1,0}} parameter(2)
+  %q = bf16[16,2560]{{1,0}} parameter(3)
+  %pool = bf16[2,65,8,2,16]{{4,3,2,1,0}} parameter(4)
+  %fusion.352 = bf16[2560,10240]{{1,0}} fusion(%w), kind=kLoop, calls=%bitcast_fusion.2
+  %copy.5 = bf16[32,2560]{{0,1}} copy(%x)
+  %fusion.441.clone.1 = bf16[32,10240]{{1,0}} fusion(%copy.5, %fusion.352), kind=kOutput, calls=%fused_computation.286.clone
+  %fusion.70 = f32[32,5120]{{1,0}} fusion(%fusion.441.clone.1), kind=kLoop, calls=%fused_computation.7
+  %fusion.80 = f32[32,5120]{{1,0}} fusion(%fusion.70), kind=kLoop, calls=%fused_computation.8
+  %copy-start.1 = (bf16[2560,2560]{{1,0:S(1)}}, bf16[2560,2560]{{1,0}}, u32[]{{:S(2)}}) copy-start(%w2)
+  %copy-done.1 = bf16[2560,2560]{{1,0:S(1)}} copy-done(%copy-start.1)
+  %fusion.9 = bf16[32,2560]{{1,0}} fusion(%fusion.80, %copy-done.1), kind=kOutput{_meta(_D + "/attn/attn_full/dot_general")}
+  %all-gather.3 = bf16[32,2560]{{1,0}} all-gather(%q), channel_id=1, replica_groups=[2,2]<=[4], dimensions={{0}}
+  %bitcast.7 = bf16[32,1,2560]{{2,1,0}} bitcast(%all-gather.3)
+  %fusion.10 = bf16[32,2560]{{1,0}} fusion(%fusion.9, %bitcast.7), kind=kOutput{_meta(_T + "/layer/attn/dot_general")}
+  %copy.6 = bf16[2,65,8,2,16]{{4,3,2,1,0}} copy(%pool)
+  %pallas_paged_attention.3 = bf16[32,2560]{{1,0}} custom-call(%fusion.10, %copy.6), custom_call_target="tpu_custom_call"{_meta(_D + "/attn/pallas_paged_attention/pallas_call")}
+  %constant.1 = s32[] constant(1)
+  %add.99 = s32[] add(%constant.1, %constant.1)
+  ROOT %tuple.1 = (bf16[32,2560]{{1,0}}, s32[]) tuple(%pallas_paged_attention.3, %add.99)
+}}
+"""
+
+
+@pytest.mark.parametrize("case,name,expect,source", [
+    # (i) a `.clone` fusion without metadata: its body's PRODUCT names the
+    # group, not the two elementwise instructions of another scope
+    ("product", "fusion.441.clone.1", ("layer/mlp", "fwd"), "body"),
+    # (ii) no product in the body: the group most instructions name ...
+    ("majority", "fusion.70", ("layer/ssm_conv", "fwd"), "body"),
+    # ... and the innermost scope on a tie
+    ("innermost", "fusion.80", ("layer/ssm_proj", "fwd"), "body"),
+    # (iii) a relayout of a parameter: the group of what reads it
+    ("bitcast_fusion", "fusion.352", ("layer/mlp", "fwd"), "user"),
+    ("copy", "copy.5", ("layer/mlp", "fwd"), "user"),
+    # (iv) an asynchronous pair: the done finds its reader, the start
+    # its done
+    ("copy-done", "copy-done.1", ("layer/attn_full", "fwd"), "user"),
+    ("copy-start", "copy-start.1", ("layer/attn_full", "fwd"), "user"),
+    # (v) a ZeRO gather, read through a bitcast, the pass included
+    ("all-gather", "all-gather.3", ("layer/attn", "bwd"), "user"),
+    # a kernel's row is its own instructions' alone: what the kernel
+    # reads goes to the layer part around it
+    ("kernel", "copy.6", ("layer/attn", "fwd"), "user"),
+    # (vi) nothing places a loop counter; a parameter runs nothing
+    ("residue", "add.99", (hp.UNSCOPED, "fwd"), "none"),
+    ("parameter", "w", (hp.UNSCOPED, "fwd"), "none"),
+    # what names its own scope is never moved
+    ("own", "fusion.9", ("layer/attn_full", "fwd"), "own"),
+])
+def test_scope_map_resolves_what_the_compiler_left_unnamed(
+        case, name, expect, source):
+    assert hp.scope_map(RESOLVED)[name] == expect
+    assert hp.scope_sources(RESOLVED)[name] == source
+
+
+def test_a_gather_placed_by_its_reader_is_still_a_collective():
+    """(v) in the benchmark's join: the group comes from the program,
+    the kind from the operation's name."""
+    _, index = bench_trace.scope_index(RESOLVED)
+    short = next(k for k in index if k.startswith("all-gather.3"))
+    assert index[short] == ("layer/attn", "bwd")
+    assert bench_trace.COLLECTIVE.search(short)
+
+
+def _own(text):
+    """The parent's rule (PR 52): an instruction's own `op_name`, nothing
+    else."""
+    phases = (*hp.PHASES, *hp.SCOPE_MAP_GROUPS)
+    out = {}
+    for line in text.splitlines():
+        m = hp.INSTR_PAT.match(line)
+        if m:
+            om = hp.OP_NAME_PAT.search(line)
+            op = om.group(1) if om else ""
+            g = hp.group_of(op, phases)
+            out[m.group(1)] = (hp.UNSCOPED if g == "other" else g,
+                               hp.pass_of(op))
+    return out
+
+
+def test_scope_map_moves_nothing_that_names_its_scope(train_step_text):
+    """(vii) every instruction of the train step that named a scope
+    itself maps exactly as before; only what the parent left `unscoped`
+    may be placed, and every instruction ends in one group with one
+    source."""
+    new, src, old = (hp.scope_map(train_step_text),
+                     hp.scope_sources(train_step_text), _own(train_step_text))
+    assert set(new) == set(src) == set(old)
+    assert {k: v for k, v in new.items() if src[k] in ("own", "none")} == \
+        {k: v for k, v in old.items() if src[k] in ("own", "none")}
+    assert all(old[k][0] == hp.UNSCOPED for k in new if new[k] != old[k])
+    assert all((src[k] == "none") == (new[k][0] == hp.UNSCOPED) for k in new)
+    assert {"own", "none"} <= set(src.values())
+    # and a program every instruction of which names its scope is the
+    # parent's map, key for key
+    scoped = "\n".join(ln for ln in train_step_text.splitlines()
+                       if (m := hp.INSTR_PAT.match(ln))
+                       and src[m.group(1)] == "own")
+    assert hp.scope_map(scoped) == _own(scoped)
+    assert set(hp.scope_sources(scoped).values()) == {"own"}
+
+
+def test_layer_table_places_an_instruction_where_scope_map_does():
+    """The static profile takes its groups from the one resolver: the
+    gather without a name moves its wire bytes into the layer that
+    reads it, and `other` is what `scope_map` calls `unscoped`."""
+    table = hp.layer_table(RESOLVED, phases=(*hp.PHASES,
+                                             *hp.SCOPE_MAP_GROUPS),
+                           default_world=4)
+    assert table["layer/attn"]["wire_bytes"] > 0
+    assert "other" in table and not table["other"]["wire_bytes"]
+    assert "layer/attn/pallas_paged_attention" in table
+
+
+def test_profile_record_says_how_the_map_knew():
+    got = hp.profile_record(RESOLVED)["scope_sources"]
+    assert got["body"] == {"instructions": 3, "groups": [
+        "layer/mlp", "layer/ssm_conv", "layer/ssm_proj"]}
+    assert got["user"]["instructions"] == 6
+    assert hp.UNSCOPED in got["none"]["groups"]
+    assert sum(r["instructions"] for r in got.values()) == len(
+        hp.scope_map(RESOLVED))

@@ -178,6 +178,20 @@ def summarize(records) -> dict:
         # it from the report machine's hardware profile would let two
         # keys for one quantity disagree
         out["profile"] = prof
+        # how obs.scope_map placed each program's instructions (a
+        # trainer's step by its plan's name, a serving engine's programs
+        # by theirs, the newest record of each): what the program said
+        # itself ("own") beside what was inferred from a fusion's body,
+        # a reader or an operand, and what no rule placed ("none")
+        scopes = {}
+        for r in profiles:
+            if r.get("scope_sources"):
+                scopes[r.get("name") or "step"] = {
+                    **{k: r[k] for k in ("instructions", "fingerprint")
+                       if r.get(k) is not None},
+                    "placed_by": r["scope_sources"]}
+        if scopes:
+            out["scopes"] = scopes
     if budgets:
         fails = [r for r in budgets if not r.get("ok")]
         out["budget"] = {"checks": len(budgets), "failed": len(fails),

@@ -18,7 +18,9 @@ The fused-kernel layer (docs/kernels.md):
                        launch (spec-decode verification)
   * paged_latent_attention — the paged decode kernel of latent (MLA)
                        attention: one pool of [c_kv | k_rope] vectors,
-                       each page read once and used as key and value
+                       a slot's live pages walked in blocks by
+                       paged_attention's walk, each block fetched once
+                       and used as key and value
   * chunk_attention  — the chunk program's attention over a dense K/V
                        cache (chunked prefill): blockwise, online
                        softmax, only the key blocks a chunk can see
@@ -234,12 +236,32 @@ def record_routes(into: Optional[dict] = None):
 
 
 def _note_route(name: str, routed: bool, why: str):
-    log = getattr(_routes, "log", None)
+    log, _routes.last = getattr(_routes, "log", None), None
     if log is None:
         return
     rec = log.setdefault(name, {"pallas": 0, "xla": 0, "why": {}})
     rec["pallas" if routed else "xla"] += 1
     rec["why"][why] = rec["why"].get(why, 0) + 1
+    if routed:
+        _routes.last = (name, rec["why"], why)
+
+
+def _note_engagement(name: str, how: str):
+    """A routed kernel's wrapper says HOW it engages: what its own rule
+    chose from operands the gate never sees (a pool's item size), so that
+    the reason `resolve_route` has just recorded for this call reads
+    "shape gate passes, pages_per_block=4".  With no decision for `name`
+    pending (a call past the dispatcher, or outside `record_routes`) it
+    does nothing."""
+    last, _routes.last = getattr(_routes, "last", None), None
+    if last is None or last[0] != name:
+        return
+    _, reasons, why = last
+    reasons[why] -= 1
+    if not reasons[why]:
+        del reasons[why]
+    told = f"{why}, {how}"
+    reasons[told] = reasons.get(told, 0) + 1
 
 
 def resolve_route(name: str, check, *shapes, layouts=None, **kw) -> bool:

@@ -7,7 +7,9 @@ layer before attending — three passes over the cache bytes (gather read,
 dense write, attention read), most of them over DEAD tail positions.
 This kernel reads each slot's live pages straight from the pool, once.
 
-**The walk** (`_walk_live_blocks`, the one page walk of this module).
+**The walk** (`_walk_live_blocks`, the one page walk of `ops/pallas`:
+this module's two kernels and `paged_latent_attention`'s, which walks
+ONE stream, the latent pool, by it).
 The grid is the slots: one grid step a slot, whatever the table's
 width.  The pools stay whole in HBM (`memory_space=pl.ANY`); the page
 table and the positions are scalar-prefetched.  Inside a grid step a

@@ -286,6 +286,47 @@ def test_paged_kernel_takes_the_serving_cells_pool_as_it_lies():
         <= pa._VMEM_BUDGET
 
 
+#: the three cells' decode launches of the paged LATENT kernel: (slots,
+#: query heads, table width, pages a cache layer + the null page, cache
+#: layers in the one flat pool); pages of 256 tokens of 640 lanes
+LATENT_CELLS = {
+    "longcat": (96, 64, 10, 977, 8),
+    "kimi": (64, 64, 16, 1041, 6),
+    "ling": (32, 32, 128, 4113, 1),
+}
+
+
+@pytest.mark.parametrize("cell", LATENT_CELLS)
+def test_latent_kernel_takes_the_serving_cells_pool_as_it_lies(cell):
+    """At each latent cell's shape the kernel walks the flat pool of all
+    cache layers where it lies (`MLAttention.attend_paged`: the layer's
+    pages reached through `table + base`): a whole-array HBM operand
+    (`pl.ANY`), so the program around the call holds no temporary, let
+    alone PR 27's 2.0 GB layout copy of a latent pool; the block the
+    rule chose is 1,024 tokens (4 pages), and its double buffers and
+    float32 scores fit the budget and the call's default VMEM (the
+    compile is the witness)."""
+    from hetu_tpu.ops.pallas import paged_latent_attention as pla
+    slots, heads, width, pages, layers = LATENT_CELLS[cell]
+    pool = spec((layers * pages, 256, 640), BF16)
+
+    def fn(q, pool, table, pos):
+        return pla.paged_latent_attention(
+            q, pool, table + (layers - 1) * pages, pos, value_dim=512,
+            softmax_scale=0.1)
+    compiled = jax.jit(fn).lower(
+        spec((slots, heads, 640), BF16), pool, spec((slots, width), I32),
+        spec((slots,), I32)).compile()
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1 and "pallas_paged_latent_attention" in calls[0]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    ppb = pla.pages_per_block(heads, 256, 640, 2, width)
+    assert ppb * 256 == pla._BLOCK_TOKENS == 1024
+    assert ppb * 256 * pla._token_vmem_bytes(heads, 640, 2) \
+        <= pla._VMEM_BUDGET
+
+
 # ---------------------------------------------------------------------------
 # the AdamW update as `auto` runs it: XLA's chain, a leaf where it lies
 # ---------------------------------------------------------------------------
@@ -631,6 +672,10 @@ def test_serving_programs_compile_for_one_v5e(family, decode_text):
         scope = KERNEL_SCOPES.get(k, f"pallas_{k}_attention")
         assert any(scope in ln for ln in calls), (k, len(calls))
         assert routes[k]["pallas"] and not routes[k]["xla"], routes
+    if "paged_latent" in kernels:
+        # the reason says the block the wrapper's rule chose: 1,024 tokens
+        assert list(routes["paged_latent"]["why"]) == [
+            "shape gate passes, pages_per_block=4"]
     # the chunk program of a K/V family asks the blockwise kernel's gate
     # in every layer it traces: Trinity's 168 / 537 MB of float32 scores
     # a layer take it (two calls a layer: K and V relaid head-major, then

@@ -218,6 +218,117 @@ def test_latent_kernel_in_interpret_mode_is_jnp(rng):
                                atol=1e-5, rtol=0)
 
 
+#: positions of the walk's slots at pages of 8 and a table of 5: None = an
+#: idle slot (position 0, a null table row); mid-page, a page's last
+#: position, the first of a page, the edges of blocks of 2, 3 and 4 pages,
+#: the table's end
+WALK_POSITIONS = {
+    "ragged": [None, 11, 15, 16, 23, 31, 39, 3, None],
+    "all_idle": [None] * 4,
+}
+#: pages a block: one, two, the whole table, one that does not divide it
+WALK_BLOCKS = (1, 2, 5, 3)
+
+
+def _poisoned_walk(rng, slots, dtype, *, nq=4, dk=256, ps=8, mp=5):
+    """-> (q, clean pool, clean table, poisoned pool, poisoned table,
+    positions).  Poisoned: every table entry past a slot's live pages
+    names a page of NaN (a valid id), every position past a slot's last,
+    in its last page and in the null page, holds NaN: a walk that reads
+    one of them into the result gives NaN."""
+    S = len(slots)
+    P = 2 + S * mp                       # null page, pages, the NaN page
+    pool = rng.standard_normal((P, ps, dk)).astype(np.float32)
+    table = np.arange(1, P - 1).reshape(S, mp).astype(np.int32)
+    bad_pool, bad_table = pool.copy(), table.copy()
+    bad_pool[P - 1] = np.nan
+    bad_pool[0, 1:] = np.nan
+    positions = np.zeros(S, np.int32)
+    for s, pos in enumerate(slots):
+        if pos is None:
+            table[s], bad_table[s] = 0, 0
+            continue
+        positions[s] = pos
+        table[s, pos // ps + 1:] = 0
+        bad_table[s, pos // ps + 1:] = P - 1
+        bad_pool[table[s, pos // ps], pos % ps + 1:] = np.nan
+    q = jnp.asarray(rng.standard_normal((S, nq, dk)), dtype)
+    cast = lambda a: jnp.asarray(a).astype(dtype)
+    return (q, cast(pool), jnp.asarray(table), cast(bad_pool),
+            jnp.asarray(bad_table), jnp.asarray(positions))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("ppb", WALK_BLOCKS)
+@pytest.mark.parametrize("slots", WALK_POSITIONS)
+def test_latent_walk_reads_a_slots_live_pages_and_nothing_else(
+        slots, ppb, dtype, rng, monkeypatch):
+    """The walk at the latent shape, a block of `ppb` pages: slot 0 idle,
+    the last slot idle, every slot idle; lengths that end mid-page, at a
+    page's last position, at a block's edge and at the table's end.  The
+    kernel sees the POISONED pool and table, the composition the clean
+    ones: float32 differs by the order of the softmax's sums (1e-5), a
+    bfloat16 pool by the products' and the output's 2**-8 (2e-2, the K/V
+    walk's)."""
+    from hetu_tpu.ops.pallas import paged_latent_attention as pla
+    ps, mp, dv = 8, 5, 128
+    monkeypatch.setattr(pla, "_BLOCK_TOKENS", ppb * ps)
+    q, pool, table, bad_pool, bad_table, positions = _poisoned_walk(
+        rng, WALK_POSITIONS[slots], jnp.dtype(dtype), ps=ps, mp=mp)
+    assert pla.pages_per_block(q.shape[1], ps, q.shape[2],
+                               pool.dtype.itemsize, mp) == ppb
+    kw = dict(value_dim=dv, softmax_scale=0.11)
+    got = pla.paged_latent_attention(q, bad_pool, bad_table, positions, **kw)
+    want = pla.paged_latent_attention_xla(
+        q.astype(F32), pool.astype(F32), table, positions, **kw)
+    assert got.dtype == q.dtype and got.shape == (len(positions), 4, dv)
+    np.testing.assert_allclose(
+        np.asarray(got.astype(F32)), np.asarray(want),
+        atol=1e-5 if dtype == "float32" else 2e-2, rtol=0)
+
+
+#: the three cells' decode launches: (query rows, table width)
+LATENT_CELLS = {"longcat": (64, 10), "kimi": (64, 16), "ling": (32, 128)}
+
+
+@pytest.mark.parametrize("cell", LATENT_CELLS)
+def test_latent_block_rule_fits_vmem_and_the_table(cell):
+    """The block the rule returns for a cell's shape (pages of 256
+    tokens of 640 bfloat16 lanes) holds its two buffers and a block's
+    float32 scores within `_VMEM_BUDGET`, is never wider than the table
+    and never under a page; a table narrower than the block bounds it."""
+    from hetu_tpu.ops.pallas import paged_latent_attention as pla
+    nq, mp = LATENT_CELLS[cell]
+    ppb = pla.pages_per_block(nq, 256, 640, 2, mp)
+    assert 1 <= ppb <= mp
+    assert ppb * 256 * pla._token_vmem_bytes(nq, 640, 2) <= pla._VMEM_BUDGET
+    assert pla.pages_per_block(nq, 256, 640, 2, 1) == 1
+    # float32 pages under a table of any width: the budget still holds
+    wide = pla.pages_per_block(nq, 256, 640, 4, 1 << 20)
+    assert 1 <= wide <= ppb and wide * 256 * pla._token_vmem_bytes(
+        nq, 640, 4) <= pla._VMEM_BUDGET
+
+
+def test_latent_route_reason_carries_the_block(monkeypatch):
+    """`kernel_routes["paged_latent"]`: the dispatcher's reason, then the
+    block the wrapper's rule chose, once a traced layer; a call past the
+    dispatcher records nothing."""
+    from hetu_tpu.ops import pallas as plz
+    from hetu_tpu.ops.pallas import paged_latent_attention as pla
+    monkeypatch.setenv("HETU_TPU_PALLAS", "1")
+    shapes = ((2, 4, 256), (7, 8, 256), (2, 3), (2,))
+    q, pool = jnp.zeros(shapes[0], F32), jnp.zeros(shapes[1], F32)
+    table, pos = jnp.zeros(shapes[2], jnp.int32), jnp.zeros(2, jnp.int32)
+    with plz.record_routes() as routes:
+        for _ in range(2):
+            assert plz.resolve_route("paged_latent", pla.check_shapes,
+                                     *shapes, value_dim=128)
+            pla.paged_latent_attention(q, pool, table, pos, value_dim=128)
+        pla.paged_latent_attention(q, pool, table, pos, value_dim=128)
+    assert routes["paged_latent"] == {"pallas": 2, "xla": 0, "why": {
+        "forced on by HETU_TPU_PALLAS=1, pages_per_block=3": 2}}
+
+
 def test_latent_kernel_gate_names_what_it_refuses():
     from hetu_tpu.ops.pallas import paged_latent_attention as pla
     ok = ((4, 64, 640), (9, 64, 640), (4, 3), (4,))

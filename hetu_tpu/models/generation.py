@@ -12,6 +12,9 @@ here knows a family.  What a token stores is the model's cache contract
 it are the model's hooks:
 
   embed_tokens(params, ids, pos_ids)   -> x [b, s, hidden]
+      (or whatever the model carries between its layers: a block with
+      the residual hooks below may carry a stream [b, s, n, hidden],
+      which `final_hidden` collapses)
   rope_tables(max_len)                 -> whatever its `project` takes
   serving_params(params)               -> params     (OPTIONAL)
       the parameters as these programs read them, where serving wants
@@ -26,6 +29,12 @@ it are the model's hooks:
       layer's own arrays, and the layer is called.  `_walk_layers`, the
       one walk over the layers, chooses by that and by nothing else.
   block.input_norm / post_norm / mlp_stats(params, x) -> (y, stats)
+  block.residual_pre(lp, side, carry) -> (x, mix),
+  block.residual_post(lp, side, mix, carry, y) -> carry   (OPTIONAL)
+      THE BLOCK'S RESIDUAL PATH around each of its two sublayers (`side`
+      "attn" or "mlp"): what the sublayer reads of the carry and how its
+      output is folded back (`_layer`).  Absent: x is the carry and y is
+      added to it.
   block.window, block.attn_scope         (OPTIONAL)
       how far back the layer's queries read, their own position counted
       (None or absent: everything), as the cache contract's `windows`
@@ -357,12 +366,54 @@ def _attn_scopes(block):
     return stack
 
 
+class _Add:
+    """The residual path of a block that brings none: a sublayer reads
+    the carry as it is, and its output is added to it under the
+    sublayer's own scopes, where the add has always stood (so the
+    programs of every such block lower to the text they lowered to)."""
+
+    def __init__(self, block):
+        self.block = block
+
+    def residual_pre(self, lp, side, h):
+        return h, None
+
+    def residual_post(self, lp, side, mix, h, y):
+        with (_attn_scopes(self.block) if side == "attn"
+              else jax.named_scope(side)):
+            return h + y
+
+
+def _residual_path(block):
+    """The block's pair of residual hooks (`_layer`), or `_Add`."""
+    return block if hasattr(block, "residual_pre") else _Add(block)
+
+
 def _layer(block, lp, h, rope, pos_ids, cache_step, state_step=None,
            handed=None):
     """THE decoder layer of every program below: norm, projection,
     attention over the cache, output and residual, then the MLP and its
     residual, under the scopes a device trace is summed by
     (obs.scope_map: `attn`, `attn/kv_write`, `mlp`).
+
+    THE RESIDUAL PATH IS THE BLOCK'S, by one pair of hooks around each
+    of the two sublayers (`side` is "attn" or "mlp", `lp` the layer's
+    parameters):
+
+      block.residual_pre(lp, side, carry)  -> (x, mix)
+          the sublayer's input x [b, s, hidden] from what is carried
+          between layers, and whatever `residual_post` takes
+      block.residual_post(lp, side, mix, carry, y) -> carry
+          the sublayer's output y folded back into the carry
+
+    Both run OUTSIDE the sublayer's scopes, so that a block's own scopes
+    for them (nn/hyper_connections: `mhc_pre`, `mhc_sinkhorn`,
+    `mhc_post`) are siblings of `attn` and `mlp` and those keep meaning
+    what they mean in every other program.  What is carried is the
+    model's business (`embed_tokens` makes it, `final_hidden` takes it):
+    [b, s, hidden], or a stream [b, s, n, hidden]; the programs only
+    require its first two dimensions.  A block without the hooks has
+    `_Add`: x is the carry, and y is added to it.
 
     cache_step(attn module, its params, q, entries, win) -> (attention
     output [b, s, n_q * hd], *rest) is what a program differs by: where
@@ -393,31 +444,34 @@ def _layer(block, lp, h, rope, pos_ids, cache_step, state_step=None,
     Returns (h, the layer's stats, handed, *rest)."""
     window = getattr(block, "window", None)
     win = {} if window is None else {"window": window}
+    path = _residual_path(block)
+    x, mix = path.residual_pre(lp, "attn", h)
     with _attn_scopes(block):
-        hn = block.input_norm(lp["input_norm"], h)
+        hn = block.input_norm(lp["input_norm"], x)
         if state_step is not None:
             out, new, *rest = state_step(block.attn, lp["attn"], hn)
             if getattr(block, "hands_on", False):
                 handed = new
-            h = h + out
         elif cache_step is None:
             rest = []
-            h = h + block.attn.mix(
+            out = block.attn.mix(
                 lp["attn"], hn, **({"handed": handed} if getattr(
                     block, "takes_handed", False) else {}))
         else:
             q, entries, *aux = block.attn.project(lp["attn"], hn, rope,
                                                   pos_ids)
             attn, *rest = cache_step(block.attn, lp["attn"], q, entries, win)
-            h = h + block.attn.output(lp["attn"], attn, *aux)
+            out = block.attn.output(lp["attn"], attn, *aux)
+    h = path.residual_post(lp, "attn", mix, h, out)
+    x, mix = path.residual_pre(lp, "mlp", h)
     with jax.named_scope("mlp"):
         y, st, *new = block.mlp_stats(
-            lp["mlp"], block.post_norm(lp["post_norm"], h),
+            lp["mlp"], block.post_norm(lp["post_norm"], x),
             **({"handed": handed} if getattr(
                 block, "mlp_takes_handed", False) else {}))
         if getattr(block, "mlp_hands_on", False):
             (handed,) = new
-        h = h + y
+    h = path.residual_post(lp, "mlp", mix, h, y)
     return (h, st, handed, *rest)
 
 
@@ -849,9 +903,10 @@ def extend_cache(model, params, tokens, cache, start, stats=None, *,
         projection and the cache write; h goes on as it came."""
         kind, at = page_at
         mine, put = _of_kind(cache, kind, n)
+        x, _ = _residual_path(block).residual_pre(lp, "attn", h)
         with _attn_scopes(block):
             entries = block.attn.project(
-                lp["attn"], block.input_norm(lp["input_norm"], h), rope,
+                lp["attn"], block.input_norm(lp["input_norm"], x), rope,
                 qpos)[1]
             with jax.named_scope("kv_write"):
                 new = tuple(

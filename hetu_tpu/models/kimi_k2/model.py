@@ -338,15 +338,16 @@ class _Layers(Module):
 
 
 class KimiK2Model(Module):
-    def __init__(self, config: KimiK2Config, strategy: ParallelStrategy):
+    def __init__(self, config: KimiK2Config, strategy: ParallelStrategy,
+                 block=KimiBlock):
         super().__init__()
         c = config
         self.embed = VocabParallelEmbedding(
             c.vocab_size, c.hidden_size, strategy, param_dtype=c.param_dtype,
             weight_init=init.normal(c.initializer_range))
-        self.dense_layers = _Layers(KimiBlock(c, strategy, moe=False),
+        self.dense_layers = _Layers(block(c, strategy, moe=False),
                                     c.first_k_dense_replace)
-        self.moe_layers = _Layers(KimiBlock(c, strategy, moe=True),
+        self.moe_layers = _Layers(block(c, strategy, moe=True),
                                   c.num_moe_layers)
         self.final_norm = ParallelRMSNorm(c.hidden_size, strategy,
                                           eps=c.rms_norm_eps,
@@ -354,6 +355,10 @@ class KimiK2Model(Module):
 
 
 class KimiK2LMHeadModel(Module):
+    #: the block of every layer (models/xing4 brings its own around this
+    #: one's sublayers)
+    BLOCK = KimiBlock
+
     def __init__(self, config: KimiK2Config,
                  strategy: Optional[ParallelStrategy] = None):
         super().__init__()
@@ -363,7 +368,7 @@ class KimiK2LMHeadModel(Module):
                 "models/kimi_k2 runs on one device: experts across chips "
                 "(ep > 1) and a sharded MLA are not built (ROADMAP)")
         self.config, self.strategy = config, strategy
-        self.model = KimiK2Model(config, strategy)
+        self.model = KimiK2Model(config, strategy, self.BLOCK)
         if config.tie_word_embeddings:
             raise NotImplementedError("Kimi-K2's head is untied")
         self.param("lm_head", (config.hidden_size, config.vocab_size),

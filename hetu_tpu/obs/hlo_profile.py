@@ -211,7 +211,10 @@ def group_of(op_name: str, phases: Tuple[str, ...] = PHASES) -> str:
 #: that no inner scope names (`tail`: models/generation.extend_cache; a
 #: layer's own scopes inside the tail keep their groups); the addend of
 #: the identity experts a token chose (`mlp` > `zero_experts`:
-#: nn/moe.SharedRoutedExperts told of them, models/longcat_flash).  `diff_out`,
+#: nn/moe.SharedRoutedExperts told of them, models/longcat_flash); the mixes
+#: of a residual stream around a sublayer, SIBLINGS of `attn` and `mlp`
+#: under `layer` (`mhc_pre`, `mhc_sinkhorn`, `mhc_post`:
+#: nn/hyper_connections, models/xing4).  `diff_out`,
 #: what follows the attention kernel in a differential-attention layer,
 #: is a scope of the operations' paths and NOT a group: its time stays
 #: with its kind of layer.  No program without these scopes changes its
@@ -223,7 +226,7 @@ SCOPE_MAP_GROUPS = ("kv_write", "loss", "mla_q", "mla_kv", "mla_out",
                     "kda_out",
                     "attn_cross", "ssm", "ssm_proj", "ssm_conv", "ssm_scan",
                     "ssm_step", "ssm_out", "gmu", "tail", "ssm_norm",
-                    "zero_experts")
+                    "zero_experts", "mhc_pre", "mhc_sinkhorn", "mhc_post")
 UNSCOPED = "unscoped"
 #: what `scope_sources` says of an instruction: its own `op_name` named
 #: the group; its called computation's instructions did; its first

@@ -33,7 +33,7 @@ LONGEST_FIRST = (
     "test_chip_compile", "test_benchmark_registry", "test_pallas_kernels",
     "test_phi4_flash", "test_chunk_read_row", "test_bailing_hybrid",
     "test_serving_view", "test_serving_decode", "test_comm", "test_lint",
-    "test_mimo_v2", "test_kimi_k2", "test_serving_families",
+    "test_mimo_v2", "test_kimi_k2", "test_xing4", "test_serving_families",
     "test_serving_pipeline", "test_jamba", "test_kda_scan_kernel",
     "test_trinity", "test_latent_chunk_attention", "test_step_spans",
     "test_pipeline_1f1b", "test_disagg", "test_serving_families_window",
@@ -42,9 +42,10 @@ LONGEST_FIRST = (
     "test_hlo_profile", "test_prefill_budget", "test_serving_trace",
     "test_hetero_pp", "test_numerics", "test_trainer", "test_moe_dispatch",
     "test_hetero_dp", "test_hetero_ring_tp", "test_serving_chaos",
-    "test_longcat", "test_benchmark_longcat", "test_pipeline",
+    "test_longcat", "test_benchmark_longcat", "test_benchmark_xing4",
+    "test_pipeline",
     "test_llama", "test_chip_smoke", "test_flash_attention", "test_gpt",
-    "test_chip_compile_longcat")
+    "test_chip_compile_longcat", "test_chip_compile_xing4")
 
 
 def pytest_configure(config):

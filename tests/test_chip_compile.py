@@ -286,10 +286,11 @@ def test_paged_kernel_takes_the_serving_cells_pool_as_it_lies():
         <= pa._VMEM_BUDGET
 
 
-#: the three cells' decode launches of the paged LATENT kernel: (slots,
+#: the four cells' decode launches of the paged LATENT kernel: (slots,
 #: query heads, table width, pages a cache layer + the null page, cache
 #: layers in the one flat pool); pages of 256 tokens of 640 lanes
 LATENT_CELLS = {
+    "xing4": (16, 32, 132, 2129, 5),
     "longcat": (96, 64, 10, 977, 8),
     "kimi": (64, 64, 16, 1041, 6),
     "ling": (32, 32, 128, 4113, 1),
@@ -600,6 +601,18 @@ def _scan_is_the_kernel(routes, chunk_text, decode_calls):
     assert not any("pallas_selective_scan" in ln for ln in decode_calls)
 
 
+def _xing4_cut():
+    """Xing4.0-29B-A4B as its cell serves it: the WHOLE cut (1 dense + 4
+    expert layers, all 64 experts and all 131,072 vocabulary rows) at
+    published widths, at the cell's slots, page, chunk and max_len."""
+    from benchmarks import traffic
+    from benchmarks.families import xing4
+    cfg = traffic.load_json("configs", "xing4.0-29b-a4b-depth5")
+    sv = cfg["serving"]
+    return xing4.build_model(cfg, sv), {k: sv[k] for k in (
+        "num_slots", "page_size", "max_len", "prefill_chunk", "num_pages")}
+
+
 #: family -> ((model, ServeConfig overrides) or None for the Llama-2-7B
 #: block, the kernels its decode program must hold as tpu_custom_calls)
 SERVING_FAMILIES = {
@@ -612,11 +625,12 @@ SERVING_FAMILIES = {
     "phi4flash": (_phi4flash_block, ("paged_attn",)),
     "jamba": (_jamba_whole, ("paged_attn",)),
     "longcat": (_longcat_cut, ("paged_latent",)),
+    "xing4": (_xing4_cut, ("paged_latent",)),
 }
 #: the families whose case runs from a file of its own
-#: (tests/test_chip_compile_longcat.py): a file is what one worker of the
-#: tier-1 run takes whole, and this one is among the longest
-ELSEWHERE = ("longcat",)
+#: (tests/test_chip_compile_longcat.py, .._xing4.py): a file is what one
+#: worker of the tier-1 run takes whole, and this one is among the longest
+ELSEWHERE = ("longcat", "xing4")
 
 
 @pytest.fixture(scope="module")
@@ -689,10 +703,10 @@ def test_serving_programs_compile_for_one_v5e(family, decode_text):
     latent_calls = [ln for ln in chunk_text
                     if 'custom_call_target="tpu_custom_call"' in ln
                     and "pallas_latent_chunk_attention" in ln]
-    if family in ("kimi", "ling", "longcat"):
+    if family in ("kimi", "ling", "longcat", "xing4"):
         rec = routes["latent_chunk_attn"]
         assert rec["pallas"] == len(latent_calls) == {
-            "kimi": 2, "ling": 1, "longcat": 8}[family] \
+            "kimi": 2, "ling": 1, "longcat": 8, "xing4": 5}[family] \
             and not rec["xla"], rec
         assert list(rec["why"]) == ["shape gate passes"]
         assert all("attn/pallas_latent_chunk_attention" in ln
@@ -814,6 +828,37 @@ def test_serving_programs_compile_for_one_v5e(family, decode_text):
         for name in ("decode", "prefill_chunk"):
             text = compiled[name].as_text()
             assert text.count("zero_experts") and "router" in text
+    elif family == "xing4":
+        # Kimi's sublayers inside the stream: both latent kernels once a
+        # layer, at 132 pages a slot and 33,792 positions of scratch; the
+        # pool (2,128 pages x 5 layers of 640 lanes) is carried in place,
+        # the stream is the carry ([1, 1024, 4, 3584]: the compiler lays
+        # it stream-major, whole tiles of positions x lanes, nothing
+        # padded), its three scopes stand in both programs, and weights
+        # + pool + the largest program's temporaries fit the chip
+        assert "chunk_attn" not in routes and not chunk_calls
+        assert sum("pallas_paged_latent_attention" in ln
+                   for ln in calls) == 5 == routes["paged_latent"]["pallas"]
+        assert engine.pool.arrays.k.shape == (5, 2129, 256, 640)
+        pool = sum(a.size * a.dtype.itemsize
+                   for a in engine.pool.arrays.tree())
+        assert pool == 5 * 2129 * 256 * 640 * 2
+        mem = {name: c.memory_analysis() for name, c in compiled.items()}
+        assert mem["decode"].alias_size_in_bytes >= pool
+        assert mem["decode"].temp_size_in_bytes < 0.1e9
+        assert mem["prefill_chunk"].temp_size_in_bytes < 0.3e9
+        assert 8.0e9 < 2 * engine.model.num_params() < 8.2e9
+        assert all(m.argument_size_in_bytes + m.temp_size_in_bytes
+                   < 15.2e9 for m in mem.values())
+        assert mem["decode"].argument_size_in_bytes \
+            > 2 * engine.model.num_params() + pool
+        for name in ("decode", "prefill_chunk"):
+            text = compiled[name].as_text()
+            assert all(f"layer/{scope}/" in text for scope in (
+                "mhc_pre", "mhc_sinkhorn", "mhc_post"))
+            assert "attn/mhc_" not in text and "mlp/mhc_" not in text
+        assert "bf16[1,1024,4,3584]{3,1,2,0:T(8,128)(2,1)" in \
+            compiled["prefill_chunk"].as_text()
     elif family == "trinity":
         rec = routes["chunk_attn"]
         assert 2 * rec["pallas"] == chunk_calls == 16 and not rec["xla"]

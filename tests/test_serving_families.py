@@ -219,6 +219,10 @@ def _family_case(family, hd128):
         from test_longcat import build, ref_logits
         cfg, model, params = build()
         return model, params, lambda p, ids: ref_logits(p, cfg, ids)
+    if family == "xing4":
+        from test_xing4 import build, ref_logits
+        cfg, model, params = build()
+        return model, params, lambda p, ids: ref_logits(p, cfg, ids)
     if family == "gpt":
         from hetu_tpu.models.gpt import GPTConfig, GPTLMHeadModel
         kw = dict(hidden_size=256, num_attention_heads=2) if hd128 else {}
@@ -236,7 +240,7 @@ def _family_case(family, hd128):
 
 
 #: the families whose cache is a latent a token (models/kimi_k2.MLAttention)
-LATENT = ("kimi", "ling", "longcat")
+LATENT = ("kimi", "ling", "longcat", "xing4")
 
 
 #: the cases of `test_a_family_is_served_by_its_hooks` by the file that
@@ -290,6 +294,9 @@ def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
     side to the second's (`mlp_hands_on` / `mlp_takes_handed`), routed by
     softmax over routed and identity experts, over the two latent
     attentions as kimi.
+    `xing4` is the family whose carry between layers is a STREAM of four
+    hidden vectors a token, read and written through the block's
+    `residual_pre` / `residual_post` hooks around Kimi's sublayers.
     `llama-unstacked` is the Llama block built with
     use_scan=False: a layer's own arrays, called, never scanned."""
     monkeypatch.setenv("HETU_TPU_PALLAS",
@@ -353,9 +360,13 @@ def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
         n = {k[len("serve.moe_"):]: reg.counter_value(k)
              for k, _ in model.STATS}
         assert n["extra_row_blocks"] >= 0
-        assert n["layer_steps"] > 0 \
-            and n["assignments"] > n["local_assignments"]
-        assert n["expert_hits"] <= 4 * n["layer_steps"]
+        # (xing4 holds every expert of a layer, 8 here: every pair is
+        # local; the others hold 4 of their router's 16)
+        held = 8 if family == "xing4" else 4
+        assert n["layer_steps"] > 0 and (
+            n["assignments"] == n["local_assignments"] if held == 8
+            else n["assignments"] > n["local_assignments"])
+        assert n["expert_hits"] <= held * n["layer_steps"]
         assert 0 < n["max_expert_load"] <= 16 * 4
 
 

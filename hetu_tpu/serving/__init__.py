@@ -12,6 +12,13 @@ pay for (or lower differently because of) the serving stack — the
 serving flags (HETU_TPU_KV_QUANT, HETU_TPU_SERVE_TRACE + the
 serve-shape flags) are read only inside this package, so leaving them
 unset cannot perturb any training program.
+
+The reverse does not hold yet: this package imports the trainer
+(`reshard` -> `engine.hot_switch.param_handle` -> `hetu_tpu.engine` ->
+`engine.trainer`).  That is hetu_tpu's own modules alone, tens of
+milliseconds, since `utils.checkpoint` loads orbax at the first save or
+restore (PR 56; tests/test_import_cost.py keeps it so); cutting the edge
+itself is ROADMAP queue 3's.
 """
 from hetu_tpu.serving.costs import (COST_FIELDS,  # noqa: F401
                                     CostLedger, CostModel,

@@ -12,6 +12,15 @@ the strategy-resharding load the reference implements by slice bookkeeping
 comes from handing orbax the new NamedShardings.  Async save uses orbax's
 AsyncCheckpointer (background thread), the analog of save_file_async.
 
+orbax itself loads at the first save or restore (`_ocp()`), not when this
+module is imported: `hetu_tpu.engine` and, through `serving.reshard`,
+`hetu_tpu.serving` import this module, and orbax brings ~230 packages
+(google.cloud.logging, grpc, tensorstore, aiohttp) that took 12-14 s of
+every serving run's set-up and 35-39 s of every train run's on the chip's
+host (PERF.md s5, PR 56).  A job that checkpoints pays the load once, in
+`CheckpointManager.__init__` (`Trainer.__init__` with a `ckpt_dir`), never
+inside a step.
+
 Verified fallback (docs/fault_tolerance.md): every committed save gets a
 per-step MANIFEST next to the step directory — the state's pytree
 structure hash plus per-file size+crc32 — written atomically AFTER the
@@ -26,16 +35,29 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import time
 import zlib
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
-import orbax.checkpoint as ocp
 
 from hetu_tpu.utils.logging import get_logger
 
 logger = get_logger("checkpoint")
+
+
+def _ocp():
+    """`orbax.checkpoint`, imported at the first save or restore."""
+    first = "orbax.checkpoint" not in sys.modules
+    t0 = time.perf_counter()
+    import orbax.checkpoint
+    if first:
+        logger.info(f"orbax.checkpoint loaded in "
+                    f"{time.perf_counter() - t0:.2f} s (this process's "
+                    f"first save or restore)")
+    return orbax.checkpoint
+
 
 # remote stores ride orbax's filesystem layer untouched — the TPU-native
 # analog of the reference's HDFS branch (model_saver.py:168): on TPU pods
@@ -174,6 +196,7 @@ class CheckpointManager:
         self._manifests_enabled = not _is_remote(self.directory)
         self._pending: Optional[Tuple[int, Optional[str]]] = None
         self._manifest_thread = None
+        ocp = _ocp()
         options = ocp.CheckpointManagerOptions(
             max_to_keep=max_to_keep, enable_async_checkpointing=async_save)
         self._mgr = ocp.CheckpointManager(self.directory, options=options)
@@ -182,7 +205,7 @@ class CheckpointManager:
     def save(self, step: int, state: Dict[str, Any], wait: bool = False):
         """state: arbitrary pytree (params/opt_state/step/...)."""
         self._finalize_pending()   # manifest for the PREVIOUS async save
-        saved = self._mgr.save(step, args=ocp.args.StandardSave(state))
+        saved = self._mgr.save(step, args=_ocp().args.StandardSave(state))
         if saved is False:
             # orbax declines silently when the step already exists (e.g.
             # re-saving the restore point after a fallback walked past a
@@ -288,7 +311,8 @@ class CheckpointManager:
                                            sharding=getattr(x, "sharding", None))
             if hasattr(x, "shape") else x,
             target)
-        return self._mgr.restore(step, args=ocp.args.StandardRestore(abstract))
+        return self._mgr.restore(
+            step, args=_ocp().args.StandardRestore(abstract))
 
     def restore_latest_valid(self, target: Optional[Any] = None,
                              restore_fn=None, on_fallback=None
@@ -412,7 +436,7 @@ class CheckpointManager:
 
 def save_checkpoint(path: str, state: Any):
     """One-shot synchronous save (reference temp_save analog)."""
-    ckptr = ocp.StandardCheckpointer()
+    ckptr = _ocp().StandardCheckpointer()
     ckptr.save(resolve_ckpt_path(path), state, force=True)
     ckptr.wait_until_finished()
     ckptr.close()
@@ -420,7 +444,7 @@ def save_checkpoint(path: str, state: Any):
 
 def load_checkpoint(path: str, target: Optional[Any] = None) -> Any:
     """One-shot load, resharding into `target`'s shardings if given."""
-    ckptr = ocp.StandardCheckpointer()
+    ckptr = _ocp().StandardCheckpointer()
     try:
         if target is None:
             return ckptr.restore(resolve_ckpt_path(path))

@@ -75,10 +75,11 @@ the published code interleaves (`rope_interleave`: a fixed permutation of
 weight columns, nothing with random weights); the multi-token-prediction
 layer (`num_nextn_predict_layers` 1) is not built.
 
-The family also brings a reduction rule, `scope_roofline_pct` (below):
-the two KDA computations are XLA compositions, whose device events carry
+The two KDA computations are XLA compositions, whose device events carry
 no name of their own for `roofline_pct` to match, so their share is the
-cost function's least time over the device time of their SCOPES.
+cost function's least time over the device time of their SCOPES: the
+harness's rule `scope_roofline_pct` (benchmarks/trace.py; written here in
+PR 41, moved there in PR 57).
 """
 from __future__ import annotations
 
@@ -87,9 +88,10 @@ import math
 import jax
 import jax.numpy as jnp
 
-from benchmarks import trace as trace_mod
 from benchmarks.families.kimi_k2 import (_rms_norm, _swiglu,  # noqa: F401
                                          grouped_matmul_cost)
+from benchmarks.families.kimi_k2 import \
+    latent_chunk_attn_cost as kimi_k2_latent_chunk_attn_cost
 from benchmarks.families.llama import serve_config  # noqa: F401
 # at import, not in `build_model`: a program without the family (the
 # parent of PR 41) then fails in `run.load_cell`, at once, with exit 2
@@ -606,6 +608,17 @@ def paged_latent_attn_cost(cfg: dict, window: dict,
                                        + queries * nh * (latent + r))}
 
 
+def latent_chunk_attn_cost(cfg: dict, window: dict,
+                           elem_bytes: float = 2.0):
+    """families/kimi_k2.latent_chunk_attn_cost over this family's latent
+    layers alone (one of every `layer_group_size`).
+    `serve.prefill_attended_keys` is a launch's pairs in ONE layer of
+    the one kind of layer that attends (the KDA layers keep state, no
+    keys)."""
+    return kimi_k2_latent_chunk_attn_cost(
+        dict(cfg, num_hidden_layers=_kinds(cfg)[1]), window, elem_bytes)
+
+
 def kda_state_cost(cfg: dict, window: dict, elem_bytes: float = 2.0):
     """Required bytes and operations of the KDA layers of the window's
     decode steps: the state of each row that decodes read once and
@@ -647,33 +660,3 @@ def kda_chunk_cost(cfg: dict, window: dict):
     return {"ops": n_kda * nh * per_block * tokens / L,
             "bytes": n_kda * nh * 4.0 * (tokens * (5 * d + 1)
                                          + launches * 2 * d * d)}
-
-
-# ---------------------------------------------------------------------------
-# a rule of the family's own: a cost function over the time of SCOPES
-# ---------------------------------------------------------------------------
-
-def rule_scope_roofline_pct(p, trace, window, ctx):
-    """`roofline_pct` for a computation that is an XLA composition: least
-    time for the cost function's operations and bytes over the device
-    time of the selected scopes (`rule_scope_ms`'s selection: `program`
-    and `phase` / `group`) of the program's executions in the window.
-    None where the program has no such scope (the parent) or the
-    function finds nothing counted."""
-    from benchmarks import peaks
-    table = trace_mod.scope_table(trace, window, ctx)
-    rec = trace_mod._program(table, p["program"]) if table else None
-    device_s = trace_mod._selected(rec, p) if rec else None
-    cost_fn = getattr(ctx["family"], p["cost"], None)
-    if not device_s or cost_fn is None:
-        return None
-    cost = cost_fn(ctx["config"], ctx["window_counts"])
-    if not cost:
-        return None
-    least = peaks.roofline_seconds(cost, ctx["peaks"])
-    ctx.setdefault("notes", {})[p["cost"]] = dict(
-        least, executions=rec["executions"], device_s=device_s, **cost)
-    return 100.0 * least["seconds"] / device_s
-
-
-trace_mod.RULES.setdefault("scope_roofline_pct", rule_scope_roofline_pct)

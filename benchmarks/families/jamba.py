@@ -38,8 +38,8 @@ every layer (a later row's state and keys need it).
 
 The family also brings its cost functions under the names the standing
 rule files ask of a cell's family (`ssm_chunk_cost`, `ssm_state_cost`,
-`paged_attn_cost`, `chunk_attn_cost`) and reuses
-families/bailing_hybrid's rule `scope_roofline_pct`: the two scan
+`paged_attn_cost`, `chunk_attn_cost`) and reads them
+through the harness's rule `scope_roofline_pct` (benchmarks/trace.py): the two scan
 computations are XLA compositions, whose device events carry no name of
 their own.
 """
@@ -50,7 +50,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from benchmarks.families import bailing_hybrid as _ling  # noqa: F401 (its rule)
 from benchmarks.families.kimi_k2 import _rms_norm, _swiglu
 from benchmarks.families.phi4flash import serve_config  # noqa: F401
 # at import, not in `build_model`: a program without the family (the

@@ -430,6 +430,20 @@ def paged_latent_attn_cost(cfg: dict, window: dict,
                                        + queries * nh * (latent + r))}
 
 
+def latent_chunk_attn_cost(cfg: dict, window: dict,
+                           elem_bytes: float = 2.0):
+    """families/longcat_flash.latent_chunk_attn_cost over this family's
+    `num_hidden_layers` cache layers, every one a latent layer (that
+    function counts two cache layers a layer of its `num_layers`, and
+    every term by the layer; families/xing4 reads it the same way).
+    Imported here: that module imports this one."""
+    from benchmarks.families import longcat_flash
+    cost = longcat_flash.latent_chunk_attn_cost(
+        dict(cfg, num_layers=1), window, elem_bytes)
+    return cost and {k: v * cfg["num_hidden_layers"] / 2
+                     for k, v in cost.items()}
+
+
 def grouped_matmul_cost(cfg: dict, window: dict, elem_bytes: float = 2.0):
     """Required operations and bytes of the routed experts' grouped
     matrix products (gate|up, then down) of the window's decode and

@@ -48,8 +48,8 @@ other.  (3) attention runs in blocks of `Q_BLOCK` query rows so that
 24,576 positions fit; every block sees every key under its explicit mask.
 
 The family also brings its cost functions (`ssm_chunk_cost`,
-`ssm_state_cost`, `paged_attn_cost`, `chunk_attn_cost`) and reuses
-families/bailing_hybrid's rule `scope_roofline_pct`: the two scan
+`ssm_state_cost`, `paged_attn_cost`, `chunk_attn_cost`) and reads them
+through the harness's rule `scope_roofline_pct` (benchmarks/trace.py): the two scan
 computations are XLA compositions, whose device events carry no name of
 their own.
 """
@@ -60,7 +60,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from benchmarks.families import bailing_hybrid as _ling  # noqa: F401 (its rule)
 from benchmarks.families.kimi_k2 import _swiglu
 # at import, not in `build_model`: a program without the family (the
 # parent of PR 43) then fails in `run.load_cell`, at once, with exit 2

@@ -715,9 +715,16 @@ def run_serve(cell, args, dev, tracer, compiles, phases):
     hlo_texts = [c.as_text() for c in compiled] if args.trace else []
     del compiled
     lower_compile_s = time.perf_counter() - t0
+    # the collector was frozen in the ramp's last second, the engine with
+    # it: thawed, or `del engine` and `collect()` free no pool (the engine
+    # and its scheduler, cache and recorder refer to each other) and the
+    # reference runs beside it
+    gc.unfreeze()
     engine.close()
     del engine
     gc.collect()
+    in_use_at_check = int((jax.devices()[0].memory_stats() or {}).get(
+        "bytes_in_use", 0))
     from benchmarks import reference
     rng = traffic_mod.rng_for(args.seed, "check")
     good = [r.rid for r in done_in
@@ -734,6 +741,9 @@ def run_serve(cell, args, dev, tracer, compiles, phases):
                 results[rid].tokens, sv["max_len"])))
     check = {"ok": bool(streams) and all(s["ok"] for s in streams),
              "streams": streams,
+             # what the device held when the reference began: the
+             # weights (and the runtime's own), the pool freed
+             "device_bytes_in_use_at_start": in_use_at_check,
              "rule": "each served token's reference logit within 16 bf16 "
                      "ulps of the reference's maximum given the stream's "
                      "own prefix, and 70% of a stream's tokens the "

@@ -30,11 +30,25 @@ CELL = "jamba2-3b-serve-concurrent-turns"
 CONFIG = "jamba2-3b"
 BENCHMARK = os.path.join(ROOT, "BENCHMARK.json")
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-# the 17 standing entries the cell joined, and the four it brought
-JOINED = {
-    "phi4f.decode_step_dev_ms", "phi4f.prefill_chunk_dev_ms",
-    "phi4f.device_idle", "phi4f.compiles_in_window",
-    "phi4f.decode_batch_inside", "phi4f.decode_ctx_ktokens_step",
+# the engine-loop entries every serving cell reports since PR 57 (the five
+# first cells had them since PR 40): the host's share of a step, where the
+# device idles, the loop's own counts
+ENGINE_LOOP = {
+    "peak_hbm_gb", "host_work_ms_step", "starved_ms_step",
+    "sync_idle_ms_step", "prefill_token_share_inside",
+    "decode_unscoped_dev_ms", "engine_empty_pct", "stalled_steps_pct",
+    "fetch_wait_ms_step", "decode_overlap_pct"}
+# of those, what a run without a device plane reports too
+ENGINE_LOOP_COUNTERS = ENGINE_LOOP - {
+    "starved_ms_step", "sync_idle_ms_step", "decode_unscoped_dev_ms"}
+# the 17 standing entries the cell joined in PR 47, the engine-loop
+# entries and the decode program's `mlp` scope (PR 57), and the four it
+# brought
+JOINED = ENGINE_LOOP | {
+    "decode_mlp_dev_ms",
+    "decode_step_dev_ms", "prefill_chunk_dev_ms",
+    "device_idle", "compiles_in_window",
+    "decode_batch_inside", "decode_ctx_ktokens_step",
     "phi4f.decode_ssm_dev_ms", "phi4f.prefill_ssm_dev_ms",
     "phi4f.ssm_scan_roofline", "phi4f.ssm_state_roofline",
     "phi4f.prefill_tail_rows_pct", "decode_full_attn_dev_ms",
@@ -45,9 +59,9 @@ BROUGHT = {"jamba.ssm_state_gb_step", "jamba.decode_ssm_norm_dev_ms",
            "jamba.prefill_ssm_norm_dev_ms", "jamba.prefill_ssm_scan_dev_ms"}
 # what the cell reports without a device plane (a rule file's `device`
 # false)
-COUNTER_METRICS = {
-    "phi4f.compiles_in_window", "phi4f.decode_ctx_ktokens_step",
-    "phi4f.decode_batch_inside", "mimo.prefill_attended_kkeys_token",
+COUNTER_METRICS = ENGINE_LOOP_COUNTERS | {
+    "compiles_in_window", "decode_ctx_ktokens_step",
+    "decode_batch_inside", "mimo.prefill_attended_kkeys_token",
     "phi4f.prefill_tail_rows_pct", "jamba.ssm_state_gb_step"}
 ROOFLINES = {"phi4f.ssm_scan_roofline": "ssm_chunk_cost",
              "phi4f.ssm_state_roofline": "ssm_state_cost",
@@ -87,7 +101,7 @@ def test_tiny_jamba_rehearses_correct(trace_on):
     assert got == (COUNTER_METRICS if trace_on
                    else {"serve_tokens_per_s", "setup_s"})
     if trace_on:
-        m = {k.removeprefix("cpu_rehearsal.").split(".", 1)[1]:
+        m = {k.removeprefix("cpu_rehearsal.").split(".", 1)[-1]:
              v["value"] for k, v in line["metrics"].items()}
         assert m["compiles_in_window"] == 0
         # one tail row a prompt: 8 prompts of 460 tokens a block
@@ -158,7 +172,7 @@ def test_the_cell_and_its_files():
     assert max(p) + max(o) <= sv["max_len"]
     assert sum(p) / (sum(p) + sum(o)) == pytest.approx(0.814, abs=0.001)
     # what is reported IN the cell, wherever the entries stand and
-    # whichever other cells share them: the 17 joined and the 4 brought
+    # whichever other cells share them: the 28 joined and the 4 brought
     mine = [m for m in b["per_layer"] if CELL in m["workloads"]]
     assert {m["name"] for m in mine} == JOINED | BROUGHT
     assert all(m["workloads"] == [CELL] for m in mine
@@ -232,7 +246,8 @@ def test_every_scope_rule_of_the_cell_finds_its_scope_in_the_programs():
         "phi4f.ssm_scan_roofline", "phi4f.ssm_state_roofline",
         "decode_full_attn_dev_ms", "mimo.prefill_full_attn_dev_ms",
         "decode_kv_write_dev_ms", "jamba.decode_ssm_norm_dev_ms",
-        "jamba.prefill_ssm_norm_dev_ms", "jamba.prefill_ssm_scan_dev_ms"}
+        "jamba.prefill_ssm_norm_dev_ms", "jamba.prefill_ssm_scan_dev_ms",
+        "decode_mlp_dev_ms", "decode_unscoped_dev_ms"}
     tr = trace.Trace({dev: ops}, {dev: mods}, [])
     value = {}
     for name in by_scope:

@@ -170,7 +170,7 @@ def test_device_metrics_from_a_hand_made_trace():
         for m in cell["per_layer"]
         if runner.metric_spec(m["name"])["device"]}
     assert got["decode_step_dev_ms"] == pytest.approx(10.0)
-    assert got["kimi.decode_mla_dev_ms"] == pytest.approx(5.0)
+    assert got["decode_mla_dev_ms"] == pytest.approx(5.0)
     # the grouped product's custom call has lost its path in the
     # compiler and takes the scope of the rows it multiplies
     assert got["decode_experts_dev_ms"] == pytest.approx(3.2)
@@ -178,7 +178,7 @@ def test_device_metrics_from_a_hand_made_trace():
     assert got["decode_unscoped_dev_ms"] == pytest.approx(0.5)
     fam = cell["family"]
     lat = fam.paged_latent_attn_cost(cell["config"], ctx["window_counts"])
-    assert got["kimi.paged_latent_attn_roofline"] == pytest.approx(
+    assert got["paged_latent_attn_roofline"] == pytest.approx(
         100 * max(lat["bytes"] / 819e9, lat["ops"] / 197e12) / 0.008)
     gm = fam.grouped_matmul_cost(cell["config"], ctx["window_counts"])
     assert got["grouped_matmul_roofline"] == pytest.approx(

@@ -30,32 +30,46 @@ CELL = "longcat-flash-serve-chat-turns"
 CONFIG = "longcat-flash-ep32-depth4"
 BENCHMARK = os.path.join(ROOT, "BENCHMARK.json")
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-# the 8 standing entries the cell joined, and the 13 it brought
-JOINED = {
-    "phi4f.compiles_in_window", "phi4f.decode_step_dev_ms",
-    "phi4f.prefill_chunk_dev_ms", "phi4f.device_idle",
-    "phi4f.decode_ctx_ktokens_step", "phi4f.decode_batch_inside",
-    "phi4f.prefill_tail_rows_pct", "prefill_rows_launch"}
-BROUGHT = {
-    "longcat.decode_mla_dev_ms", "longcat.prefill_mla_dev_ms",
-    "longcat.paged_latent_attn_roofline",
-    "longcat.latent_chunk_attn_roofline", "longcat.grouped_matmul_roofline",
-    "longcat.decode_experts_dev_ms", "longcat.prefill_experts_dev_ms",
-    "longcat.decode_dense_mlp_dev_ms", "longcat.zero_assignment_pct",
-    "longcat.local_assignment_pct", "longcat.experts_hit_per_layer_step",
-    "longcat.decode_unscoped_dev_ms", "longcat.peak_hbm_gb"}
+# the engine-loop entries every serving cell reports since PR 57 (the five
+# first cells had them since PR 40): the host's share of a step, where the
+# device idles, the loop's own counts
+ENGINE_LOOP = {
+    "peak_hbm_gb", "host_work_ms_step", "starved_ms_step",
+    "sync_idle_ms_step", "prefill_token_share_inside",
+    "decode_unscoped_dev_ms", "engine_empty_pct", "stalled_steps_pct",
+    "fetch_wait_ms_step", "decode_overlap_pct"}
+# of those, what a run without a device plane reports too
+ENGINE_LOOP_COUNTERS = ENGINE_LOOP - {
+    "starved_ms_step", "sync_idle_ms_step", "decode_unscoped_dev_ms"}
+# the 29 entries the cell shares with other cells (8 joined in PR 51; of
+# the 13 it had to bring, PR 57 folded ten repeats into the lists whose
+# rule they state and opened two new rules' lists to other cells; it
+# joined the engine-loop entries and the experts' row blocks), and the
+# one that is its own
+JOINED = ENGINE_LOOP | {
+    "compiles_in_window", "decode_step_dev_ms",
+    "prefill_chunk_dev_ms", "device_idle",
+    "decode_ctx_ktokens_step", "decode_batch_inside",
+    "phi4f.prefill_tail_rows_pct", "prefill_rows_launch",
+    "decode_mla_dev_ms", "prefill_mla_dev_ms",
+    "paged_latent_attn_roofline",
+    "latent_chunk_attn_roofline", "grouped_matmul_roofline",
+    "decode_experts_dev_ms", "prefill_experts_dev_ms",
+    "decode_mlp_dev_ms", "local_assignment_pct",
+    "experts_hit_per_layer_step", "experts_extra_blocks_pct"}
+BROUGHT = {"longcat.zero_assignment_pct"}
 # what the cell reports without a device plane (a rule file's `device`
 # false)
-COUNTER_METRICS = {
-    "phi4f.compiles_in_window", "phi4f.decode_ctx_ktokens_step",
-    "phi4f.decode_batch_inside", "phi4f.prefill_tail_rows_pct",
+COUNTER_METRICS = ENGINE_LOOP_COUNTERS | {
+    "compiles_in_window", "decode_ctx_ktokens_step",
+    "decode_batch_inside", "phi4f.prefill_tail_rows_pct",
     "prefill_rows_launch", "longcat.zero_assignment_pct",
-    "longcat.local_assignment_pct", "longcat.experts_hit_per_layer_step",
-    "longcat.peak_hbm_gb"}
+    "local_assignment_pct", "experts_hit_per_layer_step",
+    "experts_extra_blocks_pct"}
 ROOFLINES = {
-    "longcat.paged_latent_attn_roofline": "paged_latent_attn_cost",
-    "longcat.latent_chunk_attn_roofline": "latent_chunk_attn_cost",
-    "longcat.grouped_matmul_roofline": "grouped_matmul_cost"}
+    "paged_latent_attn_roofline": "paged_latent_attn_cost",
+    "latent_chunk_attn_roofline": "latent_chunk_attn_cost",
+    "grouped_matmul_roofline": "grouped_matmul_cost"}
 
 
 def run(*args):
@@ -165,7 +179,7 @@ def test_the_cell_and_its_files():
     assert max(p) + max(o) <= sv["max_len"]
     assert sum(p) / (sum(p) + sum(o)) == pytest.approx(0.730, abs=0.001)
     # what is reported IN the cell, wherever the entries stand and
-    # whichever other cells share them: the 8 joined and the 13 brought
+    # whichever other cells share them: the 29 shared and the 1 its own
     mine = [m for m in b["per_layer"] if CELL in m["workloads"]]
     assert {m["name"] for m in mine} == JOINED | BROUGHT
     assert all(m["workloads"] == [CELL] for m in mine
@@ -234,9 +248,9 @@ def test_every_scope_rule_of_the_cell_finds_its_scope_in_the_programs():
                 if runner.metric_spec(m["name"])["reduce"]["rule"]
                 == "scope_ms"]
     assert set(by_scope) == {
-        "longcat.decode_mla_dev_ms", "longcat.prefill_mla_dev_ms",
-        "longcat.decode_experts_dev_ms", "longcat.prefill_experts_dev_ms",
-        "longcat.decode_dense_mlp_dev_ms", "longcat.decode_unscoped_dev_ms"}
+        "decode_mla_dev_ms", "prefill_mla_dev_ms",
+        "decode_experts_dev_ms", "prefill_experts_dev_ms",
+        "decode_mlp_dev_ms", "decode_unscoped_dev_ms"}
     tr = trace.Trace({dev: ops}, {dev: mods}, [])
     value = {}
     for name in by_scope:

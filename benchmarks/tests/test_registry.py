@@ -1,8 +1,9 @@
 """The registry of per-layer metrics: `BENCHMARK.json`'s `per_layer` list
 and the rule files under `benchmarks/metrics/`, held to each other and to
 the limits of the file (PR 40: one entry a quantity, each with the list
-of the cells that report it).  Plain tests, not one a metric: a later
-fold or prune does not change how many there are."""
+of the cells that report it; PR 57: one entry a RULE and end-to-end
+metric, held by a guard).  Plain tests, not one a metric: a later fold
+or prune does not change how many there are."""
 import importlib
 import json
 import os
@@ -54,6 +55,21 @@ def test_every_entry_lists_its_cells_and_every_listed_name_is_a_cell():
     for m in b["per_layer"]:
         at = [order[c] for c in m["workloads"]]
         assert at == sorted(at), m["name"]
+
+
+def test_no_two_entries_of_one_end_to_end_metric_state_the_same_rule():
+    """PR 57 folded twenty entries that repeated, under a cell's prefix,
+    a rule (`reduce` and `device` of the rule file) that an entry moving
+    the same end-to-end metric already stated: a cell that reports a
+    quantity joins that entry's list.  A repeat would come back as a
+    second name for one reading."""
+    seen = {}
+    for m in bench()["per_layer"]:
+        spec = traffic.load_json("metrics", m["name"])
+        key = (m["moves"], json.dumps(spec["reduce"], sort_keys=True),
+               spec["device"])
+        assert key not in seen, (m["name"], seen[key])
+        seen[key] = m["name"]
 
 
 def test_a_shared_roofline_finds_its_cost_in_every_listed_cells_family():

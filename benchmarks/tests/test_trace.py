@@ -570,13 +570,28 @@ def close(a, b):
     return same(a, b, tol=0.0, rel=1e-9)
 
 
+def _row_sum(program):
+    return sum(r["ms"] for r in program["rows"])
+
+
 def test_a_trace_with_the_programs_spans_reads_as_head_read_it(tmp_path):
     """`tiny-chat-spans.xplane.pb.gz`: the tiny chat rehearsal on a TPU
     v5e with the program's spans, launches and scopes in it (my chip run,
     PR 31).  Beside it, the run's own ctx and every reading `trace.py` of
     the commit before PR 31 made of the file: the 23 metrics of the cell,
     the breakdown, the sync spans' idle by position, the launches by
-    phase and the scopes line.  The reduction gives the same, to 1e-9."""
+    phase and the scopes line.  The reduction gives the same, to 1e-9.
+
+    `head` was recorded again in PR 57 for what reads a SCOPE: since
+    PR 53 `obs.hlo_profile.scope_map` places an instruction by its own
+    `op_name`, a fusion's body, the first scoped user, the first scoped
+    operand, and the recorded decode text was not fully scoped (16.6 of
+    its 24.1 us were pool copies, `copy-start` / `-done` pairs and
+    fusions without metadata).  Three `scope_ms` metrics of the decode
+    program and the scopes line moved; `head_before_pr53` keeps what the
+    map read of them until then, and the guards below hold the two to
+    each other: time moved between a program's rows, none made or
+    lost."""
     import gzip
     import importlib
     from benchmarks import peaks
@@ -609,8 +624,28 @@ def test_a_trace_with_the_programs_spans_reads_as_head_read_it(tmp_path):
     assert trace.eager_dispatches(tr.launches, w) == head["eager_dispatches"]
     assert head["eager_dispatches"]["serve.page_write"][
         "PjitFunction(write_fn)"] == 7
-    assert close(json.loads(json.dumps(emitted[0]["programs"])),
-                 head["scopes"])
+    scopes = json.loads(json.dumps(emitted[0]["programs"]))
+    assert close(scopes, head["scopes"])
+    # what PR 53's map moved: three metrics, each still a reading; time
+    # moved between a program's rows, none made or lost; and less of
+    # every program is `unscoped` than the map before read, never more
+    before = saved["head_before_pr53"]
+    assert set(before["metrics"]) == {
+        "chat.decode_attn_dev_ms", "chat.decode_kv_write_dev_ms",
+        "chat.decode_unscoped_dev_ms"}
+    for name, was in before["metrics"].items():
+        assert not close(head["metrics"][name], was), name
+    assert set(scopes) == set(before["scopes"])
+    for name, program in scopes.items():
+        was = before["scopes"][name]
+        assert program["executions"] == was["executions"]
+        assert same(program["device_ms_per_execution"],
+                    was["device_ms_per_execution"])
+        assert same(_row_sum(program), _row_sum(was), tol=1e-12)
+        unscoped = [sum(r["ms"] for r in p["rows"]
+                        if r["group"] == "unscoped")
+                    for p in (program, was)]
+        assert unscoped[0] <= unscoped[1] + 1e-12, name
     # and the old arithmetic, kept above, is HEAD's on this file too
     assert_reduces_as_before(tr, w)
 

@@ -30,26 +30,44 @@ CELL = "xing4.0-serve-long-documents"
 CONFIG = "xing4.0-29b-a4b-depth5"
 BENCHMARK = os.path.join(ROOT, "BENCHMARK.json")
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-# the 8 standing entries the cell joined, and the 5 it brought
-JOINED = {
-    "phi4f.compiles_in_window", "phi4f.decode_step_dev_ms",
-    "phi4f.prefill_chunk_dev_ms", "phi4f.device_idle",
-    "phi4f.decode_ctx_ktokens_step", "phi4f.decode_batch_inside",
-    "phi4f.prefill_tail_rows_pct", "prefill_rows_launch"}
-BROUGHT = {
-    "xing.prefill_mhc_dev_ms", "xing.prefill_experts_dev_ms",
-    "xing.grouped_matmul_roofline", "xing.latent_chunk_attn_roofline",
-    "xing.paged_latent_attn_roofline"}
+# the engine-loop entries every serving cell reports since PR 57 (the five
+# first cells had them since PR 40): the host's share of a step, where the
+# device idles, the loop's own counts
+ENGINE_LOOP = {
+    "peak_hbm_gb", "host_work_ms_step", "starved_ms_step",
+    "sync_idle_ms_step", "prefill_token_share_inside",
+    "decode_unscoped_dev_ms", "engine_empty_pct", "stalled_steps_pct",
+    "fetch_wait_ms_step", "decode_overlap_pct"}
+# of those, what a run without a device plane reports too
+ENGINE_LOOP_COUNTERS = ENGINE_LOOP - {
+    "starved_ms_step", "sync_idle_ms_step", "decode_unscoped_dev_ms"}
+# the 28 entries the cell shares with other cells (8 joined in PR 55; in
+# PR 57 the four it had to bring as repeats were folded into the lists
+# whose rule they state, and it joined the engine-loop entries and the
+# six of Kimi's latent attention and experts that its programs have),
+# and the one it brought
+JOINED = ENGINE_LOOP | {
+    "compiles_in_window", "decode_step_dev_ms",
+    "prefill_chunk_dev_ms", "device_idle",
+    "decode_ctx_ktokens_step", "decode_batch_inside",
+    "phi4f.prefill_tail_rows_pct", "prefill_rows_launch",
+    "prefill_experts_dev_ms", "grouped_matmul_roofline",
+    "latent_chunk_attn_roofline", "paged_latent_attn_roofline",
+    "decode_experts_dev_ms", "experts_hit_per_layer_step",
+    "local_assignment_pct", "experts_extra_blocks_pct",
+    "decode_mla_dev_ms", "prefill_mla_dev_ms"}
+BROUGHT = {"xing.prefill_mhc_dev_ms"}
 # what the cell reports without a device plane (a rule file's `device`
 # false)
-COUNTER_METRICS = {
-    "phi4f.compiles_in_window", "phi4f.decode_ctx_ktokens_step",
-    "phi4f.decode_batch_inside", "phi4f.prefill_tail_rows_pct",
-    "prefill_rows_launch"}
+COUNTER_METRICS = ENGINE_LOOP_COUNTERS | {
+    "compiles_in_window", "decode_ctx_ktokens_step",
+    "decode_batch_inside", "phi4f.prefill_tail_rows_pct",
+    "prefill_rows_launch", "experts_hit_per_layer_step",
+    "local_assignment_pct", "experts_extra_blocks_pct"}
 ROOFLINES = {
-    "xing.grouped_matmul_roofline": "grouped_matmul_cost",
-    "xing.paged_latent_attn_roofline": "paged_latent_attn_cost",
-    "xing.latent_chunk_attn_roofline": "latent_chunk_attn_cost"}
+    "grouped_matmul_roofline": "grouped_matmul_cost",
+    "paged_latent_attn_roofline": "paged_latent_attn_cost",
+    "latent_chunk_attn_roofline": "latent_chunk_attn_cost"}
 MHC = ["mhc_pre", "mhc_sinkhorn", "mhc_post"]
 
 
@@ -161,7 +179,7 @@ def test_the_cell_and_its_files():
     assert max(p) + max(o) <= sv["max_len"]
     assert sum(p) / (sum(p) + sum(o)) == pytest.approx(0.982, abs=0.001)
     # what is reported IN the cell, wherever the entries stand and
-    # whichever other cells share them: the 8 joined and the 5 brought
+    # whichever other cells share them: the 28 shared and the 1 brought
     mine = [m for m in b["per_layer"] if CELL in m["workloads"]]
     assert {m["name"] for m in mine} == JOINED | BROUGHT
     assert all(m["workloads"] == [CELL] for m in mine
@@ -247,7 +265,6 @@ def test_every_scope_rule_of_the_cell_finds_its_scope_in_the_programs():
     device.  The stream's three scopes hold no attention and no expert
     operation: no product but the one with phi, no kernel."""
     from benchmarks import run as runner
-    from benchmarks.families import bailing_hybrid  # noqa: F401 (the rule)
     cfg, fam, engine = _tiny_engine()
     texts = [low.compile().as_text()
              for low in engine.lower_programs().values()]
@@ -269,8 +286,10 @@ def test_every_scope_rule_of_the_cell_finds_its_scope_in_the_programs():
     by_scope = [m["name"] for m in cell["per_layer"]
                 if runner.metric_spec(m["name"])["reduce"]["rule"]
                 in ("scope_ms", "scope_roofline_pct")]
-    assert set(by_scope) == {"xing.prefill_mhc_dev_ms",
-                             "xing.prefill_experts_dev_ms"}
+    assert set(by_scope) == {
+        "xing.prefill_mhc_dev_ms", "prefill_experts_dev_ms",
+        "decode_experts_dev_ms", "decode_mla_dev_ms", "prefill_mla_dev_ms",
+        "decode_unscoped_dev_ms"}
     tr = trace.Trace({dev: ops}, {dev: mods}, [])
     for name in by_scope:
         value = trace.reduce_metric(runner.metric_spec(name), tr, (0.0, t),

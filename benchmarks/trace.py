@@ -19,11 +19,12 @@ gave.  From inside it (PR 24):
   (instruction names repeat across programs), its `short_name` is looked
   up in that program's `hetu_tpu.obs.hlo_profile.scope_map`, and device
   time is summed per (group, pass, kind): `scope_table`, and the rules
-  `scope_ms` / `scope_pct`.  `kind` is `collective` for an operation
-  that `COLLECTIVE` names (the test `collective_ms_step` uses) and
-  `compute` for every other: the partitioner's all-reduce carries the
-  scope of what it reduces, so without the kind a layer's time on four
-  chips would hold its communication and on one chip not;
+  `scope_ms` / `scope_pct` / `scope_roofline_pct`.  `kind` is
+  `collective` for an operation that `COLLECTIVE` names (the test
+  `collective_exposed_ms_step` uses) and `compute` for every other: the
+  partitioner's all-reduce carries the scope of what it reduces, so
+  without the kind a layer's time on four chips would hold its
+  communication and on one chip not;
 * the program's `MetricsRegistry`: the loop snapshots it at the start and
   the end of the window, and the rule `counter` reads the difference of
   any counter by its name and labels (`counter_values`, `counter_key`).
@@ -815,6 +816,29 @@ def rule_roofline_pct(p, trace, window, ctx):
     return 100.0 * least["seconds"] / sum(e.dur for e in hit)
 
 
+def rule_scope_roofline_pct(p, trace, window, ctx):
+    """`roofline_pct` for a computation that is an XLA composition: least
+    time for the cost function's operations and bytes over the device
+    time of the selected scopes (`rule_scope_ms`'s selection: `program`
+    and `phase` / `group`) of the program's executions in the window.
+    None where the program has no such scope (the parent) or the
+    function finds nothing counted."""
+    from benchmarks import peaks
+    table = scope_table(trace, window, ctx)
+    rec = _program(table, p["program"]) if table else None
+    device_s = _selected(rec, p) if rec else None
+    cost_fn = getattr(ctx["family"], p["cost"], None)
+    if not device_s or cost_fn is None:
+        return None
+    cost = cost_fn(ctx["config"], ctx["window_counts"])
+    if not cost:
+        return None
+    least = peaks.roofline_seconds(cost, ctx["peaks"])
+    ctx.setdefault("notes", {})[p["cost"]] = dict(
+        least, executions=rec["executions"], device_s=device_s, **cost)
+    return 100.0 * least["seconds"] / device_s
+
+
 def _collective_spans(trace, window):
     """[(seconds of collective operations, seconds of them during which
     no other operation ran)] per device plane; made once per window, both
@@ -857,6 +881,7 @@ RULES = {
     "span_idle_ms": rule_span_idle_ms,
     "scope_ms": rule_scope_ms,
     "scope_pct": rule_scope_pct,
+    "scope_roofline_pct": rule_scope_roofline_pct,
 }
 
 

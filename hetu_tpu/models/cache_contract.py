@@ -4,9 +4,12 @@ model says it, and nothing else.
 `PagePool`, `kv_bytes_per_token`, the engine's prefill scratch and
 `serving/costs.py` size the cache from this one place.  A model of the
 K/V kind (llama, gpt) stores two arrays of `n_kv x head_dim` a token a
-layer; a latent-attention model stores one vector.  A model says what it
-stores by a `cache_contract()` method; one without it is of the K/V kind
-and its contract is read off its config.
+layer; a latent-attention model stores one vector, or a vector and
+whatever else its layers keep a token, each array of a shape of its own
+(a sparse-attention layer's latent AND its indexer's key: models/
+deepseek_v32), all under the one page table of the layer's kind.  A model
+says what it stores by a `cache_contract()` method; one without it is of
+the K/V kind and its contract is read off its config.
 
 How a query ATTENDS what is stored is the model's as well (the hooks of
 models/generation.py).  For the K/V kind that is the same for every
@@ -28,6 +31,16 @@ because what lies behind a window layer's window is never read again
 and its pages go back to the free list while the request lives.  A
 model all of whose layers read everything and store alike has one kind,
 and is served as it always was.
+
+**How many of the positions it may read a query ATTENDS** is the
+contract's as well (`selects`: per layer, the most positions a query's
+attention reads of those its window lets it see, chosen by the layer's
+own scoring of every one of them; None for a layer that attends all it
+sees).  Nothing is released by it (a later query may choose any cached
+position, so every page stays held): the engine counts from it what the
+layers select from and read (`serve.decode_selectable_tokens`,
+`serve.decode_selected_tokens`, `serve.prefill_selected_keys`), beside
+what they may read.
 
 **What a SEQUENCE stores** is the third part of a kind: a layer whose
 cache is a fixed state a sequence, whatever its length (a linear-attention
@@ -81,7 +94,8 @@ class CacheContract:
     #: "kv": K and V arrays of `n_kv x head_dim`, which the paged K/V
     #: kernels, speculative decoding, the prefix cache and the quantized
     #: page modes are built for; any other name
-    #: ("latent"): ONE array of the model's own shape, exact pages only
+    #: ("latent"): as many arrays as `token_shapes` names, each of the
+    #: model's own shape, under one page table; exact pages only
     kind: str = "kv"
     #: per layer, how far back the layer reads (positions, the query's
     #: own counted); None for a layer that reads everything, and None
@@ -106,6 +120,11 @@ class CacheContract:
     #: attends nothing).  None for the whole tuple: every layer keeps its
     #: own
     reads: Tuple[Optional[int], ...] = None
+    #: per layer, the most positions a query's attention reads of those
+    #: the layer lets it see (2048: the best by the layer's own scores);
+    #: None for a layer that attends everything it sees, and None for
+    #: the whole tuple where every layer does
+    selects: Tuple[Optional[int], ...] = None
 
     def __post_init__(self):
         if self.stored_shapes is None:
@@ -125,9 +144,11 @@ class CacheContract:
                                (None,) * self.num_layers)
         if self.reads is None:
             object.__setattr__(self, "reads", (None,) * self.num_layers)
+        if self.selects is None:
+            object.__setattr__(self, "selects", (None,) * self.num_layers)
         for what in (self.windows, self.layer_token_shapes,
                      self.layer_stored_shapes, self.state_shapes,
-                     self.reads):
+                     self.reads, self.selects):
             if len(what) != self.num_layers:
                 raise ValueError(f"{len(what)} entries for "
                                  f"{self.num_layers} layers: {what}")
@@ -159,8 +180,15 @@ class CacheContract:
                              "(models/generation.py)")
         if len({len(s) for s in paged} | {len(self.token_shapes)}) != 1:
             raise ValueError("every storing layer that holds pages stores "
-                             "the same NUMBER of arrays a token (K and V, "
-                             "or one latent)")
+                             "the same NUMBER of arrays a token (K and V; "
+                             "one latent; a latent and an indexer's key): "
+                             f"{len(self.token_shapes)} by `token_shapes`, "
+                             f"{sorted({len(s) for s in paged})} by layer")
+        if any(n is not None and (n < 1 or st is not None or r is not None)
+               for n, st, r in zip(self.selects, self.state_shapes,
+                                   self.reads)):
+            raise ValueError("`selects` is a count of positions, of a "
+                             f"layer that holds pages: {self.selects}")
         if any(st is not None and w is not None
                for st, w in zip(self.state_shapes, self.windows)):
             raise ValueError("a state layer reads no window: its state is "
@@ -254,12 +282,14 @@ class CacheContract:
     def by_kind(self) -> bool:
         """Pages, tables and the prefill scratch are laid out by kind of
         layer: some kind reads a window only, the kinds differ in what a
-        token stores, or the arrays a token stores (K and V) differ in
-        shape.  (Of the kinds that hold pages: a state kind holds
-        none.)"""
+        token stores, or K and V differ in shape.  (Of the kinds that
+        hold pages: a state kind holds none.  The arrays of a contract
+        that is not of the K/V kind are each of a shape of their own by
+        nature, a latent beside an indexer's key: one kind of them is a
+        pool of that many arrays under one table.)"""
         pages = [k for k in self._kinds if k[3] is None]
         return (len(pages) > 1 or pages[0][0] is not None
-                or len(set(pages[0][2])) > 1)
+                or (self.kind == "kv" and len(set(pages[0][2])) > 1))
 
     def kind_of(self, layer: int) -> Optional[int]:
         """The kind of one layer: its own where it stores, that of the

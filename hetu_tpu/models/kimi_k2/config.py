@@ -28,6 +28,11 @@ class KimiK2Config:
     n_routed_experts: int = 384             # the router's width
     n_shared_experts: int = 1
     num_experts_per_tok: int = 8
+    #: the router's groups of neighbouring experts and how many of them a
+    #: token's experts may lie in (`nn.moe.noaux_tc_gate`); 1 and 1,
+    #: Kimi's: no groups
+    n_group: int = 1
+    topk_group: int = 1
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 2.827
     max_position_embeddings: int = 262144

@@ -75,9 +75,18 @@ class MLAttention(Module):
         rotated); entries = (latent [b, s, stored],), the token's cache
         entry [RMSNorm(c_kv) | RoPE(k_rope) | 0 ...] (the normed latent
         times `kv_lora_scale`: what is cached is what W_kvb takes)."""
+        q, q_rope, _ = self.project_queries(params, hn, rope, pos_ids)
+        entries = self.project_entries(params, hn, rope, pos_ids)
+        return (q[..., :self.config.qk_nope_head_dim], q_rope), entries
+
+    def project_queries(self, params, hn, rope, pos_ids):
+        """(a head's [q_nope | q_rope] before the rotation [b, s, nh, dn +
+        dr], q_rope rotated, the normed low-rank query c_q [b, s,
+        q_lora_rank] both are made from: what a layer's indexer reads
+        too, models/deepseek_v32)."""
         c = self.config
         cos, sin = rope
-        r, dn = c.kv_lora_rank, c.qk_nope_head_dim
+        dn = c.qk_nope_head_dim
         with jax.named_scope("mla_q"):
             cq = self.q_norm(params["q_norm"],
                              hn @ params["wq_a"].astype(hn.dtype))
@@ -86,6 +95,13 @@ class MLAttention(Module):
             if self.q_lora_scale != 1.0:
                 q = (q.astype(jnp.float32) * self.q_lora_scale).astype(q.dtype)
             q_rope = ops.apply_rotary(q[..., dn:], cos, sin, pos_ids)
+        return q, q_rope, cq
+
+    def project_entries(self, params, hn, rope, pos_ids):
+        """`project`'s entries: (latent [b, s, stored],)."""
+        c = self.config
+        cos, sin = rope
+        r = c.kv_lora_rank
         with jax.named_scope("mla_kv"):
             ckv = hn @ params["wkv_a"].astype(hn.dtype)
             k_rope = ops.apply_rotary(ckv[..., None, r:], cos, sin,
@@ -100,13 +116,15 @@ class MLAttention(Module):
                 + ([jnp.zeros(ckv.shape[:-1] + (
                     c.latent_stored_dim - c.latent_dim,), ckv.dtype)]
                    if c.latent_stored_dim > c.latent_dim else []), axis=-1)
-        return (q[..., :dn], q_rope), (latent,)
+        return (latent,)
 
     # -- how a query attends the cache --------------------------------------
-    def attend_dense(self, params, q, caches, start):
+    def attend_dense(self, params, q, caches, start, keep=None):
         """EXPANDED form.  q of a C-token block at positions
         start[b] + i; caches = (latents [b, M, stored],) holding every
-        position <= start + C - 1.  Returns [b, C, nh * dv].
+        position <= start + C - 1.  Returns [b, C, nh * dv].  `keep`
+        [b, C, M] bool (a layer that selects what it attends: models/
+        deepseek_v32): of the positions a query sees, those it attends.
 
         Which shapes take which attention (the route record
         `kernel_routes["latent_chunk_attn"]` says it per traced layer):
@@ -138,17 +156,21 @@ class MLAttention(Module):
                         "a single query, or rows at depths of their own: "
                         "the composition")
         if not kernel:
-            return self._attend_composed(params, q, caches, start)
+            return self._attend_composed(params, q, caches, start, keep=keep)
         with jax.named_scope("pallas_latent_chunk_attention"):
             return _lca.latent_chunk_attention(
                 q_nope, q_rope, lat, params["wkv_b"], start,
-                softmax_scale=self.config.softmax_scale)
+                softmax_scale=self.config.softmax_scale,
+                keep=None if keep is None else keep[0])
 
-    def _attend_composed(self, params, q, caches, start, block: int = 512):
+    def _attend_composed(self, params, q, caches, start, block: int = 512,
+                         keep=None):
         """`attend_dense` as an XLA composition: keys are walked in
         blocks of `block` cached positions up to the last one any query
         sees (a loop with a data-dependent trip count, online softmax),
-        each block's k_nope and v made from its latents by W_kvb."""
+        each block's k_nope and v made from its latents by W_kvb.  `keep`
+        [b, C, M] bool (a layer that selects what it attends): of the
+        positions a query sees, those it attends."""
         c = self.config
         q_nope, q_rope = q
         (lat,) = caches
@@ -174,6 +196,9 @@ class MLAttention(Module):
                               preferred_element_type=f32)) * c.softmax_scale
             kpos = i * kb + jnp.arange(kb, dtype=jnp.int32)
             seen = kpos[None, None, :] <= qpos[:, :, None]     # [b, C, kb]
+            if keep is not None:
+                seen = seen & lax.dynamic_slice_in_dim(keep, i * kb, kb,
+                                                       axis=2)
             s = jnp.where(seen[:, None], s, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -229,7 +254,13 @@ class MLAttention(Module):
             with jax.named_scope("paged_latent_attention_xla"):
                 o_lat = _pla.paged_latent_attention_xla(qa, pool, table,
                                                         positions, **kw)
-        w_v = params["wkv_b"][..., c.qk_nope_head_dim:].astype(o_lat.dtype)
+        return self.expand_output(params, o_lat)
+
+    def expand_output(self, params, o_lat):
+        """The absorbed form's latent output [S, nh, r] through W_kvb's
+        value columns -> [S, 1, nh * dv]."""
+        w_v = params["wkv_b"][..., self.config.qk_nope_head_dim:].astype(
+            o_lat.dtype)
         o = jnp.einsum("bnr,rnd->bnd", o_lat, w_v)
         return o.reshape(o.shape[0], 1, -1)
 
@@ -272,6 +303,10 @@ class DenseMLP(Module):
 
 
 class KimiBlock(Module):
+    #: the attention of every layer (models/deepseek_v32 brings its own,
+    #: which selects what it attends)
+    ATTENTION = MLAttention
+
     def __init__(self, config: KimiK2Config, strategy: ParallelStrategy,
                  *, moe: bool):
         super().__init__()
@@ -279,7 +314,7 @@ class KimiBlock(Module):
         self.moe = moe
         norm = dict(eps=c.rms_norm_eps, param_dtype=c.param_dtype)
         self.input_norm = ParallelRMSNorm(c.hidden_size, strategy, **norm)
-        self.attn = MLAttention(c, strategy)
+        self.attn = self.ATTENTION(c, strategy)
         self.post_norm = ParallelRMSNorm(c.hidden_size, strategy, **norm)
         if moe:
             self.mlp = SharedRoutedExperts(
@@ -292,7 +327,8 @@ class KimiBlock(Module):
                 routed_scaling_factor=c.routed_scaling_factor,
                 param_dtype=c.param_dtype,
                 initializer_range=c.initializer_range,
-                bias_range=c.correction_bias_range)
+                bias_range=c.correction_bias_range,
+                n_group=c.n_group, topk_group=c.topk_group)
         else:
             self.mlp = DenseMLP(c)
 

@@ -214,7 +214,12 @@ def group_of(op_name: str, phases: Tuple[str, ...] = PHASES) -> str:
 #: nn/moe.SharedRoutedExperts told of them, models/longcat_flash); the mixes
 #: of a residual stream around a sublayer, SIBLINGS of `attn` and `mlp`
 #: under `layer` (`mhc_pre`, `mhc_sinkhorn`, `mhc_post`:
-#: nn/hyper_connections, models/xing4).  `diff_out`,
+#: nn/hyper_connections, models/xing4); the parts of a latent-attention
+#: layer that SELECTS what it attends, beside MLA's own (`attn` >
+#: `dsa_index_q`, `dsa_index_k`: the indexer's projections; `dsa_score`,
+#: `dsa_select`, `dsa_attend`: the scores of every position a query sees,
+#: the exact selection, and the attention over it: models/deepseek_v32,
+#: ops/sparse_attention).  `diff_out`,
 #: what follows the attention kernel in a differential-attention layer,
 #: is a scope of the operations' paths and NOT a group: its time stays
 #: with its kind of layer.  No program without these scopes changes its
@@ -226,7 +231,9 @@ SCOPE_MAP_GROUPS = ("kv_write", "loss", "mla_q", "mla_kv", "mla_out",
                     "kda_out",
                     "attn_cross", "ssm", "ssm_proj", "ssm_conv", "ssm_scan",
                     "ssm_step", "ssm_out", "gmu", "tail", "ssm_norm",
-                    "zero_experts", "mhc_pre", "mhc_sinkhorn", "mhc_post")
+                    "zero_experts", "mhc_pre", "mhc_sinkhorn", "mhc_post",
+                    "dsa_index_q", "dsa_index_k", "dsa_score", "dsa_select",
+                    "dsa_attend")
 UNSCOPED = "unscoped"
 #: what `scope_sources` says of an instruction: its own `op_name` named
 #: the group; its called computation's instructions did; its first

@@ -26,7 +26,13 @@ rule over a latent operand:
   live block and `pl.when` skips the dead steps, so a chunk over a 32k
   scratch pays for its prefix;
 * **the mask is by global position**; a block wholly in the cached
-  prefix takes none;
+  prefix takes none.  A layer that SELECTS what each query attends
+  (models/deepseek_v32) hands in its own mask instead, `keep` [C, M]
+  int8, nonzero where the query attends the position (a subset of what it
+  may see; every query keeps one position or more): a block of it rides
+  beside the latent block and every live block is masked by it.  Dense
+  work under a sparse mask: the right mathematics, not yet the right
+  cost (PERF.md s7);
 * **a head's `k_nope | v` of a key block are made HERE, from the block's
   latents by the head's `[r, dn + dv]` columns of `W_kvb`**, in the
   cache's dtype as the composition makes them: nothing expanded ever
@@ -176,8 +182,11 @@ def _tile_blocks(s, t, tr: int, kb: int):
     return jnp.minimum(s[0], (s[1] + (t + 1) * tr - 1) // kb + 1)
 
 
-def _kernel(s_ref, q_ref, w_ref, lat_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            scale, tr, kb, r, dn):
+def _kernel(s_ref, q_ref, w_ref, lat_ref, *refs, scale, tr, kb, r, dn,
+            keep=False):
+    # `keep`: one more operand [tr, kb] after the latents, the layer's mask
+    keep_ref, refs = (refs[0], refs[1:]) if keep else (None, refs)
+    o_ref, m_scr, l_scr, acc_scr = refs
     t, j = pl.program_id(1), pl.program_id(2)
     q0 = s_ref[1]
     live = _tile_blocks(s_ref, t, tr, kb)
@@ -196,28 +205,36 @@ def _kernel(s_ref, q_ref, w_ref, lat_ref, o_ref, m_scr, l_scr, acc_scr, *,
              + jax.lax.dot_general(q[:, dn:], lat[:, r:], dims,
                                    preferred_element_type=jnp.float32)
              ) * scale
-        if masked:
+        if keep_ref is not None:
+            s = jnp.where(keep_ref[...] != 0, s, NEG_INF)
+        elif masked:
             qpos = q0 + t * tr + jax.lax.broadcasted_iota(
                 jnp.int32, (tr, 1), 0)
             kpos = j * kb + jax.lax.broadcasted_iota(jnp.int32, (1, kb), 1)
             s = jnp.where(kpos <= qpos, s, NEG_INF)
         softmax_step(s, kv[:, dn:], *stats)
 
-    clear = j * kb + kb - 1 <= q0 + t * tr
-    pl.when((j < live) & clear)(lambda: update(False))
-    pl.when((j < live) & jnp.logical_not(clear))(lambda: update(True))
+    if keep_ref is not None:
+        pl.when(j < live)(lambda: update(True))
+    else:
+        clear = j * kb + kb - 1 <= q0 + t * tr
+        pl.when((j < live) & clear)(lambda: update(False))
+        pl.when((j < live) & jnp.logical_not(clear))(lambda: update(True))
     pl.when(j == pl.num_programs(2) - 1)(
         lambda: softmax_finish(o_ref, *stats))
 
 
 def latent_chunk_attention(q_nope, q_rope, lat, wkv_b, start, *,
-                           softmax_scale: float):
+                           softmax_scale: float, keep=None):
     """q_nope [1, C, nh, dn] and q_rope [1, C, nh, dr] (rotated) at
     positions start .. start + C - 1 (start a traced scalar, or [1]);
     lat [1, M, stored] the cached latents `[c_kv r | k_rope dr | 0 ...]`
     of positions 0 .. M - 1, every one of them up to start + C - 1
     written; wkv_b [r, nh, dn + dv].  Query i sees key position j iff
-    j <= start + i.  Returns [1, C, nh * dv].  Raises ValueError on
+    j <= start + i; given `keep` [C, M] (int8, or bool), iff keep[i, j] is
+    nonzero (the caller's mask lets nothing through that lies past
+    start + i, and something for every i).  Returns [1, C, nh * dv].
+    Raises ValueError on
     shapes outside `compatible` (`MLAttention._attend_composed` takes
     those)."""
     C, nh, M, tr, kb = check_shapes(q_nope.shape, q_rope.shape, lat.shape,
@@ -238,15 +255,18 @@ def latent_chunk_attention(q_nope, q_rope, lat, wkv_b, start, *,
     def key_block(h, t, j, s):
         return jnp.minimum(j, _tile_blocks(s, t, tr, kb) - 1), 0
 
+    masks = () if keep is None else (keep.astype(jnp.int8),)
     out = pl.pallas_call(
         functools.partial(_kernel, scale=softmax_scale, tr=tr, kb=kb, r=r,
-                          dn=dn),
+                          dn=dn, keep=bool(masks)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(nh, C // tr, M // kb),
             in_specs=[pl.BlockSpec((tr, dq), lambda h, t, j, s: (t, h)),
                       pl.BlockSpec((r, dn + dv), lambda h, t, j, s: (0, h)),
-                      pl.BlockSpec((kb, stored), key_block)],
+                      pl.BlockSpec((kb, stored), key_block)] + [
+                pl.BlockSpec((tr, kb), lambda h, t, j, s: (
+                    t, key_block(h, t, j, s)[0])) for _ in masks],
             out_specs=pl.BlockSpec((tr, dv), lambda h, t, j, s: (t, h)),
             scratch_shapes=[pltpu.VMEM((tr, 1), jnp.float32),
                             pltpu.VMEM((tr, 1), jnp.float32),
@@ -257,5 +277,5 @@ def latent_chunk_attention(q_nope, q_rope, lat, wkv_b, start, *,
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
     )(scalars, q, wkv_b.astype(lat.dtype).reshape(r, nh * (dn + dv)),
-      lat[0])
+      lat[0], *masks)
     return out[None]

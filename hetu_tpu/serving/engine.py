@@ -469,6 +469,10 @@ class ServingEngine:
         self._read_windows = tuple(
             self.cache.windows[r] for r in self.cache.reads
             if r is not None and r >= 0)
+        #: a layer each that SELECTS what it attends (the contract's
+        #: `selects`), the most positions a query of it attends
+        self._selects = tuple(k for k in self.cache.selects
+                              if k is not None)
         if (self.cache.kind != "kv" or self.windowed or self.stateful
                 or self.borrows):
             self._refuse_unbuilt(reshard, draft_model, drafter)
@@ -1598,6 +1602,17 @@ class ServingEngine:
             self._registry.inc("serve.decode_slot_steps", len(batch))
             self._registry.inc("serve.decode_context_tokens",
                                int(positions.sum()) + len(batch))
+            if self._selects:
+                # the layers that SELECT, summed over them: the cached
+                # tokens they select FROM, and what their attention reads
+                # of those: per slot min(context, the layer's `selects`)
+                context = positions[batch] + 1
+                self._registry.inc("serve.decode_selectable_tokens",
+                                   int(context.sum()) * len(self._selects))
+                self._registry.inc(
+                    "serve.decode_selected_tokens",
+                    sum(int(np.minimum(context, k).sum())
+                        for k in self._selects))
             sample_args = (self._sample_args(batch)
                            if self.config.sampling else ())
         if self.spec:
@@ -1767,7 +1782,14 @@ class ServingEngine:
         everything, sum_i min(start + i + 1, window) under a window (a
         chunk's padding rows count: the program computes them).  A kind
         whose layers attend for the read row alone (`read_rows_from`):
-        that row's start + row + 1 keys, none where `row` is negative."""
+        that row's start + row + 1 keys, none where `row` is negative.
+        `serve.prefill_selected_keys`: of those pairs, the ones the
+        layers that SELECT attend, summed over those layers: sum_i
+        min(start + i + 1, the layer's `selects`)."""
+        def capped(cap):
+            # the first `a` rows see start + i + 1 keys, the rest `cap`
+            a = min(max(cap - start - 1, 0), C)
+            return a * (start + 1) + a * (a - 1) // 2 + (C - a) * cap
         for k, (kind, w) in enumerate(zip(self._kind_names,
                                           self.cache.kinds)):
             if k in self._tail_kinds:
@@ -1775,11 +1797,12 @@ class ServingEngine:
             elif w is None:
                 pairs = C * start + C * (C + 1) // 2
             else:
-                # the first `a` rows see start + i + 1 keys, the rest w
-                a = min(max(w - start - 1, 0), C)
-                pairs = a * (start + 1) + a * (a - 1) // 2 + (C - a) * w
+                pairs = capped(w)
             self._registry.inc("serve.prefill_attended_keys", pairs,
                                kind=kind)
+        if self._selects:
+            self._registry.inc("serve.prefill_selected_keys",
+                               sum(capped(k) for k in self._selects))
 
     def _stats_args(self) -> tuple:
         """The extra argument of the programs of a model that counts

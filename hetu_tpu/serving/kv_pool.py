@@ -32,8 +32,11 @@ byte-identical semantics to `init_cache`.
 
 What a token stores comes from the model's cache contract
 (models/cache_contract.py, `PagePool.for_contract`): K and V arrays as
-above, or ONE array of the model's own token shape (a latent-attention
-model: `[L, num_pages, page_size, 640]`, exact pages only).
+above, or one array a shape of the model's own token shapes (a
+latent-attention model: `[L, num_pages, page_size, 640]`; one whose layers
+select what they attend keeps its indexer's key beside the latent,
+`[L, num_pages, page_size, 128]`, under the same page ids: a page of a
+layer is that page of BOTH arrays; exact pages only).
 
 How far back each layer READS comes from the contract too.  Layers
 that read everything are one kind of layer and layers that read a window
@@ -158,7 +161,10 @@ class PoolArrays:
     step (a pytree: quant scales are None in the exact mode).  A pool
     with several kinds of layer (exact K/V pages) holds the first kind's
     arrays as `k`, `v` and the further kinds' as `more`, (k, v) after
-    (k, v), each kind's of its own shape."""
+    (k, v), each kind's of its own shape.  A pool of `token_shapes` holds
+    its arrays, one a shape, in the same places: the first as `k`, a
+    second (a sparse-attention layer's index keys beside the latents) as
+    `v`, any further as `more`."""
     k: jnp.ndarray
     v: Optional[jnp.ndarray] = None     # None: a one-array (latent) pool
     k_scale: Optional[jnp.ndarray] = None
@@ -302,8 +308,8 @@ class PagePool:
                 # V differ in width: each kind's pages of its own shapes
                 what.update(kind_shapes=stored)
         else:
-            (stored,) = contract.stored_shapes
-            what = dict(token_shape=tuple(stored))
+            what = dict(token_shapes=tuple(
+                tuple(s) for s in contract.stored_shapes))
         if K > 1 or contract.kinds[0] is not None:
             # (a kind's layers numbered among the layers that hold pages:
             # not a state layer, not a layer that keeps no cache)
@@ -322,7 +328,7 @@ class PagePool:
                  head_dim: Optional[int] = None,
                  dtype=jnp.float32, quant: str = "none",
                  device_arrays: bool = True,
-                 token_shape: Optional[Tuple[int, ...]] = None,
+                 token_shapes: Optional[Tuple[Tuple[int, ...], ...]] = None,
                  windows: Tuple[Optional[int], ...] = (None,),
                  layers: Optional[Tuple[Tuple[int, ...], ...]] = None,
                  kind_shapes=None, state_kinds=(),
@@ -330,17 +336,21 @@ class PagePool:
         if quant not in ("none", "int8", "int4"):
             raise ValueError(f"kv quant mode {quant!r} invalid; "
                              "choices: ('none', 'int8', 'int4')")
-        if (token_shape is None) == (num_kv_heads is None
-                                     or head_dim is None):
+        if (token_shapes is None) == (num_kv_heads is None
+                                      or head_dim is None):
             raise ValueError("a pool holds K and V arrays of num_kv_heads "
-                             "x head_dim, or ONE array of token_shape")
-        #: a ONE-array pool's stored shape per token ((640,) for a latent);
-        #: None = the K and V arrays of `num_kv_heads` x `head_dim`
-        self.token_shape = token_shape
-        if token_shape is not None and quant != "none":
+                             "x head_dim, or one array a shape of "
+                             "token_shapes")
+        #: the stored shape a token of each array of a pool that is not
+        #: K and V (((640,),) for a latent; ((640,), (128,)) for a latent
+        #: and an indexer's key, both under the one page table); None =
+        #: the K and V arrays of `num_kv_heads` x `head_dim`
+        self.token_shapes = token_shapes and tuple(
+            tuple(x) for x in token_shapes)
+        if token_shapes is not None and quant != "none":
             raise ValueError(
                 f"kv_quant={quant!r}: int8/int4 pages are built for K/V "
-                f"pools only, not for one array of {token_shape} a token")
+                f"pools only, not for arrays of {token_shapes} a token")
         if quant == "int4" and head_dim % 2:
             raise ValueError(f"int4 pages need an even head_dim, "
                              f"got {head_dim}")
@@ -359,7 +369,7 @@ class PagePool:
         #: layer (serving/scheduler.py)
         self.windowed = K > 1 or self.windows[0] is not None \
             or kind_shapes is not None
-        if self.windowed and (token_shape is not None or quant != "none"):
+        if self.windowed and (token_shapes is not None or quant != "none"):
             raise ValueError("kinds of layer are built for exact K/V "
                              "pages")
         by_kind = (tuple(int(n) for n in num_pages)
@@ -388,15 +398,16 @@ class PagePool:
             raise ValueError("shapes by kind of layer are one (K, V) pair "
                              "a kind, over exact pages")
         shape = (num_layers, by_kind[0] + 1, page_size) + (
-            token_shape or (num_kv_heads, head_dim))
+            () if token_shapes else (num_kv_heads, head_dim))
         if not device_arrays:
             # host-only pool (serving/fleet.py's discrete-event sim): the
             # allocator / refcount / page-table machinery is the real
             # thing, but no device memory is ever touched — a 10^6-page
             # pool costs one numpy array, not gigabytes of jnp.zeros
             self.arrays = None
-        elif token_shape is not None:
-            self.arrays = PoolArrays(k=jnp.zeros(shape, dtype))
+        elif token_shapes is not None:
+            self.arrays = PoolArrays.from_tree(tuple(
+                jnp.zeros(shape + one, dtype) for one in self.token_shapes))
         elif quant == "int4":
             pshape = shape[:-1] + (head_dim // 2,)
             self.arrays = PoolArrays(
@@ -627,8 +638,8 @@ class PagePool:
         """Bulk-write a prefilled sequence's K/V into its pages.
         pages_row: [mp] int32 page ids (pad unused tail entries with the
         null page — their garbage lands in page 0); ks/vs:
-        [L, mp*page_size, n_kv, hd]; a one-array pool takes its one
-        dense cache [L, mp*page_size, *token_shape] as `ks`.
+        [L, mp*page_size, n_kv, hd]; a pool of `token_shapes` takes one
+        dense cache [L, mp*page_size, *shape] an array, in their order.
 
         With a window kind of layer (`windows`), the scratch comes by
         kind ((k, v) of [layers of the kind, positions, ...] a kind, kind
@@ -646,9 +657,13 @@ class PagePool:
         a = PoolArrays.from_tree(arrays_tree)
         L = self.num_layers
         mp = pages_row.shape[0]
-        if self.token_shape is not None:
-            x = ks.reshape((L, mp, self.page_size) + self.token_shape)
-            return (a.k.at[:, pages_row].set(x.astype(a.k.dtype)),)
+        if self.token_shapes is not None:
+            return tuple(
+                pool.at[:, pages_row].set(
+                    x.reshape((L, mp, self.page_size) + one)
+                    .astype(pool.dtype))
+                for pool, x, one in zip(arrays_tree, (ks, vs) + more,
+                                        self.token_shapes))
         paged_shape = (L, mp, self.page_size, self.num_kv_heads,
                        self.head_dim)
 

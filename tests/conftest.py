@@ -33,7 +33,8 @@ LONGEST_FIRST = (
     "test_chip_compile", "test_benchmark_registry", "test_pallas_kernels",
     "test_phi4_flash", "test_chunk_read_row", "test_bailing_hybrid",
     "test_serving_view", "test_serving_decode", "test_comm", "test_lint",
-    "test_mimo_v2", "test_kimi_k2", "test_xing4", "test_serving_families",
+    "test_mimo_v2", "test_kimi_k2", "test_xing4", "test_deepseek_v32",
+    "test_serving_families",
     "test_serving_pipeline", "test_jamba", "test_kda_scan_kernel",
     "test_trinity", "test_latent_chunk_attention", "test_step_spans",
     "test_pipeline_1f1b", "test_disagg", "test_serving_families_window",
@@ -45,7 +46,8 @@ LONGEST_FIRST = (
     "test_longcat", "test_benchmark_longcat", "test_benchmark_xing4",
     "test_pipeline",
     "test_llama", "test_chip_smoke", "test_flash_attention", "test_gpt",
-    "test_chip_compile_longcat", "test_chip_compile_xing4")
+    "test_chip_compile_longcat", "test_chip_compile_xing4",
+    "test_benchmark_deepseek_v32", "test_chip_compile_deepseek_v32")
 
 
 def pytest_configure(config):

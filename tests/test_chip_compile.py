@@ -613,6 +613,19 @@ def _xing4_cut():
         "num_slots", "page_size", "max_len", "prefill_chunk", "num_pages")}
 
 
+def _deepseek_v32_cut():
+    """DeepSeek-V3.2 as its cell serves it: the WHOLE cut (1 dense + 4
+    expert layers, 8 of 256 experts a layer, 16,160 vocabulary rows) at
+    published widths (128 heads, the 64 x 128 indexer, index_topk 2,048),
+    at the cell's slots, page, chunk and max_len."""
+    from benchmarks import traffic
+    from benchmarks.families import deepseek_v32
+    cfg = traffic.load_json("configs", "deepseek-v3.2-ep32-depth5")
+    sv = cfg["serving"]
+    return deepseek_v32.build_model(cfg, sv), {k: sv[k] for k in (
+        "num_slots", "page_size", "max_len", "prefill_chunk", "num_pages")}
+
+
 #: family -> ((model, ServeConfig overrides) or None for the Llama-2-7B
 #: block, the kernels its decode program must hold as tpu_custom_calls)
 SERVING_FAMILIES = {
@@ -626,11 +639,13 @@ SERVING_FAMILIES = {
     "jamba": (_jamba_whole, ("paged_attn",)),
     "longcat": (_longcat_cut, ("paged_latent",)),
     "xing4": (_xing4_cut, ("paged_latent",)),
+    "deepseek_v32": (_deepseek_v32_cut, ()),
 }
 #: the families whose case runs from a file of its own
-#: (tests/test_chip_compile_longcat.py, .._xing4.py): a file is what one
-#: worker of the tier-1 run takes whole, and this one is among the longest
-ELSEWHERE = ("longcat", "xing4")
+#: (tests/test_chip_compile_longcat.py, .._xing4.py, .._deepseek_v32.py): a
+#: file is what one worker of the tier-1 run takes whole, and this one is
+#: among the longest
+ELSEWHERE = ("longcat", "xing4", "deepseek_v32")
 
 
 @pytest.fixture(scope="module")
@@ -712,8 +727,54 @@ def test_serving_programs_compile_for_one_v5e(family, decode_text):
         assert all("attn/pallas_latent_chunk_attention" in ln
                    for ln in latent_calls)
         assert not any("pallas_latent_chunk_attention" in ln for ln in calls)
+    elif family == "deepseek_v32":
+        # the blockwise latent kernel once a layer, under the scope of the
+        # attention over the selection and GIVEN the selection's mask (an
+        # int8 operand [rows, positions] beside the latents); the paged
+        # latent kernel is not on this family's path: the decode step
+        # gathers the selected entries
+        rec = routes["latent_chunk_attn"]
+        assert rec["pallas"] == len(latent_calls) == 5 and not rec["xla"]
+        assert all("attn/dsa_attend/pallas_latent_chunk_attention" in ln
+                   and f"s8[{engine.config.prefill_chunk},33792]" in ln
+                   for ln in latent_calls)
+        assert "paged_latent" not in routes
+        assert not any("paged_latent" in ln for ln in calls)
     else:
         assert "latent_chunk_attn" not in routes and not latent_calls
+    if family == "deepseek_v32":
+        # TWO page arrays under one table (the latents and the indexer's
+        # keys), carried in place; a decode pass gathers the index keys of
+        # every slot's table (132 pages) and then AT MOST 2,048 latents a
+        # slot a layer, whatever the context;
+        # the five scopes stand in both programs; weights + pool + the
+        # largest program's temporaries fit the chip
+        S = engine.config.num_slots
+        assert [a.shape for a in engine.pool.arrays.tree()] == [
+            (5, S * 133 + 1, 256, 640), (5, S * 133 + 1, 256, 128)]
+        pool = sum(a.size * a.dtype.itemsize
+                   for a in engine.pool.arrays.tree())
+        assert S == 16 and pool == 5 * 2129 * 256 * (640 + 128) * 2
+        mem = {name: c.memory_analysis() for name, c in compiled.items()}
+        assert mem["decode"].alias_size_in_bytes >= pool
+        assert mem["decode"].temp_size_in_bytes < 0.3e9
+        assert mem["prefill_chunk"].temp_size_in_bytes < 1.0e9
+        assert 6.4e9 < 2 * engine.model.num_params() < 6.5e9
+        assert all(m.argument_size_in_bytes + m.temp_size_in_bytes
+                   < 15.2e9 for m in mem.values())
+        text = decode_text(family, programs["decode"])
+        latents = [ln for ln in text.splitlines()
+                   if " gather(" in ln and ",640]" in ln.split(" gather(")[0]]
+        assert len(latents) == 5 and all(
+            f"bf16[{S},2048,640]" in ln for ln in latents)
+        keys = {ln.split(" = ")[1].split("{")[0] for ln in text.splitlines()
+                if " gather(" in ln and ",256,128]" in ln.split(" gather(")[0]}
+        assert keys == {f"bf16[{S},132,256,128]"}
+        for name in ("decode", "prefill_chunk"):
+            body = compiled[name].as_text()
+            assert all(f"/{scope}/" in body for scope in (
+                "dsa_index_q", "dsa_index_k", "dsa_score", "dsa_select",
+                "dsa_attend")), name
     if family == "ling":
         # state beside pages: both programs take the state arrays as
         # donated arguments and hand them back in place, with the pool
@@ -801,7 +862,7 @@ def test_serving_programs_compile_for_one_v5e(family, decode_text):
         assert "ssm_norm" in compiled["prefill_chunk"].as_text()
         assert "ssm_norm" in compiled["decode"].as_text()
         _scan_is_the_kernel(routes, chunk_text, calls)
-    elif family == "kimi":
+    elif family in ("kimi", "deepseek_v32"):
         assert "chunk_attn" not in routes and not chunk_calls
     elif family == "longcat":
         # TWO latent cache layers a published layer: the paged latent

@@ -92,9 +92,10 @@ def _programs(model, params, prompt, n_decode, *, page=8, chunk=16,
     then `n_decode` paged decode steps feeding the reference's own next
     tokens: the logits of every position the programs produce."""
     contract = model.cache_contract()
-    (stored,) = contract.stored_shapes
     L, mp = contract.num_layers, max_len // page
-    cache = (jnp.zeros((L, 1, max_len) + stored, F32),)
+    # (one array of the scratch and of the pool a shape a token stores)
+    cache = tuple(jnp.zeros((L, 1, max_len) + stored, F32)
+                  for stored in contract.stored_shapes)
     stats = model.zero_stats()
     plen = len(prompt)
     padded = -(-plen // chunk) * chunk
@@ -114,7 +115,7 @@ def _programs(model, params, prompt, n_decode, *, page=8, chunk=16,
         num_pages=slots * mp, page_size=page)
     pages = np.arange(mp, dtype=np.int32) * slots + slot + 1
     tree = pool.write_pages(pool.arrays.tree(), jnp.asarray(pages),
-                            cache[0][:, 0])
+                            *(c[:, 0] for c in cache))
     table = np.zeros((slots, mp), np.int32)
     table[slot] = pages
     return prefill_logits, tree, table, stats

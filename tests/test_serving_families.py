@@ -223,6 +223,10 @@ def _family_case(family, hd128):
         from test_xing4 import build, ref_logits
         cfg, model, params = build()
         return model, params, lambda p, ids: ref_logits(p, cfg, ids)
+    if family == "deepseek_v32":
+        from test_deepseek_v32 import build, ref_logits
+        cfg, model, params = build()
+        return model, params, lambda p, ids: ref_logits(p, cfg, ids)
     if family == "gpt":
         from hetu_tpu.models.gpt import GPTConfig, GPTLMHeadModel
         kw = dict(hidden_size=256, num_attention_heads=2) if hd128 else {}
@@ -240,7 +244,7 @@ def _family_case(family, hd128):
 
 
 #: the families whose cache is a latent a token (models/kimi_k2.MLAttention)
-LATENT = ("kimi", "ling", "longcat", "xing4")
+LATENT = ("kimi", "ling", "longcat", "xing4", "deepseek_v32")
 
 
 #: the cases of `test_a_family_is_served_by_its_hooks` by the file that
@@ -297,6 +301,12 @@ def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
     `xing4` is the family whose carry between layers is a STREAM of four
     hidden vectors a token, read and written through the block's
     `residual_pre` / `residual_post` hooks around Kimi's sublayers.
+    `deepseek_v32` is the family whose latent layers SELECT what they
+    attend: a token stores two arrays (the latent and an indexer's key)
+    under one page table, every decode step and most chunk rows attend
+    the 24 best-scored of the positions they see, and the decode step
+    gathers them (neither paged latent attention: its route is not
+    asked).
     `llama-unstacked` is the Llama block built with
     use_scan=False: a layer's own arrays, called, never scanned."""
     monkeypatch.setenv("HETU_TPU_PALLAS",
@@ -326,7 +336,12 @@ def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
             assert took["xla"] and not took["pallas"]
             assert list(took["why"]) == [
                 "switched off by HETU_TPU_PALLAS / HETU_TPU_PALLAS_KERNELS"]
-    if family in LATENT:
+    if family == "deepseek_v32":
+        assert "paged_latent" not in eng.kernel_routes
+        assert not eng.windowed and len(eng.pool.arrays.tree()) == 2
+        assert 0 < reg.counter_value("serve.decode_selected_tokens") \
+            < reg.counter_value("serve.decode_selectable_tokens")
+    elif family in LATENT:
         assert eng.kernel_routes["paged_latent"][
             "pallas" if route == "kernel" else "xla"]
     if family == "ling":

@@ -115,5 +115,14 @@ def mesh_axis_size(mesh: Optional[Mesh], axis: str) -> int:
     return int(mesh.shape.get(axis, 1))
 
 
+def mesh_axis_group(mesh: Mesh, axis: str) -> Tuple[int, ...]:
+    """The ranks of `axis`'s group that holds rank 0, as a compiled
+    program's `replica_groups` number them: positions in the mesh's
+    device order (dp2 x tp2 with tp fastest: dp's group is (0, 2))."""
+    ranks = np.arange(mesh.devices.size).reshape(mesh.devices.shape)
+    along = np.moveaxis(ranks, mesh.axis_names.index(axis), 0)
+    return tuple(int(r) for r in along.reshape(along.shape[0], -1)[:, 0])
+
+
 def single_device_mesh() -> Mesh:
     return create_mesh(MeshConfig())

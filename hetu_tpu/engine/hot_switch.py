@@ -64,19 +64,8 @@ class HotSwitchTrainer(Trainer):
             model = (self.model if sid == self.active_id and self.params is not None
                      else self.model_factory(st))
             mesh = st.build_mesh()
-            pshard = model.shardings(mesh)
-            abstract = model.abstract_params()
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            from hetu_tpu.optim.optimizer import zero_shardings
-            if st.zero:
-                sshard = {
-                    "step": NamedSharding(mesh, P()),
-                    "m": zero_shardings(pshard, abstract, mesh, "dp"),
-                    "v": zero_shardings(pshard, abstract, mesh, "dp"),
-                }
-            else:
-                sshard = {"step": NamedSharding(mesh, P()),
-                          "m": pshard, "v": pshard}
+            from hetu_tpu.optim.optimizer import state_shardings
+            pshard, sshard = state_shardings(model, mesh, st.zero)
             h = StrategyHandle(st, model, mesh, pshard, sshard)
             self._handles[sid] = h
         return h

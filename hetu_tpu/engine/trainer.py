@@ -28,7 +28,7 @@ import hetu_tpu as ht
 from hetu_tpu import optim
 from hetu_tpu.core.mesh import use_mesh
 from hetu_tpu.engine.trainer_config import TrainingConfig
-from hetu_tpu.optim.optimizer import zero_shardings
+from hetu_tpu.optim.optimizer import state_shardings
 from hetu_tpu.parallel.strategy import ParallelStrategy
 from hetu_tpu.utils.checkpoint import CheckpointManager
 from hetu_tpu.utils.logging import get_logger
@@ -269,19 +269,7 @@ class Trainer:
     def _make_shardings(self):
         """(param_shardings, opt_state_shardings) — overridable (e.g. the
         LoRA SFT trainer replicates its tiny adapter tree)."""
-        mesh, st = self.mesh, self.strategy
-        pshard = self.model.shardings(mesh)
-        abstract = self.model.abstract_params()
-        if st.zero:
-            sshard = {
-                "step": NamedSharding(mesh, P()),
-                "m": zero_shardings(pshard, abstract, mesh, "dp"),
-                "v": zero_shardings(pshard, abstract, mesh, "dp"),
-            }
-        else:
-            sshard = {"step": NamedSharding(mesh, P()),
-                      "m": pshard, "v": pshard}
-        return pshard, sshard
+        return state_shardings(self.model, self.mesh, self.strategy.zero)
 
     def lower_abstract(self):
         """The train step lowered for ABSTRACT arguments laid out as
@@ -424,6 +412,7 @@ class Trainer:
         self._registry.observe("trainer.compile_s", compile_s,
                                pool=pool_name)
         from hetu_tpu.utils import flags as _flags
+        comm_analyze = _flags.bool_flag("HETU_TPU_COMM_ANALYZE")
         est, comm = {}, {}
         # ONE lazy as_text() shared by the comm analysis and the
         # profiler — stringifying a large module twice per compile is
@@ -454,11 +443,12 @@ class Trainer:
                 # fresh compile; that is once per plan, not per step,
                 # but very large programs can opt out via
                 # HETU_TPU_COMM_ANALYZE=0
-                if _flags.bool_flag("HETU_TPU_COMM_ANALYZE"):
+                if comm_analyze:
                     from hetu_tpu.obs.comm import collective_report
                     comm = collective_report(_hlo_text())
             except Exception:
                 comm = {}
+        sync = self._grad_sync_form(_hlo_text) if comm_analyze else {}
         if self.run_log is not None:
             self.run_log.log(
                 "compile", name=pool_name, plan=str(key)[:500],
@@ -471,6 +461,7 @@ class Trainer:
                 collectives={op: rec["count"] for op, rec in
                              (comm.get("collectives") or {}).items()}
                 or None,
+                grad_sync=sync or None,
                 grad_compress=(self._grad_compress
                                if self._grad_compress != "none"
                                else None),
@@ -497,6 +488,34 @@ class Trainer:
             self.run_log.log("lint", name=pool_name,
                              plan=str(key)[:500], **lint_rec)
         self._check_budgets(pool_name, prof, est, comm)
+
+    def _grad_sync_form(self, hlo_text_fn) -> Dict[str, float]:
+        """The form the dp gradient sync took in the program just
+        compiled (`obs.comm.grad_sync_report`), as gauges
+        `trainer.grad_sync_collectives{form=all_reduce|reduce_scatter}`
+        (collectives a step) and `trainer.grad_sync_bytes_step` (their
+        bytes on the wire a step a chip) — whether ZeRO's split let the
+        backward reduce-scatter into the state's shards
+        (`optim.zero_shardings`) is a fact of the compiled text, not of
+        the strategy.  Without a dp axis there is no sync: nothing is
+        read and nothing set."""
+        if self.strategy.dp <= 1:
+            return {}
+        try:
+            from hetu_tpu.core.mesh import mesh_axis_group
+            from hetu_tpu.obs.comm import grad_sync_report
+            sync = grad_sync_report(
+                hlo_text_fn(), mesh_axis_group(self.mesh, "dp"),
+                default_world=self.mesh.devices.size)
+        except Exception as e:
+            logger.warning(f"per-compile grad-sync count failed: {e!r}")
+            return {}
+        for form in ("all_reduce", "reduce_scatter"):
+            self._registry.set_gauge("trainer.grad_sync_collectives",
+                                     sync[form], form=form)
+        self._registry.set_gauge("trainer.grad_sync_bytes_step",
+                                 sync["wire_bytes"])
+        return sync
 
     def _maybe_profile(self, plan, hlo_text_fn=None):
         """The flag-gated per-compile analytic profile

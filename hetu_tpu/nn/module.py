@@ -37,6 +37,9 @@ class ParamSpec:
     dtype: Any
     init: Callable[[jax.Array, Tuple[int, ...], Any], jax.Array]
     ds: Optional[DistributedStates] = None
+    #: leading dims that index the copies of a stack (`stacked_spec`): a
+    #: scan over them sees, and its backward makes, one copy at a time
+    stack_dims: int = 0
 
     def abstract(self) -> jax.ShapeDtypeStruct:
         return jax.ShapeDtypeStruct(self.shape, self.dtype)
@@ -184,7 +187,8 @@ def stacked_spec(spec: ParamSpec, num: int,
         ds = DistributedStates.make(len(spec.shape) + 1, {0: lead_axis})
     else:
         ds = None
-    return ParamSpec((num,) + spec.shape, spec.dtype, init, ds)
+    return ParamSpec((num,) + spec.shape, spec.dtype, init, ds,
+                     stack_dims=spec.stack_dims + 1)
 
 
 def stack_param_specs(specs, num: int, lead_axis: Optional[str] = None):

@@ -55,10 +55,11 @@ from __future__ import annotations
 from typing import Any, Dict, List, Optional
 
 from hetu_tpu.comm.wire import analytic_dp_sync  # noqa: F401  (re-export)
-from hetu_tpu.obs.hlo_text import (COLLECTIVE_OPS,  # noqa: F401 (re-export)
-                                   as_hlo_text, first_group,
-                                   maybe_collective, payload_bytes,
-                                   ring_wire_bytes, split_computations,
+from hetu_tpu.obs.hlo_text import (CALLEE_PAT, COLLECTIVE_OPS,  # noqa: F401
+                                   OP_NAME_PAT, OUT_PAT, as_hlo_text,
+                                   first_group, maybe_collective,
+                                   payload_bytes, ring_wire_bytes,
+                                   shape_bytes, split_computations,
                                    while_multipliers)
 
 
@@ -97,6 +98,61 @@ def collective_table(compiled_or_text, default_world: int = 1
                 "line": line.strip()[:200],
             })
     return rows
+
+
+def grad_sync_report(compiled_or_text, dp_group, default_world: int = 1
+                     ) -> Dict[str, float]:
+    """The FORM of a compiled train step's data-parallel gradient sync:
+    {all_reduce, reduce_scatter: collectives a step, wire_bytes: their
+    ring bytes a step a participant}, while trips multiplied through.
+
+    A gradient sync is a reduction (`all-reduce` / `reduce-scatter`) of
+    the BACKWARD pass (`transpose(` in its op_name, as
+    `hlo_profile.pass_of` reads it) over a replica group that holds
+    `dp_group` — the ranks, in the mesh's device order, of the dp group
+    of rank 0; a reduction over dp x tp jointly (a norm gain's gradient
+    under sequence parallelism) is one too.  The TPU compiler writes a
+    reduce-scatter as an `all-reduce` inside a fusion whose output is
+    the shard (`all-reduce-scatter`; a device trace shows the FUSION's
+    name, not a collective's): such a one counts as `reduce_scatter`, at
+    half an all-reduce's bytes, and takes its op_name and its trips from
+    the fusion's line.  What says whether ZeRO's split let the sync land
+    in its shards (`optim.zero_shardings`): under ZeRO, `all_reduce`
+    bytes beyond the norm gains' are bytes sent for nothing."""
+    comps = split_computations(as_hlo_text(compiled_or_text))
+    mults = while_multipliers(comps)
+    callers = {}            # a fused computation -> (where, its fusion)
+    for cname, lines in comps.items():
+        for line in lines:
+            if " fusion(" in line:
+                callee = CALLEE_PAT.search(line)
+                if callee is not None:
+                    callers[callee.group(1)] = (cname, line)
+    out = {"all_reduce": 0, "reduce_scatter": 0, "wire_bytes": 0.0}
+    for cname, lines in comps.items():
+        for line in lines:
+            found = maybe_collective(line)
+            if found is None or found[0] not in ("all-reduce",
+                                                 "reduce-scatter"):
+                continue
+            base, is_start, m = found
+            n, ranks = first_group(line, default_world)
+            if ranks is not None and not set(dp_group) <= set(ranks):
+                continue
+            where, at = callers.get(cname, (cname, line))
+            op_name = OP_NAME_PAT.search(at)
+            if op_name is None or "transpose(" not in op_name.group(1):
+                continue
+            payload = payload_bytes(m.group("out"), is_start)
+            if (at is not line and base == "all-reduce"
+                    and shape_bytes(OUT_PAT.search(at).group(1)) * n
+                    == payload):
+                base, payload = "reduce-scatter", payload // n
+            trips = mults[where][0]
+            out[base.replace("-", "_")] += trips
+            out["wire_bytes"] += trips * ring_wire_bytes(base, payload, n,
+                                                         is_start)
+    return out
 
 
 def _row_rate_class(row, topo) -> str:

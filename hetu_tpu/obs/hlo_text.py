@@ -82,9 +82,12 @@ COMP_HEAD_PAT = re.compile(
 WHILE_PAT = re.compile(r'=\s*[^=]*\bwhile\(')
 COND_REF_PAT = re.compile(r'condition=%?([\w.\-]+)')
 BODY_REF_PAT = re.compile(r'body=%?([\w.\-]+)')
-CONST_PAT = re.compile(r'%?([\w.\-]+)\s*=\s*[su]\d+\[\]\s+constant\((\d+)\)')
+# the TPU compiler's text gives a scalar a layout (`s32[]{:T(128)}`) and
+# leaves the operands' types out (`compare(%i, %n)`); XLA:CPU's does neither
+CONST_PAT = re.compile(
+    r'%?([\w.\-]+)\s*=\s*[su]\d+\[\](?:\{[^}]*\})?\s+constant\((\d+)\)')
 COMPARE_PAT = re.compile(
-    r'compare\(\s*\S+\s+%?([\w.\-]+),\s*\S+\s+%?([\w.\-]+)\s*\)')
+    r'compare\(\s*(?:\S+\s+)?%?([\w.\-]+),\s*(?:\S+\s+)?%?([\w.\-]+)\s*\)')
 DIRECTION_PAT = re.compile(r'direction=(\w+)')
 CALLEE_PAT = re.compile(r'(?:calls|body|condition|to_apply)=%?([\w.\-]+)')
 BRANCH_PAT = re.compile(r'branch_computations=\{([^}]*)\}')

@@ -1,6 +1,6 @@
 from hetu_tpu.optim.optimizer import (
     Optimizer, AdamW, Adam, SGD, clip_by_global_norm, zero_shardings,
-    cosine_schedule, constant_schedule,
+    state_shardings, cosine_schedule, constant_schedule,
 )
 from hetu_tpu.optim.grad_scaler import GradScaler
 from hetu_tpu.optim.zero_refresh import (

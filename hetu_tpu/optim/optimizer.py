@@ -11,7 +11,9 @@ ZeRO-1 (optimizer-state sharding over dp, reference: distributed_states.h:15
 `zero_shardings()` returns NamedShardings that additionally shard every state
 leaf (and master param copy) over the dp axis; GSPMD then turns the grad
 all-reduce into reduce-scatter + the param refresh into all-gather — the same
-comm pattern the reference builds explicitly with Split* collectives.
+comm pattern the reference builds explicitly with Split* collectives.  The
+scatter lands only where the split is INSIDE a scanned layer (see there);
+`Trainer`'s `trainer.grad_sync_*` gauges say which form a compile took.
 """
 from __future__ import annotations
 
@@ -181,15 +183,27 @@ def Adam(lr=1e-3, b1=0.9, b2=0.999, eps=1e-8):
 # ZeRO-1 sharding helpers
 # ---------------------------------------------------------------------------
 
-def zero_shardings(param_shardings, abstract_params, mesh, axis: str = "dp"):
+def zero_shardings(param_shardings, param_specs, mesh, axis: str = "dp"):
     """Derive optimizer-state shardings: each state leaf inherits its param's
-    sharding plus an extra split of the first free, divisible dim over `axis`
+    sharding plus an extra split of one free, divisible dim over `axis`
     (ZeRO-1; the comm consequences — reduce-scatter of grads, all-gather of
     fresh params — are inserted by GSPMD).  Scalars and indivisible params
     stay replicated.
 
-    `abstract_params` supplies shapes (params or ShapeDtypeStructs) since a
-    NamedSharding's spec alone does not know the tensor rank.
+    WHICH dim: the first free, divisible one INSIDE a layer.  A leaf stacked
+    for a scan over layers (`ParamSpec.stack_dims` leading dims, set by
+    `nn.module.stacked_spec`) has its gradient made one layer at a time by
+    the backward scan, and no reduce-scatter of ONE layer's gradient lands
+    in a split BETWEEN layers: split there, GSPMD all-reduces the whole
+    gradient inside the loop and slices after it (twice the bytes; PR 59,
+    63 MB a layer on the four-chip cell).  Split inside the layer, the
+    state's sharding propagates back through the update into the loop and
+    the sync is a reduce-scatter into the shard this rank updates.  A
+    stacked leaf with no such inner dim falls back to its stack dims.
+
+    `param_specs` supplies shapes — the model's `param_specs()`, or params /
+    ShapeDtypeStructs (a NamedSharding's spec alone does not know the tensor
+    rank), which know of no stack and are split on their first free dim.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -204,13 +218,26 @@ def zero_shardings(param_shardings, abstract_params, mesh, axis: str = "dp"):
                 for a in ((s,) if isinstance(s, str) else s)]
         if axis in flat:
             return ns  # already sharded over this axis (e.g. FSDP weights)
-        for i in range(len(shape)):
+        stack = getattr(ref, "stack_dims", 0)
+        for i in (*range(stack, len(shape)), *range(stack)):
             if spec[i] is None and shape[i] % size == 0 and shape[i] >= size:
                 spec[i] = axis
                 return NamedSharding(mesh, P(*spec))
         return ns
 
-    return jax.tree.map(shard_one, param_shardings, abstract_params)
+    return jax.tree.map(shard_one, param_shardings, param_specs)
+
+
+def state_shardings(model, mesh, zero: bool, axis: str = "dp"):
+    """({param shardings}, {step, m, v} shardings) of AdamW's state for
+    `model` on `mesh`: the moments laid out as the params, split once more
+    over `axis` under ZeRO (`zero_shardings`)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    pshard = model.shardings(mesh)
+    moments = (zero_shardings(pshard, model.param_specs(), mesh, axis)
+               if zero else pshard)
+    return pshard, {"step": NamedSharding(mesh, P()),
+                    "m": moments, "v": moments}
 
 
 # ---------------------------------------------------------------------------

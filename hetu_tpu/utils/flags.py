@@ -116,7 +116,9 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
          "tools_bench_diff.py diffs BENCH rounds with its thresholds"),
     Flag("HETU_TPU_COMM_ANALYZE", "bool", True,
          "per-compile bytes-on-wire analysis (obs.comm) in RunLog compile "
-         "events; costs one as_text() of the optimized HLO per fresh "
+         "events, and with a dp axis the gradient sync's form in the "
+         "trainer.grad_sync_* gauges; costs one as_text() of the optimized "
+         "HLO per fresh "
          "compile — set 0 on very large programs where stringifying the "
          "module is noticeable next to the compile itself", identity="0"),
     Flag("HETU_TPU_LINT", "bool", False,

@@ -383,10 +383,9 @@ def test_adamw_update_reads_a_cell_leaf_where_it_lies(leaf):
 # whole programs, as chip_smoke.py runs them
 # ---------------------------------------------------------------------------
 
-def _train_step(strategy, batch=2):
-    """(the Trainer, its step compiled for described devices): the 2-layer
-    Llama-2-7B-width model at batch 2 x seq 4096 of `chip_smoke.py`,
-    lowered by `Trainer.lower_abstract` — no parameter is materialised."""
+def _trainer(strategy, batch=2):
+    """The Trainer of `chip_smoke.py`'s train phases over described
+    devices: the 2-layer Llama-2-7B-width model at batch 2 x seq 4096."""
     from hetu_tpu.core.mesh import create_mesh
     from hetu_tpu.engine.trainer import Trainer
     from hetu_tpu.engine.trainer_config import TrainingConfig
@@ -397,8 +396,14 @@ def _train_step(strategy, batch=2):
     tc = TrainingConfig(global_batch_size=batch,
                         micro_batch_size=batch // max(strategy.dp, 1),
                         seq_len=SEQ)
-    trainer = Trainer(LlamaLMHeadModel(cfg, strategy), tc, strategy,
-                      mesh=create_mesh(strategy.mesh, devices=TOPO.devices))
+    return Trainer(LlamaLMHeadModel(cfg, strategy), tc, strategy,
+                   mesh=create_mesh(strategy.mesh, devices=TOPO.devices))
+
+
+def _train_step(strategy, batch=2):
+    """(the Trainer, its step compiled for described devices), lowered by
+    `Trainer.lower_abstract` — no parameter is materialised."""
+    trainer = _trainer(strategy, batch)
     return trainer, trainer.lower_abstract().compile()
 
 
@@ -436,23 +441,114 @@ def test_train_step_compiles_for_one_v5e_with_every_kernel():
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
-def test_sharded_train_step_compiles_for_four_v5e_with_every_kernel():
-    """dp2 x tp2 + sequence parallel + ZeRO over four described chips —
-    `chip_smoke.py --chips 4`.  GSPMD cannot partition a Mosaic call (the
-    lowering raises), so each kernel runs once per shard inside a
-    shard_map over the layouts the model declares (ops/pallas.per_shard):
-    the same four kernels as on one chip, and the collectives of the
-    partitioned program around them."""
+@pytest.fixture(scope="module")
+def sharded_step():
+    """(trainer, compiled): dp2 x tp2 + sequence parallel + ZeRO over the
+    four described chips — `chip_smoke.py --chips 4`."""
     from hetu_tpu.core.mesh import MeshConfig
     from hetu_tpu.parallel import ParallelStrategy
-    strategy = ParallelStrategy(mesh=MeshConfig(dp=2, tp=2),
-                                sequence_parallel=True, zero=True)
-    trainer, compiled = _train_step(strategy)
+    return _train_step(ParallelStrategy(mesh=MeshConfig(dp=2, tp=2),
+                                        sequence_parallel=True, zero=True))
+
+
+def test_sharded_train_step_compiles_for_four_v5e_with_every_kernel(
+        sharded_step):
+    """GSPMD cannot partition a Mosaic call (the lowering raises), so each
+    kernel runs once per shard inside a shard_map over the layouts the
+    model declares (ops/pallas.per_shard): the same four kernels as on one
+    chip, and the collectives of the partitioned program around them."""
+    trainer, compiled = sharded_step
     _assert_every_train_kernel(trainer, compiled)
     text = compiled.as_text()
     assert "all-gather" in text and "all-reduce" in text
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_sharded_zero_step_reduce_scatters_a_layers_gradients_in_the_scan(
+        sharded_step):
+    """Under ZeRO a layer's weight gradients are reduce-scattered over dp
+    into the state's shards where the backward scan makes them (PR 59).
+    With the state split BETWEEN layers (the rule before) the body held
+    one tuple all-reduce over the dp pairs of a layer's four matrices,
+    twice the bytes, sliced after the loop; split INSIDE the layer
+    (`optim.zero_shardings`), the state's sharding propagates back into
+    the loop: no all-reduce over the dp pairs in the backward body, and
+    four `all-reduce-scatter` fusions there whose output is half their
+    operand — wqkv, o_proj, gate|up, down_proj."""
+    from hetu_tpu.core.mesh import mesh_axis_group
+    from hetu_tpu.obs import hlo_text as H
+    from hetu_tpu.obs.comm import grad_sync_report
+    trainer, compiled = sharded_step
+    text = compiled.as_text()
+    dp_pair = mesh_axis_group(trainer.mesh, "dp")
+    assert dp_pair == (0, 2)
+    comps = H.split_computations(text)
+    trips = H.while_multipliers(comps)
+    layers = trainer.model.config.num_hidden_layers
+    backward = [name for name, lines in comps.items()
+                if trips[name] == (layers, False)
+                and any("transpose(jvp" in ln for ln in lines)]
+    assert len(backward) == 1, backward
+    whole, scattered = [], []
+    for line in comps[backward[0]]:
+        if " fusion(" in line and "all-reduce-scatter" in line:
+            inner = next(ln for ln in comps[H.CALLEE_PAT.search(line).group(1)]
+                         if H.maybe_collective(ln))
+            if H.first_group(inner, 4)[1] == dp_pair:
+                scattered.append(
+                    (H.shape_bytes(H.OUT_PAT.search(line).group(1)),
+                     H.shape_bytes(H.OUT_PAT.search(inner).group(1))))
+        found = H.maybe_collective(line)
+        if found and found[0] == "all-reduce" \
+                and H.first_group(line, 4)[1] == dp_pair:
+            whole.append(line.strip()[:160])
+    assert not whole, whole
+    assert len(scattered) == 4, scattered
+    assert all(2 * out == operand for out, operand in scattered), scattered
+    # what the four carry: a tp shard (half) of a layer's matrices, bf16
+    c = trainer.model.config
+    shard_bytes = 2 * (c.hidden_size * (c.num_attention_heads
+                                        + 2 * c.num_key_value_heads) * HEAD_DIM
+                       + c.hidden_size * c.hidden_size
+                       + 3 * c.hidden_size * c.intermediate_size) // 2
+    assert sum(operand for _, operand in scattered) == shard_bytes
+    # and the trainer's count of the same text: a scatter sends half its
+    # operand; beside the layers' and the head's, only the norm gains'
+    # vectors are left, all-reduced over dp x tp
+    sync = grad_sync_report(text, dp_pair, default_world=4)
+    assert sync["reduce_scatter"] == 4 * layers + 1, sync
+    head_bytes = 2 * c.hidden_size * VOCAB // 2
+    assert 1.0 <= sync["wire_bytes"] / (
+        (layers * shard_bytes + head_bytes) / 2) < 1.001, sync
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("zero", [False, True])
+def test_one_chip_train_step_is_the_same_program_under_the_zero_rule(
+        zero, monkeypatch):
+    """Without a dp axis `zero_shardings` returns the parameters'
+    shardings before it reads any spec: the one-chip step lowers to the
+    same text under the rule of PR 59 and under the one before it
+    (shapes that know of no stack), ZeRO asked for or not — so it
+    compiles to the same program (`mistral7b-train-1chip`)."""
+    import hetu_tpu.optim.optimizer as O
+    from hetu_tpu.parallel import ParallelStrategy
+
+    def lowered_text():
+        return _trainer(ParallelStrategy(zero=zero)).lower_abstract(
+            ).as_text()
+
+    with_rule = lowered_text()
+    rule = O.zero_shardings
+    monkeypatch.setattr(
+        O, "zero_shardings",
+        lambda pshard, specs, mesh, axis="dp": rule(
+            pshard, jax.tree.map(
+                lambda s: s.abstract(), specs,
+                is_leaf=lambda s: hasattr(s, "abstract")), mesh, axis))
+    assert lowered_text() == with_rule
 
 
 def _serving_engine(cfg=None, num_slots=8, model=None, **serve):

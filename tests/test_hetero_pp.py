@@ -100,7 +100,7 @@ def test_full_train_step_driver_envelope():
     all-reduce with a partial-manual sdy constraint in its reducer) that
     r3 shipped because the unit tests only covered 4-dev fwd/grad."""
     from hetu_tpu import optim
-    from hetu_tpu.optim.optimizer import zero_shardings
+    from hetu_tpu.optim.optimizer import state_shardings
 
     st = ParallelStrategy(mesh=MeshConfig(dp=2, pp=2, tp=2), zero=True,
                           pp_tp_eff=(2, 1))
@@ -110,13 +110,7 @@ def test_full_train_step_driver_envelope():
     opt = optim.AdamW(lr=1e-3)
     with ht.use_mesh(mesh):
         params = model.init(jax.random.key(0), mesh=mesh)
-        pshard = model.shardings(mesh)
-        sshard = {
-            "step": jax.sharding.NamedSharding(
-                mesh, jax.sharding.PartitionSpec()),
-            "m": zero_shardings(pshard, model.abstract_params(), mesh, "dp"),
-            "v": zero_shardings(pshard, model.abstract_params(), mesh, "dp"),
-        }
+        pshard, sshard = state_shardings(model, mesh, zero=True)
         opt_state = jax.jit(opt.init, out_shardings=sshard)(params)
         ids = jnp.zeros((8, 64), jnp.int32)
         ids = jax.device_put(ids, st.act_tokens().named_sharding(mesh))
@@ -213,7 +207,7 @@ def test_sp_hetero_full_train_step_driver_envelope():
     guards the 16-bit all-gather-transpose reduce-scatter crash the
     _gather_seq widening works around (test_xla_canaries pins it)."""
     from hetu_tpu import optim
-    from hetu_tpu.optim.optimizer import zero_shardings
+    from hetu_tpu.optim.optimizer import state_shardings
 
     st = ParallelStrategy(mesh=MeshConfig(dp=2, pp=2, tp=2), zero=True,
                           pp_tp_eff=(2, 1), sequence_parallel=True)
@@ -223,13 +217,7 @@ def test_sp_hetero_full_train_step_driver_envelope():
     opt = optim.AdamW(lr=1e-3)
     with ht.use_mesh(mesh):
         params = model.init(jax.random.key(0), mesh=mesh)
-        pshard = model.shardings(mesh)
-        sshard = {
-            "step": jax.sharding.NamedSharding(
-                mesh, jax.sharding.PartitionSpec()),
-            "m": zero_shardings(pshard, model.abstract_params(), mesh, "dp"),
-            "v": zero_shardings(pshard, model.abstract_params(), mesh, "dp"),
-        }
+        pshard, sshard = state_shardings(model, mesh, zero=True)
         opt_state = jax.jit(opt.init, out_shardings=sshard)(params)
         ids = jax.device_put(jnp.zeros((8, 64), jnp.int32),
                              st.act_tokens().named_sharding(mesh))

@@ -141,6 +141,19 @@ def test_cond_trip_count_lt_and_unresolvable():
         ["%lt = pred[] compare(s32[] %i, s32[] %n), direction=LT"]) is None
 
 
+def test_cond_trip_count_reads_the_tpu_compilers_condition():
+    """The compiler for a TPU gives the bound a layout and the compare
+    untyped operands; XLA:CPU's text (above) does neither."""
+    assert H.cond_trip_count([
+        "%constant.1830 = s32[]{:T(128)} constant(16)",
+        "%i = s32[]{:T(128)} get-tuple-element(%p), index=0",
+        "ROOT %lt.80 = pred[]{:T(512)} compare(%i, %constant.1830), "
+        "direction=LT"]) == 16
+    assert H.cond_trip_count([
+        "%c = s32[]{:T(128)} constant(4)",
+        "ROOT %gt = pred[]{:T(512)} compare(%c, %i), direction=GT"]) == 4
+
+
 def test_while_multipliers_nested_compose():
     comps = H.split_computations(_NESTED_WHILE)
     mults = H.while_multipliers(comps)

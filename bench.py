@@ -357,7 +357,8 @@ def _measured_chunk_flops(cfg, chunk: int):
     import jax.numpy as jnp
     from hetu_tpu.models.generation import extend_cache, init_cache
     from hetu_tpu.models.llama import LlamaConfig, LlamaLMHeadModel
-    from hetu_tpu.obs.hlo_text import dot_flops
+    from hetu_tpu.obs.hlo_text import (definitions, dot_flops,
+                                       split_computations)
     tiny = LlamaConfig(vocab_size=256, hidden_size=64,
                        num_hidden_layers=2, num_attention_heads=4,
                        num_key_value_heads=2, max_position_embeddings=256,
@@ -370,7 +371,10 @@ def _measured_chunk_flops(cfg, chunk: int):
         lambda p, t, c, s: extend_cache(model, p, t, c, s)).lower(
             params, jnp.zeros((1, 8), jnp.int32), cache,
             jnp.int32(0)).compile().as_text()
-    measured = sum(dot_flops(ln) for ln in text.splitlines())
+    comps = split_computations(text)
+    defs = definitions(comps)
+    measured = sum(dot_flops(ln, defs)
+                   for lines in comps.values() for ln in lines)
     # scale tiny-model 8-token chunk FLOPs to the bench config's chunk
     scale = (2.0 * float(cfg.num_params()) * chunk) / \
         (2.0 * float(tiny.num_params()) * 8)

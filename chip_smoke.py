@@ -45,12 +45,11 @@ KERNEL_SCOPES = {
     "norm": "pallas_residual_rmsnorm",
     "swiglu": "pallas_swiglu",
     "rotary": "pallas_rotary",
-    "adam": "pallas_adam",
     "paged_attn": "pallas_paged_attention",
 }
 
 #: the kernels the train step runs on a TPU, under any mesh (the AdamW
-#: update is XLA's: `ops/pallas.AUTO_KEEPS_XLA`)
+#: update is an op chain XLA fuses)
 TRAIN_KERNELS = ("flash", "norm", "rotary", "swiglu")
 
 #: sharded-vs-single first-step loss: the bound __graft_entry__'s

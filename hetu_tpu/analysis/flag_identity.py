@@ -10,18 +10,22 @@ the ~10 hand-written per-flag byte-identity tests of PRs 2/6/8/9: a new
 flag gets enforcement by REGISTERING its contract, not by writing a
 test.
 
-Mechanics: lower the canonical train step and serving decode
-(analysis/programs.py) once with every contracted flag UNSET — the
-baseline fingerprints — then once per (flag, program) with exactly that
-flag set to its identity value, and compare sha256 fingerprints of the
-traced module text.  Every contract acts at build/trace time, so
-trace-level identity implies compiled identity (and costs no XLA
-compile, which is what makes sweeping the whole table per CI run
-affordable).
+Mechanics: build and lower each canonical program (analysis/programs.py)
+once with every contracted flag UNSET — the baseline fingerprints —
+while `flags.recorded_reads` notes which flags that build and trace
+asked for.  Every read goes through an accessor that reads the
+environment at the call (the env-bypass lint holds the first, `flags.py`
+the second), so a program that never asked for a flag cannot depend on
+it: that (flag, program) pair is held by the record, reported with
+`"read": False`, and costs nothing.  A pair whose flag WAS read is built
+and lowered again with exactly that flag set to its identity value, and
+the sha256 fingerprints of the traced module text are compared.  Every
+contract acts at build/trace time, so trace-level identity implies
+compiled identity (and costs no XLA compile).
 
 A mismatch is an ERROR finding carrying both fingerprints; the sweep
 also returns its coverage rows so the acceptance test can assert 100%
-of `flags.identity_flags()` ran against BOTH programs.
+of `flags.identity_flags()` is held against EVERY program.
 """
 from __future__ import annotations
 
@@ -30,10 +34,14 @@ from typing import Any, Dict, List, Optional, Sequence
 
 from hetu_tpu.analysis.findings import ERROR, INFO, Finding
 from hetu_tpu.analysis.programs import PROGRAMS, scoped_env
+from hetu_tpu.obs.hlo_text import without_source_positions
 
 
 def fingerprint(text: str) -> str:
-    return hashlib.sha256(text.encode()).hexdigest()[:16]
+    """What "the same program" is compared on: a traced module's text,
+    or a compiled one's less the source positions it was lowered from."""
+    return hashlib.sha256(
+        without_source_positions(text).encode()).hexdigest()[:16]
 
 
 def identity_sweep(only_flags: Optional[Sequence[str]] = None,
@@ -41,8 +49,10 @@ def identity_sweep(only_flags: Optional[Sequence[str]] = None,
                    ) -> Dict[str, Any]:
     """Run the sweep; returns {"baseline", "rows", "findings"}.
 
-    rows: one {"flag", "value", "program", "fingerprint", "ok"} per
-    (contracted flag, program) pair — the coverage record.  findings:
+    rows: one {"flag", "value", "program", "read", "fingerprint", "ok"}
+    per (contracted flag, program) pair — the coverage record; `read`
+    says whether the program's build asked for the flag, and so whether
+    the fingerprint is a second lower's or the baseline's.  findings:
     one ERROR per broken contract + one INFO summarizing the sweep.
     `only_flags` restricts the table (tools_lint --flags <name> for
     bisection); coverage claims are only made for what actually ran.
@@ -64,25 +74,24 @@ def identity_sweep(only_flags: Optional[Sequence[str]] = None,
     all_unset = {name: None for name in _flags.identity_flags()}
 
     baseline: Dict[str, str] = {}
+    reads: Dict[str, set] = {}
     with scoped_env(**all_unset):
         for prog in prog_names:
-            baseline[prog] = fingerprint(PROGRAMS[prog]())
+            with _flags.recorded_reads() as reads[prog]:
+                baseline[prog] = fingerprint(PROGRAMS[prog]())
 
     rows: List[Dict[str, Any]] = []
     findings: List[Finding] = []
     for name, value in sorted(table.items()):
-        # serving-only flags contract against the decode program alone
-        # (Flag.identity_programs — reads are structurally confined to
-        # hetu_tpu/serving, so a training lower is pure sweep cost)
-        flag_progs = _flags.identity_contract_programs(name)
-        progs = (prog_names if flag_progs is None
-                 else [p for p in prog_names if p in flag_progs])
-        for prog in progs:
-            with scoped_env(**{**all_unset, name: value}):
-                fp = fingerprint(PROGRAMS[prog]())
+        for prog in prog_names:
+            read = name in reads[prog]
+            fp = baseline[prog]
+            if read:
+                with scoped_env(**{**all_unset, name: value}):
+                    fp = fingerprint(PROGRAMS[prog]())
             ok = fp == baseline[prog]
             rows.append({"flag": name, "value": value, "program": prog,
-                         "fingerprint": fp, "ok": ok})
+                         "read": read, "fingerprint": fp, "ok": ok})
             if not ok:
                 findings.append(Finding(
                     "flag-identity", ERROR, f"flag:{name}/{prog}",
@@ -93,10 +102,13 @@ def identity_sweep(only_flags: Optional[Sequence[str]] = None,
                     {"flag": name, "value": value, "program": prog,
                      "baseline": baseline[prog], "got": fp}))
     n_bad = sum(1 for r in rows if not r["ok"])
+    n_read = sum(1 for r in rows if r["read"])
     findings.append(Finding(
         "flag-identity", INFO, "flag:sweep",
         f"{len(table)} contracted flags x {len(prog_names)} programs: "
-        f"{len(rows) - n_bad}/{len(rows)} identities hold",
+        f"{len(rows) - n_bad}/{len(rows)} identities hold ({n_read} "
+        f"lowered again, {len(rows) - n_read} whose program never reads "
+        f"the flag)",
         {"flags": sorted(table), "programs": prog_names,
-         "violations": n_bad}))
+         "lowered": n_read, "violations": n_bad}))
     return {"baseline": baseline, "rows": rows, "findings": findings}

@@ -55,8 +55,9 @@ from hetu_tpu.analysis.findings import ERROR, INFO, WARNING, Finding
 from hetu_tpu.obs.hlo_text import (BRANCH_PAT, GROUPS_ATTR_PAT, LINE_PAT,
                                    OP_NAME_PAT, REF_PAT,
                                    alias_attribute_body, as_hlo_text,
-                                   call_multipliers, donated_parameters,
-                                   dot_flops, entry_computation,
+                                   call_multipliers, definitions,
+                                   donated_parameters, dot_flops,
+                                   entry_computation,
                                    entry_parameters, first_group,
                                    maybe_collective, payload_bytes,
                                    split_computations)
@@ -299,6 +300,7 @@ def lint_scope_coverage(compiled_or_text, *, floor: float = 0.90,
     from hetu_tpu.obs.hlo_profile import group_of
     txt = as_hlo_text(compiled_or_text)
     comps = split_computations(txt)
+    defs = definitions(comps)
     mults = call_multipliers(comps)
     total = named = 0.0
     for cname, lines in comps.items():
@@ -306,7 +308,7 @@ def lint_scope_coverage(compiled_or_text, *, floor: float = 0.90,
         for ln in lines:
             if " dot(" not in ln:
                 continue
-            fl = dot_flops(ln) * mult
+            fl = dot_flops(ln, defs) * mult
             if fl <= 0:
                 continue
             total += fl

@@ -3,24 +3,30 @@ decode, ONE MoE forward+backward, and ONE expert-parallel (ep=2) MoE
 step, built the same way every time.
 
 The flag-identity sweep (flag_identity.py) lowers these under each
-contracted flag value and diffs fingerprints against an unset
-environment; tools_lint.py --hlo compiles the train step once and runs
-the HLO lints over its post-optimization text.  Both front ends share
-these builders so "the canonical program" means exactly one thing.
+contracted flag value that their build reads and diffs fingerprints
+against an unset environment; tools_lint.py --hlo compiles the train
+step once and runs the HLO lints over its post-optimization text.  Both
+front ends share these builders so "the canonical program" means exactly
+one thing.
 
-Shapes are tiny on purpose (the sweep lowers the train step a dozen
-times): a 2-layer scanned llama on the dp=4 virtual CPU mesh — the same
-configuration the per-flag byte-identity tests used before the sweep
-replaced them — the 8-slot serving decode program at page 8 /
+Shapes are tiny on purpose (the sweep lowers each program once a flag
+it reads): a 2-layer scanned llama on the dp=4 virtual CPU mesh — the
+same configuration the per-flag byte-identity tests used before the
+sweep replaced them — the 8-slot serving decode program at page 8 /
 max_len 32, and a one-block unrolled MoE train step — once on a single
 device and once on an ep=2 mesh — so the sweep's identity claims also
 cover the routing/dispatch code paths (incl. the HETU_TPU_MOE_DISPATCH
 branch point, which only an ep>1 trace reaches).
 
-Every flag under contract acts at Trainer/ServingEngine BUILD time or
+Every flag under contract acts at Trainer/ServingEngine construction or
 at trace time, so the builders construct FRESH objects per call: the
-caller scopes the environment (``scoped_env``), then builds, then
-lowers.
+caller scopes the environment (``scoped_env``), then constructs, then
+lowers.  A train step's TRACED text is lowered for abstract arguments
+(`Trainer.lower_abstract`: the module `build` + `lowered_step` give,
+with no parameter initialised — two thirds of a lower's seconds were the
+compiles of `init`); a flag value that takes the step off its default
+form is refused there by name, which fails the sweep as a moved
+fingerprint would.
 """
 from __future__ import annotations
 
@@ -58,9 +64,9 @@ def canonical_batch(n: int = 8, seq: int = 64,
     return {"input_ids": ids, "labels": ids.copy()}
 
 
-def canonical_trainer(dp: int = 4, zero: bool = False):
-    """The canonical train-step owner: tiny scanned llama, homogeneous
-    dp=4 — reads every training-side flag at build()."""
+def _dense_trainer(dp: int, zero: bool):
+    """The canonical train-step owner, not yet built: tiny scanned
+    llama, homogeneous dp=4."""
     from hetu_tpu.core.mesh import MeshConfig
     from hetu_tpu.engine import Trainer, TrainingConfig
     from hetu_tpu.models.llama import LlamaConfig, LlamaLMHeadModel
@@ -70,7 +76,13 @@ def canonical_trainer(dp: int = 4, zero: bool = False):
     tc = TrainingConfig(global_batch_size=8, micro_batch_size=8 // dp,
                         seq_len=64, lr=1e-3, warmup_steps=2,
                         total_steps=10, log_every=1000)
-    return Trainer(LlamaLMHeadModel(cfg, st), tc, st).build()
+    return Trainer(LlamaLMHeadModel(cfg, st), tc, st)
+
+
+def canonical_trainer(dp: int = 4, zero: bool = False):
+    """The canonical trainer, built — reads every training-side flag at
+    construction or build()."""
+    return _dense_trainer(dp, zero).build()
 
 
 def canonical_compute_dtype() -> Optional[str]:
@@ -85,26 +97,31 @@ def canonical_compute_dtype() -> Optional[str]:
         LlamaConfig.tiny(remat=False, use_scan=True).compute_dtype)
 
 
-def train_step_text(*, optimized: bool = False, dp: int = 4,
-                    zero: bool = False) -> str:
-    """Lowered text of the canonical train step under the CURRENT
-    environment (traced module by default; post-optimization HLO with
-    optimized=True — the HLO lints' input)."""
-    tr = canonical_trainer(dp=dp, zero=zero)
+def _step_text(tr, batch: Dict[str, np.ndarray], optimized: bool) -> str:
+    """A trainer's step for its configured batch, under the CURRENT
+    environment: the traced module (the sweep's fingerprint surface), or
+    with optimized=True the post-optimization HLO of the built trainer's
+    compile (the HLO lints' input)."""
     try:
-        return tr.lowered_step(canonical_batch(), optimized=optimized)
+        if not optimized:
+            return tr.lower_abstract().as_text()
+        return tr.build().lowered_step(batch, optimized=True)
     finally:
         tr.close()
 
 
-def canonical_moe_trainer():
-    """The canonical MoE train-step owner: one UNROLLED MoE llama block
-    (sort dispatch, 4 experts, top-2) on a single device — tiny because
-    the sweep lowers it once per contracted flag, unrolled because the
-    numerics observatory's router taps live at the loss-trace level
-    (scanned layer bodies cannot hand values out; documented in
-    docs/observability.md)."""
-    from hetu_tpu.core.mesh import MeshConfig
+def train_step_text(*, optimized: bool = False, dp: int = 4,
+                    zero: bool = False) -> str:
+    """Lowered text of the canonical train step."""
+    return _step_text(_dense_trainer(dp, zero), canonical_batch(), optimized)
+
+
+def _moe_trainer(mesh):
+    """One UNROLLED MoE llama block (sort dispatch, 4 experts, top-2),
+    not yet built — tiny because the sweep lowers it once a flag it
+    reads, unrolled because the numerics observatory's router taps live
+    at the loss-trace level (scanned layer bodies cannot hand values
+    out; documented in docs/observability.md)."""
     from hetu_tpu.engine import Trainer, TrainingConfig
     from hetu_tpu.models.llama import LlamaConfig, LlamaLMHeadModel
     from hetu_tpu.parallel import ParallelStrategy
@@ -113,11 +130,11 @@ def canonical_moe_trainer():
         num_hidden_layers=1, hidden_size=32, intermediate_size=64,
         vocab_size=128, num_attention_heads=2, num_key_value_heads=2,
         max_position_embeddings=64, moe_capacity_factor=1.0)
-    st = ParallelStrategy(mesh=MeshConfig(dp=1))
+    st = ParallelStrategy(mesh=mesh)
     tc = TrainingConfig(global_batch_size=4, micro_batch_size=4,
                         seq_len=16, lr=1e-3, warmup_steps=2,
                         total_steps=10, log_every=1000)
-    return Trainer(LlamaLMHeadModel(cfg, st), tc, st).build()
+    return Trainer(LlamaLMHeadModel(cfg, st), tc, st)
 
 
 def canonical_moe_batch(seed: int = 0) -> Dict[str, np.ndarray]:
@@ -125,50 +142,25 @@ def canonical_moe_batch(seed: int = 0) -> Dict[str, np.ndarray]:
 
 
 def moe_step_text(*, optimized: bool = False) -> str:
-    """Lowered text of the canonical MoE forward+backward step under the
-    CURRENT environment — the sweep's third program, covering the MoE
-    code path (routing, sort dispatch, expert einsums, aux losses) that
+    """Lowered text of the canonical MoE forward+backward step on a
+    single device — the sweep's third program, covering the MoE code
+    path (routing, sort dispatch, expert einsums, aux losses) that
     neither the dense train step nor the serving decode exercises."""
-    tr = canonical_moe_trainer()
-    try:
-        return tr.lowered_step(canonical_moe_batch(), optimized=optimized)
-    finally:
-        tr.close()
+    from hetu_tpu.core.mesh import MeshConfig
+    return _step_text(_moe_trainer(MeshConfig(dp=1)),
+                      canonical_moe_batch(), optimized)
 
 
-def canonical_moe_ep_trainer():
-    """The canonical EXPERT-PARALLEL MoE train-step owner: the same
-    one-block MoE llama as `canonical_moe_trainer`, on an ep=2 mesh —
-    the program whose trace actually reaches the ep>1 branch point in
-    `nn/moe.py` (HETU_TPU_MOE_DISPATCH reads there), so the dispatch
+def moe_ep_step_text(*, optimized: bool = False) -> str:
+    """Lowered text of the same MoE step on an ep=2 mesh — the sweep's
+    fourth program, whose trace actually reaches the ep>1 branch point
+    in `nn/moe.py` (HETU_TPU_MOE_DISPATCH reads there), so the dispatch
     flag's gspmd identity contract covers the code path it gates and a
     regression that perturbs the ep lowering under any contracted flag
     fails the sweep."""
     from hetu_tpu.core.mesh import MeshConfig
-    from hetu_tpu.engine import Trainer, TrainingConfig
-    from hetu_tpu.models.llama import LlamaConfig, LlamaLMHeadModel
-    from hetu_tpu.parallel import ParallelStrategy
-    cfg = LlamaConfig.tiny(
-        remat=False, use_scan=False, num_experts=4, moe_top_k=2,
-        num_hidden_layers=1, hidden_size=32, intermediate_size=64,
-        vocab_size=128, num_attention_heads=2, num_key_value_heads=2,
-        max_position_embeddings=64, moe_capacity_factor=1.0)
-    st = ParallelStrategy(mesh=MeshConfig(ep=2))
-    tc = TrainingConfig(global_batch_size=4, micro_batch_size=4,
-                        seq_len=16, lr=1e-3, warmup_steps=2,
-                        total_steps=10, log_every=1000)
-    return Trainer(LlamaLMHeadModel(cfg, st), tc, st).build()
-
-
-def moe_ep_step_text(*, optimized: bool = False) -> str:
-    """Lowered text of the canonical ep=2 MoE step under the CURRENT
-    environment — the sweep's fourth program (the expert-parallel
-    dispatch surface)."""
-    tr = canonical_moe_ep_trainer()
-    try:
-        return tr.lowered_step(canonical_moe_batch(), optimized=optimized)
-    finally:
-        tr.close()
+    return _step_text(_moe_trainer(MeshConfig(ep=2)),
+                      canonical_moe_batch(), optimized)
 
 
 def serving_decode_text(*, optimized: bool = False) -> str:

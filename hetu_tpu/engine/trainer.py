@@ -834,16 +834,7 @@ class Trainer:
         # lands here too — it consumes the updated shards)
         with jax.named_scope("optimizer"):
             if self._zero_compress == "none":
-                # the fused optimizer kernel runs once per shard of the
-                # STATE layout (under ZeRO: the dp shard the update is
-                # owed on), so the optimizer is told where the state lives
-                from hetu_tpu.dstates import DistributedStates
-                layouts = jax.tree.map(
-                    lambda ns, p: DistributedStates.from_pspec(ns.spec,
-                                                               p.ndim),
-                    self._sshard["m"], params)
-                return self.optimizer.update(grads, opt_state, params,
-                                             layouts=layouts)
+                return self.optimizer.update(grads, opt_state, params)
             from hetu_tpu.optim.zero_refresh import quantized_zero_update
             return quantized_zero_update(
                 self.optimizer, grads, opt_state, params, mesh=self.mesh,

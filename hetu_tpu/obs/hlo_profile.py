@@ -105,7 +105,8 @@ from typing import Any, Dict, List, Optional, Tuple
 from hetu_tpu.obs.hlo_text import (BRANCH_PAT, CALLEE_PAT, DEF_PAT,
                                    INSTR_PAT, OP_NAME_PAT, OUT_PAT, REF_PAT,
                                    as_hlo_text,
-                                   call_multipliers, dot_flops,
+                                   call_multipliers, definitions,
+                                   dot_flops,
                                    entry_computation, line_wire_bytes,
                                    shape_bytes, split_computations)
 from hetu_tpu.utils.profiling import PHASES
@@ -421,7 +422,7 @@ def scope_map(compiled_or_text) -> Dict[str, Tuple[str, str]]:
     post-optimization HLO module, fused ones included.  `group` is
     `group_of`'s key with the groups of SCOPE_MAP_GROUPS known too --
     `layer/attn`, `layer/attn/pallas_flash_attention`,
-    `layer/kv_write`, `lm_head`, `optimizer/pallas_adam`, ... -- found
+    `layer/kv_write`, `lm_head`, `optimizer`, ... -- found
     in the module docstring's fixed order: the instruction's own
     `op_name`; for a fusion, call, while or conditional the compiler
     left without one, its body's; for what is still without (the
@@ -478,6 +479,7 @@ def layer_table(compiled_or_text, *, phases: Tuple[str, ...] = PHASES,
     contract the tests pin."""
     txt = as_hlo_text(compiled_or_text)
     comps = split_computations(txt)
+    defs = definitions(comps)
     mults = (call_multipliers(comps) if apply_multipliers
              else {name: (1.0, False) for name in comps})
     placed = placed if placed is not None else _resolve(txt, comps)
@@ -525,7 +527,7 @@ def layer_table(compiled_or_text, *, phases: Tuple[str, ...] = PHASES,
             rec["instructions"] += mult
             if " dot(" in line or " convolution(" in line:
                 rec["dots"] += mult
-                rec["flops"] += dot_flops(line) * mult
+                rec["flops"] += dot_flops(line, defs) * mult
                 if " convolution(" in line:
                     # conv FLOPs are not statically parsed (no conv in
                     # the model zoo today) — surface the undercount

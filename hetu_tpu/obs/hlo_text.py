@@ -26,8 +26,17 @@ byte models silently drifted apart.  This module owns the shared layer:
   comm accounting) and `call_multipliers` (EVERY call edge: fusions,
   calls, conditional branches — the profiler's accounting) turn those
   into per-computation execution multipliers;
-* **FLOPs** — `dot_flops` prices one `dot(...)` line from its operand
-  shapes x `lhs_contracting_dims`;
+* **operands** — a compiler prints an operand as `f32[8,32]{1,0} %x` or
+  as `%x` alone (jax 0.9's XLA:CPU and the TPU compiler both leave the
+  shape out); `call_operands` names them in order and `definitions`
+  maps a name to the shape section of the line that DEFINES it, so a
+  walker that needs an operand's shape finds it under either print;
+* **FLOPs** — `dot_flops` prices one `dot(...)` line from its left
+  operand's shape x `lhs_contracting_dims`;
+* **identity** — `without_source_positions` takes out the tables of
+  file / function / line / column that follow the module header and the
+  `stack_frame_id` that points into them: two lowers of one program
+  from two lines of a file differ in nothing else;
 * **module contracts** — `donated_parameters` parses
   `input_output_alias`, `entry_parameters` lists the entry computation's
   parameter buffers — what the donation lint checks against liveness.
@@ -92,8 +101,17 @@ DIRECTION_PAT = re.compile(r'direction=(\w+)')
 CALLEE_PAT = re.compile(r'(?:calls|body|condition|to_apply)=%?([\w.\-]+)')
 BRANCH_PAT = re.compile(r'branch_computations=\{([^}]*)\}')
 ENTRY_PAT = re.compile(r'^ENTRY\s+%?([\w.\-]+)', re.M)
+PARAM_PAT = re.compile(r'%([\w.\-]+)\s*=.*\sparameter\((\d+)\)')
 DOT_CONTRACT_PAT = re.compile(r'lhs_contracting_dims=\{([0-9,]*)\}')
 ALIAS_ENTRY_PAT = re.compile(r'\(\s*(\d+)\s*,')
+
+
+#: the tables jax 0.9 appends under the module header, one numbered row a
+#: line, and the id an instruction's metadata carries into them
+SOURCE_TABLES_PAT = re.compile(
+    r'^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n'
+    r'(?:\d+ .*\n)*\n*', re.M)
+FRAME_ID_PAT = re.compile(r' stack_frame_id=\d+')
 
 
 def as_hlo_text(compiled_or_text) -> str:
@@ -102,6 +120,14 @@ def as_hlo_text(compiled_or_text) -> str:
     line, so large modules stringify once per caller, not per helper."""
     return (compiled_or_text if isinstance(compiled_or_text, str)
             else compiled_or_text.as_text())
+
+
+def without_source_positions(txt: str) -> str:
+    """The text less WHERE in the source each instruction was lowered
+    from — what "the same program" is compared on
+    (`analysis.flag_identity.fingerprint`).  A traced module's text
+    carries no positions and comes back as it went in."""
+    return FRAME_ID_PAT.sub("", SOURCE_TABLES_PAT.sub("", txt))
 
 
 # ---------------------------------------------------------------------------
@@ -258,28 +284,88 @@ def entry_computation(txt: str, comps: Optional[Dict[str, List[str]]] = None
     return next(iter(comps), "")
 
 
-def cond_trip_count(lines: List[str]) -> Optional[int]:
-    """Trip count from a while condition computation: the
-    `compare(induction, constant), direction=LT` form lax.scan lowers to
-    (0-based, unit step).  Non-zero-start loops (fori_loop(2, 10, ...))
-    are safe too: XLA's while canonicalization rebases the induction to
-    0 and folds the start into the bound BEFORE the post-optimization
-    text this module parses (regression-pinned in test_comm).  None =
-    not statically recoverable."""
-    consts = {name: int(val)
-              for name, val in (CONST_PAT.search(ln).groups()
-                                for ln in lines if CONST_PAT.search(ln))}
-    for ln in lines:
+def call_operands(line: str) -> List[str]:
+    """Names of an instruction's operands, in order: the `%name`s between
+    its opcode's parens (a tuple-typed operand printed with its shape
+    nests parens of its own)."""
+    m = LINE_PAT.search(line)
+    if m is None:
+        return []
+    depth, j = 1, m.end()
+    while j < len(line) and depth:
+        depth += {"(": 1, ")": -1}.get(line[j], 0)
+        j += 1
+    return REF_PAT.findall(line[m.end():j - 1])
+
+
+def definitions(comps: Dict[str, List[str]]) -> Dict[str, str]:
+    """{instruction name: its output-shape section} over a module's
+    computations, parameters included (a module names an instruction
+    once) — where an operand printed by name alone has its shape."""
+    defs: Dict[str, str] = {}
+    for lines in comps.values():
+        for ln in lines:
+            m = DEF_PAT.search(ln)
+            if m is not None:
+                defs[m.group(1)] = m.group(2)
+    return defs
+
+
+def _compare(line: str, comps: Dict[str, List[str]]
+             ) -> Optional[Tuple[str, str, str, Dict[str, int]]]:
+    """(direction, lhs, rhs, constants defined beside the compare) of the
+    comparison a condition's line makes: its own `compare(`, or the one
+    inside the fusion it calls (XLA:CPU wraps a lone compare as
+    `fusion(%i, %n), calls=%wrapped_compare_computation`), whose
+    parameters are then named as the fusion's operands."""
+    inner: List[str] = []
+    if " fusion(" in line:
+        callee = CALLEE_PAT.search(line)
+        inner = comps.get(callee.group(1), []) if callee else []
+    for ln in inner or [line]:
         cm = COMPARE_PAT.search(ln)
         if cm is None:
             continue
+        outer = call_operands(line)
+        names = {}
+        for pl in inner:
+            pm = PARAM_PAT.search(pl)
+            if pm is not None and int(pm.group(2)) < len(outer):
+                names[pm.group(1)] = outer[int(pm.group(2))]
         dm = DIRECTION_PAT.search(ln)
-        direction = dm.group(1) if dm else ""
-        lhs, rhs = cm.group(1), cm.group(2)
-        if direction == "LT" and rhs in consts:
-            return consts[rhs]
-        if direction == "GT" and lhs in consts:
-            return consts[lhs]
+        return (dm.group(1) if dm else "",
+                names.get(cm.group(1), cm.group(1)),
+                names.get(cm.group(2), cm.group(2)), _constants(inner))
+    return None
+
+
+def _constants(lines: List[str]) -> Dict[str, int]:
+    return {m.group(1): int(m.group(2))
+            for m in map(CONST_PAT.search, lines) if m}
+
+
+def cond_trip_count(lines: List[str],
+                    comps: Optional[Dict[str, List[str]]] = None
+                    ) -> Optional[int]:
+    """Trip count from a while condition computation: the
+    `compare(induction, constant), direction=LT` form lax.scan lowers to
+    (0-based, unit step), made on the condition's own line or inside a
+    fusion of `comps` that it calls.  Non-zero-start loops
+    (fori_loop(2, 10, ...)) are safe too: XLA's while canonicalization
+    rebases the induction to 0 and folds the start into the bound BEFORE
+    the post-optimization text this module parses (regression-pinned in
+    test_comm).  None = not statically recoverable."""
+    consts = _constants(lines)
+    for ln in lines:
+        found = _compare(ln, comps or {})
+        if found is None:
+            continue
+        direction, lhs, rhs, fused = found
+        bound = {**consts, **fused}
+        if direction == "LT" and rhs in bound:
+            return bound[rhs]
+        if direction == "GT" and lhs in bound:
+            return bound[lhs]
     return None
 
 
@@ -302,7 +388,7 @@ def while_multipliers(comps: Dict[str, List[str]]
                 continue
             trip = None
             if cm is not None and cm.group(1) in comps:
-                trip = cond_trip_count(comps[cm.group(1)])
+                trip = cond_trip_count(comps[cm.group(1)], comps)
             parent[bm.group(1)] = (cname, trip)
 
     memo: Dict[str, Tuple[int, bool]] = {}
@@ -338,7 +424,7 @@ def call_multipliers(comps: Dict[str, List[str]]
                 cm = COND_REF_PAT.search(ln)
                 trip = None
                 if cm is not None and cm.group(1) in comps:
-                    t = cond_trip_count(comps[cm.group(1)])
+                    t = cond_trip_count(comps[cm.group(1)], comps)
                     trip = float(t) if t else None
             for m in CALLEE_PAT.finditer(ln):
                 callee = m.group(1)
@@ -376,12 +462,16 @@ def call_multipliers(comps: Dict[str, List[str]]
 # FLOPs
 # ---------------------------------------------------------------------------
 
-def dot_flops(line: str) -> float:
+def dot_flops(line: str, defs: Dict[str, str]) -> float:
     """FLOPs of one `dot(...)` line: 2 * out_elems * contraction size,
-    contraction parsed from the FIRST operand shape (inside the parens)
-    and `lhs_contracting_dims`.  0.0 when not statically parseable."""
+    the contraction from `lhs_contracting_dims` over the LEFT operand's
+    shape: printed before its name inside the parens, or else the one
+    `defs` (`definitions`) holds for that name.  0.0 when not statically
+    parseable."""
     om = OUT_PAT.search(line)
-    if om is None:
+    paren = line.find(" dot(")
+    cm = DOT_CONTRACT_PAT.search(line)
+    if om is None or paren < 0 or cm is None:
         return 0.0
     out_elems = 0
     for dt, dims in SHAPE_PAT.findall(om.group(1)):
@@ -390,13 +480,12 @@ def dot_flops(line: str) -> float:
             if d:
                 n *= int(d)
         out_elems += n
-    paren = line.find(" dot(")
-    if paren < 0:
-        return 0.0
     operands = line[paren + 5:]
-    lhs = SHAPE_PAT.search(operands)
-    cm = DOT_CONTRACT_PAT.search(line)
-    if lhs is None or cm is None:
+    name = REF_PAT.search(operands)
+    lhs = SHAPE_PAT.search(operands[:name.start()] if name else operands)
+    if lhs is None and name is not None:
+        lhs = SHAPE_PAT.search(defs.get(name.group(1), ""))
+    if lhs is None:
         return 0.0
     lhs_dims = [int(d) for d in lhs.group(2).split(",") if d]
     contract = 1

@@ -40,8 +40,6 @@ The fused-kernel layer (docs/kernels.md):
   * sample           — fused last-layer epilogue: lm_head matmul +
                        temperature/top-k/top-p filter + Gumbel draw per
                        row without materializing [rows, vocab] logits
-  * adam             — fused AdamW moment + parameter update, one launch
-                       per flat parameter leaf (FusedAdam.cu)
 
 Every kernel follows the flash-attention pattern: a shape gate that
 EXACTLY mirrors the kernel's own entry validation (`compatible()` /
@@ -68,19 +66,9 @@ from typing import FrozenSet, Optional, Tuple
 
 #: every routable kernel name (the HETU_TPU_PALLAS_KERNELS vocabulary)
 KERNEL_NAMES = ("flash", "norm", "swiglu", "rotary", "quant", "paged_attn",
-                "paged_verify", "sample", "adam", "paged_latent",
+                "paged_verify", "sample", "paged_latent",
                 "chunk_attn", "kda_scan", "latent_chunk_attn",
                 "selective_scan")
-
-
-#: kernels `auto` leaves to XLA, and why: each was timed on the chip against
-#: XLA's own composition in every benchmark cell that runs it, and lost.  A
-#: forced flag (HETU_TPU_PALLAS=1) still routes them.
-AUTO_KEEPS_XLA = {
-    "adam": ("XLA fuses the update with the gradient's rescale and reads "
-             "each leaf where it lies: faster than the kernel in both "
-             "train cells (PERF.md s6, PR 39)"),
-}
 
 
 def _interpret() -> bool:
@@ -275,9 +263,8 @@ def resolve_route(name: str, check, *shapes, layouts=None, **kw) -> bool:
     Forced flags win.  Auto takes the kernel on a TPU backend when the
     gate passes and the call can lower: in a one-device program, an
     all-manual region, or — through `per_shard` — under a multi-device
-    mesh whose caller declared the layouts — unless the chip has shown
-    XLA's composition to be the faster (`AUTO_KEEPS_XLA`).  A kernel that
-    then fails to compile is an error, never a route."""
+    mesh whose caller declared the layouts.  A kernel that then fails to
+    compile is an error, never a route."""
     en = kernel_enabled(name)
     if en is not None:
         routed = en
@@ -289,8 +276,6 @@ def resolve_route(name: str, check, *shapes, layouts=None, **kw) -> bool:
         routed = False
         if jax.default_backend() != "tpu":
             why = "not a TPU backend"
-        elif name in AUTO_KEEPS_XLA:
-            why = AUTO_KEEPS_XLA[name]
         elif axes and layouts is None:
             why = ("multi-device mesh and the caller declares no layout: "
                    "a Mosaic call has to run per shard")

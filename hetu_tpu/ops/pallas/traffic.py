@@ -275,28 +275,6 @@ def sample_traffic(rows: int, hidden: int, vocab: int, *,
     return _report("sample", chain, h_in + w, toks)
 
 
-def adam_traffic(n_params: int, *, param_bytes: float = 4.0
-                 ) -> Dict[str, Any]:
-    """Fused AdamW update (ops/pallas/adam.py) vs the XLA op chain of
-    optim/optimizer.AdamW.update: per step the chain materializes the
-    two moment updates, the bias-corrected mhat/vhat, the denominator
-    and the final update — each a params-sized f32 round trip.  The
-    kernel reads p/g/m/v once and writes p'/m'/v' once."""
-    n = float(n_params)
-    pb = float(param_bytes)
-    chain: Chain = [
-        ("m_update", 2 * _F32 * n, _F32 * n),        # b1*m + (1-b1)*g
-        ("v_update", 2 * _F32 * n, _F32 * n),        # b2*v + (1-b2)*g^2
-        ("mhat", _F32 * n, _F32 * n),
-        ("vhat", _F32 * n, _F32 * n),
-        ("denom", _F32 * n, _F32 * n),               # sqrt(vhat) + eps
-        ("update", 2 * _F32 * n + pb * n, pb * n),   # mhat/denom + wd*p
-    ]
-    return _report("adam", chain,
-                   pb * n + 3 * _F32 * n,            # p + g + m + v
-                   pb * n + 2 * _F32 * n)            # p' + m' + v'
-
-
 def fused_verify_chain(slots: int, k: int, max_pages: int, page_size: int,
                        kv_heads: int, head_dim: int, hidden: int,
                        vocab: int, *, num_layers: int = 1,
@@ -340,8 +318,7 @@ def kernel_traffic_report(*, batch: int, seq: int, hidden: int,
                           quant_block: int = 1024,
                           serve_slots: int = 8, serve_pages: int = 16,
                           serve_page_size: int = 16, spec_k: int = 4,
-                          vocab: Optional[int] = None,
-                          n_params: Optional[int] = None
+                          vocab: Optional[int] = None
                           ) -> Dict[str, Dict[str, Any]]:
     """Per-kernel fused-vs-unfused bytes for ONE forward pass of a
     transformer stack shaped like the arguments (per-step: every count
@@ -405,12 +382,6 @@ def kernel_traffic_report(*, batch: int, seq: int, hidden: int,
     sm["per_step_multiplier"] = 1
     sm.pop("chain", None)
     out["sample"] = sm
-    pn = n_params if n_params is not None else \
-        num_layers * (4 * hidden * hidden + 3 * hidden * intermediate)
-    ad = adam_traffic(pn)
-    ad["per_step_multiplier"] = 1
-    ad.pop("chain", None)
-    out["adam"] = ad
     return out
 
 
@@ -423,11 +394,10 @@ def report_for_config(cfg, *, batch: int, seq: int,
         elem_bytes = float(jnp.dtype(cfg.compute_dtype).itemsize)
     kv = getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
     kind = "rms" if hasattr(cfg, "rms_norm_eps") else "ln"
-    n_params = cfg.num_params() if hasattr(cfg, "num_params") else None
     return kernel_traffic_report(
         batch=batch, seq=seq, hidden=cfg.hidden_size,
         intermediate=cfg.intermediate_size,
         num_layers=cfg.num_hidden_layers,
         q_heads=cfg.num_attention_heads, kv_heads=kv,
         head_dim=cfg.head_dim, elem_bytes=elem_bytes, norm_kind=kind,
-        vocab=cfg.vocab_size, n_params=n_params)
+        vocab=cfg.vocab_size)

@@ -45,11 +45,8 @@ class Optimizer:
     def init(self, params):
         raise NotImplementedError
 
-    def update(self, grads, state, params, layouts=None):
-        """-> (new_params, new_state).  `layouts`: a tree like `params`
-        of DistributedStates saying where each leaf's STATE lives on the
-        mesh (the Trainer passes its optimizer-state shardings); an
-        update that launches a fused kernel runs it per shard of that."""
+    def update(self, grads, state, params):
+        """-> (new_params, new_state)."""
         raise NotImplementedError
 
 
@@ -67,7 +64,7 @@ class SGD(Optimizer):
             "velocity": jax.tree.map(lambda p: jnp.zeros_like(p, dtype=jnp.float32), params),
         }
 
-    def update(self, grads, state, params, layouts=None):
+    def update(self, grads, state, params):
         step = state["step"] + 1
 
         def upd(p, g, v=None):
@@ -111,42 +108,17 @@ class AdamW(Optimizer):
     def _lr(self, step):
         return self.lr(step) if callable(self.lr) else self.lr
 
-    def update(self, grads, state, params, layouts=None):
+    def update(self, grads, state, params):
         step = state["step"] + 1
         lr = self._lr(step)
         c1 = 1.0 - self.b1 ** step.astype(jnp.float32)
         c2 = 1.0 - self.b2 ** step.astype(jnp.float32)
-        from hetu_tpu.ops import pallas as _pl
-        from hetu_tpu.ops.pallas import adam as _padam
 
-        def fused(p, g, m, v, lr, c1, c2):
-            return _padam.adam_update(
-                p, g, m, v, lr, c1, c2, b1=self.b1, b2=self.b2,
-                eps=self.eps, weight_decay=self.weight_decay)
-
-        def upd(p, g, m, v, layout=None):
-            # fused Adam kernel (ops/pallas/adam.py): one read of
-            # p/g/m/v, one write of p'/m'/v' per lane-aligned leaf;
-            # ragged leaves (biases, gains) keep the XLA chain below.
-            # `auto` keeps the chain for EVERY leaf
-            # (ops/pallas.AUTO_KEEPS_XLA): XLA fuses it with the rescale
-            # that made `g` and reads each leaf where it lies, and the
-            # chip read it faster in both train cells (PR 39); the kernel
-            # runs where HETU_TPU_PALLAS=1 forces it.
-            # Under a multi-device mesh it runs per shard of the STATE
-            # layout: p and g are sliced to the shard the update is owed
-            # on, and the fresh p is gathered back by the step's
-            # out_shardings — the ZeRO-1 exchange, as GSPMD places it
-            # around the XLA chain too
-            four = None if layout is None else (layout,) * 4
-            if _pl.resolve_route("adam", _padam.check_shapes, p.shape,
-                                 g.shape, m.shape, v.shape, layouts=four):
-                with jax.named_scope("pallas_adam"):
-                    return _pl.per_shard(
-                        fused, None if four is None else four + (None,) * 3,
-                        (layout,) * 3)(
-                        p, g, m, v, *(jnp.asarray(x, jnp.float32)
-                                      for x in (lr, c1, c2)))
+        def upd(p, g, m, v):
+            # an op chain, left to XLA: it fuses the chain with the
+            # rescale that made `g` and reads each leaf where it lies,
+            # which the chip read faster than a fused kernel behind a
+            # call boundary in both train cells (PERF.md s6, PR 39)
             g = g.astype(jnp.float32)
             m = self.b1 * m + (1.0 - self.b1) * g
             v = self.b2 * v + (1.0 - self.b2) * jnp.square(g)
@@ -156,8 +128,7 @@ class AdamW(Optimizer):
             newp = pf - lr * (mhat / (jnp.sqrt(vhat) + self.eps) + self.weight_decay * pf)
             return newp.astype(p.dtype), m, v
 
-        triples = jax.tree.map(upd, params, grads, state["m"], state["v"],
-                               *(() if layouts is None else (layouts,)))
+        triples = jax.tree.map(upd, params, grads, state["m"], state["v"])
         is_t = lambda t: isinstance(t, tuple)
         new_params = jax.tree.map(lambda t: t[0], triples, is_leaf=is_t)
         new_m = jax.tree.map(lambda t: t[1], triples, is_leaf=is_t)

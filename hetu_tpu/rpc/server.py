@@ -181,7 +181,12 @@ class CoordinationServer:
                     except OSError as e:
                         logger.debug(f"conn recv error: {e}")
                         return
-                    if req is None:
+                    if req is None or self._shutdown:
+                        # a request that arrives after `close` is not
+                        # acknowledged: the state it would change is gone
+                        # with this server, and the client re-issues it
+                        # to the next one (`close` cannot shut a
+                        # connection the accept loop has yet to list)
                         return
                     try:
                         resp = self._handle(req, state)

@@ -16,9 +16,10 @@ Usage:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
-from typing import Dict, Optional, Tuple
+from typing import Dict, Iterator, Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -39,14 +40,6 @@ class Flag:
     #: (hetu_tpu/analysis/flag_identity.py, tools_lint.py --flags), which
     #: replaced the per-flag hand-written byte-identity tests.
     identity: Optional[str] = None
-    #: which canonical programs (analysis/programs.py PROGRAMS keys) the
-    #: identity contract sweeps against; None = all of them.  Flags read
-    #: ONLY inside hetu_tpu/serving (structurally enforced: serving is
-    #: never imported from the package root and the env-bypass AST lint
-    #: pins every read to this module) cannot perturb a training trace,
-    #: so their contracts sweep the decode program alone — the training
-    #: lowers would be pure sweep cost with no information.
-    identity_programs: Optional[Tuple[str, ...]] = None
 
 
 REGISTRY: Dict[str, Flag] = {f.name: f for f in [
@@ -240,7 +233,7 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
          "Unset (default) builds the greedy-only decode program "
          "byte-identical to the flag not existing (registered identity "
          "contract); SamplingParams on a Request then raise loudly",
-         identity="0", identity_programs=("decode",)),
+         identity="0"),
     Flag("HETU_TPU_SPEC_DECODE", "str", "none",
          "speculative decoding (serving/spec_decode.py): ngram drafts "
          "HETU_TPU_SPEC_K tokens per slot per step (prompt-lookup, "
@@ -256,8 +249,7 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
          "resample on rejection — the output distribution is exactly "
          "the target's for ANY drafter.  none (default) builds the "
          "single-token decode program byte-identical to unset",
-         choices=("none", "ngram", "model"), identity="none",
-         identity_programs=("decode",)),
+         choices=("none", "ngram", "model"), identity="none"),
     Flag("HETU_TPU_SPEC_K", "int", 4,
          "draft tokens per speculative decode step (the verify "
          "program's static width is k+1); also widens every page "
@@ -265,7 +257,7 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
          "draft writes).  Read only when HETU_TPU_SPEC_DECODE is set — "
          "the registered identity contract pins that setting it alone "
          "leaves the decode program byte-identical",
-         identity="4", identity_programs=("decode",)),
+         identity="4"),
     Flag("HETU_TPU_SERVE_PREFIX_CACHE", "bool", False,
          "radix prefix cache (serving/prefix_cache.py): finished "
          "prompts' page-aligned KV pages stay resident in a radix tree "
@@ -276,13 +268,13 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
          "fully-shared system prompt, bench.py detail.serving).  "
          "Host-side bookkeeping only: the decode program is "
          "byte-identical either way (registered identity contract)",
-         identity="0", identity_programs=("decode",)),
+         identity="0"),
     Flag("HETU_TPU_SERVE_PREFIX_PAGES", "int", 0,
          "radix-cache page budget (0 = bounded only by pool pressure: "
          "the scheduler evicts LRU cache entries on demand when an "
          "admission's reservation comes up short, so cached pages are "
          "best-effort slack and can never deadlock admission)",
-         identity="0", identity_programs=("decode",)),
+         identity="0"),
     Flag("HETU_TPU_SERVE_PREEMPT", "bool", False,
          "SLO-class-aware preemptive admission: when the queue head's "
          "class priority strictly outranks the lowest-priority live "
@@ -292,7 +284,7 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
          "Equal priorities never preempt (no thrash).  Host-side "
          "policy only — decode program byte-identical (registered "
          "identity contract)",
-         identity="0", identity_programs=("decode",)),
+         identity="0"),
     Flag("HETU_TPU_SERVE_QUOTAS", "str", "",
          "per-tenant admission quotas (serving/request.py parse_quotas): "
          "comma list of tenant[:max_slots[:max_pages]] specs, e.g. "
@@ -303,7 +295,7 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
          "= quota-free: the admission path is byte-identical to the "
          "flag not existing (registered identity contract; host-side "
          "policy only — the decode program never sees tenants)",
-         identity="", identity_programs=("decode",)),
+         identity=""),
     Flag("HETU_TPU_RUNLOG_SERVE_SAMPLE", "int", 1,
          "serve-event/span RunLog sampling: only a deterministic hashed "
          "1-in-N of request ids (serving/request.py rid_sampled — "
@@ -314,7 +306,7 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
          "sampled).  1 (default) logs every request — the RunLog is "
          "byte-identical to the flag not existing (registered identity "
          "contract); raise to ~1000 for 10^6-request fleet runs",
-         identity="1", identity_programs=("decode",)),
+         identity="1"),
     Flag("HETU_TPU_SERVE_TRACE", "bool", False,
          "serving flight recorder (serving/tracing.py): record every "
          "request's lifecycle as schema-versioned 'span' RunLog records "
@@ -330,7 +322,7 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
          "bookkeeping: the compiled prefill/decode programs are "
          "byte-identical with the flag on or off (registered identity "
          "contract, decode program — reads are serving-confined)",
-         identity="1", identity_programs=("decode",)),
+         identity="1"),
     Flag("HETU_TPU_SERVE_RETRY", "int", 0,
          "per-request retry budget after a serving replica death (chaos "
          "engine_kill): in-flight requests re-enter the queue with the "
@@ -342,7 +334,7 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
          "killed replica's in-flight requests terminate.  Host-side "
          "failover policy only — the decode program is byte-identical "
          "at any value (registered identity contract)",
-         identity="3", identity_programs=("decode",)),
+         identity="3"),
     Flag("HETU_TPU_SERVE_DEADLINE", "bool", False,
          "enforce SLOClass deadlines (serving/request.py deadline_s, "
          "the 5th --slo-class field): each engine step sweeps queued "
@@ -352,7 +344,7 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
          "(default) = deadlines never inspected.  Host-side policy "
          "only — decode program byte-identical (registered identity "
          "contract)",
-         identity="1", identity_programs=("decode",)),
+         identity="1"),
     Flag("HETU_TPU_SERVE_BROWNOUT", "bool", False,
          "sustained-pressure brownout shedding: when KV page "
          "utilization sits at the high watermark with a backed-up "
@@ -364,7 +356,7 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
          "Unset (default) = never shed.  Host-side policy only — "
          "decode program byte-identical (registered identity "
          "contract)",
-         identity="1", identity_programs=("decode",)),
+         identity="1"),
     Flag("HETU_TPU_SERVE_KV_REPAGE", "bool", False,
          "migrate the paged KV pool through a LoadAdaptiveMesh tier "
          "change (serving/reshard.py reshard_pool): the pool arrays "
@@ -376,7 +368,7 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
          "params-only reshard (the pool stays on its original "
          "placement).  Pure data movement between steps — the decode "
          "program is byte-identical (registered identity contract)",
-         identity="1", identity_programs=("decode",)),
+         identity="1"),
     Flag("HETU_TPU_SERVE_DISAGG", "bool", False,
          "disaggregated prefill/decode serving (serving/disagg.py): "
          "prompts prefill on a separate tier running the SAME chunk "
@@ -391,7 +383,7 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
          "the decode program is byte-identical with the flag on or "
          "off (registered identity contract) and exact-wire streams "
          "are token-identical to the colocated run",
-         identity="1", identity_programs=("decode",)),
+         identity="1"),
     Flag("HETU_TPU_SERVE_SHIP_QUANT", "str", "none",
          "wire quantization for prefill->decode KV shipments "
          "(serving/disagg.py pack_shipment): int8/int4 ship blockwise "
@@ -403,7 +395,7 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
          "decode program is byte-identical at any value (registered "
          "identity contract)",
          choices=("none", "int8", "int4"),
-         identity="int8", identity_programs=("decode",)),
+         identity="int8"),
     Flag("HETU_TPU_SERVE_HEDGE", "int", 0,
          "frontend hedged re-dispatch (serving/frontend.py): a request "
          "queued on its replica for more than this many router steps "
@@ -415,12 +407,12 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
          "never hedge.  Host-side routing policy only — the decode "
          "program is byte-identical at any value (registered "
          "identity contract)",
-         identity="2", identity_programs=("decode",)),
+         identity="2"),
     Flag("HETU_TPU_PALLAS", "str", "auto",
          "Pallas fused-kernel layer routing (ops/pallas: flash attention, "
          "residual+RMS/LayerNorm, SwiGLU, rotary, blockwise quantize, "
          "paged-attention decode, multi-query verify, fused sampling "
-         "epilogue, fused AdamW — docs/kernels.md): auto (shape-gated, "
+         "epilogue — docs/kernels.md): auto (shape-gated, "
          "TPU only), 1 (force the kernels; unsupported shapes raise), "
          "0 (force the XLA compositions — byte-identical to the seed "
          "lowering, tested)",
@@ -428,7 +420,7 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
     Flag("HETU_TPU_PALLAS_KERNELS", "str", "",
          "restrict WHICH Pallas kernels participate in HETU_TPU_PALLAS "
          "routing: comma list over {flash, norm, swiglu, rotary, quant, "
-         "paged_attn, paged_verify, sample, adam, paged_latent, "
+         "paged_attn, paged_verify, sample, paged_latent, "
          "chunk_attn}, or 'all' (default: "
          "empty = all) / 'none' — lets one kernel be bisected out "
          "without losing the rest",
@@ -463,7 +455,28 @@ REGISTRY: Dict[str, Flag] = {f.name: f for f in [
 ]}
 
 
+#: the names the accessors were asked for while `recorded_reads` is
+#: open (None: nobody is recording).  Every read of a flag goes through
+#: an accessor (the env-bypass lint, analysis/ast_lints.py) and the
+#: accessors read the environment at every call, so a build that never
+#: asked for a flag cannot depend on it: what the identity sweep
+#: (analysis/flag_identity.py) skips.
+_reads: Optional[set] = None
+
+
+@contextlib.contextmanager
+def recorded_reads() -> Iterator[set]:
+    global _reads
+    outer, _reads = _reads, set()
+    try:
+        yield _reads
+    finally:
+        _reads = outer
+
+
 def _lookup(name: str) -> Flag:
+    if _reads is not None:
+        _reads.add(name)
     try:
         return REGISTRY[name]
     except KeyError:
@@ -511,12 +524,6 @@ def identity_flags() -> Dict[str, str]:
     under systematic enforcement; there are no per-flag tests to write."""
     return {f.name: f.identity for f in REGISTRY.values()
             if f.identity is not None}
-
-
-def identity_contract_programs(name: str) -> Optional[Tuple[str, ...]]:
-    """The canonical programs `name`'s identity contract sweeps against
-    (None = every program) — the sweep's per-flag program axis."""
-    return _lookup(name).identity_programs
 
 
 def describe() -> str:

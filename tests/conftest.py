@@ -20,34 +20,41 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 
-#: The files that take 40 s or more of one worker, longest first (test-
-#: seconds summed a file from the junit the tier-1 command writes,
-#: /tmp/_t1.xml; read the ORDER, the seconds are a machine's).  Under
-#: `-n 6 --dist loadfile` a file is what one worker takes whole, and xdist
-#: hands the files out by their NUMBER of tests, most first, so a file of
-#: four tests and 140 s began among the last and the run ended on one
-#: worker while five idled.  Handed out longest first, the workers end
-#: within seconds of each other.  A file not named here follows in
-#: alphabetical order; a new long file belongs in the list.
-LONGEST_FIRST = (
-    "test_chip_compile", "test_benchmark_registry", "test_pallas_kernels",
-    "test_phi4_flash", "test_chunk_read_row", "test_bailing_hybrid",
-    "test_serving_view", "test_serving_decode", "test_comm", "test_lint",
-    "test_mimo_v2", "test_kimi_k2", "test_xing4", "test_deepseek_v32",
-    "test_serving_families",
-    "test_serving_pipeline", "test_jamba", "test_kda_scan_kernel",
-    "test_trinity", "test_latent_chunk_attention", "test_step_spans",
-    "test_pipeline_1f1b", "test_disagg", "test_serving_families_window",
-    "test_serving_families_latent", "test_moe_sort",
-    "test_chunk_attention", "test_generation", "test_serving",
-    "test_hlo_profile", "test_prefill_budget", "test_serving_trace",
-    "test_hetero_pp", "test_numerics", "test_trainer", "test_moe_dispatch",
-    "test_hetero_dp", "test_hetero_ring_tp", "test_serving_chaos",
-    "test_longcat", "test_benchmark_longcat", "test_benchmark_xing4",
-    "test_pipeline",
-    "test_llama", "test_chip_smoke", "test_flash_attention", "test_gpt",
-    "test_chip_compile_longcat", "test_chip_compile_xing4",
-    "test_benchmark_deepseek_v32", "test_chip_compile_deepseek_v32")
+#: Test-seconds a file (stem -> seconds summed over its cases), written
+#: whole from the junit of the tier-1 command by `write_durations` below
+#: (`python tests/conftest.py /tmp/_t1.xml`): data, never edited by
+#: hand.  Read the ORDER, the seconds are a machine's.
+DURATIONS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                         "durations.json")
+
+
+def write_durations(junit_xml: str, out: str = DURATIONS) -> dict:
+    """Sum a junit's test-seconds a file and write them, longest first."""
+    import collections
+    import json
+    import xml.etree.ElementTree as ET
+    seconds = collections.Counter()
+    for case in ET.parse(junit_xml).iter("testcase"):
+        stem = case.get("classname", "").split(".")[-1]
+        seconds[stem] += float(case.get("time", 0.0))
+    record = {stem: round(s, 1) for stem, s in seconds.most_common()}
+    with open(out, "w") as fh:
+        json.dump(record, fh, indent=0)
+        fh.write("\n")
+    return record
+
+
+def longest_first(stems, seconds):
+    """The order the files are handed out in.  Under `-n 6 --dist
+    loadfile` a file is what one worker takes whole, and xdist hands the
+    files out by their NUMBER of tests, most first, so a file of four
+    tests and 140 s began among the last and the run ended on one worker
+    while five idled.  Handed out longest first, the workers end within
+    seconds of each other.  A file the record has no seconds for goes
+    BEFORE every file it has: a new long file then starts early with no
+    edit anywhere, and a short one costs its few seconds there."""
+    return sorted(stems, key=lambda stem: (
+        stem in seconds, -seconds.get(stem, 0.0), stem))
 
 
 def pytest_configure(config):
@@ -58,8 +65,80 @@ def pytest_configure(config):
 
 
 def pytest_collection_modifyitems(items):
-    rank = {name: i for i, name in enumerate(LONGEST_FIRST)}
-    items.sort(key=lambda item: rank.get(item.path.stem, len(rank)))
+    import json
+    with open(DURATIONS) as fh:
+        seconds = json.load(fh)
+    order = longest_first({item.path.stem for item in items}, seconds)
+    rank = {stem: i for i, stem in enumerate(order)}
+    items.sort(key=lambda item: rank[item.path.stem])
+
+
+def _share_identical_compiles():
+    """One XLA:CPU compile per identical module a worker: a test builds
+    an engine or a Trainer of its own, so its programs are lowered anew,
+    and a third of a serving file's compile seconds (a fifth of its wall)
+    went to modules byte-identical to one the process had compiled
+    minutes before (641 compiles, 551 distinct, in test_chunk_read_row:
+    PR 60).  Tracing and lowering are the test's still; the executable
+    of the same text (debug positions apart) under the same options for
+    the same devices is handed out again, the last 512 of them kept.
+    Not for a module that calls back into Python (its text holds an
+    address), nor for another backend than the CPU (a described chip's
+    compiles are few and none repeats).  The hook is jax 0.9's; without
+    it the suite runs as it did."""
+    import collections
+    import hashlib
+    from jax._src import compiler
+    compile_and_load = getattr(compiler, "backend_compile_and_load", None)
+    if compile_and_load is None:
+        return
+    kept = collections.OrderedDict()
+
+    def shared(backend, module, executable_devices, options,
+               host_callbacks):
+        text = "" if host_callbacks or backend.platform != "cpu" else \
+            module.operation.get_asm(enable_debug_info=False)
+        if not text or "callback" in text:
+            return compile_and_load(backend, module, executable_devices,
+                                    options, host_callbacks)
+        key = (hashlib.sha256(text.encode()).digest(),
+               options.SerializeAsString(),
+               tuple(d.id for d in executable_devices))
+        if key in kept:
+            kept.move_to_end(key)
+        else:
+            kept[key] = compile_and_load(
+                backend, module, executable_devices, options,
+                host_callbacks)
+            if len(kept) > 512:
+                kept.popitem(last=False)
+        return kept[key]
+    compiler.backend_compile_and_load = shared
+
+
+_share_identical_compiles()
+
+
+def generate(model, params, input_ids, **kw):
+    """`models.generation.generate` as ONE program a prompt length: the
+    golden of the serving tests (`from conftest import generate`).  Run
+    op by op, its prefill and its scan were most of a golden test's
+    seconds (PR 46 found it in the family cases)."""
+    from hetu_tpu.models import generation
+    return jax.jit(lambda p, ids: generation.generate(
+        model, p, ids, **kw))(params, input_ids)
+
+
+@pytest.fixture(scope="session")
+def orbax_loaded():
+    """`orbax.checkpoint` loaded before a test that times heartbeats.
+    The package loads at a process's first save or restore (PR 56):
+    seconds, its extension modules' with the GIL held, 6.6 s on this
+    machine under load.  A worker whose first save fell inside a chaos
+    test sent no heartbeat for the 0.6-1.0 s its server allows and was
+    declared dead: one red test in each of two whole runs of PR 60, a
+    different test each time."""
+    import orbax.checkpoint  # noqa: F401
 
 
 @pytest.fixture(scope="session")
@@ -72,3 +151,8 @@ def devices():
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+if __name__ == "__main__":
+    import sys
+    write_durations(sys.argv[1])

@@ -13,6 +13,10 @@ from hetu_tpu.chaos import FaultPlan, FaultSpec
 from hetu_tpu.obs.metrics import get_registry
 from hetu_tpu.rpc import CoordinationClient, CoordinationServer
 
+# the first save of a process loads orbax, for seconds: before a heartbeat
+# is timed, not while (conftest.orbax_loaded)
+pytestmark = pytest.mark.usefixtures("orbax_loaded")
+
 
 @pytest.fixture(autouse=True)
 def _no_leftover_plan():

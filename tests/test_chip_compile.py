@@ -125,16 +125,6 @@ def _rotary(batch, seq, bwd=True):
     return (scalar_loss(fn) if bwd else fn), (q, q)
 
 
-def _adam(shape):
-    from hetu_tpu.ops.pallas.adam import adam_update
-
-    def fn(p, g, m, v):
-        return adam_update(p, g, m, v, 1e-4, 0.1, 0.05, b1=0.9, b2=0.95,
-                           eps=1e-8, weight_decay=0.1)
-    return fn, (spec(shape, BF16), spec(shape, F32), spec(shape, F32),
-                spec(shape, F32))
-
-
 def _paged(quant, n_kv, verify_c=0):
     """8 slots x 2048 positions of 16-token pages, as chip_smoke serves."""
     from hetu_tpu.ops.pallas.paged_attention import (paged_attention,
@@ -237,9 +227,6 @@ KERNEL_CASES = {
     "rotary_prompt_300": lambda: _rotary(2, 300, bwd=False),
     "rotary_prompt_1100": lambda: _rotary(1, 1100, bwd=False),
     "rotary_decode_step": lambda: _rotary(8, 1, bwd=False),
-    "adam_matrix": lambda: _adam((HIDDEN, 2, INTER)),
-    "adam_embedding": lambda: _adam((VOCAB, HIDDEN)),
-    "adam_vector": lambda: _adam((HIDDEN,)),
     "paged_attention_fp": lambda: _paged(False, HEADS),
     "paged_attention_fp_gqa": lambda: _paged(False, 8),
     "paged_attention_int8": lambda: _paged(True, HEADS),
@@ -410,16 +397,11 @@ def _train_step(strategy, batch=2):
 def _assert_every_train_kernel(trainer, compiled):
     """flash, norm, swiglu and rotary: each routed to Pallas by the shape
     gate (nothing forced), each a tpu_custom_call in the program.  The
-    AdamW update is XLA's (`ops/pallas.AUTO_KEEPS_XLA`, PR 39): no
-    instruction of the program carries the `pallas_adam` scope, so there
-    is no kernel and no `reshape`, `copy` or `transpose` of a leaf to
-    `[n/128, 128]` and back around one."""
+    AdamW update is XLA's (PR 39; the kernel went in PR 60): no route is
+    asked for it and no instruction of the program carries the
+    `pallas_adam` scope."""
     from chip_smoke import TRAIN_KERNELS, kernels_in
-    from hetu_tpu.ops.pallas import AUTO_KEEPS_XLA
     routes = dict(trainer.kernel_routes)
-    adam = routes.pop("adam")
-    assert adam["xla"] and not adam["pallas"], adam
-    assert list(adam["why"]) == [AUTO_KEEPS_XLA["adam"]], adam
     assert sorted(routes) == sorted(TRAIN_KERNELS), routes
     for name, rec in routes.items():
         assert rec["pallas"] and not rec["xla"], (name, rec)
@@ -1116,8 +1098,34 @@ def test_decode_program_leaves_no_product_without_a_scope(
                 and op not in hp._ALIASES]) < 0.08 * len(top)
 
 
+@pytest.fixture(scope="module")
+def internlm2_cell():
+    """kv_quant -> (the engine of the benchmark's serving cells,
+    InternLM2-1.8B at 32 slots and 2048 pages; name -> that program
+    compiled for one v5e), each built and compiled once for the module:
+    two tests read the exact pool's decode program."""
+    from hetu_tpu.models.llama import LlamaConfig
+    cfg = LlamaConfig(vocab_size=92544, hidden_size=2048,
+                      intermediate_size=8192, num_hidden_layers=24,
+                      num_attention_heads=16, num_key_value_heads=8,
+                      max_position_embeddings=32768, rope_theta=1e6,
+                      param_dtype=BF16)
+    kept = {}
+
+    def of(kv_quant="none"):
+        if kv_quant not in kept:
+            engine = _serving_engine(cfg, num_slots=32, num_pages=2048,
+                                     kv_quant=kv_quant)
+            programs = engine.lower_programs(sharding=ONE_CHIP)
+            compiled = {}
+            kept[kv_quant] = engine, lambda name: compiled.setdefault(
+                name, programs[name].compile())
+        return kept[kv_quant]
+    return of
+
+
 @pytest.mark.parametrize("kv_quant", ["none", "int8"])
-def test_decode_program_updates_the_pool_in_place(kv_quant):
+def test_decode_program_updates_the_pool_in_place(kv_quant, internlm2_cell):
     """The donated KV pool is ONE buffer from the decode program's
     argument to its result (PR 25).  As an xs -> ys of the layer scan it
     was sliced, stacked and copied whole three times a step (24 ms of a
@@ -1139,16 +1147,9 @@ def test_decode_program_updates_the_pool_in_place(kv_quant):
         which reads scales in a lane-padded layout: 1 MB a layer, and
         what `_paged_forward` says it does."""
     import re
-    from hetu_tpu.models.llama import LlamaConfig
-    cfg = LlamaConfig(vocab_size=92544, hidden_size=2048,
-                      intermediate_size=8192, num_hidden_layers=24,
-                      num_attention_heads=16, num_key_value_heads=8,
-                      max_position_embeddings=32768, rope_theta=1e6,
-                      param_dtype=BF16)
-    engine = _serving_engine(cfg, num_slots=32, num_pages=2048,
-                             kv_quant=kv_quant)
+    engine, program = internlm2_cell(kv_quant)
     pool = list(engine.pool.arrays.tree())
-    compiled = engine.lower_programs(sharding=ONE_CHIP)["decode"].compile()
+    compiled = program("decode")
     assert _took_paged_kernel(engine)
     pool_bytes = sum(a.size * a.dtype.itemsize for a in pool)
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 4
@@ -1178,7 +1179,8 @@ def test_decode_program_updates_the_pool_in_place(kv_quant):
     assert scatters == len(pool), scatters
 
 
-def test_serving_programs_move_no_bytes_a_layer_does_not_read():
+def test_serving_programs_move_no_bytes_a_layer_does_not_read(
+        internlm2_cell):
     """The InternLM2 serving cells' decode and chunk programs (PR 32).
 
     * The weights: a product takes its matrix out of the layers' stack
@@ -1193,19 +1195,11 @@ def test_serving_programs_move_no_bytes_a_layer_does_not_read():
       is a carry of the layer walk and donated: aliased from argument to
       result, and no second one among the temporaries."""
     import re
-    from hetu_tpu.models.llama import LlamaConfig
-    cfg = LlamaConfig(vocab_size=92544, hidden_size=2048,
-                      intermediate_size=8192, num_hidden_layers=24,
-                      num_attention_heads=16, num_key_value_heads=8,
-                      max_position_embeddings=32768, rope_theta=1e6,
-                      param_dtype=BF16)
-    engine = _serving_engine(cfg, num_slots=32, num_pages=2048)
+    engine, program = internlm2_cell()
     assert engine.relaid_weight_bytes == 2_013_265_920
     assert engine.kernel_routes["relaid_weight_bytes"] == 2_013_265_920
-    programs = engine.lower_programs(sharding=ONE_CHIP)
     assert _took_paged_kernel(engine)
-    compiled = {name: programs[name].compile()
-                for name in ("decode", "prefill_chunk")}
+    compiled = {name: program(name) for name in ("decode", "prefill_chunk")}
 
     # one layer's part of every stacked matrix: [h, w], or [1, h, w]
     layer = set()

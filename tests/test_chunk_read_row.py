@@ -23,6 +23,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+from conftest import generate  # noqa: E402
 from hetu_tpu.models import generation as gen  # noqa: E402
 from hetu_tpu.models.cache_contract import cache_contract  # noqa: E402
 from hetu_tpu.obs import hlo_profile as hp  # noqa: E402
@@ -242,8 +243,8 @@ def test_llama_tokens_are_generates_and_a_prefix_hit_leaves_a_row(
     for req in reqs:
         _holds_against_reference(fam, cfg, params, req, got[req.rid])
         if not sampled:
-            gold = gen.generate(model, params, jnp.asarray(req.prompt[None]),
-                                max_new_tokens=req.max_new_tokens)
+            gold = generate(model, params, jnp.asarray(req.prompt[None]),
+                            max_new_tokens=req.max_new_tokens)
             assert got[req.rid] == list(
                 np.asarray(gold)[0, req.prompt_len:])
 

@@ -19,6 +19,10 @@ from hetu_tpu.obs.runlog import RunLog
 from hetu_tpu.rpc.client import CoordinationClient, fetch_cluster_snapshot
 from hetu_tpu.rpc.server import CoordinationServer
 
+# the first save of a process loads orbax, for seconds: before a heartbeat
+# is timed, not while (conftest.orbax_loaded)
+pytestmark = pytest.mark.usefixtures("orbax_loaded")
+
 
 @pytest.fixture(autouse=True)
 def _no_leftover_plan():

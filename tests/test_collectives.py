@@ -274,6 +274,7 @@ def _sp_program(mesh):
 def test_convert_sp_compress_cuts_bytes_3x(monkeypatch):
     """Acceptance: obs.comm reports >=3x fewer bytes on the SP
     all-gather/reduce-scatter path at int8 vs fp32 (real lowered HLO)."""
+    from hetu_tpu.analysis.flag_identity import fingerprint
     from hetu_tpu.obs.comm import collective_report
     mesh = create_mesh(MeshConfig(tp=4))
     x = jnp.zeros((4, 256, 64), jnp.float32)
@@ -289,7 +290,8 @@ def test_convert_sp_compress_cuts_bytes_3x(monkeypatch):
 
     rep32, txt_unset = bytes_under(None)
     rep_none, txt_none = bytes_under("none")
-    assert txt_unset == txt_none   # flag "none" is HLO-byte-identical
+    # flag "none" compiles to the same program
+    assert fingerprint(txt_unset) == fingerprint(txt_none)
     rep8, _ = bytes_under("int8")
     rep4, _ = bytes_under("int4")
     assert rep32["total_wire_bytes"] >= 3.0 * rep8["total_wire_bytes"], (

@@ -16,6 +16,10 @@ from hetu_tpu.models.llama import LlamaConfig, LlamaLMHeadModel
 from hetu_tpu.parallel import ParallelStrategy
 from hetu_tpu.rpc import CoordinationClient, CoordinationServer
 
+# the first save of a process loads orbax, for seconds: before a heartbeat
+# is timed, not while (conftest.orbax_loaded)
+pytestmark = pytest.mark.usefixtures("orbax_loaded")
+
 
 @pytest.mark.slow
 def test_elastic_survives_worker_loss(tmp_path):

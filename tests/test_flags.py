@@ -111,7 +111,7 @@ def test_identity_contract_table():
     an unsettable value would sweep vacuously), routing flags carry
     their neutral value, analysis flags carry "1", and the known
     contracted surface never silently shrinks — the flag-identity sweep
-    (tests/test_lint.py) enforces the semantics; this pins the table."""
+    (tests/test_flag_identity.py) enforces the semantics; this pins the table."""
     table = flags.identity_flags()
     for name, value in table.items():
         f = flags.REGISTRY[name]
@@ -127,11 +127,8 @@ def test_identity_contract_table():
     # the serving flight recorder is host-side only: ON must be a no-op
     # for the compiled programs.  Since the distributed-tracing layer
     # (PR 20) it also stamps clock/tier/replica trace context and the
-    # hedge_withdrawn terminal — still pure bookkeeping, and its reads
-    # are serving-confined, so the contract sweeps the decode program
+    # hedge_withdrawn terminal — still pure bookkeeping
     assert table["HETU_TPU_SERVE_TRACE"] == "1"
-    assert flags.identity_contract_programs(
-        "HETU_TPU_SERVE_TRACE") == ("decode",)
     # the numerics observatory changes the traced program when ON (the
     # stats ride the step outputs), so its contract is the OFF value
     assert table["HETU_TPU_NUMERICS"] == "0"
@@ -139,8 +136,7 @@ def test_identity_contract_table():
     # so its contract is the GSPMD default
     assert table["HETU_TPU_MOE_DISPATCH"] == "gspmd"
     # the decoding subsystem: every new serve/spec flag is contracted
-    # at its off/neutral value, and — being serving-confined reads —
-    # each sweeps the decode program (identity_programs)
+    # at its off/neutral value
     assert table["HETU_TPU_SERVE_SAMPLE"] == "0"
     assert table["HETU_TPU_SPEC_DECODE"] == "none"
     assert table["HETU_TPU_SPEC_K"] == "4"
@@ -150,36 +146,21 @@ def test_identity_contract_table():
     # identity values (host-side policy only; decode program unchanged)
     assert table["HETU_TPU_SERVE_QUOTAS"] == ""
     assert table["HETU_TPU_RUNLOG_SERVE_SAMPLE"] == "1"
-    for name in ("HETU_TPU_SERVE_SAMPLE", "HETU_TPU_SPEC_DECODE",
-                 "HETU_TPU_SPEC_K", "HETU_TPU_SERVE_PREFIX_CACHE",
-                 "HETU_TPU_SERVE_PREFIX_PAGES",
-                 "HETU_TPU_SERVE_PREEMPT", "HETU_TPU_SERVE_QUOTAS",
-                 "HETU_TPU_RUNLOG_SERVE_SAMPLE"):
-        assert flags.identity_contract_programs(name) == ("decode",)
     # the serving fault-tolerance flags: all host-side policy, each
     # contracted at a SETTABLE value (retry sweeps a nonzero budget —
     # the budget only gates requeue bookkeeping, never the program)
-    # and restricted to the decode program
     assert table["HETU_TPU_SERVE_RETRY"] == "3"
     assert table["HETU_TPU_SERVE_DEADLINE"] == "1"
     assert table["HETU_TPU_SERVE_BROWNOUT"] == "1"
     assert table["HETU_TPU_SERVE_KV_REPAGE"] == "1"
-    for name in ("HETU_TPU_SERVE_RETRY", "HETU_TPU_SERVE_DEADLINE",
-                 "HETU_TPU_SERVE_BROWNOUT", "HETU_TPU_SERVE_KV_REPAGE"):
-        assert flags.identity_contract_programs(name) == ("decode",)
     # the disaggregated fleet + frontend: all host-side orchestration
     # (the tiers run the engine's own chunk/write/decode programs), so
     # each is contracted at an ON value — disagg enabled, int8 wire,
-    # hedging armed — and restricted to the decode program.  The
-    # TOKEN-identity half (exact wire only) lives in tests/test_disagg.py
+    # hedging armed.  The TOKEN-identity half (exact wire only) lives in
+    # tests/test_disagg.py
     assert table["HETU_TPU_SERVE_DISAGG"] == "1"
     assert table["HETU_TPU_SERVE_SHIP_QUANT"] == "int8"
     assert table["HETU_TPU_SERVE_HEDGE"] == "2"
-    for name in ("HETU_TPU_SERVE_DISAGG", "HETU_TPU_SERVE_SHIP_QUANT",
-                 "HETU_TPU_SERVE_HEDGE"):
-        assert flags.identity_contract_programs(name) == ("decode",)
-    # unrestricted contracts sweep everything
-    assert flags.identity_contract_programs("HETU_TPU_PALLAS") is None
     assert len(table) >= 29
     # flags with NO contract must stay contract-free: these genuinely
     # change program shapes, so an identity entry would be a lie the

@@ -217,13 +217,18 @@ def test_peak_hbm_within_20pct_of_xla(kw):
 
 
 def test_peak_hbm_remat_reduces_working_set():
-    """Remat awareness: the same model without remat holds a larger
-    estimated working set (full activations live into the backward)."""
-    with_remat = hp.peak_hbm_estimate(_compiled(L=2, scan=False,
+    """Remat awareness: the same scanned stack without remat holds a
+    larger estimated working set (every layer's residuals are stacked
+    for the backward; with remat, the layers' inputs alone).  Scanned,
+    because there the saving is the program's structure: unrolled, it is
+    the compiler's schedule, and XLA:CPU's own temp arena is no smaller
+    with remat at these sizes."""
+    with_remat = hp.peak_hbm_estimate(_compiled(L=4, scan=True,
                                                 remat=True))
-    without = hp.peak_hbm_estimate(_compiled(L=2, scan=False,
+    without = hp.peak_hbm_estimate(_compiled(L=4, scan=True,
                                              remat=False))
-    assert without["temp_peak_bytes"] > with_remat["temp_peak_bytes"]
+    assert without["temp_peak_bytes"] > 2 * with_remat["temp_peak_bytes"]
+    assert without["xla_temp_bytes"] > 2 * with_remat["xla_temp_bytes"]
 
 
 def test_analytic_peak_hbm_model():
@@ -428,12 +433,13 @@ def test_trainer_profile_record_flag_gated(tmp_path, monkeypatch):
     # HLO byte-identity: the flag changes analysis, never the program
     hb = _tiny_batch()
     key = tuple(sorted((k, tuple(v.shape)) for k, v in hb.items()))
+    from hetu_tpu.analysis.flag_identity import fingerprint
     with_flag = tr._compiled_for_shape(hb, key).as_text()
     monkeypatch.delenv("HETU_TPU_PROFILE")
     tr2 = _tiny_trainer(tmp_path, monkeypatch)
     tr2.build()
     without = tr2._compiled_for_shape(hb, key).as_text()
-    assert with_flag == without
+    assert fingerprint(with_flag) == fingerprint(without)
 
 
 def test_trainer_budget_check_and_enforce(tmp_path, monkeypatch):

@@ -210,12 +210,83 @@ def test_line_wire_bytes_composes():
 # FLOPs + module contracts
 # ---------------------------------------------------------------------------
 
-def test_dot_flops():
-    line = ("%dot.1 = f32[8,16]{1,0} dot(f32[8,32]{1,0} %a, "
-            "f32[32,16]{1,0} %b), lhs_contracting_dims={1}, "
-            "rhs_contracting_dims={0}")
-    assert H.dot_flops(line) == 2.0 * (8 * 16) * 32
-    assert H.dot_flops("%a = f32[8]{0} add(%x, %y)") == 0.0
+_DOT_ATTRS = "lhs_contracting_dims={1}, rhs_contracting_dims={0}"
+
+
+@pytest.mark.parametrize("dot", [
+    # an earlier jax printed an operand's shape before its name
+    "dot(f32[8,32]{1,0} %a, f32[32,16]{1,0} %b)",
+    # jax 0.9 (XLA:CPU and the TPU compiler) prints the name alone
+    "dot(%a, %b)",
+], ids=["operand_shapes", "operand_names"])
+def test_dot_flops(dot):
+    lines = ["%a = f32[8,32]{1,0} parameter(0)",
+             "%b = f32[32,16]{1,0} parameter(1)",
+             f"ROOT %dot.1 = f32[8,16]{{1,0}} {dot}, {_DOT_ATTRS}"]
+    defs = H.definitions({"main": lines})
+    assert H.dot_flops(lines[2], defs) == 2.0 * (8 * 16) * 32
+    assert H.dot_flops("%s = f32[8]{0} add(%x, %y)", defs) == 0.0
+
+
+def test_cond_trip_count_follows_a_fused_compare():
+    """XLA:CPU wraps a condition's lone compare in a fusion: the bound is
+    the fusion's operand that the compare's right parameter stands for."""
+    comps = H.split_computations("""
+%wrapped_compare_computation (param_0.2: s32[], param_1.2: s32[]) -> pred[] {
+  %param_0.2 = s32[] parameter(0)
+  %param_1.2 = s32[] parameter(1)
+  ROOT %lt.3 = pred[] compare(%param_0.2, %param_1.2), direction=LT
+}
+
+%cond (param.1: (s32[], f32[256])) -> pred[] {
+  %param.1 = (s32[], f32[256]{0}) parameter(0)
+  %constant.7 = s32[] constant(8)
+  %get-tuple-element.12 = s32[] get-tuple-element(%param.1), index=0
+  ROOT %wrapped_compare = pred[] fusion(%get-tuple-element.12, %constant.7), kind=kLoop, calls=%wrapped_compare_computation
+}
+""")
+    assert H.cond_trip_count(comps["cond"], comps) == 8
+    assert H.cond_trip_count(comps["cond"]) is None   # the callee unseen
+    assert H.call_operands(comps["cond"][-1]) == [
+        "get-tuple-element.12", "constant.7"]
+
+
+_POSITIONS = """HloModule jit_f, is_scheduled=true
+
+FileNames
+1 "/root/repo/tests/test_comm.py"
+
+FunctionNames
+1 "test_a"
+
+FileLocations
+1 {file_name_id=1 function_name_id=1 line=%d end_line=%d column=4 end_column=49}
+
+StackFrames
+1 {file_location_id=1 parent_frame_id=1}
+
+
+ENTRY %%main.1 (a.1: f32[8]) -> f32[8] {
+  %%a.1 = f32[8]{0} parameter(0), metadata={op_name="a"}
+  ROOT %%r.1 = f32[8]{0} %s(%%a.1), metadata={op_name="jit(f)/r" stack_frame_id=1}
+}
+"""
+
+
+def test_without_source_positions_keeps_the_program_alone():
+    """Two lowers of one program from two lines of a file compare equal;
+    two programs from one line do not; a text with no positions (a traced
+    module's) comes back as it went in."""
+    at_191 = _POSITIONS % (191, 191, "negate")
+    at_192 = _POSITIONS % (192, 192, "negate")
+    other = _POSITIONS % (191, 191, "exponential")
+    assert at_191 != at_192
+    bare = H.without_source_positions(at_191)
+    assert bare == H.without_source_positions(at_192)
+    assert bare != H.without_source_positions(other)
+    assert "line=" not in bare and "stack_frame_id" not in bare
+    assert 'negate(%a.1), metadata={op_name="jit(f)/r"}' in bare
+    assert H.without_source_positions(bare) == bare
 
 
 def test_donated_parameters_and_entry_parameters():

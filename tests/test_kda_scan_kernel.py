@@ -253,5 +253,4 @@ def test_a_shape_the_gate_refuses_keeps_the_composition(rng, monkeypatch):
     rec = routes["kda_scan"]
     assert rec["xla"] == 2 and not rec["pallas"]
     assert all(w.startswith("shape gate: ") for w in rec["why"]), rec
-    assert "kda_scan" in pk.KERNEL_NAMES and "kda_scan" not in \
-        pk.AUTO_KEEPS_XLA
+    assert "kda_scan" in pk.KERNEL_NAMES

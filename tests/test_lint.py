@@ -1,9 +1,8 @@
 """Graph-contract linter (hetu_tpu/analysis, tools_lint.py,
 docs/static_analysis.md): every HLO lint against its positive/negative
 fixture pair, every AST lint against synthetic offenders, the allowlist
-policy, the flag-identity sweep (coverage of 100% of registered
-contracts for BOTH canonical programs, and that a broken contract is
-DETECTED), the HETU_TPU_LINT per-compile trainer hook, and the CLI
+policy (the flag-identity sweep has tests/test_flag_identity.py, a
+worker of its own), the HETU_TPU_LINT per-compile trainer hook, and the CLI
 acceptance runs — incl. `--self` as the tier-1 gate: this suite failing
 means a convention violation landed."""
 import json
@@ -390,86 +389,6 @@ def test_cli_dtype_flag(capsys):
         "--expected-dtype", "bf16")
     assert rc == 0  # warnings never fail
     assert "[dtype-drift]" in out
-
-
-# ---------------------------------------------------------------------------
-# flag-identity sweep
-# ---------------------------------------------------------------------------
-
-def test_identity_sweep_rejects_unknown_flag():
-    from hetu_tpu.analysis.flag_identity import identity_sweep
-    with pytest.raises(ValueError, match="no identity contract"):
-        identity_sweep(only_flags=["HETU_TPU_RUNLOG"])
-
-
-def test_identity_sweep_detects_a_broken_contract(monkeypatch):
-    """A contract that genuinely changes the program must be CAUGHT:
-    temporarily register identity=\"2\" on HETU_TPU_SERVE_SLOTS (slots
-    reshape the decode program) and watch the sweep fail it."""
-    import dataclasses
-    from hetu_tpu.analysis.flag_identity import identity_sweep
-    from hetu_tpu.utils import flags
-    fake = dataclasses.replace(flags.REGISTRY["HETU_TPU_SERVE_SLOTS"],
-                               identity="2")
-    monkeypatch.setitem(flags.REGISTRY, "HETU_TPU_SERVE_SLOTS", fake)
-    sweep = identity_sweep(only_flags=["HETU_TPU_SERVE_SLOTS"],
-                           programs=["decode"])
-    errors = [f for f in sweep["findings"] if f.severity == "error"]
-    assert len(errors) == 1
-    assert errors[0].lint == "flag-identity"
-    assert "HETU_TPU_SERVE_SLOTS" in errors[0].message
-    assert not sweep["rows"][0]["ok"]
-
-
-def test_identity_sweep_covers_every_contract_and_holds():
-    """Acceptance: 100% of registered byte-identity flags, each against
-    its contracted program set — ALL FOUR canonical programs (train,
-    serving decode, the MoE forward+backward added with the numerics
-    observatory, and the ep=2 expert-parallel MoE step added with the
-    explicit dispatch) by default, the decode program alone for
-    serving-confined flags (Flag.identity_programs: their reads are
-    structurally pinned to hetu_tpu/serving by the env-bypass lint +
-    the serving package never importing from the root, so a training
-    lower carries no information) — zero violations: the systematic
-    replacement for the per-flag hand-written byte-identity tests."""
-    from hetu_tpu.analysis.flag_identity import identity_sweep
-    from hetu_tpu.utils import flags
-    table = flags.identity_flags()
-    # the surface under contract — shrinkage is a failure
-    assert set(table) >= {
-        "HETU_TPU_GRAD_COMPRESS", "HETU_TPU_SP_COMPRESS",
-        "HETU_TPU_ZERO_COMPRESS", "HETU_TPU_COMM_TOPOLOGY",
-        "HETU_TPU_PALLAS", "HETU_TPU_PALLAS_KERNELS",
-        "HETU_TPU_KV_QUANT", "HETU_TPU_PROFILE",
-        "HETU_TPU_COMM_ANALYZE", "HETU_TPU_LINT",
-        "HETU_TPU_NUMERICS", "HETU_TPU_MOE_DISPATCH",
-        # the PR 15 decoding subsystem (decode-program contracts)
-        "HETU_TPU_SERVE_SAMPLE", "HETU_TPU_SPEC_DECODE",
-        "HETU_TPU_SPEC_K", "HETU_TPU_SERVE_PREFIX_CACHE",
-        "HETU_TPU_SERVE_PREFIX_PAGES", "HETU_TPU_SERVE_PREEMPT",
-        # the distributed-tracing flight recorder (PR 20: clock basis,
-        # tier/replica trace context, hedge_withdrawn terminals — all
-        # host-side, decode-program contract)
-        "HETU_TPU_SERVE_TRACE"}
-    all_programs = ("train", "decode", "moe", "moe_ep")
-    want = set()
-    for f in table:
-        progs = flags.identity_contract_programs(f)
-        for p in (all_programs if progs is None else progs):
-            want.add((f, p))
-    # a restricted contract may only restrict to real programs, and
-    # every serving-confined flag still sweeps the decode program
-    for f in table:
-        progs = flags.identity_contract_programs(f)
-        if progs is not None:
-            assert set(progs) <= set(all_programs), (f, progs)
-            assert "decode" in progs, f
-    sweep = identity_sweep()
-    covered = {(r["flag"], r["program"]) for r in sweep["rows"]}
-    assert covered == want
-    violations = [r for r in sweep["rows"] if not r["ok"]]
-    assert violations == [], violations
-    assert not any(f.severity == "error" for f in sweep["findings"])
 
 
 # ---------------------------------------------------------------------------

@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 import hetu_tpu as ht
 from hetu_tpu import nn, optim
@@ -59,6 +60,43 @@ def test_adamw_converges_and_zero_shardings():
     from hetu_tpu.optim.optimizer import zero_shardings
     z = zero_shardings(m.shardings(mesh), m.abstract_params(), mesh, "dp")
     assert z["weight"].spec == jax.sharding.PartitionSpec("dp", None)
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((8, 128), jnp.float32), ((256,), jnp.bfloat16), ((5,), jnp.float32),
+], ids=["matrix_f32", "vector_bf16", "ragged_f32"])
+def test_adamw_update_is_the_reference_update(shape, dtype):
+    """Two steps of `AdamW.update` (the bias corrections move) against
+    the update written out in numpy float64: decoupled weight decay, f32
+    moments whatever the leaf's dtype, the new leaf rounded once."""
+    rng = np.random.default_rng(3)
+    p = jnp.asarray(rng.normal(size=shape), dtype)
+    g = jnp.asarray(rng.normal(size=shape) * 0.1, dtype)
+    lr, b1, b2, eps, wd = 1e-2, 0.9, 0.95, 1e-8, 0.01
+    opt = optim.AdamW(lr=lr, b1=b1, b2=b2, eps=eps, weight_decay=wd)
+    state = opt.init({"w": p})
+    ref_p, ref_g = np.asarray(p, np.float64), np.asarray(g, np.float64)
+    m = v = np.zeros(shape)
+    params = {"w": p}
+    for step in (1, 2):
+        params, state = opt.update({"w": g}, state, params)
+        m = b1 * m + (1 - b1) * ref_g
+        v = b2 * v + (1 - b2) * ref_g ** 2
+        ref_p = ref_p - lr * (
+            (m / (1 - b1 ** step)) / (np.sqrt(v / (1 - b2 ** step)) + eps)
+            + wd * ref_p)
+        assert params["w"].dtype == dtype
+        assert state["m"]["w"].dtype == state["v"]["w"].dtype == jnp.float32
+        tol = 1e-5 if dtype == jnp.float32 else 1e-2   # one bf16 rounding
+        np.testing.assert_allclose(np.asarray(params["w"], np.float64),
+                                   ref_p, rtol=tol, atol=tol)
+        np.testing.assert_allclose(np.asarray(state["m"]["w"]), m,
+                                   rtol=1e-5, atol=1e-7)
+        np.testing.assert_allclose(np.asarray(state["v"]["w"]), v,
+                                   rtol=1e-5, atol=1e-9)
+        # the bf16 leaf is carried rounded, as the step carries it
+        ref_p = np.asarray(params["w"], np.float64)
+    assert int(state["step"]) == 2
 
 
 def test_grad_scaler_dynamics():

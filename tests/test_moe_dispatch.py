@@ -458,7 +458,7 @@ def test_serving_moe_decode_matches_generate(tiny_moe_llama):
     """MoE decode through the engine: token-for-token vs sequential
     generate() (the continuous-batching goldens extend to MoE)."""
     from hetu_tpu import serving
-    from hetu_tpu.models.generation import generate
+    from conftest import generate
     from hetu_tpu.obs.metrics import MetricsRegistry
     from hetu_tpu.serving.request import Request
     model, params = tiny_moe_llama
@@ -480,7 +480,7 @@ def test_serving_resident_int8_experts(tiny_moe_llama):
     DEQUANTIZED weights (quantize-once determinism), the resident-bytes
     gauges land (~3.9x), and the reshard hook is refused."""
     from hetu_tpu import serving
-    from hetu_tpu.models.generation import generate
+    from conftest import generate
     from hetu_tpu.obs.metrics import MetricsRegistry
     from hetu_tpu.serving.experts import (dequantize_expert_tree,
                                           quantize_expert_tree)

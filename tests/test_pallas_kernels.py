@@ -749,18 +749,6 @@ def test_gate_drift_sample(hw):
         psample.fused_sample, h, w, words, t, k, p)
 
 
-@pytest.mark.parametrize("shape", [
-    (8, 128), (256,), (2, 3, 128), (3, 100), (5,),
-])
-def test_gate_drift_adam(shape):
-    from hetu_tpu.ops.pallas import adam as padam
-    x = jnp.zeros(shape, jnp.float32)
-    assert padam.compatible(shape) == _accepts(
-        lambda p, g, m, v: padam.adam_update(
-            p, g, m, v, 1e-3, 0.5, 0.5, b1=0.9, b2=0.95, eps=1e-8,
-            weight_decay=0.01), x, x, x, x)
-
-
 @pytest.mark.parametrize("sq,sk,d", [
     (256, 256, 128), (256, 256, 64), (100, 256, 128), (8, 8, 128),
 ])
@@ -947,9 +935,9 @@ def test_flash_refuses_a_layout_that_shards_the_sequence(devices):
 
 def test_trainer_runs_every_kernel_per_shard(devices, monkeypatch):
     """The whole step under dp2 x tp2 + SP + ZeRO with every kernel forced
-    on — flash, norm, swiglu, rotary per shard of the activations, adam
-    per shard of the optimizer state — tracks the one-device step, and
-    the Trainer says which kernels the plan runs."""
+    on — flash, norm, swiglu, rotary per shard of the activations —
+    tracks the one-device step, and the Trainer says which kernels the
+    plan runs."""
     from hetu_tpu.engine.trainer import Trainer
     from hetu_tpu.engine.trainer_config import TrainingConfig
     from hetu_tpu.models.llama import LlamaConfig, LlamaLMHeadModel
@@ -971,7 +959,7 @@ def test_trainer_runs_every_kernel_per_shard(devices, monkeypatch):
         losses[name] = [float(trainer.train_step(batch)["loss"])
                         for _ in range(3)]
         routes = trainer.kernel_routes
-        assert sorted(routes) == ["adam", "flash", "norm", "rotary", "swiglu"]
+        assert sorted(routes) == ["flash", "norm", "rotary", "swiglu"]
         assert all(r["pallas"] and not r["xla"] for r in routes.values())
         assert ("manual_computation" in trainer.lowered_step(batch)) == (
             name == "sharded")
@@ -1008,34 +996,6 @@ def test_routes_are_recorded_with_their_reasons(monkeypatch, devices):
     assert sum(rec["why"].values()) == 5
 
 
-@pytest.mark.parametrize("flag,routed", [(None, False), ("1", True)])
-def test_auto_leaves_adam_to_xla_on_a_tpu(monkeypatch, flag, routed):
-    """`auto` on a TPU backend keeps XLA's chain for the AdamW update and
-    says why (`AUTO_KEEPS_XLA`: the chip read the chain faster in both
-    train cells, PR 39), while a kernel not listed there is still taken;
-    the forced flag routes the kernel as before."""
-    from hetu_tpu.ops import pallas as pk
-    from hetu_tpu.ops.pallas import adam as padam
-    if flag is None:
-        monkeypatch.delenv("HETU_TPU_PALLAS", raising=False)
-    else:
-        monkeypatch.setenv("HETU_TPU_PALLAS", flag)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    shape = (8, 128)
-    assert padam.compatible(shape)
-    with record_routes() as log:
-        assert pk.resolve_route("adam", padam.check_shapes,
-                                *(shape,) * 4) is routed
-        g = jnp.zeros((2, 128, 512), jnp.float32)
-        jax.eval_shape(lambda: ops.swiglu(g, g))
-    assert log["swiglu"]["pallas"] == 1
-    assert (log["adam"]["pallas"], log["adam"]["xla"]) == (routed,
-                                                           not routed)
-    assert list(log["adam"]["why"]) == [
-        "forced on by HETU_TPU_PALLAS=1" if routed
-        else pk.AUTO_KEEPS_XLA["adam"]]
-
-
 def test_fused_sample_token_identity(monkeypatch):
     """The fused sampling epilogue picks the IDENTICAL tokens as the XLA
     path (both consume the same hash-Gumbel words and the same exact
@@ -1066,41 +1026,6 @@ def test_fused_sample_token_identity(monkeypatch):
     logits = np.asarray(hidden @ w)
     np.testing.assert_array_equal(np.asarray(out)[[0, 3]],
                                   logits.argmax(-1)[[0, 3]])
-
-
-def test_adam_kernel_parity(monkeypatch):
-    """Fused AdamW matches the XLA chain over two steps (the bias
-    corrections move) on lane-aligned f32 and bf16 leaves to 1 ulp —
-    the expression is identical but the compiled kernel body may
-    contract multiply-adds into FMAs where the op-by-op chain doesn't.
-    Ragged leaves keep the XLA path under auto routing and raise loudly
-    under the forced flag (the repo-wide forced-route convention)."""
-    from hetu_tpu.optim.optimizer import AdamW
-    from hetu_tpu.ops.pallas import adam as padam
-    params = {"w": _rand((8, 128), 1),
-              "e": _rand((256,), 2).astype(jnp.bfloat16)}
-    grads = {"w": _rand((8, 128), 3) * 0.1,
-             "e": (_rand((256,), 4) * 0.1).astype(jnp.bfloat16)}
-    opt = AdamW(lr=1e-2, weight_decay=0.01)
-    monkeypatch.setenv("HETU_TPU_PALLAS", "0")
-    p0, s0 = opt.update(grads, opt.init(params), params)
-    p0, s0 = opt.update(grads, s0, p0)
-    monkeypatch.setenv("HETU_TPU_PALLAS", "1")
-    monkeypatch.setenv("HETU_TPU_PALLAS_KERNELS", "adam")
-    p1, s1 = opt.update(grads, opt.init(params), params)
-    p1, s1 = opt.update(grads, s1, p1)
-    for a, b in zip(jax.tree.leaves((p0, s0["m"], s0["v"])),
-                    jax.tree.leaves((p1, s1["m"], s1["v"]))):
-        np.testing.assert_allclose(np.asarray(a, np.float32),
-                                   np.asarray(b, np.float32),
-                                   rtol=3e-7, atol=1e-8)
-    # ragged leaf: auto gate says no, forced flag raises loudly
-    assert not padam.compatible((5,))
-    with pytest.raises(ValueError, match="lane-aligned"):
-        opt.update({"w": grads["w"], "e": grads["e"],
-                    "b": _rand((5,), 5)},
-                   opt.init({**params, "b": _rand((5,), 5)}),
-                   {**params, "b": _rand((5,), 5)})
 
 
 # ---------------------------------------------------------------------------
@@ -1422,8 +1347,7 @@ def test_kernel_traffic_acceptance():
                                 q_heads=12, kv_heads=12, head_dim=128)
     assert set(rep) == {"norm", "swiglu", "rotary", "flash", "quant",
                         "paged_attn", "paged_attn_int8",
-                        "paged_attn_int4", "paged_verify", "sample",
-                        "adam"}
+                        "paged_attn_int4", "paged_verify", "sample"}
     for r in rep.values():
         assert r["fused_bytes"] > 0
         assert r["unfused_bytes"] > r["fused_bytes"]
@@ -1449,7 +1373,7 @@ def test_bench_detail_kernels_record():
     assert set(rec) == {"norm", "swiglu", "rotary", "flash", "quant",
                         "paged_attn", "paged_attn_int8",
                         "paged_attn_int4", "paged_verify", "sample",
-                        "adam", "fused_verify_chain"}
+                        "fused_verify_chain"}
     assert rec["norm"]["reduction"] >= 3.0
     assert rec["paged_attn"]["reduction"] >= 3.0
     assert rec["paged_attn_int8"]["reduction"] >= 3.0

@@ -211,8 +211,7 @@ def test_a_shape_the_gate_refuses_keeps_the_composition(rng, monkeypatch):
         "shape gate passes",
         "shape gate: 100 rows are not a multiple of the kernel's 128 "
         "positions"], rec
-    assert "selective_scan" in pallas.KERNEL_NAMES \
-        and "selective_scan" not in pallas.AUTO_KEEPS_XLA
+    assert "selective_scan" in pallas.KERNEL_NAMES
 
 
 def test_grad_through_the_mixer_is_the_compositions(rng, monkeypatch):

@@ -9,8 +9,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from conftest import generate
 from hetu_tpu import serving
-from hetu_tpu.models.generation import generate, prefill, decode_step
+from hetu_tpu.models.generation import prefill, decode_step
 from hetu_tpu.models.llama import LlamaConfig, LlamaLMHeadModel
 from hetu_tpu.obs.metrics import MetricsRegistry
 from hetu_tpu.obs.runlog import RunLog

@@ -11,8 +11,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from conftest import generate
 from hetu_tpu import serving
-from hetu_tpu.models.generation import generate
 from hetu_tpu.models.llama import LlamaConfig, LlamaLMHeadModel
 from hetu_tpu.obs.metrics import MetricsRegistry
 from hetu_tpu.obs.runlog import RunLog

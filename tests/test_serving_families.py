@@ -9,8 +9,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from conftest import generate
 from hetu_tpu.models.cache_contract import KVAttention
-from hetu_tpu.models.generation import generate
 from hetu_tpu.models.llama import LlamaConfig, LlamaLMHeadModel
 from hetu_tpu.obs.metrics import MetricsRegistry
 from hetu_tpu.serving.request import Request
@@ -359,9 +359,8 @@ def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
                 and family != "hooks":
             # (one program a prompt length: run op by op, `generate()`'s
             # prefill was most of these cases' seconds)
-            gold = jax.jit(lambda p, ids, n=req.max_new_tokens: generate(
-                model, p, ids, max_new_tokens=n))(
-                    params, jnp.asarray(req.prompt[None]))
+            gold = generate(model, params, jnp.asarray(req.prompt[None]),
+                            max_new_tokens=req.max_new_tokens)
             assert list(toks) == list(np.asarray(gold)[0, req.prompt_len:])
     eng.scheduler.check_invariants()
     assert eng.pool.free_count == eng.pool.num_pages

@@ -27,7 +27,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from hetu_tpu import serving  # noqa: E402
-from hetu_tpu.models.generation import generate  # noqa: E402
+from conftest import generate  # noqa: E402
 from hetu_tpu.models.llama import LlamaConfig, LlamaLMHeadModel  # noqa: E402
 from hetu_tpu.obs.metrics import MetricsRegistry  # noqa: E402
 from hetu_tpu.serving.request import SamplingParams, SLOClass  # noqa: E402

@@ -19,8 +19,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from conftest import generate
 from hetu_tpu import ops, serving
-from hetu_tpu.models.generation import (extend_cache, generate, init_cache,
+from hetu_tpu.models.generation import (extend_cache, init_cache,
                                         prefill, verify_step_slots)
 from hetu_tpu.models.llama import LlamaConfig, LlamaLMHeadModel
 from hetu_tpu.models.llama.model import LlamaAttention, LlamaMLP

@@ -51,7 +51,7 @@ def main(argv=None) -> int:
                     help="print one kernel's unfused op chain (norm, "
                          "swiglu, rotary, quant, flash, paged_attn, "
                          "paged_attn_int8, paged_attn_int4, "
-                         "paged_verify, sample, adam)")
+                         "paged_verify, sample)")
     args = ap.parse_args(argv)
 
     if args.chain:
@@ -85,7 +85,6 @@ def main(argv=None) -> int:
                 quant="int8"),
             "sample": lambda: t.sample_traffic(
                 8 * 5, cfg.hidden_size, cfg.vocab_size),
-            "adam": lambda: t.adam_traffic(cfg.num_params()),
         }
         if args.chain not in builders:
             print(f"unknown kernel {args.chain!r}; "

@@ -137,11 +137,9 @@ class GPTAttention(Module, KVAttention):
                 q, k, v, causal=True, segment_ids=segment_ids,
                 use_pallas=None if c.use_flash_attention else False,
                 layout=st.act_attn())
+        # each route names what the "dots_attn" remat policy keeps of it
+        # where it makes it; none is put here (models/llama/model.py)
         attn = st.constrain(attn, st.act_attn())
-        # named so the "dots_attn" remat policy can save the kernel output
-        # (mirrors models/llama/model.py)
-        from jax.ad_checkpoint import checkpoint_name
-        attn = checkpoint_name(attn, "attn_out")
         return self.o_proj(params["o_proj"], attn.reshape(b, s, h))
 
     # -- the serving programs' hooks (models/generation.py); how a query

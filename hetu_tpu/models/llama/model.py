@@ -107,12 +107,13 @@ class LlamaAttention(Module, KVAttention):
                 q, k, v, causal=True, segment_ids=segment_ids,
                 use_pallas=None if c.use_flash_attention else False,
                 layout=st.act_attn())
+        # no name is put on `attn` for the "dots_attn" remat policy here:
+        # each route names what it keeps where it makes it (nn/remat.py).
+        # The flash kernel keeps its own [b, heads, s, hd] `o`, from which
+        # o_proj's backward remakes its operand by a transpose; a name
+        # here would keep this copy beside it, a second 67 MB a layer at
+        # Mistral's widths
         attn = st.constrain(attn, st.act_attn())
-        # named so the "dots_attn" remat policy can SAVE the kernel output:
-        # recomputing flash attention in the bwd is the single most
-        # expensive recompute under the dot-only policies (nn/remat.py)
-        from jax.ad_checkpoint import checkpoint_name
-        attn = checkpoint_name(attn, "attn_out")
         out = self.o_proj(params["o_proj"], attn.reshape(b, s, self.n_q * hd))
         return out
 

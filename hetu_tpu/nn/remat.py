@@ -16,12 +16,18 @@ def remat_policy(name: str):
     if name == "dots":
         return cp.dots_with_no_batch_dims_saveable
     if name == "dots_attn":
-        # dots + the named attention-kernel output (models tag it
-        # checkpoint_name "attn_out"): the flash kernel is the costliest
-        # thing the dot-only policy recomputes
+        # dots + what the attention's own backward reads, so that the
+        # flash kernel (the costliest thing the dot-only policy recomputes)
+        # is not launched again: the kernel's forward RULE names its
+        # residuals `o` "attn_out" and `lse` "attn_lse"
+        # (ops/pallas/flash_attention._flash_fwd); a name on a custom_vjp's
+        # result saves none of its residuals.  The other routes name
+        # their result "attn_out" (ops.attention, the XLA composition;
+        # parallel.ring_attention_gspmd): a layer keeps the attention
+        # output once whichever route it took, and no model names it
         return cp.save_from_both_policies(
             cp.dots_with_no_batch_dims_saveable,
-            cp.save_only_these_names("attn_out"))
+            cp.save_only_these_names("attn_out", "attn_lse"))
     if name == "offload":
         return cp.offload_dot_with_no_batch_dims("device", "pinned_host")
     raise ValueError(f"unknown remat_policy {name!r}; one of {REMAT_POLICIES}")

@@ -14,6 +14,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from hetu_tpu.dstates import DistributedStates
 
@@ -49,7 +50,8 @@ def attention(q, k, v, *, causal: bool = True, bias: Optional[jnp.ndarray] = Non
         mask2 = jax.random.bernoulli(dropout_rng, keep, probs.shape)
         probs = jnp.where(mask2, probs / keep, 0.0)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(jnp.float32))
-    return out.astype(orig_dtype)
+    # what the "dots_attn" remat policy keeps of this route (nn/remat.py)
+    return checkpoint_name(out.astype(orig_dtype), "attn_out")
 
 
 def flash_attention(q, k, v, *, causal: bool = True,

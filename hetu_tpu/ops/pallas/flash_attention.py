@@ -37,6 +37,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -566,6 +567,13 @@ def _flash_fwd(q, k, v, q_pos, k_pos, q_seg, k_seg, scale, causal, block_q,
     o, lse = _fwd(q, k, v, q_pos, k_pos, q_seg, k_seg, scale=scale,
                   causal=causal, block_q=block_q, block_k=block_k,
                   block_mask=block_mask)
+    # the two residuals only this launch can make, named HERE for the
+    # "dots_attn" remat policy (nn/remat.py): a name on `_flash`'s result
+    # outside is another variable and saves neither, and a checkpointed
+    # block's backward would launch `_fwd` again to make them.  `o` goes
+    # out as the primal result under the same name: one variable
+    o = checkpoint_name(o, "attn_out")
+    lse = checkpoint_name(lse, "attn_lse")
     return o, (q, k, v, o, lse, q_pos, k_pos, q_seg, k_seg)
 
 

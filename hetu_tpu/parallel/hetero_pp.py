@@ -141,7 +141,6 @@ def llama_block_maker(cfg, cos, sin, *, tp: int, tp_axis: str = "tp",
     projections, reduce-scatter out of the row-parallel matmuls; weight
     blocks still replicate m-fold at effective degree e)."""
     from hetu_tpu import ops
-    from jax.ad_checkpoint import checkpoint_name
 
     hd = cfg.head_dim
     n_q, n_kv = cfg.num_attention_heads, cfg.num_key_value_heads
@@ -181,7 +180,6 @@ def llama_block_maker(cfg, cos, sin, *, tp: int, tp_axis: str = "tp",
             attn = ops.flash_attention(
                 q, k, v, causal=True, segment_ids=seg,
                 use_pallas=None if cfg.use_flash_attention else False)
-            attn = checkpoint_name(attn, "attn_out")
             wo = _blk(lp["attn"]["o_proj"]["weight"], 0, t, e, m, tp_axis)
             attn2, wo = _al(attn.reshape(b, s, kv_e * group * hd), wo)
             if rng is not None and sp:
@@ -229,7 +227,6 @@ def gpt_block_maker(cfg, *, tp: int, tp_axis: str = "tp",
     (the hetero envelope ParallelStrategy.validate enforces).
     sequence_parallel: see llama_block_maker."""
     from hetu_tpu import ops
-    from jax.ad_checkpoint import checkpoint_name
 
     hd = cfg.head_dim
     n_heads = cfg.num_attention_heads
@@ -265,7 +262,6 @@ def gpt_block_maker(cfg, *, tp: int, tp_axis: str = "tp",
             attn = ops.flash_attention(
                 q, k, v, causal=True, segment_ids=seg,
                 use_pallas=None if cfg.use_flash_attention else False)
-            attn = checkpoint_name(attn, "attn_out")
             wo = _blk(lp["attn"]["o_proj"]["weight"], 0, t, e, m, tp_axis)
             attn2, wo = _al(attn.reshape(b, s, n_e * hd), wo)
             h1 = attn2 @ wo.astype(x.dtype)

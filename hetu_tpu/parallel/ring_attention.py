@@ -34,6 +34,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from hetu_tpu.ops.pallas.flash_attention import (NEG_INF, _bwd, _fwd,
@@ -444,7 +445,11 @@ def ring_attention_gspmd(q, k, v, *, strategy: ParallelStrategy,
         local, mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, tok_spec, tok_spec),
         out_specs=qkv_spec, check_vma=False)
-    return fn(q, k, v, segment_ids, position_ids)
+    # what the "dots_attn" remat policy keeps of this route (nn/remat.py):
+    # the result, for o_proj's backward.  `_ring`'s own residuals carry no
+    # name, so a checkpointed block still runs the ring forward again
+    return checkpoint_name(fn(q, k, v, segment_ids, position_ids),
+                           "attn_out")
 
 
 def ring_attention_fallback(q, k, v, *, strategy: ParallelStrategy,

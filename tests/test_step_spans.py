@@ -15,6 +15,7 @@ import gc
 import glob
 import logging
 import os
+import re
 import sys
 import time
 
@@ -886,11 +887,19 @@ def test_scope_map_train_step_kernels_and_passes(train_step_text):
             "pallas_rotary"} <= kernels
     passes = collections.Counter(p for _, p in smap.values())
     assert passes["fwd"] and passes["bwd"] and passes["recompute"]
-    # the flash kernel runs forward, again in the recomputed forward,
-    # and backward: the join can tell the three apart
+    # the join tells the three passes of a kernel's scope apart.  The
+    # flash kernel ITSELF (interpret mode: a `while` under its scope) runs
+    # forward and backward and not again in the recomputed forward, as the
+    # swiglu kernel does: `dots_attn` keeps the `o` and `lse` its forward
+    # rule names, and what the scope recomputes are the relayouts of q, k,
+    # v on their way in and of `o` on its way to `o_proj`
     flash = {p for g, p in smap.values()
              if g.endswith("pallas_flash_attention")}
     assert flash == {"fwd", "bwd", "recompute"}
+    remade = set(re.findall(r"rematted_computation/[^\"]*?(pallas_\w+)/while",
+                            train_step_text))
+    assert "pallas_swiglu" in remade
+    assert "pallas_flash_attention" not in remade
 
 
 def test_scope_map_names_are_unique_within_a_program(train_step_text):

@@ -356,6 +356,17 @@ def _widen(q, width: int):
                    + ((0, width - q.shape[-1]),))
 
 
+def head_rms_norm(x, gain, eps: float):
+    """RMSNorm over each head of q or k [.., heads, head_dim] with a
+    learned gain [head_dim], statistics in float32, in x's dtype: the
+    per-head norm of the families that norm q and k before they attend
+    (models/trinity, models/lfm2_moe)."""
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    return (x32 * lax.rsqrt(var + eps)
+            * gain.astype(jnp.float32)).astype(x.dtype)
+
+
 class KVAttention:
     """How a query attends a K/V cache: the `attend_paged`,
     `attend_dense` and `attend_prompt` hooks of an attention module whose
@@ -606,21 +617,23 @@ class KVAttention:
 
     def attend_prompt(self, params, q, entries, window=None):
         """Whole prompts attending their own entries, causally: the
-        training forward's flash path; under a `window`, with a sink or
-        with keys wider than the values the XLA composition by the
-        window's mask (ops/pallas/flash_attention has none of the
-        three).  -> [b, s, n_q * d_v]."""
+        training forward's flash path; under a `window`, with a sink,
+        with keys wider than the values or at a scale of the family's own
+        the XLA composition by the window's mask (ops/pallas/
+        flash_attention has none of the four).  -> [b, s, n_q * d_v]."""
         from hetu_tpu import ops
         b, s, nq, hd = q.shape
         d_k, d_v = entries[0].shape[-1], entries[1].shape[-1]
         sink = self.sink(params, window)
-        if window is not None or sink is not None or d_k != d_v:
+        if (window is not None or sink is not None or d_k != d_v
+                or self.softmax_scale(hd) != hd ** -0.5):
             from hetu_tpu.models.generation import _attend_cached_chunk
             from hetu_tpu.ops.pallas import _note_route
             _note_route("flash_attn_window", False,
-                        "whole prompts under a window, with a sink or with "
-                        "keys wider than the values: the XLA composition "
-                        "(none of them in ops/pallas/flash_attention)")
+                        "whole prompts under a window, with a sink, with "
+                        "keys wider than the values or at a scale of the "
+                        "family's own: the XLA composition (none of them "
+                        "in ops/pallas/flash_attention)")
             return _attend_cached_chunk(
                 _widen(q, d_k), *entries, 0, self.softmax_scale(hd),
                 window=window, sink=sink).reshape(b, s, nq * d_v)

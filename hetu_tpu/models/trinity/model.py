@@ -29,11 +29,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from hetu_tpu import ops
 from hetu_tpu.models.cache_contract import (CacheContract, KVAttention,
-                                            kv_contract)
+                                            head_rms_norm, kv_contract)
 from hetu_tpu.models.trinity.config import TrinityConfig
 from hetu_tpu.nn import initializers as init
 from hetu_tpu.nn.module import Module
@@ -65,12 +64,6 @@ class GatedAttention(KVAttention, Module):
                                         eps=c.rms_norm_eps,
                                         param_dtype=c.param_dtype)
 
-    def _head_norm(self, x, gain):
-        x32 = x.astype(jnp.float32)
-        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-        return (x32 * lax.rsqrt(var + self.config.rms_norm_eps)
-                * gain.astype(jnp.float32)).astype(x.dtype)
-
     def project(self, params, hn, rope, pos_ids):
         """hn [b, s, h] (normed) at positions pos_ids [b, s] -> (q
         [b, s, nq, hd], entries (k, v) [b, s, n_kv, hd], the gate
@@ -84,8 +77,8 @@ class GatedAttention(KVAttention, Module):
         v = x[..., (nq + nkv) * hd: (nq + 2 * nkv) * hd] \
             .reshape(lead + (nkv, hd))
         gate = x[..., (nq + 2 * nkv) * hd:]
-        q = self._head_norm(q, params["q_norm"])
-        k = self._head_norm(k, params["k_norm"])
+        q = head_rms_norm(q, params["q_norm"], c.rms_norm_eps)
+        k = head_rms_norm(k, params["k_norm"], c.rms_norm_eps)
         if self.window is not None:
             cos, sin = rope
             q = ops.apply_rotary(q, cos, sin, pos_ids)

@@ -481,7 +481,7 @@ class MoELayer(Module):
 
 def noaux_tc_gate(x, w_gate, bias, *, top_k: int, norm_topk_prob: bool,
                   routed_scaling_factor: float, n_group: int = 1,
-                  topk_group: int = 1):
+                  topk_group: int = 1, norm_eps: float = 1e-20):
     """The published `noaux_tc` gate, in float32 as the published code
     computes it.  x [T, h]; w_gate [h, E]; bias [E]
     (`e_score_correction_bias`, a buffer).  The `top_k` experts of a
@@ -493,6 +493,8 @@ def noaux_tc_gate(x, w_gate, bias, *, top_k: int, norm_topk_prob: bool,
     groups stay, and the `top_k` are chosen inside them (a deployment
     that holds a group a chip sends a token to `topk_group` chips at
     most).  `n_group` = `topk_group` = 1 is the gate as it always was.
+    `norm_eps` stands beside the chosen scores' sum (1e-20 for the
+    families that publish that; 1e-6 in `lfm2_moe`).
     Returns (expert ids [T, k] int32, weights [T, k] float32)."""
     scores = jax.nn.sigmoid(jnp.einsum(
         "th,he->te", x.astype(jnp.float32), w_gate.astype(jnp.float32),
@@ -507,16 +509,18 @@ def noaux_tc_gate(x, w_gate, bias, *, top_k: int, norm_topk_prob: bool,
         choice = jnp.where(kept[:, :, None], by_group,
                            -jnp.inf).reshape(T, E)
     return _top_k_weights(scores, choice, top_k, norm_topk_prob,
-                          routed_scaling_factor)
+                          routed_scaling_factor, norm_eps)
 
 
-def _top_k_weights(scores, choice, top_k, norm_topk_prob, factor):
+def _top_k_weights(scores, choice, top_k, norm_topk_prob, factor,
+                   eps: float = 1e-20):
     """The `top_k` largest of `choice` [T, E] and their weights: `scores`
-    there, over their sum where `norm_topk_prob`, times `factor`."""
+    there, over (their sum + `eps`) where `norm_topk_prob`, times
+    `factor`."""
     _, idx = jax.lax.top_k(choice, top_k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if norm_topk_prob:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
     return idx.astype(jnp.int32), w * factor
 
 
@@ -646,7 +650,8 @@ class SharedRoutedExperts(Module):
                  initializer_range: float = 0.02,
                  bias_range: float = 0.02, n_group: int = 1,
                  topk_group: int = 1, scoring: str = "sigmoid",
-                 n_zero_experts: int = 0):
+                 n_zero_experts: int = 0, norm_eps: Optional[float] = None,
+                 bias_mean: float = 0.0):
         super().__init__()
         self.gate = GATES_BY_SCORING[scoring]
         self.n_routed, self.n_zero = n_routed_experts, n_zero_experts
@@ -654,10 +659,17 @@ class SharedRoutedExperts(Module):
         if n_routed_experts % n_group or not 0 < topk_group <= n_group:
             raise ValueError(f"{n_routed_experts} experts in {n_group} "
                              f"groups, {topk_group} of them kept")
+        #: what the gate is told beyond the four every family tells it:
+        #: the groups, and what stands beside the chosen scores' sum where
+        #: the family publishes another than the gate's own 1e-20 (a layer
+        #: told neither hands the gate nothing: its program is unchanged)
         self.groups = (dict(n_group=n_group, topk_group=topk_group)
-                       if n_group > 1 else {})
+                           if n_group > 1 else {})
+        if norm_eps is not None:
+            self.groups["norm_eps"] = norm_eps
         if self.groups and scoring != "sigmoid":
-            raise ValueError(f"a {scoring} gate limited to groups")
+            raise ValueError(f"a {scoring} gate limited to groups or with "
+                             "an epsilon of its own")
         if not 0 <= first_expert <= n_routed_experts - experts_held:
             raise ValueError(
                 f"experts {first_expert}..{first_expert + experts_held - 1}"
@@ -672,9 +684,10 @@ class SharedRoutedExperts(Module):
         self.param("w_gate", (hidden, outputs), w, dtype=jnp.float32)
         # a buffer of the published model (its update rule is not part of
         # `config`); random, small and non-zero here so that choosing by
-        # s + b and weighting by s differ
+        # s + b and weighting by s differ (`bias_mean`: an offset common
+        # to every expert, which the choice cannot see)
         self.param("e_score_correction_bias", (outputs,),
-                   init.normal(bias_range), dtype=jnp.float32)
+                   init.normal(bias_range, bias_mean), dtype=jnp.float32)
         # gate|up fused as [.., hidden, 2 I] (gate columns, then up): a
         # [.., hidden, 2, I] weight is tiled (2, 128) on the chip and
         # copied whole into matmul layout at every step (compiler, PR 27)

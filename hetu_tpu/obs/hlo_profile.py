@@ -220,7 +220,11 @@ def group_of(op_name: str, phases: Tuple[str, ...] = PHASES) -> str:
 #: `dsa_index_q`, `dsa_index_k`: the indexer's projections; `dsa_score`,
 #: `dsa_select`, `dsa_attend`: the scores of every position a query sees,
 #: the exact selection, and the attention over it: models/deepseek_v32,
-#: ops/sparse_attention).  `diff_out`,
+#: ops/sparse_attention); a gated short convolution and its parts (`attn`
+#: > `short_conv` > `short_conv_proj`: W_in and W_out; `short_conv_mix`:
+#: the two gates, the taps and the tail; `short_conv` alone keeps the
+#: input norm and the state's rows taken out and written back:
+#: models/lfm2_moe).  `diff_out`,
 #: what follows the attention kernel in a differential-attention layer,
 #: is a scope of the operations' paths and NOT a group: its time stays
 #: with its kind of layer.  No program without these scopes changes its
@@ -234,7 +238,8 @@ SCOPE_MAP_GROUPS = ("kv_write", "loss", "mla_q", "mla_kv", "mla_out",
                     "ssm_step", "ssm_out", "gmu", "tail", "ssm_norm",
                     "zero_experts", "mhc_pre", "mhc_sinkhorn", "mhc_post",
                     "dsa_index_q", "dsa_index_k", "dsa_score", "dsa_select",
-                    "dsa_attend")
+                    "dsa_attend",
+                    "short_conv", "short_conv_proj", "short_conv_mix")
 UNSCOPED = "unscoped"
 #: what `scope_sources` says of an instruction: its own `op_name` named
 #: the group; its called computation's instructions did; its first

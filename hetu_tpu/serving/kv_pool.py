@@ -634,6 +634,21 @@ class PagePool:
         nv, nvs = put(a.v, a.v_scale, v_toks)
         return PoolArrays(nk, nv, nks, nvs).tree()
 
+    @property
+    def short_rows(self) -> bool:
+        """One kind of exact K/V pages whose token holds 2 to 7 rows (two
+        K/V heads of 64 a lane row, four rows a token: models/lfm2_moe).
+        The rows of a token are the array's second-minor dimension and
+        the device lays that out in tiles of 8 sublanes: fewer rows than
+        one tile and the compiler pads every token to a tile, then takes
+        the scatter over pages as a copy of the whole padded pool.  8
+        rows and more fill their tiles (InternLM2's 8, Phi's 20); a
+        single row (Jamba's one K/V head) keeps the scatter it has been
+        measured with.  tests/test_lfm2_moe.py builds every serving
+        cell's pool and finds this true of that family's alone."""
+        return (not self.windowed and self.token_shapes is None
+                and self.quant == "none" and 1 < self.num_kv_heads < 8)
+
     def write_pages(self, arrays_tree, pages_row, ks, vs=None, *more):
         """Bulk-write a prefilled sequence's K/V into its pages.
         pages_row: [mp] int32 page ids (pad unused tail entries with the
@@ -654,6 +669,11 @@ class PagePool:
         if self.windowed:
             return self._write_pages_kinds(arrays_tree, pages_row,
                                            (ks, vs) + more)
+        if self.short_rows:
+            # the scatter below would copy the whole pool, as
+            # `_write_pages_kinds` says of its own kinds
+            return self._write_pages_kinds(arrays_tree, (pages_row,),
+                                           (ks, vs))
         a = PoolArrays.from_tree(arrays_tree)
         L = self.num_layers
         mp = pages_row.shape[0]

@@ -704,6 +704,21 @@ def _deepseek_v32_cut():
         "num_slots", "page_size", "max_len", "prefill_chunk", "num_pages")}
 
 
+def _lfm2_cut():
+    """LFM2-8B-A1B as its cell serves it: layers 0-11 as published (9
+    gated short convolutions, 3 attention layers of 64-wide heads stored
+    two a lane row, both dense layers, 10 expert layers with all 32
+    experts) and the whole vocabulary, at the cell's slots, page, chunk
+    and max_len."""
+    from benchmarks import traffic
+    from benchmarks.families import lfm2_moe
+    cfg = traffic.load_json("configs", "lfm2-8b-a1b-depth12")
+    sv = cfg["serving"]
+    return lfm2_moe.build_model(cfg, sv), {k: sv[k] for k in (
+        "num_slots", "page_size", "max_len", "prefill_chunk", "num_pages",
+        "max_prefilling")}
+
+
 #: family -> ((model, ServeConfig overrides) or None for the Llama-2-7B
 #: block, the kernels its decode program must hold as tpu_custom_calls)
 SERVING_FAMILIES = {
@@ -718,12 +733,13 @@ SERVING_FAMILIES = {
     "longcat": (_longcat_cut, ("paged_latent",)),
     "xing4": (_xing4_cut, ("paged_latent",)),
     "deepseek_v32": (_deepseek_v32_cut, ()),
+    "lfm2": (_lfm2_cut, ("paged_attn",)),
 }
 #: the families whose case runs from a file of its own
-#: (tests/test_chip_compile_longcat.py, .._xing4.py, .._deepseek_v32.py): a
-#: file is what one worker of the tier-1 run takes whole, and this one is
-#: among the longest
-ELSEWHERE = ("longcat", "xing4", "deepseek_v32")
+#: (tests/test_chip_compile_longcat.py, .._xing4.py, .._deepseek_v32.py,
+#: .._lfm2.py): a file is what one worker of the tier-1 run takes whole,
+#: and this one is among the longest
+ELSEWHERE = ("longcat", "xing4", "deepseek_v32", "lfm2")
 
 
 @pytest.fixture(scope="module")
@@ -940,6 +956,45 @@ def test_serving_programs_compile_for_one_v5e(family, decode_text):
         assert "ssm_norm" in compiled["prefill_chunk"].as_text()
         assert "ssm_norm" in compiled["decode"].as_text()
         _scan_is_the_kernel(routes, chunk_text, calls)
+    elif family == "lfm2":
+        # heads of 64 stored two a lane row: to both kernels grouped-query
+        # attention of 32 heads over 4 rows of 128 (the paged kernel once
+        # an attention layer; the chunk kernel and its relayout, two calls
+        # a layer), never the composition; the pool holds the model's own
+        # 2,048 B a token a layer and, with the 9 layers' tails [9, 129,
+        # 4096], is carried in place; the page write moves a prompt's
+        # pages and no more (a scatter of whole pages of 4 rows a token
+        # copied the pool, 1.8 GB of temporaries: serving/kv_pool.py);
+        # the operator's scopes stand in both programs; weights + pool +
+        # the largest program's temporaries fit the chip
+        paged = [ln for ln in calls if "pallas_paged_attention" in ln]
+        assert len(paged) == 3 == routes["paged_attn"]["pallas"] and all(
+            "attn_full/pallas_paged_attention" in ln for ln in paged)
+        rec = routes["chunk_attn"]
+        assert 2 * rec["pallas"] == chunk_calls == 6 and not rec["xla"], rec
+        assert list(rec["why"]) == ["shape gate passes"]
+        assert engine.pool.arrays.k.shape == (3, 4625, 128, 4, 128)
+        nbytes = lambda tree: sum(  # noqa: E731
+            a.size * a.dtype.itemsize for a in tree)
+        state, pool = nbytes(engine.pool.state), nbytes(
+            engine.pool.arrays.tree())
+        assert state == 129 * 9 * 8192
+        assert pool == 2 * 3 * 4625 * 128 * 2048 // 2
+        mem = {name: c.memory_analysis() for name, c in compiled.items()}
+        assert mem["decode"].alias_size_in_bytes >= pool + state
+        assert mem["decode"].temp_size_in_bytes < 0.1e9
+        assert mem["prefill_chunk"].alias_size_in_bytes >= state
+        # (a chunk of 1,536 rows: 6,144 pairs' gate|up rows among them)
+        assert mem["prefill_chunk"].temp_size_in_bytes < 0.5e9
+        assert mem["write_pages"].temp_size_in_bytes < 1 << 20
+        assert 2 * engine.model.num_params() == 7_857_456_512
+        assert all(m.argument_size_in_bytes + m.temp_size_in_bytes
+                   < 12.0e9 for m in mem.values())
+        for name in ("decode", "prefill_chunk"):
+            text = compiled[name].as_text()
+            assert all(f"/{scope}/" in text for scope in (
+                "short_conv", "short_conv_proj", "short_conv_mix",
+                "router", "experts")), name
     elif family in ("kimi", "deepseek_v32"):
         assert "chunk_attn" not in routes and not chunk_calls
     elif family == "longcat":

@@ -227,6 +227,10 @@ def _family_case(family, hd128):
         from test_deepseek_v32 import build, ref_logits
         cfg, model, params = build()
         return model, params, lambda p, ids: ref_logits(p, cfg, ids)
+    if family == "lfm2":
+        from test_lfm2_moe import build, ref_logits
+        cfg, model, params = build(head_dim=64 if hd128 else None)
+        return model, params, lambda p, ids: ref_logits(p, cfg, ids)
     if family == "gpt":
         from hetu_tpu.models.gpt import GPTConfig, GPTLMHeadModel
         kw = dict(hidden_size=256, num_attention_heads=2) if hd128 else {}
@@ -256,7 +260,8 @@ HERE = [
     ("llama", "composition"), ("llama", "paged"),
     ("llama-unstacked", "composition"),
     ("gpt", "composition"), ("gpt", "paged"),
-    ("hooks-window", "composition"), ("hooks-window", "paged")]
+    ("hooks-window", "composition"), ("hooks-window", "paged"),
+    ("lfm2", "composition"), ("lfm2", "paged")]
 LATENT_CASES = [(f, r) for f in LATENT for r in ("xla", "kernel")]
 WINDOW_CASES = [(f, r) for f in ("trinity", "mimo")
                 for r in ("composition", "paged")]
@@ -307,6 +312,10 @@ def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
     the 24 best-scored of the positions they see, and the decode step
     gathers them (neither paged latent attention: its route is not
     asked).
+    `lfm2` is the family whose state is a short convolution's TAIL alone
+    (five layers, two positions a sequence, held by slot) beside K/V
+    pages whose heads of 64 are stored two a lane row (`paged`: heads of
+    64 in rows of 128 under the kernel), with every expert held.
     `llama-unstacked` is the Llama block built with
     use_scan=False: a layer's own arrays, called, never scanned."""
     monkeypatch.setenv("HETU_TPU_PALLAS",
@@ -344,8 +353,9 @@ def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
     elif family in LATENT:
         assert eng.kernel_routes["paged_latent"][
             "pallas" if route == "kernel" else "xla"]
-    if family == "ling":
-        assert eng.stateful and len(eng.pool.state) == 2
+    if family in ("ling", "lfm2"):
+        assert eng.stateful and len(eng.pool.state) == (
+            2 if family == "ling" else 1)
         assert reg.counter_value("serve.state_resets") == len(reqs)
     assert sorted(results) == list(range(len(reqs)))
     for req in reqs:
@@ -355,8 +365,8 @@ def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
             np.concatenate([req.prompt, toks[:-1]]))))[req.prompt_len - 1:]
         gap = lg.max(-1) - lg[np.arange(len(toks)), toks]
         assert (gap <= 2e-4).all(), (req.rid, gap)
-        if family not in LATENT and route == "composition" \
-                and family != "hooks":
+        if family not in LATENT + ("hooks", "lfm2") \
+                and route == "composition":
             # (one program a prompt length: run op by op, `generate()`'s
             # prefill was most of these cases' seconds)
             gold = generate(model, params, jnp.asarray(req.prompt[None]),
@@ -374,9 +384,9 @@ def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
         n = {k[len("serve.moe_"):]: reg.counter_value(k)
              for k, _ in model.STATS}
         assert n["extra_row_blocks"] >= 0
-        # (xing4 holds every expert of a layer, 8 here: every pair is
-        # local; the others hold 4 of their router's 16)
-        held = 8 if family == "xing4" else 4
+        # (xing4 and lfm2 hold every expert of a layer, 8 here: every
+        # pair is local; the others hold 4 of their router's 16)
+        held = 8 if family in ("xing4", "lfm2") else 4
         assert n["layer_steps"] > 0 and (
             n["assignments"] == n["local_assignments"] if held == 8
             else n["assignments"] > n["local_assignments"])

@@ -546,6 +546,51 @@ def softmax_gate(x, w_gate, bias, *, top_k: int, norm_topk_prob: bool,
 GATES_BY_SCORING = {"sigmoid": noaux_tc_gate, "softmax": softmax_gate}
 
 
+#: The rows of a block that `dropless_local_experts` walks, from the chip
+#: (TPU v5e: 197 TFLOP/s over 819 GB/s = 240 rows, the RIDGE: an expert's
+#: weights take as long to stream as 240 rows take to multiply; the MXU
+#: multiplies 128 rows at once, so the ridge in tiles is 256 and no tile
+#: under 128 multiplies less).  `jax.lax.ragged_dot` pays every expert it
+#: visits its weights' bytes AND a tile of the block's rows (of 512 where
+#: the block has more), nearly one after the other (my chip runs, PR 63:
+#: LFM2's decode pass, 512 pairs on 32 experts, the two products 2.30 ms
+#: in one block, 1.43 in four of 128, 1.21 in blocks of 64 that end at
+#: whole experts; visits x (bytes / 819 GB/s + rows x FLOPs / 197
+#: TFLOP/s) gives 2.7 / 1.44 / 1.09).  So a block over the ridge is cut
+#: to a tile that holds ONE expert's expected rows and a quarter more,
+#: between these two, and ends where its last whole expert ends.  The
+#: sweep (`python tools_bench_kernels.py --grouped-product` on the chip
+#: reads it again; my chip run, PR 63), ms a layer's walk at each cell's
+#: shapes with blocks of 64 / 128 / 192 / 256 / 512 / one of 1.5 x the
+#: expected rows as before, the rule's in brackets:
+#:   LFM2 decode (512)    1.33 [1.41] 1.50  1.66   -    2.39
+#:   LFM2 chunk (6,144)   6.63  3.83  5.07 [2.44] 2.93  3.89
+#:   Xing chunk (4,096)   5.78 [3.59] 4.63  3.63  5.12  5.52
+#:   Ling chunk (3,072)   2.43 [2.18] 2.42  2.34  6.96  4.10
+#:   MiMo chunk (768)     1.61 [1.67] 2.17  2.04  2.91  2.13
+#:   Trinity chunk (768)  1.47 [0.90] 0.77  0.72  0.85  0.63
+#:   DeepSeek chunk (384) 1.40 [1.49] 2.76  3.06   -    2.48
+#:   Kimi chunk (192)     1.91  2.08 [2.30]; LongCat (192) 2.15 2.33 [2.32]
+#: (192 rows are tiled worse than 128 or 256; Trinity reads worse alone,
+#: where the compiler copies its 67 MB of down weights near every block,
+#: and better in its cell: `experts` 4.09 -> 3.45 ms a chunk launch.)
+MXU_ROWS = 128
+RIDGE_ROWS = 256
+
+
+def row_block(pairs: int, share: float, held: int):
+    """(rows of a block that `dropless_local_experts` walks, rows that
+    one and a half times the pairs expected here fill: `expected`), for
+    `pairs` (token, expert) pairs of which `share` are expected on the
+    `held` experts here.  `expected` at or under the ridge IS the block;
+    over it the block is a tile for one expert's expected rows."""
+    expected = min(pairs, -(-int(1.5 * share * pairs) // 64) * 64)
+    if expected <= RIDGE_ROWS:
+        return expected, expected
+    tile = -(-int(1.25 * share * pairs / held) // MXU_ROWS) * MXU_ROWS
+    return min(max(tile, MXU_ROWS), RIDGE_ROWS), expected
+
+
 def dropless_local_experts(x, idx, weights, w_gate_up, w_down, *,
                            first_expert: int, share: float = 1.0):
     """sum_i w_i E_i(x) over the experts HELD here: experts
@@ -558,20 +603,34 @@ def dropless_local_experts(x, idx, weights, w_gate_up, w_down, *,
     uneven the load.  A token none of whose experts is here gets 0.
 
     `share` is the part of all pairs expected here (held / router
-    width).  The grouped product works in row tiles of min(rows, 512)
-    and pays a whole tile for each expert it visits (compiler, PR 27),
-    so with 1/32 of the pairs here a product over all rows would
-    multiply mostly padding.  The sorted pairs on held experts are
-    walked in BLOCKS of one and a half times their expected number of
-    rows (rounded up to 64), as many blocks as they fill (a loop whose
-    trip count the device computes: none where no pair is here, one as
-    a rule): the cost follows the pairs and no pair is dropped.  Pairs
-    are not independent (a chunk's padding rows are one token and route
-    alike): 0.6-8% of executions hold more than one block's (my chip
-    run, PR 27), so nothing here assumes that they fit.
+    width).  The grouped product pays each expert it visits a whole tile
+    of the rows it is given (of 512 where they are more: compiler,
+    PR 27), so a product over all rows would multiply mostly padding.
+    The sorted pairs on held experts are walked in BLOCKS (`row_block`:
+    one and a half times their expected number of rows, rounded up to
+    64; where that is over the chip's ridge, a tile of 128 or 256 rows
+    for one expert's expected rows), as many as they fill (a loop whose
+    trip count the device computes: none where no pair is here).  A
+    block ends where the last whole expert inside it ends, so an expert
+    is visited twice only where it alone has more rows than a block.
+    The cost follows the pairs and no pair is dropped.  Pairs are not
+    independent (a chunk's padding rows are one token and route alike):
+    0.6-8% of executions hold more than 1.5 x the expected (my chip run,
+    PR 27), so nothing here assumes that they fit.
+
+    A block's rows reach `y` one of two ways.  With `share` under 1 few
+    of the T k pairs are here: each block's rows are added to their
+    tokens' where the block is computed (a scatter of at most 256 rows;
+    of more rows it is the larger part of the walk: 0.78 of DeepSeek's
+    2.45 ms at 384, my chip run, PR 63).  With every pair here the blocks
+    fill a buffer in sorted order, and each token gathers its k rows
+    from it once, behind the loop (Xing's chunk: 0.65 ms of scatter and
+    two copies of `y` a block against 0.16 ms).
 
     Returns (y [T, h] in x's dtype, counts [held] int32: pairs per held
-    expert, extra int32: blocks walked beyond the first)."""
+    expert, extra int32: blocks of `expected` rows that the pairs here
+    fill beyond the first (what was walked before the block had a cap),
+    blocks int32: the blocks walked)."""
     T, h = x.shape
     k = idx.shape[1]
     held, inter = w_gate_up.shape[0], w_gate_up.shape[-1] // 2
@@ -582,31 +641,58 @@ def dropless_local_experts(x, idx, weights, w_gate_up, w_down, *,
     counts = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
     ends = jnp.cumsum(counts)
     n_here = ends[-1]
-    rows = min(T * k, -(-int(1.5 * share * T * k) // 64) * 64)
-    # whole blocks, so that no slice below is shifted back at the end
-    pad = -(T * k) % rows
-    token = jnp.pad((order // k).astype(jnp.int32), (0, pad))
-    w_sorted = jnp.pad(jnp.where(here, weights, 0.0).reshape(T * k)[order],
-                       (0, pad))
+    rows, expected = row_block(T * k, share, held)
+    # a block of rows behind the last pair, so that no slice below is
+    # shifted back at the end
+    token = jnp.pad((order // k).astype(jnp.int32), (0, rows))
+    w_here = jnp.where(here, weights, 0.0)
 
-    def block(b, y):
-        lo = b * rows
-        # each expert's pairs that fall in rows lo .. lo + rows - 1
-        sizes = (jnp.clip(ends - lo, 0, rows)
-                 - jnp.clip(ends - counts - lo, 0, rows))
+    def product(lo):
+        """The pairs of sorted rows lo .. hi - 1 through their experts:
+        (hi, their tokens, their rows; a row past hi is 0)."""
+        hi = lo + rows
+        whole = jnp.max(jnp.where(ends <= hi, ends, 0))
+        hi = jnp.where(whole > lo, whole, jnp.minimum(hi, n_here))
+        # each expert's pairs that fall in rows lo .. hi - 1
+        sizes = jnp.clip(ends, lo, hi) - jnp.clip(ends - counts, lo, hi)
         tok = jax.lax.dynamic_slice_in_dim(token, lo, rows)
         gu = jax.lax.ragged_dot(x[tok], w_gate_up.astype(x.dtype), sizes)
         act = jax.nn.silu(gu[:, :inter]) * gu[:, inter:]
         out = jax.lax.ragged_dot(act, w_down.astype(x.dtype), sizes)
         # rows past the last group are not computed: take them as 0
-        out = jnp.where((lo + jnp.arange(rows) < n_here)[:, None],
-                        out.astype(jnp.float32), 0.0) \
-            * jax.lax.dynamic_slice_in_dim(w_sorted, lo, rows)[:, None]
-        return y.at[tok].add(out)
+        return hi, tok, jnp.where((lo + jnp.arange(rows) < hi)[:, None],
+                                  out, 0)
 
-    blocks = (n_here + rows - 1) // rows
-    y = jax.lax.fori_loop(0, blocks, block, jnp.zeros((T, h), jnp.float32))
-    return y.astype(x.dtype), counts, jnp.maximum(blocks - 1, 0)
+    def walk(step, start):
+        _, carried, blocks = jax.lax.while_loop(
+            lambda c: c[0] < n_here, step, (jnp.int32(0), start,
+                                            jnp.int32(0)))
+        return carried, blocks
+
+    if share < 1:
+        w_sorted = jnp.pad(w_here.reshape(T * k)[order], (0, rows))
+
+        def add(c):
+            lo, y, blocks = c
+            hi, tok, out = product(lo)
+            out = out.astype(jnp.float32) \
+                * jax.lax.dynamic_slice_in_dim(w_sorted, lo, rows)[:, None]
+            return hi, y.at[tok].add(out), blocks + 1
+
+        y, blocks = walk(add, jnp.zeros((T, h), jnp.float32))
+    else:
+        def put(c):
+            lo, sorted_out, blocks = c
+            hi, _, out = product(lo)
+            return hi, jax.lax.dynamic_update_slice_in_dim(
+                sorted_out, out, lo, 0), blocks + 1
+
+        sorted_out, blocks = walk(put, jnp.zeros((T * k + rows, h), x.dtype))
+        # pair (t, j) lies at row argsort(order)[t k + j]
+        mine = sorted_out[jnp.argsort(order)].reshape(T, k, h)
+        y = jnp.sum(mine.astype(jnp.float32) * w_here[:, :, None], axis=1)
+    extra = jnp.maximum((n_here + expected - 1) // expected - 1, 0)
+    return y.astype(x.dtype), counts, extra, blocks
 
 
 class SharedRoutedExperts(Module):
@@ -622,11 +708,14 @@ class SharedRoutedExperts(Module):
     be summed by the exchange this layer does not do (no code stands in
     for absent chips).
 
-    forward(params, x [b, s, h]) -> (y [b, s, h], stats int32 [5] in the
+    forward(params, x [b, s, h]) -> (y [b, s, h], stats int32 [6] in the
     order of `STATS`: (token, expert) pairs chosen, pairs on held
-    experts, held experts with at least one token, row blocks the
-    grouped product walked beyond the first (`dropless_local_experts`),
-    the largest load of a held expert).
+    experts, held experts with at least one token, blocks of one and a
+    half times the expected rows that the pairs here fill beyond the
+    first (`extra_row_blocks`: what the grouped product walked before
+    its block had a cap, 0 where the pairs fit as expected), the blocks
+    it walked (`row_blocks`: `dropless_local_experts`, `row_block`), the
+    largest load of a held expert).
 
     **Identity experts** (`n_zero_experts` > 0: LongCat-Flash's
     zero-compute experts): the router has that many outputs MORE, behind
@@ -635,12 +724,14 @@ class SharedRoutedExperts(Module):
     token's addend is (the sum of its weights on them) x the token,
     computed where the token lives, under the scope `zero_experts`: no
     weights, no exchange, all of them "held" by every chip.  `share`,
-    which sizes the grouped product's row blocks, is then the held
-    experts over ALL the router's outputs, and `stats` has one entry
-    more at its end (`ZERO_STATS`): the pairs on identity experts."""
+    which sizes the grouped product's row blocks and says whether every
+    pair is here (1: each token gathers its rows; under 1: a block's rows
+    are added where it is computed), is then the held experts over ALL
+    the router's outputs, and `stats` has one entry more at its end
+    (`ZERO_STATS`): the pairs on identity experts."""
 
     STATS = ("assignments", "local_assignments", "expert_hits",
-             "extra_row_blocks", "max_expert_load")
+             "extra_row_blocks", "row_blocks", "max_expert_load")
     ZERO_STATS = STATS + ("zero_assignments",)
 
     def __init__(self, hidden: int, inter: int, *, n_routed_experts: int,
@@ -714,7 +805,7 @@ class SharedRoutedExperts(Module):
         with jax.named_scope("router"):
             idx, weights = self.route(params, xt)
         with jax.named_scope("experts"):
-            y, counts, extra = dropless_local_experts(
+            y, counts, extra, blocks = dropless_local_experts(
                 xt, idx, weights, params["w_gate_up"], params["w_down"],
                 first_expert=self.first_expert, share=self.share)
         if self.shared:
@@ -724,7 +815,7 @@ class SharedRoutedExperts(Module):
                 y = y + (jax.nn.silu(gu[:, :si]) * gu[:, si:]) \
                     @ params["shared_down"].astype(x.dtype)
         stats = [jnp.int32(idx.size), jnp.sum(counts),
-                 jnp.sum((counts > 0).astype(jnp.int32)), extra,
+                 jnp.sum((counts > 0).astype(jnp.int32)), extra, blocks,
                  jnp.max(counts)]
         if self.n_zero:
             with jax.named_scope("zero_experts"):
@@ -770,5 +861,7 @@ zero_moe_stats, add_moe_stats = stats_ops(MOE_STATS)
 def moe_layer_stats(st):
     """One execution of a `SharedRoutedExperts` layer (its `stats`, with
     or without the identity experts' count at the end) as a MOE_STATS /
-    ZERO_MOE_STATS vector: one layer step, behind the four sums."""
-    return jnp.concatenate([st[:4], jnp.ones((1,), jnp.int32), st[4:]])
+    ZERO_MOE_STATS vector: one layer step, behind the layer's sums."""
+    sums = len(SharedRoutedExperts.STATS) - 1
+    return jnp.concatenate([st[:sums], jnp.ones((1,), jnp.int32),
+                            st[sums:]])

@@ -152,6 +152,9 @@ def test_chunked_prefill_page_write_and_paged_decode_are_the_reference(
         < stats["serve.moe_assignments"]
     assert stats["serve.moe_expert_hits"] <= 4 * steps
     assert stats["serve.moe_extra_row_blocks"] >= 0
+    # (a program's stats count the blocks the grouped product walked)
+    assert stats["serve.moe_row_blocks"] \
+        >= max(stats["serve.moe_extra_row_blocks"], 1)
 
 
 # (the served streams against the reference, over the XLA attention and
@@ -409,11 +412,11 @@ def test_dropless_under_imbalance_and_shared_alone_elsewhere(rng):
     np.testing.assert_allclose(np.asarray(here[0]), np.asarray(want),
                                atol=5e-5, rtol=0)
     # 96 pairs of 96 here: two blocks of 64 rows (1.5 x 1/4 of 96)
-    assert list(np.asarray(stats)) == [96, 96, 4, 1, 24]
+    assert list(np.asarray(stats)) == [96, 96, 4, 1, 2, 24]
     gone, stats = _layer(8, 4)[0](_share(params, 8, 4), x)
     np.testing.assert_allclose(np.asarray(gone[0]), np.asarray(shared),
                                atol=1e-6, rtol=0)
-    assert list(np.asarray(stats)) == [96, 0, 0, 0, 0]
+    assert list(np.asarray(stats)) == [96, 0, 0, 0, 0, 0]
 
 
 def test_one_row_block_and_several_agree_with_the_reference(rng):
@@ -435,31 +438,189 @@ def test_one_row_block_and_several_agree_with_the_reference(rng):
     assert int(stats[1]) == 256 == 2 * 128
 
 
+def _walk_beside_an_expert_loop(rng, idx, held, share, atol=5e-5):
+    """`dropless_local_experts` for the routing `idx` [T, k] over `held`
+    experts of 16 -> 8 -> 16 in float32, held to a plain loop over the
+    experts: (counts, extra, blocks)."""
+    T, k = idx.shape
+    h, inter = 16, 8
+    idx = jnp.asarray(idx, jnp.int32)
+    w = jnp.asarray(rng.uniform(0.5, 1.0, size=(T, k)), F32)
+    x = jnp.asarray(rng.standard_normal((T, h)), F32)
+    wgu = jnp.asarray(rng.standard_normal((held, h, 2 * inter)) * 0.3, F32)
+    wd = jnp.asarray(rng.standard_normal((held, inter, h)) * 0.3, F32)
+    with jax.default_matmul_precision("highest"):
+        y, counts, extra, blocks = jax.jit(
+            lambda *a: moe.dropless_local_experts(
+                *a, first_expert=0, share=share))(x, idx, w, wgu, wd)
+        want = sum(jnp.sum(jnp.where(idx == e, w, 0.0), -1)[:, None]
+                   * fam._swiglu(x, wgu[e], wd[e]) for e in range(held))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=atol,
+                               rtol=0)
+    return list(np.asarray(counts)), int(extra), int(blocks)
+
+
+def _blocks_walked(loads, rows):
+    """The walk of `dropless_local_experts` over experts with `loads`
+    pairs in blocks of `rows`: a block ends where the last whole expert
+    inside it ends, or, where none ends inside, after `rows` rows."""
+    ends = np.cumsum(loads)
+    lo = blocks = 0
+    while lo < ends[-1]:
+        whole = max([e for e in ends if e <= lo + rows], default=0)
+        lo = whole if whole > lo else min(lo + rows, ends[-1])
+        blocks += 1
+    return blocks
+
+
 @pytest.mark.parametrize("loads", [(70, 0, 20), (0, 0, 0), (1, 64, 35)])
 def test_row_blocks_cut_an_experts_pairs_at_any_row(loads, rng):
     """100 pairs in blocks of 64 rows (the last block padded): an
     expert's pairs may straddle a block's edge, an expert may have none,
     and the pairs here may be none at all; every pair on a held expert
     is computed once."""
-    from hetu_tpu.nn.moe import dropless_local_experts
-    T, k, h, inter = 50, 2, 16, 8
+    T, k = 50, 2
     idx = np.full(T * k, 7, np.int32)                   # 7: held elsewhere
     idx[rng.permutation(T * k)[: sum(loads)]] = np.repeat(
         np.arange(3), loads)
-    idx = jnp.asarray(idx.reshape(T, k))
-    w = jnp.asarray(rng.uniform(0.5, 1.0, size=(T, k)), F32)
-    x = jnp.asarray(rng.standard_normal((T, h)), F32)
-    wgu = jnp.asarray(rng.standard_normal((3, h, 2 * inter)) * 0.3, F32)
-    wd = jnp.asarray(rng.standard_normal((3, inter, h)) * 0.3, F32)
-    with jax.default_matmul_precision("highest"):
-        y, counts, extra = dropless_local_experts(
-            x, idx, w, wgu, wd, first_expert=0, share=0.1)
-        want = sum(jnp.sum(jnp.where(idx == e, w, 0.0), -1)[:, None]
-                   * fam._swiglu(x, wgu[e], wd[e]) for e in range(3))
-    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-5,
-                               rtol=0)
-    assert tuple(np.asarray(counts)) == loads
-    assert int(extra) == max(-(-sum(loads) // 64) - 1, 0)
+    counts, extra, blocks = _walk_beside_an_expert_loop(
+        rng, idx.reshape(T, k), 3, 0.1, atol=2e-5)
+    assert tuple(counts) == loads
+    assert extra == max(-(-sum(loads) // 64) - 1, 0)
+    # (70, 0, 20): 64 of expert 0, then its last 6 and expert 2;
+    # (1, 64, 35): a block an expert, each whole
+    assert blocks == _blocks_walked(loads, 64) \
+        == {90: 2, 0: 0, 100: 3}[sum(loads)]
+
+
+def _round_robin(T, k, experts):
+    """Token t's k experts are k t .. k t + k - 1 (mod `experts`): every
+    expert gets T k / experts pairs exactly."""
+    return (k * np.arange(T)[:, None] + np.arange(k)) % experts
+
+
+def _skewed(T, k, rng):
+    """Every token's first expert is 3 (T pairs on one expert: more than
+    a block's rows); its others are drawn from all 512."""
+    rest = np.stack([rng.permutation(np.r_[0:3, 4:512])[:k - 1]
+                     for _ in range(T)])
+    return np.concatenate([np.full((T, 1), 3), rest], axis=1)
+
+
+# a cell's block regime: (T, k, held, share, its routing)
+ROW_BLOCK_REGIMES = {
+    # LFM2's decode pass: 512 pairs, every expert held, 16 rows each
+    "all held, 16 rows an expert": (
+        128, 4, 32, 1.0, lambda rng: _round_robin(128, 4, 32)),
+    # LFM2's chunk: every expert's rows just under a block of 192-256
+    "all held, 190 rows an expert": (
+        380, 4, 8, 1.0, lambda rng: _round_robin(380, 4, 8)),
+    # Ling: an eighth of the router's width, one expert over a block
+    "64 of 512 held, one expert over a block": (
+        320, 8, 64, 64 / 512, lambda rng: _skewed(320, 8, rng)),
+    # Kimi: 1/32 of the pairs expected here, one block of 64 rows
+    "12 of 384 held": (
+        64, 8, 12, 12 / 384,
+        lambda rng: np.stack([rng.permutation(384)[:8]
+                              for _ in range(64)])),
+    "no pair here": (
+        48, 4, 4, 4 / 16, lambda rng: 4 + _round_robin(48, 4, 12)),
+}
+
+
+@pytest.mark.parametrize("regime", list(ROW_BLOCK_REGIMES))
+def test_row_blocks_of_a_cells_regime_agree_with_an_expert_loop(regime,
+                                                                rng):
+    """`dropless_local_experts` at the block regimes the cells have,
+    against a plain loop over the held experts: every pair on a held
+    expert is computed once however the blocks cut them, `counts` are
+    the loads, and the blocks walked are the pairs here over a block's
+    rows."""
+    T, k, held, share, routing = ROW_BLOCK_REGIMES[regime]
+    idx = routing(rng)
+    counts, extra, blocks = _walk_beside_an_expert_loop(rng, idx, held,
+                                                        share)
+    loads = np.bincount(idx.ravel(), minlength=512)[:held]
+    assert counts == list(loads)
+    rows, expected = moe.row_block(T * k, share, held)
+    assert rows <= min(moe.RIDGE_ROWS, expected)
+    assert blocks == _blocks_walked(loads, rows)
+    assert extra == max(-(-loads.sum() // expected) - 1, 0)
+    if regime.endswith("over a block"):
+        assert loads.max() > rows
+
+
+@pytest.mark.parametrize("cell, T, k, held, outputs, rows, expected", [
+    # at or under the ridge: the block of PR 27, row for row
+    ("kimi-k2.6 chunk", 512, 8, 12, 384, 192, 192),
+    ("kimi-k2.6 decode", 64, 8, 12, 384, 64, 64),
+    ("longcat-flash chunk", 512, 12, 16, 768, 192, 192),
+    ("longcat-flash decode", 96, 12, 16, 768, 64, 64),
+    ("xing4.0 decode", 16, 4, 64, 64, 64, 64),
+    ("ling-3.0 decode", 32, 8, 64, 512, 64, 64),
+    # over it: a tile for one expert's expected rows and a quarter more
+    ("lfm2 decode", 128, 4, 32, 32, 128, 512),          # 16 an expert
+    ("lfm2 chunk", 1536, 4, 32, 32, 256, 6144),         # 192 an expert
+    ("xing4.0 chunk", 1024, 4, 64, 64, 128, 4096),      # 64 an expert
+    ("ling-3.0 chunk", 2048, 8, 64, 512, 128, 3072),
+    ("mimo-v2 chunk", 1024, 8, 16, 256, 128, 768),
+    ("trinity-mini chunk", 512, 8, 16, 128, 128, 768),
+    ("deepseek-v3.2 chunk", 1024, 8, 8, 256, 128, 384),
+    ("an expert over the ridge", 4096, 2, 8, 8, 256, 8192),
+])
+def test_the_row_block_of_a_cell(cell, T, k, held, outputs, rows, expected):
+    """The block is one and a half times the expected pairs, rounded up
+    to 64 (the rule of PR 27), where that is a tile the chip can pay
+    for; over the ridge it is a tile of 128 or 256 rows."""
+    assert moe.row_block(T * k, held / outputs, held) == (rows, expected)
+    assert (rows == expected) == (expected <= moe.RIDGE_ROWS)
+
+
+@pytest.mark.parametrize("T, k, share, expected, rows, over", [
+    (64, 4, 0.25, 128, 128, 0),     # a block under the ridge: as it was
+    (64, 4, 0.25, 128, 128, 1),
+    (256, 4, 0.25, 384, 128, 0),    # over it: tiles of 128
+    (256, 4, 0.25, 384, 128, 1),
+    (96, 4, 1.0, 384, 128, 0),      # every pair here: they always fit
+])
+def test_extra_row_blocks_count_in_the_expected_rows_whatever_the_block(
+        T, k, share, expected, rows, over, rng):
+    """`extra` is counted in blocks of 1.5 x the expected rows, as it was
+    before the walk's block had a cap: 0 where the pairs here fill them,
+    1 where there is one pair more; the walk takes its own blocks, which
+    the last result counts."""
+    held = 4
+    assert moe.row_block(T * k, share, held) == (rows, expected)
+    idx = np.full(T * k, 9, np.int32)                   # 9: held elsewhere
+    loads = np.bincount(rng.integers(0, held, expected + over),
+                        minlength=held)
+    idx[rng.permutation(T * k)[: expected + over]] = np.repeat(
+        np.arange(held), loads)
+    counts, extra, blocks = _walk_beside_an_expert_loop(
+        rng, idx.reshape(T, k), held, share)
+    assert counts == list(loads)
+    assert extra == over
+    assert blocks == _blocks_walked(loads, rows)
+
+
+def test_the_tools_sweep_walks_the_rules_block_and_forced_ones():
+    """`tools_bench_kernels.py --grouped-product` (the sweep the comment
+    above `moe.RIDGE_ROWS` cites) times the walk with the rule's block
+    and with others forced, and leaves the rule as it found it."""
+    from tools_bench_kernels import grouped_product_sweep
+    rule = moe.row_block
+    recs = list(grouped_product_sweep(
+        blocks=(64, 128), reps=1, draws=2,
+        shapes=(("all held", 64, 4, 8, 8, 32, 16),
+                ("cut", 256, 4, 32, 8, 32, 16))))
+    assert moe.row_block is rule
+    assert [(r["shape"], r["rows"], r["taken"]) for r in recs] == [
+        ("all held", 64, False), ("all held", 128, False),
+        ("all held", 256, True), ("cut", 64, False), ("cut", 128, True),
+        ("cut", 384, False)]
+    by = {(r["shape"], r["rows"]): r["blocks"] for r in recs}
+    assert by["all held", 256] == 1 and by["cut", 384] == 1
+    assert by["all held", 64] >= 4 and by["cut", 64] > by["cut", 128] >= 2
 
 
 def test_gate_chooses_by_s_plus_b_and_weights_by_s():

@@ -249,12 +249,12 @@ def test_identity_experts_return_their_input_and_are_counted(rng):
     n = dict(zip(layer.ZERO_STATS, np.asarray(stats)))
     assert n["assignments"] == 36 and n["local_assignments"] == 0
     assert n["zero_assignments"] == int((np.asarray(idx) >= 16).sum()) > 0
-    # a layer told of no identity expert has none: five counts, as ever
+    # a layer told of no identity expert has none: six counts, as ever
     plain = moe.SharedRoutedExperts(
         32, 16, n_routed_experts=16, experts_held=4, first_expert=0,
         top_k=4, n_shared_experts=1, norm_topk_prob=True,
         routed_scaling_factor=2.5, param_dtype=F32)
-    assert jax.eval_shape(plain, plain.abstract_params(), x)[1].shape == (5,)
+    assert jax.eval_shape(plain, plain.abstract_params(), x)[1].shape == (6,)
     assert plain.share == 4 / 16
 
 
@@ -351,7 +351,8 @@ def test_scopes_stats_and_routes_of_the_programs(served):
     n = {k[len("serve.moe_"):]: reg.counter_value(k)
          for k, _ in fam.LongCatFlashLMHeadModel.STATS}
     assert list(n) == ["assignments", "local_assignments", "expert_hits",
-                       "extra_row_blocks", "layer_steps", "max_expert_load",
+                       "extra_row_blocks", "row_blocks", "layer_steps",
+                       "max_expert_load",
                        "zero_assignments"]
     assert 0 < n["zero_assignments"] < n["assignments"]
     # 8 of the 24 outputs are identity experts: about a third of the pairs

@@ -384,6 +384,7 @@ def test_a_family_is_served_by_its_hooks(family, route, monkeypatch):
         n = {k[len("serve.moe_"):]: reg.counter_value(k)
              for k, _ in model.STATS}
         assert n["extra_row_blocks"] >= 0
+        assert n["row_blocks"] >= n["extra_row_blocks"]
         # (xing4 and lfm2 hold every expert of a layer, 8 here: every
         # pair is local; the others hold 4 of their router's 16)
         held = 8 if family in ("xing4", "lfm2") else 4

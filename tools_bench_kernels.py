@@ -12,6 +12,10 @@ shapes, no device contact — not kernel timings).
     python tools_bench_kernels.py --json           # machine-readable
     python tools_bench_kernels.py --chain norm     # audit one kernel's
                                                    # unfused op chain
+    python tools_bench_kernels.py --grouped-product  # ON THE CHIP: the
+                                                   # expert walk by block
+                                                   # size (the one case
+                                                   # that times anything)
 
 tools_obs_report.py embeds the same numbers as its `kernels` section
 (--kernels).
@@ -29,6 +33,84 @@ def kernel_section(batch: int = 8, seq: int = 2048) -> dict:
     tools_obs_report's `kernels` section."""
     import bench
     return bench._hardware_free_kernels(batch, seq)
+
+
+#: the walks the expert cells run (nn/moe.dropless_local_experts):
+#: (cell's program, tokens T, experts a token k, router outputs, experts
+#: held, hidden, expert intermediate)
+GROUPED_PRODUCT_SHAPES = (
+    ("lfm2 decode", 128, 4, 32, 32, 2048, 1792),
+    ("lfm2 chunk", 1536, 4, 32, 32, 2048, 1792),
+    ("xing4.0 chunk", 1024, 4, 64, 64, 3584, 1024),
+    ("ling-3.0 chunk", 2048, 8, 512, 64, 2560, 768),
+    ("mimo-v2 chunk", 1024, 8, 256, 16, 4096, 2048),
+    ("trinity-mini chunk", 512, 8, 128, 16, 2048, 1024),
+    ("deepseek-v3.2 chunk", 1024, 8, 256, 8, 7168, 2048),
+    ("kimi-k2.6 chunk", 512, 8, 384, 12, 7168, 2048),
+    ("longcat-flash chunk", 512, 12, 768, 16, 6144, 2048),
+)
+
+
+def grouped_product_sweep(blocks=(64, 128, 192, 256, 512), reps: int = 5,
+                          draws: int = 6, shapes=GROUPED_PRODUCT_SHAPES):
+    """`nn/moe.dropless_local_experts` alone (sort, walk, combine; no
+    router, no shared expert) at each expert cell's shapes in bfloat16,
+    with the block `row_block` gives and with every block of `blocks`
+    under today's 1.5 x expected rows forced instead: milliseconds a
+    call by the host's clock over `reps` x `draws` launches that end in
+    `block_until_ready` (top-k of uniform scores, `draws` routings), and
+    the blocks walked.  Yields one record a (shape, block).  The numbers
+    in the comment above `nn/moe.RIDGE_ROWS` are this sweep's; a time
+    means something on the chip only."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from hetu_tpu.nn import moe
+
+    rule = moe.row_block
+    dev = jax.devices()[0]
+    for name, T, k, outputs, held, h, inter in shapes:
+        kx, kg, kd, ki = jax.random.split(jax.random.PRNGKey(63), 4)
+        x = (jax.random.normal(kx, (T, h)) * 0.5).astype(jnp.bfloat16)
+        wgu = (jax.random.normal(kg, (held, h, 2 * inter)) * 0.02) \
+            .astype(jnp.bfloat16)
+        wd = (jax.random.normal(kd, (held, inter, h)) * 0.02) \
+            .astype(jnp.bfloat16)
+        routed = []
+        for d in range(draws):
+            w, idx = jax.lax.top_k(
+                jax.random.uniform(jax.random.fold_in(ki, d), (T, outputs)),
+                k)
+            routed.append((idx.astype(jnp.int32), w))
+        share = held / outputs
+        taken, expected = rule(T * k, share, held)
+        for rows in sorted({b for b in blocks if b < expected}
+                           | {taken, expected}):
+            # the walk with `rows` forced: the rule is a module function
+            # read at trace time, patched for this one trace
+            moe.row_block = lambda *a, rows=rows: (rows, expected)
+            try:
+                f = jax.jit(lambda x, i, w, a, b: moe.dropless_local_experts(
+                    x, i, w, a, b, first_expert=0, share=share))
+                walked = [int(f(x, idx, w, wgu, wd)[3])
+                          for idx, w in routed]
+            finally:
+                moe.row_block = rule
+            t0 = time.perf_counter()
+            for _ in range(reps):
+                for idx, w in routed:
+                    y = f(x, idx, w, wgu, wd)[0]
+            y.block_until_ready()
+            ms = (time.perf_counter() - t0) / (reps * draws) * 1e3
+            yield {"shape": name, "pairs": T * k, "held": held,
+                   "share": share, "expected_rows": expected,
+                   "rows": rows, "taken": rows == taken,
+                   "ms": round(ms, 4),
+                   "blocks": round(sum(walked) / draws, 2),
+                   "device": dev.device_kind}
+        del x, wgu, wd
 
 
 def _fmt_bytes(b: float) -> str:
@@ -52,7 +134,17 @@ def main(argv=None) -> int:
                          "swiglu, rotary, quant, flash, paged_attn, "
                          "paged_attn_int8, paged_attn_int4, "
                          "paged_verify, sample)")
+    ap.add_argument("--grouped-product", action="store_true",
+                    help="time nn/moe.dropless_local_experts by row "
+                         "block at the expert cells' shapes (run it on "
+                         "the chip: a CPU's times say nothing); one "
+                         "JSON record a (shape, block)")
     args = ap.parse_args(argv)
+
+    if args.grouped_product:
+        for rec in grouped_product_sweep():
+            print(json.dumps(rec), flush=True)
+        return 0
 
     if args.chain:
         from hetu_tpu.ops.pallas import traffic as t
